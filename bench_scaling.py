@@ -8,60 +8,30 @@ mesh grows: ideal weak scaling keeps ms/step constant, so
 (h, Y, Z) halo slabs per stage per neighbor over ICI, independent of mesh
 size, so the model predicts near-flat scaling; this harness measures it.
 
-On a TPU slice it reports the real number. On the virtual CPU mesh
-(default: 8 devices via ``--xla_force_host_platform_device_count``) the
-"devices" share the same physical cores — useful as a harness check and a
-regression signal for accidental replication, not as a hardware claim.
+It takes the devices jax finds and refuses anything but TPUs: virtual
+CPU "devices" share the same physical cores, and a ratio of their times
+is not a scaling number.
 
-Prints one JSON line per mesh size and a final efficiency line.
+Prints one JSON line per mesh size (each naming the device) and a final
+efficiency line.
 
 Usage: ``python bench_scaling.py [--local 64] [--devices 1,2,4,8]
-[--profile DIR]`` (set ``PYSTELLA_BENCH_PLATFORM=tpu`` to dial
-hardware). ``--profile`` wraps the LARGEST mesh's timed window in a
+[--profile DIR]``. ``--profile`` wraps the LARGEST mesh's timed window in a
 ``jax.profiler`` capture; the parsed per-scope durations land in the
 run-event log (``PYSTELLA_EVENT_LOG``) as a ``trace_summary`` event —
 the at-scale halo-exchange/stencil breakdown the perf ledger cites.
 """
 
-import contextlib
 import json
 import os
 import sys
 import time
 
-def _cfg():
-    """The central env registry, loaded BY FILE (pre-jax, pre-package —
-    the same trick bench.py's orchestrator uses)."""
-    import importlib.util
-    path = os.path.join(os.path.dirname(os.path.abspath(__file__)),
-                        "pystella_tpu", "config.py")
-    spec = importlib.util.spec_from_file_location("_scaling_config", path)
-    mod = importlib.util.module_from_spec(spec)
-    sys.modules[spec.name] = mod
-    spec.loader.exec_module(mod)
-    return mod
+import numpy as np
+import jax
 
-
-_cpu = _cfg().getenv("PYSTELLA_BENCH_PLATFORM") == "cpu"
-if _cpu:
-    _flags = os.environ.get("XLA_FLAGS", "")
-    if "xla_force_host_platform_device_count" not in _flags:
-        os.environ["XLA_FLAGS"] = \
-            _flags + " --xla_force_host_platform_device_count=8"
-    from __graft_entry__ import _drop_remote_tpu_plugin
-    _drop_remote_tpu_plugin()
-else:
-    # async-collective + latency-hiding-scheduler flags, set before the
-    # backend dials: the sharded payloads' overlapped halo path depends
-    # on them to hide ppermutes behind interior compute (recorded in
-    # every perf report's env fingerprint)
-    from pystella_tpu.parallel.overlap import ensure_scheduler_flags
-    ensure_scheduler_flags()
-
-import numpy as np  # noqa: E402
-import jax  # noqa: E402
-
-from bench import build_gw_step, build_preheat_step  # noqa: E402
+from bench import build_gw_step, build_preheat_step, device_block
+from pystella_tpu.ops.pallas_stencil import LANE
 
 
 def _factor2(n):
@@ -108,13 +78,14 @@ def run_mesh(ndev, local_n, nsteps=10, nwarmup=2, dtype=np.float32,
         grid_shape = (local_n * ndev, local_n, local_n)
         decomp = ps.DomainDecomposition((ndev, 1, 1),
                                         devices=jax.devices()[:ndev])
-        # coupled_multi_step is a fused-stepper driver: force the fused
-        # tier there (construction is the real feasibility check), and
-        # skip the random state it builds its own ICs to replace
+        # coupled_multi_step is a fused-stepper driver (and builds its
+        # own ICs); otherwise the fused tier runs where its compiled
+        # kernels can — a lane-aligned z axis — and the generic XLA
+        # path elsewhere
         coupled = system == "coupled"
         stepper, state, dt = build_preheat_step(
             grid_shape, dtype, decomp=decomp,
-            fused=True if coupled else "auto",
+            fused=coupled or local_n % LANE == 0,
             make_state=not coupled)
     t = dtype(0.0)
 
@@ -124,8 +95,6 @@ def run_mesh(ndev, local_n, nsteps=10, nwarmup=2, dtype=np.float32,
         # (the per-stage barrier the physics requires) — weak-scaling
         # evidence for the ACCURATE chunked path, not just the
         # frozen-background bench loop
-        if not hasattr(stepper, "coupled_multi_step"):
-            raise SystemExit(f"no fused tier for {grid_shape}")
         # near-homogeneous preheating ICs (random noise is violently
         # unstable under the g^2 phi^2 chi^2 coupling — same choice as
         # bench.py run_coupled)
@@ -179,8 +148,7 @@ def run_mesh(ndev, local_n, nsteps=10, nwarmup=2, dtype=np.float32,
     def _profiled_steps():
         s = state
         for _ in range(nsteps):
-            # host-side span per step: even a CPU capture (no device
-            # rows) then yields a non-empty per-scope table
+            # host-side span per step, beside the device rows
             with ps.obs.trace_scope("bench_step"):
                 s = step(s)
         jax.block_until_ready(s)
@@ -205,10 +173,12 @@ def main():
     profile_dir = None
     if "--profile" in argv:
         profile_dir = argv[argv.index("--profile") + 1]
-    # persistent compilation cache: a weak-scaling sweep re-dials and
-    # recompiles the same per-device program shapes run after run;
-    # cached backend compiles take minutes off the sweep (cold_start
-    # events from the instrumented steppers record the split)
+    dev = device_block()
+    if dev["platform"] != "tpu":
+        raise SystemExit(f"bench_scaling.py measures on TPUs; jax found "
+                         f"{dev}")
+    # persistent compilation cache: a weak-scaling sweep recompiles the
+    # same per-device program shapes run after run
     from pystella_tpu.obs.memory import ensure_compilation_cache
     ensure_compilation_cache()
     navail = len(jax.devices())
@@ -222,9 +192,6 @@ def main():
         dev_counts = [d for d in dev_counts if d <= navail]
     if not dev_counts:
         raise SystemExit("no runnable device counts")
-    platform = jax.devices()[0].platform
-    suffix = "" if platform == "tpu" else f", {platform}"
-
     sysname = "" if system == "scalar" else f" {system}"
     times = {}
     for ndev in dev_counts:
@@ -236,9 +203,9 @@ def main():
         times[ndev] = ms
         print(json.dumps({
             "metric": f"weak-scaling{sysname} {ndev} dev "
-                      f"({local_n}^3/dev{suffix})",
+                      f"({local_n}^3/dev)",
             "value": ms, "unit": "ms/step",
-            "vs_baseline": None}), flush=True)
+            "vs_baseline": None, "device": dev}), flush=True)
         print(f"# {ndev} devices: {ms:8.2f} ms/step "
               f"({sites * 1e3 / ms:.3e} site-updates/s total)",
               file=sys.stderr, flush=True)
@@ -246,10 +213,9 @@ def main():
     n0, n1 = min(times), max(times)
     eff = times[n0] / times[n1]
     print(json.dumps({
-        "metric": f"weak-scaling{sysname} efficiency {n0}->{n1} "
-                  f"dev{suffix}",
-        "value": eff, "unit": "fraction", "vs_baseline": eff / 0.85}),
-        flush=True)
+        "metric": f"weak-scaling{sysname} efficiency {n0}->{n1} dev",
+        "value": eff, "unit": "fraction", "vs_baseline": eff / 0.85,
+        "device": dev}), flush=True)
 
 
 if __name__ == "__main__":

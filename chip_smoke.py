@@ -1,0 +1,364 @@
+"""The quickest proof that the system still starts on the chip.
+
+``python chip_smoke.py`` drives the main path once, in one process,
+through the entry point a user calls — ``examples/scalar_preheating.py::
+main(argv)``: two-field preheating at 512**3 float32, fused Pallas RK54
+stages, WKB initial fluctuations, self-consistent expansion, energy,
+statistics, spectra, histogram, HDF5 output, sentinel, checkpoints —
+
+1. as coupled 4-step chunks (the deferred-drag stage-pair kernels) for
+   16 steps with a checkpoint every 8, then
+2. as the stage-by-stage host loop upstream ships, for 2 steps,
+
+and checks what comes out by the repository's own means: the run events
+(completion, no kernel or assembly fallback, every kernel on the
+streaming tier with the blocking it took), the HDF5 series, the
+checkpoint read back, the Friedmann constraint, the chip's own
+``peak_bytes_in_use`` — and two steps of the fused stepper against the
+plain ``LowStorageRK54`` + XLA ``FiniteDifferencer`` reference from one
+seeded state (``bench.fused_parity``). With four chips it repeats all of
+it on a ``(2, 2, 1)`` mesh and checks the work is spread over them.
+
+It exits non-zero, before building anything, unless jax finds a TPU. Its
+last line of standard output is one JSON object,
+``{"ok": true, "device": {"platform", "kind", "count"}}``. The readings
+it prints on the way (set-up seconds, ms per chunk) are smoke readings,
+not a benchmark. The compile cache follows
+``pystella_tpu.obs.ensure_compilation_cache``: ``JAX_COMPILATION_CACHE_DIR``
+when set, else ``bench_results/xla_cache`` in the checkout.
+"""
+
+import hashlib
+import json
+import logging
+import os
+import sys
+import tempfile
+import time
+import warnings
+
+import numpy as np
+
+HERE = os.path.dirname(os.path.abspath(__file__))
+
+GRID = (512, 512, 512)
+#: two steps of the fused stepper may differ from the generic reference
+#: by float32 round-off; the compiled kernels read 2.7e-7 at 128**3
+PARITY_BOUND = 1e-5
+#: warning text that means a tier other than the designed one was built
+#: (ops/fused.py and ops/derivs.py)
+FALLBACK_WARNINGS = ("falling back", "fusion disabled",
+                     "kernels unavailable", "the option is ignored")
+#: kernels of the main path, all on the streaming tier at these sizes
+MAIN_KERNELS = ("stage", "pair", "coupled_pair", "energy")
+
+
+class SmokeFailure(RuntimeError):
+    """A check of the smoke did not hold."""
+
+
+def require(cond, msg):
+    if not cond:
+        raise SmokeFailure(msg)
+
+
+def say(msg):
+    print(f"[chip_smoke] {msg}", flush=True)
+
+
+class watch_fallbacks:
+    """Collect, over a ``with`` block, every warning and every
+    ``pystella_tpu`` log record that names a tier fallback."""
+
+    def __enter__(self):
+        self.messages = []
+        self._handler = logging.Handler(logging.WARNING)
+        self._handler.emit = lambda rec: self.messages.append(
+            rec.getMessage())
+        logging.getLogger("pystella_tpu").addHandler(self._handler)
+        self._caught = warnings.catch_warnings(record=True)
+        self._warned = self._caught.__enter__()
+        warnings.simplefilter("always")
+        return self
+
+    def __exit__(self, *exc):
+        self._caught.__exit__(*exc)
+        logging.getLogger("pystella_tpu").removeHandler(self._handler)
+        self.messages += [str(w.message) for w in self._warned]
+        self.fallbacks = [m for m in self.messages
+                          if any(t in m for t in FALLBACK_WARNINGS)]
+
+
+def run_example(argv, label):
+    """``main(argv)`` of the flagship example, in-process; returns the
+    final constraint and the fallback warnings it raised (none, on a
+    passing run)."""
+    sys.path.insert(0, os.path.join(HERE, "examples"))
+    try:
+        import scalar_preheating
+    finally:
+        sys.path.pop(0)
+    say(f"{label}: scalar_preheating.main({' '.join(argv)})")
+    t0 = time.perf_counter()
+    with watch_fallbacks() as watch:
+        constraint = scalar_preheating.main(argv)
+    say(f"{label}: returned in {time.perf_counter() - t0:.1f}s, "
+        f"constraint {constraint:.6e}")
+    return float(constraint), watch.fallbacks
+
+
+def check_run_events(events, label, kernels):
+    """The run's own record: completed, never aborted, every fused
+    kernel the designed tier. Returns the ``{kernel: (bx, by, source)}``
+    table and the ``run_complete`` payload."""
+    kinds = [e["kind"] for e in events]
+    require("run_complete" in kinds, f"{label}: no run_complete event")
+    require("run_aborted" not in kinds, f"{label}: run_aborted")
+    for kind in ("kernel_fallback", "assemble_fallback", "diverged"):
+        hit = [e["data"] for e in events if e["kind"] == kind]
+        require(not hit, f"{label}: {kind} event(s): {hit}")
+    blocks = {}
+    for e in events:
+        if e["kind"] != "block_choice":
+            continue
+        d = e["data"]
+        require(d["stencil"] == "StreamingStencil",
+                f"{label}: kernel {d['kernel']} took {d['stencil']}")
+        require(d["source"] in ("heuristic", "explicit"),
+                f"{label}: kernel {d['kernel']} blocked from "
+                f"{d['source']} (an uncommitted table or override)")
+        blocks[d["kernel"]] = (d["bx"], d["by"], d["source"])
+    missing = [k for k in kernels if k not in blocks]
+    require(not missing, f"{label}: no block_choice for {missing}")
+    for k, (bx, by, source) in blocks.items():
+        say(f"{label}: kernel {k}: (bx, by) = ({bx}, {by}), {source}")
+    done = [e for e in events if e["kind"] == "run_complete"][-1]
+    done = {"step": done["step"], **done["data"]}
+    require(np.isfinite(done["constraint"]),
+            f"{label}: constraint {done['constraint']}")
+    return blocks, done
+
+
+def check_hdf5(path, label):
+    import h5py
+    with h5py.File(path, "r") as f:
+        for series in ("energy/total", "energy/constraint",
+                       "statistics/f/mean", "spectra/scalar",
+                       "spectra/rho", "rho_histogram/linear"):
+            require(series in f, f"{label}: {path} lacks {series}")
+            data = np.asarray(f[series])
+            require(data.shape[0] >= 1 and np.all(np.isfinite(data)),
+                    f"{label}: {series} empty or non-finite")
+        require(np.isfinite(f.attrs["final_constraint"]),
+                f"{label}: final_constraint attribute")
+        return int(f["energy/total"].shape[0])
+
+
+def state_digest(ckpt_dir, decomp, step):
+    """sha256 of the final state, read back from the checkpoint the run
+    wrote at ``step`` straight onto ``decomp``'s mesh."""
+    import pystella_tpu as ps
+    with ps.Checkpointer(ckpt_dir) as ckpt:
+        require(ckpt.latest_step == step,
+                f"newest checkpoint is step {ckpt.latest_step}, the run "
+                f"ended at {step}")
+        _, state, meta = ckpt.restore(step, mesh=decomp)
+    h = hashlib.sha256()
+    for name in sorted(state):
+        h.update(np.asarray(state[name]).tobytes())
+    return h.hexdigest()[:16], state, meta
+
+
+def run_leg(grid_shape, proc_shape, workdir):
+    """The whole smoke on one mesh: both driver invocations, their
+    checks, the checkpoint read-back and the parity comparison (at the
+    run's own lattice: the generic path fits the chip at 512**3). Raises
+    :class:`SmokeFailure` when a check does not hold; returns the leg's
+    summary dict."""
+    import jax
+    import bench
+    import pystella_tpu as ps
+    from pystella_tpu import obs
+    from pystella_tpu.obs.events import read_events
+
+    grid_shape = tuple(int(n) for n in grid_shape)
+    proc_shape = tuple(int(p) for p in proc_shape)
+    ndev = int(np.prod(proc_shape))
+    devices = jax.devices()[:ndev]
+    label = "x".join(str(p) for p in proc_shape)
+    os.makedirs(workdir, exist_ok=True)
+    dt = 0.1 * 5.0 / max(grid_shape)
+    common = ["--grid-shape", *map(str, grid_shape),
+              "--proc-shape", *map(str, proc_shape),
+              "--dtype", "float32", "--halo-shape", "2", "--fused",
+              "--forensics-dir", os.path.join(workdir, "forensics")]
+
+    # 1. coupled chunks: 16 steps as 4 chunks of 4, checkpoint every 8
+    nsteps, chunk = 16, 4
+    ev_path = os.path.join(workdir, "chunk_events.jsonl")
+    ckpt_dir = os.path.join(workdir, "ckpt")
+    _, bad = run_example(
+        common + ["--chunk-steps", str(chunk),
+                  "--end-time", repr((nsteps - 0.5) * dt),
+                  "--checkpoint-dir", ckpt_dir,
+                  "--checkpoint-interval", "8",
+                  "--outfile", os.path.join(workdir, "chunk"),
+                  "--event-log", ev_path], f"{label} chunked")
+    require(not bad, f"{label} chunked: fallback warning(s): {bad}")
+    events = read_events(ev_path)
+    blocks, done = check_run_events(events, f"{label} chunked",
+                                    MAIN_KERNELS)
+    require(done["step"] == nsteps,
+            f"{label} chunked: ended at step {done['step']}, "
+            f"expected {nsteps}")
+    rows = check_hdf5(os.path.join(workdir, "chunk.h5"), f"{label} chunked")
+    saves = [e for e in events if e["kind"] == "checkpoint_durable"]
+    require(saves, f"{label} chunked: no durable checkpoint")
+    chunk_ms = [e["data"]["ms"] for e in events if e["kind"] == "step_time"]
+    require(chunk_ms, f"{label} chunked: no step_time event")
+
+    # the final state, read back from the checkpoint onto the same mesh
+    decomp = ps.DomainDecomposition(proc_shape, devices=devices)
+    digest, state, meta = state_digest(ckpt_dir, decomp, nsteps)
+    shards = state["f"].addressable_shards
+    local = (2,) + tuple(n // p for n, p in zip(grid_shape, proc_shape))
+    require(len(state["f"].sharding.device_set) == ndev,
+            f"{label}: restored state on "
+            f"{len(state['f'].sharding.device_set)} device(s)")
+    require(all(tuple(s.data.shape) == local for s in shards),
+            f"{label}: restored shards {[s.data.shape for s in shards]}")
+    for name in sorted(state):
+        require(bool(np.all(np.isfinite(np.asarray(state[name])))),
+                f"{label}: restored {name} non-finite")
+    del state
+    say(f"{label}: final state digest {digest} (checkpoint step "
+        f"{nsteps}, t = {meta['t']:.6f}, a = {meta['a']:.9f})")
+
+    # the run's own account of where its state lived
+    require(done["devices"] == ndev
+            and tuple(done["shard_shape"]) == local,
+            f"{label}: run state on {done['devices']} device(s), shards "
+            f"{done['shard_shape']}; expected {ndev} x {local}")
+    if ndev > 1:
+        require(done["halo_bytes"] > 0,
+                f"{label}: no halo traffic traced on a sharded mesh")
+
+    # 2. the stage-by-stage host loop, 2 steps
+    ev2_path = os.path.join(workdir, "stage_events.jsonl")
+    _, bad = run_example(
+        common + ["--end-time", repr(1.5 * dt),
+                  "--outfile", os.path.join(workdir, "stage"),
+                  "--event-log", ev2_path], f"{label} stage loop")
+    require(not bad, f"{label} stage loop: fallback warning(s): {bad}")
+    _, done2 = check_run_events(read_events(ev2_path),
+                                f"{label} stage loop", ("stage",))
+    require(done2["step"] == 2, f"{label} stage loop: ended at step "
+                                f"{done2['step']}, expected 2")
+    check_hdf5(os.path.join(workdir, "stage.h5"), f"{label} stage loop")
+    obs.configure(None)
+
+    # 3. parity against the plain reference, on the same devices
+    with watch_fallbacks() as watch:
+        maxrel, _ = bench.fused_parity(grid_shape, decomp, nsteps=2)
+    require(not watch.fallbacks,
+            f"{label} parity: fallback warning(s): {watch.fallbacks}")
+    say(f"{label}: parity at {grid_shape}: max relative difference "
+        f"{maxrel:.3e} (bound {PARITY_BOUND:g})")
+    require(maxrel <= PARITY_BOUND,
+            f"{label}: parity {maxrel:.3e} over {PARITY_BOUND:g}")
+
+    # the chip's own memory account (None on a backend without one —
+    # which would mean this did not run on the chip)
+    peaks = []
+    for d in devices:
+        stats = d.memory_stats()
+        peak = stats.get("peak_bytes_in_use") if stats else None
+        peaks.append(peak)
+    say(f"{label}: peak_bytes_in_use per device: {peaks}")
+    return {
+        "proc_shape": list(proc_shape), "grid_shape": list(grid_shape),
+        "steps": nsteps, "chunk_steps": chunk,
+        "constraint": done["constraint"],
+        "stage_loop_constraint": done2["constraint"],
+        "digest": digest, "parity_maxrel": maxrel,
+        "blocks": {k: list(v) for k, v in blocks.items()},
+        "energy_rows": rows, "checkpoints": len(saves),
+        "last_chunk_ms": chunk_ms[-1],
+        "halo_bytes": done["halo_bytes"],
+        "peak_bytes_in_use": peaks,
+    }
+
+
+def check_peaks(leg):
+    """Every device reports a peak, and on a mesh the largest is within
+    1.5x of the smallest — everything on device 0 is the failure to
+    look for."""
+    peaks = leg["peak_bytes_in_use"]
+    require(all(isinstance(p, int) and p > 0 for p in peaks),
+            f"peak_bytes_in_use {peaks}: not every device reports one")
+    require(max(peaks) <= 1.5 * min(peaks),
+            f"peak_bytes_in_use {peaks}: spread over 1.5x")
+
+
+def main():
+    import jax
+    import jaxlib
+    dev = jax.devices()[0]
+    device = {"platform": dev.platform, "kind": dev.device_kind,
+              "count": len(jax.devices())}
+    if dev.platform != "tpu":
+        print(f"chip_smoke: jax found {device}, not a TPU; nothing run",
+              file=sys.stderr)
+        return 1
+
+    import pystella_tpu  # noqa: F401 — fail here, not mid-run, without it
+    from importlib import metadata
+    from pystella_tpu import obs
+    from pystella_tpu.obs import ledger
+
+    cache_dir = obs.ensure_compilation_cache()
+    say(f"device {device}; jax {jax.__version__}, jaxlib "
+        f"{jaxlib.__version__}, libtpu {metadata.version('libtpu')}; "
+        f"compile cache {cache_dir} "
+        f"({len(os.listdir(cache_dir))} entries)")
+    say(f"device order: {[d.id for d in jax.devices()]}, coords "
+        f"{[getattr(d, 'coords', None) for d in jax.devices()]}")
+    require(any(key in dev.device_kind for key in ledger.HBM_PEAK_GBPS),
+            f"device kind {dev.device_kind!r} matches no key of "
+            "obs.ledger.HBM_PEAK_GBPS")
+
+    legs = []
+    with tempfile.TemporaryDirectory(prefix="chip_smoke_") as tmp:
+        if len(jax.devices()) >= 4:
+            # first: an allocator's peak is for the life of the process,
+            # and the one-chip leg leaves device 0's far above the rest
+            legs.append(run_leg(GRID, (2, 2, 1),
+                                os.path.join(tmp, "4chip")))
+            check_peaks(legs[-1])
+        else:
+            say(f"four-chip leg not run: {len(jax.devices())} device(s)")
+        legs.append(run_leg(GRID, (1, 1, 1), os.path.join(tmp, "1chip")))
+        check_peaks(legs[-1])
+
+    totals = obs.compile_totals()
+    say(f"set-up: trace {totals['trace_s']:.1f}s + compile "
+        f"{totals['compile_s']:.1f}s; compile cache hits "
+        f"{totals['cache_hits']}, misses {totals['cache_misses']}")
+    for leg in legs:
+        say(f"{leg['proc_shape']}: last {leg['chunk_steps']}-step chunk "
+            f"{leg['last_chunk_ms']:.1f} ms (a smoke reading, output "
+            "and checkpoint included — not a benchmark)")
+    print(json.dumps({"summary": {
+        "legs": legs,
+        "setup_s": {"trace": totals["trace_s"],
+                    "compile": totals["compile_s"]},
+        "cache": {"dir": cache_dir, "hits": totals["cache_hits"],
+                  "misses": totals["cache_misses"]},
+        "versions": {"jax": jax.__version__, "jaxlib": jaxlib.__version__,
+                     "libtpu": metadata.version("libtpu")}}}), flush=True)
+    print(json.dumps({"ok": True, "device": device}), flush=True)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

@@ -28,7 +28,11 @@ parser.add_argument("--grid-shape", "-grid", type=int, nargs=3,
                     metavar=("Nx", "Ny", "Nz"), default=(128, 128, 128))
 parser.add_argument("--proc-shape", "-proc", type=int, nargs=3,
                     metavar=("Npx", "Npy", "Npz"), default=(1, 1, 1))
-parser.add_argument("--dtype", type=np.dtype, default=np.float64)
+parser.add_argument("--dtype", type=np.dtype, default=np.float64,
+                    help="field dtype; float64 needs jax's x64 mode "
+                         "(JAX_ENABLE_X64=1) — without it jax truncates "
+                         "to float32 with only a warning, so pass "
+                         "float32 explicitly for a float32 run")
 parser.add_argument("--halo-shape", type=int, default=2, metavar="h",
                     help="stencil radius; 0 selects spectral derivatives")
 parser.add_argument("--box-dim", "-box", type=float, nargs=3,
@@ -132,14 +136,6 @@ parser.add_argument("--perf-report", type=str, default=None,
                     " log + metrics registry into perf_report.json/.md"
                     " under DIR (requires --event-log or"
                     " PYSTELLA_EVENT_LOG)")
-parser.add_argument("--compile-cache-dir", type=str, default=None,
-                    metavar="DIR", help="persistent XLA"
-                    " compilation-cache directory (default: the"
-                    " registered PYSTELLA_COMPILE_CACHE_DIR,"
-                    " bench_results/xla_cache; 'off' disables) — a"
-                    " restarted run then skips every already-seen"
-                    " backend compile, and the cold_start event records"
-                    " the hit/miss split")
 
 
 def main(argv=None):
@@ -154,7 +150,7 @@ def main(argv=None):
             and not ps.config.getenv("PYSTELLA_EVENT_LOG"):
         raise ValueError("--perf-report digests the event log: pass "
                          "--event-log (or set PYSTELLA_EVENT_LOG)")
-    cache_dir = ps.obs.ensure_compilation_cache(p.compile_cache_dir)
+    cache_dir = ps.obs.ensure_compilation_cache()
     p.grid_shape = tuple(p.grid_shape)
     p.proc_shape = tuple(p.proc_shape)
     p.box_dim = tuple(p.box_dim)
@@ -557,8 +553,14 @@ def main(argv=None):
     if decomp.rank == 0:
         print("Simulation complete")
         print(f"final constraint: {constraint:.16e}")
+    # where the final state lived, beside how the run ended: a mesh run
+    # whose state sits on one device is a failure this record shows
     ps.obs.emit("run_complete", step=step_count, t=t,
-                a=float(expand.a), constraint=float(constraint))
+                a=float(expand.a), constraint=float(constraint),
+                devices=len(state["f"].sharding.device_set),
+                shard_shape=list(
+                    state["f"].addressable_shards[0].data.shape),
+                halo_bytes=decomp.traced_halo_bytes())
     if p.perf_report is not None:
         # digest this run's record into the evidence artifact the
         # regression gate consumes (python -m pystella_tpu.obs.gate)

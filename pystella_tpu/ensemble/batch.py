@@ -220,7 +220,7 @@ class EnsembleStepper:
                  + (".health" if sentinel is not None else ""))
         fn = _obs_memory.instrument_jit(
             jax.jit(impl, donate_argnums=(0,) if self._donate else ()),
-            label=label, donated=self._donate)
+            label=label)
         self._jits[key] = fn
         return fn
 
@@ -285,8 +285,7 @@ class EnsembleStepper:
                     lambda ba, ma: jax.lax.dynamic_update_index_in_dim(
                         ba, ma.astype(ba.dtype), idx, 0), b, m)
             self._write_jit = _obs_memory.instrument_jit(
-                jax.jit(impl), label="ensemble.write_member",
-                donated=False)
+                jax.jit(impl), label="ensemble.write_member")
         member_state = jax.tree_util.tree_map(jnp.asarray, member_state)
         with trace_scope("ensemble_evict"):
             return self._write_jit(batch, jnp.asarray(index, jnp.int32),

@@ -360,7 +360,7 @@ class DFT:
             P(*((None,) * outer), names[0], yz or None, None))
 
     def _dft_impl(self, fx):
-        from pystella_tpu._compat import reshard
+        from jax.sharding import reshard
         outer = fx.ndim - 3
         if self._nproc == 1:
             return (jnp.fft.rfftn if self.is_real else jnp.fft.fftn)(
@@ -388,7 +388,7 @@ class DFT:
         return reshard(xk, khome)
 
     def _idft_impl(self, fk):
-        from pystella_tpu._compat import reshard
+        from jax.sharding import reshard
         outer = fk.ndim - 3
         if self._nproc == 1:
             if self.is_real:

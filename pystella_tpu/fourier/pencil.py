@@ -49,9 +49,8 @@ use :func:`pystella_tpu.fourier.plan.make_dft` to fall back to the
 Batched (multi-field) transforms pipeline the transposes: field
 ``k+1``'s ``all_to_all`` is issued BEFORE field ``k``'s local FFT
 stage, so the collective is in flight while dependence-free compute
-runs — the same issue-first discipline as the PR-3 halo overlap, and
-the program shape ``parallel.overlap.ensure_scheduler_flags`` exists
-for. Each stage carries a ``fft_stage`` scope and each transpose an
+runs — the same issue-first discipline as the PR-3 halo overlap. Each
+stage carries a ``fft_stage`` scope and each transpose an
 ``fft_transpose`` scope; the perf ledger's ``fft`` report section
 derives its exposed-vs-hidden transpose split from those rows.
 """

@@ -62,12 +62,17 @@ class PowerSpectra:
         kvecs = np.meshgrid(*sub_k, indexing="ij", sparse=False)
         kmags = np.sqrt(sum((dki * ki)**2 for dki, ki in zip(self.dk, kvecs)))
 
+        # float64 whatever the field dtype: np.histogram accumulates its
+        # weights in THEIR dtype, and float32 stops counting by ones at
+        # 2**24 modes — at 512**3 the sparse corner bins came out rounded
+        # to multiples of 8 and the last one (6 modes) as 0, an inf in
+        # every spectrum
         if fft.is_real:
-            counts = 2.0 * np.ones_like(kmags)
+            counts = np.full(kmags.shape, 2.0, np.float64)
             counts[kvecs[2] == 0] = 1.0
             counts[kvecs[2] == self.grid_shape[-1] // 2] = 1.0
         else:
-            counts = np.ones_like(kmags)
+            counts = np.ones(kmags.shape, np.float64)
 
         max_k = np.max(kmags)
         self.num_bins = int(max_k / self.bin_width + 0.5) + 1

@@ -1,7 +1,7 @@
 """Unified telemetry: run events, metrics, trace scopes, memory reports.
 
 One subsystem behind the pieces that grew up scattered (``utils/monitor``,
-``utils/profiling``, ``bench.py``'s hand-rolled orchestrator prints):
+``utils/profiling``, ``bench.py``'s hand-rolled prints):
 
 - :mod:`pystella_tpu.obs.events` — a structured JSONL run-event log
   (wall + monotonic timestamps, host id, step, event kind, payload) that
@@ -126,10 +126,10 @@ from pystella_tpu.obs.scope import (
     has_scope, lowered_scopes, register_scope, registered_scopes,
     trace_scope, traced)
 from pystella_tpu.obs.memory import (
-    CompileRecord, cache_bypass, cache_donation_safe, compile_totals,
+    CompileRecord, compile_totals,
     compile_watch, compile_with_report, device_memory_report,
     device_memory_stats, ensure_compilation_cache, instrument_jit,
-    probe_cache_donation_safety, program_fingerprint, runtime_versions,
+    program_fingerprint, runtime_versions,
     signature_fingerprint)
 # obs.gate, obs.warmstart, and obs.spans are deliberately NOT imported
 # here: their primary entry points are ``python -m pystella_tpu.obs.gate``
@@ -156,7 +156,6 @@ __all__ = [
     "register_scope", "registered_scopes",
     "CompileRecord", "compile_with_report", "compile_watch",
     "compile_totals", "instrument_jit", "ensure_compilation_cache",
-    "cache_bypass", "cache_donation_safe", "probe_cache_donation_safety",
     "program_fingerprint", "signature_fingerprint", "runtime_versions",
     "device_memory_report", "device_memory_stats",
     "trace", "ledger", "sentinel", "forensics", "perf", "stragglers",

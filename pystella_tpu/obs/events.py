@@ -43,9 +43,9 @@ ride an ambient thread-local context (:func:`tracing`), so existing
 emitting outside any context produces records indistinguishable from
 v1 apart from the version number.
 
-This module is importable without jax (the ``bench.py`` orchestrator
-process never touches jax by design); the host id is resolved lazily
-from an already-imported jax only.
+This module is importable without jax (a supervisor that must stay
+off the chip can log through it); the host id is resolved lazily from
+an already-imported jax only.
 
 Usage::
 
@@ -326,7 +326,7 @@ for _name, _help in (
                        "watermark coverage)"),
     # -- driver-side kinds (bench.py / examples; outside the package, so
     # -- not lint-audited, but registered so the vocabulary is one list)
-    ("bench_run", "bench payload run metadata"),
+    ("bench_run", "bench run metadata"),
     ("bench_metric", "one bench headline metric line"),
     ("run_start", "example-driver run began"),
     ("run_complete", "example-driver run completed"),
@@ -375,9 +375,8 @@ def rotated_family(path):
 
 def _host_id():
     """This process's index in the multi-controller cluster. Resolved
-    from jax only when jax is already imported — the bench orchestrator
-    (and any other jax-free supervisor) must be able to emit events
-    without dialing a backend."""
+    from jax only when jax is already imported — a jax-free supervisor
+    must be able to emit events without starting a backend."""
     jax = sys.modules.get("jax")
     if jax is None:
         return 0
@@ -425,7 +424,7 @@ class EventLog:
         splits a line — whole events only.
 
     Thread-safe; every line is flushed on write so concurrently-appending
-    processes (orchestrator + payload) interleave whole lines.
+    processes (a supervisor and its workers) interleave whole lines.
     """
 
     def __init__(self, path=None, host=None, rotate_bytes=None):
@@ -462,7 +461,7 @@ class EventLog:
         the rotated member). Rotation failures degrade to
         keep-appending — telemetry must never kill the run.
 
-        Concurrent appenders (the orchestrator + payload pattern) are
+        Concurrent appenders (a supervisor and its workers) are
         tolerated via an inode check: when ANOTHER process already
         rotated the live file out from under this one, this writer
         re-points at the fresh live file instead of renaming it away —
@@ -633,8 +632,8 @@ def get_log():
     global _default
     if _default is None:
         # direct read, not pystella_tpu.config.getenv: this module must
-        # stay loadable BY FILE in a jax-free supervisor (bench.py's
-        # orchestrator), where no package import is available
+        # stay loadable BY FILE in a jax-free supervisor, where no
+        # package import is available
         path = os.environ.get(
             "PYSTELLA_EVENT_LOG") or None  # env-registry: PYSTELLA_EVENT_LOG
         try:

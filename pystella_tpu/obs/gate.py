@@ -93,8 +93,8 @@ The module body is stdlib-only on purpose (report comparison must not
 require a working accelerator stack), but the ``python -m`` entry point
 imports the ``pystella_tpu`` package — and therefore jax — like any
 in-repo CI environment has. A truly jax-free supervisor should call
-:func:`compare_reports` from a by-file module load (the trick
-``bench.py`` uses for ``obs/events.py``), loading ``ledger.py`` the
+:func:`compare_reports` from a by-file module load
+(``importlib.util.spec_from_file_location``), loading ``ledger.py`` the
 same way first.
 """
 

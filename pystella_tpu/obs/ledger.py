@@ -30,8 +30,8 @@ consumed by :mod:`pystella_tpu.obs.gate`) and a human ``perf_report.md``.
 The module body never requires jax at runtime — versions come from
 package metadata and device fields degrade to ``None`` when no jax is
 loaded (importing it as ``pystella_tpu.obs.ledger`` still pulls jax via
-the package ``__init__``; a jax-free supervisor should load it by file,
-like ``bench.py`` loads ``obs/events.py``).
+the package ``__init__``; a jax-free supervisor should load it by
+file).
 """
 
 from __future__ import annotations
@@ -130,7 +130,7 @@ def xla_flag_fingerprint():
 def environment_fingerprint():
     """Everything needed to decide whether two perf reports are
     comparable. Resolved from an already-imported jax only (the module
-    must stay importable in the jax-free orchestrator); device fields
+    must stay importable in a jax-free supervisor); device fields
     are ``None`` when jax is not loaded."""
     env = {
         "python": _platform.python_version(),
@@ -1923,7 +1923,7 @@ def render_markdown(rep):
                f"hit(s) / {_fmt(ca.get('misses'), '.0f', '0')} miss(es)"
                f" (hit rate {_fmt(ca.get('hit_rate'), '.1%')})"
                if ca.get("dir") else "not wired "
-               "(set PYSTELLA_COMPILE_CACHE_DIR)"))
+               "(obs.ensure_compilation_cache was not called)"))
         ws = cs.get("warmstart") or {}
         if ws.get("claimed"):
             arts = ws.get("artifacts") or []

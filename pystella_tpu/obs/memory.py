@@ -16,11 +16,11 @@ Three kinds of evidence, all recorded into the event log:
   persistent-cache hit/miss attribution — the raw material of the perf
   ledger's ``cold_start`` section.
 - :func:`ensure_compilation_cache` — wires jax's persistent
-  compilation cache (``jax_compilation_cache_dir``) to the registered
-  ``PYSTELLA_COMPILE_CACHE_DIR``, so a process that re-dials a device
-  pays XLA's backend compile once per program *ever*, not once per
-  process. Hit/miss counts are read back through the same monitoring
-  hooks.
+  compilation cache: the directory ``JAX_COMPILATION_CACHE_DIR`` names
+  when the environment sets it, else ``bench_results/xla_cache`` in the
+  checkout, so a restarted process pays XLA's backend compile once per
+  program *ever*, not once per process. Hit/miss counts are read back
+  through the same monitoring hooks.
 - :func:`device_memory_report` — live allocator statistics
   (``Device.memory_stats()``: bytes in use, peak, limit). TPU backends
   populate these; CPU returns ``None`` and the report degrades to a
@@ -49,9 +49,7 @@ from pystella_tpu.obs import metrics as _metrics
 
 __all__ = ["CompileRecord", "compile_with_report", "compile_watch",
            "instrument_jit", "InstrumentedJit", "compile_totals",
-           "ensure_compilation_cache", "cache_donation_safe",
-           "should_bypass_cache",
-           "cache_bypass", "probe_cache_donation_safety",
+           "ensure_compilation_cache",
            "program_fingerprint", "signature_fingerprint",
            "runtime_versions", "device_memory_stats",
            "device_memory_report"]
@@ -307,7 +305,7 @@ class CompileRecord:
     label: str
     compile_seconds: float
     trace_seconds: float = 0.0
-    #: MLIR text serialization for the fingerprint/donation scan —
+    #: MLIR text serialization for the fingerprint —
     #: measurement overhead kept OUT of both spans above, but visible
     #: here so large-module hashing cost cannot hide
     serialize_seconds: float = 0.0
@@ -414,24 +412,10 @@ def compile_with_report(fn, *args, label=None, log=None, step=None,
         # MLIR serialization is Python-side measurement overhead —
         # keep it out of BOTH reported spans (a cache-hit
         # compile_seconds must show retrieval cost, not as_text()),
-        # and skip it entirely unless the fingerprint or the donation
-        # check below actually needs the text
-        text = None
-        if fingerprint or (_cache_configured()
-                           and not cache_donation_safe()):
-            text = lowered.as_text()
-        # a DONATED program must not be served from a deserialized
-        # cache entry on backends where that corrupts repeat calls
-        # (cache_donation_safe docstring): compile it fresh instead
-        donated = (text is not None
-                   and any(m in text for m in _DONATION_MARKERS))
-        bypass = should_bypass_cache(donated)
+        # and skip it entirely unless the fingerprint needs the text
+        text = lowered.as_text() if fingerprint else None
         tc = time.perf_counter()
-        if bypass:
-            with cache_bypass():
-                compiled = lowered.compile()
-        else:
-            compiled = lowered.compile()
+        compiled = lowered.compile()
         t2 = time.perf_counter()
     fp = kind = None
     if fingerprint:
@@ -447,10 +431,7 @@ def compile_with_report(fn, *args, label=None, log=None, step=None,
                         **_memory_analysis(compiled))
     _record_compile_metrics(rec)
     (log if log is not None else _events.get_log()).emit(
-        "compile", step=step, source="aot",
-        **(dict(cache_bypass="donation-unsafe-backend") if bypass
-           else {}),
-        **rec.asdict())
+        "compile", step=step, source="aot", **rec.asdict())
     return compiled, rec
 
 
@@ -473,31 +454,15 @@ class InstrumentedJit:
     tier's ``.lower()`` audits and ``functools`` interop keep working.
     """
 
-    __slots__ = ("_jitted", "_label", "_donated")
+    __slots__ = ("_jitted", "_label")
 
-    def __init__(self, jitted, label, donated=False):
+    def __init__(self, jitted, label):
         self._jitted = jitted
         self._label = label
-        self._donated = bool(donated)
-
-    def _bypass_cache(self):
-        """Donated program dispatched while the persistent cache is
-        wired on a donation-unsafe backend: any compile this call
-        triggers (first dispatch OR a later re-specialization — e.g. a
-        ``static_argnums`` stage index) must be fresh, so the whole
-        call runs under :class:`cache_bypass` (see
-        :func:`cache_donation_safe`). ~7 us per call, and only in
-        that specific configuration; undonated jits and safe backends
-        pay one bool."""
-        return should_bypass_cache(self._donated)
 
     def __call__(self, *args, **kwargs):
         with compile_watch(self._label) as w:
-            if self._bypass_cache():
-                with cache_bypass(watch=w):
-                    out = self._jitted(*args, **kwargs)
-            else:
-                out = self._jitted(*args, **kwargs)
+            out = self._jitted(*args, **kwargs)
         if (w.compile_seconds > 0.0 or w.cache_hits or w.cache_misses
                 or w.trace_seconds >= MIN_EVENT_TRACE_S):
             try:
@@ -529,258 +494,67 @@ class InstrumentedJit:
         return f"InstrumentedJit({self._label!r}, {self._jitted!r})"
 
 
-def instrument_jit(jitted, label, donated=False):
+def instrument_jit(jitted, label):
     """Wrap a ``jax.jit`` object so its compiles land in the compile
     ledger under ``label``. The package's internal jit sites (steppers,
     fused chunks, multigrid, spectra) all route through this — the
-    compile half of cold start stops being invisible. Pass
-    ``donated=True`` when the jit donates lattice buffers, so its first
-    compile bypasses the persistent cache on backends where a
-    cache-served donated executable corrupts
-    (:func:`cache_donation_safe`)."""
-    return InstrumentedJit(jitted, str(label), donated=donated)
+    compile half of cold start stops being invisible."""
+    return InstrumentedJit(jitted, str(label))
 
 
 # ---------------------------------------------------------------------------
 # persistent compilation cache
 # ---------------------------------------------------------------------------
 
-#: StableHLO markers of buffer donation (input->output aliasing) — the
-#: same attributes the lint tier's donation audit keys on
-_DONATION_MARKERS = ("tf.aliasing_output", "jax.buffer_donor")
-
-_donation_safe_cache = None
-
-
-def cache_donation_safe():
-    """May a DONATED program be served from a deserialized
-    persistent-cache entry on this backend?
-
-    Measured on this container (jax/jaxlib 0.4.37, CPU backend): a
-    cache-served executable with donated inputs returns a CORRECT first
-    call and progressively corrupted results from the second call on —
-    the cold/warm smoke e2e caught the warmed run silently computing
-    garbage through all 12 steps (``bench_results/
-    cache_donation_repro.py`` is the standalone cross-process repro;
-    the corruption is racy but reproduces most runs). Undonated
-    programs, and donated programs compiled fresh, are unaffected — so
-    on CPU the answer is ``False`` and the drivers dispatch undonated
-    twins (a no-op there: XLA:CPU drops donation anyway, realized
-    ``alias_bytes`` is 0). TPU is untested on this container; the
-    consolidated TPU-window script carries
-    :func:`probe_cache_donation_safety` to settle it on hardware.
-    """
-    global _donation_safe_cache
-    if _donation_safe_cache is None:
-        try:
-            _donation_safe_cache = jax.default_backend() != "cpu"
-        except Exception:
-            return False
-    return _donation_safe_cache
+#: the in-checkout cache directory used when the environment names none
+#: — a fixed path, because the path is part of the cache's key
+DEFAULT_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), "bench_results", "xla_cache")
 
 
-class cache_bypass:
-    """Context manager: compile fresh, neither reading nor writing the
-    persistent cache (``jax_enable_compilation_cache`` toggled off and
-    restored — the flag is not part of the trace context, so no
-    retraces are forced). The escape hatch donated compiles take on
-    backends where :func:`cache_donation_safe` is ``False``.
+def ensure_compilation_cache(log=None):
+    """Turn on jax's persistent compilation cache, so a restarted
+    process pays each program's XLA backend compile once per *cache
+    lifetime*, not once per process.
 
-    ``watch`` (an active :class:`compile_watch`) lets a dispatch-path
-    caller skip the latch reset on exits where nothing compiled inside
-    the block — steady-state calls of a donated program then pay only
-    the two config toggles, not a cache teardown per step."""
-
-    def __init__(self, watch=None):
-        self._watch = watch
-
-    def __enter__(self):
-        self._prev = bool(jax.config.jax_enable_compilation_cache)
-        jax.config.update("jax_enable_compilation_cache", False)
-        return self
-
-    def __exit__(self, *exc):
-        jax.config.update("jax_enable_compilation_cache", self._prev)
-        if self._prev and (self._watch is None or self._watch.compiled):
-            # jax latches cache-enablement at the first compile it
-            # inspects; if the bypassed compile was that first one, the
-            # latch froze the cache OFF for the task — clear it so
-            # later (undonated) compiles still cache
-            try:
-                from jax._src import compilation_cache as _cc
-                _cc.reset_cache()
-            except Exception:
-                pass
-
-
-def _cache_configured():
-    try:
-        return bool(jax.config.jax_compilation_cache_dir)
-    except Exception:
-        return False
-
-
-def should_bypass_cache(donated):
-    """The donated-compile cache-bypass policy, in ONE place for every
-    dispatch site (``compile_with_report``, ``InstrumentedJit``,
-    ``warmstart.WarmProgram``): a DONATED program must not have its
-    backend compile served from a persistent-cache entry on backends
-    where that corrupts repeat calls (:func:`cache_donation_safe`)."""
-    return (bool(donated) and _cache_configured()
-            and not cache_donation_safe())
-
-
-def probe_cache_donation_safety(trials=4, calls=3):
-    """Empirically probe the cached-donated-executable hazard on the
-    LIVE backend (requires :func:`ensure_compilation_cache` first):
-    compile a small donated RK-style step (populating the cache), then
-    per trial force the backend compile to re-run and be SERVED from
-    the persistent cache — ``jax.clear_caches()`` first, because a
-    fresh ``jax.jit`` wrapper alone is satisfied by jax's in-memory
-    executable caches and never touches the persistent one — and
-    compare ``calls`` repeated applications against an undonated
-    reference. Returns ``{"triggered", "trials", "mismatched_calls",
-    "cache_served_compiles", "populate_cache_served", "valid"}``;
-    ``valid`` is ``False`` when no compile was actually cache-served
-    (the hazard configuration never arose, so the verdict proves
-    nothing).
-
-    The measured CPU corruption only manifests in a process whose
-    donated compile is served from a cache populated by an EARLIER
-    process (``bench_results/cache_donation_repro.py``) — same-process
-    re-serving after ``clear_caches()`` stays clean there. So the
-    decisive probe is the one run in a fresh process against an
-    already-warm cache: ``populate_cache_served=True`` marks that
-    configuration (the TPU-window leg's warm phase), and a first-
-    process probe (``populate_cache_served=False``) only covers the
-    weaker same-process configuration. The corruption is race-like,
-    so a clean *valid* probe is evidence, not proof (hence multiple
-    trials). Side effect: ``clear_caches()`` drops every live jit
-    executable in the process — run the probe between workloads, not
-    inside one. CPU's verdict is already baked into
-    :func:`cache_donation_safe`."""
-    import numpy as np
-    import jax.numpy as jnp
-
-    a_coefs = (0.0, -0.5, -1.2, -0.7, -0.3)
-    b_coefs = (0.1, 0.3, 0.8, 0.7, 0.2)
-
-    def step(state, dt):
-        y = state
-        k = jax.tree_util.tree_map(lambda x: x * 0, state)
-        for s in range(5):
-            lap = -6.0 * y["f"]
-            for ax in (1, 2, 3):
-                lap = lap + jnp.roll(y["f"], 1, ax) \
-                    + jnp.roll(y["f"], -1, ax)
-            r = {"f": y["dfdt"], "dfdt": lap - y["f"]}
-            k = jax.tree_util.tree_map(
-                lambda kk, rr, s=s: a_coefs[s] * kk + dt * rr, k, r)
-            y = jax.tree_util.tree_map(
-                lambda yy, kk, s=s: yy + b_coefs[s] * kk, y, k)
-        return y
-
-    rng = np.random.default_rng(17)
-    host = {n: rng.standard_normal((2, 16, 16, 16)).astype(np.float32)
-            for n in ("f", "dfdt")}
-    dt = np.float32(0.01)
-
-    def fresh():
-        return {k: jax.device_put(v) for k, v in host.items()}
-
-    ref = jax.block_until_ready(jax.jit(step)(fresh(), dt))
-    ref = {k: np.asarray(v) for k, v in ref.items()}
-    # populate the cache with the donated program's entry — in a FRESH
-    # process against an already-warm cache this compile is itself
-    # cache-served, which makes that process's probe the faithful
-    # cross-process repro (see below)
-    with compile_watch("donation_probe_populate") as wp:
-        jax.block_until_ready(
-            jax.jit(step, donate_argnums=0)(fresh(), dt))
-    mismatched = 0
-    served_compiles = 0
-    for _ in range(int(trials)):
-        # drop the in-memory executables so the next dispatch re-runs
-        # the backend compile — served (deserialized) from the
-        # persistent cache, the exact configuration the hazard needs
-        jax.clear_caches()
-        served = jax.jit(step, donate_argnums=0)
-        with compile_watch("donation_probe") as w:
-            out = jax.block_until_ready(served(fresh(), dt))
-        served_compiles += w.cache_hits
-        for call in range(int(calls)):
-            if call:
-                out = jax.block_until_ready(served(fresh(), dt))
-            if not all(np.array_equal(np.asarray(out[k]), ref[k])
-                       for k in ref):
-                mismatched += 1
-    return {"triggered": mismatched > 0, "trials": int(trials),
-            "mismatched_calls": mismatched,
-            "cache_served_compiles": int(served_compiles),
-            "populate_cache_served": wp.cache_hits > 0,
-            "valid": served_compiles > 0}
-
-
-def ensure_compilation_cache(cache_dir=None, log=None):
-    """Point jax's persistent compilation cache at ``cache_dir``
-    (default: the registered ``PYSTELLA_COMPILE_CACHE_DIR``). A process
-    that re-dials a device then pays each program's XLA backend compile
-    once per *cache lifetime*, not once per process — round 3 measured
-    ~365 s of multigrid compile at 512^3 that this line amortizes away.
+    Where the cache lives is decided OUTSIDE the program: when
+    ``JAX_COMPILATION_CACHE_DIR`` is set, jax already uses that
+    directory and no directory is set in code; when it is not, the
+    cache is :data:`DEFAULT_CACHE_DIR` (``bench_results/xla_cache`` in
+    the checkout — a fixed path, never one built from a temp name, pid
+    or time).
 
     The compile-time/entry-size floors are zeroed so even fast CPU
     (smoke) compiles populate and hit the cache — the smoke cold/warm
     e2e in CI depends on that, and production TPU compiles clear any
-    floor anyway. Values ``""``/``"0"``/``"off"``/``"none"`` disable.
+    floor anyway.
 
-    Returns the absolute cache dir (``None`` when disabled). Emits one
-    ``compile_cache`` event recording the wiring.
+    Returns the cache dir jax uses. Emits one ``compile_cache`` event
+    recording the wiring.
     """
-    if cache_dir is None:
-        from pystella_tpu import config as _config
-        cache_dir = _config.getenv("PYSTELLA_COMPILE_CACHE_DIR")
-    if (cache_dir is None
-            or str(cache_dir).strip().lower() in ("", "0", "off", "none")):
-        # an explicit "off" must also UN-WIRE a cache set earlier in
-        # the process (or inherited via JAX_COMPILATION_CACHE_DIR) —
-        # returning None while the cache keeps serving would let a
-        # driver report "disabled" over live cache traffic
-        try:
-            if jax.config.jax_compilation_cache_dir:
-                jax.config.update("jax_compilation_cache_dir", None)
-                from jax._src import compilation_cache as _cc
-                _cc.reset_cache()
-        except Exception:
-            pass
-        return None
-    cache_dir = str(cache_dir)
-    if not os.path.isabs(cache_dir):
-        # a relative configured path (the registered default is
-        # "bench_results/xla_cache") anchors at the repository root,
-        # not the invocation cwd — a warmed rerun from a different
-        # directory must find the same cache, and bench.py anchors
-        # its bench_results/ the same way
-        cache_dir = os.path.join(os.path.dirname(os.path.dirname(
-            os.path.dirname(os.path.abspath(__file__)))), cache_dir)
-    cache_dir = os.path.abspath(cache_dir)
+    if os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        cache_dir = jax.config.jax_compilation_cache_dir
+        if not cache_dir:
+            raise RuntimeError(
+                "JAX_COMPILATION_CACHE_DIR is set but jax did not read "
+                "it: set it before jax is imported")
+    else:
+        cache_dir = DEFAULT_CACHE_DIR
+        jax.config.update("jax_compilation_cache_dir", cache_dir)
     os.makedirs(cache_dir, exist_ok=True)
-    jax.config.update("jax_compilation_cache_dir", cache_dir)
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
-    # jax latches "is the cache enabled for this task" at the FIRST
-    # compile; any compile before this call (package import, another
-    # test) would freeze the cache off for the whole process — reset
-    # the latch so wiring takes effect now
-    try:
-        from jax._src import compilation_cache as _cc
-        _cc.reset_cache()
-    except Exception:
-        pass
+    # jax latches "is the cache used by this task" at the FIRST compile;
+    # any compile before this call (package import, another test) would
+    # freeze the cache off for the whole process — reset the latch so
+    # the wiring takes effect now
+    from jax._src import compilation_cache as _cc
+    _cc.reset_cache()
     _install_jax_listeners()
     (log if log is not None else _events.get_log()).emit(
         "compile_cache", dir=cache_dir, enabled=True,
-        entries=len(os.listdir(cache_dir)),
-        donation_safe=cache_donation_safe())
+        entries=len(os.listdir(cache_dir)))
     return cache_dir
 
 

@@ -65,6 +65,13 @@ def registered_scopes():
     return frozenset(_SCOPE_REGISTRY)
 
 
+#: raw-op row spellings that fold into a registered scope: jax 0.9 names
+#: an HLO instruction after the PRIMITIVE that produced it, so the
+#: ``collective-permute`` ops of a halo exchange appear in a trace as
+#: ``ppermute.N`` rows
+RAW_OP_ALIASES = {"ppermute": "collective-permute"}
+
+
 for _name in (
     # generic stepper stages (rk_stage0..N fold into this at parse time)
     "rk_stage",

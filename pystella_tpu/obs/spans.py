@@ -33,7 +33,7 @@ trace scopes (:mod:`pystella_tpu.obs.scope`), so hardware profiler
 captures and service traces read through one parser
 (:func:`pystella_tpu.obs.trace.scope_durations` folds both).
 
-Stdlib-only and jax-free, like ``obs.events``: the bench orchestrator
+Stdlib-only and jax-free, like ``obs.events``: a jax-free supervisor
 and offline analysis load it by file. CLI::
 
     python -m pystella_tpu.obs.spans --events run_events.jsonl \
@@ -196,7 +196,7 @@ class SpanAssembler:
         """Load from a JSONL event log — the whole rotated family,
         oldest first, so one request's spans reassemble across
         rotation boundaries (loaded by file to stay importable in the
-        jax-free orchestrator)."""
+        jax-free supervisor)."""
         from pystella_tpu.obs import events as _events
         return cls(_events.read_events(path, include_rotated=True))
 

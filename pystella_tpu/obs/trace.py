@@ -17,7 +17,7 @@ Two halves:
   instrumented run never dies for lack of a profile.
 - the parser (:func:`find_trace_file`, :func:`parse_trace_file`,
   :func:`scope_durations`) — stdlib-only (``gzip`` + ``json``), so the
-  jax-free bench orchestrator and offline analysis scripts can digest a
+  jax-free supervisor and offline analysis scripts can digest a
   trace captured elsewhere.
 
 Matching semantics: a trace event belongs to the *longest* known scope
@@ -40,6 +40,7 @@ import os
 import re
 
 from pystella_tpu.obs import events as _events
+from pystella_tpu.obs.scope import RAW_OP_ALIASES as _ALIASES
 from pystella_tpu.obs.scope import registered_scopes as _registered
 
 __all__ = ["KNOWN_SCOPES", "capture", "find_trace_file",
@@ -55,9 +56,9 @@ __all__ = ["KNOWN_SCOPES", "capture", "find_trace_file",
 # ``halo_overlap*`` are the overlapped-halo-path phases (whole
 # overlapped update / interior-while-collectives-fly / shell
 # stitching); ``collective-permute`` matches the RAW XLA ppermute op
-# rows, which appear in device traces (TPU and the TFRT CPU backend)
-# without any named-scope path — the comm-time denominator for the
-# ledger's exposed-vs-hidden breakdown.
+# rows (spelled ``ppermute.N`` by jax 0.9 — ``scope.RAW_OP_ALIASES``),
+# which appear in device traces without any named-scope path — the
+# comm-time denominator for the ledger's exposed-vs-hidden breakdown.
 
 
 def __getattr__(name):
@@ -71,11 +72,15 @@ def _scope_matchers(scopes):
     rule: the scope name must not be preceded by an identifier char and
     must not be followed by a lowercase letter or underscore — digits
     ARE allowed after (``rk_stage0`` is an ``rk_stage`` span) but
-    ``fused_rk_stage_pair`` is not a ``fused_rk_stage`` span."""
+    ``fused_rk_stage_pair`` is not a ``fused_rk_stage`` span. A raw-op
+    alias (``scope.RAW_OP_ALIASES``) matches under the same rule and
+    counts toward the scope it stands for."""
+    names = {s: s for s in scopes}
+    names.update({a: s for a, s in _ALIASES.items() if s in names})
     out = []
-    for s in sorted(scopes, key=len, reverse=True):
-        out.append((s, re.compile(
-            r"(?<![A-Za-z0-9_])" + re.escape(s) + r"(?![a-z_])")))
+    for n in sorted(names, key=len, reverse=True):
+        out.append((names[n], re.compile(
+            r"(?<![A-Za-z0-9_])" + re.escape(n) + r"(?![a-z_])")))
     return out
 
 

@@ -90,18 +90,8 @@ class WarmProgram:
         self.fingerprint = meta.get("fingerprint")
 
     def __call__(self, *args, **kwargs):
-        # a DONATED exported program must not have its backend compile
-        # served from a deserialized persistent-cache entry on backends
-        # where that corrupts repeat calls (obs.memory.
-        # cache_donation_safe) — bypass the cache for its compile; the
-        # AOT artifact still skips all tracing either way
-        bypass = _memory.should_bypass_cache(self.meta.get("donated"))
         with _memory.compile_watch(f"warmstart.{self.label}") as w:
-            if bypass:
-                with _memory.cache_bypass(watch=w):
-                    out = self.exported.call(*args, **kwargs)
-            else:
-                out = self.exported.call(*args, **kwargs)
+            out = self.exported.call(*args, **kwargs)
         if w.compiled:
             rec = _memory.CompileRecord(
                 label=f"warmstart.{self.label}",
@@ -165,10 +155,8 @@ class WarmstartStore:
         # the exported module is the ONE lowering this save pays for —
         # an explicit .lower() for the fingerprint would re-trace the
         # whole program (minutes for the 512^3 targets this store
-        # exists for), and the export text keeps the aliasing attrs
-        # the donation-bypass policy scans for
+        # exists for)
         text = exported.mlir_module()
-        donated = any(m in text for m in _memory._DONATION_MARKERS)
         fingerprint, components = _memory.program_fingerprint(
             text=text, label=label, args=args, kwargs=kwargs)
         blob = exported.serialize()
@@ -179,7 +167,6 @@ class WarmstartStore:
         meta = {
             "label": str(label),
             "fingerprint": fingerprint,
-            "donated": donated,
             "components": components,
             "artifact": os.path.basename(artifact),
             "serialized_bytes": len(blob),
@@ -378,9 +365,6 @@ def main(argv=None):
                          "$PYSTELLA_WARMSTART_DIR)")
     pe.add_argument("--target", action="append", default=None,
                     help="target name (repeatable; default: all)")
-    pe.add_argument("--cache-dir", default=None,
-                    help="also wire the persistent compilation cache "
-                         "here, so verification populates it")
     pv = sub.add_parser("verify", help="check every artifact against "
                                        "the live versions/flags")
     pv.add_argument("--dir", default=None,
@@ -406,13 +390,13 @@ def main(argv=None):
     args = p.parse_args(argv)
 
     if args.cmd == "export":
-        # the lint CLI's platform dance: the targets want the CPU-safe
-        # 8-device mesh unless the operator explicitly dialed hardware
+        # the lint CLI's platform default: the targets want the
+        # 8-device CPU mesh unless JAX_PLATFORMS says otherwise
         from pystella_tpu.lint.__main__ import _force_platform
         _force_platform()
         from pystella_tpu.lint.targets import targets_by_name
-        if args.cache_dir:
-            _memory.ensure_compilation_cache(args.cache_dir)
+        # verification then populates the persistent compilation cache
+        _memory.ensure_compilation_cache()
         try:
             store = WarmstartStore(args.out)
         except ValueError as e:

@@ -275,7 +275,7 @@ class FusedScalarStepper(_step.Stepper):
         import jax
         self._jit_step = _obs_memory.instrument_jit(jax.jit(
             self._step_impl, donate_argnums=(0,) if donate else ()),
-            label=f"fused.{type(self).__name__}.step", donated=donate)
+            label=f"fused.{type(self).__name__}.step")
         self._jit_multi = {}  # (nsteps, seq struct) -> jitted multi_step
         self._jit_coupled = {}  # (nsteps, grid_size, mpl, pair) -> jitted
         self._es_call = None  # lazily built energy-emitting stage kernel
@@ -477,8 +477,7 @@ class FusedScalarStepper(_step.Stepper):
             import jax
             return _obs_memory.instrument_jit(
                 jax.jit(call, donate_argnums=(0, 2)),
-                label=f"fused.{type(self).__name__}.stage_call",
-                donated=True)
+                label=f"fused.{type(self).__name__}.stage_call")
 
         import jax
         from pystella_tpu.ops.pallas_stencil import (
@@ -532,8 +531,7 @@ class FusedScalarStepper(_step.Stepper):
         sharded = _obs_memory.instrument_jit(jax.jit(
             decomp.shard_map(body, in_specs, out_specs, check_vma=False),
             donate_argnums=donate),
-            label=f"fused.{type(self).__name__}.stage_call_sharded",
-            donated=bool(donate))
+            label=f"fused.{type(self).__name__}.stage_call_sharded")
 
         def call(win_arrays, scalars, extras):
             flat = ([win_arrays[n] for n in windows]
@@ -577,13 +575,15 @@ class FusedScalarStepper(_step.Stepper):
         energy, sectors.py reducers), plus ``sum(V(f))`` — the inputs of
         :func:`~pystella_tpu.models.sectors.get_rho_and_p` up to the
         ``1/(2 a**2)`` combine factors applied by the coupled driver."""
-        kin = jnp.sum(dfdt * dfdt, axis=(1, 2, 3))
-        grad = jnp.sum(-fv * lap, axis=(1, 2, 3))
+        kin = [jnp.sum(dfdt[i] * dfdt[i]) for i in range(self.F)]
+        grad = [jnp.sum(-fv[i] * lap[i]) for i in range(self.F)]
         env = {"f": fv, "a": a, "hubble": hub}
         pot = jnp.sum(jnp.broadcast_to(
             jnp.asarray(_field.evaluate(self._V, env), fv.dtype),
             fv.shape[1:]))
-        return jnp.concatenate([kin, grad, pot.reshape(1)])
+        # scalars, one per term: Mosaic cannot lay out the (F,) vector a
+        # multi-axis reduction would produce (pallas_stencil._sum_tile)
+        return kin + grad + [pot]
 
     def _dV(self, fv, a, hub):
         env = {"f": fv, "a": a, "hubble": hub}
@@ -1166,7 +1166,7 @@ class FusedScalarStepper(_step.Stepper):
                     return new, hv
             fn = _obs_memory.instrument_jit(
                 jax.jit(impl, donate_argnums=0),
-                label=f"fused.multi_step[{int(nsteps)}]", donated=True)
+                label=f"fused.multi_step[{int(nsteps)}]")
             self._jit_multi[key] = fn
         return fn
 
@@ -1298,7 +1298,11 @@ class FusedScalarStepper(_step.Stepper):
         pair with the (by now exact) ``hubfix``: one fused elementwise
         pass, the same arithmetic the next kernel would have applied."""
         state, k = carry
-        kdf = k["dfdt"] - 2 * dt * hubfix * state["dfdt"]
+        # the background scalars are float64 under x64 whatever the
+        # fields' dtype; meet the lattice arrays in THEIR dtype, as the
+        # kernels' scalar operands do
+        drag = jnp.asarray(2 * dt * hubfix, state["dfdt"].dtype)
+        kdf = k["dfdt"] - drag * state["dfdt"]
         df = state["dfdt"] + B2p * kdf
         return ({"f": state["f"], "dfdt": df}, {"f": k["f"], "dfdt": kdf})
 
@@ -1572,8 +1576,7 @@ class FusedScalarStepper(_step.Stepper):
                     return new, a2, adot2, hv
             fn = _obs_memory.instrument_jit(
                 jax.jit(impl, donate_argnums=0),
-                label=f"fused.coupled_multi_step[{int(nsteps)}]",
-                donated=True)
+                label=f"fused.coupled_multi_step[{int(nsteps)}]")
             self._jit_coupled[key] = fn
         return fn
 

@@ -41,7 +41,6 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from pystella_tpu import _compat
 from pystella_tpu import config as _config
 from pystella_tpu.obs import memory as _obs_memory
 from pystella_tpu.obs.scope import trace_scope
@@ -95,7 +94,7 @@ def _compiler_params(interpret):
     mode — TPU-specific params are meaningless there)."""
     if interpret:
         return None
-    return _compat.tpu_compiler_params(vmem_limit_bytes=vmem_limit_bytes())
+    return pltpu.CompilerParams(vmem_limit_bytes=vmem_limit_bytes())
 
 
 def sharded_halo(h, px, py):
@@ -262,6 +261,22 @@ class Taps:
         return pltpu.roll(arr, jnp.int32((self._Z - sz) % self._Z), 3)
 
 
+def _sum_tile(terms, shape, dtype):
+    """A zero 2-D tile of ``shape`` with scalar ``terms[t]`` at
+    ``[t, 0]``, composed from iota selects: Mosaic lays out neither a
+    1-D vector nor a stack of scalars (the v5e compiler rejects the
+    ``(nt,)`` result of a multi-axis reduction with "Invalid output
+    layout"), so block sums travel as scalars and meet the output ref as
+    a whole 2-D tile."""
+    row = lax.broadcasted_iota(jnp.int32, shape, 0)
+    col = lax.broadcasted_iota(jnp.int32, shape, 1)
+    tile = jnp.zeros(shape, dtype)
+    for t, term in enumerate(terms):
+        tile = jnp.where((row == t) & (col == 0),
+                         jnp.asarray(term, dtype), tile)
+    return tile
+
+
 def lap_from_taps(taps, coefs, inv_dx2):
     """Laplacian from centered-difference taps: ``coefs`` maps offset ->
     coefficient (offset 0 included), ``inv_dx2`` is ``1/dx**2`` per axis."""
@@ -418,8 +433,7 @@ class ResidentStencil:
             for n, ref in zip(self.out_defs, out_refs[:no]):
                 ref[...] = outs[n].astype(ref.dtype)
             for n, ref in zip(self.sum_defs, out_refs[no:]):
-                ref[...] = outs[n].astype(ref.dtype).reshape(
-                    self.sum_defs[n], 1)
+                ref[...] = _sum_tile(outs[n], ref.shape, ref.dtype)
 
         def whole(lead):
             shape = tuple(lead) + self.lattice_shape
@@ -490,7 +504,8 @@ class StreamingStencil:
         moves 8 rows instead of ``h`` — a few percent extra ICI bytes
         for guaranteed Mosaic-clean windows).
     :arg sum_defs: dict name -> term count: lattice-summed outputs. The
-        body returns a ``(nterms,)`` vector of block sums per name; each
+        body returns a sequence of ``nterms`` scalar block sums per name
+        (scalars, not a vector — see :func:`_sum_tile`); each
         grid program adds its partial into one ``(nt_pad8, LANE)``
         accumulator tile revisited across the (sequential) grid, and
         :meth:`__call__` finishes the reduction over y-slabs outside the
@@ -686,17 +701,12 @@ class StreamingStencil:
 
     @staticmethod
     def _accumulate_sums(ref, terms, nt, i):
-        """Add this program's ``(nt,)`` block sums into the revisited
-        ``(nt_pad8, LANE)`` accumulator tile (terms in lane 0).
-        Zero-padding via explicit concatenates — ``jnp.pad`` recurses
-        infinitely in the Pallas TPU lowering (tests/test_tpu_lowering)."""
-        ntp, lanes = ref.shape
-        tile = terms.astype(ref.dtype).reshape(nt, 1)
-        if ntp > nt:
-            tile = jnp.concatenate(
-                [tile, jnp.zeros((ntp - nt, 1), ref.dtype)], axis=0)
-        tile = jnp.concatenate(
-            [tile, jnp.zeros((ntp, lanes - 1), ref.dtype)], axis=1)
+        """Add this program's ``nt`` block sums into the revisited
+        ``(nt_pad8, LANE)`` accumulator tile (terms in lane 0)."""
+        if len(terms) != nt:
+            raise ValueError(f"body returned {len(terms)} sum terms, "
+                             f"sum_defs declares {nt}")
+        tile = _sum_tile(terms, ref.shape, ref.dtype)
 
         @pl.when(i == 0)
         def _():

@@ -33,9 +33,8 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax import lax
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
-from pystella_tpu import _compat
 from pystella_tpu.obs.scope import trace_scope
 from pystella_tpu.parallel.overlap import MIN_INTERIOR_FACTOR
 
@@ -61,12 +60,10 @@ def make_mesh(proc_shape=None, axis_names=("x", "y", "z"), devices=None):
     # Explicit axis types: required by the declarative pencil-FFT reshards
     # (jax.sharding.reshard refuses Auto axes). On a single-device mesh
     # nothing is ever resharded and explicit-sharding type tracking only
-    # gets in the way (e.g. of pallas_call), so use Auto there. Runtimes
-    # predating axis types build a plain mesh (resharding then goes
-    # through with_sharding_constraint — see pystella_tpu._compat).
+    # gets in the way (e.g. of pallas_call), so use Auto there.
+    kind = AxisType.Explicit if len(devices) > 1 else AxisType.Auto
     return Mesh(mesh_devices, axis_names[:len(proc_shape)],
-                **_compat.mesh_axis_types(len(proc_shape),
-                                          explicit=len(devices) > 1))
+                axis_types=(kind,) * len(proc_shape))
 
 
 def ensemble_mesh(proc_shape=None, ensemble_devices=None,
@@ -118,9 +115,8 @@ def ensemble_mesh(proc_shape=None, ensemble_devices=None,
     mesh_devices = np.asarray(devices[:need]).reshape(
         (ensemble_devices,) + proc_shape)
     names = (ensemble_axis,) + tuple(axis_names[:len(proc_shape)])
-    return Mesh(mesh_devices,
-                names, **_compat.mesh_axis_types(len(names),
-                                                 explicit=False))
+    return Mesh(mesh_devices, names,
+                axis_types=(AxisType.Auto,) * len(names))
 
 
 class DomainDecomposition:
@@ -399,8 +395,7 @@ class DomainDecomposition:
         ``exchange[d]`` semantically-read rows ride ``ppermute`` and the
         remaining ``halo[d] - exchange[d]`` alignment rows are LOCAL
         zeros — cutting the per-stage ICI bytes by ``halo/exchange``
-        (4x for the h=2 y halo; the 64-chip scaling model's first knob,
-        bench_results/r05_scaling_model.md) without touching the
+        (4x for the h=2 y halo) without touching the
         Mosaic-clean buffer layout. Callers must guarantee no tap reads
         beyond ``exchange[d]`` (stencil taps reach at most the radius).
 
@@ -652,22 +647,22 @@ class DomainDecomposition:
         return fn(array)
 
     def shard_map(self, fn, in_specs, out_specs, **kwargs):
-        """Thin wrapper over ``jax.shard_map`` bound to this mesh (via
-        the version shim in :mod:`pystella_tpu._compat`).
+        """Thin wrapper over ``jax.shard_map`` bound to this mesh.
         ``check_vma=False`` is needed for bodies containing ``pallas_call``
-        (whose outputs carry no varying-mesh-axes annotation). On an
-        ensemble decomposition the replication check is off by default:
-        batched member bodies run under ``vmap(spmd_axis_name=<ensemble
-        axis>)``, where member-batched operands are device-varying over
+        (jax 0.9.0: "`vma` on `jax.ShapeDtypeStruct` must not be `None`"
+        — the kernels' ``out_shape`` carries no varying-mesh-axes
+        annotation). On an ensemble decomposition the replication check
+        is off by default: batched member bodies run under
+        ``vmap(spmd_axis_name=<ensemble axis>)``, where member-batched
+        operands are device-varying over
         the ensemble axis while unbatched captures (stencil
         coefficients, scalars) are replicated — a mix the checker
         rejects even though the program is correct (each member's
         stencil reads only its own ensemble slice)."""
         if self.ensemble_axis is not None:
             kwargs.setdefault("check_vma", False)
-        return _compat.shard_map(fn, mesh=self.mesh,
-                                 in_specs=in_specs, out_specs=out_specs,
-                                 **kwargs)
+        return jax.shard_map(fn, mesh=self.mesh, in_specs=in_specs,
+                             out_specs=out_specs, **kwargs)
 
     # -- decomposition from a device set (the re-mesh path) -----------------
 

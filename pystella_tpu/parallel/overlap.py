@@ -20,9 +20,6 @@ This module is the POLICY side:
   path: per-call/constructor override > ``PYSTELLA_HALO_OVERLAP`` env
   (``1``/``0``/``auto``) > auto (on for sharded meshes, i.e. >1 rank on
   any lattice axis).
-- :func:`ensure_scheduler_flags` — sets the async-collective /
-  latency-hiding-scheduler flags the overlap needs to pay off on TPU
-  (``LIBTPU_INIT_ARGS``; must run before the backend initializes).
 - :func:`flags_fingerprint` — the scheduler-relevant flags currently in
   the environment, recorded into ``perf_report.json``'s environment
   fingerprint so two reports that differ only in scheduler flags are
@@ -46,26 +43,14 @@ from pystella_tpu import config as _config
 
 logger = logging.getLogger(__name__)
 
-__all__ = ["enabled", "env_setting", "ensure_scheduler_flags",
-           "flags_fingerprint", "SCHEDULER_FLAGS", "MIN_INTERIOR_FACTOR"]
+__all__ = ["enabled", "env_setting", "flags_fingerprint",
+           "MIN_INTERIOR_FACTOR"]
 
 #: a block must span at least ``MIN_INTERIOR_FACTOR * h`` sites along a
 #: communicated axis for the interior/shell split to leave a non-empty
 #: interior worth hiding the transfer behind (two h-deep shells + at
 #: least h interior rows); thinner blocks take the padded path.
 MIN_INTERIOR_FACTOR = 3
-
-#: flags handed to libtpu so XLA's scheduler can actually hide the
-#: ppermutes the overlapped path makes hideable: async collective
-#: permutes (the collective start/done pair the scheduler reorders
-#: around) and the latency-hiding scheduler itself. Recorded into the
-#: perf-report environment fingerprint either way — a baseline measured
-#: without them is not comparable to one measured with them.
-SCHEDULER_FLAGS = (
-    "--xla_tpu_enable_async_collective_permute=true",
-    "--xla_enable_async_all_gather=true",
-    "--xla_tpu_enable_latency_hiding_scheduler=true",
-)
 
 #: env-var name substrings that make a flag scheduler-relevant for the
 #: fingerprint (kept deliberately broad: any async-collective or
@@ -102,25 +87,6 @@ def enabled(decomp=None, override=None):
     if decomp is None:
         return False
     return any(p > 1 for p in decomp.proc_shape)
-
-
-def ensure_scheduler_flags(env=os.environ):
-    """Append :data:`SCHEDULER_FLAGS` to ``LIBTPU_INIT_ARGS`` (idempotent
-    per flag name). Only effective when called BEFORE the TPU backend
-    initializes (libtpu reads the variable once at init); harmless on
-    CPU backends, which never read it. Returns the flags added."""
-    current = env.get("LIBTPU_INIT_ARGS", "")
-    added = []
-    for flag in SCHEDULER_FLAGS:
-        name = flag.split("=", 1)[0]
-        if name not in current:
-            added.append(flag)
-    if added:
-        env["LIBTPU_INIT_ARGS"] = " ".join(
-            ([current] if current else []) + added)
-        logger.info("halo overlap: added scheduler flags to "
-                    "LIBTPU_INIT_ARGS: %s", " ".join(added))
-    return added
 
 
 def flags_fingerprint(env=os.environ):

@@ -1,15 +1,13 @@
 """Elastic runtime: retry/backoff, fault injection, and the supervisor.
 
-The production environment for this stack loses device links mid-run
-(rounds 3 and 5: 18 dial attempts over 9.5 h, all UNAVAILABLE). This
+A long run can lose a device link, a device or its host mid-run. This
 package is the recovery layer that treats that as weather, not
 catastrophe:
 
 - :mod:`pystella_tpu.resilience.retry` — budget-aware jittered
   exponential backoff with transient-vs-deterministic triage
-  (:func:`classify_exception`), promoted out of ``bench.py``'s
-  orchestrator, which now consumes it. Stdlib-only and loadable by
-  file, like ``config.py``.
+  (:func:`classify_exception`). Stdlib-only and loadable by file,
+  like ``config.py``.
 - :mod:`pystella_tpu.resilience.faults` — a deterministic
   fault-injection harness (:class:`FaultInjector`: raise-at-step /
   simulated device loss / NaN corruption / SIGTERM preemption) so

@@ -135,7 +135,7 @@ class Stepper:
         # into startup time.
         self._jit_step = _obs_memory.instrument_jit(
             jax.jit(_step_impl, donate_argnums=(0,) if donate else ()),
-            label=f"step.{type(self).__name__}", donated=donate)
+            label=f"step.{type(self).__name__}")
 
     def _ensure_stage_jits(self):
         """Per-stage executables for the reference-style driver loop
@@ -156,12 +156,12 @@ class Stepper:
             self._jit_stage = _obs_memory.instrument_jit(jax.jit(
                 self.stage, static_argnums=0,
                 donate_argnums=(1,) if donate else ()),
-                label=f"step.{cls}.stage", donated=donate)
+                label=f"step.{cls}.stage")
             self._jit_stage0 = _obs_memory.instrument_jit(jax.jit(
                 lambda state, t, dt, rhs_args:
                     self.stage(0, self.init_carry(state), t, dt, rhs_args),
                 donate_argnums=(0,) if donate else ()),
-                label=f"step.{cls}.stage0", donated=donate)
+                label=f"step.{cls}.stage0")
 
     # -- whole-step interface ---------------------------------------------
 
@@ -197,8 +197,7 @@ class Stepper:
             fn = _obs_memory.instrument_jit(
                 jax.jit(impl, donate_argnums=(
                     (0,) if getattr(self, "_donate", False) else ())),
-                label=f"step.{type(self).__name__}.health",
-                donated=getattr(self, "_donate", False))
+                label=f"step.{type(self).__name__}.health")
             cache[id(sentinel)] = fn
         return fn
 

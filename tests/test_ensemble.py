@@ -9,7 +9,7 @@ is resampled), ensemble-mesh packing on the 8-device CPU mesh
 import numpy as np
 import pytest
 
-import common  # noqa: F401  (side effect: forces the CPU platform)
+import common  # noqa: F401  (side effect: enables x64)
 
 import jax
 import jax.numpy as jnp

@@ -16,7 +16,7 @@ import time
 import numpy as np
 import pytest
 
-import common  # noqa: F401  (side effect: forces the CPU platform)
+import common  # noqa: F401  (side effect: enables x64)
 
 import pystella_tpu as ps  # noqa: F401
 from pystella_tpu import obs

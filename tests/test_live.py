@@ -17,7 +17,7 @@ import urllib.request
 import numpy as np
 import pytest
 
-import common  # noqa: F401  (side effect: forces the CPU platform)
+import common  # noqa: F401  (side effect: enables x64)
 
 import jax.numpy as jnp
 

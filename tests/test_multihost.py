@@ -17,7 +17,7 @@ import sys
 
 import pytest
 
-import common
+import common  # noqa: F401  (side effect: enables x64)
 
 WORKER = os.path.join(os.path.dirname(__file__), "multihost_worker.py")
 
@@ -28,13 +28,6 @@ def _free_port():
         return s.getsockname()[1]
 
 
-@pytest.mark.skipif(
-    common.jax_minor_version() < (0, 5),
-    reason="jax-0.4.x environmental: cross-process collectives on the "
-           "CPU backend raise \"Multiprocess computations aren't "
-           "implemented on the CPU backend\" (workers build a localhost "
-           "jax.distributed cluster over virtual CPU devices, which "
-           "0.4.x cannot execute); re-arms on jax >= 0.5")
 @pytest.mark.parametrize("nproc", [2, 3])
 def test_process_cluster(tmp_path, nproc):
     """2- and 3-process clusters (each contributing 2 devices) — the

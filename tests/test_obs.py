@@ -9,7 +9,7 @@ import os
 import numpy as np
 import pytest
 
-import common  # noqa: F401  (side effect: forces the CPU platform)
+import common  # noqa: F401  (side effect: enables x64)
 import jax
 import jax.numpy as jnp
 

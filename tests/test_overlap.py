@@ -19,7 +19,7 @@ import os
 import numpy as np
 import pytest
 
-import common  # noqa: F401  (side effect: forces the CPU platform)
+import common  # noqa: F401  (side effect: enables x64)
 
 import pystella_tpu as ps
 from pystella_tpu import obs
@@ -257,14 +257,16 @@ def test_overlap_env_gate(make_decomp, monkeypatch):
     assert not overlap_mod.enabled(single, override=False)
 
 
-def test_scheduler_flags_and_fingerprint():
-    env = {}
-    added = overlap_mod.ensure_scheduler_flags(env)
-    assert added == list(overlap_mod.SCHEDULER_FLAGS)
-    assert overlap_mod.ensure_scheduler_flags(env) == []  # idempotent
+def test_scheduler_flags_fingerprint():
+    """The package sets no libtpu flag itself (the set it used to append
+    named one libtpu 0.0.34 aborts on); what the environment carries is
+    still fingerprinted."""
+    env = {"LIBTPU_INIT_ARGS":
+           "--xla_tpu_enable_latency_hiding_scheduler=true "
+           "--xla_enable_async_all_gather=true"}
     fp = overlap_mod.flags_fingerprint(env)
     assert fp.get("xla_tpu_enable_latency_hiding_scheduler") == "true"
-    assert fp.get("xla_tpu_enable_async_collective_permute") == "true"
+    assert fp.get("xla_enable_async_all_gather") == "true"
     # the ledger's stdlib twin parses the same environment shape
     from pystella_tpu.obs import ledger
     os.environ["LIBTPU_INIT_ARGS"] = env["LIBTPU_INIT_ARGS"]

@@ -196,8 +196,8 @@ def test_streaming_sum_outputs_and_update_assembly():
                     taps(s) + taps(-s) + taps(0, s) + taps(0, -s)
                     + taps(0, 0, s) + taps(0, 0, -s))
         fv = taps()
-        sums = jnp.stack([jnp.sum(fv[i] * fv[i]) for i in range(F)]
-                         + [jnp.sum(lap[0])])
+        sums = ([jnp.sum(fv[i] * fv[i]) for i in range(F)]
+                + [jnp.sum(lap[0])])
         return {"lap": lap, "sums": sums}
 
     kw = dict(dtype=jnp.float64, bx=4, by=8, sum_defs={"sums": F + 1})
@@ -316,7 +316,8 @@ def test_resident_extras_scalars_sums():
 
     def body(taps, extras, scalars):
         v = taps() * scalars["alpha"] + extras["g"]
-        return {"out": v, "sums": jnp.sum(v * v, axis=(1, 2, 3))}
+        return {"out": v,
+                "sums": [jnp.sum(v[i] * v[i]) for i in range(F)]}
 
     st = ResidentStencil((N, N, N), F, 1, body, {"out": (F,)},
                          extra_defs={"g": (F,)}, scalar_names=("alpha",),

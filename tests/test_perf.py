@@ -17,7 +17,7 @@ import sys
 import numpy as np
 import pytest
 
-import common  # noqa: F401  (side effect: forces the CPU platform)
+import common  # noqa: F401  (side effect: enables x64)
 
 from pystella_tpu import obs
 from pystella_tpu.obs import events, gate, metrics, slo, stragglers

@@ -18,7 +18,7 @@ import sys
 import numpy as np
 import pytest
 
-import common  # noqa: F401  (side effect: forces the CPU platform)
+import common  # noqa: F401  (side effect: enables x64)
 
 import jax
 
@@ -631,14 +631,6 @@ def test_remesh_drill_dry_run(tmp_path):
 
 
 @pytest.mark.slow
-@pytest.mark.skipif(
-    common.jax_minor_version() < (0, 5),
-    reason="jax-0.4.x environmental: cross-process collectives on the "
-           "CPU backend raise \"Multiprocess computations aren't "
-           "implemented on the CPU backend\" (the drill's global mesh "
-           "spans two localhost jax.distributed workers); re-arms on "
-           "jax >= 0.5 — the dry-run above rehearses the identical "
-           "supervisor/planner path in tier-1")
 def test_remesh_drill_two_process(tmp_path):
     """The REAL >=2-process drill: two jax.distributed workers share
     one (2,2,2) mesh; the victim SIGKILLs itself mid-run; the

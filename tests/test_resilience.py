@@ -15,7 +15,7 @@ import sys
 import numpy as np
 import pytest
 
-import common  # noqa: F401  (side effect: forces the CPU platform)
+import common  # noqa: F401  (side effect: enables x64)
 
 import jax
 import jax.numpy as jnp
@@ -481,7 +481,7 @@ def test_supervisor_remesh_hook_degrades(tmp_path):
             rep = sup.run(_toy_state())
     finally:
         events.configure(None)
-    assert rep["completed"] and hook_calls == [("XlaRuntimeError", 1)]
+    assert rep["completed"] and hook_calls == [("JaxRuntimeError", 1)]
     degraded = events.read_events(log_path, kind="run_degraded")
     assert degraded and "surviving" in degraded[0]["data"]["note"]
 

@@ -14,7 +14,7 @@ import os
 
 import pytest
 
-import common  # noqa: F401  (side effect: forces the CPU platform)
+import common  # noqa: F401  (side effect: enables x64)
 
 from pystella_tpu.lint import source as lint_source
 from pystella_tpu.obs import scope as obs_scope

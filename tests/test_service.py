@@ -15,7 +15,7 @@ import sys
 import numpy as np
 import pytest
 
-import common  # noqa: F401  (side effect: forces the CPU platform)
+import common  # noqa: F401  (side effect: enables x64)
 
 import jax
 import jax.numpy as jnp

@@ -13,7 +13,7 @@ import time
 
 import pytest
 
-import common  # noqa: F401  (side effect: forces the CPU platform)
+import common  # noqa: F401  (side effect: enables x64)
 
 import pystella_tpu as ps  # noqa: F401  (package import for the service)
 from pystella_tpu import obs

@@ -153,3 +153,25 @@ def test_vector_polarization_batching(setup, grid_shape, proc_shape):
     # sanity: polarization power is contained in the full decomposition
     assert np.all(batched_dec[:, :2] >= 0)
     assert np.allclose(batched_pol, batched_dec[:, :2], rtol=1e-6)
+
+
+def test_bin_counts_exact_past_2_to_24_modes():
+    """The mode count per k-bin normalizes every spectrum. Accumulated
+    in float32 it stops counting by ones at 2**24 modes: at 512**3 the
+    sparse corner bins came out rounded and the last one empty (an inf
+    in every spectrum — found by the chip smoke, PR 21). 288**3 holds
+    2.4e7 modes, enough to show it."""
+    import jax
+    grid_shape = (288, 288, 288)
+    decomp = ps.DomainDecomposition((1, 1, 1), devices=jax.devices()[:1])
+    lattice = ps.Lattice(grid_shape, (5.0,) * 3, dtype=np.float32)
+    fft = ps.DFT(decomp, grid_shape=grid_shape, dtype=np.float32)
+    spectra = ps.PowerSpectra(decomp, fft, lattice.dk, lattice.volume)
+    # the same count from the device-side bin indices, in float64
+    exact = np.bincount(
+        np.asarray(spectra._bin_idx).ravel(),
+        weights=np.asarray(spectra._counts, np.float64).ravel(),
+        minlength=spectra.num_bins)
+    assert exact.sum() == float(np.prod(grid_shape))
+    assert np.array_equal(spectra.bin_counts, exact)
+    assert np.all(spectra.bin_counts > 0)

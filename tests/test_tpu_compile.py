@@ -1,0 +1,125 @@
+"""Compile every main-path kernel family FOR the chip, without the chip.
+
+``tests/test_tpu_lowering.py`` stops at the client-side Pallas lowering;
+what the device side of the compiler rejects — a vector layout Mosaic
+cannot realize, a scoped-VMEM or HBM overflow — used to be learned only
+on the chip. libtpu can compile against a *description* of a TPU slice
+(``jax.experimental.topologies``: a compile-only client, no device), so
+these tests take the real programs through Mosaic and XLA:TPU for a
+v5e 2x2 host: on the (2, 2, 1) mesh at the real 512**3, on one chip at
+512 x 128 x 512 (the same kernels and blockings over a quarter of the
+y-slabs; the chip smoke runs the full size). They prove a program
+compiles and fits HBM; they say nothing about what it computes or how
+fast (``chip_smoke.py`` and ``bench.py`` do, on the chip). Tier-1 runs
+the coupled chunk on the mesh — every energy-emitting kernel, with x-
+and y-halo windows; the rest is ``slow``-marked: run the whole file
+(``-m "slow or not slow"``, under a minute) after touching a kernel.
+
+Found here first (PR 21): the coupled pair kernel's ``(F,)`` energy sums
+— "Mosaic failed to compile TPU kernel: Invalid output layout" on a
+multi-axis ``vector.multi_reduction`` to a 1-D vector.
+"""
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import NamedSharding, PartitionSpec as P
+
+import pystella_tpu as ps
+
+#: (mesh, lattice) pairs — see the module docstring
+ONE_CHIP = pytest.param((1, 1, 1), (512, 128, 512),
+                        marks=pytest.mark.slow)
+MESH = ((2, 2, 1), (512, 512, 512))
+
+
+@pytest.fixture(scope="module")
+def v5e():
+    """The four devices of a compile-only v5e 2x2 topology."""
+    try:
+        from jax.experimental import topologies
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no libtpu here: nothing to test
+        pytest.skip(f"no compile-only TPU topology: {type(e).__name__}: {e}")
+    return topo.devices
+
+
+@pytest.fixture(autouse=True)
+def no_x64():
+    """The chip runs without 64-bit mode (the suite turns it on): under
+    it the kernels' grid indices trace as i64, which Mosaic refuses."""
+    with jax.enable_x64(False):
+        yield
+
+
+def compile_tpu(fn, *args, donate=()):
+    """XLA:TPU + Mosaic compile of ``fn`` for the devices ``args``'
+    shardings name. A program over the chip's HBM fails here too
+    ("Ran out of memory in memory space hbm"), so passing means it
+    fits."""
+    jax.jit(fn, donate_argnums=donate).trace(*args).lower(
+        lowering_platforms=("tpu",)).compile()
+
+
+def _preheat(devices, proc_shape, grid):
+    """The flagship model as the example builds it, abstract state."""
+    ndev = int(np.prod(proc_shape))
+    decomp = ps.DomainDecomposition(proc_shape, devices=devices[:ndev])
+    mphi, gsq = 1.20e-6, 2.5e-7
+
+    def potential(f):
+        return (mphi**2 / 2 * f[0]**2
+                + gsq / 2 * f[0]**2 * f[1]**2) / mphi**2
+
+    dx = tuple(5.0 / n for n in grid)
+    stepper = ps.FusedScalarStepper(
+        ps.ScalarSector(2, potential=potential), decomp, grid, dx, 2,
+        dtype=jnp.float32, dt=np.float32(0.1 * min(dx)), donate=True,
+        interpret=False)
+    state = {k: jax.ShapeDtypeStruct((2,) + grid, jnp.float32,
+                                     sharding=decomp.sharding(1))
+             for k in ("f", "dfdt")}
+    scalar = jax.ShapeDtypeStruct(
+        (), jnp.float32, sharding=NamedSharding(decomp.mesh, P()))
+    return stepper, state, scalar
+
+
+@pytest.mark.parametrize("proc_shape,grid", [ONE_CHIP, MESH])
+def test_coupled_chunk_compiles(v5e, proc_shape, grid):
+    """One coupled step = two deferred-drag pair kernels (normal-in and
+    deferred-in variants) + the single-stage energy kernel for the odd
+    fifth stage: every energy-emitting kernel of the main path, with the
+    in-trace Friedmann integration between them."""
+    stepper, state, scalar = _preheat(v5e, proc_shape, grid)
+    assert stepper._ensure_coupled_pair_calls() is not None
+    stepper._ensure_energy_call()
+
+    def chunk(st, a, adot):
+        return stepper._coupled_pair_impl(
+            st, t=0.0, dt=stepper.dt, a=a, adot=adot, nsteps=1,
+            grid_size=float(np.prod(grid)), mpl=1.0)
+
+    compile_tpu(chunk, state, scalar, scalar, donate=0)
+
+
+@pytest.mark.slow
+@pytest.mark.parametrize("proc_shape,grid", [ONE_CHIP, MESH])
+def test_step_and_stage_loop_compile(v5e, proc_shape, grid):
+    """``step()`` (pair kernels + the odd single stage) and the
+    stage-by-stage protocol the upstream-style host loop drives."""
+    stepper, state, _ = _preheat(v5e, proc_shape, grid)
+    args = {"a": np.float32(1.0), "hubble": np.float32(0.5)}
+    compile_tpu(lambda st: stepper._step_impl(st, 0.0, stepper.dt, args),
+                state)
+
+    def stage_loop(st):
+        carry = st
+        for s in range(stepper.num_stages):
+            carry = stepper(s, carry, 0.0, a=np.float32(1.0),
+                            hubble=np.float32(0.5))
+        return carry
+
+    compile_tpu(stage_loop, state)
