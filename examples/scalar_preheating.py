@@ -399,7 +399,8 @@ def main(argv=None):
 
     # --profile: jax.profiler capture of a mid-run step window (entered
     # once compilation has settled), parsed into per-scope durations on
-    # exit (obs.trace.capture emits the trace_summary event)
+    # exit (obs.trace.capture emits the trace_summary event, with the
+    # host-span table of the window, which is printed too)
     profiler = None
     profile_begin = None
     profile_done = p.profile is None
@@ -414,7 +415,7 @@ def main(argv=None):
                     p.profile, label="scalar_preheating", step=step_count)
                 profiler.__enter__()
                 profile_begin = step_count
-            with ps.obs.trace_scope("driver_step"):
+            with ps.obs.host_span("driver_step"):
                 if p.chunk_steps:
                     # chunked hot loop: one device dispatch per N steps
                     n = p.chunk_steps
@@ -473,7 +474,15 @@ def main(argv=None):
             if profiler is not None and not profile_done \
                     and step_count - profile_begin >= p.profile_steps:
                 jax.block_until_ready(state)
+                # the program's own host spans over the window: where
+                # it dispatched, where it waited, how many host syncs
+                # a step cost (also on the trace_summary event)
+                profiler.steps = step_count - profile_begin
                 profiler.__exit__(None, None, None)
+                spans = (profiler.summary or {}).get("host_spans")
+                if spans and decomp.rank == 0:
+                    print("\n".join(
+                        ps.obs.trace.format_host_spans(spans)))
                 profiler, profile_done = None, True
             output(step_count, t, energy, expand, state)
             # host-side model invariants ride the same health record the

@@ -216,11 +216,11 @@ class EnsembleStepper:
                 with trace_scope("sentinel"):
                     hm = sentinel.compute_members(new)
                 return new, hm
-        label = (f"ensemble.{self.via}[{self.size}x{int(nsteps)}]"
+        label = (f"ensemble.step_{self.via}[{self.size}x{int(nsteps)}]"
                  + (".health" if sentinel is not None else ""))
         fn = _obs_memory.instrument_jit(
-            jax.jit(impl, donate_argnums=(0,) if self._donate else ()),
-            label=label)
+            impl, label=label,
+            donate_argnums=(0,) if self._donate else ())
         self._jits[key] = fn
         return fn
 
@@ -285,7 +285,7 @@ class EnsembleStepper:
                     lambda ba, ma: jax.lax.dynamic_update_index_in_dim(
                         ba, ma.astype(ba.dtype), idx, 0), b, m)
             self._write_jit = _obs_memory.instrument_jit(
-                jax.jit(impl), label="ensemble.write_member")
+                impl, label="ensemble.write_member")
         member_state = jax.tree_util.tree_map(jnp.asarray, member_state)
         with trace_scope("ensemble_evict"):
             return self._write_jit(batch, jnp.asarray(index, jnp.int32),

@@ -264,9 +264,9 @@ class DFT:
         from pystella_tpu.obs import memory as _obs_memory
         fwd_label, inv_label = self._jit_labels()
         self._dft = _obs_memory.instrument_jit(
-            jax.jit(self._dft_impl), label=fwd_label)
+            self._dft_impl, label=fwd_label)
         self._idft = _obs_memory.instrument_jit(
-            jax.jit(self._idft_impl), label=inv_label)
+            self._idft_impl, label=inv_label)
 
     def shape(self, forward_output=True):
         """Global array shape (reference dft.py:124-133 reports per-rank
@@ -304,7 +304,7 @@ class DFT:
 
     def _jit_labels(self):
         """Compile-ledger labels for the forward/inverse jits."""
-        return "dft.forward", "dft.inverse"
+        return "fourier.dft_forward", "fourier.dft_inverse"
 
     # -- pencil transforms -------------------------------------------------
     #

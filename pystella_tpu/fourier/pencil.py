@@ -127,7 +127,7 @@ class PencilFFT(DFT):
                          grid_shape=grid_shape, dtype=dtype, **kwargs)
 
     def _jit_labels(self):
-        return "pencil.forward", "pencil.inverse"
+        return "fourier.pencil_forward", "fourier.pencil_inverse"
 
     # -- layout ------------------------------------------------------------
 

@@ -18,6 +18,7 @@ import jax
 import jax.numpy as jnp
 
 from pystella_tpu.fourier.projectors import tensor_index
+from pystella_tpu.obs.scope import host_span
 
 __all__ = ["PowerSpectra"]
 
@@ -99,7 +100,7 @@ class PowerSpectra:
 
         from pystella_tpu.obs import memory as _obs_memory
         jitted = _obs_memory.instrument_jit(
-            jax.jit(weights_impl), label="spectra.weights")
+            weights_impl, label="fourier.spectra_bin_weights")
         self._weights = lambda fk, k_power: jitted(
             fk, k_power, self._counts, self._kmags, self._bin_idx)
         #: one-dispatch (transform + weights + shard-local bincount)
@@ -135,7 +136,7 @@ class PowerSpectra:
 
         from pystella_tpu.obs import memory as _obs_memory
         fn = _obs_memory.instrument_jit(
-            jax.jit(impl), label=f"spectra.pencil_k{kp}")
+            impl, label=f"fourier.spectra_pencil_k{kp}")
         self._spectrum_cache[key] = fn
         return fn
 
@@ -156,8 +157,11 @@ class PowerSpectra:
         from pystella_tpu.ops.histogram import fetch_partials
         outer_shape = tuple(fx.shape[:-3])
         fn = self._spectrum_fn(outer_shape, k_power)
-        partials = fn(fx, self._counts, self._kmags, self._bin_idx)
-        h = fetch_partials(partials).astype(np.float64).sum(axis=0)
+        with host_span("spectra_dispatch"):
+            partials = fn(fx, self._counts, self._kmags, self._bin_idx)
+        with host_span("spectra_fetch"):
+            partials = fetch_partials(partials)
+        h = partials.astype(np.float64).sum(axis=0)
         hist = h.reshape(outer_shape + (self.num_bins,))
         return self.norm * (hist / self.bin_counts)
 
@@ -168,11 +172,13 @@ class PowerSpectra:
         from pystella_tpu.ops.histogram import weighted_bincount
         if isinstance(fk, np.ndarray):
             fk = self.fft.shard_k(fk)
-        b, w = self._weights(fk, k_power)
+        with host_span("spectra_dispatch"):
+            b, w = self._weights(fk, k_power)
         # k-space layout: x/y as the decomposition, half-spectrum z local
         hist = weighted_bincount(self.decomp, b, w, self.num_bins,
                                  lattice_names=tuple(
-                                     self.fft.k_sharding(0).spec))
+                                     self.fft.k_sharding(0).spec),
+                                 owner="spectra")
         return np.asarray(hist) / self.bin_counts
 
     def __call__(self, fx, queue=None, k_power=3, allocator=None):
@@ -183,12 +189,14 @@ class PowerSpectra:
         binning — is ONE fused device dispatch (see
         :meth:`spectrum_program`); the DFT tiers keep their separate
         transform/weights/bincount dispatches byte-for-byte."""
-        if isinstance(fx, np.ndarray):
-            fx = self.decomp.shard(np.asarray(fx, self.fft.dtype))
-        if self.fft.is_pencil and self.fft._nproc > 1:
-            return self._pencil_spectrum(fx, k_power)
-        fk = self.fft.dft(fx)
-        return self.norm * self.bin_power(fk, k_power=k_power)
+        with host_span("spectra"):
+            if isinstance(fx, np.ndarray):
+                fx = self.decomp.shard(np.asarray(fx, self.fft.dtype))
+            if self.fft.is_pencil and self.fft._nproc > 1:
+                return self._pencil_spectrum(fx, k_power)
+            with host_span("spectra_dispatch"):
+                fk = self.fft.dft(fx)
+            return self.norm * self.bin_power(fk, k_power=k_power)
 
     def polarization(self, vector, projector, queue=None, k_power=3,
                      allocator=None):

@@ -26,7 +26,7 @@ need not even be importable):
   registry is recovered *statically* (AST over ``config.py``), so this
   checker works on any package layout.
 - ``scope-registry`` — every literal scope name passed to
-  ``trace_scope`` / ``named_scope`` / ``traced`` must be registered in
+  ``trace_scope`` / ``named_scope`` / ``host_span`` must be registered in
   :func:`pystella_tpu.obs.scope.registered_scopes` (f-string literals
   normalize by dropping the interpolated parts, matching the trace
   parser's fold rule). This absorbs the grep that used to live in
@@ -76,7 +76,10 @@ _SYNC_JAX_FNS = ("block_until_ready", "device_get")
 _HOST_BUILTINS = ("float", "int")
 _HOST_NP_FNS = ("asarray", "array")
 
-_SCOPE_FNS = ("trace_scope", "named_scope", "traced")
+#: the first two open a traced region (host materializers inside are
+#: flagged); a ``host_span`` names run-time host work, fetches included,
+#: so it only has to be registered
+_SCOPE_FNS = ("trace_scope", "named_scope", "host_span")
 
 _HOT_MARKER = re.compile(r"#\s*lint:\s*hot-path")
 _ALLOW_PRAGMA = re.compile(r"#\s*lint:\s*allow\(([\w., -]+)\)")
@@ -206,8 +209,7 @@ class _FileChecker(ast.NodeVisitor):
         base, attr = _call_name(node)
 
         # scope-registry: literal names handed to trace_scope/named_scope/
-        # traced (the decorator's default — the function name — is not a
-        # literal and registers itself at runtime via register_scope)
+        # host_span
         if attr in _SCOPE_FNS and node.args:
             lit = _literal_str(node.args[0])
             if lit is not None:

@@ -12,6 +12,8 @@ from __future__ import annotations
 
 import numpy as np
 
+from pystella_tpu.obs.scope import host_span
+
 __all__ = ["Expansion"]
 
 
@@ -53,20 +55,21 @@ class Expansion:
     def step(self, stage, energy, pressure, dt):
         """Execute one stage of the stepper (reference expansion.py:140-157);
         updates ``a``, ``adot``, ``hubble``."""
-        state_or_carry = ({"a": self.a, "adot": self.adot}
-                          if stage == 0 else self._carry)
-        result = self.stepper(stage, state_or_carry, 0.0, dt,
-                              energy=energy, pressure=pressure)
-        if stage == self.stepper.num_stages - 1:
-            self.a = self.dtype.type(result["a"])
-            self.adot = self.dtype.type(result["adot"])
-            self._carry = None
-        else:
-            self._carry = result
-            current = self.stepper.current(result)
-            self.a = self.dtype.type(current["a"])
-            self.adot = self.dtype.type(current["adot"])
-        self.hubble = self.adot / self.a
+        with host_span("expansion_step"):
+            state_or_carry = ({"a": self.a, "adot": self.adot}
+                              if stage == 0 else self._carry)
+            result = self.stepper(stage, state_or_carry, 0.0, dt,
+                                  energy=energy, pressure=pressure)
+            if stage == self.stepper.num_stages - 1:
+                self.a = self.dtype.type(result["a"])
+                self.adot = self.dtype.type(result["adot"])
+                self._carry = None
+            else:
+                self._carry = result
+                current = self.stepper.current(result)
+                self.a = self.dtype.type(current["a"])
+                self.adot = self.dtype.type(current["adot"])
+            self.hubble = self.adot / self.a
 
     def stage_sequence(self, nsteps, energy, pressure, dt):
         """Advance ``nsteps`` full steps with FROZEN ``(energy, pressure)``,

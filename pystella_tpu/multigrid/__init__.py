@@ -173,7 +173,7 @@ class FullApproximationScheme:
                 return op.apply_local(blk, pad_fn=decomp.pad_with_halos)
 
             cached = _obs_memory.instrument_jit(
-                jax.jit(decomp.shard_map(body, spec, spec)),
+                decomp.shard_map(body, spec, spec),
                 label=f"mg.transfer.{type(op).__name__}")
             self._transfer_cache[key] = cached
         return cached

@@ -261,11 +261,9 @@ class RelaxationBase:
 
         if level.sharded:
             spec = decomp.spec(0)
-            fn = jax.jit(decomp.shard_map(body, (spec, spec, spec), spec))
-        else:
-            fn = jax.jit(body)
+            body = decomp.shard_map(body, (spec, spec, spec), spec)
         fn = _obs_memory.instrument_jit(
-            fn, label=f"mg.{kind}{tuple(level.grid_shape)}")
+            body, label=f"mg.{kind}{tuple(level.grid_shape)}")
         self._compiled[key] = fn
         return fn
 
@@ -421,8 +419,7 @@ class RelaxationBase:
             return [out[i] for i in range(len(f_list))]
 
         fn = _obs_memory.instrument_jit(
-            jax.jit(entry),
-            label=f"mg.pallas_{kind}{tuple(level.grid_shape)}")
+            entry, label=f"mg.pallas_{kind}{tuple(level.grid_shape)}")
         self._compiled[key] = fn
         return fn
 

@@ -191,7 +191,6 @@ def _run_local(op, x, decomp):
     executable. The replicated branch is jitted too: eagerly it issues
     ~a dozen sliced ops per transfer, each a separate device dispatch
     (what a dispatch costs on the chip is not measured)."""
-    import jax
     cache = getattr(op, "_jit_cache", None)
     if cache is None:
         cache = op._jit_cache = {}
@@ -206,13 +205,13 @@ def _run_local(op, x, decomp):
 
             from pystella_tpu.obs import memory as _obs_memory
             fn = cache[key] = _obs_memory.instrument_jit(
-                jax.jit(decomp.shard_map(body, spec, spec)),
+                decomp.shard_map(body, spec, spec),
                 label=f"mg.transfer.{type(op).__name__}.sharded")
         return fn(x)
     fn = cache.get("local")
     if fn is None:
         from pystella_tpu.obs import memory as _obs_memory
         fn = cache["local"] = _obs_memory.instrument_jit(
-            jax.jit(lambda a: op.apply_local(a)),
+            op.apply_local,
             label=f"mg.transfer.{type(op).__name__}.local")
     return fn(x)

@@ -14,11 +14,12 @@ One subsystem behind the pieces that grew up scattered (``utils/monitor``,
   events, ms/step EMA, site-updates/s) with a multihost-aware
   :meth:`~pystella_tpu.obs.metrics.MetricsRegistry.aggregate` so host 0
   reports fleet-wide numbers.
-- :mod:`pystella_tpu.obs.scope` — ``jax.named_scope`` +
-  ``jax.profiler.TraceAnnotation`` wrappers threaded through the hot
-  paths, so Perfetto/TensorBoard traces show semantically named regions
-  (RK stages, halo exchanges, stencil kernels, multigrid smoothers)
-  instead of raw XLA op soup.
+- :mod:`pystella_tpu.obs.scope` — names for traces: ``trace_scope``
+  (``jax.named_scope``, plus a host annotation when eager) threaded
+  through the hot paths so device rows say which layer they are (RK
+  stages, halo exchanges, stencil kernels by kind, multigrid
+  smoothers), and ``host_span`` at every dispatch and fetch of the main
+  path, recorded while ``recording()`` is active.
 - :mod:`pystella_tpu.obs.memory` — compile-time and HBM
   instrumentation: per-computation compile seconds and
   ``memory_analysis()`` byte counts recorded into the event log, plus
@@ -123,8 +124,8 @@ from pystella_tpu.obs.events import (
 from pystella_tpu.obs.metrics import (
     Counter, Gauge, MetricsRegistry, Timer, counter, gauge, registry, timer)
 from pystella_tpu.obs.scope import (
-    has_scope, lowered_scopes, register_scope, registered_scopes,
-    trace_scope, traced)
+    has_scope, host_span, lowered_scopes, recording, register_scope,
+    registered_scopes, trace_scope)
 from pystella_tpu.obs.memory import (
     CompileRecord, compile_totals,
     compile_watch, compile_with_report, device_memory_report,
@@ -152,7 +153,8 @@ __all__ = [
     "register_event_kind", "registered_event_kinds", "tracing",
     "Counter", "Gauge", "Timer", "MetricsRegistry",
     "counter", "gauge", "timer", "registry",
-    "trace_scope", "traced", "lowered_scopes", "has_scope",
+    "trace_scope", "host_span", "recording", "lowered_scopes",
+    "has_scope",
     "register_scope", "registered_scopes",
     "CompileRecord", "compile_with_report", "compile_watch",
     "compile_totals", "instrument_jit", "ensure_compilation_cache",

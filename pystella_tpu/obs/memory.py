@@ -39,6 +39,7 @@ import dataclasses
 import hashlib
 import json
 import os
+import re
 import threading
 import time
 
@@ -48,7 +49,8 @@ from pystella_tpu.obs import events as _events
 from pystella_tpu.obs import metrics as _metrics
 
 __all__ = ["CompileRecord", "compile_with_report", "compile_watch",
-           "instrument_jit", "InstrumentedJit", "compile_totals",
+           "instrument_jit", "InstrumentedJit", "program_name",
+           "compile_totals",
            "ensure_compilation_cache",
            "program_fingerprint", "signature_fingerprint",
            "runtime_versions", "device_memory_stats",
@@ -494,12 +496,35 @@ class InstrumentedJit:
         return f"InstrumentedJit({self._label!r}, {self._jitted!r})"
 
 
-def instrument_jit(jitted, label):
-    """Wrap a ``jax.jit`` object so its compiles land in the compile
-    ledger under ``label``. The package's internal jit sites (steppers,
-    fused chunks, multigrid, spectra) all route through this — the
-    compile half of cold start stops being invisible."""
-    return InstrumentedJit(jitted, str(label))
+def program_name(label):
+    """The XLA module name a program labelled ``label`` gets: the label
+    without its leading namespace (``fused.coupled_multi_step[4]`` ->
+    ``coupled_multi_step_4``, ``spectra.spectra_bin_weights`` ->
+    ``spectra_bin_weights``), every other run of non-identifier
+    characters folded to one ``_``. jax prefixes ``jit_``; a trace keys
+    each device op by this name."""
+    head, dot, tail = str(label).partition(".")
+    name = re.sub(r"\W+", "_", tail if dot and tail else head).strip("_")
+    return name or "program"
+
+
+def instrument_jit(fn, label, name=None, **jit_kwargs):
+    """``jax.jit(fn, **jit_kwargs)`` as a named, instrumented program:
+    the one place a hot-path program gets its name. ``fn`` is jitted
+    under ``name``, by default made from ``label``
+    (:func:`program_name`), so the compiled module, and with it every
+    row of a device trace, says which program it is instead of
+    ``jit__unknown`` / ``jit_wrapped`` / ``jit__lambda``; its compiles
+    land in the compile ledger under ``label``. The package's internal jit sites (steppers, fused chunks,
+    operators, reductions, multigrid, spectra) all route through this,
+    and the stencil kernels' slab calls where they are dispatched
+    eagerly (``name`` given: every slab of a kernel is one name)."""
+    def named(*args, **kwargs):
+        return fn(*args, **kwargs)
+    # jax resolves static/donated argument names through __wrapped__
+    named.__wrapped__ = fn
+    named.__name__ = named.__qualname__ = name or program_name(label)
+    return InstrumentedJit(jax.jit(named, **jit_kwargs), str(label))
 
 
 # ---------------------------------------------------------------------------

@@ -1,18 +1,27 @@
-"""Named trace scopes for hot paths, plus the central scope registry.
+"""Named trace scopes for hot paths, host spans where the program
+dispatches and waits, and the central registry of both vocabularies.
 
-One context manager, two sinks:
+Two context managers, one registry:
 
-- ``jax.named_scope`` attaches the name to every op traced inside, so
-  compiled-code profiles (Perfetto / TensorBoard traces captured with
-  :class:`pystella_tpu.trace`) show ``fused_rk_stage_pair`` /
-  ``halo_exchange`` / ``pallas_stencil`` regions instead of raw XLA op
-  names;
-- ``jax.profiler.TraceAnnotation`` marks the host-side timeline, so
-  eager driver loops (per-stage protocol, multigrid cycle orchestration)
-  show up as named spans in the same trace.
+- :func:`trace_scope` names what the DEVICE runs: ``jax.named_scope``
+  attaches the name to every op traced inside, so compiled-code
+  profiles show ``fused_rk_stage_pair`` / ``halo_exchange`` /
+  ``pallas_stencil_coupled_pair`` instead of raw XLA op names (on a TPU
+  the HLO instruction of a Pallas call takes the innermost scope's
+  name). Entered eagerly it also marks the host timeline
+  (``jax.profiler.TraceAnnotation``); entered under ``jit`` tracing it
+  does not: a host annotation there would fire once, at trace time, and
+  time Python tracing under the scope's name.
+- :func:`host_span` names what the HOST does at run time: one span at
+  every dispatch and every fetch of the main path (the table in
+  ``doc/observability.md`` "Host spans"). It is a ``TraceAnnotation``,
+  so the span lies on the profiler's clock under the device's idle gaps,
+  and, while a recorder is installed (:func:`recording`), one row
+  ``[name, parent row, start_ns, end_ns]`` kept in memory. A span never
+  syncs.
 
-Both are no-ops costing ~a microsecond when no profiler is attached and
-are platform-agnostic (the CPU test suite runs them constantly).
+Both cost about a microsecond when no profiler is attached and are
+platform-agnostic (the CPU test suite runs them constantly).
 
 The scope names survive into the lowered MLIR's debug locations, which
 is how tests verify instrumentation without capturing a real trace:
@@ -38,10 +47,12 @@ this module stays loadable by file in a jax-free supervisor, like
 from __future__ import annotations
 
 import contextlib
-import functools
 import re
+import threading
+import time
 
-__all__ = ["trace_scope", "traced", "lowered_scopes", "has_scope",
+__all__ = ["trace_scope", "host_span", "recording", "span_table",
+           "kernel_scope", "in_jax_trace", "lowered_scopes", "has_scope",
            "register_scope", "registered_scopes"]
 
 
@@ -84,8 +95,18 @@ for _name in (
     # the raw XLA ppermute op rows — device traces carry them with no
     # named-scope path; the ledger's communication-time denominator
     "collective-permute",
-    # Pallas kernel dispatch
+    # Pallas kernel dispatch: a streaming kernel of no stated kind
+    # (multigrid sweeps, bare StreamingStencil users) and the
+    # whole-lattice-resident tier; the kinds follow below
     "pallas_stencil", "pallas_resident_stencil",
+    # ...the fused steppers' kernels by kind (kernel_scope)...
+    "pallas_stencil_stage", "pallas_stencil_pair",
+    "pallas_stencil_coupled_pair", "pallas_stencil_energy",
+    "pallas_stencil_chunk",
+    # ...and FiniteDifferencer's operators
+    "pallas_stencil_lap", "pallas_stencil_grad",
+    "pallas_stencil_grad_lap", "pallas_stencil_pdx",
+    "pallas_stencil_pdy", "pallas_stencil_pdz", "pallas_stencil_div",
     # the whole-RK-chunk (temporal blocking) kernel dispatch and the
     # persistent autotuner's timed candidate probes (ops.autotune)
     "chunk_stage", "autotune_probe",
@@ -96,8 +117,16 @@ for _name in (
     "carry_quantize",
     # multigrid
     "mg_cycle", "mg_smooth", "mg_residual",
-    # driver-level spans (bench smoke / example loops)
+    # driver-level spans (bench.py's loop / the example's loop)
     "bench_step", "driver_step",
+    # host spans (host_span): where the main path dispatches and where
+    # it waits — doc/observability.md "Host spans" says which call
+    # holds each
+    "step_dispatch", "step_fetch", "lap_dispatch", "grad_dispatch",
+    "reduce_dispatch", "reduce_fetch", "statistics", "expansion_step",
+    "output_write", "sentinel_observe", "sentinel_poll",
+    "histogram", "histogram_dispatch", "histogram_fetch",
+    "spectra", "spectra_dispatch", "spectra_fetch",
     # the in-graph numerics health vector (obs.sentinel)
     "sentinel",
     # the ensemble tier (pystella_tpu.ensemble): the batched member
@@ -136,28 +165,172 @@ for _name in (
 del _name
 
 
+def kernel_scope(kind=None):
+    """The dispatch scope of a streaming stencil kernel of ``kind``:
+    ``pallas_stencil_<kind>``, or the bare ``pallas_stencil`` for
+    ``None``. The kinds are the fused steppers'
+    (``FusedScalarStepper._build_stencil``'s ``kind``) and
+    ``FiniteDifferencer``'s operators, registered above by their full
+    spelling. A TPU trace shows the scope as the HLO instruction's name
+    (``%pallas_stencil_pair.3``), so a breakdown by instruction is a
+    breakdown by kind; the shared prefix is deliberate — whatever
+    matches ``pallas_stencil`` keeps matching every kind. An unknown
+    kind is an error here, at build time, rather than a row no table
+    folds."""
+    name = "pallas_stencil" if kind is None else f"pallas_stencil_{kind}"
+    if name not in _SCOPE_REGISTRY:
+        raise ValueError(f"stencil kernel kind {kind!r}: register_scope("
+                         f"{name!r}) in obs/scope.py first")
+    return name
+
+
+#: jax's own "is no trace in progress on this thread" and its
+#: ``TraceAnnotation``, resolved on first use (this module imports no jax)
+_trace_state_clean = _TraceAnnotation = None
+
+
+def _resolve_jax():
+    global _trace_state_clean, _TraceAnnotation
+    import jax
+    _TraceAnnotation = jax.profiler.TraceAnnotation
+    try:
+        from jax._src.core import trace_state_clean
+    except ImportError:  # a jax that moved it: annotate as before
+        def trace_state_clean():
+            return True
+    _trace_state_clean = trace_state_clean
+
+
+def in_jax_trace():
+    """Is a jax trace (``jit``, ``shard_map``, ``vmap`` ...) in progress
+    on this thread?"""
+    if _trace_state_clean is None:
+        _resolve_jax()
+    return not _trace_state_clean()
+
+
 @contextlib.contextmanager
 def trace_scope(name):
-    """Name everything inside for both compiled-code traces
-    (``jax.named_scope``) and the host timeline
-    (``jax.profiler.TraceAnnotation``)."""
+    """Name everything inside for compiled-code traces
+    (``jax.named_scope``) and, when entered eagerly, for the host
+    timeline too (``jax.profiler.TraceAnnotation``). Under ``jit``
+    tracing only the named scope is entered: the host would otherwise
+    record, once, how long Python took to trace the block, and
+    ``trace_summary`` would count that as the scope's time."""
     import jax
-    with jax.named_scope(name), jax.profiler.TraceAnnotation(name):
-        yield
+    with jax.named_scope(name):
+        if in_jax_trace():
+            yield
+        else:
+            with jax.profiler.TraceAnnotation(name):
+                yield
 
 
-def traced(name=None):
-    """Decorator form of :func:`trace_scope` (defaults to the function's
-    ``__name__``)."""
-    def wrap(fn):
-        scope_name = name if name is not None else fn.__name__
+# -- host spans ------------------------------------------------------------
 
-        @functools.wraps(fn)
-        def inner(*args, **kwargs):
-            with trace_scope(scope_name):
-                return fn(*args, **kwargs)
-        return inner
-    return wrap
+#: the installed recorder's state, or ``None``: rows, the stack of open
+#: rows, and the one thread whose spans are recorded (the driver's; a
+#: span on another thread is an annotation only, so no lock is needed)
+_RECORDER = None
+
+
+class _Recorder:
+    __slots__ = ("rows", "open", "thread")
+
+    def __init__(self):
+        self.rows, self.open = [], []
+        self.thread = threading.get_ident()
+
+
+class host_span:
+    """``with host_span("reduce_fetch"): ...`` — one host-side span at
+    run time: a ``jax.profiler.TraceAnnotation`` (so it lies on the
+    profiler's clock, under the device's idle gaps) and, while
+    :func:`recording` is active, one row ``[name, parent, start_ns,
+    end_ns]`` on ``time.perf_counter_ns``; ``parent`` is the index of
+    the row that was open when this one started, or ``-1``. With no
+    recorder the added cost over the annotation is one comparison with
+    ``None``. A span never waits for the device: put one round a call
+    that already does (``np.asarray``) to time the wait, never a
+    ``block_until_ready`` of its own. Entered under ``jit`` tracing (an
+    operator called from inside someone's program) it does nothing:
+    there is no run-time host work to name."""
+
+    __slots__ = ("_name", "_ann", "_rec")
+
+    def __init__(self, name):
+        self._name = name
+
+    def __enter__(self):
+        self._rec = self._ann = None
+        if in_jax_trace():  # called inside someone's jit: no run-time span
+            return self
+        self._ann = _TraceAnnotation(self._name)
+        self._ann.__enter__()
+        rec = _RECORDER
+        if rec is not None and rec.thread == threading.get_ident():
+            rec.rows.append([self._name, rec.open[-1] if rec.open else -1,
+                             time.perf_counter_ns(), 0])
+            rec.open.append(len(rec.rows) - 1)
+            self._rec = rec
+        return self
+
+    def __exit__(self, exc_type, exc, tb):
+        rec = self._rec
+        if rec is not None:
+            rec.rows[rec.open.pop()][3] = time.perf_counter_ns()
+        if self._ann is not None:
+            self._ann.__exit__(exc_type, exc, tb)
+        return False
+
+
+@contextlib.contextmanager
+def recording():
+    """Install the host-span recorder for the block and yield its rows
+    (a list that fills as spans close; read it after the block, or hand
+    it to :func:`span_table`). One recorder at a time: a nested
+    ``recording()`` raises. Only the installing thread's spans are
+    recorded."""
+    global _RECORDER
+    if _RECORDER is not None:
+        raise RuntimeError("a host-span recorder is already installed")
+    rec = _RECORDER = _Recorder()
+    try:
+        yield rec.rows
+    finally:
+        _RECORDER = None
+
+
+def span_table(rows, steps=None):
+    """Fold recorder rows into ``{"spans": {name: {"count", "total_ms",
+    "self_ms"[, "ms_per_step"]}}, "fetches": n[, "host_syncs_per_step"]}``.
+    A span's self time is its duration minus its children's; a fetch is
+    a row whose name ends in ``_fetch`` (each is one host sync of the
+    program's own). Rows still open (``end_ns == 0``) are left out."""
+    child_ns = [0] * len(rows)
+    for name, parent, t0, t1 in rows:
+        if t1 and parent >= 0:
+            child_ns[parent] += t1 - t0
+    spans, fetches = {}, 0
+    for i, (name, parent, t0, t1) in enumerate(rows):
+        if not t1:
+            continue
+        acc = spans.setdefault(name, [0, 0, 0])
+        acc[0] += 1
+        acc[1] += t1 - t0
+        acc[2] += t1 - t0 - child_ns[i]
+        fetches += name.endswith("_fetch")
+    table = {}
+    for name, (count, total, own) in sorted(spans.items()):
+        table[name] = {"count": count, "total_ms": total / 1e6,
+                       "self_ms": own / 1e6}
+        if steps:
+            table[name]["ms_per_step"] = total / 1e6 / steps
+    out = {"spans": table, "fetches": fetches}
+    if steps:
+        out["steps"] = int(steps)
+        out["host_syncs_per_step"] = fetches / steps
+    return out
 
 
 def lowered_scopes(lowered):

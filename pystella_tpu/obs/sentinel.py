@@ -47,7 +47,9 @@ import jax
 import jax.numpy as jnp
 
 from pystella_tpu.obs import events as _events
+from pystella_tpu.obs import memory as _memory
 from pystella_tpu.obs import metrics as _metrics
+from pystella_tpu.obs.scope import host_span
 
 __all__ = ["HEALTH_SCHEMA_VERSION", "Sentinel", "SentinelMonitor",
            "SimulationDiverged"]
@@ -198,7 +200,8 @@ class Sentinel:
         """Jitted :meth:`compute` — one tiny fused dispatch, returning a
         device array (NO host sync)."""
         if self._jit is None:
-            self._jit = jax.jit(self.compute)
+            self._jit = _memory.instrument_jit(
+                self.compute, label="sentinel.health_vector")
         return self._jit(state, aux or {})
 
     def compute_members(self, states, aux=None):
@@ -344,7 +347,8 @@ class SentinelMonitor:
     def observe(self, step, state, aux=None):
         """Compute the health vector of ``state`` (one tiny jitted
         dispatch, NO host sync) and enqueue it for ``step``."""
-        with _metrics.timer(self._timer_name):
+        with host_span("sentinel_observe"), \
+                _metrics.timer(self._timer_name):
             self.push(step, self.sentinel.compute_jit(state, aux))
 
     def push(self, step, vector):
@@ -401,7 +405,8 @@ class SentinelMonitor:
         # the one host transfer — plus the checks); event-log JSONL
         # writes are I/O of the telemetry sink, not sentinel cost, and
         # stay outside it like every other event emission
-        with _metrics.timer(self._timer_name):
+        with host_span("sentinel_poll"), \
+                _metrics.timer(self._timer_name):
             decoded = self.sentinel.decode(vector)
             bad, why = self.sentinel.problems(
                 decoded, max_abs=self.max_abs,

@@ -40,11 +40,13 @@ import os
 import re
 
 from pystella_tpu.obs import events as _events
+from pystella_tpu.obs import scope as _scope
 from pystella_tpu.obs.scope import RAW_OP_ALIASES as _ALIASES
 from pystella_tpu.obs.scope import registered_scopes as _registered
 
 __all__ = ["KNOWN_SCOPES", "capture", "find_trace_file",
-           "parse_trace_file", "scope_durations", "summarize_trace"]
+           "parse_trace_file", "scope_durations", "summarize_trace",
+           "format_host_spans"]
 
 # The instrumentation vocabulary (doc/observability.md "Trace
 # scopes") is the central registry in :mod:`pystella_tpu.obs.scope`:
@@ -142,12 +144,14 @@ def scope_durations(trace_events, scopes=None):
 
 
 def summarize_trace(logdir, scopes=None, label="", step=None,
-                    log=None):
+                    log=None, host_spans=None):
     """Parse the newest trace under ``logdir`` into a per-scope duration
     table and emit it as one ``kind="trace_summary"`` run event
     (``kind="trace_missing"`` when no trace file appeared — CPU or
-    interpret-mode captures sometimes produce none). Returns the summary
-    dict, or ``None`` when there was nothing to parse."""
+    interpret-mode captures sometimes produce none). ``host_spans`` (a
+    :func:`pystella_tpu.obs.scope.span_table`) rides on the event as it
+    is. Returns the summary dict, or ``None`` when there was nothing to
+    parse."""
     sink = log if log is not None else _events.get_log()
     path = find_trace_file(logdir)
     if path is None:
@@ -156,8 +160,28 @@ def summarize_trace(logdir, scopes=None, label="", step=None,
         return None
     table = scope_durations(parse_trace_file(path), scopes)
     summary = {"trace_file": path, "label": label, "scopes": table}
+    if host_spans is not None:
+        summary["host_spans"] = host_spans
     sink.emit("trace_summary", step=step, **summary)
     return summary
+
+
+def format_host_spans(table):
+    """The lines ``--profile`` prints for a ``host_spans`` table."""
+    steps = table.get("steps")
+    head = f"host spans over {steps} steps" if steps else "host spans"
+    if "host_syncs_per_step" in table:
+        head += (f": {table['fetches']} fetches, "
+                 f"{table['host_syncs_per_step']:.3g} host syncs per step")
+    lines = [head, f"  {'span':<20}{'count':>7}{'total ms':>12}"
+                   f"{'self ms':>12}" + (f"{'ms/step':>10}" if steps else "")]
+    for name, row in sorted(table["spans"].items(),
+                            key=lambda kv: -kv[1]["total_ms"]):
+        lines.append(
+            f"  {name:<20}{row['count']:>7}{row['total_ms']:>12.3f}"
+            f"{row['self_ms']:>12.3f}"
+            + (f"{row['ms_per_step']:>10.3f}" if steps else ""))
+    return lines
 
 
 class capture:
@@ -176,25 +200,42 @@ class capture:
     inspection (``ui.perfetto.dev``); the extracted per-scope durations
     additionally land in the run-event log, where
     :class:`pystella_tpu.obs.ledger.PerfLedger` picks them up.
+
+    The capture also records the program's host spans
+    (:func:`pystella_tpu.obs.scope.recording`) for the window and puts
+    their table into the summary as ``host_spans``: per span the count,
+    total, self and (with ``steps``, the steps the window advances)
+    per-step milliseconds, and ``host_syncs_per_step``. There is one
+    recorder at a time: a capture inside someone else's ``recording()``
+    raises on entry.
     """
 
     def __init__(self, logdir, scopes=None, label="", step=None,
-                 log=None):
+                 log=None, steps=None):
         self.logdir = str(logdir)
         self.scopes = scopes
         self.label = label
         self.step = step
         self.log = log
+        self.steps = steps
         self.summary = None
+        self._recording = _scope.recording()
+        self._rows = None
 
     def __enter__(self):
         import jax
         os.makedirs(self.logdir, exist_ok=True)
-        jax.profiler.start_trace(self.logdir)
+        self._rows = self._recording.__enter__()
+        try:
+            jax.profiler.start_trace(self.logdir)
+        except BaseException:
+            self._recording.__exit__(None, None, None)
+            raise
         return self
 
     def __exit__(self, exc_type, exc, tb):
         import jax
+        self._recording.__exit__(None, None, None)
         try:
             jax.profiler.stop_trace()
         except Exception:
@@ -204,5 +245,6 @@ class capture:
         if exc_type is None:
             self.summary = summarize_trace(
                 self.logdir, self.scopes, label=self.label,
-                step=self.step, log=self.log)
+                step=self.step, log=self.log,
+                host_spans=_scope.span_table(self._rows, self.steps))
         return False

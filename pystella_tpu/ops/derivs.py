@@ -25,6 +25,9 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from pystella_tpu.obs import memory as _obs_memory
+from pystella_tpu.obs.scope import host_span
+
 logger = logging.getLogger(__name__)
 
 __all__ = [
@@ -337,7 +340,9 @@ class FiniteDifferencer:
         out_spec = self.decomp.spec(outer_axes + (1 if extra_out_axis else 0))
         if name == "grad_lap":
             out_spec = (out_spec, self.decomp.spec(outer_axes))
-        result = jax.jit(self.decomp.shard_map(fn, in_spec, out_spec))
+        result = _obs_memory.instrument_jit(
+            self.decomp.shard_map(fn, in_spec, out_spec),
+            label=f"derivs.{name}")
         self._sharded_cache[key] = result
         return result
 
@@ -425,7 +430,7 @@ class FiniteDifferencer:
         body = self._pallas_bodies(name, n_out)
         try:
             st = StreamingStencil(local_shape, {"f": n_comp}, self.h, body,
-                                  out_defs, dtype=dtype,
+                                  out_defs, dtype=dtype, kind=name,
                                   x_halo=(px > 1), y_halo=(py > 1))
         except ValueError:
             if px > 1 or py > 1:
@@ -459,14 +464,15 @@ class FiniteDifferencer:
                                              exchange=(self.h,) * 3)
                 return tuple(st(xpad).values())
 
-            import jax as _jax
             in_spec = decomp.spec(1)
             out_specs = tuple(
                 decomp.spec(len(lead)) for lead in out_defs.values())
-            fn = _jax.jit(decomp.shard_map(
-                sharded_fn, in_spec,
-                out_specs if len(out_specs) > 1 else out_specs[0],
-                check_vma=False))
+            fn = _obs_memory.instrument_jit(
+                decomp.shard_map(
+                    sharded_fn, in_spec,
+                    out_specs if len(out_specs) > 1 else out_specs[0],
+                    check_vma=False),
+                label=f"derivs.{name}")
 
             def call(x, fn=fn):
                 res = fn(x)
@@ -547,13 +553,15 @@ class FiniteDifferencer:
 
     def lap(self, f):
         """Laplacian of ``f`` (lattice axes trailing)."""
-        return self._dispatch("lap", f)
+        with host_span("lap_dispatch"):
+            return self._dispatch("lap", f)
 
     def grad(self, f):
         """Gradient; inserts a length-3 component axis before the lattice
         axes (matching the reference's ``pd`` field layout,
         /root/reference/pystella/field/__init__.py:250-258)."""
-        return self._dispatch("grad", f, extra_out_axis=True)
+        with host_span("grad_dispatch"):
+            return self._dispatch("grad", f, extra_out_axis=True)
 
     def grad_lap(self, f):
         """Fused gradient + Laplacian: one halo exchange, one pass."""

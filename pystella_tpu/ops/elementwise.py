@@ -11,9 +11,8 @@ to manage — layout and fusion are the compiler's job.
 
 from __future__ import annotations
 
-import jax
-
 from pystella_tpu import field as _field
+from pystella_tpu.obs import memory as _obs_memory
 
 __all__ = ["ElementWiseMap"]
 
@@ -54,7 +53,12 @@ class ElementWiseMap:
             return {name: self._eval(expr, env)
                     for name, expr in self.map_instructions}
 
-        self._run = jax.jit(run)
+        # the program is named after what it writes (``map_rho``), so a
+        # trace tells one map from another
+        outs = [name for name, _ in self.map_instructions]
+        self._run = _obs_memory.instrument_jit(
+            run, label="elementwise.map_" + "_".join(outs[:3])
+            + ("_etc" if len(outs) > 3 else ""))
 
     @staticmethod
     def _eval(expr, env):

@@ -120,7 +120,7 @@ class FFTStencil:
 
         from pystella_tpu.obs import memory as _obs_memory
         self._apply = _obs_memory.instrument_jit(
-            jax.jit(impl, static_argnums=2), label=f"{self.name}.apply")
+            impl, label=f"ops.{self.name}", static_argnums=2)
 
     def __call__(self, fx, repeats=1):
         """``repeats`` stencil applications through one transform

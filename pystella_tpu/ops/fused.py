@@ -47,7 +47,7 @@ from pystella_tpu import step as _step
 from pystella_tpu.obs import events as _events
 from pystella_tpu.obs import memory as _obs_memory
 from pystella_tpu.obs import metrics as _metrics
-from pystella_tpu.obs.scope import trace_scope
+from pystella_tpu.obs.scope import host_span, trace_scope
 from pystella_tpu.ops.derivs import _grad_coefs, _lap_coefs
 from pystella_tpu.ops.pallas_stencil import (
     ResidentStencil, StreamingStencil,
@@ -272,10 +272,9 @@ class FusedScalarStepper(_step.Stepper):
         # ``donate=True`` donates the input state buffers (halves the
         # eager-step peak-HBM footprint; the caller must not reuse the
         # state afterwards — see doc/performance.md "Memory").
-        import jax
-        self._jit_step = _obs_memory.instrument_jit(jax.jit(
-            self._step_impl, donate_argnums=(0,) if donate else ()),
-            label=f"fused.{type(self).__name__}.step")
+        self._jit_step = _obs_memory.instrument_jit(
+            self._step_impl, label=f"fused.{type(self).__name__}.step",
+            donate_argnums=(0,) if donate else ())
         self._jit_multi = {}  # (nsteps, seq struct) -> jitted multi_step
         self._jit_coupled = {}  # (nsteps, grid_size, mpl, pair) -> jitted
         self._es_call = None  # lazily built energy-emitting stage kernel
@@ -363,7 +362,7 @@ class FusedScalarStepper(_step.Stepper):
                 st = StreamingStencil(
                     self.local_shape, win_defs, self.h, body, out_defs,
                     bx=bx, by=by, assemble=self._assemble,
-                    win_halo=win_halo, stages=stages,
+                    win_halo=win_halo, stages=stages, kind=kind,
                     **self._halo_kw, **common)
                 self._emit_block_choice(kind, st, source)
                 return st
@@ -474,12 +473,10 @@ class FusedScalarStepper(_step.Stepper):
                 return st(arg, scalars=scalars, extras=extras)
             if not self._donate:
                 return call
-            import jax
             return _obs_memory.instrument_jit(
-                jax.jit(call, donate_argnums=(0, 2)),
-                label=f"fused.{type(self).__name__}.stage_call")
+                call, label=f"fused.{type(self).__name__}.stage_call",
+                donate_argnums=(0, 2))
 
-        import jax
         from pystella_tpu.ops.pallas_stencil import (
             OverlapStreamingStencil, sharded_halo)
         decomp = self.decomp
@@ -528,10 +525,10 @@ class FusedScalarStepper(_step.Stepper):
         donate = (tuple(range(nw))
                   + tuple(range(nw + ns, nw + ns + len(extra_names)))
                   if self._donate else ())
-        sharded = _obs_memory.instrument_jit(jax.jit(
+        sharded = _obs_memory.instrument_jit(
             decomp.shard_map(body, in_specs, out_specs, check_vma=False),
-            donate_argnums=donate),
-            label=f"fused.{type(self).__name__}.stage_call_sharded")
+            label=f"fused.{type(self).__name__}.stage_call_sharded",
+            donate_argnums=donate)
 
         def call(win_arrays, scalars, extras):
             flat = ([win_arrays[n] for n in windows]
@@ -1152,7 +1149,6 @@ class FusedScalarStepper(_step.Stepper):
         fn = self._jit_multi.get(key)
         if fn is None:
             import functools
-            import jax
             impl = functools.partial(self._multi_step_impl,
                                      nsteps=int(nsteps))
             if sentinel is not None:
@@ -1165,8 +1161,8 @@ class FusedScalarStepper(_step.Stepper):
                         hv = sentinel.compute(new)
                     return new, hv
             fn = _obs_memory.instrument_jit(
-                jax.jit(impl, donate_argnums=0),
-                label=f"fused.multi_step[{int(nsteps)}]")
+                impl, label=f"fused.multi_step[{int(nsteps)}]",
+                donate_argnums=0)
             self._jit_multi[key] = fn
         return fn
 
@@ -1228,14 +1224,16 @@ class FusedScalarStepper(_step.Stepper):
         fn = self._multi_jit(nsteps, rhs_seq, sentinel)
         _metrics.counter("steps").inc(nsteps)
         self._emit_tier("multi_step")
-        return fn(state, t=t, dt=dt, rhs_args=rhs_args or {},
-                  rhs_seq=rhs_seq or {})
+        with host_span("step_dispatch"):
+            return fn(state, t=t, dt=dt, rhs_args=rhs_args or {},
+                      rhs_seq=rhs_seq or {})
 
     def step(self, state, t=0.0, dt=None, rhs_args=None):
         dt = dt if dt is not None else self.dt
         _metrics.counter("steps").inc()
         self._emit_tier("step")
-        return self._jit_step(state, t, dt, rhs_args or {})
+        with host_span("step_dispatch"):
+            return self._jit_step(state, t, dt, rhs_args or {})
 
     # -- deferred-drag coupled pair kernels --------------------------------
     #
@@ -1555,7 +1553,6 @@ class FusedScalarStepper(_step.Stepper):
         :meth:`coupled_multi_step` for the same reason as
         :meth:`_multi_jit` — the IR audit lowers it without running."""
         import functools
-        import jax
         key = (int(nsteps), float(grid_size), float(mpl), bool(pair),
                None if sentinel is None else id(sentinel))
         fn = self._jit_coupled.get(key)
@@ -1575,8 +1572,8 @@ class FusedScalarStepper(_step.Stepper):
                                                     "adot": adot2})
                     return new, a2, adot2, hv
             fn = _obs_memory.instrument_jit(
-                jax.jit(impl, donate_argnums=0),
-                label=f"fused.coupled_multi_step[{int(nsteps)}]")
+                impl, label=f"fused.coupled_multi_step[{int(nsteps)}]",
+                donate_argnums=0)
             self._jit_coupled[key] = fn
         return fn
 
@@ -1628,12 +1625,15 @@ class FusedScalarStepper(_step.Stepper):
         self._ensure_energy_call()  # pair path's odd-tail stage uses it
         fn = self._coupled_jit(nsteps, grid_size, mpl, pair, sentinel)
         _metrics.counter("steps").inc(nsteps)
-        res = fn(state, t=t, dt=dt,
-                 a=jnp.asarray(float(expansion.a)),
-                 adot=jnp.asarray(float(expansion.adot)))
+        with host_span("step_dispatch"):
+            res = fn(state, t=t, dt=dt,
+                     a=jnp.asarray(float(expansion.a)),
+                     adot=jnp.asarray(float(expansion.adot)))
         state, a, adot = res[:3]
-        expansion.a = expansion.dtype.type(np.asarray(a))
-        expansion.adot = expansion.dtype.type(np.asarray(adot))
+        # the chunk's own host sync: the background it ends on
+        with host_span("step_fetch"):
+            expansion.a = expansion.dtype.type(np.asarray(a))
+            expansion.adot = expansion.dtype.type(np.asarray(adot))
         expansion.hubble = expansion.adot / expansion.a
         return state if sentinel is None else (state, res[3])
 

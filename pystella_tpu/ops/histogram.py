@@ -30,6 +30,8 @@ import jax.numpy as jnp
 import weakref
 
 from pystella_tpu import field as _field
+from pystella_tpu.obs import memory as _obs_memory
+from pystella_tpu.obs.scope import host_span
 from pystella_tpu.ops.reduction import Reduction
 
 __all__ = ["Histogrammer", "FieldHistogrammer", "weighted_bincount",
@@ -132,16 +134,27 @@ def bincount_core(decomp, outer_shape, num_bins, weighted,
     return fn
 
 
+#: who bins: the program's name in a trace, by the owner
+#: :func:`weighted_bincount` is told (its spans are ``<owner>_dispatch``
+#: and ``<owner>_fetch``)
+_BINCOUNT_PROGRAMS = {"histogram": "histogram_bincount",
+                      "spectra": "spectra_bin"}
+
+
 def _bincount_fn(decomp, outer_shape, num_bins, weighted,
-                 lattice_names=None):
-    """Jitted wrapper of :func:`bincount_core` (cached)."""
+                 lattice_names=None, owner="histogram"):
+    """Jitted wrapper of :func:`bincount_core` (cached), named after
+    its ``owner`` so a trace tells the histogram's binning from the
+    spectra's."""
     per_decomp = _bincount_cache.setdefault(decomp, {})
     key = ("jit", outer_shape, num_bins, weighted,
-           None if lattice_names is None else tuple(lattice_names))
+           None if lattice_names is None else tuple(lattice_names), owner)
     cached = per_decomp.get(key)
     if cached is None:
-        cached = jax.jit(bincount_core(decomp, outer_shape, num_bins,
-                                       weighted, lattice_names))
+        cached = _obs_memory.instrument_jit(
+            bincount_core(decomp, outer_shape, num_bins, weighted,
+                          lattice_names),
+            label=f"histogram.{_BINCOUNT_PROGRAMS[owner]}")
         per_decomp[key] = cached
     return cached
 
@@ -159,26 +172,30 @@ def fetch_partials(partials):
     return np.asarray(partials)
 
 
-def weighted_bincount(decomp, bins, weights, num_bins, lattice_names=None):
+def weighted_bincount(decomp, bins, weights, num_bins, lattice_names=None,
+                      owner="histogram"):
     """Distributed histogram: chunked per-device ``jnp.bincount``s with
     host-side wide-precision finalization (see module docstring). ``bins``
     (int32) has shape ``outer + lattice``; ``weights`` shares it, or is
     ``None`` for an exact integer count histogram. ``lattice_names``
     optionally overrides the assumed input layout (see
-    :func:`_bincount_fn`). Returns a **host** ``np.ndarray`` of shape
+    :func:`_bincount_fn`); ``owner`` (``"histogram"`` or ``"spectra"``)
+    names the program and the two host spans, the enqueue and the wait
+    for the partials. Returns a **host** ``np.ndarray`` of shape
     ``outer + (num_bins,)`` (float64, or int64 for counts). The shared
     primitive behind :class:`Histogrammer` and
     :class:`~pystella_tpu.PowerSpectra`."""
     outer_shape = tuple(bins.shape[:-3])
     num_bins = int(num_bins)
-    if weights is None:
-        partials = _bincount_fn(decomp, outer_shape, num_bins, False,
-                                lattice_names)(bins)
-        h = fetch_partials(partials).astype(np.int64).sum(axis=0)
-    else:
-        partials = _bincount_fn(decomp, outer_shape, num_bins, True,
-                                lattice_names)(bins, weights)
-        h = fetch_partials(partials).astype(np.float64).sum(axis=0)
+    args = (bins,) if weights is None else (bins, weights)
+    with host_span(owner + "_dispatch"):
+        partials = _bincount_fn(decomp, outer_shape, num_bins,
+                                weights is not None, lattice_names,
+                                owner)(*args)
+    with host_span(owner + "_fetch"):
+        partials = fetch_partials(partials)
+    h = partials.astype(np.int64 if weights is None
+                        else np.float64).sum(axis=0)
     return h.reshape(outer_shape + (num_bins,))
 
 
@@ -225,10 +242,16 @@ class Histogrammer:
                 out[name] = (b, jnp.broadcast_to(w, b.shape).astype(acc))
             return out
 
-        self._prepare = jax.jit(prepare)
+        self._prepare = _obs_memory.instrument_jit(
+            prepare, label="histogram.histogram_prepare")
 
     def __call__(self, allocator=None, **env):
-        prepared = self._prepare(env)
+        with host_span("histogram"):
+            return self._histograms(env)
+
+    def _histograms(self, env):
+        with host_span("histogram_dispatch"):
+            prepared = self._prepare(env)
         return {name: weighted_bincount(
                     self.decomp, b, w, self.num_bins).astype(self.dtype)
                 for name, (b, w) in prepared.items()}
@@ -277,9 +300,13 @@ class FieldHistogrammer(Histogrammer):
                 return (jnp.max(fa, axis=lat), jnp.min(fa, axis=lat),
                         jnp.max(log_absf, axis=lat),
                         jnp.min(log_absf, axis=lat))
-            fn = jax.jit(impl)
+            fn = _obs_memory.instrument_jit(
+                impl, label="histogram.histogram_bounds")
             self._jit_bounds[f.ndim] = fn
-        mx, mn, mxl, mnl = jax.device_get(fn(f))
+        with host_span("histogram_dispatch"):
+            res = fn(f)
+        with host_span("histogram_fetch"):
+            mx, mn, mxl, mnl = jax.device_get(res)
         return {"max_f": mx, "min_f": mn,
                 "max_log_f": mxl, "min_log_f": mnl}
 
@@ -315,6 +342,10 @@ class FieldHistogrammer(Histogrammer):
         bincount batches all slices through a single device dispatch
         (the reference loops components host-side, histogram.py:313-350;
         so did rounds 1-3 here)."""
+        with host_span("histogram"):
+            return self._field_histograms(f, kwargs)
+
+    def _field_histograms(self, f, kwargs):
         min_max_keys = set(self.get_min_max.reducers.keys())
         bounds_passed = min_max_keys.issubset(set(kwargs.keys()))
 
@@ -329,7 +360,7 @@ class FieldHistogrammer(Histogrammer):
         env_bounds = {k: jnp.asarray(np.reshape(v, v.shape + (1, 1, 1)))
                       for k, v in bounds.items()}
 
-        out = dict(super().__call__(f=f, **env_bounds))
+        out = self._histograms(dict(env_bounds, f=f))
         out["linear_bins"] = np.linspace(
             bounds["min_f"], bounds["max_f"], self.num_bins + 1,
             axis=-1).astype(self.dtype)

@@ -43,7 +43,8 @@ from jax.experimental.pallas import tpu as pltpu
 
 from pystella_tpu import config as _config
 from pystella_tpu.obs import memory as _obs_memory
-from pystella_tpu.obs.scope import trace_scope
+from pystella_tpu.obs.scope import (
+    in_jax_trace, kernel_scope, trace_scope)
 
 __all__ = ["StreamingStencil", "ResidentStencil", "OverlapStreamingStencil",
            "Taps", "HY", "LANE",
@@ -407,7 +408,7 @@ class ResidentStencil:
                 "budget; use the streaming kernels or the halo path")
         # compile-ledger attribution: an eagerly-dispatched resident
         # kernel's Mosaic/XLA build is a real cold-start cost
-        self._call = _obs_memory.instrument_jit(
+        self._call = _obs_memory.InstrumentedJit(
             self._build(),
             label=f"pallas.resident{tuple(self.lattice_shape)}")
 
@@ -513,15 +514,24 @@ class StreamingStencil:
         one tile writeback per kernel. This is how fused RK stages emit
         energy reductions of their input state for free (the whole state
         is already in VMEM).
+    :arg kind: what the kernel is, for traces: its y-slab calls are
+        dispatched under ``obs.scope.kernel_scope(kind)``
+        (``pallas_stencil_<kind>``), the name a TPU trace gives their
+        HLO instructions. ``None`` keeps the bare ``pallas_stencil``.
     """
 
     def __init__(self, lattice_shape, win_defs, h, body, out_defs,
                  extra_defs=None, scalar_names=(), dtype=jnp.float32,
                  bx=None, by=None, x_halo=False, y_halo=False,
                  interpret=None, sum_defs=None, dtypes=None,
-                 assemble="concat", win_halo=None, stages=1):
+                 assemble="concat", win_halo=None, stages=1, kind=None):
         if h > HY:
             raise ValueError(f"stencil radius {h} exceeds aligned halo {HY}")
+        #: what the kernel is (``"pair"``, ``"lap"`` ...; ``None``: not
+        #: said) and the scope its y-slab calls are dispatched under: the
+        #: name their HLO instructions carry in a TPU trace
+        self.kind = kind
+        self._scope = kernel_scope(kind)
         #: fused-stage count of the body (1 single, 2 pair, >=4 chunk):
         #: scales the compute-temporary share of the default-blocking
         #: VMEM model — composed stages keep extra window-sized values
@@ -602,14 +612,35 @@ class StreamingStencil:
                 f"multiple of the {LANE}-lane tile (got Z={Z}): Mosaic "
                 f"rejects windowed DMAs with unaligned lane slices; use "
                 f"the halo/roll path (or interpret mode) for this lattice")
-        self._calls = [
+        #: the y-slab calls, each under the kernel's dispatch scope:
+        #: what a caller's program traces
+        self._slabs = [self._scoped(self._build(j))
+                       for j in range(Y // self.by)]
+        # applied eagerly (on one chip FiniteDifferencer's operators
+        # are), each slab call and the join of the slabs is a program
+        # of its own: named after the kind, so a trace shows jit_lap /
+        # jit_lap_join where it showed jit_wrapped / jit_concatenate
+        name = kind or "stencil"
+        self._programs = [
             _obs_memory.instrument_jit(
-                self._build(j),
-                label=f"pallas.streaming{tuple(self.lattice_shape)}"
-                      f"[slab{j}]")
-            for j in range(Y // self.by)]
+                slab, label=f"pallas.streaming{tuple(self.lattice_shape)}"
+                            f"[slab{j}]", name=name)
+            for j, slab in enumerate(self._slabs)]
+        self._join = _obs_memory.instrument_jit(
+            self._assemble, label=f"pallas.{name}_join")
 
     # -- construction ------------------------------------------------------
+
+    def _scoped(self, call):
+        """``call`` under this kernel's dispatch scope: the name its HLO
+        instruction carries (entered inside the function, because a
+        scope round an eager dispatch does not reach the program)."""
+        scope = self._scope
+
+        def slab(*args):
+            with trace_scope(scope):
+                return call(*args)
+        return slab
 
     def _y_pieces(self, j):
         """Static (src_y0, dst_y0, n) DMA pieces for the y-window of block
@@ -875,7 +906,7 @@ class StreamingStencil:
             bx=bx, by=by, x_halo=self.x_halo, y_halo=self.y_halo,
             interpret=self.interpret, sum_defs=self.sum_defs,
             dtypes=self.dtypes, assemble=self.assemble,
-            win_halo=self.wh, stages=self.stages)
+            win_halo=self.wh, stages=self.stages, kind=self.kind)
 
     # -- invocation --------------------------------------------------------
 
@@ -897,20 +928,23 @@ class StreamingStencil:
         nlat = len(out_names)
         X, Y, Z = self.lattice_shape
         nby = Y // self.by
+        # inside someone's program the slab calls are traced as they
+        # are; dispatched eagerly they go as named programs
+        eager = not in_jax_trace()
+        calls = self._programs if eager else self._slabs
 
-        out = {}
         if self.assemble == "update" and nby > 1:
             # slab-at-a-time: each slab output is dead right after its
             # dynamic_update_slice, so XLA can reuse one slab-sized temp
             # instead of keeping all nby of them live for a concatenate
+            out = {}
             for n in out_names:
                 out[n] = jnp.zeros(
                     self.out_defs[n] + (X, Y, Z),
                     self.dtypes.get(n, self.dtype))
             sums = dict.fromkeys(self.sum_defs, 0)
-            for j, call in enumerate(self._calls):
-                with trace_scope("pallas_stencil"):
-                    res = call(*win_args, *scalar_args, *extra_args)
+            for j, call in enumerate(calls):
+                res = call(*win_args, *scalar_args, *extra_args)
                 for k, n in enumerate(out_names):
                     yax = len(self.out_defs[n]) + 1
                     out[n] = jax.lax.dynamic_update_slice_in_dim(
@@ -920,11 +954,18 @@ class StreamingStencil:
             out.update(sums)
             return out
 
-        with trace_scope("pallas_stencil"):
-            slabs = [call(*win_args, *scalar_args, *extra_args)
-                     for call in self._calls]
-        for k, n in enumerate(out_names):
-            if nby == 1:
+        slabs = [call(*win_args, *scalar_args, *extra_args)
+                 for call in calls]
+        # a single slab is its own output: no program to join it
+        return (self._join(slabs) if eager and nby > 1
+                else self._assemble(slabs))
+
+    def _assemble(self, slabs):
+        """The full-lattice outputs from the y-slabs' outputs."""
+        nlat = len(self.out_defs)
+        out = {}
+        for k, n in enumerate(self.out_defs):
+            if len(slabs) == 1:
                 out[n] = slabs[0][k]
             else:
                 yax = len(self.out_defs[n]) + 1  # y of (*lead, X, by, Z)

@@ -12,10 +12,11 @@ from __future__ import annotations
 
 import numpy as np
 
-import jax
 import jax.numpy as jnp
 
 from pystella_tpu import field as _field
+from pystella_tpu.obs import memory as _obs_memory
+from pystella_tpu.obs.scope import host_span
 
 __all__ = ["Reduction", "FieldStatistics"]
 
@@ -89,7 +90,11 @@ class Reduction:
                 out[name] = jnp.stack(vals) if len(vals) > 1 else vals[0]
             return out
 
-        self._run = jax.jit(run, static_argnums=())
+        # a sector's reducers are its energy components: that program is
+        # the run's energy reduction, and is named so in a trace
+        self._run = _obs_memory.instrument_jit(
+            run, label="reduction." + (
+                "reduce" if isinstance(input, dict) else "energy_reduce"))
 
     def __call__(self, allocator=None, **env):
         first = next((a for a in env.values() if hasattr(a, "ndim")
@@ -101,8 +106,15 @@ class Reduction:
                 f"low-rank values for {sorted(env)}; pass grid_size= at "
                 "construction or include a lattice array")
         grid_size = self.grid_size or int(np.prod(first.shape[-3:]))
-        result = self._run(env, grid_size)
-        result = {k: np.asarray(v) for k, v in result.items()}
+        return self._fetch(env, grid_size)
+
+    def _fetch(self, env, grid_size):
+        """Enqueue the reduction, then wait for its numbers: the one
+        host sync of a call, each half under its own span."""
+        with host_span("reduce_dispatch"):
+            result = self._run(env, grid_size)
+        with host_span("reduce_fetch"):
+            result = {k: np.asarray(v) for k, v in result.items()}
         if self.callback is not None:
             result = self.callback(result)
         return result
@@ -136,11 +148,12 @@ class FieldStatistics(Reduction):
                 out["abs_min"] = jnp.min(jnp.abs(f), axis=lat_axes)
             return out
 
-        self._run = jax.jit(run)
+        self._run = _obs_memory.instrument_jit(
+            run, label="reduction.field_statistics")
 
     def __call__(self, f=None, allocator=None, **kwargs):
         if f is None:
             f = kwargs.pop("f")
         grid_size = self.grid_size or int(np.prod(f.shape[-3:]))
-        result = self._run({"f": f}, grid_size)
-        return {k: np.asarray(v) for k, v in result.items()}
+        with host_span("statistics"):
+            return self._fetch({"f": f}, grid_size)

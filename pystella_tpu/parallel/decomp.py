@@ -35,6 +35,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
+from pystella_tpu.obs import memory as _obs_memory
 from pystella_tpu.obs.scope import trace_scope
 from pystella_tpu.parallel.overlap import MIN_INTERIOR_FACTOR
 
@@ -641,8 +642,9 @@ class DomainDecomposition:
             def body(x):
                 return self.pad_with_halos(x, halo)
 
-            fn = jax.jit(self.shard_map(body, in_specs=spec,
-                                        out_specs=spec))
+            fn = _obs_memory.instrument_jit(
+                self.shard_map(body, in_specs=spec, out_specs=spec),
+                label="decomp.halo_pad")
             self._share_halos_cache[(halo, outer_axes)] = fn
         return fn(array)
 

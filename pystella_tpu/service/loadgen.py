@@ -79,7 +79,8 @@ from pystella_tpu.service.queue import (
 from pystella_tpu.service.results import ResultEmitter
 from pystella_tpu.service.server import ScenarioService
 
-__all__ = ["run", "run_fleet", "run_perf", "build_preheat_model",
+__all__ = ["run", "run_fleet", "run_perf", "VirtualClock",
+           "build_preheat_model",
            "seeded_slo_monitor", "seeded_fleet_legs",
            "seeded_perf_monitor"]
 
@@ -626,9 +627,25 @@ def seeded_perf_monitor(recorder, label="perf-drill"):
                              digest_every=32, label=label)
 
 
+class VirtualClock:
+    """A clock that moves only when slept on: ``clock()`` reads it,
+    ``clock.sleep(s)`` advances it. Handed to :func:`run_perf` it makes
+    the drill's step times exactly its schedule, whatever else the
+    machine is doing."""
+
+    def __init__(self):
+        self.now = 0.0
+
+    def __call__(self):
+        return self.now
+
+    def sleep(self, seconds):
+        self.now += float(seconds)
+
+
 def run_perf(capture_dir, base_ms=5.0, slow_ms=25.0, healthy=30,
              slow=12, cooldown=20, capture_steps=4, cooldown_s=3600.0,
-             seed=0, label="perf-drill", tracer=None):
+             seed=0, label="perf-drill", tracer=None, clock=None):
     """The seeded continuous-performance drill: a sleep-in-step loop
     through a real :class:`~pystella_tpu.utils.profiling.StepTimer`
     with TWO injected sustained slowdowns, proving the whole plane in
@@ -656,7 +673,13 @@ def run_perf(capture_dir, base_ms=5.0, slow_ms=25.0, healthy=30,
     PerfLedger` report whose ``perf`` section links the capture — the
     record the gate's ``check_perf`` audit consumes. Returns the stats
     dict (also emitted as ``perf_loadgen``), ``stats["ok"]`` rolling
-    up the acceptance pins above."""
+    up the acceptance pins above.
+
+    ``clock`` (a :class:`VirtualClock`) replaces the real sleeps and the
+    StepTimer's clock: the schedule is then exact, so the detector sees
+    the injected slowdowns and nothing the host's scheduler added (the
+    tier-1 test runs it so, beside five other xdist workers; the
+    profiler capture stays real)."""
     from pystella_tpu.obs import perf as _perf
     from pystella_tpu.utils.profiling import StepTimer
 
@@ -670,8 +693,10 @@ def run_perf(capture_dir, base_ms=5.0, slow_ms=25.0, healthy=30,
         "perf_regression": {"window_samples": 1, "min_samples": 1},
     }, label=label)
     _events.get_log().subscribe(slo.handle)
+    sleep = time.sleep if clock is None else clock.sleep
     timer = StepTimer(report_every=1e9, emit_steps=True,
-                      signature="drill", perf=monitor)
+                      signature="drill", perf=monitor,
+                      clock=time.perf_counter if clock is None else clock)
     # the schedule: healthy/slow/healthy/slow/healthy, the jitter
     # seeded so the healthy phases are not a constant series (the
     # detector must stay quiet on realistic noise, not on zeros)
@@ -681,7 +706,7 @@ def run_perf(capture_dir, base_ms=5.0, slow_ms=25.0, healthy=30,
     try:
         timer.tick()                      # arms the inter-step clock
         for ms in plan:
-            time.sleep((ms + float(rng.uniform(0.0, 0.2))) * 1e-3)
+            sleep((ms + float(rng.uniform(0.0, 0.2))) * 1e-3)
             timer.tick()
         recorder.flush()                  # close a still-open capture
         slo.evaluate()

@@ -24,7 +24,7 @@ import jax.numpy as jnp
 
 from pystella_tpu import field as _field
 from pystella_tpu.obs import memory as _obs_memory
-from pystella_tpu.obs.scope import trace_scope
+from pystella_tpu.obs.scope import host_span, trace_scope
 
 __all__ = [
     "Stepper", "RungeKuttaStepper", "LowStorageRKStepper", "compile_rhs_dict",
@@ -134,8 +134,8 @@ class Stepper:
         # ledger (obs.memory) under a stable label instead of vanishing
         # into startup time.
         self._jit_step = _obs_memory.instrument_jit(
-            jax.jit(_step_impl, donate_argnums=(0,) if donate else ()),
-            label=f"step.{type(self).__name__}")
+            _step_impl, label=f"step.{type(self).__name__}",
+            donate_argnums=(0,) if donate else ())
 
     def _ensure_stage_jits(self):
         """Per-stage executables for the reference-style driver loop
@@ -153,15 +153,14 @@ class Stepper:
         if not hasattr(self, "_jit_stage"):
             donate = getattr(self, "_donate", False)
             cls = type(self).__name__
-            self._jit_stage = _obs_memory.instrument_jit(jax.jit(
-                self.stage, static_argnums=0,
-                donate_argnums=(1,) if donate else ()),
-                label=f"step.{cls}.stage")
-            self._jit_stage0 = _obs_memory.instrument_jit(jax.jit(
+            self._jit_stage = _obs_memory.instrument_jit(
+                self.stage, label=f"step.{cls}.stage", static_argnums=0,
+                donate_argnums=(1,) if donate else ())
+            self._jit_stage0 = _obs_memory.instrument_jit(
                 lambda state, t, dt, rhs_args:
                     self.stage(0, self.init_carry(state), t, dt, rhs_args),
-                donate_argnums=(0,) if donate else ()),
-                label=f"step.{cls}.stage0")
+                label=f"step.{cls}.stage0",
+                donate_argnums=(0,) if donate else ())
 
     # -- whole-step interface ---------------------------------------------
 
@@ -179,7 +178,8 @@ class Stepper:
             from pystella_tpu.obs import events as _events
             _events.emit("kernel_tier", entrypoint="step", tier="xla",
                          label=type(self).__name__)
-        return self._jit_step(state, t, dt, rhs_args or {})
+        with host_span("step_dispatch"):
+            return self._jit_step(state, t, dt, rhs_args or {})
 
     def _health_jit(self, sentinel):
         """The cached jitted step+health executable for ``sentinel``
@@ -195,9 +195,9 @@ class Stepper:
                     hv = sentinel.compute(new, aux)
                 return new, hv
             fn = _obs_memory.instrument_jit(
-                jax.jit(impl, donate_argnums=(
-                    (0,) if getattr(self, "_donate", False) else ())),
-                label=f"step.{type(self).__name__}.health")
+                impl, label=f"step.{type(self).__name__}.health",
+                donate_argnums=(
+                    (0,) if getattr(self, "_donate", False) else ()))
             cache[id(sentinel)] = fn
         return fn
 
@@ -258,11 +258,13 @@ class Stepper:
                         jax.tree_util.tree_leaves(state_or_carry))
         if on_device:
             self._ensure_stage_jits()
-            if stage == 0:
-                carry = self._jit_stage0(state_or_carry, t, dt, rhs_args)
-            else:
-                carry = self._jit_stage(stage, state_or_carry, t, dt,
-                                        rhs_args)
+            with host_span("step_dispatch"):
+                if stage == 0:
+                    carry = self._jit_stage0(state_or_carry, t, dt,
+                                             rhs_args)
+                else:
+                    carry = self._jit_stage(stage, state_or_carry, t, dt,
+                                            rhs_args)
         else:
             carry = (self.init_carry(state_or_carry) if stage == 0
                      else state_or_carry)

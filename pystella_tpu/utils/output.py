@@ -23,6 +23,8 @@ import sys
 
 import numpy as np
 
+from pystella_tpu.obs.scope import host_span
+
 __all__ = ["OutputFile", "ShardedSnapshot"]
 
 
@@ -105,20 +107,22 @@ class OutputFile:
     def output(self, group, **kwargs):
         """Append one record per keyword to (lazily-created) resizable
         datasets under ``group`` (reference output.py:157-181)."""
-        if group not in self.file:
-            grp = self.file.create_group(group)
-        else:
-            grp = self.file[group]
+        with host_span("output_write"):
+            if group not in self.file:
+                grp = self.file.create_group(group)
+            else:
+                grp = self.file[group]
 
-        for key, val in kwargs.items():
-            arr = np.asarray(val)
-            if key not in grp:
-                grp.create_dataset(key, shape=(0,) + arr.shape,
-                                   maxshape=(None,) + arr.shape,
-                                   dtype=arr.dtype)
-            dset = grp[key]
-            dset.resize(dset.shape[0] + 1, axis=0)
-            dset[-1] = arr
+            for key, val in kwargs.items():
+                # a device value waits here: the span holds that too
+                arr = np.asarray(val)
+                if key not in grp:
+                    grp.create_dataset(key, shape=(0,) + arr.shape,
+                                       maxshape=(None,) + arr.shape,
+                                       dtype=arr.dtype)
+                dset = grp[key]
+                dset.resize(dset.shape[0] + 1, axis=0)
+                dset[-1] = arr
 
     def close(self):
         if self.file:  # h5py File is falsy once closed; idempotent

@@ -123,10 +123,15 @@ class StepTimer:
         ``PYSTELLA_PERF`` is on; ``False`` disables the feed; a
         :class:`~pystella_tpu.obs.perf.PerfMonitor` instance is used
         directly (drills).
+    :arg clock: the monotonic seconds source ticks are timed on
+        (default ``time.perf_counter``; a drill injects one that only
+        moves when it says so).
     """
 
     def __init__(self, report_every=30.0, emit_steps=False,
-                 sample_capacity=4096, signature="step", perf=None):
+                 sample_capacity=4096, signature="step", perf=None,
+                 clock=time.perf_counter):
+        self.clock = clock
         self.report_every = float(report_every)
         self.emit_steps = bool(emit_steps)
         self.signature = str(signature)
@@ -150,7 +155,7 @@ class StepTimer:
 
     def tick(self):
         self.steps += 1
-        now = time.perf_counter()
+        now = self.clock()
         if self.last_tick is None:
             self.last_tick = now
             self.last_report = now

@@ -1,0 +1,464 @@
+"""Names that reach the trace, and spans where the program waits.
+
+Device side: every streaming kernel is dispatched under the scope of its
+kind (``pallas_stencil_<kind>``), and every program ``instrument_jit``
+builds is named after its label, so a breakdown by ``<XLA module>/<HLO
+instruction>`` is a breakdown by layer. Host side: ``obs.host_span`` at
+every dispatch and every fetch of the main path, recorded only while
+``obs.recording()`` is active, never adding a sync. (The compiled-HLO
+half of the kernel names is in ``tests/test_tpu_compile.py``.)
+"""
+
+import numpy as np
+import pytest
+
+import common  # noqa: F401  (side effect: enables x64)
+
+import jax
+import jax.numpy as jnp
+
+import pystella_tpu as ps
+from pystella_tpu import obs
+from pystella_tpu.obs import events, scope
+from pystella_tpu.obs.memory import InstrumentedJit, program_name
+
+
+def _potential(f):
+    return 0.5 * f[0]**2 + 0.5 * f[0]**2 * f[1]**2
+
+
+def _preheat(decomp, n=32, dtype=np.float32):
+    """The flagship model at ``n**3``: stepper, operators, reductions
+    and a seeded state, as ``benchmark/system.py`` builds them."""
+    grid = (n, n, n)
+    lattice = ps.Lattice(grid, (5.0,) * 3, dtype=dtype)
+    sector = ps.ScalarSector(2, potential=_potential)
+    grid_size = float(np.prod(grid))
+    stepper = ps.FusedScalarStepper(
+        sector, decomp, grid, lattice.dx, 2, dtype=dtype,
+        dt=dtype(0.1 * min(lattice.dx)), donate=True)
+    derivs = ps.FiniteDifferencer(decomp, 2, lattice.dx)
+    reduce_energy = ps.Reduction(decomp, sector, callback=ps.get_rho_and_p,
+                                 grid_size=grid_size)
+    stats = ps.FieldStatistics(decomp, grid_size=grid_size)
+    rng = np.random.default_rng(5)
+    state = {
+        "f": decomp.shard((0.1 + 0.01 * rng.standard_normal(
+            (2,) + grid)).astype(dtype)),
+        "dfdt": decomp.shard((0.01 * rng.standard_normal(
+            (2,) + grid)).astype(dtype))}
+
+    def energy_of(st, a):
+        return reduce_energy(f=st["f"], dfdt=st["dfdt"],
+                             lap_f=derivs.lap(st["f"]), a=np.float64(a))
+
+    return stepper, derivs, stats, state, energy_of, grid_size
+
+
+@pytest.fixture
+def event_log(tmp_path):
+    """The process-default event log pointed at a temp file."""
+    path = tmp_path / "events.jsonl"
+    events.configure(str(path))
+    yield str(path)
+    events.configure(None)
+
+
+# -- the recorder ----------------------------------------------------------
+
+def test_recorder_off_means_no_rows_and_no_sync(make_decomp, monkeypatch):
+    """With no recorder installed a span keeps nothing, and no span ever
+    waits for the device: the coupled chunk, the energy and the
+    statistics run without a single ``block_until_ready``."""
+    stepper, derivs, stats, state, energy_of, grid_size = _preheat(
+        make_decomp((1, 1, 1)), n=16)
+    energy = energy_of(state, 1.0)
+    expand = ps.Expansion(energy["total"], ps.LowStorageRK54)
+    # compile first: the spy below is for the steady state
+    state = stepper.coupled_multi_step(state, 2, expand, 0.0,
+                                       grid_size=grid_size)
+    calls = []
+    monkeypatch.setattr(jax, "block_until_ready",
+                        lambda x: calls.append("jax") or x)
+    monkeypatch.setattr(type(state["f"]), "block_until_ready",
+                        lambda self: calls.append("array") or self)
+    assert scope._RECORDER is None
+    state = stepper.coupled_multi_step(state, 2, expand, 0.0,
+                                       grid_size=grid_size)
+    energy_of(state, expand.a)
+    stats(state["f"])
+    assert calls == []
+    assert scope._RECORDER is None
+    # and a recorder that was never installed has nothing to show
+    with obs.recording() as rows:
+        pass
+    assert rows == []
+
+
+def test_recording_is_exclusive_and_threads_do_not_mix():
+    import threading
+    with obs.recording() as rows:
+        with pytest.raises(RuntimeError, match="already installed"):
+            with obs.recording():
+                pass
+        t = threading.Thread(
+            target=lambda: obs.host_span("other_thread").__enter__())
+        t.start()
+        t.join()
+        with obs.host_span("mine"):
+            pass
+    assert [r[0] for r in rows] == ["mine"]
+    with obs.recording() as again:  # the first one left cleanly
+        pass
+    assert again == []
+
+
+def test_span_table_self_times_and_fetch_count():
+    ms = 1_000_000
+    rows = [["root", -1, 0, 100 * ms],
+            ["a_dispatch", 0, 10 * ms, 20 * ms],
+            ["a_fetch", 0, 20 * ms, 70 * ms],
+            ["inner_fetch", 2, 30 * ms, 40 * ms],
+            ["open_fetch", 0, 80 * ms, 0]]      # never closed: left out
+    table = scope.span_table(rows, steps=4)
+    spans = table["spans"]
+    assert spans["root"]["self_ms"] == pytest.approx(40.0)
+    assert spans["a_fetch"]["total_ms"] == pytest.approx(50.0)
+    assert spans["a_fetch"]["self_ms"] == pytest.approx(40.0)
+    assert spans["a_fetch"]["ms_per_step"] == pytest.approx(12.5)
+    assert "open_fetch" not in spans
+    assert table["fetches"] == 2
+    assert table["host_syncs_per_step"] == pytest.approx(0.5)
+    assert sum(r["self_ms"] for r in spans.values()) == pytest.approx(
+        spans["root"]["total_ms"])
+    assert "host_syncs_per_step" not in scope.span_table(rows)
+
+
+def test_coupled_chunks_span_tree(make_decomp, tmp_path):
+    """Two coupled chunks at 32**3 as the example and the benchmark's
+    driver run them: the span tree of doc/observability.md "Host
+    spans", three fetches per chunk (the chunk's background, the
+    energy, the statistics), every child inside its parent."""
+    stepper, derivs, stats, state, energy_of, grid_size = _preheat(
+        make_decomp((1, 1, 1)))
+    out = ps.OutputFile(name=str(tmp_path / "spans"), runfile=__file__)
+    energy = energy_of(state, 1.0)
+    expand = ps.Expansion(energy["total"], ps.LowStorageRK54)
+    monitor = ps.HealthMonitor(every=1)
+
+    def chunk(state, step):
+        with obs.host_span("driver_step"):
+            state = stepper.coupled_multi_step(state, 2, expand, 0.0,
+                                               grid_size=grid_size)
+            energy = energy_of(state, expand.a)
+            f_stats = stats(state["f"])
+            out.output("statistics/f", a=expand.a, **f_stats)
+            monitor.observe(step, state)
+            monitor.poll()
+        return state, energy
+
+    state, _ = chunk(state, 0)       # compiles, unrecorded
+    with obs.recording() as rows:
+        for i in (1, 2):
+            state, _ = chunk(state, i)
+    out.close()
+
+    names = [r[0] for r in rows]
+    roots = [i for i, r in enumerate(rows) if r[1] == -1]
+    assert [names[i] for i in roots] == ["driver_step", "driver_step"]
+    for lo, hi in zip(roots, roots[1:] + [len(rows)]):
+        sub = rows[lo:hi]
+        tree = [(r[0], rows[r[1]][0] if r[1] >= 0 else None) for r in sub]
+        assert tree == [
+            ("driver_step", None),
+            ("step_dispatch", "driver_step"),
+            ("step_fetch", "driver_step"),
+            ("lap_dispatch", "driver_step"),
+            ("reduce_dispatch", "driver_step"),
+            ("reduce_fetch", "driver_step"),
+            ("statistics", "driver_step"),
+            ("reduce_dispatch", "statistics"),
+            ("reduce_fetch", "statistics"),
+            ("output_write", "driver_step"),
+            ("sentinel_observe", "driver_step"),
+            ("sentinel_poll", "driver_step"),
+        ], tree
+        assert sum(n.endswith("_fetch") for n, _ in tree) == 3
+    for name, parent, t0, t1 in rows:
+        assert t1 >= t0 > 0
+        if parent >= 0:
+            assert rows[parent][2] <= t0 and t1 <= rows[parent][3]
+    table = scope.span_table(rows, steps=4)
+    assert table["fetches"] == 6
+    assert table["host_syncs_per_step"] == pytest.approx(1.5)
+    spans = table["spans"]
+    assert sum(r["self_ms"] for r in spans.values()) == pytest.approx(
+        spans["driver_step"]["total_ms"])
+    assert spans["statistics"]["self_ms"] < spans["statistics"]["total_ms"]
+    # every name the program emitted is registered, so trace tables
+    # fold it
+    assert set(names) <= scope.registered_scopes()
+
+
+def test_stage_loop_and_output_spans(make_decomp):
+    """The other spans of the table: the per-stage protocol
+    (``step_dispatch`` + ``expansion_step``), the gradient, the
+    histogram and the spectra, each with its dispatch and fetch."""
+    decomp = make_decomp((1, 1, 1))
+    stepper, derivs, stats, state, energy_of, grid_size = _preheat(
+        decomp, n=16)
+    lattice = ps.Lattice((16,) * 3, (5.0,) * 3, dtype=np.float32)
+    fft = ps.DFT(decomp, grid_shape=(16,) * 3, dtype=np.float32)
+    spectra = ps.PowerSpectra(decomp, fft, lattice.dk, lattice.volume)
+    hist = ps.FieldHistogrammer(decomp, 16, np.float32)
+    energy = energy_of(state, 1.0)
+    expand = ps.Expansion(energy["total"], ps.LowStorageRK54)
+    with obs.recording() as rows:
+        carry = stepper(0, state, 0.0, a=np.float64(expand.a),
+                        hubble=np.float64(expand.hubble))
+        expand.step(0, energy["total"], energy["pressure"], stepper.dt)
+        f = stepper.current(carry)["f"]
+        derivs.grad(f)
+        hist(f)
+        spectra(f)
+    tree = [(r[0], rows[r[1]][0] if r[1] >= 0 else None) for r in rows]
+    assert tree[:3] == [("step_dispatch", None), ("expansion_step", None),
+                        ("grad_dispatch", None)]
+    assert ("histogram", None) in tree and ("spectra", None) in tree
+    for owner in ("histogram", "spectra"):
+        kids = [n for n, p in tree if p == owner]
+        assert set(kids) == {owner + "_dispatch", owner + "_fetch"}, kids
+        assert kids[-1] == owner + "_fetch"
+    # the histogram waits three times (bounds, linear, log), a spectrum
+    # once
+    assert sum(n == "histogram_fetch" for n, _ in tree) == 3
+    assert sum(n == "spectra_fetch" for n, _ in tree) == 1
+    assert {n for n, _ in tree} <= scope.registered_scopes()
+
+
+# -- capture ---------------------------------------------------------------
+
+def _host_annotations(logdir):
+    """Names of the complete-span events of the captured Perfetto trace
+    that lie on host threads."""
+    path = obs.trace.find_trace_file(logdir)
+    assert path is not None
+    return [ev["name"] for ev in obs.trace.parse_trace_file(path)
+            if ev.get("ph") == "X" and isinstance(ev.get("name"), str)]
+
+
+def test_capture_puts_host_spans_into_trace_summary(make_decomp, tmp_path,
+                                                    event_log):
+    stepper, derivs, stats, state, energy_of, grid_size = _preheat(
+        make_decomp((1, 1, 1)), n=16)
+    energy = energy_of(state, 1.0)
+    expand = ps.Expansion(energy["total"], ps.LowStorageRK54)
+    state = stepper.coupled_multi_step(state, 2, expand, 0.0,
+                                       grid_size=grid_size)
+    logdir = str(tmp_path / "trace")
+    with obs.trace.capture(logdir, label="spans", steps=2) as cap:
+        state = stepper.coupled_multi_step(state, 2, expand, 0.0,
+                                           grid_size=grid_size)
+        energy_of(state, expand.a)
+        jax.block_until_ready(state)
+    assert scope._RECORDER is None
+    if cap.summary is None:
+        pytest.skip("this backend wrote no trace file")
+    table = cap.summary["host_spans"]
+    assert table["steps"] == 2 and table["fetches"] == 2
+    assert table["host_syncs_per_step"] == pytest.approx(1.0)
+    assert set(table["spans"]) == {
+        "step_dispatch", "step_fetch", "lap_dispatch", "reduce_dispatch",
+        "reduce_fetch"}
+    ev = events.read_events(event_log, kind="trace_summary")[-1]["data"]
+    assert ev["host_spans"]["spans"]["step_fetch"]["count"] == 1
+    # the same spans lie on the profiler's clock, by name
+    seen = _host_annotations(logdir)
+    assert "step_fetch" in seen and "reduce_fetch" in seen
+    text = "\n".join(obs.trace.format_host_spans(table))
+    assert "1 host syncs per step" in text and "step_fetch" in text
+
+
+def test_trace_scope_under_jit_leaves_no_host_annotation(tmp_path):
+    """A ``trace_scope`` entered while jax traces used to annotate the
+    host timeline with how long Python took to trace the block, once,
+    and ``trace_summary`` counted it as the scope's time. Pinned on a
+    captured CPU trace: the traced scope leaves no host event, the
+    eager one does."""
+    def f(x):
+        with obs.trace_scope("mg_smooth"):
+            return x * 2 + 1
+
+    logdir = str(tmp_path / "trace")
+    with obs.trace.capture(logdir) as cap:
+        y = jax.jit(f)(jnp.ones((8, 8)))      # traced here, in the window
+        with obs.trace_scope("mg_residual"):   # eager
+            z = y + 1
+        jax.block_until_ready(z)
+    if cap.summary is None:
+        pytest.skip("this backend wrote no trace file")
+    seen = _host_annotations(logdir)
+    assert "mg_residual" in seen
+    assert "mg_smooth" not in seen
+    # the scope still names the compiled ops
+    assert obs.has_scope(jax.jit(f).lower(jnp.ones((8, 8))), "mg_smooth")
+
+
+# -- kernel kinds and program names ----------------------------------------
+
+def _stencil_of_kind(kind):
+    from pystella_tpu.ops.pallas_stencil import LANE, StreamingStencil
+
+    def body(taps, extras, scalars):
+        return {"out": taps(0, 0, 1) - taps(0, 0, -1)}
+
+    return StreamingStencil((16, 16, LANE), 1, 1, body, {"out": (1,)},
+                            bx=8, by=8, kind=kind), (1, 16, 16, LANE)
+
+
+@pytest.mark.parametrize("kind", [
+    None, "stage", "pair", "coupled_pair", "energy", "chunk", "lap",
+    "grad", "grad_lap", "pdx", "pdy", "pdz", "div"])
+def test_kernel_kind_scope(kind):
+    """Each kind of streaming kernel is dispatched under its own
+    registered scope; the shared prefix keeps ``pallas_stencil``
+    matching all of them."""
+    st, shape = _stencil_of_kind(kind)
+    name = "pallas_stencil" + ("_" + kind if kind else "")
+    assert scope.kernel_scope(kind) == name
+    assert name in scope.registered_scopes()
+    lowered = jax.jit(lambda x: st(x)["out"]).lower(
+        jnp.zeros(shape, jnp.float32))
+    paths = obs.lowered_scopes(lowered)
+    assert any(name in p.split("/") for p in paths), sorted(paths)[:5]
+    assert obs.has_scope(lowered, "pallas_stencil")
+    # the interior/shell split keeps the kind
+    assert st.with_lattice((8, 16, st.lattice_shape[2])).kind == kind
+
+
+def test_unknown_kernel_kind_is_refused():
+    with pytest.raises(ValueError, match="register_scope"):
+        scope.kernel_scope("mystery")
+
+
+def test_fused_and_derivs_kernels_carry_their_kind(make_decomp):
+    decomp = make_decomp((1, 1, 1))
+    stepper, derivs, stats, state, energy_of, grid_size = _preheat(
+        decomp, n=16)
+    assert stepper._scalar_st.kind == "stage"
+    assert stepper._pair_st.kind == "pair"
+    stepper._ensure_energy_call()
+    assert stepper._ensure_coupled_pair_calls() is not None
+    lowered = stepper._coupled_jit(1, grid_size, 1.0, True, None).lower(
+        state, t=0.0, dt=stepper.dt, a=jnp.float32(1.0),
+        adot=jnp.float32(0.1))
+    assert obs.has_scope(lowered, "pallas_stencil_coupled_pair")
+    assert obs.has_scope(lowered, "pallas_stencil_energy")
+    pallas = ps.FiniteDifferencer(decomp, 2, (0.1,) * 3, mode="pallas")
+    x = jnp.zeros((2, 16, 16, 128), jnp.float32)
+    for name in ("lap", "grad"):
+        # on one chip the operator is applied eagerly: each y-slab call
+        # and the join of the slabs is a program of its own, named
+        # after the operator (they were jit_wrapped / jit_concatenate)
+        st = pallas._pallas_op(name, 2, x.dtype, False, x.shape[-3:])
+        assert st.kind == name
+        low = st._programs[0].lower(x)
+        assert obs.has_scope(low, "pallas_stencil_" + name)
+        assert _module_name(low) == "jit_" + name
+        two, shape = _stencil_of_kind(name)     # one with two y-slabs
+        y = jnp.zeros(shape, jnp.float32)
+        slabs = [jax.eval_shape(call._jitted, y) for call in two._programs]
+        assert len(slabs) == 2
+        assert _module_name(two._join.lower(slabs)) == f"jit_{name}_join"
+        assert two(y)["out"].shape == shape
+
+
+def _module_name(lowered):
+    return lowered.compiler_ir().operation.attributes[
+        "sym_name"].value
+
+
+def test_program_name_from_label():
+    assert program_name("fused.coupled_multi_step[4]") == \
+        "coupled_multi_step_4"
+    assert program_name("fused.multi_step[4]") == "multi_step_4"
+    assert program_name("step.LowStorageRK54.stage0") == \
+        "LowStorageRK54_stage0"
+    assert program_name("mg.smooth(32, 32, 32)") == "smooth_32_32_32"
+    assert program_name("plain") == "plain"
+    assert program_name("..") == "program"
+
+
+def _programs(make_decomp):
+    """(instrumented program, arguments to lower it with) for the hot
+    path's ``instrument_jit`` sites."""
+    decomp = make_decomp((1, 1, 1))
+    stepper, derivs, stats, state, energy_of, grid_size = _preheat(
+        decomp, n=16)
+    a = jnp.float32(1.0)
+    f = state["f"]
+    lattice = ps.Lattice((16,) * 3, (5.0,) * 3, dtype=np.float32)
+    fft = ps.DFT(decomp, grid_shape=(16,) * 3, dtype=np.float32)
+    spectra = ps.PowerSpectra(decomp, fft, lattice.dk, lattice.volume)
+    hist = ps.FieldHistogrammer(decomp, 16, np.float32)
+    reduce_energy = ps.Reduction(
+        decomp, ps.ScalarSector(2, potential=_potential),
+        callback=ps.get_rho_and_p, grid_size=grid_size)
+    env = dict(f=f, dfdt=state["dfdt"], lap_f=f, a=np.float64(1.0))
+    sentinel = obs.sentinel.Sentinel(("dfdt", "f"))
+    sentinel.compute_jit(state)
+    rho_map = ps.ElementWiseMap({ps.Field("rho"): ps.Field("f") * 2})
+    stepper._ensure_stage_jits()
+    stepper._ensure_energy_call()
+    from pystella_tpu.ops.histogram import _bincount_fn
+    bins = jnp.zeros((2, 16, 16, 16), jnp.int32)
+    decomp.share_halos(f, 1, outer_axes=1)
+    yield stepper._jit_step, (state, 0.0, stepper.dt, {})
+    yield stepper._multi_jit(2, None, None), (state,), dict(
+        t=0.0, dt=stepper.dt, rhs_args={"a": a, "hubble": a}, rhs_seq={})
+    yield stepper._coupled_jit(2, grid_size, 1.0, False, None), (
+        state,), dict(t=0.0, dt=stepper.dt, a=a, adot=a)
+    yield stepper._jit_stage0, (state, 0.0, stepper.dt,
+                                {"a": a, "hubble": a})
+    yield derivs._sharded("lap", 1), (f,)
+    yield derivs._sharded("grad", 1, True), (f,)
+    yield reduce_energy._run, (env, grid_size)
+    yield stats._run, ({"f": f}, grid_size)
+    yield hist._prepare, ({"f": f, "max_f": a, "min_f": a,
+                           "max_log_f": a, "min_log_f": a},)
+    yield _bincount_fn(decomp, (2,), 16, False), (bins,)
+    yield _bincount_fn(decomp, (2,), 16, True, owner="spectra"), (
+        bins, jnp.zeros(bins.shape, jnp.float32))
+    yield spectra._weights.__closure__[0].cell_contents, (
+        fft.dft(f), 3, spectra._counts, spectra._kmags, spectra._bin_idx)
+    yield fft._dft, (f,)
+    yield sentinel._jit, (state, {})
+    yield rho_map._run, ({"f": f},)
+    yield decomp._share_halos_cache[((1, 1, 1), 1)], (f,)
+
+
+def test_instrumented_programs_are_named_after_their_labels(make_decomp):
+    """Every ``instrument_jit`` program's module carries its label's
+    name: none is ``jit__unknown`` / ``jit__lambda`` / ``jit_wrapped``
+    / ``jit_run`` / ``jit_local`` any more."""
+    seen = {}
+    for case in _programs(make_decomp):
+        fn, args = case[0], case[1]
+        kwargs = case[2] if len(case) > 2 else {}
+        assert isinstance(fn, InstrumentedJit), fn
+        name = _module_name(fn.lower(*args, **kwargs))
+        assert name == "jit_" + program_name(fn._label), (fn._label, name)
+        seen[fn._label] = name
+    names = set(seen.values())
+    for bad in ("jit__unknown", "jit__lambda", "jit_local", "jit_wrapped",
+                "jit_run", "jit_concatenate", "jit_sharded_fn",
+                "jit_body", "jit_impl"):
+        assert not any(n == bad or n.startswith(bad + "_")
+                       for n in names), (bad, sorted(names))
+    for want in ("jit_coupled_multi_step_2", "jit_multi_step_2",
+                 "jit_lap", "jit_grad", "jit_energy_reduce",
+                 "jit_field_statistics", "jit_histogram_prepare",
+                 "jit_histogram_bincount", "jit_spectra_bin",
+                 "jit_spectra_bin_weights", "jit_halo_pad",
+                 "jit_health_vector", "jit_map_rho", "jit_dft_forward"):
+        assert want in names, (want, sorted(names))

@@ -155,7 +155,7 @@ def test_default_registry_accessors():
 
 def test_trace_scope_noop_safety():
     """Scopes must be free of side effects on CPU with no profiler
-    attached — eager, jitted, and as a decorator."""
+    attached — eager, jitted, and as a host span."""
     with obs.trace_scope("eager_region"):
         x = jnp.sum(jnp.ones(8))
     assert float(x) == 8.0
@@ -167,11 +167,17 @@ def test_trace_scope_noop_safety():
 
     assert float(f(jnp.float32(3.0))) == 6.0
 
-    @obs.traced("decorated_region")
     def g(x):
-        return x + 1
+        with obs.host_span("host_region"):
+            return x + 1
 
     assert g(1) == 2
+    # a span inside someone's jit names no run-time host work: it must
+    # neither fail nor leave a row
+    with obs.recording() as rows:
+        assert float(jax.jit(g)(jnp.float32(1.0))) == 2.0
+        assert g(2) == 3
+    assert [r[0] for r in rows] == ["host_region"]
 
 
 def test_fused_step_lowering_has_named_scopes(make_decomp):

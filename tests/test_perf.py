@@ -483,9 +483,13 @@ def test_perf_drill_through_ledger_and_gate(tmp_path, event_log):
     straggler attribution) -> exactly one rate-limited real
     jax.profiler capture linked from the ledger's perf section ->
     perf_recovered -> the gate passes the honest record and refuses
-    the same record doctored to leave the anomaly unresolved."""
+    the same record doctored to leave the anomaly unresolved. The drill
+    runs on an injected clock: its step times are its schedule, not
+    what real sleeps come to beside five other xdist workers (it failed
+    one run in four that way)."""
     events.emit("run_start", label="perf-drill-test")
-    stats = loadgen.run_perf(str(tmp_path / "caps"))
+    stats = loadgen.run_perf(str(tmp_path / "caps"),
+                             clock=loadgen.VirtualClock())
     assert stats["ok"] is True, stats
     assert stats["anomalies"] >= 2
     assert stats["recovered"] == stats["anomalies"]

@@ -59,8 +59,9 @@ def compile_tpu(fn, *args, donate=()):
     """XLA:TPU + Mosaic compile of ``fn`` for the devices ``args``'
     shardings name. A program over the chip's HBM fails here too
     ("Ran out of memory in memory space hbm"), so passing means it
-    fits."""
-    jax.jit(fn, donate_argnums=donate).trace(*args).lower(
+    fits. Returns the compiled executable (its ``as_text()`` is the
+    optimized HLO a TPU trace names its rows from)."""
+    return jax.jit(fn, donate_argnums=donate).trace(*args).lower(
         lowering_platforms=("tpu",)).compile()
 
 
@@ -87,22 +88,89 @@ def _preheat(devices, proc_shape, grid):
     return stepper, state, scalar
 
 
+#: compiled HLO text of the coupled chunk per (mesh, lattice): compiled
+#: once, read by the compile test and by the name test
+_COUPLED_HLO = {}
+
+
+def _coupled_chunk_hlo(v5e, proc_shape, grid):
+    key = (proc_shape, grid)
+    if key not in _COUPLED_HLO:
+        stepper, state, scalar = _preheat(v5e, proc_shape, grid)
+        assert stepper._ensure_coupled_pair_calls() is not None
+        stepper._ensure_energy_call()
+
+        def chunk(st, a, adot):
+            return stepper._coupled_pair_impl(
+                st, t=0.0, dt=stepper.dt, a=a, adot=adot, nsteps=1,
+                grid_size=float(np.prod(grid)), mpl=1.0)
+
+        _COUPLED_HLO[key] = compile_tpu(
+            chunk, state, scalar, scalar, donate=0).as_text()
+    return _COUPLED_HLO[key]
+
+
 @pytest.mark.parametrize("proc_shape,grid", [ONE_CHIP, MESH])
 def test_coupled_chunk_compiles(v5e, proc_shape, grid):
     """One coupled step = two deferred-drag pair kernels (normal-in and
     deferred-in variants) + the single-stage energy kernel for the odd
     fifth stage: every energy-emitting kernel of the main path, with the
     in-trace Friedmann integration between them."""
-    stepper, state, scalar = _preheat(v5e, proc_shape, grid)
-    assert stepper._ensure_coupled_pair_calls() is not None
-    stepper._ensure_energy_call()
+    assert _coupled_chunk_hlo(v5e, proc_shape, grid)
 
-    def chunk(st, a, adot):
-        return stepper._coupled_pair_impl(
-            st, t=0.0, dt=stepper.dt, a=a, adot=adot, nsteps=1,
-            grid_size=float(np.prod(grid)), mpl=1.0)
 
-    compile_tpu(chunk, state, scalar, scalar, donate=0)
+def _custom_call_names(hlo):
+    """Names of the Mosaic custom calls in optimized HLO text."""
+    import re
+    return re.findall(
+        r"%([\w.\-]+) = [^\n]*custom_call_target=\"tpu_custom_call\"", hlo)
+
+
+def test_coupled_chunk_kernels_are_named_by_kind(v5e):
+    """What a TPU trace will call the chunk's kernels: every Mosaic
+    custom call of the compiled (2,2,1) chunk is named after its kind
+    (``%pallas_stencil_coupled_pair.N``, ``%pallas_stencil_energy.N``),
+    none bare ``%pallas_stencil.N`` or ``%tpu_custom_call.N`` — so
+    ``benchmark/kernels/pallas_stencil.json`` still matches each and the
+    breakdown splits by kind."""
+    import re
+    names = _custom_call_names(_coupled_chunk_hlo(v5e, *MESH))
+    assert names
+    kinds = {re.sub(r"\.\d+$", "", n) for n in names}
+    assert kinds == {"pallas_stencil_coupled_pair",
+                     "pallas_stencil_energy"}, sorted(kinds)
+
+
+@pytest.mark.slow
+def test_derivs_kernels_are_named_by_kind(v5e, monkeypatch):
+    """``FiniteDifferencer``'s lap and grad (``%tpu_custom_call.N`` in
+    the PR 23 traces) compile to ``%pallas_stencil_lap`` /
+    ``%pallas_stencil_grad`` inside programs called ``lap`` / ``grad``:
+    on one chip each eagerly dispatched y-slab call, on the mesh the
+    operator's one program."""
+    import re
+    from pystella_tpu.ops import pallas_stencil
+    # the operators take no interpret= override: build for the chip
+    monkeypatch.setattr(pallas_stencil, "_is_cpu", lambda: False)
+    for proc_shape, grid in (((1, 1, 1), (512, 128, 512)), MESH):
+        ndev = int(np.prod(proc_shape))
+        decomp = ps.DomainDecomposition(proc_shape, devices=v5e[:ndev])
+        fd = ps.FiniteDifferencer(decomp, 2, tuple(5.0 / n for n in grid),
+                                  mode="pallas")
+        x = jax.ShapeDtypeStruct((2,) + grid, jnp.float32,
+                                 sharding=decomp.sharding(1))
+        for name in ("lap", "grad"):
+            op = fd._pallas_op(name, 2, jnp.dtype("float32"), False, grid)
+            if ndev == 1:       # the stencil itself: take one slab call
+                fn = op._programs[0]._jitted
+            else:               # a closure over the sharded program
+                fn = op.__defaults__[0]._jitted
+            hlo = fn.trace(x).lower(
+                lowering_platforms=("tpu",)).compile().as_text()
+            assert hlo.startswith("HloModule jit_" + name), hlo[:60]
+            kinds = {re.sub(r"\.\d+$", "", n)
+                     for n in _custom_call_names(hlo)}
+            assert kinds == {"pallas_stencil_" + name}, sorted(kinds)
 
 
 @pytest.mark.slow
