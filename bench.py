@@ -294,20 +294,8 @@ def run_gw_spectra(n=256, nreps=5):
     return (time.perf_counter() - start) / nreps * 1e3
 
 
-def auto_assemble(decomp, grid_shape):
-    """Default y-slab assembly mode for the GW stepper: 'update' only
-    when the PER-DEVICE block is at the single-chip HBM edge. The
-    threshold is local volume, not global: the 512^3 single-chip config
-    misses 16 GB by 183 MB under the default concat assembly (measured;
-    ~2 GB of live slab temps the update-slice chain frees), but a
-    multi-chip decomp whose per-chip state fits comfortably should not
-    pay update's extra zero-init write per output."""
-    local_sites = int(np.prod(decomp.rank_shape(grid_shape)))
-    return "update" if local_sites >= 512**3 else "concat"
-
-
 def build_gw_step(grid_shape, dtype=np.float32, decomp=None,
-                  carry_dtype=None, assemble=None):
+                  carry_dtype=None):
     """Construct the full scalar+GW preheating system (the one model that
     REQUIRES multi-chip at 512^3: ~17 GB f32 state+carry > one v5e's
     HBM) on ``decomp``'s mesh; returns ``(stepper, state, dt)`` like
@@ -328,11 +316,9 @@ def build_gw_step(grid_shape, dtype=np.float32, decomp=None,
     sector = ps.ScalarSector(2, potential=potential)
     gw = ps.TensorPerturbationSector([sector])
     kw = {} if carry_dtype is None else {"carry_dtype": carry_dtype}
-    if assemble is None:
-        assemble = auto_assemble(decomp, grid_shape)
     stepper = ps.FusedPreheatStepper(sector, gw, decomp, grid_shape,
                                      lattice.dx, 2, dtype=dtype, dt=dt,
-                                     assemble=assemble, **kw)
+                                     **kw)
     rng = np.random.default_rng(9)
     state = {
         "f": decomp.shard(

@@ -114,7 +114,7 @@ def check_run_events(events, label, kernels):
     kinds = [e["kind"] for e in events]
     require("run_complete" in kinds, f"{label}: no run_complete event")
     require("run_aborted" not in kinds, f"{label}: run_aborted")
-    for kind in ("kernel_fallback", "assemble_fallback", "diverged"):
+    for kind in ("kernel_fallback", "diverged"):
         hit = [e["data"] for e in events if e["kind"] == kind]
         require(not hit, f"{label}: {kind} event(s): {hit}")
     blocks = {}

@@ -189,11 +189,9 @@ for _name, _help in (
     ("gate_verdict", "the perf gate ran (ok, exit_code, reasons)"),
     # -- numerics / solver hot paths ----------------------------------------
     ("mg_cycle", "one multigrid cycle (depth, smooths, errors)"),
-    ("assemble_fallback", "explicit assemble='update' fell back to the "
-                          "resident kernel tier"),
     # -- fused kernel tiers + the persistent autotuner (ops.autotune) -------
     ("block_choice", "a fused kernel build chose its blocking "
-                     "(bx/by/win_halo + source: autotune table hit, "
+                     "(bx/by/grid/win_halo + source: autotune table hit, "
                      "choose_blocks heuristic, env override, explicit)"),
     ("kernel_fallback", "a fused kernel tier degraded down the ladder "
                         "(chunk -> pair -> single), with the reason"),

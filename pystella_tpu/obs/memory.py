@@ -517,8 +517,8 @@ def instrument_jit(fn, label, name=None, **jit_kwargs):
     ``jit__unknown`` / ``jit_wrapped`` / ``jit__lambda``; its compiles
     land in the compile ledger under ``label``. The package's internal jit sites (steppers, fused chunks,
     operators, reductions, multigrid, spectra) all route through this,
-    and the stencil kernels' slab calls where they are dispatched
-    eagerly (``name`` given: every slab of a kernel is one name)."""
+    and a stencil kernel where it is dispatched eagerly (``name``
+    given: the kernel's kind)."""
     def named(*args, **kwargs):
         return fn(*args, **kwargs)
     # jax resolves static/donated argument names through __wrapped__

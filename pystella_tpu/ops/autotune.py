@@ -8,7 +8,7 @@ is a genuine tradeoff — redundant halo recompute vs eliminated HBM
 round trips — that only a measurement settles. This module makes that
 measurement once per device kind and PERSISTS it:
 
-- :func:`sweep` enumerates ``(bx, by, chunk depth, layout)`` candidates
+- :func:`sweep` enumerates ``(bx, by, chunk depth)`` candidates
   from the same VMEM model the heuristic uses
   (:func:`~pystella_tpu.ops.pallas_stencil.feasible_blocks` — the
   autotuner can never propose a config the builder would reject),
@@ -235,7 +235,7 @@ class AutotuneStore:
 
     def record(self, digest, components, winner, measurements=None):
         """Persist a sweep winner. ``winner`` carries the tuned config
-        (``bx``/``by``/``chunk``/``assemble`` + the measured
+        (``bx``/``by``/``chunk`` + the measured
         ``ms_per_step``); ``measurements`` optionally keeps the ranked
         candidate table for forensics."""
         table = self._load()
@@ -253,8 +253,7 @@ class AutotuneStore:
         _emit("autotune_record", digest=digest, path=self.path,
               key_kind=components.get("kind"), **{
                   k: winner.get(k)
-                  for k in ("bx", "by", "chunk", "assemble",
-                            "ms_per_step")})
+                  for k in ("bx", "by", "chunk", "ms_per_step")})
         return entry
 
     def gc(self, dry_run=False):
@@ -312,13 +311,13 @@ def consult(kind, local_shape, h, dtype, nscalars,
 
 def candidate_configs(local_shape, h, dtype, nscalars,
                       gravitational_waves=False, chunk_depths=(0, 4),
-                      layouts=("concat",), max_blocks=4):
-    """The sweep grid: for each chunk depth (0 = the pair tier) and
-    output layout, the top ``max_blocks`` feasible ``(bx, by)``
-    blockings of the WIDEST kernel that depth builds, straight from the
-    ``choose_blocks`` VMEM model (``feasible_blocks``). Returns a list
-    of ``{"bx", "by", "chunk", "assemble"}`` dicts, heuristic-preferred
-    order first per depth."""
+                      max_blocks=4):
+    """The sweep grid: for each chunk depth (0 = the pair tier), the
+    top ``max_blocks`` feasible ``(bx, by)`` blockings of the WIDEST
+    kernel that depth builds, straight from the ``choose_blocks`` VMEM
+    model (``feasible_blocks``). Returns a list of
+    ``{"bx", "by", "chunk"}`` dicts, heuristic-preferred order first
+    per depth."""
     from pystella_tpu.ops.pallas_stencil import feasible_blocks
     F = int(nscalars) + (6 if gravitational_waves else 0)
     itemsize = np.dtype(dtype).itemsize
@@ -341,10 +340,8 @@ def candidate_configs(local_shape, h, dtype, nscalars,
         blocks = feasible_blocks(
             n_win, local_shape, int(h), itemsize, n_extra, 4 * F,
             win_halo=win_halo, stages=stages)
-        for layout in layouts:
-            for bx, by in blocks[:int(max_blocks)]:
-                out.append({"bx": bx, "by": by, "chunk": int(chunk),
-                            "assemble": str(layout)})
+        for bx, by in blocks[:int(max_blocks)]:
+            out.append({"bx": bx, "by": by, "chunk": int(chunk)})
     return out
 
 
@@ -431,10 +428,7 @@ def _build_sweep_stepper(grid_shape, cfg, dtype=np.float32, h=2,
                 + gsq / 2 * f[0]**2 * f[1]**2) / mphi**2
 
     sector = ps.ScalarSector(nscalars, potential=potential)
-    kwargs = dict(dtype=dtype, interpret=interpret, autotune=autotune,
-                  # sweep candidates pin their layout; a tuned build
-                  # (empty cfg) leaves it None so the table decides
-                  assemble=cfg.get("assemble"))
+    kwargs = dict(dtype=dtype, interpret=interpret, autotune=autotune)
     if cfg.get("chunk"):
         kwargs.update(chunk_stages=int(cfg["chunk"]),
                       chunk_bx=cfg.get("bx"), chunk_by=cfg.get("by"))
@@ -452,7 +446,7 @@ def _build_sweep_stepper(grid_shape, cfg, dtype=np.float32, h=2,
 
 
 def sweep(grid_shape, store=None, nsteps=4, rounds=3,
-          chunk_depths=(0, 4), layouts=("concat",), max_blocks=4,
+          chunk_depths=(0, 4), max_blocks=4,
           dtype=np.float32, h=2, nscalars=2, interpret=None, log=print):
     """Sweep the bench preheat system at ``grid_shape`` on the live
     backend, record the winner into ``store`` (default:
@@ -465,7 +459,7 @@ def sweep(grid_shape, store=None, nsteps=4, rounds=3,
     store = store or AutotuneStore()
     configs = candidate_configs(grid_shape, h, dtype, nscalars,
                                 chunk_depths=chunk_depths,
-                                layouts=layouts, max_blocks=max_blocks)
+                                max_blocks=max_blocks)
     if not configs:
         raise ValueError(
             f"no feasible sweep candidates for lattice {grid_shape} "
@@ -500,11 +494,11 @@ def sweep(grid_shape, store=None, nsteps=4, rounds=3,
     for rec in results:
         if "ms_per_step" in rec:
             log(f"  bx={rec['bx']:3d} by={rec['by']:4d} "
-                f"chunk={rec['chunk']} {rec['assemble']:7s}: "
+                f"chunk={rec['chunk']}: "
                 f"{rec['ms_per_step']:8.3f} ms/step")
         else:
             log(f"  bx={rec['bx']:3d} by={rec['by']:4d} "
-                f"chunk={rec['chunk']} {rec['assemble']:7s}: "
+                f"chunk={rec['chunk']}: "
                 f"FAILED {rec['error']}")
     best = next((r for r in results if "ms_per_step" in r), None)
     if best is None:
@@ -512,17 +506,16 @@ def sweep(grid_shape, store=None, nsteps=4, rounds=3,
     digest, comp = stepper_key(
         "fused_scalar", grid_shape, h, dtype, nscalars)
     sites = float(np.prod(grid_shape))
-    winner = {k: best[k] for k in ("bx", "by", "chunk", "assemble",
-                                   "ms_per_step")}
+    winner = {k: best[k] for k in ("bx", "by", "chunk", "ms_per_step")}
     winner["site_updates_per_s"] = sites * 1e3 / best["ms_per_step"]
     store.record(digest, comp, winner, measurements=[
-        {k: r.get(k) for k in ("bx", "by", "chunk", "assemble",
-                               "ms_per_step", "error")}
+        {k: r.get(k) for k in ("bx", "by", "chunk", "ms_per_step",
+                               "error")}
         for r in results])
     _emit("autotune_sweep", grid_shape=list(grid_shape),
           candidates=len(results), path=store.path, **winner)
     log(f"autotune: winner bx={best['bx']} by={best['by']} "
-        f"chunk={best['chunk']} {best['assemble']} "
+        f"chunk={best['chunk']} "
         f"({best['ms_per_step']:.3f} ms/step) -> {store.path}")
     return results
 
@@ -544,7 +537,7 @@ def _cmd_sweep(args):
           f"{store.device_kind!r}, table {store.path}")
     sweep(grid, store=store,
           chunk_depths=tuple(int(c) for c in args.chunks.split(",")),
-          layouts=tuple(args.layouts.split(",")), **kwargs)
+          **kwargs)
     return 0
 
 
@@ -563,7 +556,7 @@ def _cmd_show(args):
                 f"{'x'.join(map(str, key.get('local_shape', [])))}"
                 f" h={key.get('h')} {key.get('dtype')}"
                 f" -> bx={e.get('bx')} by={e.get('by')}"
-                f" chunk={e.get('chunk')} {e.get('assemble')}"
+                f" chunk={e.get('chunk')}"
                 f" ({e.get('ms_per_step', float('nan')):.3f} ms/step)")
         if live is not None:
             problems = store._mismatches(e, live)
@@ -595,8 +588,6 @@ def main(argv=None):
                      help="cube edge (default 256; 16 under --dry-run)")
     ps_.add_argument("--chunks", default="0,4",
                      help="comma-separated chunk depths (0 = pair tier)")
-    ps_.add_argument("--layouts", default="concat",
-                     help="comma-separated assemble layouts to sweep")
     ps_.add_argument("--dir", default=None,
                      help="table directory (default "
                           "$PYSTELLA_AUTOTUNE_DIR -> bench_results/)")

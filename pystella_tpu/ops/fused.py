@@ -152,7 +152,7 @@ class FusedScalarStepper(_step.Stepper):
                  tableau=None, dtype=jnp.float32, bx=None, by=None,
                  dt=None, pair_stages=True, pair_bx=None, pair_by=None,
                  interpret=None, donate=False, resident=None,
-                 carry_dtype=None, assemble=None, overlap=None,
+                 carry_dtype=None, overlap=None,
                  chunk_stages=None, chunk_bx=None, chunk_by=None,
                  autotune=None, **kwargs):
         tableau = tableau or _step.LowStorageRK54
@@ -213,22 +213,6 @@ class FusedScalarStepper(_step.Stepper):
         # convergence-order-critical runs).
         self._carry_dtype = (None if carry_dtype is None
                              else jnp.zeros((), carry_dtype).dtype)
-        #: y-slab output assembly for the streaming kernels:
-        #: ``"update"`` trades one zero-init write per output for ~one
-        #: full output set of peak HBM (what lets the 512**3 GW
-        #: bf16-carry step fit a single v5e — it misses by 183 MB under
-        #: the default ``"concat"``; doc/performance.md "Memory").
-        #: Validated HERE (not just in StreamingStencil) because
-        #: _build_stencil treats construction ValueErrors as "no feasible
-        #: blocking" and falls back — a typo would silently change tiers.
-        if assemble not in (None, "concat", "update"):
-            raise TypeError(f"assemble must be 'concat'/'update', "
-                            f"got {assemble!r}")
-        # None = defer the layout to policy (autotune table, else
-        # "concat") — an EXPLICIT request, 'concat' included, is never
-        # overridden (the chunk_stages=None-vs-0 sentinel convention)
-        self._assemble = assemble or "concat"
-
         # persistent-autotuner consult (ops.autotune): a live-process-
         # matching table entry supplies the hot-loop kernel's measured
         # blocking — and the chunk depth, when the caller left it to
@@ -242,11 +226,6 @@ class FusedScalarStepper(_step.Stepper):
             carry_dtype=self._carry_dtype, store=autotune,
             tableau=tableau.__name__)
         entry = self._autotune_entry
-        if (entry is not None and entry.get("assemble")
-                and assemble is None):
-            # layout is part of the swept config; any explicit request
-            # beats the table
-            self._assemble = str(entry["assemble"])
         if chunk_stages is None:
             if entry is not None and entry.get("chunk") is not None:
                 chunk_stages = int(entry["chunk"])
@@ -328,6 +307,7 @@ class FusedScalarStepper(_step.Stepper):
             "block_choice", kernel=kind,
             stencil=type(st).__name__,
             bx=getattr(st, "bx", None), by=getattr(st, "by", None),
+            grid=getattr(st, "grid", None),
             win_halo=getattr(st, "wh", None),
             stages=getattr(st, "stages", 1),
             source=source, local_shape=list(self.local_shape),
@@ -361,9 +341,8 @@ class FusedScalarStepper(_step.Stepper):
             try:
                 st = StreamingStencil(
                     self.local_shape, win_defs, self.h, body, out_defs,
-                    bx=bx, by=by, assemble=self._assemble,
-                    win_halo=win_halo, stages=stages, kind=kind,
-                    **self._halo_kw, **common)
+                    bx=bx, by=by, win_halo=win_halo, stages=stages,
+                    kind=kind, **self._halo_kw, **common)
                 self._emit_block_choice(kind, st, source)
                 return st
             except ValueError:
@@ -374,19 +353,6 @@ class FusedScalarStepper(_step.Stepper):
                         or self._py > 1 or bx is not None
                         or by is not None):
                     raise
-        if self._assemble == "update":
-            # an explicit low-peak-HBM request lands on the resident tier,
-            # where there are no y-slab outputs to assemble — say so
-            # instead of silently dropping the option
-            import warnings
-            warnings.warn(
-                "assemble='update' requested, but this lattice selected "
-                "the whole-lattice-resident kernel tier, where y-slab "
-                "assembly does not apply; the option is ignored",
-                stacklevel=4)
-            _events.emit("assemble_fallback", tier="resident",
-                         requested="update",
-                         local_shape=self.local_shape)
         st = ResidentStencil(self.local_shape, win_defs, self.h, body,
                              out_defs, interpret=self._interpret,
                              stages=stages, **common)

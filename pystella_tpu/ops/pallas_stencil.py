@@ -13,15 +13,20 @@ Design (chosen by microbenchmark on TPU v5e):
 - Arrays are ``(C, X, Y, Z)`` with lattice axes trailing. ``Z`` (the lane
   dimension) is kept whole in VMEM; z-shifts are in-register lane rolls with
   free periodic wrap. ``Y`` (sublane) is split into blocks ``by`` with an
-  8-aligned halo window; the y-offset is static per y-block (one
-  ``pallas_call`` per y-block) because Mosaic requires provably-aligned
-  sublane DMA offsets. ``X`` (untiled) is streamed: grid programs advance
-  ``bx`` rows at a time; a persistent VMEM ring of 4 x-blocks holds the
-  stencil window and each program DMAs only its one new block —
-  amplification ~1, contiguous descriptors, issued one program ahead
-  (double buffering).
-- Periodic wrap: x via block-index modulo, y via static piecewise DMAs at
-  the edge y-blocks, z via the lane roll.
+  8-aligned halo window. A kernel is ONE ``pallas_call`` with grid
+  ``(Y // by, X // bx)``, x innermost: each program writes its
+  ``(bx, by, Z)`` block where it lives in the full-lattice output, so
+  nothing is left to assemble. The y-window's DMA offset is
+  ``j * by - HY``, which Mosaic takes as provably sublane-aligned
+  (``pl.multiple_of``). ``X`` (untiled) is streamed: within one y-block
+  the programs advance ``bx`` rows at a time; a persistent VMEM ring of
+  4 x-blocks holds the stencil window and each program DMAs only its
+  one new block — amplification ~1, contiguous descriptors, issued one
+  program ahead (double buffering). The ring is primed anew at the
+  first x-block of every y-block.
+- Periodic wrap: x via block-index modulo, y via piecewise DMAs at the
+  first and last y-block (chosen in-kernel by ``pl.when``), z via the
+  lane roll.
 - ``x_halo=True`` instead reads an input whose x-axis is pre-padded with
   ``h`` halo rows (filled by the mesh halo exchange — the sharded path);
   each program then DMAs its own haloed window directly (no ring).
@@ -128,7 +133,7 @@ def choose_blocks(n_comp, lattice_shape, h, itemsize, n_extra, n_out,
     temporaries per fused stage.
 
     Preference (measured on v5e, 512^3/128^3 fused RK54 sweeps): the
-    largest feasible ``by`` (fewer per-stage pallas_calls, wider DMA
+    largest feasible ``by`` (fewer y-blocks to prime the ring for, wider DMA
     rows), then the *smallest* feasible ``bx >= h`` — small x-blocks keep
     the ring slots cheap and pipeline best ((2,128) beat every bx>=4
     blocking at 128^3; (2,64) beat (2,32) at 512^3). The default 24 MB
@@ -167,7 +172,7 @@ def choose_blocks(n_comp, lattice_shape, h, itemsize, n_extra, n_out,
     best = feasible[0] if feasible else None
     if best is None:
         if Y % 8:
-            # the streaming kernel's y-slab math assumes by >= the 8-aligned
+            # the streaming kernel's y-block math assumes by >= the 8-aligned
             # halo width, so lattices whose Y is not a multiple of 8 have no
             # feasible blocking at all — say so clearly (callers like
             # FiniteDifferencer catch this and take the halo path)
@@ -483,6 +488,10 @@ class ResidentStencil:
 class StreamingStencil:
     """Builds and calls streaming-window Pallas stencil kernels.
 
+    One ``pallas_call`` per kernel, grid ``(Y // by, X // bx)`` with x
+    innermost: every program writes its ``(*lead, bx, by, Z)`` block
+    straight into the full-lattice output.
+
     :arg lattice_shape: local interior ``(X, Y, Z)``.
     :arg win_defs: dict name -> leading component count, one entry per
         *windowed* (haloed) input; a bare int means a single input named
@@ -507,29 +516,32 @@ class StreamingStencil:
     :arg sum_defs: dict name -> term count: lattice-summed outputs. The
         body returns a sequence of ``nterms`` scalar block sums per name
         (scalars, not a vector — see :func:`_sum_tile`); each
-        grid program adds its partial into one ``(nt_pad8, LANE)``
-        accumulator tile revisited across the (sequential) grid, and
-        :meth:`__call__` finishes the reduction over y-slabs outside the
-        kernel — deterministic summation order (program order is fixed),
-        one tile writeback per kernel. This is how fused RK stages emit
-        energy reductions of their input state for free (the whole state
-        is already in VMEM).
-    :arg kind: what the kernel is, for traces: its y-slab calls are
-        dispatched under ``obs.scope.kernel_scope(kind)``
-        (``pallas_stencil_<kind>``), the name a TPU trace gives their
-        HLO instructions. ``None`` keeps the bare ``pallas_stencil``.
+        grid program adds its partial into the ``(nt_pad8, LANE)``
+        accumulator tile of its y-block, revisited across the
+        (sequential) x programs, and :meth:`__call__` finishes the
+        reduction over y-blocks outside the kernel — deterministic
+        summation order (program order is fixed), one tile writeback
+        per y-block. This is how fused RK stages emit energy reductions
+        of their input state for free (the whole state is already in
+        VMEM).
+    :arg kind: what the kernel is, for traces: the call is dispatched
+        under ``obs.scope.kernel_scope(kind)``
+        (``pallas_stencil_<kind>``), the name a TPU trace gives its
+        HLO instruction, and applied eagerly it is one program named
+        after the kind (``jit_<kind>``). ``None`` keeps the bare
+        ``pallas_stencil``.
     """
 
     def __init__(self, lattice_shape, win_defs, h, body, out_defs,
                  extra_defs=None, scalar_names=(), dtype=jnp.float32,
                  bx=None, by=None, x_halo=False, y_halo=False,
                  interpret=None, sum_defs=None, dtypes=None,
-                 assemble="concat", win_halo=None, stages=1, kind=None):
+                 win_halo=None, stages=1, kind=None):
         if h > HY:
             raise ValueError(f"stencil radius {h} exceeds aligned halo {HY}")
         #: what the kernel is (``"pair"``, ``"lap"`` ...; ``None``: not
-        #: said) and the scope its y-slab calls are dispatched under: the
-        #: name their HLO instructions carry in a TPU trace
+        #: said) and the scope its call is dispatched under: the name
+        #: its HLO instruction carries in a TPU trace
         self.kind = kind
         self._scope = kernel_scope(kind)
         #: fused-stage count of the body (1 single, 2 pair, >=4 chunk):
@@ -592,19 +604,10 @@ class StreamingStencil:
                 f"bx={bx} must be >= the window halo {self.wh} (ring "
                 "slots supply the halo rows)")
         self.bx, self.by = int(bx), int(by)
+        #: the kernel's grid: y-blocks, then x-blocks (x innermost)
+        self.grid = (Y // self.by, X // self.bx)
         self.x_halo = bool(x_halo)
         self.y_halo = bool(y_halo)
-        #: y-slab output assembly: ``"concat"`` keeps every slab output
-        #: live until one concatenate (fastest — no extra writes);
-        #: ``"update"`` threads a dynamic-update-slice chain so each slab
-        #: buffer dies after its update — peak HBM drops by ~one full
-        #: output set at the cost of a zero-init write per output
-        #: (measured need: the 512^3 GW bf16-carry step misses the v5e
-        #: 16 GB by 183 MB under concat, with ~2 GB of live slab temps).
-        if assemble not in ("concat", "update"):
-            raise ValueError(f"assemble must be 'concat'/'update', "
-                             f"got {assemble!r}")
-        self.assemble = assemble
         self.interpret = _is_cpu() if interpret is None else interpret
         if not self.interpret and Z % LANE:
             raise ValueError(
@@ -612,94 +615,87 @@ class StreamingStencil:
                 f"multiple of the {LANE}-lane tile (got Z={Z}): Mosaic "
                 f"rejects windowed DMAs with unaligned lane slices; use "
                 f"the halo/roll path (or interpret mode) for this lattice")
-        #: the y-slab calls, each under the kernel's dispatch scope:
-        #: what a caller's program traces
-        self._slabs = [self._scoped(self._build(j))
-                       for j in range(Y // self.by)]
+        self._call = self._build()
         # applied eagerly (on one chip FiniteDifferencer's operators
-        # are), each slab call and the join of the slabs is a program
-        # of its own: named after the kind, so a trace shows jit_lap /
-        # jit_lap_join where it showed jit_wrapped / jit_concatenate
-        name = kind or "stencil"
-        self._programs = [
-            _obs_memory.instrument_jit(
-                slab, label=f"pallas.streaming{tuple(self.lattice_shape)}"
-                            f"[slab{j}]", name=name)
-            for j, slab in enumerate(self._slabs)]
-        self._join = _obs_memory.instrument_jit(
-            self._assemble, label=f"pallas.{name}_join")
+        # are) the stencil is one program named after its kind, so a
+        # trace shows jit_lap; a caller's program traces _apply directly
+        self._program = _obs_memory.instrument_jit(
+            self._apply,
+            label=f"pallas.streaming{tuple(self.lattice_shape)}",
+            name=kind or "stencil")
 
     # -- construction ------------------------------------------------------
 
-    def _scoped(self, call):
-        """``call`` under this kernel's dispatch scope: the name its HLO
-        instruction carries (entered inside the function, because a
-        scope round an eager dispatch does not reach the program)."""
-        scope = self._scope
-
-        def slab(*args):
-            with trace_scope(scope):
-                return call(*args)
-        return slab
-
-    def _y_pieces(self, j):
-        """Static (src_y0, dst_y0, n) DMA pieces for the y-window of block
-        j, with periodic wrap at the global y edges — or, with
-        ``y_halo``, one contiguous piece from the HY-padded input."""
-        X, Y, Z = self.lattice_shape
+    def _each_y_case(self, j, stream):
+        """Run ``stream(pieces)`` for the y-window of y-block ``j`` (a
+        grid index): ``pieces`` are the ``(src_y0, dst_y0, n)`` DMA
+        pieces of the window. With ``y_halo`` it is one contiguous piece
+        of the HY-padded input. Otherwise the first and last y-block
+        wrap periodically at the global y edges (two static pieces
+        each), a middle block is one piece at the dynamic, 8-aligned
+        ``j * by - HY``; ``pl.when`` picks the case in-kernel."""
+        Y = self.lattice_shape[1]
         by, byw = self.by, self.by + 2 * HY
-        if self.y_halo:
-            return [(j * by, 0, byw)]
         nby = Y // by
-        y0 = j * by - HY
-        if nby == 1:
-            return [(Y - HY, 0, HY), (0, HY, Y), (0, HY + Y, HY)]
-        if j == 0:
-            return [(Y - HY, 0, HY), (0, HY, by + HY)]
-        if j == nby - 1:
-            return [(y0, 0, by + HY), (0, by + HY, HY)]
-        return [(y0, 0, byw)]
 
-    def _make_specs(self, j):
-        """(in_specs, out_specs, out_shapes) shared by both kernel modes.
-        Outputs are y-slabs ``(*lead, X, by, Z)``."""
+        def at(off):
+            # int32: under x64 a raw program_id product lowers as i64,
+            # which tpu.memref_slice rejects (test_tpu_lowering)
+            return pl.multiple_of(
+                jnp.asarray(j, jnp.int32) * jnp.int32(by) + jnp.int32(off),
+                HY)
+
+        if self.y_halo:
+            return stream([(at(0), 0, byw)])
+        if nby == 1:
+            return stream([(Y - HY, 0, HY), (0, HY, Y), (0, HY + Y, HY)])
+        cases = [
+            (j == 0, [(Y - HY, 0, HY), (0, HY, by + HY)]),
+            (j == nby - 1, [(Y - by - HY, 0, by + HY), (0, by + HY, HY)])]
+        if nby > 2:
+            cases.append(((j > 0) & (j < nby - 1), [(at(-HY), 0, byw)]))
+        for cond, pieces in cases:
+            pl.when(cond)(lambda pieces=pieces: stream(pieces))
+
+    def _make_specs(self):
+        """(in_specs, out_specs, out_shapes) shared by both kernel modes:
+        program ``(j, i)`` reads and writes block ``(i, j)`` of the
+        full-lattice extras and outputs."""
         X, Y, Z = self.lattice_shape
         bx, by = self.bx, self.by
 
-        def block_spec(lead, yidx):
+        def block_spec(lead):
             nlead = len(lead)
-
-            def index_map(i, nlead=nlead, yidx=yidx):
-                return (0,) * nlead + (i, yidx, 0)
-
-            return pl.BlockSpec(tuple(lead) + (bx, by, Z), index_map)
+            return pl.BlockSpec(
+                tuple(lead) + (bx, by, Z),
+                lambda j, i: (0,) * nlead + (i, j, 0))
 
         in_specs = [pl.BlockSpec(memory_space=pl.ANY)
                     for _ in self.win_defs]
         in_specs += [pl.BlockSpec(memory_space=pltpu.SMEM)
                      for _ in self.scalar_names]
-        in_specs += [block_spec(self.extra_defs[n], j)
-                     for n in self.extra_defs]
-        out_specs = [block_spec(self.out_defs[n], 0) for n in self.out_defs]
+        in_specs += [block_spec(lead) for lead in self.extra_defs.values()]
+        out_specs = [block_spec(lead) for lead in self.out_defs.values()]
         out_shapes = [
-            jax.ShapeDtypeStruct(self.out_defs[n] + (X, by, Z),
+            jax.ShapeDtypeStruct(lead + (X, Y, Z),
                                  self.dtypes.get(n, self.dtype))
-            for n in self.out_defs]
+            for n, lead in self.out_defs.items()]
         for nt in self.sum_defs.values():
-            # One (nt_pad8, LANE) accumulator tile REVISITED by every grid
-            # program (constant index map; the terms live in lane 0).
+            # One (nt_pad8, LANE) accumulator tile per y-block, REVISITED
+            # by that y-block's x programs (index map constant in i; the
+            # terms live in lane 0).
             # Mosaic requires an output block's trailing two dims to be
             # (8, 128)-aligned or equal to the array's (measured on v5e:
             # a per-program (nt, 1, 1) block over (nt, nbx, 1) partials
             # fails to compile), so per-program partial columns are out;
             # the revisited block stays VMEM-resident across the
-            # sequential grid and each program adds its block sum —
+            # sequential x programs and each adds its block sum —
             # deterministic (TPU grids are sequential) and written back
-            # to HBM once.
+            # to HBM once per y-block.
             ntp = -(-nt // HY) * HY
-            out_specs.append(pl.BlockSpec((ntp, LANE), lambda i: (0, 0)))
+            out_specs.append(pl.BlockSpec((ntp, LANE), lambda j, i: (j, 0)))
             out_shapes.append(
-                jax.ShapeDtypeStruct((ntp, LANE), self.dtype))
+                jax.ShapeDtypeStruct((self.grid[0] * ntp, LANE), self.dtype))
         return in_specs, out_specs, out_shapes
 
     def _unpack_refs(self, refs):
@@ -726,14 +722,15 @@ class StreamingStencil:
         nlat = len(self.out_defs)
         for n, ref in zip(self.out_defs, out_refs[:nlat]):
             ref[...] = outs[n].astype(ref.dtype)
-        i = pl.program_id(0)
+        i = pl.program_id(1)
         for n, ref in zip(self.sum_defs, out_refs[nlat:]):
             self._accumulate_sums(ref, outs[n], self.sum_defs[n], i)
 
     @staticmethod
     def _accumulate_sums(ref, terms, nt, i):
-        """Add this program's ``nt`` block sums into the revisited
-        ``(nt_pad8, LANE)`` accumulator tile (terms in lane 0)."""
+        """Add this program's ``nt`` block sums into its y-block's
+        revisited ``(nt_pad8, LANE)`` accumulator tile (terms in lane
+        0), initialised at the y-block's first x program."""
         if len(terms) != nt:
             raise ValueError(f"body returned {len(terms)} sum terms, "
                              f"sum_defs declares {nt}")
@@ -747,60 +744,82 @@ class StreamingStencil:
         def _():
             ref[...] = ref[...] + tile
 
-    def _build(self, j):
-        if self.x_halo:
-            return self._build_xhalo(j)
-        X, Y, Z = self.lattice_shape
-        h, bx, by = self.wh, self.bx, self.by
-        byw = by + 2 * HY
-        nbx = X // bx
-        R = _RING
-        ypieces = self._y_pieces(j)
+    def _pallas_call(self, kernel, win_rows):
+        """The one ``pallas_call`` of ``kernel`` over the ``(j, i)`` grid,
+        with a ``win_rows``-row VMEM window per windowed input."""
+        Z = self.lattice_shape[2]
+        in_specs, out_specs, out_shapes = self._make_specs()
+        return pl.pallas_call(
+            kernel,
+            grid=self.grid,
+            in_specs=in_specs,
+            out_specs=out_specs,
+            out_shape=out_shapes,
+            scratch_shapes=[
+                pltpu.VMEM((C, win_rows, self.by + 2 * HY, Z),
+                           self.dtypes.get(n, self.dtype))
+                for n, C in self.win_defs.items()
+            ] + [pltpu.SemaphoreType.DMA((2,))],
+            interpret=self.interpret,
+            compiler_params=_compiler_params(self.interpret),
+        )
 
-        def block_dmas(f_ref, win, sem, blk, slot):
-            b = _rem(blk + nbx, nbx)
-            return [pltpu.make_async_copy(
-                f_ref.at[:, pl.ds(b * bx, bx), pl.ds(sy0, n), :],
-                win.at[:, pl.ds(slot * bx, bx), pl.ds(dy0, n), :],
-                sem.at[_rem(slot, 2)]) for sy0, dy0, n in ypieces]
+    def _build(self):
+        if self.x_halo:
+            return self._build_xhalo()
+        h, bx = self.wh, self.bx
+        nbx = self.grid[1]
+        R = _RING
 
         def kernel(*refs):
             f_refs, scalar_refs, extra_refs, out_refs, wins, sem = \
                 self._unpack_refs(refs)
-            i = pl.program_id(0)
+            j, i = pl.program_id(0), pl.program_id(1)
 
-            def start(blk, slot):
-                for f_ref, win in zip(f_refs, wins):
-                    for d in block_dmas(f_ref, win, sem, blk, slot):
+            def stream(ypieces):
+                """Bring x-block i's window of this y-block into the
+                ring; the ring is primed anew at every y-block's i == 0."""
+                def dmas(blk, slot):
+                    b = _rem(blk + nbx, nbx)
+                    return [pltpu.make_async_copy(
+                        f_ref.at[:, pl.ds(b * bx, bx), pl.ds(sy0, n), :],
+                        win.at[:, pl.ds(slot * bx, bx), pl.ds(dy0, n), :],
+                        sem.at[_rem(slot, 2)])
+                        for f_ref, win in zip(f_refs, wins)
+                        for sy0, dy0, n in ypieces]
+
+                def start(blk, slot):
+                    for d in dmas(blk, slot):
                         d.start()
 
-            def wait(blk, slot):
-                for f_ref, win in zip(f_refs, wins):
-                    for d in block_dmas(f_ref, win, sem, blk, slot):
+                def wait(blk, slot):
+                    for d in dmas(blk, slot):
                         d.wait()
 
-            if nbx <= 2:
-                # all blocks (-1..nbx) fit in the ring: fetch once at i==0
-                @pl.when(i == 0)
-                def _():
-                    for blk in range(-1, nbx + 1):
-                        start(blk, (blk + R) % R)
-                        wait(blk, (blk + R) % R)
-            else:
-                @pl.when(i == 0)
-                def _():
-                    for db in (-1, 0, 1):
-                        start(db, (db + R) % R)
-                        wait(db, (db + R) % R)
-                    start(2, 2)
-
-                @pl.when(i > 0)
-                def _():
-                    wait(i + 1, _rem(i + 1, R))
-
-                    @pl.when(i < nbx - 1)
+                if nbx <= 2:
+                    # all blocks (-1..nbx) fit in the ring: fetch at i==0
+                    @pl.when(i == 0)
                     def _():
-                        start(i + 2, _rem(i + 2, R))
+                        for blk in range(-1, nbx + 1):
+                            start(blk, (blk + R) % R)
+                            wait(blk, (blk + R) % R)
+                else:
+                    @pl.when(i == 0)
+                    def _():
+                        for db in (-1, 0, 1):
+                            start(db, (db + R) % R)
+                            wait(db, (db + R) % R)
+                        start(2, 2)
+
+                    @pl.when(i > 0)
+                    def _():
+                        wait(i + 1, _rem(i + 1, R))
+
+                        @pl.when(i < nbx - 1)
+                        def _():
+                            start(i + 2, _rem(i + 2, R))
+
+            self._each_y_case(j, stream)
 
             sl = [_rem(i + db + R, R) for db in (-1, 0, 1)]
             ws = []
@@ -811,87 +830,58 @@ class StreamingStencil:
                 ws.append(jnp.concatenate([prev, cur, nxt], axis=1))
             self._run_body(ws, scalar_refs, extra_refs, out_refs)
 
-        in_specs, out_specs, out_shapes = self._make_specs(j)
-        return pl.pallas_call(
-            kernel,
-            grid=(nbx,),
-            in_specs=in_specs,
-            out_specs=out_specs,
-            out_shape=out_shapes,
-            scratch_shapes=[
-                pltpu.VMEM((C, R * bx, byw, Z),
-                           self.dtypes.get(n, self.dtype))
-                for n, C in self.win_defs.items()
-            ] + [pltpu.SemaphoreType.DMA((2,))],
-            interpret=self.interpret,
-            compiler_params=_compiler_params(self.interpret),
-        )
+        return self._pallas_call(kernel, R * bx)
 
-    def _build_xhalo(self, j):
+    def _build_xhalo(self):
         """Sharded-x variant: input rows are pre-padded ``(C, X+2wh, Y,
-        Z)``; each program DMAs its own haloed window (double-buffered)."""
-        X, Y, Z = self.lattice_shape
-        h, bx, by = self.wh, self.bx, self.by
-        bxw, byw = bx + 2 * h, by + 2 * HY
-        nbx = X // bx
-        ypieces = self._y_pieces(j)
-
-        def win_dmas(f_ref, win, sem, i, slot):
-            # int32 starts: under x64 a raw program_id product lowers as
-            # i64, which tpu.memref_slice rejects (test_tpu_lowering)
-            x0 = jnp.asarray(i, jnp.int32) * jnp.int32(bx)
-            # _rem also canonicalizes python-int slots to i32: a bare
-            # python index on the semaphore ref lowers as i64 under x64
-            return [pltpu.make_async_copy(
-                f_ref.at[:, pl.ds(x0, bxw), pl.ds(sy0, n), :],
-                win.at[:, pl.ds(slot * bxw, bxw), pl.ds(dy0, n), :],
-                sem.at[_rem(slot, 2)]) for sy0, dy0, n in ypieces]
+        Z)``; each program DMAs its own haloed window (double-buffered
+        within a y-block)."""
+        h, bx = self.wh, self.bx
+        bxw = bx + 2 * h
+        nbx = self.grid[1]
 
         def kernel(*refs):
             f_refs, scalar_refs, extra_refs, out_refs, wins, sem = \
                 self._unpack_refs(refs)
-            i = pl.program_id(0)
+            j, i = pl.program_id(0), pl.program_id(1)
+            slot = _rem(i, 2)
 
-            def start(ii, slot):
-                for f_ref, win in zip(f_refs, wins):
-                    for d in win_dmas(f_ref, win, sem, ii, slot):
+            def stream(ypieces):
+                def dmas(ii, buf):
+                    # int32 starts: under x64 a raw program_id product
+                    # lowers as i64, which tpu.memref_slice rejects
+                    # (test_tpu_lowering)
+                    x0 = jnp.asarray(ii, jnp.int32) * jnp.int32(bx)
+                    # _rem also canonicalizes python-int slots to i32: a
+                    # bare python index on the semaphore ref lowers as
+                    # i64 under x64
+                    return [pltpu.make_async_copy(
+                        f_ref.at[:, pl.ds(x0, bxw), pl.ds(sy0, n), :],
+                        win.at[:, pl.ds(buf * bxw, bxw), pl.ds(dy0, n), :],
+                        sem.at[_rem(buf, 2)])
+                        for f_ref, win in zip(f_refs, wins)
+                        for sy0, dy0, n in ypieces]
+
+                @pl.when(i == 0)
+                def _():
+                    for d in dmas(0, 0):
                         d.start()
 
-            def wait(ii, slot):
-                for f_ref, win in zip(f_refs, wins):
-                    for d in win_dmas(f_ref, win, sem, ii, slot):
-                        d.wait()
+                for d in dmas(i, slot):
+                    d.wait()
 
-            @pl.when(i == 0)
-            def _():
-                start(0, 0)
+                if nbx > 1:
+                    @pl.when(i < nbx - 1)
+                    def _():
+                        for d in dmas(i + 1, _rem(i + 1, 2)):
+                            d.start()
 
-            slot = _rem(i, 2)
-            wait(i, slot)
-
-            if nbx > 1:
-                @pl.when(i < nbx - 1)
-                def _():
-                    start(i + 1, _rem(i + 1, 2))
+            self._each_y_case(j, stream)
 
             ws = [win[:, pl.ds(slot * bxw, bxw), :, :] for win in wins]
             self._run_body(ws, scalar_refs, extra_refs, out_refs)
 
-        in_specs, out_specs, out_shapes = self._make_specs(j)
-        return pl.pallas_call(
-            kernel,
-            grid=(nbx,),
-            in_specs=in_specs,
-            out_specs=out_specs,
-            out_shape=out_shapes,
-            scratch_shapes=[
-                pltpu.VMEM((C, 2 * bxw, byw, Z),
-                           self.dtypes.get(n, self.dtype))
-                for n, C in self.win_defs.items()
-            ] + [pltpu.SemaphoreType.DMA((2,))],
-            interpret=self.interpret,
-            compiler_params=_compiler_params(self.interpret),
-        )
+        return self._pallas_call(kernel, 2 * bxw)
 
     def with_lattice(self, lattice_shape, bx=None, by=None):
         """A new :class:`StreamingStencil` sharing this one's body,
@@ -905,8 +895,8 @@ class StreamingStencil:
             scalar_names=self.scalar_names, dtype=self.dtype,
             bx=bx, by=by, x_halo=self.x_halo, y_halo=self.y_halo,
             interpret=self.interpret, sum_defs=self.sum_defs,
-            dtypes=self.dtypes, assemble=self.assemble,
-            win_halo=self.wh, stages=self.stages, kind=self.kind)
+            dtypes=self.dtypes, win_halo=self.wh, stages=self.stages,
+            kind=self.kind)
 
     # -- invocation --------------------------------------------------------
 
@@ -924,58 +914,27 @@ class StreamingStencil:
         scalar_args = [jnp.asarray(scalars[n], self.dtype).reshape(1)
                        for n in self.scalar_names]
         extra_args = [extras[n] for n in self.extra_defs]
-        out_names = list(self.out_defs)
-        nlat = len(out_names)
-        X, Y, Z = self.lattice_shape
-        nby = Y // self.by
-        # inside someone's program the slab calls are traced as they
-        # are; dispatched eagerly they go as named programs
-        eager = not in_jax_trace()
-        calls = self._programs if eager else self._slabs
+        # inside someone's program the call is traced as it is;
+        # dispatched eagerly it goes as the named program
+        apply = self._apply if in_jax_trace() else self._program
+        return apply(*win_args, *scalar_args, *extra_args)
 
-        if self.assemble == "update" and nby > 1:
-            # slab-at-a-time: each slab output is dead right after its
-            # dynamic_update_slice, so XLA can reuse one slab-sized temp
-            # instead of keeping all nby of them live for a concatenate
-            out = {}
-            for n in out_names:
-                out[n] = jnp.zeros(
-                    self.out_defs[n] + (X, Y, Z),
-                    self.dtypes.get(n, self.dtype))
-            sums = dict.fromkeys(self.sum_defs, 0)
-            for j, call in enumerate(calls):
-                res = call(*win_args, *scalar_args, *extra_args)
-                for k, n in enumerate(out_names):
-                    yax = len(self.out_defs[n]) + 1
-                    out[n] = jax.lax.dynamic_update_slice_in_dim(
-                        out[n], res[k], j * self.by, axis=yax)
-                for k, n in enumerate(self.sum_defs):
-                    sums[n] = sums[n] + res[nlat + k][:self.sum_defs[n], 0]
-            out.update(sums)
-            return out
-
-        slabs = [call(*win_args, *scalar_args, *extra_args)
-                 for call in calls]
-        # a single slab is its own output: no program to join it
-        return (self._join(slabs) if eager and nby > 1
-                else self._assemble(slabs))
-
-    def _assemble(self, slabs):
-        """The full-lattice outputs from the y-slabs' outputs."""
+    def _apply(self, *args):
+        """The call under this kernel's dispatch scope (entered here,
+        because a scope round an eager dispatch does not reach the
+        program), and the sums finished over the y-blocks."""
+        with trace_scope(self._scope):
+            res = self._call(*args)
         nlat = len(self.out_defs)
-        out = {}
-        for k, n in enumerate(self.out_defs):
-            if len(slabs) == 1:
-                out[n] = slabs[0][k]
-            else:
-                yax = len(self.out_defs[n]) + 1  # y of (*lead, X, by, Z)
-                out[n] = jnp.concatenate([s[k] for s in slabs], axis=yax)
-        for k, n in enumerate(self.sum_defs):
-            # each slab's kernel already reduced over its grid programs
-            # (the revisited accumulator tile); finish over y-slabs and
-            # strip the (nt_pad8, LANE) tile padding
+        out = dict(zip(self.out_defs, res))
+        for n, tiles in zip(self.sum_defs, res[nlat:]):
+            # each y-block's tile already holds the sum over its x
+            # programs; finish over the y-blocks in order and strip the
+            # (nt_pad8, LANE) tile padding
             nt = self.sum_defs[n]
-            out[n] = sum(s[nlat + k][:nt, 0] for s in slabs)
+            ntp = tiles.shape[0] // self.grid[0]
+            out[n] = sum(tiles[jj * ntp:jj * ntp + nt, 0]
+                         for jj in range(self.grid[0]))
         return out
 
 

@@ -297,7 +297,6 @@ def advise_shapes(grid_shape, n_devices=1, halo_shape=2,
                     m.notes.append(
                         f"autotuned: bx={entry.get('bx')} "
                         f"by={entry.get('by')} chunk={chunk} "
-                        f"{entry.get('assemble', 'concat')} "
                         f"({entry.get('ms_per_step', float('nan')):.3g}"
                         " ms/step measured) — kernel builds pick this "
                         "over the heuristic")

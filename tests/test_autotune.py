@@ -45,6 +45,8 @@ def _record(store, local_shape=(16, 16, 16), proc_shape=(1, 1, 1),
     digest, comp = autotune.stepper_key(
         "fused_scalar", local_shape, 2, dtype, 2,
         proc_shape=proc_shape)
+    # "assemble": tables written before the kernels became one call
+    # hold the key; a reader ignores it
     winner = {"bx": 4, "by": 8, "chunk": 0, "assemble": "concat",
               "ms_per_step": 1.0, **winner}
     store.record(digest, comp, winner)

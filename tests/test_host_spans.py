@@ -341,7 +341,7 @@ def test_unknown_kernel_kind_is_refused():
         scope.kernel_scope("mystery")
 
 
-def test_fused_and_derivs_kernels_carry_their_kind(make_decomp):
+def test_fused_and_derivs_kernels_carry_their_kind(make_decomp, event_log):
     decomp = make_decomp((1, 1, 1))
     stepper, derivs, stats, state, energy_of, grid_size = _preheat(
         decomp, n=16)
@@ -357,20 +357,25 @@ def test_fused_and_derivs_kernels_carry_their_kind(make_decomp):
     pallas = ps.FiniteDifferencer(decomp, 2, (0.1,) * 3, mode="pallas")
     x = jnp.zeros((2, 16, 16, 128), jnp.float32)
     for name in ("lap", "grad"):
-        # on one chip the operator is applied eagerly: each y-slab call
-        # and the join of the slabs is a program of its own, named
-        # after the operator (they were jit_wrapped / jit_concatenate)
+        # on one chip the operator is applied eagerly: the stencil is
+        # ONE program, named after the operator (it was a jit_wrapped
+        # per y-slab and a jit_concatenate, then jit_lap per y-slab and
+        # a jit_lap_join)
         st = pallas._pallas_op(name, 2, x.dtype, False, x.shape[-3:])
         assert st.kind == name
-        low = st._programs[0].lower(x)
+        low = st._program.lower(x)
         assert obs.has_scope(low, "pallas_stencil_" + name)
         assert _module_name(low) == "jit_" + name
-        two, shape = _stencil_of_kind(name)     # one with two y-slabs
+        two, shape = _stencil_of_kind(name)     # one with two y-blocks
+        assert two.grid == (2, 2)
         y = jnp.zeros(shape, jnp.float32)
-        slabs = [jax.eval_shape(call._jitted, y) for call in two._programs]
-        assert len(slabs) == 2
-        assert _module_name(two._join.lower(slabs)) == f"jit_{name}_join"
+        assert _module_name(two._program.lower(y)) == "jit_" + name
         assert two(y)["out"].shape == shape
+    # applied eagerly, each compiled exactly one program: no join
+    labels = [e["data"]["label"]
+              for e in events.read_events(event_log, kind="compile")
+              if e["data"]["label"].startswith("pallas.")]
+    assert labels == ["pallas.streaming(16, 16, 128)"] * 2, labels
 
 
 def _module_name(lowered):

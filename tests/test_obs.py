@@ -3,9 +3,6 @@ aggregation on the virtual multi-device CPU mesh, trace-scope no-op
 safety under ``JAX_PLATFORMS=cpu``, named-scope presence in a fused-step
 lowering, and memory-analysis capture for one fused kernel."""
 
-import importlib
-import os
-
 import numpy as np
 import pytest
 
@@ -310,17 +307,6 @@ def test_fused_step_counter(make_decomp):
     assert metrics.counter("steps").value == before + 1
 
 
-def test_assemble_update_on_resident_tier_warns(event_log, make_decomp):
-    """Satellite: an explicit assemble='update' landing on the resident
-    tier (where slab assembly is moot) warns and logs an event instead
-    of silently ignoring the request."""
-    decomp = make_decomp((1, 1, 1))
-    with pytest.warns(UserWarning, match="resident"):
-        _small_fused(decomp, n=8, resident=True, assemble="update")
-    evs = events.read_events(event_log, kind="assemble_fallback")
-    assert evs and evs[0]["data"]["requested"] == "update"
-
-
 def test_multigrid_unknown_kwargs_raise(make_decomp):
     """Satellite: a misspelled FullApproximationScheme kwarg (e.g.
     ``defer_error=``) must raise, not be silently swallowed."""
@@ -350,22 +336,6 @@ def test_vmem_limit_read_per_build(monkeypatch):
     monkeypatch.setenv("PYSTELLA_VMEM_LIMIT_MB", "64")
     assert psten._compiler_params(False).vmem_limit_bytes == 64 * 2**20
     assert psten._compiler_params(True) is None  # interpret mode
-
-
-def test_bench_auto_assemble_uses_local_volume(make_decomp):
-    """Satellite: the GW bench's assemble='update' auto-default keys on
-    PER-DEVICE volume, so multi-chip decomps with comfortably-fitting
-    blocks keep the faster concat assembly."""
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    import sys
-    if root not in sys.path:
-        sys.path.insert(0, root)
-    bench = importlib.import_module("bench")
-    single = make_decomp((1, 1, 1))
-    multi = make_decomp((2, 2, 1))
-    assert bench.auto_assemble(single, (512, 512, 512)) == "update"
-    assert bench.auto_assemble(multi, (512, 512, 512)) == "concat"
-    assert bench.auto_assemble(single, (128, 128, 128)) == "concat"
 
 
 def test_multigrid_cycle_emits_event(event_log, make_decomp):

@@ -177,46 +177,143 @@ def test_streaming_multi_output():
         assert np.max(np.abs(got - ref_g)) < 1e-11
 
 
-@interpret_only
-def test_streaming_sum_outputs_and_update_assembly():
-    """``sum_defs`` lattice sums (the revisited accumulator-tile design
-    Mosaic accepts — per-program partial columns do not compile on TPU)
-    and the ``assemble="update"`` slab chain both match the concat path
-    bit-for-bit and the numpy reference."""
-    F, N, h = 2, 16, 1
-    dx = 1.0 / N
-    rng = np.random.default_rng(7)
-    f = jnp.asarray(rng.standard_normal((F, N, N, N)))
+#: lattice of the one-call cases: by in (32, 16, 8) gives 1, 2, 4
+#: y-blocks, bx in (16, 8, 4) gives 1, 2, 4 x-blocks
+_ONE_CALL_SHAPE = (16, 32, 8)
+_ONE_CALL_VARIANTS = ("plain", "x_halo", "y_halo", "xy_halo", "extras",
+                      "win_halo")
 
+
+def _one_call_inputs(variant):
+    """Integer-valued f64 inputs, so every product and every sum below
+    is exact whatever order it is taken in: a blocking that drops,
+    repeats or misplaces a site changes the bits, one that only
+    reorders partial sums does not. Component 0 is a function of y
+    alone (its x and z taps cancel: what is left is the y-window, with
+    the wrap of the first and last y-block); component 1 is random."""
+    from pystella_tpu.ops.pallas_stencil import HY
+    X, Y, Z = _ONE_CALL_SHAPE
+    rng = np.random.default_rng(7)
+    f = np.empty((2, X, Y, Z))
+    f[0] = rng.permutation(Y).astype(float)[None, :, None] - 11
+    f[1] = rng.integers(-8, 9, size=(X, Y, Z))
+    e = rng.integers(-8, 9, size=(2, X, Y, Z)).astype(float)
+    wh = 2 if variant == "win_halo" else 1
+    fin = f
+    if variant in ("x_halo", "xy_halo"):
+        fin = np.concatenate([fin[:, -wh:], fin, fin[:, :wh]], axis=1)
+    if variant in ("y_halo", "xy_halo"):
+        fin = np.concatenate([fin[:, :, -HY:], fin, fin[:, :, :HY]], axis=2)
+    return f, e, fin
+
+
+def _one_call_reference(f, e, variant):
+    def sh(sx=0, sy=0, sz=0):
+        return np.roll(f, (-sx, -sy, -sz), (1, 2, 3))
+    lap = (-6 * f + sh(1) + sh(-1) + sh(0, 1) + sh(0, -1)
+           + sh(0, 0, 1) + sh(0, 0, -1))
+    if variant == "win_halo":
+        lap = lap + sh(2) - 3 * sh(-2) + 5 * sh(0, 2) - 7 * sh(0, -2)
+    if variant == "extras":
+        lap = 3.0 * lap + e
+    sums = np.array([(f[0]**2).sum(), (f[1]**2).sum(),
+                     lap[0].sum(), lap[1].sum()])
+    return lap, sums
+
+
+def _one_call_stencil(variant, bx, by):
     def body(taps, extras, scalars):
-        lap = 3 * _lap_coefs[1][0] / dx**2 * taps()
-        for s, c in _lap_coefs[1].items():
-            if s:
-                lap = lap + c / dx**2 * (
-                    taps(s) + taps(-s) + taps(0, s) + taps(0, -s)
-                    + taps(0, 0, s) + taps(0, 0, -s))
         fv = taps()
-        sums = ([jnp.sum(fv[i] * fv[i]) for i in range(F)]
-                + [jnp.sum(lap[0])])
+        lap = (-6 * fv + taps(1) + taps(-1) + taps(0, 1) + taps(0, -1)
+               + taps(0, 0, 1) + taps(0, 0, -1))
+        if variant == "win_halo":
+            # reaches the widened (chunk) window: 2h in x and in y
+            lap = (lap + taps(2) - 3 * taps(-2) + 5 * taps(0, 2)
+                   - 7 * taps(0, -2))
+        if variant == "extras":
+            lap = scalars["c"] * lap + extras["e"]
+        sums = ([jnp.sum(fv[i] * fv[i]) for i in range(2)]
+                + [jnp.sum(lap[i]) for i in range(2)])
         return {"lap": lap, "sums": sums}
 
-    kw = dict(dtype=jnp.float64, bx=4, by=8, sum_defs={"sums": F + 1})
-    outs = {mode: StreamingStencil((N, N, N), F, h, body, {"lap": (F,)},
-                                   assemble=mode, **kw)(f)
-            for mode in ("concat", "update")}
-    fn = np.asarray(f)
-    ref_lap = _numpy_lap(fn, _lap_coefs[1], dx)
-    ref_sums = np.array([(fn[0]**2).sum(), (fn[1]**2).sum(),
-                         ref_lap[0].sum()])
-    for mode, out in outs.items():
-        assert np.max(np.abs(np.asarray(out["lap"]) - ref_lap)) < 1e-11
-        assert np.allclose(np.asarray(out["sums"]), ref_sums,
-                           rtol=1e-12), mode
-    # the two assembly modes are bit-identical
-    assert np.array_equal(np.asarray(outs["concat"]["lap"]),
-                          np.asarray(outs["update"]["lap"]))
-    assert np.array_equal(np.asarray(outs["concat"]["sums"]),
-                          np.asarray(outs["update"]["sums"]))
+    kw = {}
+    if variant == "extras":
+        kw.update(extra_defs={"e": (2,)}, scalar_names=("c",))
+    if variant == "win_halo":
+        kw.update(win_halo=2, stages=4)
+    return StreamingStencil(
+        _ONE_CALL_SHAPE, 2, 1, body, {"lap": (2,)}, dtype=jnp.float64,
+        bx=bx, by=by, sum_defs={"sums": 4},
+        x_halo=variant in ("x_halo", "xy_halo"),
+        y_halo=variant in ("y_halo", "xy_halo"), **kw)
+
+
+_ONE_CALL_RESULTS = {}
+
+
+def _one_call_result(variant, bx, by):
+    key = (variant, bx, by)
+    if key not in _ONE_CALL_RESULTS:
+        _, e, fin = _one_call_inputs(variant)
+        st = _one_call_stencil(variant, bx, by)
+        call = {}
+        if variant == "extras":
+            call = dict(scalars={"c": 3.0}, extras={"e": jnp.asarray(e)})
+        out = st(jnp.asarray(fin), **call)
+        _ONE_CALL_RESULTS[key] = (
+            st.grid, np.asarray(out["lap"]), np.asarray(out["sums"]))
+    return _ONE_CALL_RESULTS[key]
+
+
+@interpret_only
+@pytest.mark.parametrize("variant", _ONE_CALL_VARIANTS)
+@pytest.mark.parametrize("nbx", [1, 2, 4])
+@pytest.mark.parametrize("nby", [1, 2, 4])
+def test_streaming_one_call_blockings_agree(nby, nbx, variant):
+    """The kernel is one ``pallas_call`` over a ``(nby, nbx)`` grid that
+    writes each block where it lives: for every blocking, with either or
+    both halo variants, with extras and with the widened (chunk) window,
+    the outputs and the ``sum_defs`` lattice sums (one revisited
+    accumulator tile per y-block, finished over y outside the kernel —
+    per-program partial columns do not compile on TPU) are bit-identical
+    to the single-program call's and to the numpy reference."""
+    X, Y, _ = _ONE_CALL_SHAPE
+    grid, lap, sums = _one_call_result(variant, X // nbx, Y // nby)
+    assert grid == (nby, nbx)
+    f, e, _ = _one_call_inputs(variant)
+    ref_lap, ref_sums = _one_call_reference(f, e, variant)
+    assert np.array_equal(lap, ref_lap)
+    assert np.array_equal(sums, ref_sums)
+    _, lap1, sums1 = _one_call_result(variant, X, Y)
+    assert np.array_equal(lap, lap1)
+    assert np.array_equal(sums, sums1)
+
+
+@interpret_only
+def test_streaming_sums_keep_their_order():
+    """Float sums come in the order they always did: x-blocks added in
+    program order into their y-block's tile, y-blocks finished in order
+    outside the kernel. Rebuilt here in numpy, bit for bit."""
+    F, N, bx, by = 1, 16, 4, 8
+    rng = np.random.default_rng(11)
+    f = rng.standard_normal((F, N, N, N))
+
+    def body(taps, extras, scalars):
+        return {"out": taps(), "s": [jnp.sum(taps()[0])]}
+
+    st = StreamingStencil((N, N, N), F, 1, body, {"out": (F,)},
+                          dtype=jnp.float64, bx=bx, by=by,
+                          sum_defs={"s": 1})
+    got = np.asarray(st(jnp.asarray(f))["s"])[0]
+    total = 0
+    for j in range(N // by):
+        tile = None
+        for i in range(N // bx):
+            blk = np.asarray(jnp.sum(jnp.asarray(
+                f[0, i * bx:(i + 1) * bx, j * by:(j + 1) * by])))
+            tile = blk if tile is None else tile + blk
+        total = total + tile
+    assert got == total
 
 
 @interpret_only

@@ -161,8 +161,8 @@ def test_derivs_kernels_are_named_by_kind(v5e, monkeypatch):
                                  sharding=decomp.sharding(1))
         for name in ("lap", "grad"):
             op = fd._pallas_op(name, 2, jnp.dtype("float32"), False, grid)
-            if ndev == 1:       # the stencil itself: take one slab call
-                fn = op._programs[0]._jitted
+            if ndev == 1:       # the stencil itself: its one program
+                fn = op._program._jitted
             else:               # a closure over the sharded program
                 fn = op.__defaults__[0]._jitted
             hlo = fn.trace(x).lower(

@@ -48,10 +48,18 @@ def test_streaming_ring_lowers():
     lower_tpu(lambda x: st(x), f)
 
 
-def test_streaming_sums_and_update_assembly_lower():
-    """The revisited sum-accumulator tile and the update-slice slab
-    assembly — the exact shapes the first hardware session rejected
-    (pre-fix) and the leg-3 coupled config relies on."""
+def _has_aligned_dynamic_offset(st, *args):
+    """Does the kernel hold a ``multiple_of``: the declaration Mosaic
+    needs for a dynamic sublane DMA offset (the kernel travels in the
+    lowered text as bytecode, so the jaxpr is asked)?"""
+    return "multiple_of" in str(jax.make_jaxpr(lambda *a: st(*a))(*args))
+
+
+def test_streaming_sums_lower():
+    """The per-y-block sum-accumulator tile (the block shape the first
+    hardware session rejected, pre-fix) on a 2-D grid with middle
+    y-blocks: their window offset ``j * by - HY`` is dynamic and has to
+    reach Mosaic declared 8-aligned."""
     def body(taps, extras, scalars):
         fv = taps()
         out = _lap_body(taps, extras, scalars)
@@ -59,25 +67,32 @@ def test_streaming_sums_and_update_assembly_lower():
                        + [jnp.sum(out["lap"][0])])
         return out
 
-    for assemble in ("concat", "update"):
-        st = StreamingStencil((16, 16, LANE), 2, 1, body, {"lap": (2,)},
-                              dtype=jnp.float32, bx=4, by=8,
-                              sum_defs={"sums": 3}, interpret=False,
-                              assemble=assemble)
-        f = jnp.zeros((2, 16, 16, LANE), jnp.float32)
-        lower_tpu(lambda x, st=st: st(x), f)
+    st = StreamingStencil((16, 32, LANE), 2, 1, body, {"lap": (2,)},
+                          dtype=jnp.float32, bx=4, by=8,
+                          sum_defs={"sums": 3}, interpret=False)
+    assert st.grid == (4, 4)
+    f = jnp.zeros((2, 16, 32, LANE), jnp.float32)
+    lowered = lower_tpu(lambda x: st(x), f)
+    assert lowered.as_text().count("tpu_custom_call") == 1
+    assert _has_aligned_dynamic_offset(st, f)
 
 
-def test_streaming_halo_variants_lower():
+@pytest.mark.parametrize("mode", ["x", "y"])
+def test_streaming_halo_variants_lower(mode):
+    """Both halo variants on a 2-D grid with ``nby > 2``: with ``y_halo``
+    every y-block's window is one piece at the dynamic ``j * by``."""
     h = 1
-    for mode in ("x", "y"):
-        st = StreamingStencil(
-            (16, 16, LANE), 1, h, _lap_body, {"lap": (1,)},
-            dtype=jnp.float32, bx=4, by=8, interpret=False,
-            x_halo=(mode == "x"), y_halo=(mode == "y"))
-        shape = ((1, 16 + 2 * h, 16, LANE) if mode == "x"
-                 else (1, 16, 16 + 16, LANE))
-        lower_tpu(lambda x, st=st: st(x), jnp.zeros(shape, jnp.float32))
+    st = StreamingStencil(
+        (16, 32, LANE), 1, h, _lap_body, {"lap": (1,)},
+        dtype=jnp.float32, bx=4, by=8, interpret=False,
+        x_halo=(mode == "x"), y_halo=(mode == "y"))
+    assert st.grid == (4, 4)
+    shape = ((1, 16 + 2 * h, 32, LANE) if mode == "x"
+             else (1, 16, 32 + 16, LANE))
+    x = jnp.zeros(shape, jnp.float32)
+    lowered = lower_tpu(lambda x: st(x), x)
+    assert lowered.as_text().count("tpu_custom_call") == 1
+    assert _has_aligned_dynamic_offset(st, x)
 
 
 def test_resident_rolls_lower():
@@ -122,6 +137,31 @@ def test_fused_pair_step_lowers():
     lower_tpu(lambda st: stepper.step(st, 0.0, stepper.dt, args), state)
 
 
+def test_fused_pair_step_is_one_call_per_kernel():
+    """A kernel writes its blocks where the output lives: the step
+    program of a pair step over four y-blocks holds one custom call per
+    kernel call (two stage pairs and the odd fifth stage; it was one
+    per y-slab) and no op that puts y-slabs together into a lattice
+    array."""
+    import re
+    grid_shape = (16, 32, LANE)
+    stepper, _ = _preheat_stepper(grid_shape, bx=4, by=8, pair_bx=4,
+                                  pair_by=8)
+    assert stepper._pair_st.grid == (4, 4)
+    state = _scalar_state(grid_shape, np.random.default_rng(1))
+    args = {"a": np.float32(1.0), "hubble": np.float32(0.1)}
+    text = lower_tpu(
+        lambda st: stepper.step(st, 0.0, stepper.dt, args),
+        state).as_text()
+    assert text.count("stablehlo.custom_call @tpu_custom_call") == 3
+    lattice = "x".join(str(n) for n in grid_shape) + "xf32>"
+    joins = [line for line in text.splitlines()
+             if re.search(r"stablehlo\.(dynamic_update_slice|concatenate)",
+                          line)
+             and line.rstrip().endswith(lattice)]
+    assert not joins, joins[:3]
+
+
 def test_coupled_pair_chunk_lowers():
     """The energy-coupled deferred-drag pair path (esums kernels) — the
     config that failed Mosaic in the first round-5 hardware session."""
@@ -140,13 +180,12 @@ def test_coupled_pair_chunk_lowers():
     lower_tpu(chunk, state)
 
 
-def test_gw_bf16_carry_update_assembly_lowers():
+def test_gw_bf16_carry_lowers():
     """The 512^3-fits-one-chip GW configuration in miniature: bf16
-    carries + update-slice slab assembly."""
+    carries."""
     grid_shape = (16, 16, LANE)
     stepper, _ = _preheat_stepper(grid_shape, cls="gw",
-                                  carry_dtype=jnp.bfloat16,
-                                  assemble="update")
+                                  carry_dtype=jnp.bfloat16)
     rng = np.random.default_rng(3)
     state = _scalar_state(grid_shape, rng)
     state["hij"] = jnp.zeros((6,) + grid_shape, jnp.float32)
