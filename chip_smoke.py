@@ -16,8 +16,9 @@ streaming tier with the blocking it took), the HDF5 series, the
 checkpoint read back, the Friedmann constraint, the chip's own
 ``peak_bytes_in_use`` — two steps of the fused stepper against the
 plain ``LowStorageRK54`` + XLA ``FiniteDifferencer`` reference from one
-seeded state (``bench.fused_parity``), and the transform pair's round
-trip. With four chips it repeats all of
+seeded state (``bench.fused_parity``), the transform pair's round
+trip, and the histogram and one spectrum of a seeded field against
+numpy's float64 binning of the same field. With four chips it repeats all of
 it on a ``(2, 2, 1)`` mesh and checks the work is spread over them.
 
 It exits non-zero, before building anything, unless jax finds a TPU. Its
@@ -285,7 +286,48 @@ def run_leg(grid_shape, proc_shape, workdir):
             f"{PARITY_BOUND:g}")
     require(bool((back == again).all()),
             f"{label}: two inverse transforms of one input differ")
-    del x, fk, back, again
+    del back, again
+
+    # 5. the binning behind the histogram and every spectrum (the
+    # one-hot contraction, ops.histogram) against numpy's float64
+    # binning of the same field, fetched: counts equal, sums to 1e-5
+    num_bins = 1000
+    lo, hi = float(x.min()), float(x.max())
+    pos = jax.jit(lambda f: (f - lo) / (hi - lo) * num_bins)(x)
+    hists = ps.Histogrammer(
+        decomp, {"counts": (ps.Field("f"), 1),
+                 "sums": (ps.Field("f"), ps.Field("w"))}, num_bins)(
+        f=pos, w=x * x)
+    index = np.clip(np.floor(np.asarray(pos)), 0, num_bins - 1).astype(
+        np.int64).ravel()
+    xh = np.asarray(x, np.float64).ravel()
+    counts = np.bincount(index, minlength=num_bins)
+    sums = np.bincount(index, weights=xh * xh, minlength=num_bins)
+    require(np.array_equal(hists["counts"], counts),
+            f"{label}: histogram counts differ from numpy's in "
+            f"{int((hists['counts'] != counts).sum())} of {num_bins} bins")
+    hist_gap = float(np.max(np.abs(hists["sums"] - sums)
+                            / np.where(sums == 0, 1.0, sums)))
+    del pos, index, xh
+    lattice = ps.Lattice(grid_shape, (5.0,) * 3, dtype=np.float32)
+    spectra = ps.PowerSpectra(decomp, fft, lattice.dk, lattice.volume)
+    power = spectra.bin_power(fk, k_power=3)
+    weights = (np.asarray(spectra._counts, np.float64)
+               * np.asarray(spectra._kmags, np.float64)**3
+               * np.abs(np.asarray(fk).astype(np.complex128))**2)
+    expected = np.bincount(
+        np.asarray(spectra._bin_idx).ravel(), weights=weights.ravel(),
+        minlength=spectra.num_bins) / spectra.bin_counts
+    spec_gap = float(np.max(np.abs(power - expected)
+                            / np.where(expected == 0, 1.0, expected)))
+    say(f"{label}: binning at {grid_shape} against numpy's float64: "
+        f"{num_bins}-bin counts equal, weighted sums {hist_gap:.3e}, "
+        f"{spectra.num_bins}-bin spectrum {spec_gap:.3e} "
+        f"(bound {PARITY_BOUND:g})")
+    require(max(hist_gap, spec_gap) <= PARITY_BOUND,
+            f"{label}: binned sums {hist_gap:.3e}, {spec_gap:.3e} over "
+            f"{PARITY_BOUND:g}")
+    del x, fk, weights
 
     # the chip's own memory account (None on a backend without one —
     # which would mean this did not run on the chip)

@@ -2,12 +2,15 @@
 
 TPU-native counterpart of /root/reference/pystella/fourier/spectra.py:29-419.
 The reference bins ``|f(k)|²`` with an atomic histogram kernel plus MPI
-allreduce; here the binned sums are per-device ``jnp.bincount``s inside
-``shard_map`` reduced with ``lax.psum`` (deterministic, no atomics). All
-conventions are preserved: bin index ``round(|k| / bin_width)``, r2c
-double-count weighting (2 except on the ``kz ∈ {0, Nyquist}`` planes,
-spectra.py:81-87,112-119), bin-count normalization, and the overall
-``1/(2π²V) · (d³x)²`` normalization (spectra.py:74-75).
+allreduce; here the binned sums are the one-hot contraction of
+:mod:`pystella_tpu.ops.histogram` on each device's shard inside
+``shard_map`` (deterministic, no atomics): float32 partials of at most
+2**18 modes each, fetched unreduced and summed on the host in float64 —
+there is no device-side ``psum``. All conventions are preserved: bin
+index ``round(|k| / bin_width)``, r2c double-count weighting (2 except
+on the ``kz ∈ {0, Nyquist}`` planes, spectra.py:81-87,112-119),
+bin-count normalization, and the overall ``1/(2π²V) · (d³x)²``
+normalization (spectra.py:74-75).
 """
 
 from __future__ import annotations
@@ -103,7 +106,7 @@ class PowerSpectra:
             weights_impl, label="fourier.spectra_bin_weights")
         self._weights = lambda fk, k_power: jitted(
             fk, k_power, self._counts, self._kmags, self._bin_idx)
-        #: one-dispatch (transform + weights + shard-local bincount)
+        #: one-dispatch (transform + weights + shard-local binning)
         #: spectrum programs, keyed (outer_shape, k_power) — the pencil
         #: tier's end-to-end path (built lazily in _spectrum_fn)
         self._spectrum_cache = {}
@@ -112,8 +115,8 @@ class PowerSpectra:
         """The fused pencil-tier spectrum program: ONE jitted dispatch
         from the position-space field to per-device partial bin sums —
         the distributed transform (explicit all_to_all transposes), the
-        ``counts·|k|^p·|f(k)|²`` weighting, and the chunked per-device
-        bincount all in one module, shard-local throughout; only the
+        ``counts·|k|^p·|f(k)|²`` weighting, and the per-device binning
+        kernel all in one module, shard-local throughout; only the
         ``num_bins``-scalar partials leave the devices (the binning
         "all-reduce" finalized on host in wide precision). The sharded
         k-constants ride as arguments, not captures (multi-controller
@@ -168,7 +171,7 @@ class PowerSpectra:
     def bin_power(self, fk, queue=None, k_power=3, allocator=None):
         """Unnormalized binned power spectrum of a momentum-space field,
         weighted by ``|k|**k_power`` (reference spectra.py:140-175). Outer
-        axes batch through a single distributed bincount."""
+        axes batch through a single distributed binning pass."""
         from pystella_tpu.ops.histogram import weighted_bincount
         if isinstance(fk, np.ndarray):
             fk = self.fft.shard_k(fk)
@@ -188,7 +191,7 @@ class PowerSpectra:
         On the pencil tier the whole thing — transform, weighting,
         binning — is ONE fused device dispatch (see
         :meth:`spectrum_program`); the DFT tiers keep their separate
-        transform/weights/bincount dispatches byte-for-byte."""
+        transform/weights/binning dispatches."""
         with host_span("spectra"):
             if isinstance(fx, np.ndarray):
                 fx = self.decomp.shard(np.asarray(fx, self.fft.dtype))
@@ -204,7 +207,7 @@ class PowerSpectra:
         returns shape ``vector.shape[:-4] + (2, num_bins)``
         (reference spectra.py:228-271, which loops components host-side;
         here every outer slice batches through ONE transform, one
-        projection, and one distributed bincount)."""
+        projection, and one distributed binning pass)."""
         vec_k = self.fft.dft(vector)            # (outer..., 3, kshape)
         vec_k = jnp.moveaxis(vec_k, -4, 0)      # components lead
         plus, minus = projector.vec_to_pol(vec_k)

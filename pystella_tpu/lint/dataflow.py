@@ -102,8 +102,13 @@ CARRY_SCOPES = ("carry_quantize",)
 #: Narrowing here is sanctioned because the stencil build funnel
 #: (``ops/fused.py _build_stencil``) routes every carry cast through
 #: ``_carry_cast``, and rule 2 still rejects any narrow-typed
-#: arithmetic the kernel might try.
-KERNEL_SCOPES = ("pallas_stencil", "pallas_resident_stencil")
+#: arithmetic the kernel might try. The binning kernel
+#: (``ops/histogram.py``, ``pallas_bincount``) narrows only the operands
+#: of a product it accumulates in f32: one-hot entries, exact in bf16,
+#: and the three bf16 pieces of an f32 weight, together exact to its 24
+#: bits — an operand format of the MXU, not a precision loss.
+KERNEL_SCOPES = ("pallas_stencil", "pallas_resident_stencil",
+                 "pallas_bincount")
 
 #: sub-f32 float element types: legal as state/carry storage, never as
 #: an accumulator

@@ -307,7 +307,7 @@ def build_sharded_spectra():
     module from the position-space fields to per-device partial bin
     sums — the distributed r2c transform (explicit all_to_all
     transposes), the ``counts·|k|³·|f(k)|²`` weighting, and the
-    chunked shard-local bincount. Auditing it pins the acceptance
+    shard-local binning kernel. Auditing it pins the acceptance
     contract of the spectral tier: the compiled module's only
     collectives are the allowlisted transposes — no all-gather of a
     field-sized operand anywhere in the spectra program — and no f64

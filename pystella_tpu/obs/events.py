@@ -193,6 +193,10 @@ for _name, _help in (
     ("block_choice", "a fused kernel build chose its blocking "
                      "(bx/by/grid/win_halo + source: autotune table hit, "
                      "choose_blocks heuristic, env override, explicit)"),
+    ("bincount_plan", "a binning program was built: what the one-hot "
+                      "contraction took from the shapes (hi x lo "
+                      "factorisation, tile, steps and partials, MXU "
+                      "passes, weights' dtype)"),
     ("kernel_fallback", "a fused kernel tier degraded down the ladder "
                         "(chunk -> pair -> single), with the reason"),
     ("kernel_tier", "the kernel tier a fused stepper actually "

@@ -107,6 +107,11 @@ for _name in (
     "pallas_stencil_lap", "pallas_stencil_grad",
     "pallas_stencil_grad_lap", "pallas_stencil_pdx",
     "pallas_stencil_pdy", "pallas_stencil_pdz", "pallas_stencil_div",
+    # the binning kernel behind every histogram and spectrum
+    # (ops.histogram): a one-hot contraction on the MXU. Not a
+    # pallas_stencil_* name: it streams no lattice windows, so no
+    # stencil byte rule counts it
+    "pallas_bincount",
     # the whole-RK-chunk (temporal blocking) kernel dispatch and the
     # persistent autotuner's timed candidate probes (ops.autotune)
     "chunk_stage", "autotune_probe",

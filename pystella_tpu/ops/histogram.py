@@ -3,21 +3,35 @@
 TPU-native counterpart of /root/reference/pystella/histogram.py:33-350. The
 reference uses a two-level atomic scatter kernel (workgroup-local atomics,
 barrier, global atomic flush) followed by an MPI allreduce of the host copy.
-XLA has no atomics; instead each device computes local ``jnp.bincount``s
-over its shard inside ``shard_map`` — deterministic by construction (no
-write-race silencing needed, cf. histogram.py:111-112).
+A TPU has no atomics, and a scatter there is serialised; a bin sum is
+instead a contraction: with the bin index split as ``bin = hi * 128 + lo``,
+
+    ``H[hi, lo] = sum_n (w_n * [hi_n == hi]) * [lo_n == lo]``
+
+is a matrix product over the lattice, ``(Hi x n) . (128 x n)^T``, which
+the MXU does. One Pallas kernel (:func:`_onehot_bincount`, scope
+``pallas_bincount``) builds both one-hot operands tile by tile in VMEM
+(they never reach HBM), accumulates ``(Hi, 128)`` in float32 and writes
+one partial per run of grid steps, inside ``shard_map`` over each device's
+shard: deterministic by construction (no write-race silencing needed, cf.
+histogram.py:111-112). There is one path; what it chose from the shapes
+goes out as a ``bincount_plan`` event per built program.
 
 Accumulation precision (production lattices exceed f32's 2**24 integer
-range — a 512**3 grid has 1.3e8 sites, so a single bin can overflow exact
-f32 counting even though TPUs have no native f64): each device's flat shard
-is split into chunks of at most 2**22 elements, each chunk is bincounted
-separately (int32 for pure counts, f32 for weighted sums — every per-chunk
-partial stays exactly representable), the per-device per-chunk partials are
-returned without any device-side reduction, and the final sum over chunks
-and devices happens on the host in int64/float64. Counts are therefore
-exact at any scale regardless of ``jax_enable_x64`` (matching the
-reference's f64 device accumulation, histogram.py:199-206); weighted sums
-carry at most one f32 rounding per 2**22-element chunk.
+range: a 512**3 grid has 1.3e8 sites, so a single bin can overflow exact
+f32 counting even though TPUs have no native f64): one-hot entries are
+exact in bfloat16; a float32 weight is not, so it is split into three
+bfloat16 pieces stacked along ``Hi`` (one pass of the MXU, every product
+exact to 24 bits; a float64 weight, under ``jax_enable_x64`` on a CPU,
+takes one float64 product). A partial of unit-weight counts covers at
+most 2**22 elements (0/1 products summed in f32, exact, written as int32),
+a weighted partial at most 2**18 (so the f32 accumulation inside one is
+short); the per-device partials are returned without any device-side
+reduction, and the final sum over partials and devices happens on the host
+in int64/float64. Counts are therefore exact at any scale regardless of
+``jax_enable_x64`` (matching the reference's f64 device accumulation,
+histogram.py:199-206); weighted sums carry f32 rounding within one
+2**18-element partial only.
 """
 
 from __future__ import annotations
@@ -26,12 +40,15 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 import weakref
 
 from pystella_tpu import field as _field
+from pystella_tpu.obs import events as _events
 from pystella_tpu.obs import memory as _obs_memory
-from pystella_tpu.obs.scope import host_span
+from pystella_tpu.obs.scope import host_span, trace_scope
 from pystella_tpu.ops.reduction import Reduction
 
 __all__ = ["Histogrammer", "FieldHistogrammer", "weighted_bincount",
@@ -42,9 +59,16 @@ __all__ = ["Histogrammer", "FieldHistogrammer", "weighted_bincount",
 _bincount_cache = weakref.WeakKeyDictionary()
 
 
-#: largest per-chunk element count; keeps every per-chunk partial (int32
-#: count or f32 weighted sum of same-order values) exactly representable
-_CHUNK = 1 << 22
+#: the low factor of a bin index: one lane tile, the width of the MXU
+_LO = 128
+#: elements in a row of the flattened shard (the contraction length of
+#: one product) and rows in a sublane tile
+_LANES, _SUB = 512, 8
+#: most rows of one grid step: 2**16 elements
+_TILE_ROWS = 128
+#: most elements of one partial, by ``weighted``: unit-weight counts stay
+#: exact in f32 far beyond it, a weighted partial is kept short
+_PARTIAL = {False: 1 << 22, True: 1 << 18}
 
 
 def _flat_names(lattice_names):
@@ -63,17 +87,153 @@ def _flat_names(lattice_names):
     return tuple(out)
 
 
+def _plan(num_bins, n, dtype):
+    """What the kernel takes from the shapes: ``n`` elements a slice into
+    ``num_bins`` bins, weights of ``dtype`` (``None``: counts)."""
+    hi = -(-num_bins // _LO)
+    # a float32 weight goes as three bfloat16 pieces stacked along hi
+    passes = 3 if dtype == jnp.float32 else 1
+    rows = -(-n // _LANES)
+    tile = min(_TILE_ROWS, -(-rows // _SUB) * _SUB)
+    blocks = -(-rows // tile)
+    steps = max(1, min(_PARTIAL[dtype is not None] // (tile * _LANES),
+                       blocks))
+    return {"hi": hi, "lo": _LO, "passes": passes,
+            # the stacked operand's rows, whole bfloat16 sublane tiles
+            "stack": -(-passes * hi // 16) * 16,
+            "rows": rows, "tile": (tile, _LANES), "blocks": blocks,
+            "steps": steps, "partials": -(-blocks // steps)}
+
+
+def _onehot_bincount(b, w, num_bins, interpret):
+    """Partial histograms of ``b`` (int32, ``(nouter, n)``, bins in
+    ``[0, num_bins)`` of each outer slice; anything negative is counted
+    nowhere) with weights ``w`` (same shape, float32 or float64; any
+    other dtype is taken as float32) or unit weights (``None``):
+    ``(partials, nouter * num_bins)``, int32 for counts. The
+    contraction of the module docstring as one ``pallas_call`` over
+    ``(slice, partial, step)``."""
+    nouter, n = b.shape
+    if w is not None and w.dtype not in (jnp.float32, jnp.float64):
+        w = w.astype(jnp.float32)
+    dtype = None if w is None else w.dtype
+    plan = _plan(num_bins, n, dtype)
+    hi, passes, stack = plan["hi"], plan["passes"], plan["stack"]
+    rows, (R, C) = plan["rows"], plan["tile"]
+    blocks, steps, partials = plan["blocks"], plan["steps"], plan["partials"]
+    _events.emit("bincount_plan", num_bins=num_bins, nouter=nouter,
+                 elements=n, weights=None if w is None else str(dtype),
+                 **plan)
+    # operands: bfloat16 where every entry is exact in it
+    op_dtype = dtype if dtype == jnp.float64 else jnp.bfloat16
+    acc_dtype = dtype if dtype == jnp.float64 else jnp.float32
+    precision = (jax.lax.Precision.HIGHEST if dtype == jnp.float64
+                 else None)
+    one, zero = np.ones((), acc_dtype), np.zeros((), acc_dtype)
+
+    def rows_of(a, fill):
+        pad = rows * C - n
+        if pad:
+            a = jnp.pad(a, ((0, 0), (0, pad)), constant_values=fill)
+        return a.reshape(nouter, rows, C)
+
+    args = [rows_of(b, -1)] + ([] if w is None else [rows_of(w, 0)])
+
+    def kernel(*refs):
+        b_ref, out_ref = refs[0], refs[-1]
+        q, s = pl.program_id(1), pl.program_id(2)
+
+        @pl.when(s == 0)
+        def _():
+            out_ref[...] = jnp.zeros_like(out_ref)
+
+        # row j of the stacked operand holds piece j // hi of bin row
+        # j % hi; rows past the last piece match nothing
+        j = jax.lax.broadcasted_iota(jnp.int32, (stack, C), 0)
+        piece = jax.lax.div(j, np.int32(hi))  # i32 under x64 too
+        stack_hi = jnp.where(j < passes * hi, j - piece * hi, np.int32(-2))
+        first, second = piece == 0, piece == 1
+        lo_id = jax.lax.broadcasted_iota(jnp.int32, (_LO, C), 0)
+        # rows of this block that lie inside the array (the last block
+        # is ragged; one past it is read again and counted nowhere)
+        left = rows - (q * steps + s) * R
+        row_id = jax.lax.broadcasted_iota(jnp.int32, (_SUB, C), 0)
+
+        def eight_rows(r8, acc):
+            r0 = pl.multiple_of(r8 * _SUB, _SUB)
+            b8 = b_ref[pl.ds(r0, _SUB), :]
+            hi8 = jnp.where(row_id + r0 < left, b8 >> 7, np.int32(-1))
+            lo8 = b8 & (_LO - 1)
+            if w is not None:
+                w8 = refs[1][pl.ds(r0, _SUB), :]
+            if passes == 3:
+                # w8 = w0 + w1 + w2 to its 24 bits, each exact in bf16
+                w0 = w8.astype(jnp.bfloat16).astype(jnp.float32)
+                w1 = (w8 - w0).astype(jnp.bfloat16).astype(jnp.float32)
+                w2 = ((w8 - w0) - w1).astype(jnp.bfloat16).astype(
+                    jnp.float32)
+            for i in range(_SUB):
+                def along(a, nrows):
+                    return jnp.broadcast_to(a[i:i + 1], (nrows, C))
+                sel = along(hi8, stack) == stack_hi
+                if w is None:
+                    a = jnp.where(sel, one, zero)
+                elif passes == 3:
+                    a = jnp.where(sel, jnp.where(
+                        first, along(w0, stack), jnp.where(
+                            second, along(w1, stack), along(w2, stack))),
+                        zero)
+                else:
+                    a = jnp.where(sel, along(w8, stack), zero)
+                onehot = jnp.where(along(lo8, _LO) == lo_id, one, zero)
+                acc = acc + jax.lax.dot_general(
+                    a.astype(op_dtype), onehot.astype(op_dtype),
+                    (((1,), (1,)), ((), ())), precision=precision,
+                    preferred_element_type=acc_dtype)
+            return acc
+
+        out_ref[...] += jax.lax.fori_loop(
+            jnp.int32(0), jnp.int32(R // _SUB), eight_rows,
+            jnp.zeros((stack, _LO), acc_dtype))
+
+    in_spec = pl.BlockSpec(
+        (None, R, C),
+        lambda o, q, s: (o, jnp.minimum(q * steps + s, blocks - 1), 0))
+    with trace_scope("pallas_bincount"):
+        out = pl.pallas_call(
+            kernel,
+            grid=(nouter, partials, steps),
+            in_specs=[in_spec] * len(args),
+            out_specs=pl.BlockSpec((None, None, stack, _LO),
+                                   lambda o, q, s: (o, q, 0, 0)),
+            out_shape=jax.ShapeDtypeStruct(
+                (nouter, partials, stack, _LO), acc_dtype),
+            interpret=interpret,
+            compiler_params=None if interpret else pltpu.CompilerParams(
+                dimension_semantics=("parallel", "parallel", "arbitrary")),
+        )(*args)
+    # the pieces' sums, smallest first; then bins in order, slices in order
+    out = out[:, :, :passes * hi].reshape(nouter, partials, passes, hi * _LO)
+    out = sum(out[:, :, p] for p in reversed(range(passes)))[..., :num_bins]
+    if w is None:
+        out = out.astype(jnp.int32)
+    return jnp.moveaxis(out, 1, 0).reshape(partials, nouter * num_bins)
+
+
 def bincount_core(decomp, outer_shape, num_bins, weighted,
                   lattice_names=None):
-    """The UNJITTED shard_map-wrapped local bincount (cached): callers
+    """The UNJITTED shard_map-wrapped local binning (cached): callers
     that fuse binning into a larger jitted program (the pencil-tier
     spectra path) compose this; :func:`_bincount_fn` wraps it in its
-    own jit for standalone dispatch. Returns per-device, per-chunk
-    partial histograms stacked along axis 0 (the host finalizes in
-    wide precision). ``lattice_names`` are the per-lattice-axis mesh
-    axis names of the input layout (default: the decomposition's
-    position-space layout; k-space callers pass their own — entries
-    may be combined-axis tuples)."""
+    own jit for standalone dispatch. Takes ``bins`` (int32, ``outer +
+    lattice``, each in ``[0, num_bins)``) and, if ``weighted``, weights
+    of the same shape; returns per-device partial histograms of
+    ``num_bins * prod(outer_shape)`` bins stacked along axis 0 (the
+    host finalizes in wide precision). ``lattice_names`` are the
+    per-lattice-axis mesh axis names of the input layout (default: the
+    decomposition's position-space layout; k-space callers pass their
+    own — entries may be combined-axis tuples). On CPU devices the
+    kernel runs in Pallas interpret mode."""
     from jax.sharding import PartitionSpec as P
     if lattice_names is None:
         lattice_names = tuple(decomp.spec(0))
@@ -84,52 +244,23 @@ def bincount_core(decomp, outer_shape, num_bins, weighted,
     if cached is not None:
         return cached
     nouter = int(np.prod(outer_shape, dtype=np.int64)) if outer_shape else 1
-    length = num_bins * nouter
     spec = P(*((None,) * len(outer_shape) + lattice_names))
-    # partials stay sharded along the stacked chunk axis — no device-side
+    # partials stay sharded along the stacked axis — no device-side
     # reduction, so no precision-losing f32/int32 cross-device sums;
     # stacking covers only the axes the input is actually sharded over
     # (mesh axes the input is replicated across would double count)
     stack = _flat_names(lattice_names)
     out_spec = P(stack or None, None)
+    interpret = decomp.mesh.devices.flat[0].platform == "cpu"
 
-    def flat_chunked_bins(b):
-        if nouter > 1:
-            # offset bins per outer slice: one bincount covers all slices
-            offsets = jnp.arange(nouter, dtype=jnp.int32).reshape(
-                outer_shape + (1, 1, 1))
-            b = b + offsets * num_bins
-        flat = b.reshape(-1)
-        n = flat.size
-        nchunks = -(-n // _CHUNK)
-        chunk = -(-n // nchunks)
-        pad = nchunks * chunk - n
-        if pad:
-            # padded elements go to a sentinel bin that is dropped below
-            flat = jnp.concatenate(
-                [flat, jnp.full((pad,), length, flat.dtype)])
-        return flat.reshape(nchunks, chunk), nchunks, chunk, pad
+    def local(b, w=None):
+        return _onehot_bincount(
+            b.reshape(nouter, -1),
+            None if w is None else w.reshape(nouter, -1),
+            num_bins, interpret)
 
-    if weighted:
-        def local(b, w):
-            bb, nchunks, chunk, pad = flat_chunked_bins(b)
-            flat_w = w.reshape(-1)
-            if pad:
-                flat_w = jnp.concatenate(
-                    [flat_w, jnp.zeros((pad,), flat_w.dtype)])
-            ww = flat_w.reshape(nchunks, chunk)
-            return jax.vmap(
-                lambda bi, wi: jnp.bincount(
-                    bi, weights=wi, length=length + 1)[:length])(bb, ww)
-        in_specs = (spec, spec)
-    else:
-        def local(b):
-            bb, *_ = flat_chunked_bins(b)
-            return jax.vmap(
-                lambda bi: jnp.bincount(bi, length=length + 1)[:length])(bb)
-        in_specs = (spec,)
-
-    fn = decomp.shard_map(local, in_specs, out_spec)
+    fn = decomp.shard_map(local, (spec,) * (1 + weighted), out_spec,
+                          check_vma=False)
     per_decomp[key] = fn
     return fn
 
@@ -174,10 +305,11 @@ def fetch_partials(partials):
 
 def weighted_bincount(decomp, bins, weights, num_bins, lattice_names=None,
                       owner="histogram"):
-    """Distributed histogram: chunked per-device ``jnp.bincount``s with
-    host-side wide-precision finalization (see module docstring). ``bins``
-    (int32) has shape ``outer + lattice``; ``weights`` shares it, or is
-    ``None`` for an exact integer count histogram. ``lattice_names``
+    """Distributed histogram: per-device partials of the one-hot
+    contraction with host-side wide-precision finalization (see module
+    docstring). ``bins`` (int32, each in ``[0, num_bins)``) has shape
+    ``outer + lattice``; ``weights`` shares it, or is ``None`` for an
+    exact integer count histogram. ``lattice_names``
     optionally overrides the assumed input layout (see
     :func:`_bincount_fn`); ``owner`` (``"histogram"`` or ``"spectra"``)
     names the program and the two host spans, the enqueue and the wait
@@ -238,7 +370,7 @@ class Histogrammer:
                     out[name] = (b, None)
                     continue
                 w = _field.evaluate(weight_expr, env)
-                acc = jnp.zeros((), self.dtype).dtype  # canonicalized
+                acc = jax.dtypes.canonicalize_dtype(self.dtype)
                 out[name] = (b, jnp.broadcast_to(w, b.shape).astype(acc))
             return out
 

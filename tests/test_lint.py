@@ -248,6 +248,33 @@ ENTRY main {
 """
 
 
+@pytest.mark.parametrize("weighted", [False, True],
+                         ids=["counts", "weights"])
+def test_binning_kernel_passes_precision_flow(weighted):
+    """The one-hot contraction narrows f32 to bf16 (the one-hots; the
+    three pieces of a weight) under its registered kernel scope and
+    accumulates in f32: no rule fires on the real lowered program."""
+    import jax
+    import jax.numpy as jnp
+    import pystella_tpu as ps
+    from pystella_tpu.lint import dataflow
+    from pystella_tpu.ops.histogram import bincount_core
+    with jax.enable_x64(False):
+        decomp = ps.DomainDecomposition((1, 1, 1),
+                                        devices=jax.devices()[:1])
+        core = bincount_core(decomp, (2,), 444, weighted)
+        args = [jax.ShapeDtypeStruct((2, 16, 16, 9), jnp.int32)]
+        if weighted:
+            args.append(jax.ShapeDtypeStruct((2, 16, 16, 9), jnp.float32))
+        asm = jax.jit(core).lower(*args).compiler_ir().operation.get_asm(
+            enable_debug_info=True)
+    violations, stats = dataflow.audit_precision(
+        "binning", asm, policy=lint_graph.POLICY_SPECTRAL_F32)
+    assert violations == [], [v.message for v in violations]
+    assert stats["kernel_converts"] >= (4 if weighted else 2)
+    assert stats["carry_converts"] == 0
+
+
 def test_dataflow_parse_ops():
     from pystella_tpu.lint import dataflow
     ops = {o["result"]: o for o in dataflow.parse_ops(_DF_ASM)}

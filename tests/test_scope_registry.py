@@ -35,7 +35,8 @@ def test_every_scope_literal_is_registered():
     # tests/test_host_spans.py pins those; host spans are literals)
     for expected in ("fused_rk_stage_pair", "halo_exchange", "mg_cycle",
                      "pallas_resident_stencil", "sentinel", "rk_stage",
-                     "step_dispatch", "reduce_fetch", "output_write"):
+                     "step_dispatch", "reduce_fetch", "output_write",
+                     "pallas_bincount"):
         assert expected in found, (expected, sorted(found))
     assert violations == [], (
         "unregistered trace scopes — add register_scope() entries in "
@@ -69,6 +70,17 @@ def test_parser_vocabulary_is_the_registry():
     assert set(obs_trace.KNOWN_SCOPES) == set(obs_scope.registered_scopes())
     # and the trace-only names (raw XLA op rows) are registry members
     assert "collective-permute" in obs_trace.KNOWN_SCOPES
+
+
+def test_binning_kernel_scope_is_no_stencil_kind():
+    """The binning kernel's scope is registered under a name of its own:
+    nothing that matches ``pallas_stencil`` (the benchmark's generic
+    kernel file, ``kernel_scope``) may claim it and count it by the
+    stencil byte rule."""
+    assert "pallas_bincount" in obs_scope.registered_scopes()
+    assert not "pallas_bincount".startswith("pallas_stencil")
+    with pytest.raises(ValueError, match="register_scope"):
+        obs_scope.kernel_scope("bincount")
 
 
 def test_register_scope_idempotent_and_live():

@@ -175,3 +175,32 @@ def test_bin_counts_exact_past_2_to_24_modes():
     assert exact.sum() == float(np.prod(grid_shape))
     assert np.array_equal(spectra.bin_counts, exact)
     assert np.all(spectra.bin_counts > 0)
+
+
+@pytest.mark.parametrize("proc_shape", [(1, 1, 1), (2, 2, 1)], indirect=True)
+@pytest.mark.parametrize("outer", [(), (2,), (6,)], ids=str)
+def test_bin_power_matches_float64_binning(setup, proc_shape, outer):
+    """``bin_power`` of momentum-space fields whose power spans 2**-40 ..
+    2**40, one to six of them in one pass, against numpy's float64
+    binning of the same weights: the one-hot contraction keeps the
+    weights' float32 (the sums are exact products added in float32
+    inside a partial, in float64 across partials and devices)."""
+    decomp, lattice, fft, spectra = setup
+    rng = np.random.default_rng(23 + len(outer))
+    kshape = outer + tuple(fft.shape(True))
+    fk = ((rng.standard_normal(kshape) + 1j * rng.standard_normal(kshape))
+          * 2.0 ** rng.integers(-20, 21, kshape)).astype(fft.cdtype)
+    got = spectra.bin_power(fk, k_power=3)
+    assert got.shape == outer + (spectra.num_bins,)
+
+    weights = (np.asarray(spectra._counts, np.float64)
+               * np.asarray(spectra._kmags, np.float64)**3
+               * np.abs(fk.astype(np.complex128))**2)
+    index = np.asarray(spectra._bin_idx).ravel()
+    expected = np.stack([
+        np.bincount(index, weights=w.ravel(), minlength=spectra.num_bins)
+        for w in weights.reshape((-1,) + weights.shape[-3:])])
+    expected = expected.reshape(got.shape) / spectra.bin_counts
+    # float32: the weights themselves are products of float32 factors
+    rtol = 1e-12 if fft.dtype == np.float64 else 2e-6
+    assert np.allclose(got, expected, rtol=rtol, atol=0)

@@ -242,3 +242,60 @@ def test_gw_chunk_at_384_and_what_the_carries_take(v5e):
     assert 14e9 < held[None] < 15.75 * 2**30, held
     with pytest.raises(Exception, match="RESOURCE_EXHAUSTED|hbm"):
         _gw_chunk(v5e, None, 1).compile()
+
+
+#: the binning programs of the two output cells on one chip:
+#: coupled-run's spectra (two scalars in k-space), its histogram (counts
+#: of rho), -gws' GW spectrum; and the same two on the (2,2,1) mesh at
+#: 512**3 a chip. (mesh, program name, num_bins, outer, lattice, weighted)
+BINNING = [
+    pytest.param((1, 1, 1), "spectra_bin", 444, (2,), (512, 512, 257), True,
+                 id="spectra-2x512x512x257"),
+    pytest.param((1, 1, 1), "histogram_bincount", 1000, (),
+                 (512, 512, 512), False, id="histogram-512^3"),
+    pytest.param((1, 1, 1), "spectra_bin", 334, (6,), (384, 384, 193), True,
+                 id="gw-6x384x384x193"),
+    pytest.param((2, 2, 1), "spectra_bin", 888, (2,), (1024, 1024, 257),
+                 True, id="mesh-spectra-2x1024x1024x257"),
+    pytest.param((2, 2, 1), "histogram_bincount", 1000, (),
+                 (1024, 1024, 512), False, id="mesh-histogram")]
+
+
+@pytest.mark.parametrize(
+    "proc_shape,program,num_bins,outer,lattice,weighted", BINNING)
+def test_bincount_programs_compile(v5e, proc_shape, program, num_bins,
+                                   outer, lattice, weighted):
+    """The one-hot contraction as the output branch dispatches it, at
+    the cells' shapes: the program carries the owner's name and one
+    Mosaic call named ``%pallas_bincount.N`` (what the benchmark's
+    breakdown keys its rows by; not ``pallas_stencil*``, which a kernel
+    file would claim), no collective (the partials leave each device
+    unreduced), and holds nothing the size of lattice x ``Hi``: its
+    temporaries are the two operands laid out in rows (none at all
+    where the lattice's own rows are lane-aligned)."""
+    import re
+    from pystella_tpu.ops.histogram import _bincount_fn
+    ndev = int(np.prod(proc_shape))
+    decomp = ps.DomainDecomposition(proc_shape, devices=v5e[:ndev])
+    owner = "spectra" if program == "spectra_bin" else "histogram"
+    fn = _bincount_fn(decomp, outer, num_bins, weighted, owner=owner)
+    operand = jax.ShapeDtypeStruct(outer + lattice, jnp.int32,
+                                   sharding=decomp.sharding(len(outer)))
+    args = (operand,) + ((jax.ShapeDtypeStruct(
+        operand.shape, jnp.float32, sharding=operand.sharding),)
+        if weighted else ())
+    compiled = fn._jitted.trace(*args).lower(
+        lowering_platforms=("tpu",)).compile()
+    hlo = compiled.as_text()
+    assert hlo.startswith("HloModule jit_" + program), hlo[:60]
+    names = _custom_call_names(hlo)
+    assert [re.sub(r"\.\d+$", "", n) for n in names] == ["pallas_bincount"]
+    assert "scatter" not in hlo
+    assert not re.search(r"all-(gather|reduce|to-all)|collective-permute",
+                         hlo)
+    mem = compiled.memory_analysis()
+    # operands in tiled layout (the 257- and 193-wide rows are padded to
+    # whole lane tiles), relaid once each; lattice x Hi would be 4-16x
+    assert mem.temp_size_in_bytes <= 2 * mem.argument_size_in_bytes
+    if lattice[-1] % 128 == 0:
+        assert mem.temp_size_in_bytes < 2**20

@@ -255,3 +255,35 @@ def test_x_sharded_overlap_step_lowers(make_decomp):
         lambda st: stepper.step(st, 0.0, stepper.dt, args), state)
     # the split really is in the program, not the padded fallback
     assert "halo_overlap_interior" in lowered.as_text(debug_info=True)
+
+
+#: the binning programs of the two output cells: (num_bins, outer,
+#: local lattice, weighted) of coupled-run's spectra (two scalars in
+#: k-space), its histogram (counts of rho) and -gws' GW spectrum
+BINNING_SHAPES = [
+    pytest.param(444, (2,), (512, 512, 257), True, id="spectra-2x512x512x257"),
+    pytest.param(1000, (), (512, 512, 512), False, id="histogram-512^3"),
+    pytest.param(334, (6,), (384, 384, 193), True, id="gw-6x384x384x193")]
+
+
+@pytest.mark.parametrize("num_bins,outer,lattice,weighted", BINNING_SHAPES)
+def test_bincount_kernel_lowers(num_bins, outer, lattice, weighted):
+    """The one-hot contraction behind every histogram and spectrum, at
+    the cells' shapes: one Mosaic call (an NT ``dot_general`` on
+    bfloat16 one-hots, a ragged last block masked in the kernel), with
+    x64 on as the suite runs (its grid indices must stay i32)."""
+    from pystella_tpu.ops.histogram import _onehot_bincount
+    nouter = int(np.prod(outer, dtype=np.int64))
+    n = int(np.prod(lattice))
+    b = jax.ShapeDtypeStruct((nouter, n), jnp.int32)
+    w = jax.ShapeDtypeStruct((nouter, n), jnp.float32)
+    lowered = lower_tpu(
+        lambda *args: _onehot_bincount(
+            args[0], args[1] if weighted else None, num_bins, False),
+        *((b, w) if weighted else (b,)))
+    assert lowered.as_text().count("tpu_custom_call") == 1
+    assert "pallas_bincount" in lowered.as_text(debug_info=True)
+    out, = lowered.out_info if isinstance(lowered.out_info, tuple) else (
+        lowered.out_info,)
+    assert out.shape[1] == nouter * num_bins
+    assert out.dtype == (jnp.float32 if weighted else jnp.int32)
