@@ -16,7 +16,7 @@ streaming tier with the blocking it took), the HDF5 series, the
 checkpoint read back, the Friedmann constraint, the chip's own
 ``peak_bytes_in_use`` — two steps of the fused stepper against the
 plain ``LowStorageRK54`` + XLA ``FiniteDifferencer`` reference from one
-seeded state (``bench.fused_parity``), the transform pair's round
+seeded state (:func:`fused_parity`), the transform pair's round
 trip, and the histogram and one spectrum of a seeded field against
 numpy's float64 binning of the same field. With four chips it repeats all of
 it on a ``(2, 2, 1)`` mesh and checks the work is spread over them.
@@ -171,6 +171,83 @@ def state_digest(ckpt_dir, decomp, step):
     return h.hexdigest()[:16], state, meta
 
 
+def generic_stepper(sector, decomp, dx, dt):
+    """The plain reference: ``LowStorageRK54`` over the XLA halo
+    ``FiniteDifferencer``'s Laplacian, no Pallas kernel in it."""
+    import pystella_tpu as ps
+
+    fd = ps.FiniteDifferencer(decomp, 2, dx, mode="halo")
+    rhs = ps.compile_rhs_dict(sector.rhs_dict)
+
+    def full_rhs(s, t, a, hubble):
+        return rhs(s, t, lap_f=fd.lap(s["f"]), a=a, hubble=hubble)
+
+    return ps.LowStorageRK54(full_rhs, dt=dt)
+
+
+def build_preheat_step(grid_shape, decomp):
+    """The two-field preheating system in float32 on ``decomp``'s mesh,
+    stepped by the plain reference; returns ``(stepper, dt)``. The
+    re-mesh drill (``tests/test_remesh.py``) rebuilds its step from this
+    on each mesh it lands on."""
+    import pystella_tpu as ps
+
+    lattice = ps.Lattice(grid_shape, (5.0, 5.0, 5.0), dtype=np.float32)
+    dt = np.float32(0.1 * min(lattice.dx))
+    mphi, gsq = 1.20e-6, 2.5e-7
+
+    def potential(f):
+        phi, chi = f[0], f[1]
+        return (mphi**2 / 2 * phi**2 + gsq / 2 * phi**2 * chi**2) / mphi**2
+
+    sector = ps.ScalarSector(2, potential=potential)
+    return generic_stepper(sector, decomp, lattice.dx, dt), dt
+
+
+def fused_parity(grid_shape, decomp, nsteps):
+    """The compiled Pallas path against the plain reference: ``nsteps``
+    of :class:`~pystella_tpu.FusedScalarStepper` ``step()`` vs
+    :func:`generic_stepper` from one seeded float32 state, on
+    ``decomp``'s mesh. Returns the largest difference relative to each
+    field's scale. One path at a time, results staged on the host: at
+    512**3 the two paths' device buffers together would crowd a chip."""
+    import pystella_tpu as ps
+
+    dtype = np.float32
+    grid_shape = tuple(grid_shape)
+    lattice = ps.Lattice(grid_shape, (5.0,) * 3, dtype=dtype)
+    dt = dtype(0.1 * min(lattice.dx))
+
+    def potential(f):
+        return 0.5 * f[0]**2 + 0.125 * f[0]**2 * f[1]**2
+
+    sector = ps.ScalarSector(2, potential=potential)
+    rng = np.random.default_rng(21)
+    host = {k: 0.1 * rng.standard_normal((2,) + grid_shape).astype(dtype)
+            for k in ("f", "dfdt")}
+    args = {"a": dtype(1.0), "hubble": dtype(0.1)}
+
+    fused = ps.FusedScalarStepper(sector, decomp, grid_shape, lattice.dx,
+                                  2, dtype=dtype, dt=dt)
+    generic = generic_stepper(sector, decomp, lattice.dx, dt)
+
+    results = []
+    for stepper in (fused, generic):
+        state = {k: decomp.shard(v) for k, v in host.items()}
+        for _ in range(nsteps):
+            state = stepper.step(state, 0.0, dt, args)
+        results.append({k: np.asarray(v) for k, v in state.items()})
+        del state
+    got, ref = results
+    maxrel = 0.0
+    for k in ref:
+        if not (np.all(np.isfinite(got[k])) and np.all(np.isfinite(ref[k]))):
+            raise FloatingPointError(f"non-finite {k} after {nsteps} step(s)")
+        scale = np.max(np.abs(ref[k])) or 1.0
+        maxrel = max(maxrel, float(np.max(np.abs(got[k] - ref[k])) / scale))
+    return maxrel
+
+
 def run_leg(grid_shape, proc_shape, workdir):
     """The whole smoke on one mesh: both driver invocations, their
     checks, the checkpoint read-back and the parity comparison (at the
@@ -178,7 +255,6 @@ def run_leg(grid_shape, proc_shape, workdir):
     :class:`SmokeFailure` when a check does not hold; returns the leg's
     summary dict."""
     import jax
-    import bench
     import pystella_tpu as ps
     from pystella_tpu import obs
     from pystella_tpu.obs.events import read_events
@@ -261,7 +337,7 @@ def run_leg(grid_shape, proc_shape, workdir):
 
     # 3. parity against the plain reference, on the same devices
     with watch_fallbacks() as watch:
-        maxrel, _ = bench.fused_parity(grid_shape, decomp, nsteps=2)
+        maxrel = fused_parity(grid_shape, decomp, nsteps=2)
     require(not watch.fallbacks,
             f"{label} parity: fallback warning(s): {watch.fallbacks}")
     say(f"{label}: parity at {grid_shape}: max relative difference "

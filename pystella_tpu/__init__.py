@@ -30,7 +30,6 @@ from pystella_tpu.ops import (
     expand_stencil, centered_diff,
     Reduction, FieldStatistics,
     Histogrammer, FieldHistogrammer,
-    FFTStencil, fft_laplacian, use_fft_stencil,
 )
 from pystella_tpu.ops.pallas_stencil import StreamingStencil
 from pystella_tpu.ops.fused import FusedScalarStepper, FusedPreheatStepper
@@ -109,7 +108,6 @@ __all__ = [
     "FiniteDifferencer",
     "Reduction", "FieldStatistics", "Histogrammer", "FieldHistogrammer",
     "StreamingStencil", "FusedScalarStepper", "FusedPreheatStepper",
-    "FFTStencil", "fft_laplacian", "use_fft_stencil",
     "DFT", "PencilFFT", "make_dft", "fftfreq", "pfftfreq",
     "make_hermitian",
     "Projector", "PowerSpectra", "RayleighGenerator",

@@ -1,6 +1,6 @@
 """Central environment-variable registry.
 
-Every ``PYSTELLA_*`` / ``BENCH_*`` knob the package or its drivers read
+Every ``PYSTELLA_*`` knob the package or its drivers read
 is declared here — name, default, type, and a one-line description —
 and read through :func:`getenv` / the typed getters. The source-tier
 lint (:mod:`pystella_tpu.lint.source`) enforces the contract: an
@@ -21,8 +21,8 @@ supervisor that must not import jax can load it by file::
     spec = importlib.util.spec_from_file_location(
         "_cfg", ".../pystella_tpu/config.py")
 
-Reads are LIVE (no import-time caching): sweep harnesses vary knobs
-like ``PYSTELLA_VMEM_LIMIT_MB`` between kernel builds in one process.
+Reads are LIVE (no import-time caching): a test or a harness can vary
+a knob between two builds in one process.
 """
 
 from __future__ import annotations
@@ -44,7 +44,7 @@ class EnvVar:
     help: str
     kind: str = "str"        # str | int | float | bool | path
     #: where it is consumed: "package" (pystella_tpu/ runtime),
-    #: "driver" (bench/example scripts), "test" (suite config), or
+    #: "driver" (example scripts, CLIs), "test" (suite config), or
     #: "external" (not ours — documented because reports fingerprint it)
     scope: str = "package"
 
@@ -128,24 +128,17 @@ register("PYSTELLA_HALO_OVERLAP", default="auto", kind="bool",
          help="halo-exchange/compute overlap policy for sharded stencils: "
               "1/0 force on/off, unset/'auto' enables exactly when the "
               "mesh shards a lattice axis (parallel.overlap.enabled)")
-register("PYSTELLA_VMEM_LIMIT_MB", default="100", kind="float",
-         help="per-kernel Mosaic scoped-VMEM request in MiB "
-              "(ops.pallas_stencil.vmem_limit_bytes); read at each "
-              "kernel build so sweeps can vary it in-process")
-register("PYSTELLA_BLOCK_BUDGET_MB", default="24", kind="float",
-         help="VMEM budget in MiB that ops.pallas_stencil.choose_blocks "
-              "fits the streaming window ring into")
 register("PYSTELLA_WARMSTART_DIR", default=None, kind="path",
          help="default artifact directory for the AOT warm-start "
-              "store (obs.warmstart): the export/verify CLI and "
-              "bench.py --smoke's warm-start leg persist and load matching "
-              "artifacts there, skipping trace+compile for them — "
+              "store (obs.warmstart): the export/verify CLI "
+              "persists and loads matching artifacts there, skipping "
+              "trace+compile for them — "
               "fingerprint mismatches fall back to the jit path and "
               "are recorded as warmstart_mismatch events")
 register("PYSTELLA_ENSEMBLE_SIZE", default="8", kind="int",
          help="default member count for ensemble (batched-scenario) "
-              "runs: bench.py's smoke ensemble payload and "
-              "EnsembleDriver use it when no explicit size is given")
+              "runs: EnsembleDriver uses it when no explicit size is "
+              "given")
 register("PYSTELLA_ENSEMBLE_AXIS", default="ensemble",
          help="name of the leading device-mesh axis the ensemble tier "
               "packs members along (parallel.decomp.ensemble_mesh); "
@@ -339,37 +332,7 @@ register("PYSTELLA_TRACE_EXPORT", default=None, kind="path",
          help="default Perfetto output path for the assembled service "
               "span timeline: `python -m pystella_tpu.obs.spans` "
               "writes the request-timeline trace file there when no "
-              "explicit --perfetto is given, and bench.py --smoke "
-              "mirrors its service_trace.json export to it; unset "
-              "skips the extra copy")
-register("PYSTELLA_AUTOTUNE", default="1", kind="bool",
-         help="persistent-autotuner consult policy for fused Pallas "
-              "kernel builds (ops.autotune): 1 (default) consults "
-              "bench_results/autotune_<device-kind>.json before the "
-              "choose_blocks heuristic (stale entries are refused with "
-              "an autotune_mismatch event, exactly like stale AOT "
-              "warm-start artifacts); 0 skips the table entirely — the "
-              "tier-1 suite pins 0 so ambient builds stay hermetic")
-register("PYSTELLA_AUTOTUNE_DIR", default="bench_results", kind="path",
-         help="directory of the persistent autotune winner tables "
-              "(autotune_<device-kind>.json, one per device kind); "
-              "relative paths anchor at the repository root; the sweep "
-              "CLI (python -m pystella_tpu.ops.autotune) writes there "
-              "and kernel builds read back through the same store")
-register("PYSTELLA_CHUNK_STAGES", default="0", kind="int",
-         help="default temporal-blocking chunk depth for the fused "
-              "steppers when no chunk_stages= argument and no autotune "
-              "table entry decides it: an even number >= 4 of RK "
-              "stages advanced per resident whole-RK-chunk kernel "
-              "(VMEM-window halo widens by h per stage pair; "
-              "infeasible shapes degrade to pair kernels with a "
-              "kernel_fallback event); 0 (default) keeps the "
-              "pair-stage tier")
-register("PYSTELLA_FORCE_BLOCKS", default=None,
-         help="'bx,by' override for the fused steppers' streaming-"
-              "kernel blocking — beats both the autotune table and the "
-              "choose_blocks heuristic (sweep harness escape hatch; "
-              "the block_choice event records source='override')")
+              "explicit --perfetto is given; unset writes none")
 register("PYSTELLA_FFT_SCHEME", default="auto",
          help="distributed-FFT scheme the planner (fourier.plan."
               "make_dft) and the spectra/projector/Poisson consumers "
@@ -385,15 +348,6 @@ register("PYSTELLA_FFT_REPLICATE_LIMIT", default="1073741824",
               "raises instead of silently replicating the k-space "
               "array on every device (override per-instance with "
               "replicate_limit=/allow_replicate=)")
-register("PYSTELLA_FFT_STENCIL", default="auto",
-         help="FFT-stencil fast-path policy (ops.fft_stencil."
-              "use_fft_stencil): 1/0 force the k-space/direct path, "
-              "unset/'auto' decides by the flops crossover model "
-              "(direct tap cost vs 2 x 5 N log2 N transform cost)")
-register("PYSTELLA_FFT_STENCIL_CROSSOVER", default="1.5", kind="float",
-         help="direct-to-FFT flops ratio the auto FFT-stencil policy "
-              "requires before taking the k-space path (margin for the "
-              "transpose traffic the flops model does not see)")
 register("PYSTELLA_CAPACITY_HEADROOM", default="0.9", kind="float",
          help="memory-aware admission budget as a fraction of device "
               "HBM capacity (obs.capacity.CapacityMonitor): resident "
@@ -421,7 +375,7 @@ register("PYSTELLA_CAPACITY_DIR",
               "ledger stays in-memory")
 
 # ---------------------------------------------------------------------------
-# driver knobs (bench.py / bench_scaling.py / examples)
+# driver knobs (examples, the gate's CLI)
 # ---------------------------------------------------------------------------
 
 register("PYSTELLA_GATE_COMM_EXCESS_PCT", default="25", kind="float",
@@ -431,10 +385,6 @@ register("PYSTELLA_GATE_COMM_EXCESS_PCT", default="25", kind="float",
               "lint tier's static model by more than this percentage "
               "fails the gate (the model is an upper bound — measured "
               "above it means unattributed traffic)")
-register("BENCH_PROFILE", default=None, kind="path", scope="driver",
-         help="log dir: bench.py wraps one extra (untimed) preheat "
-              "chunk per grid in a jax.profiler capture; per-scope "
-              "durations land in the event log as trace_summary events")
 
 # ---------------------------------------------------------------------------
 # external variables we read or set (not project-prefixed; documented

@@ -245,7 +245,7 @@ class EnsembleMonitor:
         # `health_checks` counter feed the ledger's numerics section
         # (sentinel overhead % vs step time), which must keep
         # describing the single-run monitor when both run in one
-        # process (bench.py --smoke does)
+        # process
         with _metrics.timer("ensemble_sentinel"):
             decoded = self.sentinel.decode_members(matrix)
         self.checked_through = (step if self.checked_through is None

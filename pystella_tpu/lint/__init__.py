@@ -104,8 +104,7 @@ def run_lint(pkg_dir=None, targets=None, run_source=True, run_graph=True,
     :arg run_dataflow: run the dataflow tier (precision-flow + static
         comm model) over the same lowered artifacts. Default
         (``None``): follows ``run_graph`` — drivers that skip the IR
-        tier and audit their own artifacts (``bench.py --smoke``) skip
-        it here too.
+        tier and audit their own artifacts skip it here too.
     :arg doc: path for the env-var doc-coverage check (default: the
         in-repo ``doc/observability.md`` when linting the real
         package).

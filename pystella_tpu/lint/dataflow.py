@@ -58,9 +58,9 @@ per-invocation bytes computed from the result shape, and classified:
   base op is allowlisted** (generalizing the PR-5 sentinel all-gather
   find: an allowlist names ops, not sizes).
 
-The per-target ``static_comm`` block lands in ``lint_report.json``;
-``bench.py --smoke`` emits the same block for the programs it actually
-dispatches, :class:`~pystella_tpu.obs.ledger.PerfLedger` joins it
+The per-target ``static_comm`` block lands in ``lint_report.json``; a
+driver may hand the same block to the event log (a ``lint`` event),
+:class:`~pystella_tpu.obs.ledger.PerfLedger` joins it
 against measured ``halo_bytes_exchanged`` traffic into the report's
 ``comm`` section, and :mod:`pystella_tpu.obs.gate` fails evidence whose
 measured traffic exceeds the model (lost overlap or a replication
@@ -427,7 +427,7 @@ def audit_dataflow_artifacts(name, asm, hlo_text, dtype_policy=None,
     """Both dataflow audits over already-lowered artifacts; returns
     ``(violations, stats)`` with ``precision`` and ``static_comm``
     blocks. The entry point for drivers auditing the executable they
-    are about to dispatch (``bench.py --smoke``)."""
+    are about to dispatch."""
     import time as _time
     violations, stats = [], {}
     t0 = _time.perf_counter()

@@ -457,9 +457,9 @@ def audit_artifacts(name, asm, hlo_text, donatable_bytes=None,
                     fused_scopes=(), timings=None):
     """Run every IR-tier audit over already-lowered artifacts; returns
     ``(violations, stats)``. This is also the entry point for drivers
-    that audit the executable they are about to dispatch
-    (``bench.py --smoke``). ``timings``, when given a dict, is filled
-    with per-audit wall seconds keyed by checker name."""
+    that audit the executable they are about to dispatch. ``timings``,
+    when given a dict, is filled with per-audit wall seconds keyed by
+    checker name."""
     violations = []
     stats = {"built": True}
 

@@ -17,7 +17,7 @@ need not even be importable):
   host conversion either breaks the trace or forces a device round
   trip.
 - ``env-registry`` — every ``os.environ`` / ``os.getenv`` read of a
-  project-prefixed (``PYSTELLA_*`` / ``BENCH_*``) variable outside
+  project-prefixed (``PYSTELLA_*``) variable outside
   ``config.py`` must carry an ``# env-registry: NAME`` pragma naming a
   variable registered in :mod:`pystella_tpu.config` (the escape hatch
   for stdlib-only modules that stay loadable by file); reads through
@@ -85,7 +85,7 @@ _HOT_MARKER = re.compile(r"#\s*lint:\s*hot-path")
 _ALLOW_PRAGMA = re.compile(r"#\s*lint:\s*allow\(([\w., -]+)\)")
 _ENV_PRAGMA = re.compile(r"#\s*env-registry:\s*([\w, ]+)")
 
-_PROJECT_PREFIXES = ("PYSTELLA_", "BENCH_")
+_PROJECT_PREFIXES = ("PYSTELLA_",)
 
 
 def iter_py_files(pkg_dir):
@@ -220,8 +220,8 @@ class _FileChecker(ast.NodeVisitor):
         # the method NAME is the contract; non-literal first args,
         # e.g. ResultEmitter.emit(request, ...), are simply not kinds).
         # A kind= keyword literal counts the same, and so do the
-        # private _emit(kind, ...) wrappers (ops.autotune,
-        # resilience.retry, obs.perf) — both would otherwise drift
+        # private _emit(kind, ...) wrappers (resilience.retry,
+        # obs.perf) — both would otherwise drift
         # past the registry silently. The keyword check is scoped to
         # emit calls on purpose: kind= elsewhere means something else
         # entirely (config.register's value type, the SLO monitor's

@@ -59,7 +59,7 @@ def _mesh_decomp(want_sharded):
 
 
 def _preheat_parts(decomp, dtype=np.float32):
-    """The smoke/bench two-field preheating system on ``GRID``:
+    """The two-field preheating system on ``GRID``:
     ``(stepper_rhs, state, t, dt, rhs_args)`` ingredients shared by the
     generic-step targets."""
     import pystella_tpu as ps
@@ -91,8 +91,7 @@ def _preheat_parts(decomp, dtype=np.float32):
 
 
 def build_step_generic():
-    """The generic (XLA-tier) LowStorageRK54 step on a sharded mesh —
-    the ``bench.py --smoke`` step program."""
+    """The generic (XLA-tier) LowStorageRK54 step on a sharded mesh."""
     import pystella_tpu as ps
     decomp = _mesh_decomp(want_sharded=True)
     full_rhs, state, t, dt, rhs_args = _preheat_parts(decomp)
@@ -174,7 +173,7 @@ def build_chunk_multi_step():
     sector = ps.ScalarSector(2, potential=potential)
     stepper = ps.FusedScalarStepper(
         sector, decomp, GRID, lattice.dx, 2, dtype=jnp.float32,
-        chunk_stages=4, chunk_bx=4, chunk_by=8, autotune=False)
+        chunk_stages=4, chunk_bx=4, chunk_by=8)
     if stepper._chunk_call is None:
         raise RuntimeError("chunk kernel failed to build at the audit "
                            "shape — the fallback warning says why")
@@ -215,7 +214,7 @@ def build_bf16_chunk_multi_step():
     stepper = ps.FusedScalarStepper(
         sector, decomp, GRID, lattice.dx, 2, dtype=jnp.float32,
         carry_dtype=jnp.bfloat16, chunk_stages=4, chunk_bx=4,
-        chunk_by=8, autotune=False)
+        chunk_by=8)
     if stepper._chunk_call is None:
         raise RuntimeError("bf16-carry chunk kernel failed to build at "
                            "the audit shape — the fallback warning "

@@ -1,12 +1,12 @@
 """Unified telemetry: run events, metrics, trace scopes, memory reports.
 
 One subsystem behind the pieces that grew up scattered (``utils/monitor``,
-``utils/profiling``, ``bench.py``'s hand-rolled prints):
+``utils/profiling``, hand-rolled prints):
 
 - :mod:`pystella_tpu.obs.events` — a structured JSONL run-event log
   (wall + monotonic timestamps, host id, step, event kind, payload) that
-  drivers, :class:`~pystella_tpu.HealthMonitor`, checkpointing, the
-  multigrid driver, and ``bench.py`` all emit through. Outage and
+  drivers, :class:`~pystella_tpu.HealthMonitor`, checkpointing and the
+  multigrid driver all emit through. Outage and
   contamination forensics become ``grep``s over one file instead of
   archaeology on interleaved stderr.
 - :mod:`pystella_tpu.obs.metrics` — a lightweight registry of counters /

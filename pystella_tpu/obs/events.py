@@ -1,9 +1,8 @@
 """Structured JSONL run-event log.
 
 Every event is one JSON object per line, appended and flushed
-immediately so a killed run keeps everything emitted before the kill
-(the property that saved round 4's bench record; ``bench.py``'s line
-cache pioneered the pattern). Schema (version 2):
+immediately so a killed run keeps everything emitted before the kill.
+Schema (version 2):
 
 ===========  ======================================================
 key          meaning
@@ -189,10 +188,11 @@ for _name, _help in (
     ("gate_verdict", "the perf gate ran (ok, exit_code, reasons)"),
     # -- numerics / solver hot paths ----------------------------------------
     ("mg_cycle", "one multigrid cycle (depth, smooths, errors)"),
-    # -- fused kernel tiers + the persistent autotuner (ops.autotune) -------
+    # -- fused kernel tiers --------------------------------------------------
     ("block_choice", "a fused kernel build chose its blocking "
-                     "(bx/by/grid/win_halo + source: autotune table hit, "
-                     "choose_blocks heuristic, env override, explicit)"),
+                     "(bx/by/grid/win_halo + source: 'explicit' "
+                     "constructor pins or the choose_blocks "
+                     "'heuristic')"),
     ("bincount_plan", "a binning program was built: what the one-hot "
                       "contraction took from the shapes (hi x lo "
                       "factorisation, tile, steps and partials, MXU "
@@ -202,17 +202,6 @@ for _name, _help in (
     ("kernel_tier", "the kernel tier a fused stepper actually "
                     "dispatched (resident-chunk/streaming-chunk/pair/"
                     "single/xla) + modeled HBM bytes per step"),
-    ("autotune_record", "a sweep winner persisted to the per-device "
-                        "autotune table"),
-    ("autotune_mismatch", "an autotune-table entry was refused "
-                          "(version/flag-stale or corrupt table)"),
-    ("autotune_gc", "stale autotune entries collected"),
-    ("autotune_sweep", "one autotune sweep's totals (winner + "
-                       "candidate count)"),
-    ("autotune_warm_build", "a table-hit stepper rebuild dispatched "
-                            "with its compile-watch record — "
-                            "backend_compiles == 0 is the "
-                            "zero-extra-compiles proof"),
     # -- checkpoints (utils.checkpoint) -------------------------------------
     ("checkpoint_save", "async checkpoint write SCHEDULED (not durable)"),
     ("checkpoint_durable", "durability barrier passed; last_good advanced"),
@@ -326,10 +315,9 @@ for _name, _help in (
     ("capacity_usage", "the serve loop's capacity/goodput rollup "
                        "(per-tenant chargeback table, reconciliation, "
                        "watermark coverage)"),
-    # -- driver-side kinds (bench.py / examples; outside the package, so
-    # -- not lint-audited, but registered so the vocabulary is one list)
-    ("bench_run", "bench run metadata"),
-    ("bench_metric", "one bench headline metric line"),
+    # -- driver-side kinds (examples, and what a driver may hand the
+    # -- ledger; outside the package, so not lint-audited, but
+    # -- registered so the vocabulary is one list)
     ("run_start", "example-driver run began"),
     ("run_complete", "example-driver run completed"),
     ("run_aborted", "example-driver run died (forensic tail)"),
@@ -337,14 +325,6 @@ for _name, _help in (
     ("spectra_time", "one spectra output's wall time"),
     ("fft_spectra", "a driver's sharded-spectra leg totals"),
     ("lint", "the static-analysis verdict of the run"),
-    ("smoke_supervised_failed", "smoke: supervised payload failed"),
-    ("smoke_autotune_failed", "smoke: fused-tier/autotune payload "
-                              "failed its pins"),
-    ("smoke_remesh_failed", "smoke: remesh drill failed"),
-    ("smoke_service_failed", "smoke: service payload failed"),
-    ("smoke_fleet_failed", "smoke: two-replica fleet drill failed"),
-    ("smoke_capacity_failed", "smoke: capacity/goodput leg failed its "
-                              "pins"),
 ):
     register_event_kind(_name, _help)
 del _name, _help
@@ -665,7 +645,7 @@ def emit(kind, step=None, **data):
 
 def read_events(path, kind=None, include_rotated=False):
     """Load events from a JSONL file (newest last). Torn trailing lines
-    from a killed writer are skipped, like ``bench.py``'s line cache.
+    from a killed writer are skipped.
     ``kind`` optionally filters. ``include_rotated=True`` reads the
     whole rotated family (:func:`rotated_family`) oldest-first, so a
     size-rotated long-lived log reads as one continuous record — the

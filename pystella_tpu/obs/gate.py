@@ -704,33 +704,6 @@ def compare_reports(baseline, current, threshold_pct=10.0, mad_k=3.0,
                                        for r in contamination["reasons"]]
                 return verdict
 
-    # autotune coverage (ops.autotune): a HARDWARE report whose fused
-    # kernels dispatched with no autotune-table hit ran the heuristic
-    # blockings — legal, but it means the window either never swept or
-    # refused every (stale) entry, and its numbers under-claim what the
-    # tuned kernels would do. The lost-coverage pattern: warn, never
-    # fail (a CPU/smoke run legitimately has no table).
-    kt = ((current.get("roofline") or {}).get("kernel_tiers")) or {}
-    if kt:
-        fused_rows = [r for r in kt.get("dispatched") or []
-                      if r.get("tier") not in (None, "xla")]
-        at = kt.get("autotune") or {}
-        if ((current.get("env") or {}).get("platform") == "tpu"
-                and fused_rows and not at.get("hits")):
-            verdict["warnings"].append(
-                "autotune-coverage: TPU report dispatched fused "
-                "kernels with zero autotune-table hits"
-                + (f" ({at.get('mismatches_refused')} stale entr(ies) "
-                   "refused)" if at.get("mismatches_refused") else "")
-                + " — heuristic blockings measured; sweep this device "
-                "kind (python -m pystella_tpu.ops.autotune sweep) so "
-                "hardware claims come from tuned kernels")
-        elif at.get("mismatches_refused"):
-            verdict["warnings"].append(
-                f"autotune: {at['mismatches_refused']} stale table "
-                "entr(ies) refused this run (version/flag mismatch) — "
-                "re-sweep or `python -m pystella_tpu.ops.autotune gc`")
-
     if baseline is None:
         verdict["warnings"].append("no baseline: contamination check "
                                    "only, no regression comparison")

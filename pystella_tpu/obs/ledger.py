@@ -236,9 +236,6 @@ class PerfLedger:
         self.kernel_tiers = []          # kernel_tier payloads (dispatch
         #                                 record: which fused tier ran)
         self.block_choices = []         # block_choice payloads
-        self.autotune_mismatches = []   # refused stale table entries
-        self.autotune_records = []      # autotune_record/sweep payloads
-        self.autotune_warm_builds = []  # table-hit rebuild compile proof
         self.cold_start_meta = {}       # cold_start-event payload
         self.cache_info = {}            # compile_cache-event payload
         self.warmstart_loads = []       # warmstart_load payloads
@@ -316,16 +313,16 @@ class PerfLedger:
           run only kept ``step_timer`` window reports, those window
           averages stand in (coarser, still gateable);
         - lattice sites: explicit ``sites`` arg, else the grid shape in
-          the latest ``run_start`` / ``bench_run`` event;
+          the latest ``run_start`` event;
         - scope table: the latest ``trace_summary`` event;
         - bytes/step: the ``compile`` event labeled ``step_label`` (or
           the largest-argument one), argument + output bytes.
 
         :class:`~pystella_tpu.obs.events.EventLog` appends, so a reused
         log file holds several runs; ingestion is scoped to the LATEST
-        run — everything from the last ``run_start``/``bench_run``
-        event on — so a report never averages two runs' step times
-        together (a regression between them would vanish into the mix).
+        run — everything from the last ``run_start`` event on — so a
+        report never averages two runs' step times together (a
+        regression between them would vanish into the mix).
         A log with no run-metadata event is ingested whole.
         """
         led = cls(label=label, sites=sites)
@@ -336,7 +333,7 @@ class PerfLedger:
         all_events = _events.read_events(events_path,
                                          include_rotated=True)
         starts = [i for i, ev in enumerate(all_events)
-                  if ev.get("kind") in ("run_start", "bench_run")]
+                  if ev.get("kind") == "run_start"]
         if starts:
             all_events = all_events[starts[-1]:]
         for ev in all_events:
@@ -369,12 +366,6 @@ class PerfLedger:
                 led.kernel_tiers.append(data)
             elif kind == "block_choice":
                 led.block_choices.append(data)
-            elif kind == "autotune_mismatch":
-                led.autotune_mismatches.append(data)
-            elif kind in ("autotune_record", "autotune_sweep"):
-                led.autotune_records.append({"kind": kind, **data})
-            elif kind == "autotune_warm_build":
-                led.autotune_warm_builds.append(data)
             elif kind == "health":
                 # sentinel health vectors (obs.sentinel): the invariant
                 # scalars become the numerics section's drift series
@@ -538,7 +529,7 @@ class PerfLedger:
                 led.capacity_accounts.append(data)
             elif kind == "capacity_usage":
                 led.capacity_usage = data
-            elif kind in ("run_start", "bench_run"):
+            elif kind == "run_start":
                 led.meta = data
         if not led.samples_ms and window_ms:
             led.samples_ms = window_ms
@@ -621,12 +612,10 @@ class PerfLedger:
         per-step lattice traffic — exact for the Pallas tiers, whose
         kernels read every input and write every output once), the
         chunk-vs-pair per-step HBM-traffic reduction when both tiers
-        ran in the window, and the autotune-table provenance of the
-        block choices (``block_choice`` sources + refused stale
-        entries). ``None`` when the run carried no tier telemetry."""
-        if not (self.kernel_tiers or self.block_choices
-                or self.autotune_mismatches
-                or self.autotune_warm_builds):
+        ran in the window, and where the block choices came from
+        (``block_choice`` sources: explicit pins or the heuristic).
+        ``None`` when the run carried no tier telemetry."""
+        if not (self.kernel_tiers or self.block_choices):
             return None
         rows = {}
         for kt in self.kernel_tiers:
@@ -636,13 +625,11 @@ class PerfLedger:
         tiers = [
             {k: r.get(k) for k in (
                 "label", "entrypoint", "tier", "chunk_depth",
-                "bytes_per_step", "kernels_per_2_steps", "local_shape",
-                "autotune")}
+                "bytes_per_step", "kernels_per_2_steps", "local_shape")}
             for r in rows.values()]
         # measured per-step traffic reduction: the chunked stepper's
         # bytes/step against the pair-tier stepper of the same system
-        # and local shape in the same window (the smoke payload runs
-        # both back to back for exactly this comparison)
+        # and local shape in the same window
         reduction = None
         chunk = next((r for r in tiers
                       if "chunk" in (r.get("tier") or "")), None)
@@ -663,22 +650,10 @@ class PerfLedger:
         for bc in self.block_choices:
             src = bc.get("source") or "?"
             sources[src] = sources.get(src, 0) + 1
-        tables = sorted({r.get("path") for r in self.autotune_records
-                         if r.get("path")})
         return {
             "dispatched": tiers,
             "chunk_vs_pair": reduction,
             "block_choice_sources": sources,
-            "autotune": {
-                "hits": sources.get("autotune", 0),
-                "mismatches_refused": len(self.autotune_mismatches),
-                "tables": tables,
-                # the zero-extra-backend-compiles proof: a table-hit
-                # rebuild dispatched against the warm compilation
-                # cache (last record wins)
-                "warm_build": (self.autotune_warm_builds[-1]
-                               if self.autotune_warm_builds else None),
-            },
         }
 
     def overlap_summary(self):
@@ -1816,9 +1791,6 @@ def render_markdown(rep):
             if isinstance(row.get("bytes_per_step"), (int, float)):
                 extra += (f", {row['bytes_per_step']:,.0f} lattice "
                           "bytes/step")
-            src = (row.get("autotune") or {}).get("source")
-            if src:
-                extra += f", blocks via {src}"
             lines.append(f"- {row.get('label')}.{row.get('entrypoint')}"
                          f": **{row.get('tier')}**{extra}")
         cvp = kt.get("chunk_vs_pair")
@@ -1828,13 +1800,6 @@ def render_markdown(rep):
                 f"{cvp['chunk_bytes_per_step']:,} vs "
                 f"{cvp['pair_bytes_per_step']:,} bytes/step -> "
                 f"{cvp['traffic_reduction']:.1%} less HBM traffic")
-        at = kt.get("autotune") or {}
-        lines.append(
-            f"- autotune: {at.get('hits', 0)} table hit(s), "
-            f"{at.get('mismatches_refused', 0)} stale entr(ies) "
-            "refused"
-            + (f", table {at['tables'][-1]}" if at.get("tables")
-               else ""))
         lines.append("")
     lint = rep.get("lint")
     if lint:
@@ -1901,8 +1866,8 @@ def render_markdown(rep):
     if cs:
         lines += ["## Cold start", ""]
         ph = cs.get("phases") or {}
-        # drivers report different phase sets (bench smoke: import/
-        # build, TPU payload: dial, examples: setup) — render whatever
+        # drivers report different phase sets (import/build, dial,
+        # the examples' setup) — render whatever
         # this run measured, in pipeline order, instead of a fixed
         # key list that dashes out the dial/setup share
         order = ("import_s", "dial_s", "setup_s", "build_s", "trace_s",

@@ -176,8 +176,8 @@ class compile_watch:
     @property
     def backend_compiles(self):
         """Backend (XLA) compiles the block actually paid — THE
-        zero-extra-compiles proof quantity (warm service leases,
-        autotune table-hit rebuilds): the cache-miss count when cache
+        zero-extra-compiles proof quantity (warm service leases): the
+        cache-miss count when cache
         counters were observed, else inferred from any nonzero
         backend-compile span (a backend without cache telemetry)."""
         if self.cache_hits or self.cache_misses:

@@ -112,9 +112,8 @@ for _name in (
     # pallas_stencil_* name: it streams no lattice windows, so no
     # stencil byte rule counts it
     "pallas_bincount",
-    # the whole-RK-chunk (temporal blocking) kernel dispatch and the
-    # persistent autotuner's timed candidate probes (ops.autotune)
-    "chunk_stage", "autotune_probe",
+    # the whole-RK-chunk (temporal blocking) kernel dispatch
+    "chunk_stage",
     # the sanctioned carry_dtype quantization point (ops.fused): the one
     # scope under which an f32->bf16 narrowing is legal; the dataflow
     # lint tier treats any float downcast OUTSIDE this scope as a
@@ -122,8 +121,8 @@ for _name in (
     "carry_quantize",
     # multigrid
     "mg_cycle", "mg_smooth", "mg_residual",
-    # driver-level spans (bench.py's loop / the example's loop)
-    "bench_step", "driver_step",
+    # driver-level span (the example's loop)
+    "driver_step",
     # host spans (host_span): where the main path dispatches and where
     # it waits — doc/observability.md "Host spans" says which call
     # holds each
@@ -155,9 +154,6 @@ for _name in (
     # scope-path rows are absent (longest-match folding keeps a
     # TPU row like `jit(..)/fft_stage/fft.3` in `fft_stage`, not here)
     "all-to-all", "fft",
-    # k-space stencil application through the transform
-    # (ops.fft_stencil)
-    "fft_stencil",
     # the scenario service's request-scoped span vocabulary
     # (obs.spans): the SpanAssembler exports assembled request
     # timelines as Perfetto complete-span rows under THESE names, so

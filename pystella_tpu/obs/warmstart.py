@@ -31,9 +31,7 @@ CLI::
     python -m pystella_tpu.obs.warmstart list --dir DIR
     python -m pystella_tpu.obs.warmstart gc --dir DIR [--dry-run]
 
-(all directories default to ``PYSTELLA_WARMSTART_DIR`` when set,
-which is also the default store location for drivers — ``bench.py``'s
-warm-start leg persists and reloads its artifacts there)
+(all directories default to ``PYSTELLA_WARMSTART_DIR`` when set)
 
 ``export`` builds the lint target registry's step programs (the same
 CPU-safe 8-device builds the IR audit lowers) and serializes each;

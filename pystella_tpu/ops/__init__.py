@@ -5,8 +5,6 @@ from pystella_tpu.ops.derivs import (
 )
 from pystella_tpu.ops.reduction import Reduction, FieldStatistics
 from pystella_tpu.ops.histogram import Histogrammer, FieldHistogrammer
-from pystella_tpu.ops.fft_stencil import (
-    FFTStencil, fft_laplacian, use_fft_stencil)
 
 __all__ = [
     "ElementWiseMap",
@@ -14,5 +12,4 @@ __all__ = [
     "FiniteDifferencer", "expand_stencil", "centered_diff",
     "Reduction", "FieldStatistics",
     "Histogrammer", "FieldHistogrammer",
-    "FFTStencil", "fft_laplacian", "use_fft_stencil",
 ]

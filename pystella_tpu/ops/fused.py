@@ -41,7 +41,6 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from pystella_tpu import config as _config
 from pystella_tpu import field as _field
 from pystella_tpu import step as _step
 from pystella_tpu.obs import events as _events
@@ -126,26 +125,17 @@ class FusedScalarStepper(_step.Stepper):
         against the sequence of pair-stage kernels it replaces (the
         deeper intermediate fields compose through the identical
         per-element arithmetic the pair kernels materialize).
-        ``None`` (default) consults the autotune table, then
-        ``PYSTELLA_CHUNK_STAGES``; ``0`` forces the pair tier. Sharded
-        meshes, window halos beyond the 8-aligned y pad, and
-        VMEM-infeasible shapes degrade to pair kernels with a
-        ``kernel_fallback`` event (the pair tier's own fallbacks to
-        single-stage/XLA below it are unchanged).
+        ``0`` (default) keeps the pair tier. Sharded meshes, window
+        halos beyond the 8-aligned y pad, and VMEM-infeasible shapes
+        degrade to pair kernels with a ``kernel_fallback`` event (the
+        pair tier's own fallbacks to single-stage/XLA below it are
+        unchanged).
     :arg chunk_bx, chunk_by: explicit blocking for the chunk kernel.
-    :arg autotune: the persistent-autotuner consult policy for this
-        build: ``None`` (default) follows ``PYSTELLA_AUTOTUNE`` and the
-        default store, ``False`` skips the table, or an explicit
-        :class:`~pystella_tpu.ops.autotune.AutotuneStore` (hermetic
-        drivers/tests). A table hit supplies the hot-loop kernel's
-        blocking (and the chunk depth when ``chunk_stages`` is None);
-        stale entries are refused like stale warm-start artifacts.
     """
 
-    #: autotune-table key kind + chunk support (the scalar+GW subclass
-    #: overrides: its chunk body is not implemented — requests degrade
-    #: to the pair tier with a kernel_fallback event)
-    _autotune_kind = "fused_scalar"
+    #: chunk support (the scalar+GW subclass overrides: its chunk body
+    #: is not implemented — requests degrade to the pair tier with a
+    #: kernel_fallback event)
     _chunk_supported = True
 
     def __init__(self, sector, decomp, grid_shape, dx, halo_shape=2,
@@ -153,8 +143,7 @@ class FusedScalarStepper(_step.Stepper):
                  dt=None, pair_stages=True, pair_bx=None, pair_by=None,
                  interpret=None, donate=False, resident=None,
                  carry_dtype=None, overlap=None,
-                 chunk_stages=None, chunk_bx=None, chunk_by=None,
-                 autotune=None, **kwargs):
+                 chunk_stages=0, chunk_bx=None, chunk_by=None):
         tableau = tableau or _step.LowStorageRK54
         self._A = tableau._A
         self._B = tableau._B
@@ -213,24 +202,6 @@ class FusedScalarStepper(_step.Stepper):
         # convergence-order-critical runs).
         self._carry_dtype = (None if carry_dtype is None
                              else jnp.zeros((), carry_dtype).dtype)
-        # persistent-autotuner consult (ops.autotune): a live-process-
-        # matching table entry supplies the hot-loop kernel's measured
-        # blocking — and the chunk depth, when the caller left it to
-        # policy — BEFORE the choose_blocks heuristic; stale entries
-        # were already refused by the store (autotune_mismatch event)
-        from pystella_tpu.ops import autotune as _autotune
-        self._autotune_entry, self._autotune_digest = _autotune.consult(
-            self._autotune_kind, self.local_shape, self.h, self.dtype,
-            self.F, gravitational_waves=hasattr(self, "n_hij"),
-            proc_shape=decomp.proc_shape,
-            carry_dtype=self._carry_dtype, store=autotune,
-            tableau=tableau.__name__)
-        entry = self._autotune_entry
-        if chunk_stages is None:
-            if entry is not None and entry.get("chunk") is not None:
-                chunk_stages = int(entry["chunk"])
-            else:
-                chunk_stages = _config.get_int("PYSTELLA_CHUNK_STAGES")
         self._chunk_requested = int(chunk_stages or 0)
         if self._chunk_requested and (self._chunk_requested % 2
                                       or self._chunk_requested < 4):
@@ -271,38 +242,10 @@ class FusedScalarStepper(_step.Stepper):
     #: storage candidates; subclasses extend)
     _carry_names = frozenset({"kf", "kdfdt", "kdfp"})
 
-    def _resolve_blocks(self, kind, bx, by, stages):
-        """Where a kernel's blocking comes from, consulted BEFORE the
-        ``choose_blocks`` heuristic: explicit constructor pins, the
-        ``PYSTELLA_FORCE_BLOCKS`` override, or a live autotune-table
-        entry matching this kernel kind and chunk depth. Returns
-        ``(bx, by, source)`` with ``bx``/``by`` still ``None`` for the
-        heuristic case."""
-        if bx is not None or by is not None:
-            return bx, by, "explicit"
-        forced = _config.getenv("PYSTELLA_FORCE_BLOCKS")
-        if forced:
-            try:
-                fbx, fby = (int(v) for v in str(forced).split(","))
-            except ValueError:
-                raise ValueError(
-                    f"PYSTELLA_FORCE_BLOCKS must be 'bx,by', got "
-                    f"{forced!r}")
-            return fbx, fby, "override"
-        entry = self._autotune_entry
-        if entry is not None:
-            tuned_chunk = int(entry.get("chunk") or 0)
-            hot = (("chunk", tuned_chunk) if tuned_chunk
-                   else ("pair", 0))
-            if ((kind, stages if kind == "chunk" else 0) == hot
-                    and entry.get("bx") and entry.get("by")):
-                return int(entry["bx"]), int(entry["by"]), "autotune"
-        return None, None, "heuristic"
-
     def _emit_block_choice(self, kind, st, source):
-        """The auditable record of what a kernel build actually chose
-        (ROADMAP: the advisor and the ledger's roofline tier rows key
-        on the same table, so advice == reality)."""
+        """The record of what a kernel build chose: ``source`` is
+        ``"explicit"`` (pinned by the caller) or ``"heuristic"``
+        (``choose_blocks``)."""
         _events.emit(
             "block_choice", kernel=kind,
             stencil=type(st).__name__,
@@ -311,7 +254,6 @@ class FusedScalarStepper(_step.Stepper):
             win_halo=getattr(st, "wh", None),
             stages=getattr(st, "stages", 1),
             source=source, local_shape=list(self.local_shape),
-            autotune_digest=self._autotune_digest,
             label=type(self).__name__)
 
     def _build_stencil(self, win_defs, body, out_defs, extra_defs,
@@ -321,10 +263,9 @@ class FusedScalarStepper(_step.Stepper):
         admits them, else (single-device) the whole-lattice-resident
         all-roll kernel — the Z < 128 small-lattice tier (VERDICT r3
         #4). ``resident=True``/``False`` at construction forces the
-        choice. Blocking resolution order: explicit ``bx``/``by`` >
-        ``PYSTELLA_FORCE_BLOCKS`` > a live autotune-table entry for the
-        hot-loop kernel > the ``choose_blocks`` heuristic; the realized
-        choice is recorded as a ``block_choice`` event either way."""
+        choice. The blocking is the caller's ``bx``/``by`` or, without
+        them, the ``choose_blocks`` heuristic's; a ``block_choice``
+        event records which."""
         dtypes = None
         if self._carry_dtype is not None:
             names = (set(win_defs) | set(extra_defs or {})
@@ -334,7 +275,8 @@ class FusedScalarStepper(_step.Stepper):
             if out_carries:
                 body = _quantize_carries(
                     body, {n: self._carry_dtype for n in out_carries})
-        bx, by, source = self._resolve_blocks(kind, bx, by, stages)
+        source = ("heuristic" if bx is None and by is None
+                  else "explicit")
         common = dict(extra_defs=extra_defs, scalar_names=scalar_names,
                       dtype=self.dtype, sum_defs=sum_defs, dtypes=dtypes)
         streaming_error = None
@@ -875,11 +817,6 @@ class FusedScalarStepper(_step.Stepper):
             "kernels_per_2_steps": kernels,
             "bytes_per_step": bytes_total // 2,
             "local_shape": list(self.local_shape),
-            "autotune": {"digest": self._autotune_digest,
-                         "hit": self._autotune_entry is not None,
-                         "source": ("autotune"
-                                    if self._autotune_entry is not None
-                                    else "heuristic")},
         }
 
     def _emit_tier(self, entrypoint):
@@ -1635,10 +1572,8 @@ class FusedPreheatStepper(FusedScalarStepper):
     _carry_names = frozenset({"kf", "kdfdt", "kdfp",
                               "khij", "kdhijdt", "kdhp"})
 
-    #: autotune entries for the scalar+GW system key separately; the
-    #: whole-RK-chunk body is scalar-only so far — a chunk_stages
+    #: the whole-RK-chunk body is scalar-only so far — a chunk_stages
     #: request here degrades to the pair tier (kernel_fallback event)
-    _autotune_kind = "fused_preheat"
     _chunk_supported = False
 
     def __init__(self, sector, gw_sector, decomp, grid_shape, dx,

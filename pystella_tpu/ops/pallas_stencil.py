@@ -46,7 +46,6 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from pystella_tpu import config as _config
 from pystella_tpu.obs import memory as _obs_memory
 from pystella_tpu.obs.scope import (
     in_jax_trace, kernel_scope, trace_scope)
@@ -54,8 +53,8 @@ from pystella_tpu.obs.scope import (
 __all__ = ["StreamingStencil", "ResidentStencil", "OverlapStreamingStencil",
            "Taps", "HY", "LANE",
            "choose_blocks", "feasible_blocks", "sharded_halo",
-           "lap_from_taps", "grad_from_taps", "vmem_limit_bytes",
-           "VMEM_LIMIT_BYTES"]
+           "lap_from_taps", "grad_from_taps", "VMEM_LIMIT_BYTES",
+           "BLOCK_BUDGET_BYTES"]
 
 #: aligned y-halo width (one sublane tile); must be >= the stencil radius
 HY = 8
@@ -71,28 +70,21 @@ LANE = 128
 
 _RING = 4  # x-block ring slots: 3 live + 1 in flight
 
-def vmem_limit_bytes():
-    """Scoped-VMEM limit requested from Mosaic for every compiled stencil
-    kernel. XLA's *default* scoped limit is 16 MB (measured on v5e: the
-    25 MB wave-64^3 resident kernel compiled fine in interpret mode but
-    Mosaic rejected it with "Scoped allocation with size 25.40M and limit
-    16.00M exceeded scoped vmem limit"), far below the 128 MB of physical
-    VMEM — so the Python-level budgets (``choose_blocks``,
-    ``ResidentStencil(budget=...)``) were silently stricter than they
-    claimed. Requesting the limit per kernel via
-    ``CompilerParams(vmem_limit_bytes=...)`` makes the physical capacity
-    available; 100 MB leaves headroom for Mosaic's own scratch.
+#: Scoped-VMEM limit requested from Mosaic for every compiled stencil
+#: kernel (``CompilerParams(vmem_limit_bytes=...)``). XLA's *default*
+#: scoped limit is 16 MB (measured on v5e: the 25 MB wave-64^3 resident
+#: kernel compiled fine in interpret mode but Mosaic rejected it with
+#: "Scoped allocation with size 25.40M and limit 16.00M exceeded scoped
+#: vmem limit"), far below the 128 MB of physical VMEM; 100 MB leaves
+#: headroom for Mosaic's own scratch.
+VMEM_LIMIT_BYTES = 100 * 2**20
 
-    ``PYSTELLA_VMEM_LIMIT_MB`` is read here, at each kernel build —
-    matching how :func:`choose_blocks` reads ``PYSTELLA_BLOCK_BUDGET_MB``
-    — so sweep harnesses can vary it between builds in one process (an
-    import-time read froze the first value for the whole run)."""
-    return int(_config.get_float("PYSTELLA_VMEM_LIMIT_MB") * 2**20)
-
-
-#: import-time snapshot of :func:`vmem_limit_bytes`, kept for callers
-#: that report the configured limit; kernel builds re-read the env.
-VMEM_LIMIT_BYTES = vmem_limit_bytes()
+#: VMEM budget :func:`choose_blocks` fits a streaming kernel's window
+#: ring, pipelined extras/outputs and compute temporaries into. It
+#: decides blockings and tiers (at 384^3 it is what sends the ``-gws``
+#: run to the single-stage ``energy`` kernel), so a change here is a
+#: change to every cell: time it on the chip first.
+BLOCK_BUDGET_BYTES = 24 * 2**20
 
 
 def _compiler_params(interpret):
@@ -100,7 +92,7 @@ def _compiler_params(interpret):
     mode — TPU-specific params are meaningless there)."""
     if interpret:
         return None
-    return pltpu.CompilerParams(vmem_limit_bytes=vmem_limit_bytes())
+    return pltpu.CompilerParams(vmem_limit_bytes=VMEM_LIMIT_BYTES)
 
 
 def sharded_halo(h, px, py):
@@ -136,15 +128,14 @@ def choose_blocks(n_comp, lattice_shape, h, itemsize, n_extra, n_out,
     largest feasible ``by`` (fewer y-blocks to prime the ring for, wider DMA
     rows), then the *smallest* feasible ``bx >= h`` — small x-blocks keep
     the ring slots cheap and pipeline best ((2,128) beat every bx>=4
-    blocking at 128^3; (2,64) beat (2,32) at 512^3). The default 24 MB
-    budget (env ``PYSTELLA_BLOCK_BUDGET_MB``) was calibrated when the
-    kernels ran under XLA's default 16 MB scoped-VMEM limit; the round-5
-    ``vmem_limit_bytes`` request raises the real ceiling to
-    ``PYSTELLA_VMEM_LIMIT_MB`` (100 MB), so larger budgets are now
+    blocking at 128^3; (2,64) beat (2,32) at 512^3). The default budget
+    (:data:`BLOCK_BUDGET_BYTES`, 24 MB) was calibrated when the kernels
+    ran under XLA's default 16 MB scoped-VMEM limit; the kernels now
+    request :data:`VMEM_LIMIT_BYTES` (100 MB), so larger budgets are
     *compilable* — the measured preference for small blocks keeps the
-    conservative default until the persistent autotuner
-    (:mod:`pystella_tpu.ops.autotune`) records a sweep winner for the
-    shape, which kernel builds then consult before this heuristic.
+    conservative default until a timing on the chip (blockings pinned
+    through a stepper's ``bx``/``by``, ``pair_*``, ``chunk_*``
+    arguments) says otherwise; its result is an edit here.
 
     ``win_halo`` is the assembled window's halo width (defaults to the
     stencil radius ``h``); temporal-blocking chunk kernels pass
@@ -153,7 +144,7 @@ def choose_blocks(n_comp, lattice_shape, h, itemsize, n_extra, n_out,
     scales the compute-temporary share of the model (composed stages
     keep ~3 extra window-sized live values each)."""
     if budget is None:
-        budget = int(_config.get_float("PYSTELLA_BLOCK_BUDGET_MB") * 2**20)
+        budget = BLOCK_BUDGET_BYTES
     wh = h if win_halo is None else int(win_halo)
     if wh < h:
         raise ValueError(f"win_halo {wh} below stencil radius {h}")
@@ -163,9 +154,6 @@ def choose_blocks(n_comp, lattice_shape, h, itemsize, n_extra, n_out,
             "feasible streaming blocking (shrink the chunk depth or "
             "use the pair/single-stage kernels)")
     X, Y, Z = lattice_shape
-    # ONE cost model: the heuristic is simply the autotuner candidate
-    # list's preferred (first) entry, so the sweep can never propose a
-    # config this builder would reject — nor vice versa
     feasible = feasible_blocks(n_comp, lattice_shape, h, itemsize,
                                n_extra, n_out, budget=budget,
                                win_halo=win_halo, stages=stages)
@@ -196,11 +184,10 @@ def choose_blocks(n_comp, lattice_shape, h, itemsize, n_extra, n_out,
 def feasible_blocks(n_comp, lattice_shape, h, itemsize, n_extra, n_out,
                     budget=None, win_halo=None, stages=1):
     """Every ``(bx, by)`` the :func:`choose_blocks` VMEM model admits,
-    heuristic-preferred order first — the candidate generator the
-    persistent autotuner (:mod:`pystella_tpu.ops.autotune`) sweeps
-    instead of re-deriving the feasibility rules."""
+    heuristic-preferred order first: what a blocking experiment picks
+    its pins from."""
     if budget is None:
-        budget = int(_config.get_float("PYSTELLA_BLOCK_BUDGET_MB") * 2**20)
+        budget = BLOCK_BUDGET_BYTES
     wh = h if win_halo is None else int(win_halo)
     if wh < h or wh > HY:
         return []

@@ -3,8 +3,7 @@
 ``run()`` stands up a :class:`~pystella_tpu.service.ScenarioService`
 around a small scalar-preheating model and drives it with a
 deterministic multi-tenant request mix that exercises every policy leg
-in one pass — the tier-1 proof (``bench.py --smoke`` wires it in; the
-TPU-window ``service`` leg scales it up):
+in one pass (``tests/test_service.py``, ``tests/test_capacity.py``):
 
 - **mixed tenants and priorities**: three tenants with 2:1:1 fair-share
   weights submit priority-1 work against one WARM signature (armed
@@ -125,10 +124,8 @@ def seeded_fleet_legs():
 
 def build_preheat_model(dtype=np.float32):
     """The loadgen's scenario model: a 2-field scalar-preheating
-    system on the generic XLA path (the same physics as ``bench.py``'s
-    smoke payload, self-contained so the package needs no driver
-    import). Returns the ``builder(grid_shape, decomp)`` the service's
-    model registry wants."""
+    system on the generic XLA path. Returns the ``builder(grid_shape,
+    decomp)`` the service's model registry wants."""
 
     def builder(grid_shape, decomp=None):
         import jax

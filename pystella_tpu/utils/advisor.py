@@ -127,7 +127,7 @@ class ShapeReport:
 
 def advise_shapes(grid_shape, n_devices=1, halo_shape=2,
                   dtype=np.float32, nscalars=2,
-                  gravitational_waves=False, autotune_store=None):
+                  gravitational_waves=False):
     """Report the feasible process meshes for ``grid_shape`` over
     ``n_devices`` and the kernel tier each subsystem takes on each.
 
@@ -138,12 +138,6 @@ def advise_shapes(grid_shape, n_devices=1, halo_shape=2,
     :arg nscalars: scalar field count ``F`` (window widths scale with it).
     :arg gravitational_waves: include the 6-component tensor sector in
         the fused-kernel window accounting.
-    :arg autotune_store: the persistent autotune table to consult per
-        mesh (:class:`~pystella_tpu.ops.autotune.AutotuneStore`) — the
-        SAME lookup the fused-stepper build performs, so the advice
-        names the blocking/chunk depth the kernel will really pick
-        (``None`` follows the ``PYSTELLA_AUTOTUNE`` policy; ``False``
-        skips).
 
     Returns a :class:`ShapeReport`; ``report.format()`` is the printable
     table, ``report.best()`` the recommended mesh. The tier logic
@@ -280,30 +274,6 @@ def advise_shapes(grid_shape, n_devices=1, halo_shape=2,
                 "compiled streaming kernels unavailable; resident/halo "
                 "tiers apply")
 
-        # the persistent autotune table — exactly the lookup the fused
-        # stepper build performs (ops.autotune.consult), so the advice
-        # and the kernel agree on what actually gets built
-        if pz == 1 and autotune_store is not False:
-            try:
-                from pystella_tpu.ops import autotune as _autotune
-                kind = ("fused_preheat" if gravitational_waves
-                        else "fused_scalar")
-                entry, _ = _autotune.consult(
-                    kind, local, h, dtype, F, proc_shape=proc,
-                    gravitational_waves=gravitational_waves,
-                    store=autotune_store)
-                if entry is not None:
-                    chunk = int(entry.get("chunk") or 0)
-                    m.notes.append(
-                        f"autotuned: bx={entry.get('bx')} "
-                        f"by={entry.get('by')} chunk={chunk} "
-                        f"({entry.get('ms_per_step', float('nan')):.3g}"
-                        " ms/step measured) — kernel builds pick this "
-                        "over the heuristic")
-                    if chunk:
-                        m.tiers["fused stepper"] += "+chunk"
-            except Exception:  # noqa: BLE001 — advice must not require
-                pass           # a live jax backend for the table read
         meshes.append(m)
 
     # preference: fused streaming > resident > generic; then pencil FFT;

@@ -25,12 +25,8 @@ def timer(kernel, ntime=200, nwarmup=2, reps=1, min_over_rounds=None):
     arrays), with warmup; mirrors /root/reference/test/common.py:41-56.
 
     ``min_over_rounds=R`` (an int > 1) instead runs R such timed rounds
-    and returns the MINIMUM of the per-round averages — the paired
-    min-estimator the autotune sweep persists its winners with
-    (:mod:`pystella_tpu.ops.autotune` takes ``min`` over its
-    interleaved rounds), so an ad-hoc timing and a persisted autotune
-    record report the same statistic: the noise floor, not the
-    scheduler's bad luck."""
+    and returns the MINIMUM of the per-round averages: the noise
+    floor, not the scheduler's bad luck."""
     result = None
     for _ in range(nwarmup):
         result = kernel()
@@ -101,8 +97,8 @@ class StepTimer:
     bounded deque, newest last) for
     :class:`~pystella_tpu.obs.ledger.PerfLedger` distribution analysis;
     with ``emit_steps=True`` each tick also emits a ``kind="step_time"``
-    run event — the ledger's preferred per-step record (the bench smoke
-    and ``--profile``'d example runs enable it; leave it off for
+    run event — the ledger's preferred per-step record (``--profile``'d
+    example runs enable it; leave it off for
     million-step production runs where one event per step is too chatty).
 
     Every tick also feeds the continuous-performance plane

@@ -10,8 +10,10 @@ parametrize over mesh shapes, exercising the identical ``shard_map`` /
 
 The suite is CPU-only: ``JAX_PLATFORMS`` defaults to ``cpu`` here, before
 jax is first imported. The chip is reached through ``chip_smoke.py`` and
-``bench.py``, one process each; the compiled-kernel evidence lives there
-and in tests/test_tpu_lowering.py (Pallas TPU lowering checks, on CPU).
+the benchmark (``benchmark/run.py``), one process each; the
+compiled-kernel evidence lives there, in tests/test_tpu_compile.py (the
+main path compiled for a described v5e) and in tests/test_tpu_lowering.py
+(Pallas TPU lowering checks, on CPU).
 """
 
 import os
@@ -32,13 +34,6 @@ if "xla_force_host_platform_device_count" not in _flags:
 # PYSTELLA_HALO_OVERLAP=1 pytest ... runs the whole suite overlapped
 # (the bit-exactness contract means results must be identical).
 os.environ.setdefault("PYSTELLA_HALO_OVERLAP", "0")
-
-# Pin the autotune-table consult OFF suite-wide: ambient fused-stepper
-# builds must be hermetic (a table a previous test — or a developer's
-# local sweep — left under bench_results/ must not silently change the
-# blockings the suite compiles). tests/test_autotune.py opts in with
-# explicit per-constructor stores, which beat this env.
-os.environ.setdefault("PYSTELLA_AUTOTUNE", "0")
 
 # Pin the continuous-performance plane's ambient feed OFF suite-wide:
 # the process-default PerfMonitor is global state (per-signature

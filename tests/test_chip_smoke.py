@@ -56,15 +56,6 @@ def test_script_refuses_a_machine_without_a_tpu():
     assert res.stdout == ""
 
 
-def test_bench_refuses_a_machine_without_a_tpu(capsys):
-    """``python bench.py`` measures on a TPU or not at all: no CPU
-    fallback, no line on stdout."""
-    import bench
-    with pytest.raises(SystemExit, match="measures on a TPU"):
-        bench.main(["wave-64^3"])
-    assert capsys.readouterr().out == ""
-
-
 _CACHE_PROBE = """
 import jax
 from pystella_tpu import obs

@@ -12,8 +12,8 @@ from pystella_tpu.ops.fused import FusedPreheatStepper, FusedScalarStepper
 
 # Small-grid bodies run the Pallas stages in interpret mode (f64,
 # bit-exact vs the generic stepper); compiled Mosaic kernels require
-# Z % 128 == 0 and f32 — the on-device check is bench.py's pallas-parity
-# config (fused vs XLA at 128^3 f32). Under a TPU-backed session these
+# Z % 128 == 0 and f32 — the on-device check is chip_smoke.py's
+# fused_parity (fused vs XLA at 512^3 f32). Under a TPU-backed session these
 # logic tests still run (ADVICE r3): arrays are placed on the host CPU
 # device and the kernels forced to interpret mode, so the f64 bit-
 # exactness pins hold without a Mosaic lowering.

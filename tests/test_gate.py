@@ -1,14 +1,11 @@
 """Perf evidence pipeline tests: Perfetto-trace parsing, PerfLedger
 ingestion/derivation, the noise-aware regression gate's verdicts and
-exit codes on synthetic ledgers, and the smoke -> gate end-to-end run
-(pipeline integrity only — no performance assertion on CPU)."""
+exit codes on synthetic ledgers."""
 
 import gzip
 import json
 import os
 import shutil
-import subprocess
-import sys
 
 import numpy as np
 import pytest
@@ -101,7 +98,7 @@ def test_ledger_from_events(tmp_path):
     record, and a trace summary all land in the report."""
     path = str(tmp_path / "run.jsonl")
     with events.EventLog(path) as log:
-        log.emit("bench_run", grid_shape=[16, 16, 16], nsteps=4)
+        log.emit("run_start", grid_shape=[16, 16, 16], nsteps=4)
         log.emit("compile", label="smoke_step", compile_seconds=1.0,
                  argument_bytes=1000, output_bytes=600, temp_bytes=50)
         log.emit("compile", label="helper", compile_seconds=0.1,
@@ -109,7 +106,7 @@ def test_ledger_from_events(tmp_path):
         for i, ms in enumerate([2.0, 2.2, 2.1, 2.3]):
             log.emit("step_time", step=i, ms=ms)
         log.emit("trace_summary", trace_file="/t.json.gz",
-                 scopes={"bench_step": {"count": 4, "total_ms": 8.0,
+                 scopes={"driver_step": {"count": 4, "total_ms": 8.0,
                                         "mean_ms": 2.0}})
     led = ledger.PerfLedger.from_events(path, label="unit",
                                         step_label="smoke_step")
@@ -122,14 +119,14 @@ def test_ledger_from_events(tmp_path):
     assert rep["steps"]["p50_ms"] == pytest.approx(2.15)
     assert rep["throughput"]["site_updates_per_s"] == pytest.approx(
         16**3 * 1e3 / 2.15)
-    assert rep["scopes"]["bench_step"]["count"] == 4
+    assert rep["scopes"]["driver_step"]["count"] == 4
     assert rep["roofline"]["achieved_gbps"] == pytest.approx(
         1600 / (2.15e-3) / 1e9)
     # jax is imported in this process, so the fingerprint is complete
     assert rep["env"]["jax"] and rep["env"]["platform"] == "cpu"
     # markdown renders without blowing up on real content
     md = ledger.render_markdown(rep)
-    assert "bench_step" in md and "Roofline" in md
+    assert "driver_step" in md and "Roofline" in md
 
 
 def test_ledger_scopes_to_latest_run(tmp_path):
@@ -434,7 +431,7 @@ def test_ledger_cold_start_ingestion(tmp_path):
     cold_start section with the trace/compile split per program."""
     path = str(tmp_path / "run.jsonl")
     with events.EventLog(path) as log:
-        log.emit("bench_run", grid_shape=[8, 8, 8])
+        log.emit("run_start", grid_shape=[8, 8, 8])
         log.emit("compile_cache", dir="/c", enabled=True)
         log.emit("compile", label="step", source="aot",
                  trace_seconds=0.4, compile_seconds=1.6,
@@ -504,57 +501,6 @@ def test_gate_cli_exit_codes(tmp_path):
                       "--threshold-pct", "50"]) == 0
 
 
-def test_gate_warns_tpu_report_without_autotune_table():
-    """The lost-coverage pattern: a TPU report that dispatched fused
-    kernels with zero autotune-table hits warns (heuristic blockings
-    measured — sweep the device kind); a CPU/smoke report with the
-    same shape does not, and refused stale entries warn on any
-    platform. Never a failure: untuned evidence is legal, just
-    under-claiming."""
-    def with_tiers(rep, hits=0, refused=0, tier="streaming-chunk"):
-        rep = json.loads(json.dumps(rep))
-        rep["roofline"]["kernel_tiers"] = {
-            "dispatched": [{"label": "FusedScalarStepper",
-                            "entrypoint": "multi_step", "tier": tier,
-                            "bytes_per_step": 1000,
-                            "local_shape": [16, 16, 16]}],
-            "chunk_vs_pair": None,
-            "block_choice_sources": {"autotune": hits},
-            "autotune": {"hits": hits, "mismatches_refused": refused,
-                         "tables": [], "warm_build": None},
-        }
-        return rep
-
-    base = _report(_steady())
-    tpu_untuned = with_tiers(_report(_steady(), platform="tpu",
-                                     device_kind="TPU v5e"))
-    v = gate.compare_reports(with_tiers(base, hits=1), tpu_untuned,
-                             allow_env_mismatch=True,
-                             check_contamination="never")
-    assert v["exit_code"] == 0
-    assert any("autotune-coverage" in w for w in v["warnings"])
-    # tuned TPU report: no warning
-    tpu_tuned = with_tiers(_report(_steady(), platform="tpu",
-                                   device_kind="TPU v5e"), hits=2)
-    v = gate.compare_reports(tpu_tuned, tpu_tuned,
-                             check_contamination="never")
-    assert not any("autotune" in w for w in v["warnings"])
-    # CPU report without a table: silent (smoke runs are legal)
-    cpu = with_tiers(base)
-    v = gate.compare_reports(cpu, cpu)
-    assert not any("autotune-coverage" in w for w in v["warnings"])
-    # refused stale entries warn on any platform
-    cpu_stale = with_tiers(base, refused=2)
-    v = gate.compare_reports(cpu_stale, cpu_stale)
-    assert any("stale table entr" in w for w in v["warnings"])
-    # the xla-only tier row never triggers the coverage warning
-    tpu_xla = with_tiers(_report(_steady(), platform="tpu",
-                                 device_kind="TPU v5e"), tier="xla")
-    v = gate.compare_reports(tpu_xla, tpu_xla,
-                             check_contamination="never")
-    assert not any("autotune-coverage" in w for w in v["warnings"])
-
-
 def test_ledger_comm_join_from_events(tmp_path):
     """The modeled-vs-measured comm join, from synthetic events: the
     lint event's static_comm block supplies the model, halo_traffic
@@ -563,7 +509,7 @@ def test_ledger_comm_join_from_events(tmp_path):
     total that also carries scalar all-reduces)."""
     path = str(tmp_path / "run.jsonl")
     with events.EventLog(path) as log:
-        log.emit("bench_run", grid_shape=[16, 16, 16], nsteps=4)
+        log.emit("run_start", grid_shape=[16, 16, 16], nsteps=4)
         for ms in (2.0, 2.1):
             log.emit("step_time", ms=ms)
         log.emit("trace_summary", scopes={
@@ -599,644 +545,6 @@ def test_ledger_comm_join_from_events(tmp_path):
     # a run with neither model nor counter carries no comm section
     bare = str(tmp_path / "bare.jsonl")
     with events.EventLog(bare) as log:
-        log.emit("bench_run", grid_shape=[8, 8, 8])
+        log.emit("run_start", grid_shape=[8, 8, 8])
         log.emit("step_time", ms=1.0)
     assert ledger.PerfLedger.from_events(bare).report()["comm"] is None
-
-
-# -- smoke -> gate end to end ---------------------------------------------
-
-def test_smoke_to_gate_end_to_end(tmp_path, capsys):
-    """Tier-1 pipeline integrity: ``bench.py --smoke`` writes a real
-    perf_report.json (per-scope breakdown, throughput, environment
-    fingerprint), and ``python -m pystella_tpu.obs.gate`` consumes it —
-    0 on self-comparison, nonzero on a synthetic degradation, nonzero
-    with invalid_evidence on a synthetic contamination burst. No
-    performance assertion: CPU numbers only gate against themselves."""
-    out = str(tmp_path / "bench_results")
-    cache_dir = str(tmp_path / "xla_cache")
-    env = dict(os.environ)
-    env["JAX_PLATFORMS"] = "cpu"
-    env["JAX_COMPILATION_CACHE_DIR"] = cache_dir
-    env["PYTHONPATH"] = REPO
-
-    def run_smoke(out_dir, *extra):
-        return subprocess.run(
-            [sys.executable, os.path.join(REPO, "bench.py"), "--smoke",
-             "--grid", "16", "--steps", "12", "--out", out_dir, *extra],
-            capture_output=True, text=True, timeout=300, env=env)
-
-    # COLD leg: fresh compilation cache — every backend compile misses
-    res = run_smoke(out)
-    assert res.returncode == 0, res.stderr[-2000:]
-
-    report_path = os.path.join(out, "perf_report.json")
-    rep = json.load(open(report_path))
-    assert rep["steps"]["count"] == 12
-    assert rep["throughput"]["site_updates_per_s"] > 0
-    assert rep["env"]["platform"] == "cpu" and rep["env"]["jax"]
-    # the profiler capture parsed into a real per-scope breakdown
-    assert rep["scopes"].get("bench_step", {}).get("count") == 12
-    # ... including the overlapped-halo payload's scope names and the
-    # ledger's exposed-vs-hidden communication derivation
-    assert rep["scopes"].get("halo_overlap", {}).get("count") == 6
-    assert rep["scopes"].get("collective-permute", {}).get("count")
-    assert rep["overlap"]["comm_ms"] > 0
-    assert rep["overlap"]["exposed_ms"] is not None
-    assert rep["overlap"]["halo_bytes_per_step"] > 0
-    assert rep["env"].get("xla_flags") is not None
-    md = open(os.path.join(out, "perf_report.md")).read()
-    assert "Communication overlap" in md and "exposed" in md
-    # the numerics sentinel ran end to end: per-step health events,
-    # an invariant drift series, no trips, bounded overhead telemetry
-    nm = rep["numerics"]
-    assert nm["invariants"]["kinetic_mean"]["n"] == 12
-    assert np.isfinite(nm["invariants"]["kinetic_mean"]["drift_per_step"])
-    assert nm["diverged"] == []
-    assert nm["health_checks"] == 12
-    assert nm["sentinel_overhead_pct"] is not None
-    assert "Numerics health" in md
-    # the ensemble payload ran end to end: a full batch with ONE
-    # forced-divergent member completed, the report carries
-    # member-steps/s and exactly one eviction naming the member and
-    # its parameter draw, and the run stays VALID evidence (a member
-    # eviction is per-draw physics, not a run failure — numerics
-    # `diverged` above is empty and the gate legs below exit 0)
-    en = rep["ensemble"]
-    assert en["size"] >= 8
-    assert en["member_steps_per_s"] > 0
-    assert en["members_completed"] >= 8
-    assert en["occupancy_mean"] > 0
-    assert en["evictions"] == 1
-    evr = en["eviction_records"][0]
-    assert evr["scenario"] == "preheat-16^3"
-    assert evr["member"] is not None and evr["params"]["seed"] == 1
-    assert en["chunks"]["count"] > 0
-    assert "## Ensemble" in md
-    # the supervised (elastic-runtime) payload AND the re-mesh drill
-    # ran end to end: an injected mid-run device-loss fault survived
-    # via restore-from-last-good, plus a persistent device-subset
-    # fault (half the 8-device mesh lost) survived via the
-    # RemeshPlanner default policy — TWO incidents total, each with a
-    # measured MTTR and a replay bounded by the checkpoint interval,
-    # the supervisors' claims consistent with the event record, and
-    # the durability split visible (saves scheduled AND durable)
-    rz = rep["resilience"]
-    assert rz["n_incidents"] == 2 and rz["resolved"] == 2, rz
-    assert rz["consistent"] is True and rz["completed"] is True
-    for rz_inc in rz["incidents"]:
-        assert rz_inc["kind"] == "device_loss"
-        assert rz_inc["mttr_s"] > 0
-        assert rz_inc["steps_replayed"] <= 4
-    assert rz["checkpoints"]["durable"] >= 2
-    assert rz["checkpoints"]["fallbacks"] == 0
-    assert rz["faults_injected"] == 2
-    assert "## Resilience" in md
-    # the remesh drill's degraded block: the remesh_plan decision
-    # record (8 -> 4 devices), and the throughput per-chip
-    # normalization flipped to the SURVIVORS — which is exactly what
-    # the gate's degraded-throughput audit accepts below
-    deg = rz["degraded"]
-    assert deg["remesh_plans"], deg
-    assert deg["old_mesh"] == [2, 2, 2]
-    assert deg["devices_used"] == 4 and deg["lost_devices"] == 4
-    assert rep["throughput"]["per_chip"]["basis"] == "surviving"
-    assert rep["throughput"]["per_chip"]["chips"] == 4
-    assert "re-mesh: [2, 2, 2] ->" in md
-    # the sharded-spectra payload ran end to end: the pencil FFT tier
-    # (explicit all_to_all transposes) timed inside the capture, the
-    # report's `fft` section populated — per-call distribution, the
-    # 5 N log2 N flops model, and per-stage rows from the trace's raw
-    # fft/all-to-all op rows — and the lint report carries the
-    # spectra program's collective audit (all-to-all allowlisted, no
-    # all-gather: the transform provably never replicated a field)
-    ff = rep["fft"]
-    assert ff["scheme"] == "pencil-a2a"
-    assert ff["calls"] == 4 and ff["ms"]["p50_ms"] > 0
-    assert ff["model"]["nfields"] == 2
-    assert ff["model"]["model_flops"] > 0
-    assert ff["model"]["achieved_gflops"] > 0
-    assert ff["stages"]["fft_transpose"]["count"] > 0
-    assert ff["transpose_exposed_ms"] is not None
-    assert "FFT / spectra" in md
-    # the fused-tier + autotune payload ran end to end: the whole-RK-
-    # chunk kernel DISPATCHED (kernel_tier record) with a measured
-    # per-step HBM-traffic reduction vs the pair tier it replaces
-    # (the acceptance criterion's roofline line), the sweep persisted
-    # a winner table for this device kind (readable ACROSS processes
-    # — this test process reloads it through the same store), the
-    # table-hit rebuild chose its blocking from the table
-    # (block_choice source="autotune"), and its dispatch against the
-    # warm compilation cache performed ZERO extra backend compiles
-    kt = rep["roofline"]["kernel_tiers"]
-    tiers = {r["tier"] for r in kt["dispatched"]}
-    assert "streaming-chunk" in tiers and "pair" in tiers, tiers
-    cvp = kt["chunk_vs_pair"]
-    assert cvp["chunk_bytes_per_step"] < cvp["pair_bytes_per_step"]
-    assert cvp["traffic_reduction"] > 0.3, cvp
-    assert kt["block_choice_sources"].get("autotune", 0) >= 1, kt
-    at = kt["autotune"]
-    assert at["hits"] >= 1 and at["mismatches_refused"] == 0
-    wb = at["warm_build"]
-    assert wb["table_hit"] is True
-    assert wb["backend_compiles"] == 0, wb
-    assert wb["cache_hits"] >= 1
-    assert "Kernel tiers dispatched" in md
-    assert "less HBM traffic" in md
-    # cross-process reload of the persisted winner, keyed on
-    # fingerprint + device kind: the smoke SUBPROCESS swept and wrote
-    # the table; this process's store lookup must serve the entry
-    # (same versions/flags) for exactly the swept key
-    from pystella_tpu.ops import autotune as ps_autotune
-    at_store = ps_autotune.AutotuneStore(root=out, device_kind="cpu")
-    assert os.path.basename(at_store.path) == "autotune_cpu.json"
-    entry, digest = ps_autotune.consult(
-        "fused_scalar", (16, 16, 16), 2, np.float32, 2,
-        store=at_store)
-    assert entry is not None and entry["key"]["kind"] == "fused_scalar"
-    assert entry["bx"] and entry["by"] and "ms_per_step" in entry
-    at_kinds = {r["kind"] for r in events.read_events(
-        os.path.join(out, "smoke_events.jsonl"))}
-    assert {"kernel_tier", "block_choice", "autotune_record",
-            "autotune_sweep", "autotune_warm_build"} <= at_kinds
-    # the scenario-service payload ran end to end: the seeded loadgen
-    # mix completed with warm admissions whose leases recorded ZERO
-    # backend compiles (the compile-ledger proof of dispatch-never-
-    # compile), one cold signature queued behind its build (cold TTFS
-    # visibly above warm), one quota rejection, and one preemption
-    # whose resumed members are bit-consistent with uninterrupted
-    # replays — the report's `service` section carries all of it
-    sv = rep["service"]
-    assert sv["completed"] == 8 and sv["diverged"] == 0
-    # the quota rejection plus the PR-19 seeded capacity hog
-    assert sv["rejected"] == {"quota": 1, "capacity_exceeded": 1}
-    assert sv["preemptions"] == 1
-    assert sv["warm_claimed"] is True
-    assert all(a["fingerprint_ok"] for a in sv["warm_admissions"])
-    assert sv["warm_leases"] >= 3
-    assert sv["warm_lease_backend_compiles"] == 0
-    assert sv["lease_failures"] == 0
-    ql = sv["queue_latency_s"]
-    assert ql["overall"]["count"] >= 9
-    assert {"1", "3"} <= set(ql["by_priority"])
-    assert sv["ttfs_s"]["cold"]["count"] == 1
-    assert sv["ttfs_s"]["cold"]["p50_s"] > sv["ttfs_s"]["warm"]["p50_s"]
-    assert set(sv["tenant_share"]) == {"alpha", "bravo", "charlie"}
-    assert sv["loadgen"]["preempt_bitexact"] is True
-    assert "## Service" in md
-    svc_kinds = {r["kind"] for r in events.read_events(
-        os.path.join(out, "smoke_events.jsonl"))}
-    assert {"service_start", "service_request", "service_admit",
-            "service_reject", "service_arm", "service_dispatch",
-            "service_lease", "service_preempted", "service_requeue",
-            "member_result", "service_done", "deadline_missed",
-            "service_trace", "service_loadgen"} <= svc_kinds
-    # the request-tracing layer ran end to end: every loadgen request's
-    # span tree assembled from the event log, the critical-path phases
-    # sum to the measured submit->retire wall within tolerance, the
-    # seeded deadline pair recorded one MISS and one hit, and the
-    # Perfetto service timeline sits next to the report
-    lat = rep["latency"]
-    assert lat["traced"] == lat["assembled"] == 10
-    assert lat["unassembled"] == []
-    assert lat["phase_sum_check"]["ok"] is True
-    assert lat["phase_sum_check"]["max_rel_err"] < 0.05
-    assert {"service_queue_wait", "service_chunk_compute",
-            "service_compile",
-            "service_preempt_drain"} <= set(lat["phases_s"])
-    assert lat["deadline"]["deadlined"] == 2
-    assert lat["deadline"]["missed"] == 1
-    assert lat["deadline"]["miss_rate"] == 0.5
-    assert lat["deadline"]["by_priority"]["1"]["missed"] == 1
-    preempted_rows = [r for r in lat["requests"] if r["leases"] > 1]
-    assert preempted_rows, "the preempted requests cross >1 lease"
-    assert "## Latency (request critical path)" in md
-    svc_trace_path = os.path.join(out, "service_trace.json")
-    assert os.path.exists(svc_trace_path)
-    from pystella_tpu.obs import trace as obs_trace
-    svc_rows = obs_trace.parse_trace_file(svc_trace_path)
-    svc_table = obs_trace.scope_durations(svc_rows)
-    assert svc_table.get("service_request_span", {}).get("count") == 10
-    # the fleet drill ran end to end: two replicas announced into the
-    # registry and aggregated live (the queue-depth gauge federated
-    # per replica), the seeded fleet burn alert fired AND resolved
-    # from replica-a's deadline story, replica-b's mid-run kill landed
-    # as fleet_replica_lost (heartbeat expiry, not a tombstone), and
-    # the report's fleet section says — honestly — that its coverage
-    # is partial; the gate cases below pin both the annotation and the
-    # refusal of the same record claiming completeness
-    fl = rep["fleet"]
-    assert [r["replica"] for r in fl["replicas"]] \
-        == ["replica-a", "replica-b"]
-    assert fl["replicas_lost"] == [{"replica": "replica-b",
-                                    "reason": "expired",
-                                    "age_s": fl["replicas_lost"][0]
-                                    ["age_s"]}]
-    assert fl["coverage"]["complete"] is False
-    assert fl["coverage"]["lost"] == 1
-    assert fl["endpoint_failed"] == 1
-    assert fl["scrapes"] >= 3
-    fal = fl["alerts"]
-    assert fal["alerts"] == 2 and fal["resolved"] == 1
-    assert [u["leg"] for u in fal["unresolved"]] == ["dead_replicas"]
-    assert fl["legs"]["queue_p95"]["value_fast"] is not None
-    assert fl["skew"]["skewed"] is False and fl["divergence"] == []
-    assert fl["announces"] == 2 and fl["withdraws"] == 1
-    assert "## Fleet (replica registry + federation)" in md
-    fleet_kinds = {r["kind"] for r in events.read_events(
-        os.path.join(out, "smoke_events.jsonl"))}
-    assert {"fleet_announce", "fleet_scrape", "fleet_alert",
-            "fleet_resolved", "fleet_replica_lost", "fleet_withdraw",
-            "fleet_loadgen"} <= fleet_kinds
-    assert "smoke_fleet_failed" not in fleet_kinds
-    # the capacity & goodput plane ran end to end: every armed program
-    # footprinted, the seeded hog rejected with the predicted-vs-budget
-    # numbers that justify it, per-tenant chip-second accounts with
-    # positive goodput, no OOM, and the CPU host's coverage honestly
-    # predicted-only (zero watermark samples, never claimed complete)
-    cp = rep["capacity"]
-    assert cp["footprints"], cp
-    assert cp["rejections"]["count"] == 1
-    rej = cp["rejections"]["last"]
-    assert rej["tenant"] == "charlie"
-    assert rej["predicted_bytes"] > rej["budget_bytes"]
-    assert cp["goodput"] and cp["goodput"] > 0
-    assert cp["committed_steps"] > 0 and cp["total_chip_s"] > 0
-    assert set(cp["tenants"]) == {"alpha", "bravo", "charlie"}
-    cap_cov = cp["coverage"]
-    assert cap_cov["predicted_only"] is True
-    assert cap_cov["complete"] is False
-    assert cap_cov["watermark_samples"] == 0
-    assert cp["oom_bundles"] == []
-    assert "Capacity & goodput" in md
-    cap_kinds = {r["kind"] for r in events.read_events(
-        os.path.join(out, "smoke_events.jsonl"))}
-    assert {"capacity_footprint", "capacity_reject",
-            "capacity_account", "capacity_usage"} <= cap_kinds
-    assert "smoke_capacity_failed" not in cap_kinds
-    lint_rep = json.load(open(os.path.join(out, "lint_report.json")))
-    spec_stats = lint_rep["graph"]["smoke_spectra"]
-    coll = spec_stats["collectives"]
-    assert "all-to-all" in {**coll["seen"], **coll["small"]}
-    assert "all-gather" not in coll["seen"]
-    assert "all-gather" not in coll["small"]
-    assert spec_stats["fusion"]["scopes"]["fft_stage"] is True
-    # the dataflow tier ran over every dispatched program: precision
-    # flow clean, and each program carries a static comm model
-    assert "precision-flow" in lint_rep["summary"]["checks"]
-    assert "static-comm" in lint_rep["summary"]["checks"]
-    assert {"smoke_step", "smoke_spectra", "smoke_overlap"} \
-        <= set(lint_rep["graph"])
-    assert lint_rep["graph"]["smoke_step"]["precision"]["ok"] is True
-    assert lint_rep["graph"]["smoke_overlap"]["static_comm"][
-        "per_invocation_bytes"].get("halo")
-    # ... and the ledger joined it against the measured traffic: the
-    # report's comm section pairs the overlap program's modeled halo
-    # bytes with the halo_traffic event's measured per-invocation ICI
-    # bytes — byte-exact at this size (both derive from the same slab
-    # shapes), so the leg is within the gate's excess threshold
-    cm = rep["comm"]
-    assert cm["covered"] is True
-    halo_leg = [leg for leg in cm["legs"]
-                if leg["target"] == "smoke_overlap"][0]
-    assert halo_leg["class"] == "halo"
-    assert halo_leg["modeled_bytes"] > 0
-    assert halo_leg["measured_bytes"] == pytest.approx(
-        halo_leg["modeled_bytes"])
-    assert halo_leg["within"] is True and halo_leg["calls"] == 6
-    spec_leg = [leg for leg in cm["legs"]
-                if leg["target"] == "smoke_spectra"][0]
-    assert spec_leg["modeled_bytes"] > 0
-    assert spec_leg["measured_bytes"] is None  # model-only row
-    assert "Modeled vs measured communication" in md
-    rz_kinds = {r["kind"] for r in events.read_events(
-        os.path.join(out, "smoke_events.jsonl"))}
-    assert {"fault_injected", "fault_detected", "recovery_attempt",
-            "run_resumed", "checkpoint_durable", "remesh_plan",
-            "run_degraded", "supervisor_done"} <= rz_kinds
-    ens_kinds = {r["kind"] for r in events.read_events(
-        os.path.join(out, "smoke_events.jsonl"))}
-    assert {"ensemble_run", "ensemble_chunk", "ensemble_done",
-            "member_started", "member_evicted",
-            "member_finished"} <= ens_kinds
-    # the event log behind it holds the full pipeline record
-    kinds = {r["kind"] for r in events.read_events(
-        os.path.join(out, "smoke_events.jsonl"))}
-    assert {"bench_run", "compile", "step_time", "trace_summary",
-            "perf_report", "health", "cold_start", "compile_cache",
-            "warmstart_export"} <= kinds
-
-    # the cold leg's cold_start section: a full time-to-first-step
-    # breakdown, a per-program compile table with the trace/compile
-    # split, a cache MISS for the step program, and a verified
-    # (bit-exact, fingerprint-matched) AOT warm-start round trip
-    cold_cs = rep["cold_start"]
-    ph = cold_cs["phases"]
-    assert cold_cs["time_to_first_step_s"] > 0
-    assert all(ph[k] >= 0 for k in
-               ("import_s", "build_s", "trace_s", "compile_s",
-                "first_dispatch_s"))
-    step_rows = [c for c in cold_cs["compiles"]
-                 if c["label"] == "smoke_step"]
-    assert step_rows and step_rows[0]["cache_hit"] is False
-    assert step_rows[0]["trace_s"] > 0 and step_rows[0]["compile_s"] > 0
-    assert step_rows[0]["fingerprint_kind"] == "lowered"
-    assert cold_cs["cache"]["dir"] == cache_dir
-    ws = cold_cs["warmstart"]
-    assert ws["claimed"] is True
-    assert ws["artifacts"][0]["match"] is True
-    assert ws["artifacts"][0]["bitexact"] is True
-    assert "Cold start" in md
-
-    # WARM leg: same cache dir, fresh out dir — the PR acceptance
-    # criterion: cache hit rate >= 0.9 and a strictly lower
-    # time-to-first-step, with the warm-start round trip still
-    # bit-exact
-    # (--no-ensemble/--no-supervised/--no-spectra/--no-service/
-    # --no-fleet: those payloads proved themselves on the cold leg
-    # above; rerunning them would spend tier-1 budget re-verifying the
-    # same pipeline. Gating warm-vs-cold below therefore also covers
-    # the lost-ensemble-, lost-resilience-, lost-fft-, lost-service-,
-    # AND lost-fleet-coverage WARNING paths: exit stays 0 — and the
-    # fft comparison never runs on the CPU smoke's 4-sample spectra
-    # times, which jitter beyond any honest threshold.)
-    out2 = str(tmp_path / "bench_results_warm")
-    res2 = run_smoke(out2, "--no-ensemble", "--no-supervised",
-                     "--no-spectra", "--no-remesh", "--no-service",
-                     "--no-autotune", "--no-fleet")
-    assert res2.returncode == 0, res2.stderr[-2000:]
-    warm = json.load(open(os.path.join(out2, "perf_report.json")))
-    warm_cs = warm["cold_start"]
-    assert warm_cs["cache"]["hit_rate"] >= 0.9, warm_cs["cache"]
-    assert warm_cs["time_to_first_step_s"] \
-        < cold_cs["time_to_first_step_s"]
-    warm_step = [c for c in warm_cs["compiles"]
-                 if c["label"] == "smoke_step"][0]
-    assert warm_step["cache_hit"] is True
-    assert warm_cs["warmstart"]["artifacts"][0]["bitexact"] is True
-    # gating warm against cold passes (a faster cold start is an
-    # improvement, not a regression; the loose step threshold keeps
-    # CPU scheduler jitter out of THIS assertion — step-time gating
-    # has its own cases above)
-    warm_path = str(tmp_path / "warm_report.json")
-    json.dump(warm, open(warm_path, "w"))
-    assert gate.main(["--baseline", report_path, "--current", warm_path,
-                      "--threshold-pct", "300"]) == 0
-
-    def run_gate(*args):
-        return subprocess.run(
-            [sys.executable, "-m", "pystella_tpu.obs.gate", *args],
-            capture_output=True, text=True, timeout=120, env=env)
-
-    # self-comparison passes
-    res = run_gate("--baseline", report_path, "--current", report_path)
-    assert res.returncode == 0, res.stderr[-2000:]
-
-    # synthetic degradation fails the gate. ADDITIVE (+3x the baseline
-    # median on every sample), not multiplicative: scaling the samples
-    # scales their MAD — and with it the gate's noise bar — so on a
-    # noisy CPU run a 2x scale can legitimately hide inside its own
-    # inflated bar (observed: MAD ~half the median under a loaded
-    # tier-1 run). A constant shift keeps the measured jitter honest
-    # while the +300% delta is unambiguous at any plausible MAD.
-    # (`resilience` is stripped first: the real smoke report records
-    # the supervised drill's incident, and a regression measured
-    # across a recorded incident is — by design — annotated instead of
-    # gated; the degraded-annotation acceptance case follows below.)
-    slow = {k: v for k, v in rep.items() if k != "resilience"}
-    slow["samples_ms"] = [x + 3.0 * rep["steps"]["p50_ms"]
-                          for x in rep["samples_ms"]]
-    slow["steps"] = ledger.step_stats(slow["samples_ms"])
-    slow_path = str(tmp_path / "slow.json")
-    json.dump(slow, open(slow_path, "w"))
-    res = run_gate("--baseline", report_path, "--current", slow_path)
-    assert res.returncode == 1, (res.stdout, res.stderr[-2000:])
-
-    # the SAME degradation with the smoke run's real resilience
-    # section kept: its single incident is a harness DRILL
-    # (faults_injected covers it, and the drill runs outside the timed
-    # window), so the regression verdict stays ARMED — exit 1 — while
-    # the verdict is still annotated degraded. The ever-present smoke
-    # drill must not disarm CI; the REAL-incident softening path is
-    # pinned in tests/test_resilience.py. Driven in-process (same
-    # argparse -> verdict -> exit path as the subprocess runs, without
-    # another interpreter + jax startup against the tier-1 budget).
-    slow_deg = dict(slow)
-    slow_deg["resilience"] = rep["resilience"]
-    assert rep["resilience"]["faults_injected"] == 2
-    slow_deg_path = str(tmp_path / "slow_degraded.json")
-    json.dump(slow_deg, open(slow_deg_path, "w"))
-    assert gate.main(["--baseline", report_path,
-                      "--current", slow_deg_path]) == 1
-    capsys.readouterr()
-    deg_verdict = gate.compare_reports(rep, slow_deg)
-    assert deg_verdict["exit_code"] == 1
-    assert deg_verdict["degraded"] is True
-    assert any("drill" in w for w in deg_verdict["warnings"])
-    # ... and the PR acceptance: the smoke report CARRYING its drill
-    # incident is accepted-with-degraded-annotation on a clean
-    # comparison — never refused for merely recording an incident
-    self_verdict = gate.compare_reports(rep, rep)
-    assert self_verdict["exit_code"] == 0
-    assert self_verdict["degraded"] is True
-    assert any("recorded incident" in w for w in self_verdict["warnings"])
-    # ... the fleet half of the same honesty rule: the smoke record's
-    # lost replica is annotated (never refused) while it stays honest
-    assert any("degraded fleet evidence" in w and "replica-b" in w
-               for w in self_verdict["warnings"])
-    # the refusal: the SAME record mutated into a complete-coverage
-    # claim over its own lossy scrapes is invalid evidence, exit 2
-    fake_fleet = json.loads(json.dumps(rep))
-    fake_fleet["fleet"]["coverage"]["complete"] = True
-    fake_verdict = gate.compare_reports(rep, fake_fleet)
-    assert fake_verdict["exit_code"] == 2
-    assert any(r.startswith("invalid_evidence: report claims complete "
-                            "fleet coverage") for r in
-               fake_verdict["reasons"])
-    # --no-fleet opts out of exactly that refusal (argparse -> verdict
-    # path, same as the subprocess runs)
-    fake_fleet_path = str(tmp_path / "fake_fleet.json")
-    json.dump(fake_fleet, open(fake_fleet_path, "w"))
-    assert gate.main(["--baseline", report_path,
-                      "--current", fake_fleet_path, "--no-fleet"]) == 0
-    capsys.readouterr()
-    # the capacity half of the same honesty rule: the CPU smoke's
-    # predicted-only coverage is annotated on the self-comparison...
-    assert any("predicted-only" in w for w in self_verdict["warnings"])
-    # ... while the SAME record mutated into a complete-coverage claim
-    # over its zero watermark samples is refused, exit 2
-    fake_cap = json.loads(json.dumps(rep))
-    fake_cap["capacity"]["coverage"].update(
-        complete=True, predicted_only=False, leases=5, leases_sampled=5)
-    fake_cap_verdict = gate.compare_reports(rep, fake_cap)
-    assert fake_cap_verdict["exit_code"] == 2
-    assert any(r.startswith("invalid_evidence: report claims complete "
-                            "capacity coverage") for r in
-               fake_cap_verdict["reasons"])
-    fake_cap_path = str(tmp_path / "fake_capacity.json")
-    json.dump(fake_cap, open(fake_cap_path, "w"))
-    assert gate.main(["--baseline", report_path,
-                      "--current", fake_cap_path, "--no-capacity"]) == 0
-    capsys.readouterr()
-    # goodput regression on the REAL smoke report: chips burning on
-    # waste drives the gate to exit 1 naming goodput
-    burned = json.loads(json.dumps(rep))
-    burned["capacity"]["goodput"] = rep["capacity"]["goodput"] / 10.0
-    burned_verdict = gate.compare_reports(rep, burned)
-    assert burned_verdict["exit_code"] == 1
-    assert any("goodput regression" in r
-               for r in burned_verdict["reasons"])
-    # the comm legs on the REAL smoke report: measured halo traffic
-    # inflated >25% over the static model exits 1 naming the leg; a
-    # comm section claiming coverage with no model behind it is
-    # refused (exit 2); --no-comm opts out of both — driven in-process
-    # (same argparse -> verdict -> exit path as the subprocess runs)
-    comm_bad = json.loads(json.dumps(rep))
-    for leg in comm_bad["comm"]["legs"]:
-        if leg["target"] == "smoke_overlap":
-            leg["measured_bytes"] = leg["modeled_bytes"] * 1.5
-    comm_bad_path = str(tmp_path / "comm_excess.json")
-    json.dump(comm_bad, open(comm_bad_path, "w"))
-    assert gate.main(["--baseline", report_path, "--current",
-                      comm_bad_path, "--threshold-pct", "300"]) == 1
-    capsys.readouterr()
-    comm_verdict = gate.compare_reports(rep, comm_bad)
-    assert comm_verdict["exit_code"] == 1
-    assert any("comm excess" in r and "smoke_overlap" in r
-               for r in comm_verdict["reasons"])
-    forged_comm = json.loads(json.dumps(rep))
-    forged_comm["comm"] = {"covered": True, "legs": [
-        {"target": "smoke_overlap", "class": "halo",
-         "modeled_bytes": None, "measured_bytes": 5120.0}]}
-    forged_verdict = gate.compare_reports(rep, forged_comm)
-    assert forged_verdict["exit_code"] == 2
-    assert any("comm coverage" in r for r in forged_verdict["reasons"])
-    assert gate.main(["--baseline", report_path, "--current",
-                      comm_bad_path, "--threshold-pct", "300",
-                      "--no-comm"]) == 0
-    capsys.readouterr()
-
-    # synthetic contamination burst -> invalid evidence (the detector
-    # is forced on: auto-mode skips it for CPU reports, where scheduler
-    # stalls are legitimate; resilience stripped — with a recorded
-    # incident the same burst would be annotated, not refused, which
-    # tests/test_resilience.py pins). The burst is ADDITIVE for the
-    # same reason the degradation synthetic above is: a noisy tier-1
-    # host inflates the run's MAD and with it the outlier threshold
-    # (median + max(5·1.4826·MAD, 0.25·median)), so a multiplicative
-    # 5x burst can land under its own inflated bar (observed once in a
-    # loaded suite run); +6·median +10·MAD clears the threshold at any
-    # plausible noise level.
-    cont = {k: v for k, v in rep.items() if k != "resilience"}
-    samples = rep["samples_ms"] * 3
-    bump = (6.0 * rep["steps"]["p50_ms"]
-            + 10.0 * (rep["steps"]["mad_ms"] or 0.0))
-    for i in range(12, 18):
-        samples[i] += bump
-    cont["samples_ms"] = samples
-    cont["steps"] = ledger.step_stats(samples)
-    cont_path = str(tmp_path / "cont.json")
-    json.dump(cont, open(cont_path, "w"))
-    res = run_gate("--baseline", report_path, "--current", cont_path,
-                   "--check-contamination", "always")
-    assert res.returncode == 2, (res.stdout, res.stderr[-2000:])
-    assert "invalid_evidence" in res.stdout
-
-    # synthetic constraint-drift regression: same step times, but the
-    # tracked invariant's drift slope blown up 1000x -> the NUMERICS
-    # gate exits nonzero and names the invariant. Driven through
-    # gate.main() in-process — the same argparse -> verdict -> exit
-    # path as the subprocess runs above, without another interpreter
-    # + jax startup against the tier-1 budget.
-    drift = dict(rep)
-    drift["numerics"] = json.loads(json.dumps(rep["numerics"]))
-    inv = drift["numerics"]["invariants"]["kinetic_mean"]
-    inv["drift_per_step"] = 1000.0 * (
-        abs(inv["drift_per_step"]) or 1e-6)
-    drift_path = str(tmp_path / "drift.json")
-    json.dump(drift, open(drift_path, "w"))
-    assert gate.main(["--baseline", report_path,
-                      "--current", drift_path]) == 1
-    capsys.readouterr()  # swallow the verdict prints
-    verdict = gate.compare_reports(rep, drift)
-    assert any("numerics regression" in r and "kinetic_mean" in r
-               for r in verdict["reasons"])
-
-    # the service SLO legs on the REAL smoke report: a seeded
-    # queue-latency regression exits 1 naming the SLO, and a claimed
-    # warm admission over a mismatched fingerprint is refused (exit 2)
-    # — driven in-process (same argparse -> verdict -> exit path as
-    # the subprocess runs, without another interpreter + jax startup
-    # against the tier-1 budget)
-    slow_q = json.loads(json.dumps(rep))
-    q = slow_q["service"]["queue_latency_s"]["overall"]
-    q["p95_s"] = q["p95_s"] * 50 + 30.0
-    slow_q_path = str(tmp_path / "slow_queue.json")
-    json.dump(slow_q, open(slow_q_path, "w"))
-    assert gate.main(["--baseline", report_path,
-                      "--current", slow_q_path]) == 1
-    capsys.readouterr()
-    verdict = gate.compare_reports(rep, slow_q)
-    assert any("queue-latency p95" in r for r in verdict["reasons"])
-    bad_warm = json.loads(json.dumps(rep))
-    bad_warm["service"]["warm_admissions"][0]["fingerprint_ok"] = False
-    bad_warm_path = str(tmp_path / "bad_warm.json")
-    json.dump(bad_warm, open(bad_warm_path, "w"))
-    assert gate.main(["--baseline", report_path,
-                      "--current", bad_warm_path]) == 2
-    assert gate.main(["--baseline", report_path,
-                      "--current", bad_warm_path, "--no-service"]) == 0
-    capsys.readouterr()
-
-    # the deadline-miss SLO leg on the REAL smoke report: against a
-    # clean baseline (misses zeroed) the run's seeded miss drives the
-    # gate to exit 1 naming the SLO; --no-latency opts out — and the
-    # self-comparison above already proved equal miss rates pass
-    clean_dl = json.loads(json.dumps(rep))
-    clean_dl["latency"]["deadline"].update(missed=0, miss_rate=0.0)
-    clean_dl_path = str(tmp_path / "clean_deadline.json")
-    json.dump(clean_dl, open(clean_dl_path, "w"))
-    assert gate.main(["--baseline", clean_dl_path,
-                      "--current", report_path]) == 1
-    capsys.readouterr()
-    verdict = gate.compare_reports(clean_dl, rep)
-    assert any("deadline-miss SLO regression" in r
-               for r in verdict["reasons"])
-    assert verdict["latency"]["current_miss_rate"] == 0.5
-    assert gate.main(["--baseline", clean_dl_path,
-                      "--current", report_path, "--no-latency"]) == 0
-    capsys.readouterr()
-
-    # the static-analysis tier ran end to end inside the smoke run: the
-    # report carries a PASSING `lint` section (clean repo, donated
-    # smoke step) and lint_report.json sits next to the perf report
-    lint = rep["lint"]
-    assert lint["ok"] is True, lint
-    assert lint["errors"] == 0
-    assert {"host-sync", "env-registry", "scope-registry", "donation",
-            "collectives", "host"} <= set(lint["checks"])
-    assert lint["donation"]["coverage_pct"] == 100.0
-    assert os.path.exists(os.path.join(out, "lint_report.json"))
-    assert "## Lint" in md and "donation coverage" in md
-
-    # a FAILED lint refuses the evidence (exit 2), whatever the step
-    # times say; --no-lint opts out
-    bad = dict(rep)
-    bad["lint"] = {"ok": False, "errors": 3,
-                   "first_errors": ["[error] donation: smoke_step: ..."]}
-    bad_path = str(tmp_path / "badlint.json")
-    json.dump(bad, open(bad_path, "w"))
-    assert gate.main(["--baseline", report_path,
-                      "--current", bad_path]) == 2
-    assert gate.main(["--baseline", report_path, "--current", bad_path,
-                      "--no-lint"]) == 0
-    capsys.readouterr()
-    verdict = gate.compare_reports(rep, bad)
-    assert verdict["exit_code"] == 2
-    assert any("static analysis FAILED" in r for r in verdict["reasons"])
-    # losing lint coverage relative to the baseline is a warning
-    nolint = {k: v for k, v in rep.items() if k != "lint"}
-    verdict = gate.compare_reports(rep, nolint)
-    assert verdict["exit_code"] == 0
-    assert any("lint coverage was lost" in w
-               for w in verdict["warnings"])

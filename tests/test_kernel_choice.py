@@ -1,0 +1,326 @@
+"""Which kernel and blocking a run gets, and from where.
+
+A kernel's blocking has two sources: the constructor's pins
+(``bx``/``by``, ``pair_bx``/``pair_by``, ``chunk_bx``/``chunk_by``) or
+``choose_blocks``; the tier is what fits. The first test holds the
+steppers and operators the benchmark's cells build to the kernels the
+ledger's numbers were measured with: construction only, at the cells'
+own shapes (read from ``benchmark/configs/*.json``), no lattice
+allocated. The expected values were recorded from commit 66181e3, before
+the autotune table, ``PYSTELLA_FORCE_BLOCKS``, ``PYSTELLA_CHUNK_STAGES``
+and the budget variables went; a change to ``choose_blocks`` that moves
+one of them moves a cell, and belongs in a PR that times it on the chip.
+"""
+
+import functools
+import json
+import os
+import warnings
+from unittest import mock
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+import pystella_tpu as ps
+from pystella_tpu.obs import events
+from pystella_tpu.ops import pallas_stencil as psten
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+_TPU_SESSION = jax.default_backend() == "tpu"
+_XKW = {"interpret": True} if _TPU_SESSION else {}
+
+
+def _devs(n):
+    return (jax.devices("cpu") if _TPU_SESSION else jax.devices())[:n]
+
+
+class _watch_events:
+    """Collect the records emitted over a ``with`` block."""
+
+    def __enter__(self):
+        self.records = []
+        self._log = events.get_log()
+        self._log.subscribe(self.records.append)
+        return self
+
+    def __exit__(self, *exc):
+        self._log.unsubscribe(self.records.append)
+
+    def of(self, kind):
+        return [r["data"] for r in self.records if r["kind"] == kind]
+
+
+# -- the cells -------------------------------------------------------------
+
+@functools.lru_cache(maxsize=None)
+def _built(name):
+    """What a cell of configuration ``name`` builds, as its family does
+    (``benchmark/families/*.py``): the stepper with ``tableau, dtype, dt,
+    donate`` and nothing else, the kernels the coupled chunk adds, and
+    the ``lap`` and ``grad`` operators of the feedback and the outputs.
+    Compiled-mode kernels (``interpret=False``), never called."""
+    with open(os.path.join(REPO, "benchmark", "configs",
+                           name + ".json")) as f:
+        cfg = json.load(f)
+    grid = tuple(cfg["grid_shape"])
+    proc = tuple(cfg["proc_shape"])
+    ndev = int(np.prod(proc))
+    if ndev > len(_devs(ndev)):
+        return None
+    dtype = np.dtype(cfg["dtype"])
+    h = int(cfg["halo_shape"])
+    decomp = ps.DomainDecomposition(proc, devices=_devs(ndev))
+    lattice = ps.Lattice(grid, tuple(cfg["box_dim"]), dtype=dtype)
+    mphi, gsq = cfg["mphi"], cfg["gsq"]
+
+    def potential(f):
+        return (mphi**2 / 2 * f[0]**2
+                + gsq / 2 * f[0]**2 * f[1]**2) / mphi**2
+
+    sector = ps.ScalarSector(cfg["nscalars"], potential=potential)
+    kw = dict(tableau=getattr(ps, cfg["stepper"]), dtype=dtype,
+              dt=cfg["kappa"] * min(lattice.dx), donate=True,
+              interpret=False)
+    with _watch_events() as seen, warnings.catch_warnings(record=True) \
+            as warned:
+        warnings.simplefilter("always")
+        if cfg.get("gravitational_waves"):
+            stepper = ps.FusedPreheatStepper(
+                sector, ps.TensorPerturbationSector([sector]), decomp,
+                grid, lattice.dx, h, carry_dtype=cfg.get("carry_dtype"),
+                **kw)
+        else:
+            stepper = ps.FusedScalarStepper(sector, decomp, grid,
+                                            lattice.dx, h, **kw)
+        # what coupled_multi_step builds at its first call
+        coupled_pair = stepper._ensure_coupled_pair_calls()
+        stepper._ensure_energy_call()
+    choices = {}
+    for d in seen.of("block_choice"):
+        choices.setdefault(d["kernel"], []).append(d)
+
+    # the operators' kernels emit no event: watch them being built
+    class Recorded(psten.StreamingStencil):
+        def __init__(self, *args, **kwargs):
+            super().__init__(*args, **kwargs)
+            pinned = (kwargs.get("bx") is not None
+                      or kwargs.get("by") is not None)
+            choices.setdefault(self.kind, []).append({
+                "kernel": self.kind, "stencil": "StreamingStencil",
+                "bx": self.bx, "by": self.by, "grid": list(self.grid),
+                "source": "explicit" if pinned else "heuristic"})
+
+    derivs = ps.FiniteDifferencer(decomp, h, lattice.dx)
+    with mock.patch.object(psten, "StreamingStencil", Recorded):
+        for op in ("lap", "grad"):
+            derivs._pallas_op(op, cfg["nscalars"], dtype, False, grid)
+    return {"choices": choices, "tier": stepper.kernel_tier_report(),
+            "coupled_pair": coupled_pair,
+            "warnings": [str(w.message) for w in warned]}
+
+
+#: (configuration, kernel, which build of that kind, (bx, by), grid,
+#: source). `energy` is "explicit" because the stepper hands it the
+#: stage kernel's blocking; ``None`` for a kernel that fits no blocking.
+_CELL_KERNELS = [
+    ("preheat-512-f32", "stage", 0, (2, 64), (8, 256), "heuristic"),
+    ("preheat-512-f32", "pair", 0, (2, 32), (16, 256), "heuristic"),
+    # the chunk's first pair (state in) and the rest (deferred drag in)
+    ("preheat-512-f32", "coupled_pair", 0, (2, 32), (16, 256),
+     "heuristic"),
+    ("preheat-512-f32", "coupled_pair", 1, (2, 32), (16, 256),
+     "heuristic"),
+    ("preheat-512-f32", "energy", 0, (2, 64), (8, 256), "explicit"),
+    ("preheat-512-f32", "lap", 0, (2, 128), (4, 256), "heuristic"),
+    ("preheat-512-f32", "grad", 0, (2, 128), (4, 256), "heuristic"),
+    # 512^3 per chip on (2, 2, 1): pre-padded windows, the same blocks
+    ("preheat-mesh4-f32", "stage", 0, (2, 64), (8, 256), "heuristic"),
+    ("preheat-mesh4-f32", "coupled_pair", 0, (2, 32), (16, 256),
+     "heuristic"),
+    ("preheat-mesh4-f32", "coupled_pair", 1, (2, 32), (16, 256),
+     "heuristic"),
+    ("preheat-mesh4-f32", "lap", 0, (2, 128), (4, 256), "heuristic"),
+    # -gws at 384^3: 32 components a stage
+    ("preheat-gw-f32", "stage", 0, (2, 16), (24, 192), "heuristic"),
+    ("preheat-gw-f32", "energy", 0, (2, 16), (24, 192), "explicit"),
+    ("preheat-gw-f32", "pair", 0, (2, 8), (48, 192), "heuristic"),
+    # the deferred pair's 32 window components fit no blocking: the
+    # coupled chunk runs `energy`, five calls a step (PERF.md section 4)
+    ("preheat-gw-f32", "coupled_pair", 1, None, None, None),
+    ("preheat-gw-f32", "lap", 0, (2, 128), (3, 192), "heuristic"),
+    ("preheat-gw-f32", "grad", 0, (2, 128), (3, 192), "heuristic"),
+]
+
+
+@pytest.mark.parametrize(
+    "config, kernel, nth, blocks, grid, source", _CELL_KERNELS,
+    ids=[f"{c}-{k}{n or ''}" for c, k, n, *_ in _CELL_KERNELS])
+def test_cells_get_the_kernels_the_ledger_measured(config, kernel, nth,
+                                                   blocks, grid, source):
+    built = _built(config)
+    if built is None:
+        pytest.skip(f"{config} needs more devices than this host has")
+    # the tier multi_step dispatches: five pair kernels per two steps
+    assert built["tier"]["tier"] == "pair"
+    assert built["tier"]["kernels_per_2_steps"] == {"pair": 5}
+    assert built["tier"]["chunk_depth"] is None
+    made = built["choices"].get(kernel, [])
+    if blocks is None:
+        assert len(made) == nth
+        assert built["coupled_pair"] is None
+        assert any("coupled pair kernels unavailable" in w
+                   and "32 window components fits the 24 MB" in w
+                   for w in built["warnings"]), built["warnings"]
+        return
+    if kernel == "coupled_pair":
+        assert built["coupled_pair"] is not None
+    d = made[nth]
+    assert d["stencil"] == "StreamingStencil"
+    assert (d["bx"], d["by"]) == blocks
+    assert tuple(d["grid"]) == grid
+    assert d["source"] == source
+
+
+# -- pins, refusals, events ------------------------------------------------
+
+_GRID = (16, 16, 16)
+
+
+def _potential(f):
+    return 0.5 * 1.2e-2 * f[0] ** 2 + 0.125 * f[0] ** 2 * f[1] ** 2
+
+
+def _scalar(**kw):
+    sector = ps.ScalarSector(2, potential=_potential)
+    decomp = ps.DomainDecomposition((1, 1, 1), devices=_devs(1))
+    return ps.FusedScalarStepper(sector, decomp, _GRID, (0.3,) * 3, 2,
+                                 dtype=jnp.float32, **_XKW, **kw)
+
+
+def _gw(**kw):
+    sector = ps.ScalarSector(2, potential=_potential)
+    decomp = ps.DomainDecomposition((1, 1, 1), devices=_devs(1))
+    return ps.FusedPreheatStepper(
+        sector, ps.TensorPerturbationSector([sector]), decomp, _GRID,
+        (0.3,) * 3, 2, dtype=jnp.float32, **_XKW, **kw)
+
+
+def _state(rng, names=("f", "dfdt"), ncomp=2):
+    return {n: jnp.asarray(
+        0.1 * rng.standard_normal((ncomp,) + _GRID).astype(np.float32))
+        for n in names}
+
+
+@pytest.mark.parametrize("kind, common, pins, attr", [
+    ("stage", {"pair_stages": False}, {"bx": 4, "by": 8}, "_scalar_st"),
+    ("pair", {}, {"pair_bx": 4, "pair_by": 8}, "_pair_st"),
+    ("chunk", {"chunk_stages": 4}, {"chunk_bx": 4, "chunk_by": 8},
+     "_chunk_st"),
+], ids=["stage", "pair", "chunk"])
+def test_pins_beat_the_heuristic_and_say_so(kind, common, pins, attr):
+    """A pinned blocking is the one built, the event says ``explicit``,
+    and blocking never enters the mathematics: two steps are bit-equal
+    to the heuristic build's."""
+    with _watch_events() as seen:
+        heuristic = _scalar(**common)
+    assert {d["source"] for d in seen.of("block_choice")} == {"heuristic"}
+    chosen = getattr(heuristic, attr)
+    assert (chosen.bx, chosen.by) != (4, 8)
+    with _watch_events() as seen:
+        pinned = _scalar(**common, **pins)
+    st = getattr(pinned, attr)
+    assert (st.bx, st.by) == (4, 8)
+    sources = {d["kernel"]: d["source"] for d in seen.of("block_choice")}
+    assert sources.pop(kind) == "explicit"
+    assert set(sources.values()) <= {"heuristic"}
+
+    args = {"a": np.float32(1.2), "hubble": np.float32(0.3)}
+    results = []
+    for stepper in (heuristic, pinned):
+        out = stepper.multi_step(_state(np.random.default_rng(31)), 2,
+                                 0.0, np.float32(0.01), args)
+        results.append({k: np.asarray(v) for k, v in out.items()})
+    for name in ("f", "dfdt"):
+        assert np.array_equal(*(r[name] for r in results)), \
+            f"{name}: a pinned blocking changed the numbers"
+
+
+@pytest.mark.parametrize("build", [_scalar, _gw], ids=["scalar", "gw"])
+def test_unknown_constructor_argument_is_refused(build):
+    """A misspelt pin is an error, not silently the heuristic."""
+    with pytest.raises(TypeError, match="pair_bby"):
+        build(pair_bby=8)
+
+
+@pytest.mark.parametrize("build, names", [
+    (_scalar, ("f", "dfdt")),
+    (_gw, ("f", "dfdt", "hij", "dhijdt")),
+], ids=["scalar", "gw"])
+def test_events_carry_what_the_benchmark_prints(build, names):
+    """``benchmark/run.py`` prints ``kernel <kernel>: <stencil> (bx, by)
+    = (<bx>, <by>) from <source>`` of every ``block_choice`` and ``tier
+    at <entrypoint>: <tier>, <kernels_per_2_steps> per 2 steps`` of
+    every ``kernel_tier``."""
+    rng = np.random.default_rng(5)
+    with _watch_events() as seen:
+        stepper = build()
+        state = _state(rng, names[:2])
+        for n in names[2:]:
+            state.update(_state(rng, (n,), 6))
+        stepper.multi_step(state, 1, 0.0, np.float32(0.01),
+                           {"a": np.float32(1.0),
+                            "hubble": np.float32(0.1)})
+    choices = seen.of("block_choice")
+    assert {d["kernel"] for d in choices} == {"stage", "pair"}
+    for d in choices:
+        assert {"kernel", "stencil", "bx", "by", "grid", "source",
+                "local_shape", "label"} <= set(d)
+        assert d["source"] in ("explicit", "heuristic")
+        assert d["grid"] == [16 // d["by"], 16 // d["bx"]]
+    tiers = seen.of("kernel_tier")
+    assert [d["entrypoint"] for d in tiers] == ["multi_step"]
+    for d in tiers:
+        assert {"entrypoint", "tier", "kernels_per_2_steps",
+                "chunk_depth", "bytes_per_step", "local_shape",
+                "label"} <= set(d)
+        assert d["tier"] == "pair"
+    for d in choices + tiers:
+        assert not any("autotune" in key for key in d)
+
+
+def test_a_stray_table_or_variable_changes_nothing(tmp_path, monkeypatch):
+    """A winner table left in the working directory and the variables
+    that once overrode the choice are not read: the blocking, the tier
+    and the VMEM request are the heuristic's and the constants'."""
+    clean = _scalar()
+    budgeted = psten.choose_blocks(6, (512, 512, 512), 2, 4, 2, 8)
+    (tmp_path / "bench_results").mkdir()
+    (tmp_path / "bench_results" / "autotune_cpu.json").write_text(
+        json.dumps({"version": 1, "entries": {"0" * 16: {
+            "components": {"kind": "fused_scalar"},
+            "winner": {"bx": 4, "by": 8, "chunk": 4}}}}))
+    monkeypatch.chdir(tmp_path)
+    for name, value in [
+            ("PYSTELLA_AUTOTUNE", "1"),
+            ("PYSTELLA_AUTOTUNE_DIR", str(tmp_path / "bench_results")),
+            ("PYSTELLA_FORCE_BLOCKS", "4,8"),
+            ("PYSTELLA_CHUNK_STAGES", "4"),
+            ("PYSTELLA_BLOCK_BUDGET_MB", "1"),
+            ("PYSTELLA_VMEM_LIMIT_MB", "48")]:
+        monkeypatch.setenv(name, value)
+    with _watch_events() as seen:
+        strayed = _scalar()
+    for attr in ("_scalar_st", "_pair_st"):
+        assert ((getattr(strayed, attr).bx, getattr(strayed, attr).by)
+                == (getattr(clean, attr).bx, getattr(clean, attr).by))
+    assert strayed._chunk_call is None
+    assert {d["source"] for d in seen.of("block_choice")} == {"heuristic"}
+    assert strayed.kernel_tier_report() == clean.kernel_tier_report()
+    assert psten.choose_blocks(6, (512, 512, 512), 2, 4, 2, 8) == budgeted
+    assert psten.BLOCK_BUDGET_BYTES == 24 * 2**20
+    assert (psten._compiler_params(False).vmem_limit_bytes
+            == psten.VMEM_LIMIT_BYTES == 100 * 2**20)
+    assert psten._compiler_params(True) is None  # interpret mode

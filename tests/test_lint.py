@@ -91,14 +91,14 @@ def test_env_registry_statically_recovered():
     names = lint_source.registered_env_vars(
         os.path.join(PKG, "config.py"))
     assert {"PYSTELLA_EVENT_LOG", "PYSTELLA_HALO_OVERLAP",
-            "BENCH_PROFILE", "XLA_FLAGS"} <= names
+            "JAX_COMPILATION_CACHE_DIR", "XLA_FLAGS"} <= names
     # and it matches the live registry exactly
     assert names == set(ps.config.registered())
 
 
 def test_config_accessors():
     assert ps.config.getenv("PYSTELLA_HALO_OVERLAP") is not None
-    assert ps.config.get_float("PYSTELLA_VMEM_LIMIT_MB") > 0
+    assert ps.config.get_float("PYSTELLA_FFT_REPLICATE_LIMIT") > 0
     with pytest.raises(KeyError):
         ps.config.getenv("PYSTELLA_NOT_A_KNOB")
     snap = ps.config.snapshot()

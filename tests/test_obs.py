@@ -325,19 +325,6 @@ def test_multigrid_unknown_kwargs_raise(make_decomp):
                             defer_errors=False)
 
 
-def test_vmem_limit_read_per_build(monkeypatch):
-    """Satellite: PYSTELLA_VMEM_LIMIT_MB is read at each kernel build,
-    not once at import."""
-    from pystella_tpu.ops import pallas_stencil as psten
-    monkeypatch.setenv("PYSTELLA_VMEM_LIMIT_MB", "48")
-    assert psten.vmem_limit_bytes() == 48 * 2**20
-    params = psten._compiler_params(interpret=False)
-    assert params.vmem_limit_bytes == 48 * 2**20
-    monkeypatch.setenv("PYSTELLA_VMEM_LIMIT_MB", "64")
-    assert psten._compiler_params(False).vmem_limit_bytes == 64 * 2**20
-    assert psten._compiler_params(True) is None  # interpret mode
-
-
 def test_multigrid_cycle_emits_event(event_log, make_decomp):
     """One tiny FAS V-cycle logs an mg_cycle event with final errors and
     bumps the cycle counters."""

@@ -1,7 +1,8 @@
 """Correctness tests for the streaming Pallas stencil kernels (interpret
-mode on CPU). The TPU-compiled path is exercised by bench.py on hardware;
-these verify the window/ring/wrap logic bit-exactly against numpy rolls
-(reference analog: /root/reference/test/test_derivs.py stencil checks)."""
+mode on CPU). The TPU-compiled path is exercised by chip_smoke.py and
+the benchmark on hardware; these verify the window/ring/wrap logic
+bit-exactly against numpy rolls (reference analog:
+/root/reference/test/test_derivs.py stencil checks)."""
 
 import jax
 import jax.numpy as jnp
@@ -12,13 +13,14 @@ from pystella_tpu.ops.pallas_stencil import LANE, StreamingStencil
 
 # These bodies verify window/ring/wrap logic bit-exactly (f64, interpret
 # mode) on small grids; compiled Mosaic kernels require Z % LANE == 0 and
-# f32, so the on-device parity check lives in bench.py (pallas-parity,
-# 128^3 f32) rather than here. Applied per-test (not module-wide) so the
-# backend-independent guard test below still runs on TPU.
+# f32, so the on-device parity check lives in chip_smoke.py
+# (fused_parity, 512^3 f32) rather than here. Applied per-test (not
+# module-wide) so the backend-independent guard test below still runs on
+# TPU.
 interpret_only = pytest.mark.skipif(
     jax.default_backend() == "tpu",
     reason="interpret-mode f64 bodies on sub-lane-tile grids; compiled "
-           "coverage: bench.py pallas-parity at 128^3")
+           "coverage: chip_smoke.py fused_parity at 512^3")
 
 
 def test_compiled_requires_lane_aligned_z():

@@ -1,7 +1,7 @@
 """Pencil-FFT subsystem tests: the fully distributed shard_map tier
 (fourier/pencil.py) bit-compared against the declarative DFT tiers and
-``numpy.fft``, the scheme planner, the spectra/projection fast path,
-the FFT-stencil lever, and the evidence pipeline's new `fft` surface
+``numpy.fft``, the scheme planner, the spectra/projection fast path, and the
+evidence pipeline's `fft` surface
 (ledger section, gate verdict, lint collective audit)."""
 
 import json
@@ -202,55 +202,6 @@ def test_pencil_poisson_and_collocator(decomp, grid_shape, proc_shape):
 
 
 # ---------------------------------------------------------------------------
-# FFT-stencil lever
-# ---------------------------------------------------------------------------
-
-@pytest.mark.parametrize("proc_shape", [(2, 2, 1)], indirect=True)
-def test_fft_stencil_matches_direct_tier(decomp, grid_shape, proc_shape):
-    """fft_laplacian through the pencil transform equals the direct
-    FiniteDifferencer Laplacian on periodic fields (stencil-consistent
-    eigenvalues — exact up to transform roundoff), and n repeated
-    applications through ONE transform pair equal n direct sweeps."""
-    lat = ps.Lattice(grid_shape, (5.0,) * 3, dtype=np.float64)
-    fft = ps.make_dft(decomp, grid_shape=grid_shape, dtype=np.float64,
-                      scheme="pencil")
-    st = ps.fft_laplacian(fft, lat.dx, halo_shape=2)
-    fd = ps.FiniteDifferencer(decomp, 2, lat.dx)
-    rng = np.random.default_rng(47)
-    fx = rng.standard_normal(grid_shape)
-
-    l_fft = np.asarray(st(decomp.shard(fx)))
-    l_dir = np.asarray(fd.lap(decomp.shard(fx)))
-    assert np.allclose(l_fft, l_dir, atol=1e-10)
-
-    twice_fft = np.asarray(st(decomp.shard(fx), repeats=2))
-    twice_dir = np.asarray(fd.lap(fd.lap(decomp.shard(fx))))
-    assert np.allclose(twice_fft, twice_dir, atol=1e-7)
-
-
-def test_fft_stencil_crossover_policy(monkeypatch):
-    """The flops crossover model: compact single applications keep the
-    direct tier, large radius x repeats flip to the FFT path, and the
-    env forces either way."""
-    from pystella_tpu.ops import fft_stencil as fs
-    grid = (512,) * 3
-    # one application of the production radius-2 stencil: direct wins
-    assert not ps.use_fft_stencil(grid, radius=2)
-    # radius 4 repeated 16x: ~3x the transform-pair flops -> FFT path
-    assert ps.use_fft_stencil(grid, radius=4, repeats=16)
-    # monotone in repeats and radius
-    assert fs.stencil_flops(grid, 4, 16) > fs.stencil_flops(grid, 4, 1)
-    assert fs.transform_flops(grid) == 2 * fs.transform_flops(grid,
-                                                              pair=False)
-    # env force beats the model; explicit override beats the env
-    monkeypatch.setenv("PYSTELLA_FFT_STENCIL", "1")
-    assert ps.use_fft_stencil(grid, radius=1)
-    monkeypatch.setenv("PYSTELLA_FFT_STENCIL", "0")
-    assert not ps.use_fft_stencil(grid, radius=4, repeats=64)
-    assert ps.use_fft_stencil(grid, radius=4, repeats=64, override=True)
-
-
-# ---------------------------------------------------------------------------
 # evidence pipeline: lint collective audit, ledger `fft` section, gate
 # ---------------------------------------------------------------------------
 
@@ -343,7 +294,7 @@ def test_ledger_fft_section(tmp_path):
     from pystella_tpu.obs.ledger import PerfLedger
     path = tmp_path / "ev.jsonl"
     log = EventLog(str(path))
-    log.emit("bench_run", grid_shape=[16, 16, 16], nsteps=4)
+    log.emit("run_start", grid_shape=[16, 16, 16], nsteps=4)
     for ms in (10.0, 11.0, 12.0):
         log.emit("spectra_time", ms=ms)
     log.emit("fft_spectra", scheme="pencil-a2a",

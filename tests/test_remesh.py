@@ -268,9 +268,8 @@ def _drill_host_state(grid):
 
 def _drill_build_step(grid, emit_times=False):
     def build_step(dec):
-        import bench
-        stepper, _, dt = bench.build_preheat_step(
-            grid, fused=False, decomp=dec, make_state=False)
+        import chip_smoke
+        stepper, dt = chip_smoke.build_preheat_step(grid, dec)
         args = {"a": np.float32(1.0), "hubble": np.float32(0.5)}
 
         def step_fn(st, i):
@@ -308,7 +307,7 @@ def test_supervisor_default_planner_degraded_continuation(tmp_path):
         dec = ps.DomainDecomposition((2, 2, 2),
                                      devices=jax.devices()[:8])
         state = {k: dec.shard(v) for k, v in host.items()}
-        events.emit("bench_run", grid_shape=list(grid), nsteps=12)
+        events.emit("run_start", grid_shape=list(grid), nsteps=12)
 
         planner = resilience.RemeshPlanner(dec, grid, build_step,
                                            halo=2, label="t-remesh")
@@ -445,7 +444,7 @@ def test_swap_refreshes_monitor_and_restore_path(tmp_path):
 def test_ledger_degraded_block_from_events(tmp_path):
     path = str(tmp_path / "run.jsonl")
     with events.EventLog(path) as log:
-        log.emit("bench_run", grid_shape=[8, 8, 8])
+        log.emit("run_start", grid_shape=[8, 8, 8])
         for ms in (2.0, 2.1, 2.05):
             log.emit("step_time", ms=ms)
         log.emit("fault_detected", step=9, fault_kind="device_loss",
@@ -494,7 +493,7 @@ def test_ledger_blip_plan_is_not_degradation(tmp_path):
     'all'."""
     path = str(tmp_path / "run.jsonl")
     with events.EventLog(path) as log:
-        log.emit("bench_run", grid_shape=[8, 8, 8])
+        log.emit("run_start", grid_shape=[8, 8, 8])
         for ms in (2.0, 2.1, 2.05):
             log.emit("step_time", ms=ms)
         log.emit("remesh_plan", step=9, old_proc_shape=[2, 2, 2],
@@ -524,7 +523,7 @@ def test_ledger_per_chip_uses_post_remesh_samples(tmp_path):
     throughput ~2x in the smoke drill shape."""
     path = str(tmp_path / "run.jsonl")
     with events.EventLog(path) as log:
-        log.emit("bench_run", grid_shape=[8, 8, 8])
+        log.emit("run_start", grid_shape=[8, 8, 8])
         for _ in range(9):
             log.emit("step_time", ms=2.0)   # full mesh, fast
         log.emit("remesh_plan", step=9, old_proc_shape=[2, 2, 2],

@@ -1,6 +1,6 @@
 """Elastic-runtime tests (pystella_tpu.resilience): the retry/backoff
-classifier promoted out of bench.py's orchestrator, checkpoint
-durability semantics, and the Supervisor's recovery round trips —
+classifier, checkpoint durability semantics, and the Supervisor's
+recovery round trips —
 injected device loss and NaN faults survived end to end on the CPU
 mesh, bit-consistent with an uninterrupted run; SIGTERM preemption
 drained to a durable checkpoint in a subprocess and resumed; the
@@ -547,7 +547,7 @@ def test_preemption_drain_health_checks_before_saving(tmp_path):
 def test_ledger_resilience_ingestion(tmp_path):
     path = str(tmp_path / "run.jsonl")
     with events.EventLog(path) as log:
-        log.emit("bench_run", grid_shape=[8, 8, 8])
+        log.emit("run_start", grid_shape=[8, 8, 8])
         log.emit("checkpoint_save", step=4, durable=False)
         log.emit("checkpoint_durable", step=4, wait_s=0.02)
         log.emit("checkpoint_save", step=8, durable=False)

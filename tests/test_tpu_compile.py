@@ -10,7 +10,7 @@ v5e 2x2 host: on the (2, 2, 1) mesh at the real 512**3, on one chip at
 512 x 128 x 512 (the same kernels and blockings over a quarter of the
 y-slabs; the chip smoke runs the full size). They prove a program
 compiles and fits HBM; they say nothing about what it computes or how
-fast (``chip_smoke.py`` and ``bench.py`` do, on the chip). Tier-1 runs
+fast (``chip_smoke.py`` and the benchmark do, on the chip). Tier-1 runs
 the coupled chunk on the mesh — every energy-emitting kernel, with x-
 and y-halo windows; the rest is ``slow``-marked: run the whole file
 (``-m "slow or not slow"``, under a minute) after touching a kernel.

@@ -12,7 +12,7 @@ rejection is caught before chip time is spent.
 
 (What this cannot catch: Mosaic/XLA *compile* failures on the device
 side — scoped-VMEM overflows, HBM OOM. Those budgets are gated in Python
-and validated on the chip by chip_smoke.py and bench.py.)
+and validated on the chip by chip_smoke.py and the benchmark.)
 """
 
 import jax
