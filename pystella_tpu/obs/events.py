@@ -190,7 +190,9 @@ for _name, _help in (
     ("mg_cycle", "one multigrid cycle (depth, smooths, errors)"),
     # -- fused kernel tiers --------------------------------------------------
     ("block_choice", "a fused kernel build chose its blocking "
-                     "(bx/by/grid/win_halo + source: 'explicit' "
+                     "(bx/by/grid/win_halo, halo: each of (x, y) "
+                     "'wrap' or, on a sharded axis, 'slab' + source: "
+                     "'explicit' "
                      "constructor pins or the choose_blocks "
                      "'heuristic')"),
     ("bincount_plan", "a binning program was built: what the one-hot "

@@ -431,7 +431,7 @@ class FiniteDifferencer:
         try:
             st = StreamingStencil(local_shape, {"f": n_comp}, self.h, body,
                                   out_defs, dtype=dtype, kind=name,
-                                  x_halo=(px > 1), y_halo=(py > 1))
+                                  x_slab=(px > 1), y_slab=(py > 1))
         except ValueError:
             if px > 1 or py > 1:
                 raise  # resident kernels assume local periodicity
@@ -443,26 +443,26 @@ class FiniteDifferencer:
 
         if px > 1 or py > 1:
             from pystella_tpu.ops.pallas_stencil import (
-                OverlapStreamingStencil, sharded_halo)
+                OverlapStreamingStencil)
             decomp = self.decomp
-            halo = sharded_halo(self.h, px, py)
             ov = None
             if self.overlap and py == 1:
                 # x-sharded windows admit the interior/shell launch
                 # split (y shells have no legal sublane blocking);
-                # infeasible shapes keep the padded single launch
+                # infeasible shapes keep the single launch
                 try:
                     ov = OverlapStreamingStencil(st, self.h)
                 except ValueError as err:
                     logger.info("pallas halo overlap infeasible for %s "
-                                "(%s); padded path", global_shape, err)
+                                "(%s); single launch", global_shape, err)
 
             def sharded_fn(x):
                 if ov is not None:
                     return tuple(ov(x, decomp).values())
-                xpad = decomp.pad_with_halos(x, halo,
-                                             exchange=(self.h,) * 3)
-                return tuple(st(xpad).values())
+                # the shard itself is the window operand; its edges are
+                # ppermuted h-row slabs (no padded copy)
+                return tuple(
+                    st(x, slabs=st.halo_slabs(decomp, x)).values())
 
             in_spec = decomp.spec(1)
             out_specs = tuple(

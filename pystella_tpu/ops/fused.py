@@ -233,9 +233,10 @@ class FusedScalarStepper(_step.Stepper):
 
     @property
     def _halo_kw(self):
-        """Shared StreamingStencil kwargs: pre-padded windows per sharded
-        axis, and the interpret-mode override."""
-        return {"x_halo": self._px > 1, "y_halo": self._py > 1,
+        """Shared StreamingStencil kwargs: slab edges on the axes the
+        mesh shards (the others wrap locally), and the interpret-mode
+        override."""
+        return {"x_slab": self._px > 1, "y_slab": self._py > 1,
                 "interpret": self._interpret}
 
     #: array names that hold 2N-storage RK carries (reduced-precision
@@ -245,7 +246,8 @@ class FusedScalarStepper(_step.Stepper):
     def _emit_block_choice(self, kind, st, source):
         """The record of what a kernel build chose: ``source`` is
         ``"explicit"`` (pinned by the caller) or ``"heuristic"``
-        (``choose_blocks``)."""
+        (``choose_blocks``); ``halo`` is where its (x, y) edges come
+        from, ``"wrap"`` or (a sharded axis) ``"slab"``."""
         _events.emit(
             "block_choice", kernel=kind,
             stencil=type(st).__name__,
@@ -253,6 +255,7 @@ class FusedScalarStepper(_step.Stepper):
             grid=getattr(st, "grid", None),
             win_halo=getattr(st, "wh", None),
             stages=getattr(st, "stages", 1),
+            halo=list(getattr(st, "halo", ("wrap", "wrap"))),
             source=source, local_shape=list(self.local_shape),
             label=type(self).__name__)
 
@@ -377,8 +380,10 @@ class FusedScalarStepper(_step.Stepper):
 
     def _make_call(self, st, windows, extra_names):
         """Wrap a StreamingStencil in a ``shard_map`` over the sharded
-        mesh axes (padding the windowed inputs with ``ppermute`` halos)
-        or call it directly on an unsharded lattice.
+        mesh axes (the kernel streams each windowed input's own shard
+        and takes its edges from ``ppermute``d ``h``-row slabs; no
+        padded copy is made) or call it directly on an unsharded
+        lattice.
 
         With ``donate=True`` (construction) the per-stage calls donate
         their lattice inputs — every stage fully replaces its state and
@@ -398,10 +403,8 @@ class FusedScalarStepper(_step.Stepper):
                 call, label=f"fused.{type(self).__name__}.stage_call",
                 donate_argnums=(0, 2))
 
-        from pystella_tpu.ops.pallas_stencil import (
-            OverlapStreamingStencil, sharded_halo)
+        from pystella_tpu.ops.pallas_stencil import OverlapStreamingStencil
         decomp = self.decomp
-        halo = sharded_halo(self.h, self._px, self._py)
         out_names = list(st.out_defs) + list(st.sum_defs)
         scalar_names = st.scalar_names
         from jax.sharding import PartitionSpec as P
@@ -409,30 +412,27 @@ class FusedScalarStepper(_step.Stepper):
         ov = None
         if self._overlap and self._px > 1 and self._py == 1:
             # x-sharded stages take the interior/shell launch split
-            # (kernels with sum outputs keep the padded launch — the
+            # (kernels with sum outputs keep the single launch — the
             # split would change the deterministic reduction order)
             try:
                 ov = OverlapStreamingStencil(st, self.h)
             except ValueError as e:
                 import logging
                 logging.getLogger(__name__).info(
-                    "fused halo overlap infeasible (%s); padded path", e)
+                    "fused halo overlap infeasible (%s); single launch", e)
 
         def body(*flat):
             nw = len(windows)
             ns = len(scalar_names)
             scalars = dict(zip(scalar_names, flat[nw:nw + ns]))
             extras = dict(zip(extra_names, flat[nw + ns:]))
+            raw = dict(zip(windows, flat[:nw]))
+            arg = raw[windows[0]] if nw == 1 else raw
             if ov is not None:
-                raw = dict(zip(windows, flat[:nw]))
-                outs = ov(raw[windows[0]] if nw == 1 else raw, decomp,
-                          scalars=scalars, extras=extras)
+                outs = ov(arg, decomp, scalars=scalars, extras=extras)
             else:
-                wins = {n: decomp.pad_with_halos(a, halo,
-                                                 exchange=(self.h,) * 3)
-                        for n, a in zip(windows, flat[:nw])}
-                arg = wins[windows[0]] if nw == 1 else wins
-                outs = st(arg, scalars=scalars, extras=extras)
+                outs = st(arg, scalars=scalars, extras=extras,
+                          slabs=st.halo_slabs(decomp, raw))
             for n in st.sum_defs:  # per-shard partials -> global sums
                 outs[n] = decomp.psum(outs[n])
             return tuple(outs[n] for n in out_names)

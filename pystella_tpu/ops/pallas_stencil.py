@@ -27,9 +27,22 @@ Design (chosen by microbenchmark on TPU v5e):
 - Periodic wrap: x via block-index modulo, y via piecewise DMAs at the
   first and last y-block (chosen in-kernel by ``pl.when``), z via the
   lane roll.
-- ``x_halo=True`` instead reads an input whose x-axis is pre-padded with
-  ``h`` halo rows (filled by the mesh halo exchange — the sharded path);
-  each program then DMAs its own haloed window directly (no ring).
+- On a sharded axis (``x_slab`` / ``y_slab``) the window operand is
+  still the unpadded local shard and the ring still streams it, one new
+  ``bx``-row DMA a program. Only the edge source differs: ring block
+  ``-1`` and block ``nbx`` are the ``h`` rows of a low / high *x slab*
+  operand (the neighbour's last / first rows) where the one-chip kernel
+  takes the shard's own last / first block, and the first and last
+  y-block take their ``HY``-row piece from a low / high *y slab*
+  ``(C, X, HY, Z)`` where the one-chip kernel takes the wrapped piece.
+  The slabs are thin arrays the ``shard_map`` body fills by ``ppermute``
+  (:meth:`StreamingStencil.halo_slabs`); nothing the size of a window
+  is copied. An axis that is not sharded wraps locally, per axis.
+- ``x_halo=True`` / ``y_halo=True`` is the older sharded variant, kept
+  for :class:`OverlapStreamingStencil` and ``multigrid/relax.py``: the
+  input is a *pre-padded copy* of the window (``h`` rows in x, ``HY`` in
+  y, ``pad_with_halos``), and with ``x_halo`` each program DMAs its own
+  ``bx + 2h`` rows (no ring: ``1 + 2h/bx`` reads of every row).
 
 The kernel body is arbitrary traced JAX: finite-difference taps, fused
 Runge-Kutta stage updates (see :mod:`pystella_tpu.ops.fused`), multigrid
@@ -96,9 +109,14 @@ def _compiler_params(interpret):
 
 
 def sharded_halo(h, px, py):
-    """Halo widths for ``pad_with_halos`` feeding x/y-sharded window
-    kernels: x pads with the stencil radius ``h``, but sharded y MUST
-    pad with the 8-aligned ``HY`` window width — an ``h``-wide y pad
+    """Halo widths for ``pad_with_halos`` feeding the PRE-PADDED
+    (``x_halo`` / ``y_halo``) window kernels, which the multigrid
+    smoothers and :class:`OverlapStreamingStencil` still build; the
+    fused steppers and ``FiniteDifferencer`` hand a sharded kernel its
+    shard and thin slabs instead (``x_slab`` / ``y_slab``,
+    :meth:`StreamingStencil.halo_slabs`), whose y slab keeps the same
+    ``HY``-aligned layout. x pads with the stencil radius ``h``, but
+    sharded y MUST pad with the 8-aligned ``HY`` window width — an ``h``-wide y pad
     would put the window DMAs on misaligned sublane offsets, which
     Mosaic rejects (and interpret mode would read wrong halo rows).
     Callers pass ``exchange=(h, h, 0)`` alongside so only the ``h``
@@ -491,8 +509,22 @@ class StreamingStencil:
     :arg extra_defs: dict input name -> leading shape tuple; same-lattice
         unhaloed arrays, pipelined blockwise.
     :arg scalar_names: names of runtime scalars (handed to the body).
-    :arg x_halo: the input x-axis is pre-padded with ``h`` halo rows
-        (sharded x); otherwise periodic wrap in-kernel.
+    :arg x_slab: sharded x, the main path: the window operand is the
+        unpadded shard and the ring's two edge blocks come from thin
+        slab operands (``slabs`` of :meth:`__call__`,
+        :meth:`halo_slabs`) instead of the periodic wrap.
+    :arg y_slab: sharded y likewise: the first and last y-block take
+        their ``HY``-row halo piece from a ``(C, X, HY, Z)`` slab, in
+        which the neighbour's ``h`` rows sit against the shard (the
+        last rows of the low slab, the first of the high one) and the
+        rest is never read. With both, the corners (x slab rows at y
+        halo rows) are NOT fetched: the window holds stale VMEM there,
+        so bodies may take axis-aligned taps only (every fused body
+        and every ``FiniteDifferencer`` operator does; a widened
+        ``win_halo`` composes diagonal taps and is refused).
+    :arg x_halo: the older sharded variant: the input x-axis is a copy
+        pre-padded with ``h`` halo rows, and every program DMAs its own
+        ``bx + 2h`` rows (no ring). Not combined with the slab modes.
     :arg y_halo: the input y-axis is pre-padded with ``HY`` (8) halo rows
         per side (sharded y): each y-block window is one contiguous
         8-aligned DMA piece from the padded input, no in-kernel wrap.
@@ -523,7 +555,8 @@ class StreamingStencil:
                  extra_defs=None, scalar_names=(), dtype=jnp.float32,
                  bx=None, by=None, x_halo=False, y_halo=False,
                  interpret=None, sum_defs=None, dtypes=None,
-                 win_halo=None, stages=1, kind=None):
+                 win_halo=None, stages=1, kind=None, x_slab=False,
+                 y_slab=False):
         if h > HY:
             raise ValueError(f"stencil radius {h} exceeds aligned halo {HY}")
         #: what the kernel is (``"pair"``, ``"lap"`` ...; ``None``: not
@@ -595,6 +628,18 @@ class StreamingStencil:
         self.grid = (Y // self.by, X // self.bx)
         self.x_halo = bool(x_halo)
         self.y_halo = bool(y_halo)
+        self.x_slab = bool(x_slab)
+        self.y_slab = bool(y_slab)
+        if (self.x_slab or self.y_slab) and (self.x_halo or self.y_halo):
+            raise ValueError(
+                "slab edges and pre-padded windows do not combine: a "
+                "sharded kernel takes either its shard and slabs or a "
+                "padded copy")
+        if self.x_slab and self.y_slab and self.wh != self.h:
+            raise ValueError(
+                f"win_halo {self.wh} beyond the stencil radius {self.h} "
+                "composes diagonal taps, and an xy-sharded kernel leaves "
+                "the window's corners unfetched")
         self.interpret = _is_cpu() if interpret is None else interpret
         if not self.interpret and Z % LANE:
             raise ValueError(
@@ -616,10 +661,14 @@ class StreamingStencil:
     def _each_y_case(self, j, stream):
         """Run ``stream(pieces)`` for the y-window of y-block ``j`` (a
         grid index): ``pieces`` are the ``(src_y0, dst_y0, n)`` DMA
-        pieces of the window. With ``y_halo`` it is one contiguous piece
+        pieces of the window; the slab-fed kernel's are ``(src, src_y0,
+        dst_y0, n)`` with ``src`` ``None`` for the window operand,
+        ``"lo"`` / ``"hi"`` for its y slab. With ``y_halo`` it is one
+        contiguous piece
         of the HY-padded input. Otherwise the first and last y-block
         wrap periodically at the global y edges (two static pieces
-        each), a middle block is one piece at the dynamic, 8-aligned
+        each) or, with ``y_slab``, take that ``HY``-row piece from the
+        slab; a middle block is one piece at the dynamic, 8-aligned
         ``j * by - HY``; ``pl.when`` picks the case in-kernel."""
         Y = self.lattice_shape[1]
         by, byw = self.by, self.by + 2 * HY
@@ -633,14 +682,19 @@ class StreamingStencil:
                 HY)
 
         if self.y_halo:
-            return stream([(at(0), 0, byw)])
+            return stream([(None, at(0), 0, byw)])
+        if self.y_slab:
+            low, high = ("lo", 0, 0, HY), ("hi", 0, by + HY, HY)
+        else:
+            low, high = (None, Y - HY, 0, HY), (None, 0, by + HY, HY)
         if nby == 1:
-            return stream([(Y - HY, 0, HY), (0, HY, Y), (0, HY + Y, HY)])
+            return stream([low, (None, 0, HY, Y), high])
         cases = [
-            (j == 0, [(Y - HY, 0, HY), (0, HY, by + HY)]),
-            (j == nby - 1, [(Y - by - HY, 0, by + HY), (0, by + HY, HY)])]
+            (j == 0, [low, (None, 0, HY, by + HY)]),
+            (j == nby - 1, [(None, Y - by - HY, 0, by + HY), high])]
         if nby > 2:
-            cases.append(((j > 0) & (j < nby - 1), [(at(-HY), 0, byw)]))
+            cases.append(((j > 0) & (j < nby - 1),
+                          [(None, at(-HY), 0, byw)]))
         for cond, pieces in cases:
             pl.when(cond)(lambda pieces=pieces: stream(pieces))
 
@@ -658,7 +712,7 @@ class StreamingStencil:
                 lambda j, i: (0,) * nlead + (i, j, 0))
 
         in_specs = [pl.BlockSpec(memory_space=pl.ANY)
-                    for _ in self.win_defs]
+                    for _ in range(len(self.win_defs) + self._nslabs)]
         in_specs += [pl.BlockSpec(memory_space=pltpu.SMEM)
                      for _ in self.scalar_names]
         in_specs += [block_spec(lead) for lead in self.extra_defs.values()]
@@ -685,16 +739,65 @@ class StreamingStencil:
                 jax.ShapeDtypeStruct((self.grid[0] * ntp, LANE), self.dtype))
         return in_specs, out_specs, out_shapes
 
+    @property
+    def _slab_keys(self):
+        """The slab operands of one group of windows, in operand
+        order."""
+        return ((("x", "lo"), ("x", "hi")) if self.x_slab else ()) + (
+            (("y", "lo"), ("y", "hi")) if self.y_slab else ())
+
+    @property
+    def _slab_groups(self):
+        """The windows whose slabs travel in one operand, stacked along
+        the component axis in ``win_defs`` order: those of one storage
+        dtype (all of them, but for reduced-precision carries). One
+        ``ppermute`` a face moves a group, and the kernel's operand
+        list stays short (the instruction's text is what a trace names
+        the kernel by)."""
+        groups = {}
+        for n in self.win_defs:
+            groups.setdefault(self.dtypes.get(n, self.dtype), []).append(n)
+        return list(groups.values())
+
+    @property
+    def _nslabs(self):
+        return len(self._slab_groups) * len(self._slab_keys)
+
+    @property
+    def halo(self):
+        """Where the (x, y) edges of the window come from: ``"wrap"``
+        (the local periodic wrap), ``"slab"`` (thin halo-slab operands)
+        or ``"padded"`` (a pre-padded copy of the window)."""
+        return tuple(
+            "slab" if slab else "padded" if padded else "wrap"
+            for slab, padded in ((self.x_slab, self.x_halo),
+                                 (self.y_slab, self.y_halo)))
+
     def _unpack_refs(self, refs):
+        """``(f_refs, slab_refs, scalar_refs, extra_refs, out_refs, wins,
+        sem)``; ``slab_refs`` is per window its group's dict ``(axis,
+        side) -> ref`` (empty without slab edges) and the slice of the
+        slabs' component axis that is this window's."""
         nw, ns, ne = (len(self.win_defs), len(self.scalar_names),
                       len(self.extra_defs))
         no = len(self.out_defs) + len(self.sum_defs)
         f_refs = refs[:nw]
+        keys = self._slab_keys
+        slab_refs = {}
+        for g, names in enumerate(self._slab_groups):
+            group = dict(zip(keys, refs[nw + g * len(keys):]))
+            c0 = 0
+            for n in names:
+                slab_refs[n] = (group, pl.ds(c0, self.win_defs[n]))
+                c0 += self.win_defs[n]
+        slab_refs = [slab_refs[n] for n in self.win_defs]
+        refs = refs[:nw] + refs[nw + self._nslabs:]
         scalar_refs = refs[nw:nw + ns]
         extra_refs = refs[nw + ns:nw + ns + ne]
         out_refs = refs[nw + ns + ne:nw + ns + ne + no]
         wins, sem = refs[-nw - 1:-1], refs[-1]
-        return f_refs, scalar_refs, extra_refs, out_refs, wins, sem
+        return (f_refs, slab_refs, scalar_refs, extra_refs, out_refs, wins,
+                sem)
 
     def _run_body(self, ws, scalar_refs, extra_refs, out_refs):
         X, Y, Z = self.lattice_shape
@@ -759,21 +862,45 @@ class StreamingStencil:
         R = _RING
 
         def kernel(*refs):
-            f_refs, scalar_refs, extra_refs, out_refs, wins, sem = \
-                self._unpack_refs(refs)
+            f_refs, slab_refs, scalar_refs, extra_refs, out_refs, wins, \
+                sem = self._unpack_refs(refs)
             j, i = pl.program_id(0), pl.program_id(1)
 
             def stream(ypieces):
                 """Bring x-block i's window of this y-block into the
                 ring; the ring is primed anew at every y-block's i == 0."""
                 def dmas(blk, slot):
+                    # a block of the window operand fills its slot. With
+                    # x slabs ring block -1 is the low slab and block
+                    # nbx the high one (static indices: a traced one is
+                    # kept inside the shard by its pl.when), whose h
+                    # rows land where the neighbouring block reads them:
+                    # the slot's last rows (low) or first
+                    xe = None
+                    if self.x_slab and isinstance(blk, int):
+                        xe = "lo" if blk < 0 else "hi" if blk >= nbx else None
                     b = _rem(blk + nbx, nbx)
-                    return [pltpu.make_async_copy(
-                        f_ref.at[:, pl.ds(b * bx, bx), pl.ds(sy0, n), :],
-                        win.at[:, pl.ds(slot * bx, bx), pl.ds(dy0, n), :],
-                        sem.at[_rem(slot, 2)])
-                        for f_ref, win in zip(f_refs, wins)
-                        for sy0, dy0, n in ypieces]
+                    out = []
+                    for f_ref, (slabs, comps), win in zip(
+                            f_refs, slab_refs, wins):
+                        for ye, sy0, dy0, n in ypieces:
+                            if xe is None:
+                                src = slabs["y", ye] if ye else f_ref
+                                rows = (pl.ds(b * bx, bx),
+                                        pl.ds(slot * bx, bx))
+                            elif ye:
+                                continue  # a corner: never read
+                            else:
+                                src = slabs["x", xe]
+                                off = bx - h if xe == "lo" else 0
+                                rows = (pl.ds(0, h),
+                                        pl.ds(slot * bx + off, h))
+                            lead = slice(None) if src is f_ref else comps
+                            out.append(pltpu.make_async_copy(
+                                src.at[lead, rows[0], pl.ds(sy0, n), :],
+                                win.at[:, rows[1], pl.ds(dy0, n), :],
+                                sem.at[_rem(slot, 2)]))
+                    return out
 
                 def start(blk, slot):
                     for d in dmas(blk, slot):
@@ -800,11 +927,31 @@ class StreamingStencil:
 
                     @pl.when(i > 0)
                     def _():
-                        wait(i + 1, _rem(i + 1, R))
+                        if self.x_slab:
+                            # the block after the shard's last is the
+                            # high slab: started by the last program
+                            # but one, awaited by the last
+                            @pl.when(i < nbx - 1)
+                            def _():
+                                wait(i + 1, _rem(i + 1, R))
 
-                        @pl.when(i < nbx - 1)
-                        def _():
-                            start(i + 2, _rem(i + 2, R))
+                            @pl.when(i == nbx - 1)
+                            def _():
+                                wait(nbx, nbx % R)
+
+                            @pl.when(i < nbx - 2)
+                            def _():
+                                start(i + 2, _rem(i + 2, R))
+
+                            @pl.when(i == nbx - 2)
+                            def _():
+                                start(nbx, nbx % R)
+                        else:
+                            wait(i + 1, _rem(i + 1, R))
+
+                            @pl.when(i < nbx - 1)
+                            def _():
+                                start(i + 2, _rem(i + 2, R))
 
             self._each_y_case(j, stream)
 
@@ -828,7 +975,7 @@ class StreamingStencil:
         nbx = self.grid[1]
 
         def kernel(*refs):
-            f_refs, scalar_refs, extra_refs, out_refs, wins, sem = \
+            f_refs, _, scalar_refs, extra_refs, out_refs, wins, sem = \
                 self._unpack_refs(refs)
             j, i = pl.program_id(0), pl.program_id(1)
             slot = _rem(i, 2)
@@ -847,7 +994,7 @@ class StreamingStencil:
                         win.at[:, pl.ds(buf * bxw, bxw), pl.ds(dy0, n), :],
                         sem.at[_rem(buf, 2)])
                         for f_ref, win in zip(f_refs, wins)
-                        for sy0, dy0, n in ypieces]
+                        for _, sy0, dy0, n in ypieces]
 
                 @pl.when(i == 0)
                 def _():
@@ -870,34 +1017,70 @@ class StreamingStencil:
 
         return self._pallas_call(kernel, 2 * bxw)
 
-    def with_lattice(self, lattice_shape, bx=None, by=None):
+    def with_lattice(self, lattice_shape, bx=None, by=None, padded=False):
         """A new :class:`StreamingStencil` sharing this one's body,
         definitions, dtypes and halo mode, built for a different local
         lattice shape — how :class:`OverlapStreamingStencil` derives the
-        interior and shell kernels from the full-block kernel. Raises
-        ``ValueError`` when the new shape admits no feasible blocking."""
+        interior and shell kernels from the full-block kernel; it asks
+        for them ``padded``: a slab-fed axis becomes a pre-padded one.
+        Raises ``ValueError`` when the new shape admits no feasible
+        blocking."""
         return StreamingStencil(
             lattice_shape, self.win_defs, self.h, self.body,
             self.out_defs, extra_defs=self.extra_defs,
             scalar_names=self.scalar_names, dtype=self.dtype,
-            bx=bx, by=by, x_halo=self.x_halo, y_halo=self.y_halo,
+            bx=bx, by=by,
+            x_halo=self.x_halo or (padded and self.x_slab),
+            y_halo=self.y_halo or (padded and self.y_slab),
+            x_slab=self.x_slab and not padded,
+            y_slab=self.y_slab and not padded,
             interpret=self.interpret, sum_defs=self.sum_defs,
             dtypes=self.dtypes, win_halo=self.wh, stages=self.stages,
             kind=self.kind)
 
     # -- invocation --------------------------------------------------------
 
-    def __call__(self, f, scalars=None, extras=None):
+    def halo_slabs(self, decomp, f):
+        """The ``slabs`` of :meth:`__call__` for the local shard(s) ``f``
+        (as ``__call__`` takes them), filled over the mesh: the
+        ``h``-row faces the neighbours owe, the windows' stacked along
+        the component axis and moved by one ``ppermute`` a face
+        (``decomp.exchange_slabs``: what ``pad_with_halos`` moves over
+        the interconnect, without the padded copy), the y pair padded
+        with local zeros to the ``HY`` rows a window piece has. MUST be
+        called inside a ``shard_map`` over ``decomp``'s mesh."""
+        wins = f if isinstance(f, dict) else {next(iter(self.win_defs)): f}
+        slabs = []
+        for names in self._slab_groups:
+            group = [wins[n] for n in names]
+            slabs.append({})
+            if self.x_slab:
+                slabs[-1]["x"] = decomp.exchange_slabs(group, 0, self.wh)
+            if self.y_slab:
+                slabs[-1]["y"] = decomp.exchange_slabs(group, 1, self.wh,
+                                                       pad_to=HY)
+        return slabs
+
+    def __call__(self, f, scalars=None, extras=None, slabs=None):
         """Apply to the windowed input(s) ``f`` — a single array (shape
         ``(n_comp, X, Y, Z)``, or x-padded ``(n_comp, X+2h, Y, Z)`` with
-        ``x_halo``) or a dict name -> array matching ``win_defs``. Returns
-        a dict of named full-lattice outputs."""
+        ``x_halo``) or a dict name -> array matching ``win_defs``. A
+        slab-fed kernel also takes ``slabs``, as :meth:`halo_slabs`
+        makes them: a list with, for each group of windows of one
+        storage dtype (one group, but for reduced-precision carries),
+        ``{"x": (low, high), "y": (low, high)}`` for its sharded axes,
+        shapes ``(n_comp, h, Y, Z)`` and ``(n_comp, X, HY, Z)`` with the
+        group's windows stacked along ``n_comp``. Returns a dict of
+        named full-lattice outputs."""
         scalars = scalars or {}
         extras = extras or {}
         if isinstance(f, dict):
             win_args = [f[n] for n in self.win_defs]
         else:
             win_args = [f]
+        for group in slabs if self._nslabs else ():
+            for axis, side in self._slab_keys:
+                win_args.append(group[axis][side == "hi"])
         scalar_args = [jnp.asarray(scalars[n], self.dtype).reshape(1)
                        for n in self.scalar_names]
         extra_args = [extras[n] for n in self.extra_defs]
@@ -946,8 +1129,9 @@ class OverlapStreamingStencil:
     and per-element arithmetic (blocking never enters the math).
 
     Feasibility (``ValueError`` otherwise — callers fall back to the
-    padded path): x-sharded pre-padded windows only (``x_halo`` set,
-    ``y_halo`` not — an h-thin y shell has no legal sublane blocking),
+    single launch): x-sharded windows only (``x_slab`` or ``x_halo``
+    set, y whole — an h-thin y shell has no legal sublane blocking; the
+    three launches are pre-padded ``x_halo`` kernels either way),
     no ``sum_defs`` (the region split would change the deterministic
     reduction order), and ``X >= 3h`` so an interior exists.
     """
@@ -958,9 +1142,9 @@ class OverlapStreamingStencil:
             raise ValueError(
                 "sum outputs: the interior/shell split would change the "
                 "deterministic reduction order")
-        if not st.x_halo or st.y_halo:
+        if not (st.x_halo or st.x_slab) or st.y_halo or st.y_slab:
             raise ValueError(
-                "overlap split supports x-sharded (x_halo) windows only")
+                "overlap split supports x-sharded windows only")
         X, Y, Z = st.lattice_shape
         self.h = int(h)
         if X < MIN_INTERIOR_FACTOR * self.h:
@@ -970,9 +1154,9 @@ class OverlapStreamingStencil:
                 "transfer behind")
         self.st = st
         self.st_interior = st.with_lattice((X - 2 * self.h, Y, Z),
-                                           by=st.by)
+                                           by=st.by, padded=True)
         self.st_shell = st.with_lattice((self.h, Y, Z), bx=self.h,
-                                        by=st.by)
+                                        by=st.by, padded=True)
 
     @staticmethod
     def _slice_x(tree, s, e):

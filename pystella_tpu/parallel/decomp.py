@@ -526,26 +526,47 @@ class DomainDecomposition:
             x = lax.concatenate([left_halo, x, right_halo], dimension=ax)
         return x
 
-    def exchange_slabs(self, x, d, width, lattice_axes=None):
+    def exchange_slabs(self, x, d, width, lattice_axes=None, pad_to=None):
         """``(left_halo, right_halo)`` slabs of ``width`` rows along
-        lattice axis ``d``, moved with periodic ``lax.ppermute`` — the
-        issue-first half of the overlapped Pallas tier (the shells are
-        assembled by the caller once the collectives land). MUST be
-        called from inside a ``shard_map``; ``d`` must be a sharded
-        axis."""
+        lattice axis ``d``, moved with periodic ``lax.ppermute``: what
+        :meth:`pad_with_halos` moves over the interconnect, without
+        the padded copy of ``x``. The slab-fed streaming kernels take
+        them as operands (``StreamingStencil.halo_slabs``), the
+        overlapped Pallas tier assembles its shells from them. With
+        ``pad_to`` each slab is grown to that many rows by local zeros
+        on its far side, the moved rows staying against the block (the
+        8-aligned piece a y window wants; the zeros are never read).
+        ``x`` may be a list of arrays of one dtype and lattice shape:
+        their faces are stacked along the leading axis and moved
+        together, one ``ppermute`` a direction. MUST be called from
+        inside a ``shard_map``; ``d`` must be a sharded axis."""
+        xs = list(x) if isinstance(x, (list, tuple)) else [x]
+        x = xs[0]
         if lattice_axes is None:
             lattice_axes = tuple(range(x.ndim - len(self.axis_names), x.ndim))
         ax = lattice_axes[d]
         name = self.axis_names[d]
-        lo = lax.slice_in_dim(x, x.shape[ax] - width, x.shape[ax], axis=ax)
-        hi = lax.slice_in_dim(x, 0, width, axis=ax)
-        key = ("slabs", tuple(x.shape), str(x.dtype), d, width)
-        nbytes = 2 * int(width) * np.dtype(x.dtype).itemsize * int(
-            np.prod([n for a, n in enumerate(x.shape) if a != ax]))
-        self._record_halo_bytes(key, nbytes)
+
+        def faces(s, e):
+            cut = [lax.slice_in_dim(a, s, e, axis=ax) for a in xs]
+            return cut[0] if len(cut) == 1 else lax.concatenate(cut, 0)
+
+        lo = faces(x.shape[ax] - width, x.shape[ax])
+        hi = faces(0, width)
+        key = ("slabs", tuple(lo.shape), str(x.dtype), d)
+        self._record_halo_bytes(
+            key, 2 * lo.size * np.dtype(x.dtype).itemsize)
         with jax.named_scope("halo_exchange"):
             left_halo = lax.ppermute(lo, name, self._perm(name, +1))
             right_halo = lax.ppermute(hi, name, self._perm(name, -1))
+            if pad_to is not None and pad_to > width:
+                zshape = list(lo.shape)
+                zshape[ax] = pad_to - width
+                zeros = jnp.zeros(zshape, x.dtype)
+                left_halo = lax.concatenate([zeros, left_halo],
+                                            dimension=ax)
+                right_halo = lax.concatenate([right_halo, zeros],
+                                             dimension=ax)
         return left_halo, right_halo
 
     def overlap_stencil(self, xs, halo, apply_fn, extras=None,

@@ -710,6 +710,128 @@ def test_fused_scalar_sharded_2d_matches_single(proc):
                            rtol=1e-13, atol=1e-13), name
 
 
+#: the kernels a sharded scalar stepper builds, per (mesh, local x
+#: extent): kind -> (stencil, windows, extras), caught at ``_make_call``
+_SLAB_KERNELS = {}
+
+
+def _slab_kernels(proc, local_x, carry_dtype=None):
+    """Every kernel kind the coupled and the fixed-background drivers
+    run (``stage``, ``pair``, ``coupled_pair`` with its two blocks of
+    sums, ``energy``) as a stepper on mesh ``proc`` builds it, at local
+    shape ``(local_x, 16, 8)``."""
+    key = (proc, local_x, carry_dtype)
+    if key not in _SLAB_KERNELS:
+        ndev = int(np.prod(proc))
+        grid_shape = (local_x * proc[0], 16 * proc[1], 8)
+        dp = ps.DomainDecomposition(proc, devices=jax.devices()[:ndev])
+        built = {}
+        make_call = FusedScalarStepper._make_call
+
+        def record(self, st, windows, extra_names):
+            built.setdefault(st.kind, (st, windows, extra_names))
+            return make_call(self, st, windows, extra_names)
+
+        with pytest.MonkeyPatch.context() as mp:
+            mp.setattr(FusedScalarStepper, "_make_call", record)
+            stepper = FusedScalarStepper(
+                ps.ScalarSector(2, potential=_potential), dp, grid_shape,
+                0.3, 2, dtype=jnp.float64, carry_dtype=carry_dtype)
+            assert stepper._ensure_coupled_pair_calls() is not None
+            stepper._ensure_energy_call()
+        _SLAB_KERNELS[key] = (dp, grid_shape, built)
+    return _SLAB_KERNELS[key]
+
+
+@pytest.mark.parametrize("kind, proc, local_x, carry_dtype", [
+    (kind, proc, local_x, None)
+    for kind in ("stage", "pair", "coupled_pair", "energy")
+    for proc in ((2, 1, 1), (1, 2, 1), (2, 2, 1))
+    for local_x in (8, 16)] + [("pair", (2, 2, 1), 16, jnp.bfloat16)],
+    ids=lambda v: ("x".join(map(str, v)) if isinstance(v, tuple)
+                   else f"nbx{v // 4}" if isinstance(v, int)
+                   else v if isinstance(v, str)
+                   else "f64" if v is None else "bf16-carries"))
+def test_fused_sharded_slab_kernel_matches_padded(kind, proc, local_x,
+                                                  carry_dtype):
+    """How a sharded kernel gets its edges: the kernel a stepper builds
+    on a mesh streams its own shard and reads its neighbours' rows from
+    thin slabs (``halo_slabs``); the variant it replaced read a padded
+    copy of every window (``pad_with_halos`` + ``x_halo`` /
+    ``y_halo``). Same body, same blocks, same grid: outputs and lattice
+    sums are bit-equal on ``(2,1,1)``, ``(1,2,1)`` and ``(2,2,1)``, with
+    two y-blocks a shard (``by = 8`` of 16 rows) and with two and four
+    x-blocks (``bx = 4``: both ways of priming the ring). On the xy
+    mesh the padded reference has NaN planted in its corners (x halo
+    rows at y halo rows), which the slab-fed kernel never fetches: a
+    tap that read one would poison the reference. With bfloat16 carries
+    the pair kernel's ``kf`` window travels in a slab operand of its
+    own beside the float64 windows' shared one."""
+    from pystella_tpu.ops.pallas_stencil import HY, sharded_halo
+    if len(jax.devices()) < int(np.prod(proc)) or _TPU_SESSION:
+        pytest.skip(f"needs {int(np.prod(proc))} CPU devices")
+    dp, grid_shape, built = _slab_kernels(proc, local_x, carry_dtype)
+    st, windows, extra_names = built[kind]
+    assert len(st._slab_groups) == (1 if carry_dtype is None else 2)
+    px, py = proc[:2]
+    assert st.halo == ("slab" if px > 1 else "wrap",
+                       "slab" if py > 1 else "wrap")
+    slab = st.with_lattice(st.lattice_shape, bx=4, by=8)
+    padded = st.with_lattice(st.lattice_shape, bx=4, by=8, padded=True)
+    assert slab.grid == padded.grid == (2, local_x // 4)
+    assert (padded.x_halo, padded.y_halo) == (px > 1, py > 1)
+    h = st.h
+    halo = sharded_halo(h, px, py)
+    names = list(st.out_defs) + list(st.sum_defs)
+
+    def corners_nan(a):
+        if px == 1 or py == 1:
+            return a
+        nan = jnp.full((a.shape[0], h, HY, a.shape[3]), jnp.nan, a.dtype)
+        for x0 in (0, a.shape[1] - h):
+            for y0 in (0, a.shape[2] - HY):
+                a = jax.lax.dynamic_update_slice(a, nan, (0, x0, y0, 0))
+        return a
+
+    def body(*flat):
+        nw, ns = len(windows), len(st.scalar_names)
+        raw = dict(zip(windows, flat[:nw]))
+        scalars = dict(zip(st.scalar_names, flat[nw:nw + ns]))
+        extras = dict(zip(extra_names, flat[nw + ns:]))
+        new = slab(raw if nw > 1 else raw[windows[0]], scalars=scalars,
+                   extras=extras, slabs=slab.halo_slabs(dp, raw))
+        wins = {n: corners_nan(dp.pad_with_halos(a, halo,
+                                                 exchange=(h,) * 3))
+                for n, a in raw.items()}
+        old = padded(wins if nw > 1 else wins[windows[0]],
+                     scalars=scalars, extras=extras)
+        # per-shard sums, before any psum: the kernel's own numbers
+        return tuple(o[n] for o in (new, old) for n in names)
+
+    from jax.sharding import PartitionSpec as P
+    lat, rep = dp.spec(1), P()
+    nsum = len(st.sum_defs)
+    out_specs = ((lat,) * len(st.out_defs)
+                 + (P(dp.axis_names[:2]),) * nsum) * 2
+    fn = jax.jit(dp.shard_map(
+        body, (lat,) * len(windows) + (rep,) * len(st.scalar_names)
+        + (lat,) * len(extra_names), out_specs, check_vma=False))
+    rng = np.random.default_rng(17)
+    def draw(n, lead):
+        return dp.shard(jnp.asarray(rng.standard_normal(lead + grid_shape),
+                                    st.dtypes.get(n, st.dtype)))
+
+    args = [draw(n, (st.win_defs[n],)) for n in windows]
+    args += [jnp.asarray(0.3 + 0.1 * k)
+             for k in range(len(st.scalar_names))]
+    args += [draw(n, st.extra_defs[n]) for n in extra_names]
+    res = fn(*args)
+    for n, new, old in zip(names, res[:len(names)], res[len(names):]):
+        new, old = (np.asarray(a, np.float64) for a in (new, old))
+        assert np.isfinite(old).all(), f"{n}: a tap read a corner"
+        assert np.array_equal(new, old), n
+
+
 @pytest.mark.slow
 def test_fused_preheat_sharded_2d_matches_single():
     """Scalar+GW fused stages (pair kernels in step()) on a (2, 2, 1)

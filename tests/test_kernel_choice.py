@@ -111,6 +111,7 @@ def _built(name):
             choices.setdefault(self.kind, []).append({
                 "kernel": self.kind, "stencil": "StreamingStencil",
                 "bx": self.bx, "by": self.by, "grid": list(self.grid),
+                "halo": list(self.halo),
                 "source": "explicit" if pinned else "heuristic"})
 
     derivs = ps.FiniteDifferencer(decomp, h, lattice.dx)
@@ -136,7 +137,8 @@ _CELL_KERNELS = [
     ("preheat-512-f32", "energy", 0, (2, 64), (8, 256), "explicit"),
     ("preheat-512-f32", "lap", 0, (2, 128), (4, 256), "heuristic"),
     ("preheat-512-f32", "grad", 0, (2, 128), (4, 256), "heuristic"),
-    # 512^3 per chip on (2, 2, 1): pre-padded windows, the same blocks
+    # 512^3 per chip on (2, 2, 1): the same kernels and blocks, their
+    # edges from slabs (`halo`, below) where one chip wraps
     ("preheat-mesh4-f32", "stage", 0, (2, 64), (8, 256), "heuristic"),
     ("preheat-mesh4-f32", "coupled_pair", 0, (2, 32), (16, 256),
      "heuristic"),
@@ -182,6 +184,10 @@ def test_cells_get_the_kernels_the_ledger_measured(config, kernel, nth,
     assert (d["bx"], d["by"]) == blocks
     assert tuple(d["grid"]) == grid
     assert d["source"] == source
+    # where the window's (x, y) edges come from follows from the mesh
+    # the stepper or operator was built on, and from nothing else
+    sharded = config == "preheat-mesh4-f32"
+    assert d["halo"] == (["slab", "slab"] if sharded else ["wrap", "wrap"])
 
 
 # -- pins, refusals, events ------------------------------------------------
@@ -276,9 +282,10 @@ def test_events_carry_what_the_benchmark_prints(build, names):
     choices = seen.of("block_choice")
     assert {d["kernel"] for d in choices} == {"stage", "pair"}
     for d in choices:
-        assert {"kernel", "stencil", "bx", "by", "grid", "source",
+        assert {"kernel", "stencil", "bx", "by", "grid", "halo", "source",
                 "local_shape", "label"} <= set(d)
         assert d["source"] in ("explicit", "heuristic")
+        assert d["halo"] == ["wrap", "wrap"]
         assert d["grid"] == [16 // d["by"], 16 // d["bx"]]
     tiers = seen.of("kernel_tier")
     assert [d["entrypoint"] for d in tiers] == ["multi_step"]

@@ -183,7 +183,7 @@ def test_streaming_multi_output():
 #: y-blocks, bx in (16, 8, 4) gives 1, 2, 4 x-blocks
 _ONE_CALL_SHAPE = (16, 32, 8)
 _ONE_CALL_VARIANTS = ("plain", "x_halo", "y_halo", "xy_halo", "extras",
-                      "win_halo")
+                      "win_halo", "x_slab", "y_slab", "xy_slab")
 
 
 def _one_call_inputs(variant):
@@ -207,6 +207,24 @@ def _one_call_inputs(variant):
     if variant in ("y_halo", "xy_halo"):
         fin = np.concatenate([fin[:, :, -HY:], fin, fin[:, :, :HY]], axis=2)
     return f, e, fin
+
+
+def _one_call_slabs(f, variant):
+    """The slab operands of a slab-fed kernel whose neighbours are the
+    lattice itself (a mesh of one): its own periodic faces, one row in
+    x, the ``HY``-row y pieces holding their one row against the block
+    and NaN in the rows no tap may read."""
+    from pystella_tpu.ops.pallas_stencil import HY
+    slabs = {}
+    if variant in ("x_slab", "xy_slab"):
+        slabs["x"] = (jnp.asarray(f[:, -1:]), jnp.asarray(f[:, :1]))
+    if variant in ("y_slab", "xy_slab"):
+        lo = np.full(f.shape[:2] + (HY,) + f.shape[3:], np.nan)
+        hi = lo.copy()
+        lo[:, :, -1:] = f[:, :, -1:]
+        hi[:, :, :1] = f[:, :, :1]
+        slabs["y"] = (jnp.asarray(lo), jnp.asarray(hi))
+    return [slabs] if slabs else None
 
 
 def _one_call_reference(f, e, variant):
@@ -247,7 +265,9 @@ def _one_call_stencil(variant, bx, by):
         _ONE_CALL_SHAPE, 2, 1, body, {"lap": (2,)}, dtype=jnp.float64,
         bx=bx, by=by, sum_defs={"sums": 4},
         x_halo=variant in ("x_halo", "xy_halo"),
-        y_halo=variant in ("y_halo", "xy_halo"), **kw)
+        y_halo=variant in ("y_halo", "xy_halo"),
+        x_slab=variant in ("x_slab", "xy_slab"),
+        y_slab=variant in ("y_slab", "xy_slab"), **kw)
 
 
 _ONE_CALL_RESULTS = {}
@@ -256,9 +276,9 @@ _ONE_CALL_RESULTS = {}
 def _one_call_result(variant, bx, by):
     key = (variant, bx, by)
     if key not in _ONE_CALL_RESULTS:
-        _, e, fin = _one_call_inputs(variant)
+        f, e, fin = _one_call_inputs(variant)
         st = _one_call_stencil(variant, bx, by)
-        call = {}
+        call = {"slabs": _one_call_slabs(f, variant)}
         if variant == "extras":
             call = dict(scalars={"c": 3.0}, extras={"e": jnp.asarray(e)})
         out = st(jnp.asarray(fin), **call)
@@ -274,7 +294,10 @@ def _one_call_result(variant, bx, by):
 def test_streaming_one_call_blockings_agree(nby, nbx, variant):
     """The kernel is one ``pallas_call`` over a ``(nby, nbx)`` grid that
     writes each block where it lives: for every blocking, with either or
-    both halo variants, with extras and with the widened (chunk) window,
+    both halo variants (pre-padded, or slab-fed: the ring's edge blocks
+    and the edge y-blocks' halo pieces from thin slab operands, one,
+    two and four x-blocks covering both ways of priming the ring), with
+    extras and with the widened (chunk) window,
     the outputs and the ``sum_defs`` lattice sums (one revisited
     accumulator tile per y-block, finished over y outside the kernel —
     per-program partial columns do not compile on TPU) are bit-identical

@@ -33,6 +33,9 @@ import pystella_tpu as ps
 ONE_CHIP = pytest.param((1, 1, 1), (512, 128, 512),
                         marks=pytest.mark.slow)
 MESH = ((2, 2, 1), (512, 512, 512))
+#: the four-chip cell's own lattice (``preheat-mesh4-f32``): 512**3 a chip
+MESH_CELL = pytest.param((2, 2, 1), (1024, 1024, 512),
+                         marks=pytest.mark.slow)
 
 
 @pytest.fixture(scope="module")
@@ -110,13 +113,33 @@ def _coupled_chunk_hlo(v5e, proc_shape, grid):
     return _COUPLED_HLO[key]
 
 
-@pytest.mark.parametrize("proc_shape,grid", [ONE_CHIP, MESH])
+@pytest.mark.parametrize("proc_shape,grid", [ONE_CHIP, MESH, MESH_CELL])
 def test_coupled_chunk_compiles(v5e, proc_shape, grid):
     """One coupled step = two deferred-drag pair kernels (normal-in and
     deferred-in variants) + the single-stage energy kernel for the odd
     fifth stage: every energy-emitting kernel of the main path, with the
-    in-trace Friedmann integration between them."""
-    assert _coupled_chunk_hlo(v5e, proc_shape, grid)
+    in-trace Friedmann integration between them. On the mesh the
+    kernels are slab-fed (the shards as the window operands, one low and
+    one high ``h``-row x slab and ``HY``-row y slab beside them), and no
+    operand of any of them is a padded copy of a window."""
+    import re
+    hlo = _coupled_chunk_hlo(v5e, proc_shape, grid)
+    assert hlo
+    if proc_shape == (1, 1, 1):
+        return
+    local = tuple(n // p for n, p in zip(grid, proc_shape))
+    calls = [ln for ln in hlo.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in ln]
+    assert calls
+    padded = f"[2,{local[0] + 4},{local[1] + 16},{local[2]}]"
+    # a kernel's windows share their slabs: C is their components, 2 each
+    x_slab = re.compile(rf"\[\d+,2,{local[1]},{local[2]}\]")
+    y_slab = re.compile(rf"\[\d+,{local[0]},8,{local[2]}\]")
+    for ln in calls:
+        operands = ln.split(" custom-call(", 1)[1]
+        assert padded not in operands, ln[:200]
+        assert len(x_slab.findall(operands)) == 2, ln[:200]
+        assert len(y_slab.findall(operands)) == 2, ln[:200]
 
 
 def _custom_call_names(hlo):

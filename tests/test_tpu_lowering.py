@@ -77,22 +77,40 @@ def test_streaming_sums_lower():
     assert _has_aligned_dynamic_offset(st, f)
 
 
-@pytest.mark.parametrize("mode", ["x", "y"])
+@pytest.mark.parametrize("mode", ["x", "y", "slab-x", "slab-y", "slab-xy"])
 def test_streaming_halo_variants_lower(mode):
-    """Both halo variants on a 2-D grid with ``nby > 2``: with ``y_halo``
-    every y-block's window is one piece at the dynamic ``j * by``."""
+    """The halo variants on a 2-D grid with ``nby > 2``: with ``y_halo``
+    every y-block's window is one piece at the dynamic ``j * by``; the
+    slab-fed kernels stream the unpadded shard (the middle y-blocks'
+    piece at the dynamic ``j * by - HY``) and take slab operands."""
+    from pystella_tpu.ops.pallas_stencil import HY
     h = 1
+    slab = mode.startswith("slab-")
+    xs, ys = "x" in mode[-2:], "y" in mode[-2:]
     st = StreamingStencil(
         (16, 32, LANE), 1, h, _lap_body, {"lap": (1,)},
         dtype=jnp.float32, bx=4, by=8, interpret=False,
-        x_halo=(mode == "x"), y_halo=(mode == "y"))
+        x_halo=(mode == "x"), y_halo=(mode == "y"),
+        x_slab=slab and xs, y_slab=slab and ys)
     assert st.grid == (4, 4)
-    shape = ((1, 16 + 2 * h, 32, LANE) if mode == "x"
+    assert st.halo == tuple(
+        ("slab" if slab else "padded") if on else "wrap"
+        for on in (xs, ys))
+    shape = ((1, 16, 32, LANE) if slab
+             else (1, 16 + 2 * h, 32, LANE) if mode == "x"
              else (1, 16, 32 + 16, LANE))
     x = jnp.zeros(shape, jnp.float32)
-    lowered = lower_tpu(lambda x: st(x), x)
+    slabs = [{}]
+    if slab and xs:
+        slabs[0]["x"] = (jnp.zeros((1, h, 32, LANE), jnp.float32),) * 2
+    if slab and ys:
+        slabs[0]["y"] = (jnp.zeros((1, 16, HY, LANE), jnp.float32),) * 2
+    def call(x, slabs):
+        return st(x, slabs=slabs)
+
+    lowered = lower_tpu(call, x, slabs)
     assert lowered.as_text().count("tpu_custom_call") == 1
-    assert _has_aligned_dynamic_offset(st, x)
+    assert "multiple_of" in str(jax.make_jaxpr(call)(x, slabs))
 
 
 def test_resident_rolls_lower():
