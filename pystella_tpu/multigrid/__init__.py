@@ -28,7 +28,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from pystella_tpu.obs import events as _events
 from pystella_tpu.obs import memory as _obs_memory
 from pystella_tpu.obs import metrics as _metrics
-from pystella_tpu.obs.scope import trace_scope
+from pystella_tpu.obs.scope import host_span, trace_scope
 from pystella_tpu.multigrid.relax import (
     LevelSpec, RelaxationBase, JacobiIterator, NewtonIterator)
 from pystella_tpu.multigrid.transfer import (
@@ -282,24 +282,32 @@ class FullApproximationScheme:
         unknowns = {0: dict(unknowns0)}
         rhos = {0: dict(rhos0)}
 
+        # host spans of the walk: each goes round dispatches only (a
+        # smooth with its two error norms, a transfer), and the one
+        # fetch of the cycle's norms is the one place the host waits
         with _metrics.timer("mg_cycle_s"), trace_scope("mg_cycle"):
-            errors = self.smooth(levels, 0, cycle[0][1], unknowns, rhos,
-                                 aux, decomp)
+            with host_span("mg_smooth"):
+                errors = self.smooth(levels, 0, cycle[0][1], unknowns,
+                                     rhos, aux, decomp)
             previous = 0
             for i, nu in cycle[1:]:
                 if i == previous + 1:
-                    self.transfer_down(decomp, levels, i, unknowns, rhos,
-                                       aux)
+                    with host_span("mg_transfer_down"):
+                        self.transfer_down(decomp, levels, i, unknowns,
+                                           rhos, aux)
                 elif i == previous - 1:
-                    self.transfer_up(decomp, levels, i, unknowns, rhos,
-                                     aux)
+                    with host_span("mg_transfer_up"):
+                        self.transfer_up(decomp, levels, i, unknowns,
+                                         rhos, aux)
                 else:
                     raise ValueError(
                         "consecutive levels must be spaced by one")
-                errors += self.smooth(levels, i, nu, unknowns, rhos, aux,
-                                      decomp)
+                with host_span("mg_smooth"):
+                    errors += self.smooth(levels, i, nu, unknowns, rhos,
+                                          aux, decomp)
                 previous = i
-            materialized = self._materialize_errors(errors)
+            with host_span("mg_errors_fetch"):
+                materialized = self._materialize_errors(errors)
         _metrics.counter("mg_cycles").inc()
         _metrics.counter("mg_smooths").inc(len(cycle))
         final = materialized[-1][1] if materialized else {}

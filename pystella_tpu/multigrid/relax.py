@@ -34,6 +34,7 @@ from jax import lax
 
 from pystella_tpu import field as _field
 from pystella_tpu.field import Field, Var, diff, evaluate
+from pystella_tpu.obs import events as _events
 from pystella_tpu.obs import memory as _obs_memory
 from pystella_tpu.obs.scope import trace_scope
 from pystella_tpu.ops.derivs import (
@@ -67,8 +68,9 @@ def _field_name(f):
 #: jitted (Linf, L2) residual norms — one executable shared by every
 #: solver instance; the four eager norm ops per unknown per smooth would
 #: each be a separate device dispatch (its cost on the chip: not measured)
-_residual_norms = jax.jit(lambda rn: (jnp.max(jnp.abs(rn)),
-                                      jnp.sqrt(jnp.mean(rn * rn))))
+_residual_norms = _obs_memory.instrument_jit(
+    lambda rn: (jnp.max(jnp.abs(rn)), jnp.sqrt(jnp.mean(rn * rn))),
+    label="mg.residual_norms")
 
 
 class RelaxationBase:
@@ -119,6 +121,7 @@ class RelaxationBase:
             self.resid_exprs[name] = rho - lhs
             self.lhs_exprs[name] = lhs
         self._compiled = {}
+        self._planned = set()
 
     # -- subclass hook ------------------------------------------------------
 
@@ -272,6 +275,27 @@ class RelaxationBase:
             return arrays
         return {k: jnp.asarray(v, self.dtype) for k, v in arrays.items()}
 
+    def _plan_level(self, kind, level, decomp, dtype, tier, st=None,
+                    reason=None):
+        """One ``mg_level_plan`` event a level: which tier serves it
+        (``streaming`` with its blocking, ``resident``, or ``xla`` with
+        the reason), said when the first of its kernels is built. A
+        level's smooth, residual and tau kernels have the same windows,
+        extras and outputs, so what one of them gets the others get."""
+        key = (level, decomp)
+        if key in self._planned:
+            return
+        self._planned.add(key)
+        proc = decomp.proc_shape if level.sharded else (1, 1, 1)
+        grid = getattr(st, "grid", None)  # a streaming kernel's alone
+        _events.emit(
+            "mg_level_plan", grid_shape=list(level.grid_shape),
+            local_shape=[n // p for n, p in zip(level.grid_shape, proc)],
+            tier=tier, stencil=type(st).__name__ if st is not None else None,
+            bx=getattr(st, "bx", None), by=getattr(st, "by", None),
+            grid=list(grid) if grid else None, reason=reason, kernel=kind, dtype=str(jnp.dtype(dtype)),
+            smoother=self.smoother, label=type(self).__name__)
+
     # -- Pallas sweep tier ---------------------------------------------------
 
     def _aux_struct(self, aux):
@@ -344,6 +368,8 @@ class RelaxationBase:
             return {"out": jnp.stack(vals)}
 
         st = None
+        reason = ("z-sharded mesh, or a sharded y no 8-row window "
+                  "divides")
         if feasible:
             extra_defs = {"rhos": (nf,), **{k: () for k in aux_lat}}
             try:
@@ -351,19 +377,26 @@ class RelaxationBase:
                     local_shape, {"f": nf}, self.halo_shape, body,
                     {"out": (nf,)}, extra_defs=extra_defs,
                     scalar_names=tuple(aux_scal), dtype=dtype,
-                    x_halo=(px > 1), y_halo=(py > 1))
-            except ValueError:
+                    x_halo=(px > 1), y_halo=(py > 1), kind="mg_" + kind)
+            except ValueError as e:
+                reason = str(e)
                 if px == 1 and py == 1:
                     try:
                         st = ResidentStencil(
                             local_shape, {"f": nf}, self.halo_shape,
                             body, {"out": (nf,)}, extra_defs=extra_defs,
                             scalar_names=tuple(aux_scal), dtype=dtype)
-                    except ValueError:
-                        st = None
+                    except ValueError as e2:
+                        reason = f"{e}; {e2}"
         if st is None:
+            self._plan_level(kind, level, decomp, dtype, "xla",
+                             reason=reason)
             self._compiled[key] = None
             return None
+        self._plan_level(
+            kind, level, decomp, dtype,
+            "streaming" if isinstance(st, StreamingStencil) else "resident",
+            st=st)
 
         halo = sharded_halo(self.halo_shape, px, py)
         sharded = px > 1 or py > 1
@@ -424,10 +457,12 @@ class RelaxationBase:
         return fn
 
     def _try_pallas(self, kind, level, fs, rhos, aux, decomp, nu=0):
-        if self.smoother != "pallas":
-            return None
         names = list(self.f_to_rho_dict)
         dtype = jnp.result_type(fs[names[0]])
+        if self.smoother != "pallas":
+            self._plan_level(kind, level, decomp, dtype, "xla",
+                             reason="smoother='xla'")
+            return None
         aux_struct = self._aux_struct(aux)
         fn = self._pallas_level(kind, level, decomp, dtype, aux_struct)
         if fn is None:

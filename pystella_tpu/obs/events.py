@@ -188,6 +188,9 @@ for _name, _help in (
     ("gate_verdict", "the perf gate ran (ok, exit_code, reasons)"),
     # -- numerics / solver hot paths ----------------------------------------
     ("mg_cycle", "one multigrid cycle (depth, smooths, errors)"),
+    ("mg_level_plan", "a multigrid level's kernels were built: which "
+                      "tier serves it ('streaming' with bx/by/grid, "
+                      "'resident', or 'xla' with the reason)"),
     # -- fused kernel tiers --------------------------------------------------
     ("block_choice", "a fused kernel build chose its blocking "
                      "(bx/by/grid/win_halo, halo: each of (x, y) "

@@ -96,7 +96,7 @@ for _name in (
     # named-scope path; the ledger's communication-time denominator
     "collective-permute",
     # Pallas kernel dispatch: a streaming kernel of no stated kind
-    # (multigrid sweeps, bare StreamingStencil users) and the
+    # (bare StreamingStencil users) and the
     # whole-lattice-resident tier; the kinds follow below
     "pallas_stencil", "pallas_resident_stencil",
     # ...the fused steppers' kernels by kind (kernel_scope)...
@@ -107,6 +107,10 @@ for _name in (
     "pallas_stencil_lap", "pallas_stencil_grad",
     "pallas_stencil_grad_lap", "pallas_stencil_pdx",
     "pallas_stencil_pdy", "pallas_stencil_pdz", "pallas_stencil_div",
+    # ...and the multigrid levels' kernels (multigrid.relax): a sweep,
+    # a residual pass, the FAS tau-corrected coarse source
+    "pallas_stencil_mg_smooth", "pallas_stencil_mg_residual",
+    "pallas_stencil_mg_tau",
     # the binning kernel behind every histogram and spectrum
     # (ops.histogram): a one-hot contraction on the MXU. Not a
     # pallas_stencil_* name: it streams no lattice windows, so no
@@ -119,8 +123,11 @@ for _name in (
     # lint tier treats any float downcast OUTSIDE this scope as a
     # POLICY_BF16_ACC32 violation
     "carry_quantize",
-    # multigrid
+    # multigrid: the cycle, and a level's sweeps and residual pass
+    # (mg_smooth is also the host span round a level's smooth with its
+    # two error norms); the host spans of the cycle's walk beside them
     "mg_cycle", "mg_smooth", "mg_residual",
+    "mg_transfer_down", "mg_transfer_up", "mg_errors_fetch",
     # driver-level span (the example's loop)
     "driver_step",
     # host spans (host_span): where the main path dispatches and where

@@ -365,7 +365,8 @@ def _stencil_of_kind(kind):
 
 @pytest.mark.parametrize("kind", [
     None, "stage", "pair", "coupled_pair", "energy", "chunk", "lap",
-    "grad", "grad_lap", "pdx", "pdy", "pdz", "div"])
+    "grad", "grad_lap", "pdx", "pdy", "pdz", "div",
+    "mg_smooth", "mg_residual", "mg_tau"])
 def test_kernel_kind_scope(kind):
     """Each kind of streaming kernel is dispatched under its own
     registered scope; the shared prefix keeps ``pallas_stencil``
@@ -514,3 +515,39 @@ def test_instrumented_programs_are_named_after_their_labels(make_decomp):
                  "jit_spectra_bin_weights", "jit_halo_pad",
                  "jit_health_vector", "jit_map_rho", "jit_dft_forward"):
         assert want in names, (want, sorted(names))
+
+
+def test_multigrid_walk_has_its_host_spans_and_kernel_kinds(make_decomp):
+    """A V-cycle's walk on the host: one ``mg_smooth`` a smooth, one
+    ``mg_transfer_down`` / ``mg_transfer_up`` a transfer, and the one
+    ``mg_errors_fetch`` that waits for the cycle; a level's kernels are
+    built under their own kinds (``doc/observability.md`` "Host spans",
+    "Kernel kinds")."""
+    from pystella_tpu.multigrid import (
+        FullApproximationScheme, NewtonIterator, v_cycle)
+    decomp = make_decomp((1, 1, 1))
+    solver = NewtonIterator(
+        decomp, {ps.Field("f"): (ps.Field("lap_f") - ps.Field("f"),
+                                 ps.Field("rho"))},
+        halo_shape=1, dtype=np.float32, smoother="pallas", omega=1 / 2)
+    mg = FullApproximationScheme(solver=solver, halo_shape=1)
+    rng = np.random.default_rng(4)
+    f, rho = (jnp.asarray(rng.random((16,) * 3), jnp.float32)
+              for _ in range(2))
+    mg(decomp, dx0=0.5, cycle=v_cycle(2, 3, 1), f=f, rho=rho)  # builds
+    with obs.recording() as rows:
+        mg(decomp, dx0=0.5, cycle=v_cycle(2, 3, 1), f=f, rho=rho)
+    table = scope.span_table(rows)
+    counts = {k: v["count"] for k, v in table["spans"].items()}
+    assert counts == {"mg_smooth": 3, "mg_transfer_down": 1,
+                      "mg_transfer_up": 1, "mg_errors_fetch": 1}
+    assert table["fetches"] == 1
+    kinds = {key[1]: fn for key, fn in solver._compiled.items()
+             if key[0] == "pallas"}
+    assert set(kinds) == {"smooth", "residual", "tau"}
+    level = next(key[2] for key in solver._compiled if key[0] == "pallas")
+    for kind in kinds:
+        lowered = solver._pallas_level(
+            kind, level, decomp, jnp.dtype("float32"), ())._jitted.lower(
+                (f,), (rho,), (), jnp.int32(2))
+        assert obs.has_scope(lowered, "pallas_stencil_mg_" + kind)

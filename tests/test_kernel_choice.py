@@ -65,6 +65,8 @@ def _built(name):
     with open(os.path.join(REPO, "benchmark", "configs",
                            name + ".json")) as f:
         cfg = json.load(f)
+    if cfg.get("family") == "multigrid":
+        return _built_multigrid(cfg)
     grid = tuple(cfg["grid_shape"])
     proc = tuple(cfg["proc_shape"])
     ndev = int(np.prod(proc))
@@ -123,8 +125,39 @@ def _built(name):
             "warnings": [str(w.message) for w in warned]}
 
 
+def _built_multigrid(cfg):
+    """What the multigrid cell's solver builds for each level of its
+    default cycle (``benchmark/families/multigrid.py``: ``NewtonIterator``
+    with the configuration's problems, the Pallas smoother a TPU gets),
+    as its ``mg_level_plan`` events say: compiled-mode kernels, never
+    called."""
+    from pystella_tpu.multigrid import NewtonIterator
+    from pystella_tpu.multigrid.relax import LevelSpec
+    grid = tuple(cfg["grid_shape"])
+    dx = cfg["box_dim"][0] / grid[0]
+    decomp = ps.DomainDecomposition((1, 1, 1), devices=_devs(1))
+    solver = NewtonIterator(
+        decomp,
+        {ps.Field("f"): (ps.Field("lap_f"), ps.Field("rho")),
+         ps.Field("f2"): (ps.Field("lap_f2") - ps.Field("f2"),
+                          ps.Field("rho2"))},
+        halo_shape=cfg["halo_shape"], dtype=np.dtype(cfg["dtype"]),
+        smoother="pallas", fixed_parameters=dict(omega=cfg["omega"]))
+    with _watch_events() as seen, \
+            mock.patch.object(psten, "_is_cpu", lambda: False):
+        for i in range(cfg["depth"] + 1):
+            level = LevelSpec(tuple(n >> i for n in grid),
+                              (dx * 2 ** i,) * 3, False)
+            for kind in ("smooth", "residual", "tau"):
+                assert solver._pallas_level(
+                    kind, level, decomp, jnp.dtype(cfg["dtype"]), ())
+    return {"plans": seen.of("mg_level_plan")}
+
+
 #: (configuration, kernel, which build of that kind, (bx, by), grid,
-#: source). `energy` is "explicit" because the stepper hands it the
+#: source). For the multigrid cell the build is the level (one
+#: ``mg_level_plan`` a level, whichever of its three kernels came first),
+#: and the source the tier where that is not ``streaming``. `energy` is "explicit" because the stepper hands it the
 #: stage kernel's blocking; ``None`` for a kernel that fits no blocking.
 _CELL_KERNELS = [
     ("preheat-512-f32", "stage", 0, (2, 64), (8, 256), "heuristic"),
@@ -154,6 +187,13 @@ _CELL_KERNELS = [
     ("preheat-gw-f32", "coupled_pair", 1, None, None, None),
     ("preheat-gw-f32", "lap", 0, (2, 128), (3, 192), "heuristic"),
     ("preheat-gw-f32", "grad", 0, (2, 128), (3, 192), "heuristic"),
+    # the multigrid cell's levels as the chip run built them (PR 32):
+    # 512^3, 256^3 and 128^3 stream, 64^3 ... 8^3 (Z < 128) are resident
+    ("multigrid-512-f32", "mg_smooth", 0, (1, 256), (2, 512), "heuristic"),
+    ("multigrid-512-f32", "mg_smooth", 1, (1, 256), (1, 256), "heuristic"),
+    ("multigrid-512-f32", "mg_smooth", 2, (1, 128), (1, 128), "heuristic"),
+    ("multigrid-512-f32", "mg_smooth", 3, None, None, "resident"),
+    ("multigrid-512-f32", "mg_smooth", 6, None, None, "resident"),
 ]
 
 
@@ -165,6 +205,21 @@ def test_cells_get_the_kernels_the_ledger_measured(config, kernel, nth,
     built = _built(config)
     if built is None:
         pytest.skip(f"{config} needs more devices than this host has")
+    if "plans" in built:
+        assert len(built["plans"]) == 7
+        d = built["plans"][nth]
+        assert d["grid_shape"] == [512 >> nth] * 3 and d["kernel"] == "smooth"
+        assert d["smoother"] == "pallas" and d["dtype"] == "float32"
+        if blocks is None:
+            assert (d["tier"], d["stencil"]) == (source, "ResidentStencil")
+            assert d["bx"] is d["by"] is d["grid"] is None
+        else:
+            assert (d["tier"], d["stencil"]) == ("streaming",
+                                                 "StreamingStencil")
+            assert (d["bx"], d["by"]) == blocks
+            assert tuple(d["grid"]) == grid
+        assert d["reason"] is None
+        return
     # the tier multi_step dispatches: five pair kernels per two steps
     assert built["tier"]["tier"] == "pair"
     assert built["tier"]["kernels_per_2_steps"] == {"pair": 5}
@@ -296,6 +351,47 @@ def test_events_carry_what_the_benchmark_prints(build, names):
         assert d["tier"] == "pair"
     for d in choices + tiers:
         assert not any("autotune" in key for key in d)
+
+
+@pytest.mark.parametrize("smoother, tiers", [
+    ("pallas", ["streaming", "streaming", "streaming"]),
+    ("xla", ["xla", "xla", "xla"]),
+])
+def test_level_plans_carry_what_the_benchmark_prints(smoother, tiers):
+    """``benchmark/families/multigrid.py`` prints ``level <grid_shape>:
+    <tier> (bx, by) = (<bx>, <by>), grid <grid>`` (or the reason, for a
+    level on the XLA path) of every ``mg_level_plan``, and counts a level
+    on the XLA path under the Pallas smoother as a fallback: one event a
+    level of a cycle, whatever kernels of it were built."""
+    from pystella_tpu.multigrid import (
+        FullApproximationScheme, NewtonIterator)
+    decomp = ps.DomainDecomposition((1, 1, 1), devices=_devs(1))
+    solver = NewtonIterator(
+        decomp, {ps.Field("f"): (ps.Field("lap_f") - ps.Field("f"),
+                                 ps.Field("rho"))},
+        halo_shape=1, dtype=np.float32, smoother=smoother, omega=1 / 2)
+    mg = FullApproximationScheme(solver=solver, halo_shape=1)
+    rng = np.random.default_rng(9)
+    f, rho = (jnp.asarray(rng.random((32,) * 3), jnp.float32)
+              for _ in range(2))
+    with _watch_events() as seen:
+        for _ in range(2):
+            mg(decomp, dx0=0.3, f=f, rho=rho)
+    plans = seen.of("mg_level_plan")
+    assert [d["grid_shape"] for d in plans] == [[32] * 3, [16] * 3, [8] * 3]
+    assert [d["tier"] for d in plans] == tiers
+    for d in plans:
+        assert {"grid_shape", "local_shape", "tier", "stencil", "bx", "by",
+                "grid", "reason", "kernel", "dtype", "smoother",
+                "label"} <= set(d)
+        assert d["smoother"] == smoother and d["dtype"] == "float32"
+        if d["tier"] == "streaming":
+            n = d["grid_shape"][0]
+            assert d["grid"] == [n // d["by"], n // d["bx"]]
+            assert d["reason"] is None
+        else:
+            assert d["bx"] is None and d["reason"] == "smoother='xla'"
+    assert len(seen.of("mg_cycle")) == 2
 
 
 def test_a_stray_table_or_variable_changes_nothing(tmp_path, monkeypatch):

@@ -322,3 +322,59 @@ def test_bincount_programs_compile(v5e, proc_shape, program, num_bins,
     assert mem.temp_size_in_bytes <= 2 * mem.argument_size_in_bytes
     if lattice[-1] % 128 == 0:
         assert mem.temp_size_in_bytes < 2**20
+
+
+#: the levels of ``multigrid-512-f32``'s default cycle; the finest in
+#: tier-1, the rest ``slow``
+MG_LEVELS = [512] + [pytest.param(n, marks=pytest.mark.slow)
+                     for n in (256, 128, 64, 32, 16, 8)]
+
+
+@pytest.mark.parametrize("n", MG_LEVELS)
+def test_multigrid_level_programs_compile(v5e, monkeypatch, n):
+    """What ``benchmark/configs/multigrid-512-f32.json`` needs of a chip:
+    the float32 two-unknown smooth, residual and tau programs of a level
+    (``NewtonIterator._pallas_level``: Poisson + Helmholtz, h = 1) compile
+    for one v5e chip and fit its 15.75 GB beside the cell's four resident
+    512**3 arrays; each is one program named after the level
+    (``jit_pallas_<kind>_<n>_<n>_<n>``) whose one Mosaic call is named
+    after the kernel's kind where the level streams (Z a multiple of 128:
+    ``%pallas_stencil_mg_<kind>.N``, what the benchmark's kernel files
+    match) and ``%pallas_resident_stencil.N`` below."""
+    import re
+    from pystella_tpu.multigrid import NewtonIterator
+    from pystella_tpu.multigrid.relax import LevelSpec
+    from pystella_tpu.ops import pallas_stencil
+    # the solver takes no interpret= override: build for the chip
+    monkeypatch.setattr(pallas_stencil, "_is_cpu", lambda: False)
+    decomp = ps.DomainDecomposition((1, 1, 1), devices=v5e[:1])
+    solver = NewtonIterator(
+        decomp,
+        {ps.Field("f"): (ps.Field("lap_f"), ps.Field("rho")),
+         ps.Field("f2"): (ps.Field("lap_f2") - ps.Field("f2"),
+                          ps.Field("rho2"))},
+        halo_shape=1, dtype=np.float32, smoother="pallas",
+        fixed_parameters=dict(omega=1 / 2))
+    level = LevelSpec((n,) * 3, (10.0 / n,) * 3, False)
+    x = jax.ShapeDtypeStruct((n,) * 3, jnp.float32,
+                             sharding=decomp.sharding(0))
+    nu = jax.ShapeDtypeStruct(
+        (), jnp.int32, sharding=NamedSharding(decomp.mesh, P()))
+    resident = 4 * 4 * 512**3  # the seeded f, f2 and rho, rho2 of the cell
+    for kind in ("smooth", "residual", "tau"):
+        fn = solver._pallas_level(kind, level, decomp, jnp.dtype("float32"),
+                                  ())
+        assert fn is not None, f"{kind} at {n}^3 fell to the XLA path"
+        compiled = fn._jitted.trace((x, x), (x, x), (), nu).lower(
+            lowering_platforms=("tpu",)).compile()
+        hlo = compiled.as_text()
+        assert hlo.startswith(f"HloModule jit_pallas_{kind}_{n}_{n}_{n}"), \
+            hlo[:60]
+        kinds = {re.sub(r"\.\d+$", "", name)
+                 for name in _custom_call_names(hlo)}
+        assert kinds == {f"pallas_stencil_mg_{kind}" if n % 128 == 0
+                         else "pallas_resident_stencil"}, sorted(kinds)
+        mem = compiled.memory_analysis()
+        held = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+                + mem.output_size_in_bytes)
+        assert held + resident < 15.75 * 2**30, held
