@@ -5,10 +5,17 @@ The reference builds four loopy kernels per solver (stepper, residual,
 lhs-correction, residual statistics) and ping-pongs ``f``/``tmp_f`` arrays
 with a halo exchange per iteration. Here each of those becomes a jitted
 function; the whole ``nu``-iteration smooth runs as ONE compiled
-computation — a ``lax.fori_loop`` whose body fuses the stencil evaluation
-with the pointwise update, with ``lax.ppermute`` halo exchanges inside (via
-``shard_map``) on sharded levels and periodic-wrap pads on replicated
-(coarse) levels.
+computation. On the XLA path that is a ``lax.fori_loop`` whose body fuses
+the stencil evaluation with the pointwise update, with ``lax.ppermute``
+halo exchanges inside (via ``shard_map``) on sharded levels and
+periodic-wrap pads on replicated (coarse) levels. On the Pallas path
+(``smoother="pallas"``) a sweep is one stencil kernel and the loop runs
+two sweeps an iteration, the odd sweep after it: a ``while``'s carry
+and the kernel's operand cannot share a buffer (the kernel reads a window
+of its input while it writes), so with one sweep an iteration XLA copies
+the whole carry before every kernel call; with two, the first sweep
+writes a temporary, the second writes the carry's buffer back, and
+nothing is copied (``RelaxationBase._pallas_level``).
 
 Equations are specified as in the reference (``lhs_dict`` mapping unknown
 :class:`~pystella_tpu.Field`\\ s to ``(lhs, rho)`` pairs), with one
@@ -309,13 +316,27 @@ class RelaxationBase:
         return tuple(struct)
 
     def _pallas_level(self, kind, level, decomp, dtype, aux_struct):
-        """A stencil-kernel pass for one level: ``smooth`` (runtime-``nu``
-        ``fori_loop`` of whole-sweep kernels — one compile serves every
-        sweep count) or ``residual``. Each sweep reads the unknowns once
-        from HBM, computes the order-2h Laplacian from the VMEM window,
-        evaluates the update pointwise, and writes once — the identical
-        streaming pattern as the fused RK stages, replacing the XLA
-        halo-pad sweeps measured ~10x below bandwidth (VERDICT r3 #5).
+        """A stencil-kernel pass for one level: ``smooth``, ``residual``
+        or ``tau``. Each sweep reads the unknowns once from HBM, computes
+        the order-2h Laplacian from the VMEM window, evaluates the update
+        pointwise, and writes once — the identical streaming pattern as
+        the fused RK stages.
+
+        A smooth is ``nu`` such kernel calls with ``nu`` a runtime
+        ``int32``, so one compile serves every sweep count: a
+        ``fori_loop`` of ``nu // 2`` iterations of TWO sweeps each, then
+        the odd sweep under a ``cond``. Two, because a ``while``'s carry
+        is one buffer and the kernel cannot write where it still reads:
+        with one sweep an iteration the carry would be both the kernel's
+        operand and its result, and XLA resolves that by copying the
+        carry before every call (a read and a write of the whole stack
+        beside each sweep's own: ``PERF.md`` section 6, PR 33). With two,
+        the first sweep writes a temporary and the second writes the
+        carry's buffer, whose last reader has finished: the buffers
+        alternate. The ``cond``'s branches return the unknowns unstacked,
+        so neither passes its operand through and the odd sweep needs no
+        copy either (``tests/test_tpu_compile.py`` holds both).
+
         Returns None when this level/mesh cannot take the kernel tier
         (z-sharded, sublane-infeasible sharded y, over-budget resident)
         — callers fall back to the XLA path."""
@@ -428,9 +449,17 @@ class RelaxationBase:
                     if sharded else fst)
                 return st(fin, scalars=scalars, extras=extras)["out"]
 
+            def unstack(fst):
+                return tuple(fst[i] for i in range(nf))
+
             if kind != "smooth":
-                return one(fstack)
-            return lax.fori_loop(0, nu, lambda _, fst: one(fst), fstack)
+                return unstack(one(fstack))
+            # two sweeps an iteration: the carry's buffer and a temporary
+            # alternate, and XLA copies nothing (the docstring says why)
+            fstack = lax.fori_loop(
+                0, nu // 2, lambda _, fst: one(one(fst)), fstack)
+            return lax.cond(nu % 2 == 1, lambda fst: unstack(one(fst)),
+                            unstack, fstack)
 
         if sharded:
             spec = decomp.spec(1)
@@ -438,7 +467,8 @@ class RelaxationBase:
             in_specs = (spec, spec,
                         (spec,) * len(aux_lat) + (P(),) * len(aux_scal),
                         P())
-            core = decomp.shard_map(run, in_specs, spec, check_vma=False)
+            core = decomp.shard_map(run, in_specs, (decomp.spec(0),) * nf,
+                                    check_vma=False)
         else:
             core = run
 
@@ -448,8 +478,7 @@ class RelaxationBase:
             # XLA fuses or aliases them into the kernel's input layout
             fstack = jnp.stack(f_list)
             rhostack = jnp.stack([jnp.asarray(r, dtype) for r in rho_list])
-            out = core(fstack, rhostack, aux_args, nu)
-            return [out[i] for i in range(len(f_list))]
+            return list(core(fstack, rhostack, aux_args, nu))
 
         fn = _obs_memory.instrument_jit(
             entry, label=f"mg.pallas_{kind}{tuple(level.grid_shape)}")

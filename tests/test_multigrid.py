@@ -300,3 +300,57 @@ def test_pallas_smoother_full_cycle(make_decomp, grid_shape):
         f = sol["f"]
         err = errs[-1][-1]["f"][1]
     assert err < 5e-13, err
+
+
+#: per (mesh, precision): the solver, its level and arrays, and the
+#: unknowns after 0..25 single-sweep calls — built once, by the first
+#: case that needs them (the file runs in one worker)
+_SWEEP_CHAINS = {}
+
+
+def _sweep_chain(make_decomp, grid_shape, proc_shape, dtype):
+    from pystella_tpu.multigrid.relax import LevelSpec
+    key = (proc_shape, grid_shape, np.dtype(dtype).name)
+    if key not in _SWEEP_CHAINS:
+        decomp = make_decomp(proc_shape)
+        level = LevelSpec(tuple(grid_shape), (10.0 / grid_shape[0],) * 3,
+                          any(p > 1 for p in proc_shape))
+        solver = NewtonIterator(
+            decomp, make_problems(), halo_shape=1, dtype=dtype,
+            smoother="pallas", fixed_parameters=dict(omega=1 / 2))
+        rng = np.random.default_rng(33)
+        f, f2, rho, rho2 = zero_mean_arrays(rng, decomp, grid_shape, 4,
+                                            dtype=dtype)
+        rhos = {"rho": rho, "rho2": rho2}
+        fs, chain = {"f": f, "f2": f2}, []
+        for _ in range(26):
+            chain.append({n: np.asarray(v) for n, v in fs.items()})
+            fs = solver.smooth(level, fs, rhos, {}, 1, decomp)
+        _SWEEP_CHAINS[key] = (decomp, level, solver, rhos, chain)
+    return _SWEEP_CHAINS[key]
+
+
+@pytest.mark.parametrize("nu", [0, 1, 2, 3, 25])
+@pytest.mark.parametrize("dtype", [np.float32, np.float64])
+@pytest.mark.parametrize("proc_shape", [(1, 1, 1), (2, 2, 1), (2, 1, 1)],
+                         indirect=True)
+def test_pallas_smooth_is_nu_single_sweeps(make_decomp, grid_shape,
+                                           proc_shape, dtype, nu):
+    """``smooth(level, ..., nu)`` on the kernel tier is ``nu`` single-sweep
+    calls, bit for bit, whatever ``nu``'s parity: the sweep loop runs two
+    sweeps an iteration and the odd one after it (so that XLA need not
+    copy the loop's carry before every kernel call on the chip), which
+    changes which buffer a sweep writes and no arithmetic. 0 is the empty
+    loop, 1 the odd sweep alone, 2 a pair alone, 3 and 25 both; one
+    compiled program serves them all. Interpret mode on the CPU."""
+    decomp, level, solver, rhos, chain = _sweep_chain(
+        make_decomp, grid_shape, proc_shape, dtype)
+    fs = {n: decomp.shard(v) for n, v in chain[0].items()}
+    got = solver.smooth(level, fs, rhos, {}, nu, decomp)
+    for n, want in chain[nu].items():
+        assert got[n].dtype == want.dtype == np.dtype(dtype)
+        np.testing.assert_array_equal(np.asarray(got[n]), want, err_msg=n)
+    # every sweep count went through the level's one smooth program
+    smooths = [k for k in solver._compiled
+               if k[0] == "pallas" and k[1] == "smooth"]
+    assert len(smooths) == 1 and solver._compiled[smooths[0]] is not None

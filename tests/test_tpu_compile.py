@@ -330,18 +330,10 @@ MG_LEVELS = [512] + [pytest.param(n, marks=pytest.mark.slow)
                      for n in (256, 128, 64, 32, 16, 8)]
 
 
-@pytest.mark.parametrize("n", MG_LEVELS)
-def test_multigrid_level_programs_compile(v5e, monkeypatch, n):
-    """What ``benchmark/configs/multigrid-512-f32.json`` needs of a chip:
-    the float32 two-unknown smooth, residual and tau programs of a level
-    (``NewtonIterator._pallas_level``: Poisson + Helmholtz, h = 1) compile
-    for one v5e chip and fit its 15.75 GB beside the cell's four resident
-    512**3 arrays; each is one program named after the level
-    (``jit_pallas_<kind>_<n>_<n>_<n>``) whose one Mosaic call is named
-    after the kernel's kind where the level streams (Z a multiple of 128:
-    ``%pallas_stencil_mg_<kind>.N``, what the benchmark's kernel files
-    match) and ``%pallas_resident_stencil.N`` below."""
-    import re
+def _mg_level_compiler(v5e, monkeypatch, n):
+    """``compile(kind)`` for the float32 two-unknown programs of one
+    level of ``multigrid-512-f32`` (``NewtonIterator._pallas_level``:
+    Poisson + Helmholtz, h = 1) on one v5e chip."""
     from pystella_tpu.multigrid import NewtonIterator
     from pystella_tpu.multigrid.relax import LevelSpec
     from pystella_tpu.ops import pallas_stencil
@@ -360,13 +352,31 @@ def test_multigrid_level_programs_compile(v5e, monkeypatch, n):
                              sharding=decomp.sharding(0))
     nu = jax.ShapeDtypeStruct(
         (), jnp.int32, sharding=NamedSharding(decomp.mesh, P()))
-    resident = 4 * 4 * 512**3  # the seeded f, f2 and rho, rho2 of the cell
-    for kind in ("smooth", "residual", "tau"):
+
+    def compile(kind):
         fn = solver._pallas_level(kind, level, decomp, jnp.dtype("float32"),
                                   ())
         assert fn is not None, f"{kind} at {n}^3 fell to the XLA path"
-        compiled = fn._jitted.trace((x, x), (x, x), (), nu).lower(
+        return fn._jitted.trace((x, x), (x, x), (), nu).lower(
             lowering_platforms=("tpu",)).compile()
+    return compile
+
+
+@pytest.mark.parametrize("n", MG_LEVELS)
+def test_multigrid_level_programs_compile(v5e, monkeypatch, n):
+    """What ``benchmark/configs/multigrid-512-f32.json`` needs of a chip:
+    the float32 two-unknown smooth, residual and tau programs of a level
+    compile for one v5e chip and fit its 15.75 GB beside the cell's four
+    resident 512**3 arrays; each is one program named after the level
+    (``jit_pallas_<kind>_<n>_<n>_<n>``) whose Mosaic calls are named
+    after the kernel's kind where the level streams (Z a multiple of 128:
+    ``%pallas_stencil_mg_<kind>.N``, what the benchmark's kernel files
+    match) and ``%pallas_resident_stencil.N`` below."""
+    import re
+    compile = _mg_level_compiler(v5e, monkeypatch, n)
+    resident = 4 * 4 * 512**3  # the seeded f, f2 and rho, rho2 of the cell
+    for kind in ("smooth", "residual", "tau"):
+        compiled = compile(kind)
         hlo = compiled.as_text()
         assert hlo.startswith(f"HloModule jit_pallas_{kind}_{n}_{n}_{n}"), \
             hlo[:60]
@@ -378,3 +388,47 @@ def test_multigrid_level_programs_compile(v5e, monkeypatch, n):
         held = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
                 + mem.output_size_in_bytes)
         assert held + resident < 15.75 * 2**30, held
+
+
+@pytest.mark.parametrize(
+    "n", [512, pytest.param(256, marks=pytest.mark.slow),
+          pytest.param(128, marks=pytest.mark.slow)])
+def test_multigrid_smooth_loop_copies_no_lattice(v5e, monkeypatch, n):
+    """A streaming level's smooth program holds no lattice-shaped ``copy``
+    inside its ``while`` body, and at most one in the whole module. The
+    sweep loop's carry is the ``(2, n, n, n)`` stack of unknowns, and the
+    kernel cannot write the buffer it reads: with one sweep an iteration
+    (the parent of PR 33, on which this test fails: it finds ``%copy.9``
+    in ``%wide.region_0.1.sunk``) XLA copies the carry before every kernel
+    call, 2.15 GB and 3.2 ms beside a 4.84-ms kernel at 512**3. With two
+    sweeps an iteration the body is two kernel calls whose buffers
+    alternate; each still takes two lattice operands (unknowns, sources)
+    and gives one output, which is what the benchmark's byte count reads
+    from the instruction."""
+    import re
+    hlo = _mg_level_compiler(v5e, monkeypatch, n)("smooth").as_text()
+    bodies = re.findall(r" while\(.*\bbody=%([\w.]+)", hlo)
+    assert len(bodies) == 1, bodies
+    computations = {}  # name -> its instructions' lines
+    for line in hlo.splitlines():
+        head = re.match(r"(?:ENTRY )?%([\w.]+) \(.*\{$", line)
+        if head:
+            lines = computations[head.group(1)] = []
+        elif line.startswith("  "):
+            lines.append(line)
+    lattice = rf"f32\[2,{n},{n},{n}\]"
+    copies = {name: [ln.split(" = ")[0].strip() for ln in lines
+                     if re.search(rf"= {lattice}\S* copy\(", ln)]
+              for name, lines in computations.items()}
+    assert not copies[bodies[0]], (bodies[0], copies[bodies[0]])
+    assert sum(map(len, copies.values())) <= 1, copies
+    calls = [ln for ln in computations[bodies[0]]
+             if "tpu_custom_call" in ln]
+    assert len(calls) == 2, calls
+    for ln in calls:
+        assert re.search(r"%pallas_stencil_mg_smooth\.\d+ = "
+                         rf"{lattice}\S* custom-call\(", ln), ln[:200]
+        operands = re.search(
+            r"operand_layout_constraints=\{(.*?)\}, \w+=", ln).group(1)
+        assert re.findall(r"f32\[[\d,]*\]", operands) \
+            == [f"f32[2,{n},{n},{n}]"] * 2, operands
