@@ -6,6 +6,16 @@ derivatives by FFT → multiply by ``i k`` (Nyquist modes zeroed for odd
 derivatives) or ``-k²`` → inverse FFT. Because :meth:`DFT.idft` is already
 normalized, no manual ``1/grid_size`` factor is needed (unlike
 derivs.py:78-79).
+
+What a trace and the event log say of it (``doc/observability.md``
+"Trace scopes"): the five programs are ``jit_spectral_lap``, ``_grad``,
+``_grad_lap``, ``_pd`` and ``_div``; inside them, and inside a caller's
+program that inlines them (a stepper's stage program whose right-hand
+side calls :meth:`SpectralCollocator.lap`), the ops lie under the scopes
+``spectral_forward``, ``spectral_symbol`` and ``spectral_inverse``; the
+host spans ``spectral_lap_dispatch`` and ``spectral_grad_dispatch`` lie
+round the two calls a driver loop makes; one ``spectral_plan`` event a
+built collocator says which transform and which inverse it got.
 """
 
 from __future__ import annotations
@@ -15,7 +25,16 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from pystella_tpu.obs import events as _events
+from pystella_tpu.obs import memory as _obs_memory
+from pystella_tpu.obs.scope import host_span, trace_scope
+
 __all__ = ["SpectralCollocator"]
+
+
+def _platform(decomp):
+    """The platform of the devices ``decomp`` places arrays on."""
+    return decomp.mesh.devices.flat[0].platform
 
 
 class SpectralCollocator:
@@ -30,6 +49,15 @@ class SpectralCollocator:
         self.fft = fft
         self.decomp = fft.decomp
         rdtype = fft.rdtype
+        inverse = "matmul" if fft._matmul_inverse else "xla"
+        if fft.is_real and inverse == "xla" \
+                and _platform(self.decomp) == "tpu":
+            raise ValueError(
+                "SpectralCollocator: this DFT brings a real field back by "
+                "XLA's inverse real transform, which is wrong on the TPU "
+                "(a third of the signal's rms off: PERF.md section 6, PR "
+                "28), so every derivative would be; build the transform "
+                "with DFT(..., real_inverse=\"matmul\")")
 
         # momentum arrays in the transform's own k layout
         # (fft.k_axis_array): the multiplies stay elementwise on the
@@ -45,57 +73,89 @@ class SpectralCollocator:
             self._k1.append(fft.k_axis_array(mu, k1))
             self._k2.append(fft.k_axis_array(mu, k2))
 
-        self._lap = jax.jit(self._lap_impl)
-        self._grad = jax.jit(self._grad_impl)
-        self._grad_lap = jax.jit(self._grad_lap_impl)
-        self._pd = jax.jit(self._pd_impl, static_argnums=1)
-        self._div = jax.jit(self._div_impl)
+        def program(impl, name, **jit_kwargs):
+            return _obs_memory.instrument_jit(
+                impl, label="fourier.spectral_" + name, **jit_kwargs)
+
+        self._lap = program(self._lap_impl, "lap")
+        self._grad = program(self._grad_impl, "grad")
+        self._grad_lap = program(self._grad_lap_impl, "grad_lap")
+        self._pd = program(self._pd_impl, "pd", static_argnums=1)
+        self._div = program(self._div_impl, "div")
+        _events.emit(
+            "spectral_plan", scheme=fft.scheme, inverse=inverse,
+            grid_shape=list(fft.grid_shape), dtype=str(fft.dtype),
+            fields_a_call="all")
+
+    # -- the three parts of every derivative, each under its scope ---------
+
+    def _forward(self, fx):
+        with trace_scope("spectral_forward"):
+            return self.fft._dft_impl(fx)
+
+    def _inverse(self, fk, dtype):
+        with trace_scope("spectral_inverse"):
+            return self.fft._idft_impl(fk).astype(dtype)
+
+    def _minus_ksq(self):
+        """``-(kx² + ky² + kz²)``, added up where it is used. The three
+        axes' momenta are constants of the program, and the compiler
+        would fold their broadcast sum into one of the whole half
+        spectrum (269 MB at 512³ in every program that takes a
+        Laplacian, and minutes of compile time: PR 34); behind the
+        barrier the sum fuses into the multiply that reads it."""
+        kx, ky, kz = jax.lax.optimization_barrier(tuple(self._k2))
+        return -(kx * kx + ky * ky + kz * kz)
 
     def _lap_impl(self, fx):
-        fk = self.fft._dft_impl(fx)
-        ksq = sum(k * k for k in self._k2)
-        return self.fft._idft_impl(-ksq * fk).astype(fx.dtype)
+        fk = self._forward(fx)
+        with trace_scope("spectral_symbol"):
+            lap_k = self._minus_ksq() * fk
+        return self._inverse(lap_k, fx.dtype)
+
+    def _pd_k(self, fk, mu):
+        with trace_scope("spectral_symbol"):
+            return 1j * self._k1[mu] * fk
 
     def _pd_impl(self, fx, mu):
-        fk = self.fft._dft_impl(fx)
-        return self.fft._idft_impl(1j * self._k1[mu] * fk).astype(fx.dtype)
+        return self._inverse(self._pd_k(self._forward(fx), mu), fx.dtype)
+
+    def _grad_of(self, fk, dtype):
+        return jnp.stack([self._inverse(self._pd_k(fk, mu), dtype)
+                          for mu in range(3)], axis=fk.ndim - 3)
 
     def _grad_impl(self, fx):
-        fk = self.fft._dft_impl(fx)
-        la = fx.ndim - 3
-        return jnp.stack(
-            [self.fft._idft_impl(1j * self._k1[mu] * fk).astype(fx.dtype)
-             for mu in range(3)], axis=la)
+        return self._grad_of(self._forward(fx), fx.dtype)
 
     def _grad_lap_impl(self, fx):
-        fk = self.fft._dft_impl(fx)
-        la = fx.ndim - 3
-        grd = jnp.stack(
-            [self.fft._idft_impl(1j * self._k1[mu] * fk).astype(fx.dtype)
-             for mu in range(3)], axis=la)
-        ksq = sum(k * k for k in self._k2)
-        lap = self.fft._idft_impl(-ksq * fk).astype(fx.dtype)
-        return grd, lap
+        fk = self._forward(fx)
+        grd = self._grad_of(fk, fx.dtype)
+        with trace_scope("spectral_symbol"):
+            lap_k = self._minus_ksq() * fk
+        return grd, self._inverse(lap_k, fx.dtype)
 
     def _div_impl(self, vec):
         # sum the i*k_mu-weighted spectra in k-space: one inverse FFT
         # instead of three (the forward transforms batch over the
         # component axis)
-        fk = self.fft._dft_impl(vec)
+        fk = self._forward(vec)
         la = fk.ndim - 4
-        div_k = sum(1j * self._k1[mu] * jnp.take(fk, mu, axis=la)
-                    for mu in range(3))
-        return self.fft._idft_impl(div_k).astype(vec.dtype)
+        with trace_scope("spectral_symbol"):
+            div_k = sum(1j * self._k1[mu] * jnp.take(fk, mu, axis=la)
+                        for mu in range(3))
+        return self._inverse(div_k, vec.dtype)
 
     # -- public interface (mirrors FiniteDifferencer) ----------------------
     # (reshard targets carry their mesh, so no ambient context is needed
     # whether called eagerly or inside a caller's jit)
 
     def lap(self, f):
-        return self._lap(f)
+        with host_span("spectral_lap_dispatch"):
+            return self._lap(f)
 
     def grad(self, f):
-        return self._grad(f)
+        with host_span("spectral_grad_dispatch"):
+            return self._grad(f)
 
     def grad_lap(self, f):
         return self._grad_lap(f)

@@ -191,6 +191,10 @@ for _name, _help in (
     ("mg_level_plan", "a multigrid level's kernels were built: which "
                       "tier serves it ('streaming' with bx/by/grid, "
                       "'resident', or 'xla' with the reason)"),
+    ("spectral_plan", "a SpectralCollocator was built: the transform's "
+                      "scheme, how a real field comes back ('xla' or "
+                      "'matmul'), grid, dtype, and how many fields "
+                      "of a call go through one transform ('all')"),
     # -- fused kernel tiers --------------------------------------------------
     ("block_choice", "a fused kernel build chose its blocking "
                      "(bx/by/grid/win_halo, halo: each of (x, y) "

@@ -141,6 +141,12 @@ for _name in (
     # the transverse-traceless projection of the -gws output
     # (fourier.projectors; its program is jit_tt_project)
     "tt_project",
+    # spectral derivatives (fourier.derivs): the three parts of every
+    # derivative, in the collocator's own programs (jit_spectral_lap,
+    # ...) and in a stepper's stage program that inlines them; and the
+    # host spans round the two calls a driver loop makes
+    "spectral_forward", "spectral_symbol", "spectral_inverse",
+    "spectral_lap_dispatch", "spectral_grad_dispatch",
     # the in-graph numerics health vector (obs.sentinel)
     "sentinel",
     # the ensemble tier (pystella_tpu.ensemble): the batched member

@@ -62,6 +62,73 @@ def test_divergence_and_pd(setup, grid_shape, proc_shape):
     assert np.abs(np.asarray(sc.pdz(arr)) - kz * np.cos(phase)).max() < 1e-10
 
 
+def test_refuses_xlas_inverse_on_a_tpu_backend(setup, monkeypatch):
+    """On the TPU XLA's inverse real transform is wrong, so a collocator
+    built on it there is refused with the option's name. The check reads
+    the backend of the transform's devices and the transform's option:
+    built on the CPU, refused on a TPU with the default inverse, built
+    there with the inverse by matrix products or a complex transform."""
+    from pystella_tpu.fourier import derivs
+    decomp, lattice, fft = setup
+    matmul = ps.DFT(decomp, grid_shape=fft.grid_shape, dtype=np.float64,
+                    real_inverse="matmul")
+    c2c = ps.DFT(decomp, grid_shape=fft.grid_shape, dtype=np.complex128)
+    ps.SpectralCollocator(fft, lattice.dk)
+    asked = []
+    monkeypatch.setattr(derivs, "_platform",
+                        lambda d: asked.append(d) or "tpu")
+    with pytest.raises(ValueError, match='real_inverse="matmul"'):
+        ps.SpectralCollocator(fft, lattice.dk)
+    assert asked == [decomp]
+    ps.SpectralCollocator(matmul, lattice.dk)
+    ps.SpectralCollocator(c2c, lattice.dk)
+
+
+@pytest.mark.parametrize("proc_shape", [(1, 1, 1)], indirect=True)
+def test_programs_scopes_spans_and_plan(setup, grid_shape, tmp_path):
+    """What a trace and the event log say of a collocator: its programs
+    are named ``spectral_<op>``, their ops lie under the three scopes
+    (inside a caller's program too), ``lap`` and ``grad`` dispatch under
+    host spans of their own, and one ``spectral_plan`` event a built
+    collocator says which transform and inverse it got."""
+    import jax
+    from pystella_tpu import obs
+    from pystella_tpu.obs.events import read_events
+    from pystella_tpu.obs.scope import has_scope
+    decomp, lattice, fft = setup
+    log = tmp_path / "events.jsonl"
+    obs.configure(str(log))
+    try:
+        sc = ps.SpectralCollocator(fft, lattice.dk)
+    finally:
+        obs.configure(None)
+    (event,) = read_events(str(log), kind="spectral_plan")
+    assert event["data"]["inverse"] == "xla"
+    assert event["data"]["scheme"] == fft.scheme
+    assert event["data"]["grid_shape"] == list(grid_shape)
+    assert event["data"]["dtype"] == "float64"
+
+    x = jax.ShapeDtypeStruct(grid_shape, np.float64)
+    vec = jax.ShapeDtypeStruct((3,) + grid_shape, np.float64)
+    for name, fn, args in (("lap", sc._lap, (x,)), ("grad", sc._grad, (x,)),
+                           ("grad_lap", sc._grad_lap, (x,)),
+                           ("pd", sc._pd, (x, 0)), ("div", sc._div, (vec,))):
+        lowered = fn.lower(*args)
+        assert "jit_spectral_" + name in lowered.as_text()[:200], name
+        for scope in ("spectral_forward", "spectral_symbol",
+                      "spectral_inverse"):
+            assert has_scope(lowered, scope), (name, scope)
+    inlined = jax.jit(lambda f: 2 * sc.lap(f)).lower(x)
+    assert has_scope(inlined, "spectral_inverse")
+
+    arr = decomp.shard(np.ones(grid_shape))
+    with obs.recording() as rows:
+        sc.lap(arr)
+        sc.grad(arr)
+    assert [r[0] for r in rows] == ["spectral_lap_dispatch",
+                                    "spectral_grad_dispatch"]
+
+
 if __name__ == "__main__":
     # spectral-derivative microbenchmark (reference test/common.py:41-56):
     #   python tests/test_spectral_collocator.py -grid 256 256 256

@@ -432,3 +432,100 @@ def test_multigrid_smooth_loop_copies_no_lattice(v5e, monkeypatch, n):
             r"operand_layout_constraints=\{(.*?)\}, \w+=", ln).group(1)
         assert re.findall(r"f32\[[\d,]*\]", operands) \
             == [f"f32[2,{n},{n},{n}]"] * 2, operands
+
+
+# -- the ``--halo-shape 0`` programs (``preheat-spectral-f32``) --------------
+
+#: the cell's lattice (``slow``, 45 s) and in tier-1 a sixteenth of it
+#: (12 s): the same programs, scopes and constants
+SPECTRAL_GRIDS = [(256, 128, 256),
+                  pytest.param((512, 512, 512), marks=pytest.mark.slow)]
+#: what ``benchmark/configs/preheat-spectral-f32.json`` quotes under
+#: ``assumed`` for 512**3, in bytes: (arguments, outputs, temporaries) of
+#: a stage program (stages 1-4, undonated) and of ``spectral_lap``
+SPECTRAL_QUOTED = {"stage": (4294968832, 4294967808, 1074419200),
+                   "lap": (1073741824, 1073741824, 3775612416)}
+
+
+def _spectral(devices, grid, monkeypatch):
+    """The example's ``--halo-shape 0`` branch on one described chip:
+    transform with the inverse by matrix products, collocator, generic
+    ``LowStorageRK54(full_rhs)``, abstract state."""
+    # a transform places its momenta with device_put, which a described
+    # device refuses: here they become constants of the programs
+    monkeypatch.setattr(
+        ps.DomainDecomposition, "axis_array",
+        lambda self, mu, values, sharded=True: np.asarray(values).reshape(
+            [-1 if i == mu else 1 for i in range(3)]))
+    decomp = ps.DomainDecomposition((1, 1, 1), devices=devices[:1])
+    lattice = ps.Lattice(grid, (5.0,) * 3, dtype=np.float32)
+    fft = ps.DFT(decomp, grid_shape=grid, dtype=np.float32,
+                 real_inverse="matmul")
+    derivs = ps.SpectralCollocator(fft, lattice.dk)
+    mphi, gsq = 1.20e-6, 2.5e-7
+
+    def potential(f):
+        return (mphi**2 / 2 * f[0]**2
+                + gsq / 2 * f[0]**2 * f[1]**2) / mphi**2
+
+    rhs = ps.compile_rhs_dict(ps.ScalarSector(2, potential=potential).rhs_dict)
+    stepper = ps.LowStorageRK54(
+        lambda state, t, a, hubble: rhs(
+            state, t, lap_f=derivs.lap(state["f"]), a=a, hubble=hubble),
+        dt=0.1 * min(lattice.dx))
+    stepper._ensure_stage_jits()
+    x = jax.ShapeDtypeStruct((2,) + grid, jnp.float32,
+                             sharding=decomp.sharding(1))
+    return decomp, fft, derivs, stepper, x
+
+
+@pytest.mark.parametrize("grid", SPECTRAL_GRIDS)
+def test_spectral_stage_and_lap_compile(v5e, monkeypatch, grid):
+    """What ``benchmark/configs/preheat-spectral-f32.json`` needs of a
+    chip: a stage program of the generic stepper whose right-hand side
+    takes ``lap f`` by transforms, not donated (as the example builds it),
+    and the collocator's own ``spectral_lap`` compile for one v5e chip
+    and fit its 15.75 GB; at the cell's lattice with the bytes the
+    configuration quotes. Each is named (``jit_LowStorageRK54_stage``,
+    ``jit_spectral_lap``: what a trace keys its rows by), carries the
+    collocator's three scopes, and holds no constant of the lattice's
+    size (``kx^2 + ky^2 + kz^2`` folded into one cost 269 MB a program
+    and minutes of compile time at 512**3: PR 34)."""
+    import re
+    _, _, derivs, stepper, x = _spectral(v5e, grid, monkeypatch)
+    state = {"f": x, "dfdt": x}
+    args = {"a": np.float64(1.0), "hubble": np.float64(0.1)}
+    programs = {
+        "stage": (stepper._jit_stage, (1, (state, state), 0.0, stepper.dt,
+                                       args), "jit_LowStorageRK54_stage"),
+        "lap": (derivs._lap, (x,), "jit_spectral_lap")}
+    for kind, (fn, fn_args, name) in programs.items():
+        compiled = fn.trace(*fn_args).lower(
+            lowering_platforms=("tpu",)).compile()
+        hlo = compiled.as_text()
+        assert hlo.startswith("HloModule " + name), hlo[:60]
+        for scope in ("spectral_forward", "spectral_symbol",
+                      "spectral_inverse"):
+            assert scope in hlo, (kind, scope)
+        largest = max(
+            int(np.prod([int(d) for d in dims.split(",")]))
+            for dims in re.findall(r"= \w+\[([\d,]+)\]\S* constant\(", hlo))
+        assert largest <= 512 * 512, (kind, largest)
+        mem = compiled.memory_analysis()
+        held = (mem.argument_size_in_bytes, mem.output_size_in_bytes,
+                mem.temp_size_in_bytes)
+        assert sum(held) < 15.75 * 2**30, (kind, held)
+        if grid == (512, 512, 512):
+            for got, quoted in zip(held, SPECTRAL_QUOTED[kind]):
+                assert abs(got - quoted) <= 0.02 * quoted, (kind, held)
+
+
+def test_spectral_collocator_refuses_xlas_inverse_for_a_tpu(v5e,
+                                                            monkeypatch):
+    """On a TPU's devices a collocator built on a real transform whose
+    inverse is XLA's is refused with the option's name; with the inverse
+    by matrix products it is built (``_spectral`` above)."""
+    decomp, fft, _, _, _ = _spectral(v5e, (32, 32, 128), monkeypatch)
+    bad = ps.DFT(decomp, grid_shape=fft.grid_shape, dtype=np.float32)
+    with pytest.raises(ValueError, match='real_inverse="matmul"'):
+        ps.SpectralCollocator(bad, (1.0, 1.0, 1.0))
