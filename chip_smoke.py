@@ -16,7 +16,9 @@ streaming tier with the blocking it took), the HDF5 series, the
 checkpoint read back, the Friedmann constraint, the chip's own
 ``peak_bytes_in_use`` — two steps of the fused stepper against the
 plain ``LowStorageRK54`` + XLA ``FiniteDifferencer`` reference from one
-seeded state (:func:`fused_parity`), the transform pair's round
+seeded state (:func:`fused_parity`), two stages of the stage loop's
+in-place programs against undonated ones, bit for bit
+(:func:`in_place_stages`), the transform pair's round
 trip, and the histogram and one spectrum of a seeded field against
 numpy's float64 binning of the same field. With four chips it repeats all of
 it on a ``(2, 2, 1)`` mesh and checks the work is spread over them.
@@ -204,6 +206,22 @@ def build_preheat_step(grid_shape, decomp):
     return generic_stepper(sector, decomp, lattice.dx, dt), dt
 
 
+def seeded_system(grid_shape, seed):
+    """A two-field float32 system and a seeded host state for the
+    comparisons below: ``(sector, lattice, dt, host)``."""
+    import pystella_tpu as ps
+
+    grid_shape = tuple(grid_shape)
+    lattice = ps.Lattice(grid_shape, (5.0,) * 3, dtype=np.float32)
+    dt = np.float32(0.1 * min(lattice.dx))
+    sector = ps.ScalarSector(
+        2, potential=lambda f: 0.5 * f[0]**2 + 0.125 * f[0]**2 * f[1]**2)
+    rng = np.random.default_rng(seed)
+    host = {k: 0.1 * rng.standard_normal((2,) + grid_shape).astype(
+        np.float32) for k in ("f", "dfdt")}
+    return sector, lattice, dt, host
+
+
 def fused_parity(grid_shape, decomp, nsteps):
     """The compiled Pallas path against the plain reference: ``nsteps``
     of :class:`~pystella_tpu.FusedScalarStepper` ``step()`` vs
@@ -213,22 +231,11 @@ def fused_parity(grid_shape, decomp, nsteps):
     512**3 the two paths' device buffers together would crowd a chip."""
     import pystella_tpu as ps
 
-    dtype = np.float32
-    grid_shape = tuple(grid_shape)
-    lattice = ps.Lattice(grid_shape, (5.0,) * 3, dtype=dtype)
-    dt = dtype(0.1 * min(lattice.dx))
-
-    def potential(f):
-        return 0.5 * f[0]**2 + 0.125 * f[0]**2 * f[1]**2
-
-    sector = ps.ScalarSector(2, potential=potential)
-    rng = np.random.default_rng(21)
-    host = {k: 0.1 * rng.standard_normal((2,) + grid_shape).astype(dtype)
-            for k in ("f", "dfdt")}
-    args = {"a": dtype(1.0), "hubble": dtype(0.1)}
+    sector, lattice, dt, host = seeded_system(grid_shape, 21)
+    args = {"a": np.float32(1.0), "hubble": np.float32(0.1)}
 
     fused = ps.FusedScalarStepper(sector, decomp, grid_shape, lattice.dx,
-                                  2, dtype=dtype, dt=dt)
+                                  2, dtype=np.float32, dt=dt)
     generic = generic_stepper(sector, decomp, lattice.dx, dt)
 
     results = []
@@ -246,6 +253,38 @@ def fused_parity(grid_shape, decomp, nsteps):
         scale = np.max(np.abs(ref[k])) or 1.0
         maxrel = max(maxrel, float(np.max(np.abs(got[k] - ref[k])) / scale))
     return maxrel
+
+
+def in_place_stages(grid_shape, decomp):
+    """The per-stage programs of a ``donate=True`` stepper, whose stage
+    kernel writes ``dfdt`` and the RK registers where it reads them,
+    against a ``donate=False`` stepper's (fresh outputs, no alias):
+    stages 0 and 1 through ``stepper(stage, ...)`` from one seeded
+    float32 state. Returns how many values of the four arrays differ;
+    none may. The order in which a block is read and its output written
+    back is Mosaic's pipeline's, so this is checked where Mosaic runs.
+    One stepper at a time, results staged on the host."""
+    import pystella_tpu as ps
+
+    sector, lattice, dt, host = seeded_system(grid_shape, 22)
+    results = []
+    for donate in (True, False):
+        stepper = ps.FusedScalarStepper(
+            sector, decomp, grid_shape, lattice.dx, 2, dtype=np.float32,
+            dt=dt, donate=donate)
+        extras = tuple(stepper._stage_st.extra_defs)
+        require(stepper._stage_st.in_place == (extras if donate else ()),
+                f"donate={donate}: stage kernel in place for "
+                f"{stepper._stage_st.in_place}")
+        carry = {k: decomp.shard(v) for k, v in host.items()}
+        for stage in (0, 1):
+            carry = stepper(stage, carry, 0.0, dt, a=np.float64(1.0),
+                            hubble=np.float64(0.1))
+        results.append([np.asarray(x) for tree in carry
+                        for _, x in sorted(tree.items())])
+        del carry
+    return sum(int((a != b).sum()) + int(not np.all(np.isfinite(a)))
+               for a, b in zip(*results))
 
 
 def run_leg(grid_shape, proc_shape, workdir):
@@ -345,6 +384,14 @@ def run_leg(grid_shape, proc_shape, workdir):
     require(maxrel <= PARITY_BOUND,
             f"{label}: parity {maxrel:.3e} over {PARITY_BOUND:g}")
 
+    # 3b. the stage loop's in-place programs against undonated ones
+    differing = in_place_stages(grid_shape, decomp)
+    say(f"{label}: stages 0 and 1 at {grid_shape}, in place against "
+        f"fresh outputs: {differing} value(s) differ")
+    require(differing == 0,
+            f"{label}: the in-place stage programs differ from the "
+            f"undonated ones in {differing} value(s)")
+
     # 4. the transform pair the seeded state and the spectra go through:
     # back is what went in, twice the same (XLA's own inverse real
     # transform is neither on this chip: PERF.md section 6, PR 28)
@@ -419,6 +466,7 @@ def run_leg(grid_shape, proc_shape, workdir):
         "constraint": done["constraint"],
         "stage_loop_constraint": done2["constraint"],
         "digest": digest, "parity_maxrel": maxrel,
+        "in_place_differing": differing,
         "blocks": {k: list(v) for k, v in blocks.items()},
         "energy_rows": rows, "checkpoints": len(saves),
         "last_chunk_ms": chunk_ms[-1],

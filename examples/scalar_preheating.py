@@ -227,10 +227,10 @@ def main(argv=None):
         raise ValueError("--carry-dtype requires --fused (the generic "
                          "steppers keep their carries in --dtype)")
     if p.fused:
-        # donate=True: the driver loop never reuses a consumed state or
-        # carry, so per-stage donation halves eager peak HBM — the
-        # difference between GW at 448^3 fitting a single chip or not
-        # (doc/performance.md "Memory")
+        # donate=True: the driver loop never reuses a consumed dfdt or
+        # carry, so the per-stage kernel writes them in place and each
+        # stage program is that kernel alone, at state + carry + one
+        # fresh f (doc/performance.md "Memory")
         fused_kw = dict(tableau=Stepper, dtype=p.dtype, dt=dt,
                         donate=True, carry_dtype=p.carry_dtype)
         if p.gravitational_waves:

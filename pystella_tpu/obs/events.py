@@ -198,7 +198,8 @@ for _name, _help in (
     # -- fused kernel tiers --------------------------------------------------
     ("block_choice", "a fused kernel build chose its blocking "
                      "(bx/by/grid/win_halo, halo: each of (x, y) "
-                     "'wrap' or, on a sharded axis, 'slab' + source: "
+                     "'wrap' or, on a sharded axis, 'slab', in_place: "
+                     "the extras it writes over + source: "
                      "'explicit' "
                      "constructor pins or the choose_blocks "
                      "'heuristic')"),

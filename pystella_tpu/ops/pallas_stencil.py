@@ -543,6 +543,20 @@ class StreamingStencil:
         per y-block. This is how fused RK stages emit energy reductions
         of their input state for free (the whole state is already in
         VMEM).
+    :arg in_place: names of extras the kernel writes over: each is
+        paired with the output of its name through
+        ``pallas_call(input_output_aliases=...)``, so a caller that owns
+        the extra's buffer (a donated argument, a temporary) gets the
+        output in it and nothing is copied. Safe for exactly these
+        arrays: an extra and its output share one ``BlockSpec``, program
+        ``(j, i)`` has block ``(i, j)`` in VMEM before it writes that
+        block back, and no other program reads it. A window is read
+        through its halo by the neighbouring programs, so a window's
+        name is refused, as is a name with no output of its name or
+        whose leading shape is not its output's (the storage dtype goes
+        by name, ``dtypes``, so it is the same on both sides). A
+        caller that does NOT own the buffer pays a copy of it: declare
+        what is donated and nothing else.
     :arg kind: what the kernel is, for traces: the call is dispatched
         under ``obs.scope.kernel_scope(kind)``
         (``pallas_stencil_<kind>``), the name a TPU trace gives its
@@ -556,7 +570,7 @@ class StreamingStencil:
                  bx=None, by=None, x_halo=False, y_halo=False,
                  interpret=None, sum_defs=None, dtypes=None,
                  win_halo=None, stages=1, kind=None, x_slab=False,
-                 y_slab=False):
+                 y_slab=False, in_place=()):
         if h > HY:
             raise ValueError(f"stencil radius {h} exceeds aligned halo {HY}")
         #: what the kernel is (``"pair"``, ``"lap"`` ...; ``None``: not
@@ -605,6 +619,24 @@ class StreamingStencil:
         #: scalars); outputs are cast to their storage dtype on write.
         self.dtypes = {k: jnp.zeros((), v).dtype
                        for k, v in dict(dtypes or {}).items()}
+        for n in in_place:
+            if n in self.win_defs:
+                raise ValueError(
+                    f"in_place {n!r} is a windowed input: its halo rows "
+                    "are read by the neighbouring programs, so it cannot "
+                    "be written where it is read")
+            if n not in self.extra_defs or n not in self.out_defs:
+                raise ValueError(
+                    f"in_place {n!r} needs an extra and an output of that "
+                    f"name (extras {list(self.extra_defs)}, outputs "
+                    f"{list(self.out_defs)})")
+            if self.extra_defs[n] != self.out_defs[n]:
+                raise ValueError(
+                    f"in_place {n!r}: the extra's leading shape "
+                    f"{self.extra_defs[n]} is not its output's "
+                    f"{self.out_defs[n]}")
+        #: the extras written in place, in ``extra_defs`` order
+        self.in_place = tuple(n for n in self.extra_defs if n in in_place)
         if bx is None or by is None:
             cbx, cby = choose_blocks(
                 sum(self.win_defs.values()), self.lattice_shape, self.h,
@@ -839,9 +871,16 @@ class StreamingStencil:
         with a ``win_rows``-row VMEM window per windowed input."""
         Z = self.lattice_shape[2]
         in_specs, out_specs, out_shapes = self._make_specs()
+        # operands: windows, slabs, scalars, then the extras
+        first_extra = (len(self.win_defs) + self._nslabs
+                       + len(self.scalar_names))
+        extras, outs = list(self.extra_defs), list(self.out_defs)
         return pl.pallas_call(
             kernel,
             grid=self.grid,
+            input_output_aliases={
+                first_extra + extras.index(n): outs.index(n)
+                for n in self.in_place},
             in_specs=in_specs,
             out_specs=out_specs,
             out_shape=out_shapes,
@@ -1036,7 +1075,7 @@ class StreamingStencil:
             y_slab=self.y_slab and not padded,
             interpret=self.interpret, sum_defs=self.sum_defs,
             dtypes=self.dtypes, win_halo=self.wh, stages=self.stages,
-            kind=self.kind)
+            kind=self.kind, in_place=self.in_place)
 
     # -- invocation --------------------------------------------------------
 

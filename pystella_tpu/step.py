@@ -147,9 +147,14 @@ class Stepper:
 
         With ``donate=True`` each stage donates its input carry (every
         stage fully replaces state and carry, and the reference-style
-        loop never reads the old one), holding the eager per-stage
-        driver's peak HBM at ~one state + one carry instead of two
-        (VERDICT r4 #7; peak-HBM table in doc/performance.md)."""
+        loop never reads the old one), so XLA may write a stage's
+        outputs over its inputs: ~one state + one carry of peak HBM
+        instead of two, where every op of the stage is XLA's own. The
+        fused steppers build theirs otherwise (``ops/fused.py``: a
+        kernel that reads ``f`` through a halo window cannot write over
+        it, so ``f`` is not donated there and comes back in a fresh
+        buffer); :meth:`_dispatch_stage` is what :meth:`__call__` runs
+        either way."""
         if not hasattr(self, "_jit_stage"):
             donate = getattr(self, "_donate", False)
             cls = type(self).__name__
@@ -161,6 +166,14 @@ class Stepper:
                     self.stage(0, self.init_carry(state), t, dt, rhs_args),
                 label=f"step.{cls}.stage0",
                 donate_argnums=(0,) if donate else ())
+
+    def _dispatch_stage(self, stage, state_or_carry, t, dt, rhs_args):
+        """One dispatch of stage ``stage``'s cached program: the state
+        in at stage 0, the carry in after it, the carry out."""
+        self._ensure_stage_jits()
+        if stage == 0:
+            return self._jit_stage0(state_or_carry, t, dt, rhs_args)
+        return self._jit_stage(stage, state_or_carry, t, dt, rhs_args)
 
     # -- whole-step interface ---------------------------------------------
 
@@ -257,14 +270,9 @@ class Stepper:
         on_device = any(isinstance(leaf, jax.Array) for leaf in
                         jax.tree_util.tree_leaves(state_or_carry))
         if on_device:
-            self._ensure_stage_jits()
             with host_span("step_dispatch"):
-                if stage == 0:
-                    carry = self._jit_stage0(state_or_carry, t, dt,
+                carry = self._dispatch_stage(stage, state_or_carry, t, dt,
                                              rhs_args)
-                else:
-                    carry = self._jit_stage(stage, state_or_carry, t, dt,
-                                            rhs_args)
         else:
             carry = (self.init_carry(state_or_carry) if stage == 0
                      else state_or_carry)

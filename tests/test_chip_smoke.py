@@ -29,6 +29,7 @@ def test_smoke_body_passes_its_own_checks(tmp_path, proc_shape,
     assert leg["steps"] == 16 and leg["checkpoints"] >= 1
     assert set(chip_smoke.MAIN_KERNELS) <= set(leg["blocks"])
     assert leg["parity_maxrel"] <= chip_smoke.PARITY_BOUND
+    assert leg["in_place_differing"] == 0
     assert len(leg["digest"]) == 16
     assert (leg["halo_bytes"] > 0) == (proc_shape != (1, 1, 1))
     # the CPU keeps no allocator statistics: the chip-only check must

@@ -606,6 +606,82 @@ def test_fused_scalar_per_stage_interface(decomp):
                            rtol=1e-13, atol=1e-13)
 
 
+@pytest.mark.parametrize("system, proc, carry_dtype", [
+    ("scalar", (1, 1, 1), None),
+    ("scalar", (1, 1, 1), jnp.bfloat16),
+    ("gw", (1, 1, 1), None),
+    ("scalar", (2, 2, 1), None),
+], ids=["scalar", "scalar-bf16-carry", "gw", "scalar-2x2x1"])
+def test_stage_loop_in_place_equals_undonated(system, proc, carry_dtype):
+    """The per-stage protocol of a ``donate=True`` stepper (its stage
+    kernel writes ``dfdt`` and the carry in place, its programs donate
+    those and not the windowed ``f`` / ``hij``) gives the bits of a
+    ``donate=False`` one over two steps, on one chip and through the
+    sharded wrapper; ``current(carry)`` between stages too. After stage 0
+    the caller's ``state["f"]`` is still readable: it was not donated;
+    its ``dfdt`` was."""
+    ndev = int(np.prod(proc))
+    if len(jax.devices()) < ndev or (ndev > 1 and _TPU_SESSION):
+        pytest.skip(f"needs {ndev} CPU devices")
+    devs = (jax.devices("cpu") if _TPU_SESSION else jax.devices())[:ndev]
+    decomp = ps.DomainDecomposition(proc, devices=devs)
+    grid_shape = (8, 16, 16)
+    h, dx, dt = 2, 0.3, np.float32(0.01)
+    rng = np.random.default_rng(37)
+    host = {"f": rng.standard_normal((2,) + grid_shape),
+            "dfdt": 0.1 * rng.standard_normal((2,) + grid_shape)}
+    sector = ps.ScalarSector(2, potential=_potential)
+    kw = dict(dtype=jnp.float32, bx=4, by=8, carry_dtype=carry_dtype,
+              **_XKW)
+    if system == "gw":
+        host["hij"] = 1e-3 * rng.standard_normal((6,) + grid_shape)
+        host["dhijdt"] = 1e-4 * rng.standard_normal((6,) + grid_shape)
+        gw = ps.TensorPerturbationSector([sector])
+
+        def build(donate):
+            return FusedPreheatStepper(sector, gw, decomp, grid_shape, dx,
+                                       h, donate=donate, **kw)
+    else:
+        def build(donate):
+            return FusedScalarStepper(sector, decomp, grid_shape, dx, h,
+                                      donate=donate, **kw)
+
+    runs = {}
+    for donate in (True, False):
+        stepper = build(donate)
+        extras = tuple(stepper._stage_st.extra_defs)
+        assert stepper._stage_st.in_place == (extras if donate else ())
+        state = {n: decomp.shard(v.astype(np.float32))
+                 for n, v in host.items()}
+        seen = []
+        for step in range(2):
+            carry = state
+            for stage in range(stepper.num_stages):
+                carry = stepper(stage, carry, 0.0, dt, a=np.float64(1.2),
+                                hubble=np.float64(0.3))
+                if stage == 0 and step == 0:
+                    # what a driver that kept its state may still read
+                    for n in stepper._stage_st.win_defs:
+                        assert np.array_equal(
+                            np.asarray(state[n]),
+                            host[n].astype(np.float32)), n
+                    # and what went to the kernel for good
+                    for n, v in state.items():
+                        assert v.is_deleted() == (
+                            donate and n not in stepper._stage_st.win_defs)
+                if stage < stepper.num_stages - 1:
+                    seen.append({n: np.asarray(v) for n, v in
+                                 stepper.current(carry).items()})
+            state = carry
+        seen.append({n: np.asarray(v) for n, v in state.items()})
+        runs[donate] = seen
+    assert len(runs[True]) == len(runs[False]) == 9
+    for got, ref in zip(runs[True], runs[False]):
+        assert set(got) == set(host)
+        for n in got:
+            assert np.array_equal(got[n], ref[n]), n
+
+
 def test_fused_preheat_matches_generic(decomp):
     grid_shape = (16, 16, 16)
     h, dx = 2, 0.3

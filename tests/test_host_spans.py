@@ -471,8 +471,8 @@ def _programs(make_decomp):
         t=0.0, dt=stepper.dt, rhs_args={"a": a, "hubble": a}, rhs_seq={})
     yield stepper._coupled_jit(2, grid_size, 1.0, False, None), (
         state,), dict(t=0.0, dt=stepper.dt, a=a, adot=a)
-    yield stepper._jit_stage0, (state, 0.0, stepper.dt,
-                                {"a": a, "hubble": a})
+    yield stepper._jit_stage0, (*stepper._split_carry((state, {})), 0.0,
+                                stepper.dt, {"a": a, "hubble": a})
     yield derivs._sharded("lap", 1), (f,)
     yield derivs._sharded("grad", 1, True), (f,)
     yield reduce_energy._run, (env, grid_size)

@@ -113,7 +113,7 @@ def _built(name):
             choices.setdefault(self.kind, []).append({
                 "kernel": self.kind, "stencil": "StreamingStencil",
                 "bx": self.bx, "by": self.by, "grid": list(self.grid),
-                "halo": list(self.halo),
+                "halo": list(self.halo), "in_place": list(self.in_place),
                 "source": "explicit" if pinned else "heuristic"})
 
     derivs = ps.FiniteDifferencer(decomp, h, lattice.dx)
@@ -243,6 +243,12 @@ def test_cells_get_the_kernels_the_ledger_measured(config, kernel, nth,
     # the stepper or operator was built on, and from nothing else
     sharded = config == "preheat-mesh4-f32"
     assert d["halo"] == (["slab", "slab"] if sharded else ["wrap", "wrap"])
+    # the per-stage protocol's kernel writes its extras in place (the
+    # families build with donate=True); no kernel of a chunk, and no
+    # operator, does (PR 37)
+    stage_extras = ["dfdt", "kf", "kdfdt"] + (
+        ["dhijdt", "khij", "kdhijdt"] if config == "preheat-gw-f32" else [])
+    assert d["in_place"] == (stage_extras if kernel == "stage" else [])
 
 
 # -- pins, refusals, events ------------------------------------------------
@@ -337,10 +343,11 @@ def test_events_carry_what_the_benchmark_prints(build, names):
     choices = seen.of("block_choice")
     assert {d["kernel"] for d in choices} == {"stage", "pair"}
     for d in choices:
-        assert {"kernel", "stencil", "bx", "by", "grid", "halo", "source",
-                "local_shape", "label"} <= set(d)
+        assert {"kernel", "stencil", "bx", "by", "grid", "halo", "in_place",
+                "source", "local_shape", "label"} <= set(d)
         assert d["source"] in ("explicit", "heuristic")
         assert d["halo"] == ["wrap", "wrap"]
+        assert d["in_place"] == []   # built without donate=True
         assert d["grid"] == [16 // d["by"], 16 // d["bx"]]
     tiers = seen.of("kernel_tier")
     assert [d["entrypoint"] for d in tiers] == ["multi_step"]

@@ -314,6 +314,102 @@ def test_streaming_one_call_blockings_agree(nby, nbx, variant):
     assert np.array_equal(sums, sums1)
 
 
+def _in_place_pair(variant, carry_dtype):
+    """A stage-shaped kernel (window ``f``; extras ``d`` in the state's
+    dtype and ``k``, one component, in the carry's; an output of each
+    name) built twice, ``d`` and ``k`` in place and not, and its call
+    arguments: wrapped, slab-fed from the lattice's own faces, or on the
+    pre-padded window (``_build_xhalo``)."""
+    X, Y, Z = shape = (8, 16, 8)
+    rng = np.random.default_rng(23)
+    f = rng.standard_normal((2,) + shape).astype(np.float32)
+    d = jnp.asarray(rng.standard_normal((2,) + shape), jnp.float32)
+    k = jnp.asarray(rng.standard_normal((1,) + shape), carry_dtype)
+
+    def body(taps, extras, scalars):
+        lap = (-6 * taps() + taps(1) + taps(-1) + taps(0, 1)
+               + taps(0, -1) + taps(0, 0, 1) + taps(0, 0, -1))
+        k2 = scalars["A"] * extras["k"] + lap[:1] - lap[1:]
+        d2 = extras["d"] + scalars["B"] * k2
+        return {"f": taps() + 0.25 * d2, "d": d2, "k": k2}
+
+    kw = {}
+    fin, slabs = f, None
+    if variant == "slab":
+        kw.update(x_slab=True, y_slab=True)
+        slabs = [{axis: tuple(x.astype(jnp.float32) for x in pair)
+                  for axis, pair in group.items()}
+                 for group in _one_call_slabs(f, "xy_slab")]
+    elif variant == "x_halo":
+        kw.update(x_halo=True)
+        fin = np.concatenate([f[:, -1:], f, f[:, :1]], axis=1)
+    built = [StreamingStencil(
+        shape, {"f": 2}, 1, body, {"f": (2,), "d": (2,), "k": (1,)},
+        extra_defs={"d": (2,), "k": (1,)}, scalar_names=("A", "B"),
+        dtype=jnp.float32, dtypes={"k": carry_dtype}, bx=2, by=8,
+        in_place=names, **kw) for names in (("d", "k"), ())]
+    call = dict(scalars={"A": 0.5, "B": 1.25}, extras={"d": d, "k": k},
+                slabs=slabs)
+    return built, jnp.asarray(fin), call
+
+
+@interpret_only
+@pytest.mark.parametrize("carry_dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16-carry"])
+@pytest.mark.parametrize("variant", ["wrap", "slab", "x_halo"])
+def test_streaming_in_place_equals_plain(variant, carry_dtype):
+    """A kernel that writes its extras in place gives the bits of the one
+    that does not, with float32 and with bfloat16 carries, wrapped,
+    slab-fed and on the pre-padded window; its ``pallas_call`` pairs each
+    named extra's operand (after the windows, the slabs and the scalars)
+    with the output of that name, and an eager call (which owns nothing)
+    leaves the caller's arrays as they were."""
+    (st, plain), fin, call = _in_place_pair(variant, carry_dtype)
+    assert st.in_place == ("d", "k") and plain.in_place == ()
+    before = {n: np.asarray(v, np.float32) for n, v in call["extras"].items()}
+    got, ref = st(fin, **call), plain(fin, **call)
+    for n in ("f", "d", "k"):
+        assert got[n].dtype == ref[n].dtype
+        assert np.array_equal(np.asarray(got[n], np.float32),
+                              np.asarray(ref[n], np.float32)), n
+    assert got["k"].dtype == carry_dtype
+    for n, v in call["extras"].items():
+        assert np.array_equal(np.asarray(v, np.float32), before[n]), n
+
+    def aliases(stencil):
+        args = [fin]
+        for group in call["slabs"] or ():
+            args += [group[a][i] for a in "xy" for i in (0, 1)]
+        args += [jnp.ones(1, jnp.float32)] * 2 + [
+            call["extras"][n] for n in ("d", "k")]
+        (eqn,) = [e for e in jax.make_jaxpr(stencil._call)(*args).eqns
+                  if e.primitive.name == "pallas_call"]
+        return dict(eqn.params["input_output_aliases"])
+
+    first = 1 + (4 if variant == "slab" else 0) + 2
+    assert aliases(st) == {first: 1, first + 1: 2}
+    assert aliases(plain) == {}
+
+
+@pytest.mark.parametrize("names, defs, match", [
+    (("f",), {}, "windowed input"),
+    (("g",), {}, "an extra and an output of that name"),
+    (("d",), {"d": (3,)}, "leading shape"),
+], ids=["a-window", "no-output-of-the-name", "another-shape"])
+def test_streaming_in_place_refusals(names, defs, match):
+    """What cannot be written in place is refused at construction: a
+    window (its halo rows are read by the neighbouring programs), a name
+    that is not both an extra and an output, an extra whose leading shape
+    is not its output's. (The storage dtype goes by name, so it cannot
+    differ: the bfloat16 carry above qualifies.)"""
+    with pytest.raises(ValueError, match=match):
+        StreamingStencil(
+            (8, 16, 8), {"f": 2}, 1, lambda t, e, s: {}, {"f": (2,),
+                                                          "d": (2,)},
+            extra_defs={"d": (2,), "g": (2,), **defs}, bx=2, by=8,
+            interpret=True, in_place=names)
+
+
 @interpret_only
 def test_streaming_sums_keep_their_order():
     """Float sums come in the order they always did: x-blocks added in

@@ -68,7 +68,7 @@ def compile_tpu(fn, *args, donate=()):
         lowering_platforms=("tpu",)).compile()
 
 
-def _preheat(devices, proc_shape, grid):
+def _preheat(devices, proc_shape, grid, donate=True):
     """The flagship model as the example builds it, abstract state."""
     ndev = int(np.prod(proc_shape))
     decomp = ps.DomainDecomposition(proc_shape, devices=devices[:ndev])
@@ -81,7 +81,7 @@ def _preheat(devices, proc_shape, grid):
     dx = tuple(5.0 / n for n in grid)
     stepper = ps.FusedScalarStepper(
         ps.ScalarSector(2, potential=potential), decomp, grid, dx, 2,
-        dtype=jnp.float32, dt=np.float32(0.1 * min(dx)), donate=True,
+        dtype=jnp.float32, dt=np.float32(0.1 * min(dx)), donate=donate,
         interpret=False)
     state = {k: jax.ShapeDtypeStruct((2,) + grid, jnp.float32,
                                      sharding=decomp.sharding(1))
@@ -140,6 +140,22 @@ def test_coupled_chunk_compiles(v5e, proc_shape, grid):
         assert padded not in operands, ln[:200]
         assert len(x_slab.findall(operands)) == 2, ln[:200]
         assert len(y_slab.findall(operands)) == 2, ln[:200]
+
+
+def _computations(hlo):
+    """Optimized HLO text by computation: name -> its instructions'
+    lines; the entry computation also under ``"ENTRY"``."""
+    import re
+    computations = {}
+    for line in hlo.splitlines():
+        head = re.match(r"(ENTRY )?%([\w.]+) \(.*\{$", line)
+        if head:
+            lines = computations[head.group(2)] = []
+            if head.group(1):
+                computations["ENTRY"] = lines
+        elif line.startswith("  "):
+            lines.append(line)
+    return computations
 
 
 def _custom_call_names(hlo):
@@ -214,6 +230,103 @@ def test_step_and_stage_loop_compile(v5e, proc_shape, grid):
         return carry
 
     compile_tpu(stage_loop, state)
+
+
+def _stage_programs(devices, proc_shape, grid, donate):
+    """``(name, compiled)`` for the two per-stage programs of the flagship
+    stepper, ``jit_FusedScalarStepper_stage0`` and ``..._stage``, with the
+    arguments ``Stepper.__call__`` hands them (the host loop's float64
+    background scalars)."""
+    stepper, state, _ = _preheat(devices, proc_shape, grid, donate=donate)
+    stepper._ensure_stage_jits()
+    tail = (0.0, stepper.dt,
+            {"a": np.float64(1.0), "hubble": np.float64(0.5)})
+    for name, fn, args in (
+            ("stage0", stepper._jit_stage0,
+             stepper._split_carry((state, {}))),
+            ("stage", stepper._jit_stage,
+             (1,) + stepper._split_carry((state, state)))):
+        compiled = fn.trace(*args, *tail).lower(
+            lowering_platforms=("tpu",)).compile()
+        assert compiled.as_text().startswith(
+            "HloModule jit_FusedScalarStepper_" + name + ",")
+        yield name, compiled
+
+
+STAGE_MESHES = [((1, 1, 1), (512, 128, 512)), MESH]
+
+
+@pytest.mark.parametrize("proc_shape,grid", STAGE_MESHES,
+                         ids=["one-chip", "mesh"])
+def test_stage_programs_are_one_kernel_in_place(v5e, proc_shape, grid):
+    """A per-stage program of a ``donate=True`` stepper, as
+    ``Stepper.__call__`` builds it, is its kernel and nothing else: no
+    lattice-shaped ``copy``; the Mosaic call carries
+    ``output_to_operand_aliasing`` for its three extras (``dfdt``, ``kf``,
+    ``kdfdt``); every donated parameter's ``input_output_alias`` entry
+    names the output the kernel writes over that very parameter (jax
+    pairs by position, so the fresh ``f`` has to come last); ``f`` is not
+    donated; and the temporaries stay under one lattice array (stage 0's
+    two zero registers are outputs). It fails on the parent of PR 37,
+    where all four arrays were donated to a kernel without aliases and
+    XLA put ``%copy.15``-``%copy.18`` (``copy(%carry_0___f__)`` ...,
+    4.29 GB at 512**3, 12.7 ms) in front of the stage kernel and
+    ``%copy.9``, ``%copy.10`` in front of stage 0's, with 4.30 GB of
+    temporaries."""
+    import re
+    local = tuple(n // p for n, p in zip(grid, proc_shape))
+    array = "f32[2,{},{},{}]".format(*local)
+    donated = {"stage0": 1, "stage": 3}
+    for name, compiled in _stage_programs(v5e, proc_shape, grid, True):
+        hlo = compiled.as_text()
+        copies = re.findall(
+            rf"%([\w.]+) = {re.escape(array)}\S* copy\(", hlo)
+        assert not copies, (name, copies)
+        entry = _computations(hlo)["ENTRY"]
+        params = {m.group(1): int(m.group(2)) for m in (
+            re.match(r"\s*%([\w.]+) = .* parameter\((\d+)\)", ln)
+            for ln in entry) if m}
+        (call,) = [ln for ln in entry if "tpu_custom_call" in ln]
+        kernel = re.match(r"\s*%(pallas_stencil_stage\.\d+) = ", call)
+        assert kernel, call[:120]
+        operands = re.findall(
+            r"%([\w.\-]+)", call.split(" custom-call(", 1)[1].split(")", 1)[0])
+        written = {int(out): operands[int(op)] for out, op in re.findall(
+            r"\{(\d+)\}: \((\d+), \{\}\)",
+            re.search(r"output_to_operand_aliasing=\{(.*?)\}, \w+=",
+                      call).group(1))}
+        assert len(written) == 3, (name, written)
+        # the kernel's window operand is a parameter nobody donated
+        pairs = {int(out): int(par) for out, par in re.findall(
+            r"\{(\d+)\}: \((\d+), \{\}, may-alias\)", hlo.split("\n", 1)[0])}
+        assert len(pairs) == donated[name], (name, pairs)
+        assert params[operands[0]] not in pairs.values(), (name, pairs)
+        elements = {m.group(1): int(m.group(2)) for m in (
+            re.match(r"\s*%([\w.\-]+) = .* get-tuple-element\(%"
+                     + re.escape(kernel.group(1)) + r"\), index=(\d+)", ln)
+            for ln in entry) if m}
+        (root,) = [ln for ln in entry if ln.lstrip().startswith("ROOT ")]
+        results = re.findall(r"%([\w.\-]+)", root.split(" tuple(", 1)[1])
+        for out, par in pairs.items():
+            over = written[elements[results[out]]]
+            assert params[over] == par, (name, out, par, over)
+        mem = compiled.memory_analysis()
+        assert mem.temp_size_in_bytes < 4 * 2 * int(np.prod(local)), (
+            name, mem.temp_size_in_bytes)
+
+
+def test_undonated_stage_programs_copy_and_alias_nothing(v5e):
+    """Built with ``donate=False`` the same two programs hold no lattice
+    ``copy`` either, and no alias: the kernel declares none (fed buffers
+    its program does not own, an in-place kernel costs a copy of each)
+    and the module pairs no parameter with an output."""
+    import re
+    proc_shape, grid = STAGE_MESHES[0]
+    for name, compiled in _stage_programs(v5e, proc_shape, grid, False):
+        hlo = compiled.as_text()
+        assert not re.findall(r"= f32\[2,[\d,]+\]\S* copy\(", hlo), name
+        assert "output_to_operand_aliasing" not in hlo, name
+        assert "input_output_alias" not in hlo.split("\n", 1)[0], name
 
 
 def _gw_chunk(devices, carry_dtype, nsteps):
@@ -409,13 +522,7 @@ def test_multigrid_smooth_loop_copies_no_lattice(v5e, monkeypatch, n):
     hlo = _mg_level_compiler(v5e, monkeypatch, n)("smooth").as_text()
     bodies = re.findall(r" while\(.*\bbody=%([\w.]+)", hlo)
     assert len(bodies) == 1, bodies
-    computations = {}  # name -> its instructions' lines
-    for line in hlo.splitlines():
-        head = re.match(r"(?:ENTRY )?%([\w.]+) \(.*\{$", line)
-        if head:
-            lines = computations[head.group(1)] = []
-        elif line.startswith("  "):
-            lines.append(line)
+    computations = _computations(hlo)
     lattice = rf"f32\[2,{n},{n},{n}\]"
     copies = {name: [ln.split(" = ")[0].strip() for ln in lines
                      if re.search(rf"= {lattice}\S* copy\(", ln)]
