@@ -17,8 +17,8 @@ Two context managers, one registry:
   ``doc/observability.md`` "Host spans"). It is a ``TraceAnnotation``,
   so the span lies on the profiler's clock under the device's idle gaps,
   and, while a recorder is installed (:func:`recording`), one row
-  ``[name, parent row, start_ns, end_ns]`` kept in memory. A span never
-  syncs.
+  ``[name, parent row, start_ns, end_ns]`` kept in memory until it is
+  drained. A span never syncs.
 
 Both cost about a microsecond when no profiler is attached and are
 platform-agnostic (the CPU test suite runs them constantly).
@@ -52,8 +52,8 @@ import threading
 import time
 
 __all__ = ["trace_scope", "host_span", "recording", "span_table",
-           "kernel_scope", "in_jax_trace", "lowered_scopes", "has_scope",
-           "register_scope", "registered_scopes"]
+           "span_paths", "kernel_scope", "in_jax_trace", "lowered_scopes",
+           "has_scope", "register_scope", "registered_scopes"]
 
 
 #: the central scope-name registry (see module docstring); seeded below
@@ -245,18 +245,36 @@ def trace_scope(name):
 
 # -- host spans ------------------------------------------------------------
 
-#: the installed recorder's state, or ``None``: rows, the stack of open
-#: rows, and the one thread whose spans are recorded (the driver's; a
-#: span on another thread is an annotation only, so no lock is needed)
+#: the installed recorder, or ``None``: the rows, the stack of open
+#: rows, the prefix its spans' annotations take, and the one thread whose
+#: spans are recorded (the driver's; a span on another thread is an
+#: annotation only, so no lock is needed)
 _RECORDER = None
 
 
-class _Recorder:
-    __slots__ = ("rows", "open", "thread")
+class _Recorder(list):
+    """The rows themselves (what :func:`recording` yields), with the
+    recorder's state beside them."""
 
-    def __init__(self):
-        self.rows, self.open = [], []
+    __slots__ = ("open", "thread", "prefix")
+
+    def __init__(self, prefix):
+        super().__init__()
+        self.open, self.prefix = [], prefix
         self.thread = threading.get_ident()
+
+    def drain(self):
+        """The rows closed so far, taken out of the recorder, which is
+        left empty: what a run that keeps the recorder on calls once a
+        block, so that the rows do not fill memory. Legal only while no
+        span is open (a parent still open would be cut from its
+        children); parent indices of what is returned point into it."""
+        if self.open:
+            raise RuntimeError(
+                f"drain() inside the open span {self[self.open[-1]][0]!r}")
+        rows = self[:]
+        del self[:]
+        return rows
 
 
 class host_span:
@@ -267,11 +285,13 @@ class host_span:
     end_ns]`` on ``time.perf_counter_ns``; ``parent`` is the index of
     the row that was open when this one started, or ``-1``. With no
     recorder the added cost over the annotation is one comparison with
-    ``None``. A span never waits for the device: put one round a call
-    that already does (``np.asarray``) to time the wait, never a
-    ``block_until_ready`` of its own. Entered under ``jit`` tracing (an
-    operator called from inside someone's program) it does nothing:
-    there is no run-time host work to name."""
+    ``None``; with one, the annotation is named with the recorder's
+    prefix in front (the row keeps the bare name). A span never waits
+    for the device: put one round a call that already does
+    (``np.asarray``) to time the wait, never a ``block_until_ready`` of
+    its own. Entered under ``jit`` tracing (an operator called from
+    inside someone's program) it does nothing: there is no run-time host
+    work to name."""
 
     __slots__ = ("_name", "_ann", "_rec")
 
@@ -282,53 +302,74 @@ class host_span:
         self._rec = self._ann = None
         if in_jax_trace():  # called inside someone's jit: no run-time span
             return self
-        self._ann = _TraceAnnotation(self._name)
-        self._ann.__enter__()
         rec = _RECORDER
-        if rec is not None and rec.thread == threading.get_ident():
-            rec.rows.append([self._name, rec.open[-1] if rec.open else -1,
-                             time.perf_counter_ns(), 0])
-            rec.open.append(len(rec.rows) - 1)
+        if rec is None:
+            self._ann = _TraceAnnotation(self._name)
+            self._ann.__enter__()
+            return self
+        self._ann = _TraceAnnotation(rec.prefix + self._name)
+        self._ann.__enter__()
+        if rec.thread == threading.get_ident():
+            rec.append([self._name, rec.open[-1] if rec.open else -1,
+                        time.perf_counter_ns(), 0])
+            rec.open.append(len(rec) - 1)
             self._rec = rec
         return self
 
     def __exit__(self, exc_type, exc, tb):
         rec = self._rec
         if rec is not None:
-            rec.rows[rec.open.pop()][3] = time.perf_counter_ns()
+            rec[rec.open.pop()][3] = time.perf_counter_ns()
         if self._ann is not None:
             self._ann.__exit__(exc_type, exc, tb)
         return False
 
 
 @contextlib.contextmanager
-def recording():
+def recording(annotation_prefix=""):
     """Install the host-span recorder for the block and yield its rows
-    (a list that fills as spans close; read it after the block, or hand
-    it to :func:`span_table`). One recorder at a time: a nested
-    ``recording()`` raises. Only the installing thread's spans are
-    recorded."""
+    (a list that fills as spans close; read it after the block, hand it
+    to :func:`span_table`, or, in a run that keeps the recorder on,
+    empty it once a block with its ``drain()``). While it is installed
+    every ``host_span``'s annotation is named ``annotation_prefix +
+    name``, so that whoever reads the profiler's trace can tell the
+    program's annotations by the prefix; the rows keep the bare names.
+    One recorder at a time: a nested ``recording()`` raises. Only the
+    installing thread's spans are recorded."""
     global _RECORDER
     if _RECORDER is not None:
         raise RuntimeError("a host-span recorder is already installed")
-    rec = _RECORDER = _Recorder()
+    rec = _RECORDER = _Recorder(annotation_prefix)
     try:
-        yield rec.rows
+        yield rec
     finally:
         _RECORDER = None
 
 
+def span_paths(rows):
+    """Each row's name qualified by its ancestors' (``reduce_fetch``
+    inside ``statistics`` is ``statistics/reduce_fetch``; a top-level
+    row keeps its bare name), in the rows' order: the energy's fetch and
+    the statistics' are two names."""
+    paths = []
+    for name, parent, _, _ in rows:
+        paths.append(name if parent < 0 else f"{paths[parent]}/{name}")
+    return paths
+
+
 def span_table(rows, steps=None):
     """Fold recorder rows into ``{"spans": {name: {"count", "total_ms",
-    "self_ms"[, "ms_per_step"]}}, "fetches": n[, "host_syncs_per_step"]}``.
-    A span's self time is its duration minus its children's; a fetch is
-    a row whose name ends in ``_fetch`` (each is one host sync of the
-    program's own). Rows still open (``end_ns == 0``) are left out."""
+    "self_ms"[, "ms_per_step"]}}, "fetches": n, "dispatches": m[,
+    "host_syncs_per_step", "dispatches_per_step"]}``. A span's self time
+    is its duration minus its children's; a fetch is a row whose name
+    ends in ``_fetch`` (each is one host sync of the program's own), a
+    dispatch one whose name ends in ``_dispatch`` (each enqueues one
+    call's programs). Rows still open (``end_ns == 0``) are left out."""
     child_ns = [0] * len(rows)
     for name, parent, t0, t1 in rows:
         if t1 and parent >= 0:
             child_ns[parent] += t1 - t0
-    spans, fetches = {}, 0
+    spans, fetches, dispatches = {}, 0, 0
     for i, (name, parent, t0, t1) in enumerate(rows):
         if not t1:
             continue
@@ -337,16 +378,18 @@ def span_table(rows, steps=None):
         acc[1] += t1 - t0
         acc[2] += t1 - t0 - child_ns[i]
         fetches += name.endswith("_fetch")
+        dispatches += name.endswith("_dispatch")
     table = {}
     for name, (count, total, own) in sorted(spans.items()):
         table[name] = {"count": count, "total_ms": total / 1e6,
                        "self_ms": own / 1e6}
         if steps:
             table[name]["ms_per_step"] = total / 1e6 / steps
-    out = {"spans": table, "fetches": fetches}
+    out = {"spans": table, "fetches": fetches, "dispatches": dispatches}
     if steps:
         out["steps"] = int(steps)
         out["host_syncs_per_step"] = fetches / steps
+        out["dispatches_per_step"] = dispatches / steps
     return out
 
 

@@ -9,6 +9,13 @@ every dispatch and every fetch of the main path, recorded only while
 half of the kernel names is in ``tests/test_tpu_compile.py``.)
 """
 
+import inspect
+import json
+import os
+import sys
+import time
+import types
+
 import numpy as np
 import pytest
 
@@ -21,6 +28,13 @@ import pystella_tpu as ps
 from pystella_tpu import obs
 from pystella_tpu.obs import events, scope
 from pystella_tpu.obs.memory import InstrumentedJit, program_name
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+if REPO not in sys.path:
+    sys.path.insert(0, REPO)
+
+from benchmark import drivers, readers, trace_reduce  # noqa: E402
+from benchmark.spans import PREFIX, Spans  # noqa: E402
 
 
 def _potential(f):
@@ -281,6 +295,296 @@ def test_gw_output_spans_and_names(make_decomp):
         state, t=0.0, dt=0.01, a=a, adot=a)
     assert _module_name(lowered) == "jit_coupled_multi_step_4"
     assert scope.has_scope(lowered, "pallas_stencil_energy")
+
+
+# -- a recorder left on: prefix, drain, paths --------------------------------
+
+@pytest.fixture
+def annotations(monkeypatch):
+    """The names ``host_span`` hands to ``TraceAnnotation``, through a
+    stub in its place."""
+    seen = []
+
+    class Stub:
+        def __init__(self, name):
+            seen.append(name)
+
+        def __enter__(self):
+            return self
+
+        def __exit__(self, *exc):
+            return False
+
+    scope.in_jax_trace()       # resolve jax's own first, then replace it
+    monkeypatch.setattr(scope, "_TraceAnnotation", Stub)
+    return seen
+
+
+@pytest.mark.parametrize("prefix", ["", PREFIX])
+def test_annotation_takes_the_recorders_prefix_and_the_row_does_not(
+        annotations, prefix):
+    with obs.recording(annotation_prefix=prefix) as rows:
+        with obs.host_span("statistics"):
+            with obs.host_span("reduce_fetch"):
+                pass
+    assert annotations == [prefix + "statistics", prefix + "reduce_fetch"]
+    assert [r[:2] for r in rows] == [["statistics", -1],
+                                     ["reduce_fetch", 0]]
+
+
+def test_no_recorder_means_a_bare_annotation_and_no_row(annotations):
+    assert scope._RECORDER is None
+    with obs.host_span("reduce_fetch"):
+        pass
+    assert annotations == ["reduce_fetch"]
+    with obs.recording(annotation_prefix=PREFIX) as rows:
+        pass
+    with obs.host_span("lap_dispatch"):     # the prefix left with it
+        pass
+    assert annotations == ["reduce_fetch", "lap_dispatch"] and rows == []
+
+
+def test_drain_hands_closed_rows_over_once_and_refuses_an_open_span():
+    with obs.recording() as rows:
+        with obs.host_span("statistics"):
+            with obs.host_span("reduce_dispatch"):
+                pass
+            with pytest.raises(RuntimeError, match="open span 'statistics'"):
+                rows.drain()
+        with obs.host_span("output_write"):
+            pass
+        first = rows.drain()
+        assert rows == [] and rows.drain() == []
+        with obs.host_span("statistics"):
+            with obs.host_span("reduce_fetch"):
+                pass
+        second = rows.drain()
+    assert [r[:2] for r in first] == [
+        ["statistics", -1], ["reduce_dispatch", 0], ["output_write", -1]]
+    # parents index into what was returned, not into all that was recorded
+    assert [r[:2] for r in second] == [["statistics", -1],
+                                       ["reduce_fetch", 0]]
+    assert all(t1 >= t0 > 0 for _, _, t0, t1 in first + second)
+    assert rows == []
+
+
+def _tree_rows(ms=1_000_000):
+    """A step's statistics beside the energy's own fetch: [name, parent,
+    start_ns, end_ns]."""
+    return [["reduce_dispatch", -1, 1 * ms, 2 * ms],
+            ["reduce_fetch", -1, 2 * ms, 42 * ms],
+            ["statistics", -1, 50 * ms, 62 * ms],
+            ["reduce_dispatch", 2, 50 * ms, 51 * ms],
+            ["reduce_fetch", 2, 51 * ms, 61 * ms],
+            ["step_dispatch", -1, 70 * ms, 73 * ms]]
+
+
+def test_span_paths_tell_the_statistics_fetch_from_the_energys():
+    assert scope.span_paths(_tree_rows()) == [
+        "reduce_dispatch", "reduce_fetch", "statistics",
+        "statistics/reduce_dispatch", "statistics/reduce_fetch",
+        "step_dispatch"]
+    deep = [["histogram", -1, 1, 9], ["histogram_fetch", 0, 2, 3],
+            ["output_write", 1, 2, 3]]
+    assert scope.span_paths(deep)[-1] == \
+        "histogram/histogram_fetch/output_write"
+    assert scope.span_paths([]) == []
+
+
+def test_span_table_counts_dispatches_beside_fetches():
+    table = scope.span_table(_tree_rows(), steps=2)
+    assert (table["fetches"], table["dispatches"]) == (2, 3)
+    assert table["host_syncs_per_step"] == pytest.approx(1.0)
+    assert table["dispatches_per_step"] == pytest.approx(1.5)
+    assert table["spans"]["statistics"]["self_ms"] == pytest.approx(1.0)
+    bare = scope.span_table(_tree_rows())
+    assert bare["dispatches"] == 3 and "dispatches_per_step" not in bare
+
+
+# -- the loop body that hands the recorder's rows to the benchmark ---------
+
+RECORDED_CELL = "preheat-512-f32.stage-loop-recorded"
+RECORDED_METRICS = (
+    "step_dispatch_ms_per_step", "expansion_step_ms_per_step",
+    "lap_dispatch_ms_per_step", "reduce_dispatch_ms_per_step",
+    "reduce_fetch_ms_per_step", "statistics_ms_per_step",
+    "output_write_ms_per_step", "sentinel_ms_per_step")
+
+
+def _bench_json(*parts):
+    with open(os.path.join(REPO, *parts)) as f:
+        return json.load(f)
+
+
+def _recorded_driver(spans, block_steps=2):
+    """``stage_loop_recorded.Driver`` on a system that is nothing but the
+    package (no lattice is built: the loop body is stubbed where a test
+    drives it)."""
+    traffic = {"block_steps": block_steps, "chunk_steps": 1,
+               "check_steps": 1}
+    return drivers.load("stage_loop_recorded")(
+        types.SimpleNamespace(ps=ps), traffic, spans)
+
+
+class _FakeRecorder:
+    def __init__(self, rows):
+        self.rows = rows
+
+    def drain(self):
+        rows, self.rows = self.rows, []
+        return rows
+
+
+def _adopted_context(capsys):
+    """One block's worth of hand-made recorder rows adopted into a real
+    ``Spans``, and the context ``benchmark.readers`` reads."""
+    ms = 1_000_000
+    block = _tree_rows() + [
+        ["expansion_step", -1, 80 * ms, 80.5 * ms],
+        ["lap_dispatch", -1, 81 * ms, 83 * ms],
+        ["output_write", -1, 84 * ms, 87 * ms],
+        ["sentinel_observe", -1, 88 * ms, 89 * ms],
+        ["sentinel_poll", -1, 89 * ms, 93 * ms]]
+    spans = Spans(sync=False)
+    driver = _recorded_driver(spans)
+    try:
+        driver._recorder = _FakeRecorder(
+            [["step_dispatch", -1, 1 * ms, 5 * ms]])
+        spans.unit = ("warmup", -1)
+        driver.adopt()
+        driver._recorder = _FakeRecorder(block)
+        spans.unit = ("block", 7)
+        driver.adopt()
+    finally:
+        driver.monitor = None
+        driver.finish()
+    line = [ln for ln in capsys.readouterr().out.splitlines()
+            if ln.startswith("program spans: ")]
+    ctx = {"spans": spans, "units": [("block", 7, 0.0, 0.1, True)],
+           "block_steps": 2, "traced": {}, "counters": {},
+           "rehearse": True}
+    return spans, ctx, json.loads(line[0][len("program spans: "):])
+
+
+def test_recorded_driver_adopts_rows_by_path_unit_and_clock(capsys):
+    spans, ctx, table = _adopted_context(capsys)
+    assert scope._RECORDER is None          # finish() left the recorder
+    assert spans.rows[0] == ("step_dispatch", "warmup", -1, 0.001, 0.005)
+    block = spans.rows[1:]
+    assert [r[0] for r in block] == [
+        "reduce_dispatch", "reduce_fetch", "statistics",
+        "statistics/reduce_dispatch", "statistics/reduce_fetch",
+        "step_dispatch", "expansion_step", "lap_dispatch", "output_write",
+        "sentinel_observe", "sentinel_poll"]
+    assert {r[1:3] for r in block} == {("block", 7)}
+    assert block[1][3:] == pytest.approx((0.002, 0.042))
+    # the table is of the window's block rows alone, per step
+    assert table["steps"] == 2 and "step_dispatch" in table["spans"]
+    assert table["spans"]["step_dispatch"]["count"] == 1
+    assert table["host_syncs_per_step"] == pytest.approx(1.0)
+    assert table["dispatches_per_step"] == pytest.approx(2.0)
+    assert table["spans"]["statistics"]["self_ms"] == pytest.approx(1.0)
+
+
+@pytest.mark.parametrize("metric, ms_per_step", zip(
+    RECORDED_METRICS, (1.5, 0.25, 1.0, 0.5, 20.0, 6.0, 1.5, 2.5)))
+def test_recorded_metric_files_read_the_adopted_rows(capsys, metric,
+                                                     ms_per_step):
+    """Each of the eight metric files, through the harness's own reader,
+    gives the hand-computed milliseconds per step of ITS spans: the
+    top-level ``reduce_*`` are the energy's, not the statistics'."""
+    _, ctx, _ = _adopted_context(capsys)
+    spec = _bench_json("benchmark", "metrics", metric + ".json")
+    assert readers.read(spec, ctx) == pytest.approx(ms_per_step)
+    entry = [m for m in _bench_json("BENCHMARK.json")["per_layer"]
+             if m["name"] == metric]
+    assert entry and entry[0]["workloads"] == [RECORDED_CELL]
+    assert entry[0]["source"] == "program_span"
+
+
+def test_recorded_driver_rows_lie_on_the_harness_clock():
+    """The real recorder under the real loop skeleton: ``block()`` drives
+    ``one_step`` / ``after_advance`` (stubbed: spans only), then hands
+    the rows over; they lie inside the unit on ``time.perf_counter``."""
+    spans = Spans(sync=False)
+    driver = _recorded_driver(spans, block_steps=3)
+
+    def one_step():
+        with obs.host_span("step_dispatch"):
+            pass
+        with obs.host_span("reduce_fetch"):
+            pass
+        driver.step_count += 1
+
+    def after_advance():
+        with obs.host_span("statistics"):
+            with obs.host_span("reduce_fetch"):
+                pass
+
+    driver.one_step, driver.after_advance = one_step, after_advance
+    try:
+        spans.unit = ("block", 0)
+        t0 = time.perf_counter()
+        driver.block()
+        t1 = time.perf_counter()
+        with pytest.raises(RuntimeError, match="already installed"):
+            with obs.recording():
+                pass
+    finally:
+        driver.finish()
+    assert scope._RECORDER is None
+    assert [r[0] for r in spans.rows] == [
+        "step_dispatch", "reduce_fetch", "statistics",
+        "statistics/reduce_fetch"] * 3
+    assert all(t0 <= a <= b <= t1 for *_, a, b in spans.rows)
+    assert spans.count(["reduce_fetch"], "block") == 3
+
+
+def test_recorded_metric_spans_are_registered_and_no_reducer_name():
+    """A span a metric file names is one the program can emit, and none
+    is a name ``benchmark/trace_reduce.py`` looks up among the host
+    annotations (the program's arrive there under the same prefix)."""
+    looked_up = {"step_call", "unit:block", "unit:output"}
+    source = inspect.getsource(trace_reduce.reduce)
+    for name in looked_up:
+        assert f'"{name}"' in source
+    seen = set()
+    for metric in RECORDED_METRICS:
+        spec = _bench_json("benchmark", "metrics", metric + ".json")
+        assert spec["reducer"] == "span_ms_per_step"
+        seen.update(spec["args"]["spans"])
+    assert len(seen) == 9
+    assert seen <= scope.registered_scopes()
+    assert not seen & looked_up
+    assert not any(n.startswith("unit:") or n in looked_up
+                   for n in scope.registered_scopes())
+    # nor one of the harness's own span names, which the same rows hold
+    assert not seen & {"feedback", "stats", "health", "output_other",
+                       "spectra", "gw_spectra", "spectral_lap"}
+
+
+def test_recorded_traffic_is_stage_loops_but_for_the_driver():
+    mine = _bench_json("benchmark", "traffic", "stage-loop-recorded.json")
+    theirs = _bench_json("benchmark", "traffic", "stage-loop.json")
+    assert mine.pop("driver") == "stage_loop_recorded"
+    assert theirs.pop("driver") == "stage_loop"
+    assert mine.pop("what") != theirs.pop("what")
+    assert mine == theirs
+    bench = _bench_json("BENCHMARK.json")
+    cell = [w for w in bench["workloads"] if w["name"] == RECORDED_CELL]
+    twin = [w for w in bench["workloads"]
+            if w["name"] == "preheat-512-f32.stage-loop"]
+    assert cell[0]["config"] == twin[0]["config"]
+    assert cell[0]["chips"] == twin[0]["chips"] == 1
+    assert cell[0]["traffic"] == "stage-loop-recorded"
+    # every metric that lists stage-loop lists its twin, so one traced run
+    # prints the outside and the inside reading of a layer side by side
+    for m in bench["per_layer"]:
+        if twin[0]["name"] in m.get("workloads", ()):
+            assert RECORDED_CELL in m["workloads"], m["name"]
+    limits = _bench_json("benchmark", "limits", RECORDED_CELL + ".json")
+    assert limits["limits"] == _bench_json(
+        "benchmark", "limits", twin[0]["name"] + ".json")["limits"]
 
 
 # -- capture ---------------------------------------------------------------
