@@ -199,7 +199,8 @@ for _name, _help in (
     ("block_choice", "a fused kernel build chose its blocking "
                      "(bx/by/grid/win_halo, halo: each of (x, y) "
                      "'wrap' or, on a sharded axis, 'slab', in_place: "
-                     "the extras it writes over + source: "
+                     "the extras it writes over, reread: modelled "
+                     "bytes moved over ideal bytes at that by + source: "
                      "'explicit' "
                      "constructor pins or the choose_blocks "
                      "'heuristic')"),

@@ -249,12 +249,16 @@ class FusedScalarStepper(_step.Stepper):
         (``choose_blocks``); ``halo`` is where its (x, y) edges come
         from, ``"wrap"`` or (a sharded axis) ``"slab"``; ``in_place``
         names the extras it writes over (the per-stage protocol's
-        ``stage`` kernel under ``donate=True``, else none)."""
+        ``stage`` kernel under ``donate=True``, else none); ``reread``
+        is the modelled real-over-ideal byte ratio of a call at that
+        ``by`` (``pallas_stencil.reread``; a resident kernel has
+        none)."""
         _events.emit(
             "block_choice", kernel=kind,
             stencil=type(st).__name__,
             bx=getattr(st, "bx", None), by=getattr(st, "by", None),
             grid=getattr(st, "grid", None),
+            reread=getattr(st, "reread", None),
             win_halo=getattr(st, "wh", None),
             stages=getattr(st, "stages", 1),
             halo=list(getattr(st, "halo", ("wrap", "wrap"))),

@@ -65,9 +65,9 @@ from pystella_tpu.obs.scope import (
 
 __all__ = ["StreamingStencil", "ResidentStencil", "OverlapStreamingStencil",
            "Taps", "HY", "LANE",
-           "choose_blocks", "feasible_blocks", "sharded_halo",
+           "choose_blocks", "feasible_blocks", "reread", "sharded_halo",
            "lap_from_taps", "grad_from_taps", "VMEM_LIMIT_BYTES",
-           "BLOCK_BUDGET_BYTES"]
+           "BLOCK_BUDGET_BYTES", "TIER_BUDGET_BYTES"]
 
 #: aligned y-halo width (one sublane tile); must be >= the stencil radius
 HY = 8
@@ -92,12 +92,30 @@ _RING = 4  # x-block ring slots: 3 live + 1 in flight
 #: headroom for Mosaic's own scratch.
 VMEM_LIMIT_BYTES = 100 * 2**20
 
-#: VMEM budget :func:`choose_blocks` fits a streaming kernel's window
-#: ring, pipelined extras/outputs and compute temporaries into. It
-#: decides blockings and tiers (at 384^3 it is what sends the ``-gws``
-#: run to the single-stage ``energy`` kernel), so a change here is a
-#: change to every cell: time it on the chip first.
-BLOCK_BUDGET_BYTES = 24 * 2**20
+#: VMEM figure :func:`choose_blocks` fits a streaming kernel's window
+#: ring, pipelined extras/outputs and compute temporaries into when it
+#: picks the *blocking* of a kernel that exists: the limit the kernels
+#: compile under. The model is the conservative side of that: for the
+#: cells' kernels Mosaic accepts a limit of 0.67-0.76 of what the model
+#: counts (bisected off the chip, PR 39: `stage` (2, 256) 42 MB for a
+#: modelled 55.6, the ``-gws`` `energy` (2, 128) 65 for 85.9), so what
+#: fits here compiles. A larger y block is fewer bytes: every windowed
+#: array is DMA'd ``(by + 2 * HY) / by`` times (:func:`reread`), and
+#: the kernels are HBM-bound. The 24 MB this replaced dated from the
+#: 16 MB default limit. A change here is a change to every cell: time
+#: it on the chip first (``tests/test_kernel_choice.py`` holds what the
+#: cells build).
+BLOCK_BUDGET_BYTES = VMEM_LIMIT_BYTES
+
+#: VMEM figure that decides whether a kernel kind *exists* (a blocking
+#: of it fits at all): the budget the tiers were measured under. At
+#: 384^3 it is what sends the ``-gws`` run to the single-stage
+#: ``energy`` kernel: the deferred pair's 32 window components fit no
+#: blocking in 24 MB, and from 32 MB up they would at ``by = 8``. It
+#: goes, and :data:`BLOCK_BUDGET_BYTES` decides both, with the PR that
+#: lets that pair exist (ROADMAP speed item 3): the cell's
+#: ``energy_roofline`` has to read whichever kind runs first.
+TIER_BUDGET_BYTES = 24 * 2**20
 
 
 def _compiler_params(interpret):
@@ -142,18 +160,25 @@ def choose_blocks(n_comp, lattice_shape, h, itemsize, n_extra, n_out,
     double-buffered extra inputs / outputs, and ~3 window-sized compute
     temporaries per fused stage.
 
-    Preference (measured on v5e, 512^3/128^3 fused RK54 sweeps): the
-    largest feasible ``by`` (fewer y-blocks to prime the ring for, wider DMA
-    rows), then the *smallest* feasible ``bx >= h`` — small x-blocks keep
-    the ring slots cheap and pipeline best ((2,128) beat every bx>=4
-    blocking at 128^3; (2,64) beat (2,32) at 512^3). The default budget
-    (:data:`BLOCK_BUDGET_BYTES`, 24 MB) was calibrated when the kernels
-    ran under XLA's default 16 MB scoped-VMEM limit; the kernels now
-    request :data:`VMEM_LIMIT_BYTES` (100 MB), so larger budgets are
-    *compilable* — the measured preference for small blocks keeps the
-    conservative default until a timing on the chip (blockings pinned
-    through a stepper's ``bx``/``by``, ``pair_*``, ``chunk_*``
-    arguments) says otherwise; its result is an edit here.
+    Preference: the largest feasible ``by``, then the *smallest*
+    feasible ``bx >= h``. The y block decides how much a kernel reads
+    twice: a windowed array is DMA'd ``(by + 2 * HY) / by`` times
+    (:func:`reread`), and the kernels are HBM-bound, so the default
+    budget (:data:`BLOCK_BUDGET_BYTES`) is sized for the
+    :data:`VMEM_LIMIT_BYTES` the kernels compile under. Timed on a v5e
+    (PR 39, the cells' loops): no kernel kind loses at a larger y
+    block; a 512^3 coupled step takes 46.9 / 41.2 / 39.3 ms at ``by`` =
+    32 / 64 / 128, a pair step 38.5 / 35.7 / 34.5, the ``-gws`` step at
+    384^3 125.4 / 116.2 / 114.2 / 113.9 at 16 / 32 / 64 / 128, ``lap``
+    4.06 / 3.60 at 128 / 256. Small x-blocks keep the ring slots cheap
+    and pipeline best ((2,128) beat every bx>=4 blocking at 128^3):
+    that is about ``bx``, not ``by``.
+
+    Without a ``budget`` two figures are asked: whether the kernel
+    exists at all under :data:`TIER_BUDGET_BYTES` (a ``ValueError``
+    where it does not, and the caller takes its next tier), then its
+    blocking under :data:`BLOCK_BUDGET_BYTES`. An explicit ``budget``
+    decides both.
 
     ``win_halo`` is the assembled window's halo width (defaults to the
     stencil radius ``h``); temporal-blocking chunk kernels pass
@@ -161,8 +186,7 @@ def choose_blocks(n_comp, lattice_shape, h, itemsize, n_extra, n_out,
     one radius further into the window — together with ``stages``, which
     scales the compute-temporary share of the model (composed stages
     keep ~3 extra window-sized live values each)."""
-    if budget is None:
-        budget = BLOCK_BUDGET_BYTES
+    tier_budget = TIER_BUDGET_BYTES if budget is None else budget
     wh = h if win_halo is None else int(win_halo)
     if wh < h:
         raise ValueError(f"win_halo {wh} below stencil radius {h}")
@@ -172,11 +196,15 @@ def choose_blocks(n_comp, lattice_shape, h, itemsize, n_extra, n_out,
             "feasible streaming blocking (shrink the chunk depth or "
             "use the pair/single-stage kernels)")
     X, Y, Z = lattice_shape
-    feasible = feasible_blocks(n_comp, lattice_shape, h, itemsize,
-                               n_extra, n_out, budget=budget,
+    model = (n_comp, lattice_shape, h, itemsize, n_extra, n_out)
+    feasible = feasible_blocks(*model, budget=tier_budget,
                                win_halo=win_halo, stages=stages)
-    best = feasible[0] if feasible else None
-    if best is None:
+    if feasible and budget is None:
+        # the kernel exists: block it for the limit it compiles under
+        feasible = feasible_blocks(
+            *model, budget=max(BLOCK_BUDGET_BYTES, tier_budget),
+            win_halo=win_halo, stages=stages)
+    if not feasible:
         if Y % 8:
             # the streaming kernel's y-block math assumes by >= the 8-aligned
             # halo width, so lattices whose Y is not a multiple of 8 have no
@@ -193,15 +221,17 @@ def choose_blocks(n_comp, lattice_shape, h, itemsize, n_extra, n_out,
         # kernel at 512^3 — callers degrade to single-stage kernels)
         raise ValueError(
             f"no (bx, by) blocking of lattice {lattice_shape} with "
-            f"{n_comp} window components fits the {budget / 2**20:.0f} MB "
+            f"{n_comp} window components fits the "
+            f"{tier_budget / 2**20:.0f} MB "
             "VMEM budget; split the kernel (fewer window components) or "
             "use the halo-exchange / generic path")
-    return best
+    return feasible[0]
 
 
 def feasible_blocks(n_comp, lattice_shape, h, itemsize, n_extra, n_out,
                     budget=None, win_halo=None, stages=1):
-    """Every ``(bx, by)`` the :func:`choose_blocks` VMEM model admits,
+    """Every ``(bx, by)`` the :func:`choose_blocks` VMEM model admits
+    under ``budget`` (default :data:`BLOCK_BUDGET_BYTES`),
     heuristic-preferred order first: what a blocking experiment picks
     its pins from."""
     if budget is None:
@@ -225,6 +255,19 @@ def feasible_blocks(n_comp, lattice_shape, h, itemsize, n_extra, n_out,
             if win + temps + io <= budget:
                 out.append((bx, by))
     return out
+
+
+def reread(n_comp, n_extra, n_out, by):
+    """Modelled bytes a streaming kernel call really moves over the
+    ideal count (every lattice input once, every output once), in
+    passes over one lattice array: each of the ``n_comp`` windowed
+    components is DMA'd with its ``HY``-row y halos, ``(by + 2 * HY) /
+    by`` times (the x ring reads every row once), the extras and
+    outputs once. What a ``*_roofline`` of ideal bytes has to be
+    multiplied by to say which share of the HBM peak the kernel reaches
+    in the bytes it moves."""
+    ideal = n_comp + n_extra + n_out
+    return (n_comp * (by + 2 * HY) / by + n_extra + n_out) / ideal
 
 
 class Taps:
@@ -637,15 +680,16 @@ class StreamingStencil:
                     f"{self.out_defs[n]}")
         #: the extras written in place, in ``extra_defs`` order
         self.in_place = tuple(n for n in self.extra_defs if n in in_place)
+        #: lattice-sized arrays a call moves: windowed components, extra
+        #: inputs, outputs (what the VMEM model and ``reread`` count)
+        self.n_arrays = (sum(self.win_defs.values()),) + tuple(
+            sum(int(np.prod(s)) if s else 1 for s in defs.values())
+            for defs in (self.extra_defs, self.out_defs))
         if bx is None or by is None:
+            n_comp, n_extra, n_out = self.n_arrays
             cbx, cby = choose_blocks(
-                sum(self.win_defs.values()), self.lattice_shape, self.h,
-                self.dtype.itemsize,
-                sum(int(np.prod(s)) if s else 1
-                    for s in self.extra_defs.values()),
-                sum(int(np.prod(s)) if s else 1
-                    for s in self.out_defs.values()),
-                win_halo=self.wh, stages=self.stages)
+                n_comp, self.lattice_shape, self.h, self.dtype.itemsize,
+                n_extra, n_out, win_halo=self.wh, stages=self.stages)
             bx = bx if bx is not None else cbx
             by = by if by is not None else cby
         if X % bx or Y % by:
@@ -804,6 +848,12 @@ class StreamingStencil:
             "slab" if slab else "padded" if padded else "wrap"
             for slab, padded in ((self.x_slab, self.x_halo),
                                  (self.y_slab, self.y_halo)))
+
+    @property
+    def reread(self):
+        """The call's modelled real-over-ideal bytes (:func:`reread`)
+        at the kernel's own y block."""
+        return reread(*self.n_arrays, self.by)
 
     def _unpack_refs(self, refs):
         """``(f_refs, slab_refs, scalar_refs, extra_refs, out_refs, wins,

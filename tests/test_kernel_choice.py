@@ -160,28 +160,28 @@ def _built_multigrid(cfg):
 #: and the source the tier where that is not ``streaming``. `energy` is "explicit" because the stepper hands it the
 #: stage kernel's blocking; ``None`` for a kernel that fits no blocking.
 _CELL_KERNELS = [
-    ("preheat-512-f32", "stage", 0, (2, 64), (8, 256), "heuristic"),
-    ("preheat-512-f32", "pair", 0, (2, 32), (16, 256), "heuristic"),
+    ("preheat-512-f32", "stage", 0, (2, 256), (2, 256), "heuristic"),
+    ("preheat-512-f32", "pair", 0, (2, 128), (4, 256), "heuristic"),
     # the chunk's first pair (state in) and the rest (deferred drag in)
-    ("preheat-512-f32", "coupled_pair", 0, (2, 32), (16, 256),
+    ("preheat-512-f32", "coupled_pair", 0, (2, 128), (4, 256),
      "heuristic"),
-    ("preheat-512-f32", "coupled_pair", 1, (2, 32), (16, 256),
+    ("preheat-512-f32", "coupled_pair", 1, (2, 128), (4, 256),
      "heuristic"),
-    ("preheat-512-f32", "energy", 0, (2, 64), (8, 256), "explicit"),
-    ("preheat-512-f32", "lap", 0, (2, 128), (4, 256), "heuristic"),
-    ("preheat-512-f32", "grad", 0, (2, 128), (4, 256), "heuristic"),
+    ("preheat-512-f32", "energy", 0, (2, 256), (2, 256), "explicit"),
+    ("preheat-512-f32", "lap", 0, (2, 256), (2, 256), "heuristic"),
+    ("preheat-512-f32", "grad", 0, (2, 256), (2, 256), "heuristic"),
     # 512^3 per chip on (2, 2, 1): the same kernels and blocks, their
     # edges from slabs (`halo`, below) where one chip wraps
-    ("preheat-mesh4-f32", "stage", 0, (2, 64), (8, 256), "heuristic"),
-    ("preheat-mesh4-f32", "coupled_pair", 0, (2, 32), (16, 256),
+    ("preheat-mesh4-f32", "stage", 0, (2, 256), (2, 256), "heuristic"),
+    ("preheat-mesh4-f32", "coupled_pair", 0, (2, 128), (4, 256),
      "heuristic"),
-    ("preheat-mesh4-f32", "coupled_pair", 1, (2, 32), (16, 256),
+    ("preheat-mesh4-f32", "coupled_pair", 1, (2, 128), (4, 256),
      "heuristic"),
-    ("preheat-mesh4-f32", "lap", 0, (2, 128), (4, 256), "heuristic"),
-    # -gws at 384^3: 32 components a stage
-    ("preheat-gw-f32", "stage", 0, (2, 16), (24, 192), "heuristic"),
-    ("preheat-gw-f32", "energy", 0, (2, 16), (24, 192), "explicit"),
-    ("preheat-gw-f32", "pair", 0, (2, 8), (48, 192), "heuristic"),
+    ("preheat-mesh4-f32", "lap", 0, (2, 256), (2, 256), "heuristic"),
+    # -gws at 384^3: 32 components a stage (no 256 divides 384)
+    ("preheat-gw-f32", "stage", 0, (2, 128), (3, 192), "heuristic"),
+    ("preheat-gw-f32", "energy", 0, (2, 128), (3, 192), "explicit"),
+    ("preheat-gw-f32", "pair", 0, (2, 64), (6, 192), "heuristic"),
     # the deferred pair's 32 window components fit no blocking: the
     # coupled chunk runs `energy`, five calls a step (PERF.md section 4)
     ("preheat-gw-f32", "coupled_pair", 1, None, None, None),
@@ -249,6 +249,67 @@ def test_cells_get_the_kernels_the_ledger_measured(config, kernel, nth,
     stage_extras = ["dfdt", "kf", "kdfdt"] + (
         ["dhijdt", "khij", "kdhijdt"] if config == "preheat-gw-f32" else [])
     assert d["in_place"] == (stage_extras if kernel == "stage" else [])
+
+
+# -- the budget, the tier figure and the re-read ---------------------------
+
+#: ``choose_blocks``' model arguments ``(n_comp, lattice, h, itemsize,
+#: n_extra, n_out)`` of the kernels the cells build
+_MODELS = {
+    "stage": (2, (512,) * 3, 2, 4, 6, 8),
+    "pair": (6, (512,) * 3, 2, 4, 2, 8),
+    "coupled_pair": (8, (512,) * 3, 2, 4, 0, 8),
+    "lap": (2, (512,) * 3, 2, 4, 0, 2),
+    "gw-energy": (8, (384,) * 3, 2, 4, 24, 32),
+    "gw-coupled_pair": (32, (384,) * 3, 2, 4, 0, 32),
+    "mg_smooth": (2, (512,) * 3, 1, 4, 2, 2),
+}
+
+
+@pytest.mark.parametrize("kernel, by, moved, ideal", [
+    ("stage", 64, 16.5, 16),
+    ("pair", 32, 19, 16),
+    ("coupled_pair", 32, 20, 16),
+    ("gw-energy", 16, 72, 64),
+    ("mg_smooth", 256, 6.125, 6),
+])
+def test_reread_counts_every_windows_y_halo(kernel, by, moved, ideal):
+    """The bytes a call moves over the bytes its roofline counts, in
+    passes over one lattice array: the rows of ISSUE 39's table (the
+    blockings the cells had under the 24-MB budget), by hand."""
+    n_comp, _, _, _, n_extra, n_out = _MODELS[kernel]
+    assert n_comp + n_extra + n_out == ideal
+    assert psten.reread(n_comp, n_extra, n_out, by) == moved / ideal
+    # a larger y block re-reads less, and never less than nothing
+    assert (psten.reread(n_comp, n_extra, n_out, 2 * by)
+            < psten.reread(n_comp, n_extra, n_out, by))
+    assert psten.reread(n_comp, n_extra, n_out, 2 * by) > 1
+
+
+@pytest.mark.parametrize("kernel", sorted(_MODELS))
+def test_a_larger_budget_never_gives_a_smaller_y_block(kernel):
+    model = _MODELS[kernel]
+    bys = []
+    for mb in (24, 32, 48, 72, 96, 128):
+        try:
+            bys.append(psten.choose_blocks(*model, budget=mb * 2**20)[1])
+        except ValueError:
+            bys.append(0)
+    assert bys == sorted(bys), bys
+    if kernel == "gw-coupled_pair":
+        # the trap of ISSUE 39: from 32 MB up the -gws deferred pair
+        # "fits" at by = 8, and the cell's kernel kind would change ...
+        assert bys[:2] == [0, 8]
+        # ... so without a budget the tier figure decides that it exists
+        # nowhere, whatever the blocking figure admits
+        assert psten.feasible_blocks(*model)
+        with pytest.raises(ValueError, match="fits the 24 MB"):
+            psten.choose_blocks(*model)
+        return
+    # a kernel that exists is blocked under the blocking figure
+    assert psten.TIER_BUDGET_BYTES < psten.BLOCK_BUDGET_BYTES
+    assert psten.choose_blocks(*model) == psten.choose_blocks(
+        *model, budget=psten.BLOCK_BUDGET_BYTES)
 
 
 # -- pins, refusals, events ------------------------------------------------
@@ -344,11 +405,13 @@ def test_events_carry_what_the_benchmark_prints(build, names):
     assert {d["kernel"] for d in choices} == {"stage", "pair"}
     for d in choices:
         assert {"kernel", "stencil", "bx", "by", "grid", "halo", "in_place",
-                "source", "local_shape", "label"} <= set(d)
+                "reread", "source", "local_shape", "label"} <= set(d)
         assert d["source"] in ("explicit", "heuristic")
         assert d["halo"] == ["wrap", "wrap"]
         assert d["in_place"] == []   # built without donate=True
         assert d["grid"] == [16 // d["by"], 16 // d["bx"]]
+        # the windowed share of the call's arrays, read with its y halo
+        assert 1 < d["reread"] <= (d["by"] + 2 * psten.HY) / d["by"]
     tiers = seen.of("kernel_tier")
     assert [d["entrypoint"] for d in tiers] == ["multi_step"]
     for d in tiers:
@@ -430,7 +493,8 @@ def test_a_stray_table_or_variable_changes_nothing(tmp_path, monkeypatch):
     assert {d["source"] for d in seen.of("block_choice")} == {"heuristic"}
     assert strayed.kernel_tier_report() == clean.kernel_tier_report()
     assert psten.choose_blocks(6, (512, 512, 512), 2, 4, 2, 8) == budgeted
-    assert psten.BLOCK_BUDGET_BYTES == 24 * 2**20
+    assert psten.TIER_BUDGET_BYTES == 24 * 2**20
+    assert psten.BLOCK_BUDGET_BYTES == psten.VMEM_LIMIT_BYTES
     assert (psten._compiler_params(False).vmem_limit_bytes
             == psten.VMEM_LIMIT_BYTES == 100 * 2**20)
     assert psten._compiler_params(True) is None  # interpret mode

@@ -38,17 +38,20 @@ def test_compiled_requires_lane_aligned_z():
 
 def test_choose_blocks_hardware_tuned_defaults():
     """Pin the measured-on-v5e selections (doc/performance.md): largest
-    feasible by, smallest bx >= h, 24 MB budget. (bx=2, by=128) beat every
-    bx>=4 blocking at 128^3 and (2,64)~(2,32) were fastest-and-feasible at
-    512^3; regressions here silently cost 15-45% of headline bandwidth."""
+    feasible by, smallest bx >= h, in the VMEM limit the kernels compile
+    under. (bx=2, by=128) beat every bx>=4 blocking at 128^3; at 512^3
+    the y block is the re-read ((by + 16) / by of every window): (2,
+    256) and (2, 128) beat the (2, 64) and (2, 32) of the 24 MB budget
+    (PR 39); regressions here silently cost 10-45% of headline
+    bandwidth."""
     from pystella_tpu.ops.pallas_stencil import choose_blocks
 
     # fused single-stage scalar kernel (F=2): n_comp=2, 6 extras, 8 outs
     assert choose_blocks(2, (128,) * 3, 2, 4, 6, 8) == (2, 128)
-    assert choose_blocks(2, (256,) * 3, 2, 4, 6, 8) == (2, 128)
-    assert choose_blocks(2, (512,) * 3, 2, 4, 6, 8) == (2, 64)
+    assert choose_blocks(2, (256,) * 3, 2, 4, 6, 8) == (2, 256)
+    assert choose_blocks(2, (512,) * 3, 2, 4, 6, 8) == (2, 256)
     # stage-pair scalar kernel: 3 windows x F, 1 extra x F, 4 outs x F
-    assert choose_blocks(6, (512,) * 3, 2, 4, 2, 8) == (2, 32)
+    assert choose_blocks(6, (512,) * 3, 2, 4, 2, 8) == (2, 128)
     # bx respects the stencil radius
     assert choose_blocks(1, (64,) * 3, 4, 8, 0, 1)[0] >= 4
 

@@ -94,14 +94,20 @@ def _preheat(devices, proc_shape, grid, donate=True):
 #: compiled HLO text of the coupled chunk per (mesh, lattice): compiled
 #: once, read by the compile test and by the name test
 _COUPLED_HLO = {}
+#: the coupled pairs' ``block_choice`` events of that build, same key
+_COUPLED_BLOCKS = {}
 
 
 def _coupled_chunk_hlo(v5e, proc_shape, grid):
+    from test_kernel_choice import _watch_events
     key = (proc_shape, grid)
     if key not in _COUPLED_HLO:
-        stepper, state, scalar = _preheat(v5e, proc_shape, grid)
-        assert stepper._ensure_coupled_pair_calls() is not None
-        stepper._ensure_energy_call()
+        with _watch_events() as seen:
+            stepper, state, scalar = _preheat(v5e, proc_shape, grid)
+            assert stepper._ensure_coupled_pair_calls() is not None
+            stepper._ensure_energy_call()
+        _COUPLED_BLOCKS[key] = [d for d in seen.of("block_choice")
+                                if d["kernel"] == "coupled_pair"]
 
         def chunk(st, a, adot):
             return stepper._coupled_pair_impl(
@@ -123,8 +129,20 @@ def test_coupled_chunk_compiles(v5e, proc_shape, grid):
     one high ``h``-row x slab and ``HY``-row y slab beside them), and no
     operand of any of them is a padded copy of a window."""
     import re
+    from test_kernel_choice import _CELL_KERNELS
     hlo = _coupled_chunk_hlo(v5e, proc_shape, grid)
     assert hlo
+    # what Mosaic took is the blocking the cells build
+    # (``test_kernel_choice.py``'s rows): a budget whose choice the
+    # compiler refuses at a cell's size fails here, off the chip
+    config = ("preheat-512-f32" if proc_shape == (1, 1, 1)
+              else "preheat-mesh4-f32")
+    recorded = [blocks for c, kernel, _, blocks, *_ in _CELL_KERNELS
+                if (c, kernel) == (config, "coupled_pair")]
+    built = _COUPLED_BLOCKS[(proc_shape, grid)]
+    assert len(recorded) == 2
+    assert [(d["bx"], d["by"]) for d in built] == recorded
+    assert {d["source"] for d in built} == {"heuristic"}
     if proc_shape == (1, 1, 1):
         return
     local = tuple(n // p for n, p in zip(grid, proc_shape))
