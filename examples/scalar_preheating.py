@@ -34,7 +34,9 @@ parser.add_argument("--dtype", type=np.dtype, default=np.float64,
                          "to float32 with only a warning, so pass "
                          "float32 explicitly for a float32 run")
 parser.add_argument("--halo-shape", type=int, default=2, metavar="h",
-                    help="stencil radius; 0 selects spectral derivatives")
+                    help="stencil radius, 1-4 (centred differences of "
+                         "order 2-8; anything else is refused); 0 "
+                         "selects spectral derivatives")
 parser.add_argument("--box-dim", "-box", type=float, nargs=3,
                     metavar=("Lx", "Ly", "Lz"), default=(5., 5., 5.))
 parser.add_argument("--kappa", type=float, default=1 / 10,
@@ -164,6 +166,11 @@ def main(argv=None):
             and not ps.config.getenv("PYSTELLA_EVENT_LOG"):
         raise ValueError("--perf-report digests the event log: pass "
                          "--event-log (or set PYSTELLA_EVENT_LOG)")
+    if p.halo_shape not in range(5):
+        raise ValueError(
+            f"--halo-shape {p.halo_shape}: takes 0-4 (0: spectral "
+            "derivatives; 1-4: the stencil radii of the coefficient "
+            "tables, order 2-8)")
     cache_dir = ps.obs.ensure_compilation_cache()
     p.grid_shape = tuple(p.grid_shape)
     p.proc_shape = tuple(p.proc_shape)

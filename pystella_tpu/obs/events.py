@@ -197,7 +197,10 @@ for _name, _help in (
                       "of a call go through one transform ('all')"),
     # -- fused kernel tiers --------------------------------------------------
     ("block_choice", "a fused kernel build chose its blocking "
-                     "(bx/by/grid/win_halo, halo: each of (x, y) "
+                     "(bx/by/grid/win_halo, h: the stencil radius, "
+                     "taps: shifted values a site and component's "
+                     "derivatives take, 6h+1 a fused stage, "
+                     "halo: each of (x, y) "
                      "'wrap' or, on a sharded axis, 'slab', in_place: "
                      "the extras it writes over, reread: modelled "
                      "bytes moved over ideal bytes at that by + source: "

@@ -95,6 +95,20 @@ _lap_coefs = {
 }
 
 
+def stencil_radius(h, who):
+    """``h`` as the radius of a centred stencil, or a ``ValueError``
+    that says what the coefficient tables hold (a ``KeyError`` of
+    ``_lap_coefs[5]`` told a caller nothing)."""
+    if h not in _lap_coefs:
+        raise ValueError(
+            f"{who}: halo_shape {h!r} is not a stencil radius the "
+            f"coefficient tables hold: 1-{max(_lap_coefs)} (order 2-"
+            f"{2 * max(_lap_coefs)}; upstream's --halo-shape takes 0-"
+            f"{max(_lap_coefs)}, and 0, spectral derivatives, is "
+            "SpectralCollocator's, not a stencil's)")
+    return int(h)
+
+
 class FirstCenteredDifference(FiniteDifferenceStencil):
     """Antisymmetric centered first difference of order ``2h``
     (reference derivs.py:134-157)."""
@@ -102,7 +116,7 @@ class FirstCenteredDifference(FiniteDifferenceStencil):
     order = 1
 
     def __init__(self, h):
-        self.h = h
+        self.h = h = stencil_radius(h, type(self).__name__)
         self.coefs = _grad_coefs[h]
         self.truncation_order = 2 * h
 
@@ -120,7 +134,7 @@ class SecondCenteredDifference(FiniteDifferenceStencil):
     order = 2
 
     def __init__(self, h):
-        self.h = h
+        self.h = h = stencil_radius(h, type(self).__name__)
         self.coefs = _lap_coefs[h]
         self.truncation_order = 2 * h
 
@@ -162,7 +176,9 @@ class FiniteDifferencer:
     arrays instead of writing into passed-in output buffers.
 
     :arg decomp: a :class:`~pystella_tpu.DomainDecomposition`.
-    :arg halo_shape: the stencil radius ``h`` (1..4 → order 2..8).
+    :arg halo_shape: the stencil radius ``h`` (1..4 → order 2..8; any
+        other is a ``ValueError`` of the stencil classes that names the
+        tables' range).
     :arg dx: lattice spacing per axis (scalar or 3-tuple).
     :arg mode: ``"pallas"`` (streaming Pallas stencil kernels — the fast
         TPU path, default on unsharded lattices), ``"halo"`` (shard_map +

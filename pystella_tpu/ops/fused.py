@@ -47,7 +47,8 @@ from pystella_tpu.obs import events as _events
 from pystella_tpu.obs import memory as _obs_memory
 from pystella_tpu.obs import metrics as _metrics
 from pystella_tpu.obs.scope import host_span, trace_scope
-from pystella_tpu.ops.derivs import _grad_coefs, _lap_coefs
+from pystella_tpu.ops.derivs import (
+    _grad_coefs, _lap_coefs, stencil_radius)
 from pystella_tpu.ops.pallas_stencil import (
     ResidentStencil, StreamingStencil,
     grad_from_taps as _grad_from_taps, lap_from_taps as _lap_from_taps,
@@ -174,7 +175,7 @@ class FusedScalarStepper(_step.Stepper):
         if np.isscalar(dx):
             dx = (dx,) * 3
         self.dx = tuple(float(d) for d in dx)
-        self.h = int(halo_shape)
+        self.h = stencil_radius(halo_shape, type(self).__name__)
         self.dtype = jnp.zeros((), dtype).dtype
 
         F = sector.nscalars
@@ -252,7 +253,15 @@ class FusedScalarStepper(_step.Stepper):
         ``stage`` kernel under ``donate=True``, else none); ``reread``
         is the modelled real-over-ideal byte ratio of a call at that
         ``by`` (``pallas_stencil.reread``; a resident kernel has
-        none)."""
+        none); ``h`` is the stencil radius and ``taps`` the shifted
+        values a site and component's derivatives take in the body,
+        ``6 h + 1`` for the Laplacian of each stage the kernel fuses
+        (two in a pair kernel, whose ``stages`` says 1: that figure is
+        the VMEM model's, which counts a pair's temporaries as one
+        stage's): what the kernel's arithmetic scales with where its
+        bytes do not."""
+        stages = getattr(st, "stages", 1)
+        fused = 2 if kind in ("pair", "coupled_pair") else stages
         _events.emit(
             "block_choice", kernel=kind,
             stencil=type(st).__name__,
@@ -260,7 +269,8 @@ class FusedScalarStepper(_step.Stepper):
             grid=getattr(st, "grid", None),
             reread=getattr(st, "reread", None),
             win_halo=getattr(st, "wh", None),
-            stages=getattr(st, "stages", 1),
+            h=self.h, taps=fused * (6 * self.h + 1),
+            stages=stages,
             halo=list(getattr(st, "halo", ("wrap", "wrap"))),
             in_place=list(getattr(st, "in_place", ())),
             source=source, local_shape=list(self.local_shape),
@@ -341,8 +351,9 @@ class FusedScalarStepper(_step.Stepper):
                 raise
             import warnings
             warnings.warn(
-                f"stage-pair fusion disabled ({e}); step() will run "
-                "single-stage fused kernels", stacklevel=3)
+                f"stage-pair fusion disabled at stencil radius "
+                f"{self.h} ({e}); step() will run single-stage fused "
+                "kernels", stacklevel=3)
             self._pair_stages = False
             return None
 
@@ -860,6 +871,7 @@ class FusedScalarStepper(_step.Stepper):
             "kernels_per_2_steps": kernels,
             "bytes_per_step": bytes_total // 2,
             "local_shape": list(self.local_shape),
+            "h": self.h,
         }
 
     def _emit_tier(self, entrypoint):
@@ -1440,9 +1452,9 @@ class FusedScalarStepper(_step.Stepper):
         except ValueError as e:
             import warnings
             warnings.warn(
-                f"deferred-drag coupled pair kernels unavailable ({e}); "
-                "coupled_multi_step will run single-stage kernels",
-                stacklevel=3)
+                f"deferred-drag coupled pair kernels unavailable at "
+                f"stencil radius {self.h} ({e}); coupled_multi_step "
+                "will run single-stage kernels", stacklevel=3)
             self._pes_call = None
         return self._pes_call
 

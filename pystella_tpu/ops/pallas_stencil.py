@@ -172,7 +172,13 @@ def choose_blocks(n_comp, lattice_shape, h, itemsize, n_extra, n_out,
     384^3 125.4 / 116.2 / 114.2 / 113.9 at 16 / 32 / 64 / 128, ``lap``
     4.06 / 3.60 at 128 / 256. Small x-blocks keep the ring slots cheap
     and pipeline best ((2,128) beat every bx>=4 blocking at 128^3):
-    that is about ``bx``, not ``by``.
+    that is about ``bx``, not ``by``, and it dates from the padded
+    layouts (before PR 26). A radius of 4 has no smaller ``bx`` than 4
+    to take; its kernels at 512^3 (PR 40, the v5e, share of 819 GB/s in
+    the bytes they move): ``stage`` (4, 128) 81.1 %, ``pair`` (4, 64)
+    79.1, ``coupled_pair`` (4, 64) 78.5, where the radius-2 kernels at
+    (2, 256) and (2, 128) read 81-82; ``lap`` (4, 256) 50.3 against
+    (2, 256)'s 76.2. ``bx`` itself has not been varied at one radius.
 
     Without a ``budget`` two figures are asked: whether the kernel
     exists at all under :data:`TIER_BUDGET_BYTES` (a ``ValueError``
@@ -192,9 +198,10 @@ def choose_blocks(n_comp, lattice_shape, h, itemsize, n_extra, n_out,
         raise ValueError(f"win_halo {wh} below stencil radius {h}")
     if wh > HY:
         raise ValueError(
-            f"win_halo {wh} exceeds the aligned y-halo width {HY}: no "
-            "feasible streaming blocking (shrink the chunk depth or "
-            "use the pair/single-stage kernels)")
+            f"win_halo {wh} (ceil(stages / 2) * h: {stages} stage(s) at "
+            f"stencil radius {h}) exceeds the aligned y-halo width HY = "
+            f"{HY}: no feasible streaming blocking (shrink the chunk "
+            "depth or use the pair/single-stage kernels)")
     X, Y, Z = lattice_shape
     model = (n_comp, lattice_shape, h, itemsize, n_extra, n_out)
     feasible = feasible_blocks(*model, budget=tier_budget,
@@ -637,9 +644,11 @@ class StreamingStencil:
                 f"win_halo {self.wh} below stencil radius {h}")
         if self.wh > HY:
             raise ValueError(
-                f"win_halo {self.wh} exceeds the aligned y-halo width "
-                f"{HY}: the y-window pad cannot cover the composed-stage "
-                "taps; use a shallower chunk or the pair kernels")
+                f"win_halo {self.wh} (ceil(stages / 2) * h: "
+                f"{self.stages} stage(s) at stencil radius {h}) exceeds "
+                f"the aligned y-halo width HY = {HY}: the y-window pad "
+                "cannot cover the composed-stage taps; use a shallower "
+                "chunk or the pair kernels")
         self.lattice_shape = X, Y, Z = tuple(int(s) for s in lattice_shape)
         if not isinstance(win_defs, dict):
             win_defs = {"f": int(win_defs)}

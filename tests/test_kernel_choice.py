@@ -194,6 +194,18 @@ _CELL_KERNELS = [
     ("multigrid-512-f32", "mg_smooth", 2, (1, 128), (1, 128), "heuristic"),
     ("multigrid-512-f32", "mg_smooth", 3, None, None, "resident"),
     ("multigrid-512-f32", "mg_smooth", 6, None, None, "resident"),
+    # --halo-shape 4 at 512^3 (PR 40): a radius of 4 has no smaller x
+    # block than 4 to take, the ring is twice as deep and the y block
+    # half the h = 2 cells'; as the chip run built them
+    ("preheat-h4-f32", "stage", 0, (4, 128), (4, 128), "heuristic"),
+    ("preheat-h4-f32", "pair", 0, (4, 64), (8, 128), "heuristic"),
+    ("preheat-h4-f32", "coupled_pair", 0, (4, 64), (8, 128),
+     "heuristic"),
+    ("preheat-h4-f32", "coupled_pair", 1, (4, 64), (8, 128),
+     "heuristic"),
+    ("preheat-h4-f32", "energy", 0, (4, 128), (4, 128), "explicit"),
+    ("preheat-h4-f32", "lap", 0, (4, 256), (2, 128), "heuristic"),
+    ("preheat-h4-f32", "grad", 0, (4, 256), (2, 128), "heuristic"),
 ]
 
 
@@ -263,6 +275,11 @@ _MODELS = {
     "gw-energy": (8, (384,) * 3, 2, 4, 24, 32),
     "gw-coupled_pair": (32, (384,) * 3, 2, 4, 0, 32),
     "mg_smooth": (2, (512,) * 3, 1, 4, 2, 2),
+    # preheat-h4-f32: the same arrays at radius 4
+    "h4-stage": (2, (512,) * 3, 4, 4, 6, 8),
+    "h4-pair": (6, (512,) * 3, 4, 4, 2, 8),
+    "h4-coupled_pair": (8, (512,) * 3, 4, 4, 0, 8),
+    "h4-lap": (2, (512,) * 3, 4, 4, 0, 2),
 }
 
 
@@ -272,6 +289,10 @@ _MODELS = {
     ("coupled_pair", 32, 20, 16),
     ("gw-energy", 16, 72, 64),
     ("mg_smooth", 256, 6.125, 6),
+    # the h = 4 cell's blocks: half the y block, twice the re-read
+    ("h4-stage", 128, 16.25, 16),
+    ("h4-pair", 64, 17.5, 16),
+    ("h4-coupled_pair", 64, 18, 16),
 ])
 def test_reread_counts_every_windows_y_halo(kernel, by, moved, ideal):
     """The bytes a call moves over the bytes its roofline counts, in
@@ -405,7 +426,11 @@ def test_events_carry_what_the_benchmark_prints(build, names):
     assert {d["kernel"] for d in choices} == {"stage", "pair"}
     for d in choices:
         assert {"kernel", "stencil", "bx", "by", "grid", "halo", "in_place",
-                "reread", "source", "local_shape", "label"} <= set(d)
+                "reread", "source", "local_shape", "label", "h",
+                "taps"} <= set(d)
+        # the radius, and a 13-tap Laplacian a fused stage at h = 2
+        assert d["h"] == 2
+        assert d["taps"] == {"stage": 13, "pair": 26}[d["kernel"]]
         assert d["source"] in ("explicit", "heuristic")
         assert d["halo"] == ["wrap", "wrap"]
         assert d["in_place"] == []   # built without donate=True

@@ -68,7 +68,7 @@ def compile_tpu(fn, *args, donate=()):
         lowering_platforms=("tpu",)).compile()
 
 
-def _preheat(devices, proc_shape, grid, donate=True):
+def _preheat(devices, proc_shape, grid, donate=True, h=2):
     """The flagship model as the example builds it, abstract state."""
     ndev = int(np.prod(proc_shape))
     decomp = ps.DomainDecomposition(proc_shape, devices=devices[:ndev])
@@ -80,7 +80,7 @@ def _preheat(devices, proc_shape, grid, donate=True):
 
     dx = tuple(5.0 / n for n in grid)
     stepper = ps.FusedScalarStepper(
-        ps.ScalarSector(2, potential=potential), decomp, grid, dx, 2,
+        ps.ScalarSector(2, potential=potential), decomp, grid, dx, h,
         dtype=jnp.float32, dt=np.float32(0.1 * min(dx)), donate=donate,
         interpret=False)
     state = {k: jax.ShapeDtypeStruct((2,) + grid, jnp.float32,
@@ -98,12 +98,12 @@ _COUPLED_HLO = {}
 _COUPLED_BLOCKS = {}
 
 
-def _coupled_chunk_hlo(v5e, proc_shape, grid):
+def _coupled_chunk_hlo(v5e, proc_shape, grid, h=2, nsteps=1):
     from test_kernel_choice import _watch_events
-    key = (proc_shape, grid)
+    key = (proc_shape, grid, h, nsteps)
     if key not in _COUPLED_HLO:
         with _watch_events() as seen:
-            stepper, state, scalar = _preheat(v5e, proc_shape, grid)
+            stepper, state, scalar = _preheat(v5e, proc_shape, grid, h=h)
             assert stepper._ensure_coupled_pair_calls() is not None
             stepper._ensure_energy_call()
         _COUPLED_BLOCKS[key] = [d for d in seen.of("block_choice")
@@ -111,12 +111,42 @@ def _coupled_chunk_hlo(v5e, proc_shape, grid):
 
         def chunk(st, a, adot):
             return stepper._coupled_pair_impl(
-                st, t=0.0, dt=stepper.dt, a=a, adot=adot, nsteps=1,
+                st, t=0.0, dt=stepper.dt, a=a, adot=adot, nsteps=nsteps,
                 grid_size=float(np.prod(grid)), mpl=1.0)
 
         _COUPLED_HLO[key] = compile_tpu(
             chunk, state, scalar, scalar, donate=0).as_text()
     return _COUPLED_HLO[key]
+
+
+@pytest.mark.parametrize("grid", [
+    (512, 128, 512),
+    pytest.param((512, 512, 512), marks=pytest.mark.slow)],
+    ids=["512x128x512", "512x512x512"])
+def test_h4_coupled_chunk_compiles(v5e, grid):
+    """``preheat-h4-f32``'s four-step coupled chunk (``--halo-shape 4
+    --chunk-steps 4``): ten deferred-drag pair kernels with 25-tap
+    Laplacians at ``bx = 4``, the smallest x block a radius of 4 takes,
+    and half the y block of the h = 2 cells. Mosaic's VMEM account is
+    the question (the model's "three window-sized temporaries a stage"
+    was calibrated on 13-tap bodies): a refusal shows here, off the
+    chip. At 512^3 the blocks are the cell's own
+    (``test_kernel_choice.py``'s rows); at Y = 128 the same kernels
+    over a quarter of the y-slabs."""
+    import re
+    from test_kernel_choice import _CELL_KERNELS
+    hlo = _coupled_chunk_hlo(v5e, (1, 1, 1), grid, h=4, nsteps=4)
+    names = _custom_call_names(hlo)
+    kinds = [re.sub(r"\.\d+$", "", n) for n in names]
+    assert kinds.count("pallas_stencil_coupled_pair") == 10, kinds
+    assert set(kinds) == {"pallas_stencil_coupled_pair"}, kinds
+    built = _COUPLED_BLOCKS[((1, 1, 1), grid, 4, 4)]
+    assert [(d["h"], d["taps"]) for d in built] == [(4, 50)] * 2
+    assert {d["bx"] for d in built} == {4}
+    if grid == (512, 512, 512):
+        recorded = [blocks for c, kernel, _, blocks, *_ in _CELL_KERNELS
+                    if (c, kernel) == ("preheat-h4-f32", "coupled_pair")]
+        assert [(d["bx"], d["by"]) for d in built] == recorded
 
 
 @pytest.mark.parametrize("proc_shape,grid", [ONE_CHIP, MESH, MESH_CELL])
@@ -139,7 +169,7 @@ def test_coupled_chunk_compiles(v5e, proc_shape, grid):
               else "preheat-mesh4-f32")
     recorded = [blocks for c, kernel, _, blocks, *_ in _CELL_KERNELS
                 if (c, kernel) == (config, "coupled_pair")]
-    built = _COUPLED_BLOCKS[(proc_shape, grid)]
+    built = _COUPLED_BLOCKS[(proc_shape, grid, 2, 1)]
     assert len(recorded) == 2
     assert [(d["bx"], d["by"]) for d in built] == recorded
     assert {d["source"] for d in built} == {"heuristic"}
