@@ -26,7 +26,6 @@ import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from pystella_tpu.obs import events as _events
-from pystella_tpu.obs import memory as _obs_memory
 from pystella_tpu.obs import metrics as _metrics
 from pystella_tpu.obs.scope import host_span, trace_scope
 from pystella_tpu.multigrid.relax import (
@@ -34,7 +33,7 @@ from pystella_tpu.multigrid.relax import (
 from pystella_tpu.multigrid.transfer import (
     RestrictionBase, FullWeighting, Injection,
     InterpolationBase, LinearInterpolation, CubicInterpolation,
-    periodic_pad, _run_local)
+    periodic_pad, _local_program, _run_local)
 
 __all__ = [
     "mu_cycle", "v_cycle", "w_cycle", "f_cycle",
@@ -167,14 +166,8 @@ class FullApproximationScheme:
         key = key + (decomp,)
         cached = self._transfer_cache.get(key)
         if cached is None:
-            spec = decomp.spec(0)
-
-            def body(blk):
-                return op.apply_local(blk, pad_fn=decomp.pad_with_halos)
-
-            cached = _obs_memory.instrument_jit(
-                decomp.shard_map(body, spec, spec),
-                label=f"mg.transfer.{type(op).__name__}")
+            cached = _local_program(
+                op, f"mg.transfer.{type(op).__name__}", decomp)
             self._transfer_cache[key] = cached
         return cached
 

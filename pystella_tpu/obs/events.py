@@ -191,6 +191,11 @@ for _name, _help in (
     ("mg_level_plan", "a multigrid level's kernels were built: which "
                       "tier serves it ('streaming' with bx/by/grid, "
                       "'resident', or 'xla' with the reason)"),
+    ("mg_transfer_plan", "a multigrid restriction program was traced: "
+                         "the operator, the fine grid_shape, the form "
+                         "each axis took ('split', 'contract' or, "
+                         "where the mesh shards it, 'contract_halo'), "
+                         "the contractions' precision and flop count"),
     ("spectral_plan", "a SpectralCollocator was built: the transform's "
                       "scheme, how a real field comes back ('xla' or "
                       "'matmul'), grid, dtype, and how many fields "

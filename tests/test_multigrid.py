@@ -9,7 +9,7 @@ import pystella_tpu as ps
 from pystella_tpu.multigrid import (
     CubicInterpolation, FullApproximationScheme, FullWeighting, Injection,
     JacobiIterator, LinearInterpolation, MultiGridSolver, NewtonIterator,
-    f_cycle, v_cycle, w_cycle)
+    RestrictionBase, f_cycle, v_cycle, w_cycle)
 
 
 def make_problems():
@@ -187,6 +187,91 @@ def test_transfer_identities(make_decomp, grid_shape, proc_shape):
                            * np.roll(fine_np, (-a, -b, -c),
                                      (0, 1, 2))[::2, ::2, ::2])
     assert np.allclose(fw, expect, atol=1e-13)
+
+
+class FivePoint(RestrictionBase):
+    """A user's wider restriction: offsets to +-2, so ``pad`` is 2."""
+
+    coefs = {-2: -1 / 16, -1: 4 / 16, 0: 10 / 16, 1: 4 / 16, 2: -1 / 16}
+
+
+_ALL_MESHES = [(1, 1, 1), (2, 1, 1), (2, 2, 1), (2, 2, 2)]
+
+
+@pytest.mark.parametrize("proc_shape", _ALL_MESHES, indirect=True)
+@pytest.mark.parametrize("dtype", [np.float32, np.float64])
+@pytest.mark.parametrize("Restrictor", [FullWeighting, Injection, FivePoint])
+def test_restriction_is_roll_and_pick(make_decomp, proc_shape, dtype,
+                                      Restrictor):
+    """The split-and-contract restriction against numpy's own reading of
+    the same ``coefs``: per axis ``sum_o c_o * roll(f, -o)[::2]``, on an
+    uneven lattice with a leading component axis, on every mesh (a
+    sharded axis takes its halo rows from the neighbour, an unsharded one
+    has the wrap in its weights)."""
+    decomp = make_decomp(proc_shape)
+    op = Restrictor()
+    fine = np.random.default_rng(41).random((2, 16, 32, 64)).astype(dtype)
+    got = op(decomp.shard(fine), decomp=decomp)
+    assert got.dtype == dtype and got.shape == (2, 8, 16, 32)
+
+    expect = fine.astype(np.float64)
+    for ax in (1, 2, 3):
+        expect = sum(c * np.roll(expect, -o, ax) for o, c in op.coefs.items())
+    expect = expect[:, ::2, ::2, ::2]
+    if len(op.coefs) == 1:
+        assert np.array_equal(np.asarray(got), expect.astype(dtype))
+    else:
+        # nine sums a point in another order than numpy's
+        assert np.allclose(got, expect, rtol=0, atol=16 * np.finfo(dtype).eps)
+
+
+@pytest.mark.parametrize("proc_shape", _ALL_MESHES, indirect=True)
+@pytest.mark.parametrize("route", ["operator", "scheme"])
+def test_transfer_plan_says_each_axis(make_decomp, grid_shape, proc_shape,
+                                      route):
+    """A built restriction program says once what it made of each axis
+    (``mg_transfer_plan``): the halo form exactly on the axes the mesh
+    shards. Through the operator's own call and through the scheme's
+    cached sharded transfer (unsharded there: ``_run_local``'s)."""
+    from test_kernel_choice import _watch_events
+    decomp = make_decomp(proc_shape)
+    fine = decomp.shard(np.random.default_rng(7).random(grid_shape))
+    op = FullWeighting()
+    if route == "operator":
+        def call():
+            return op(fine, decomp=decomp)
+    else:
+        solver = NewtonIterator(decomp, make_problems(), halo_shape=1,
+                                omega=1 / 2)
+        mg = FullApproximationScheme(solver=solver, halo_shape=1)
+        levels = mg._make_levels(decomp, grid_shape, 1.0, 1)
+        op = mg.restrictor
+
+        def call():
+            return mg._restrict(decomp, levels[0], levels[1], fine)
+
+    with _watch_events() as seen:
+        first = call()
+        again = call()
+    assert np.array_equal(first, again)
+    plan, = seen.of("mg_transfer_plan")
+    sharded = [p > 1 for p in proc_shape]
+    assert plan["axes"] == [
+        "contract_halo" if s else "contract" if d else "split"
+        for d, s in enumerate(sharded)]
+    assert plan["operator"] == "FullWeighting"
+    assert plan["grid_shape"] == list(grid_shape)
+    assert plan["local_shape"] == [n // p
+                                   for n, p in zip(grid_shape, proc_shape)]
+    assert plan["precision"] == "highest" and plan["dtype"] == "float64"
+    # each contraction is a (rows, cols) matrix on every line of its axis
+    shape, flops = list(plan["local_shape"]), 0
+    for d, form in enumerate(plan["axes"]):
+        rows, cols = shape[d] // 2, shape[d] + 2 * sharded[d]
+        shape[d] = rows
+        if form != "split":
+            flops += 2 * rows * cols * (int(np.prod(shape)) // rows)
+    assert plan["flops"] == flops > 0
 
 
 @pytest.mark.parametrize("proc_shape", [(2, 2, 1)], indirect=True)

@@ -589,6 +589,35 @@ def test_multigrid_smooth_loop_copies_no_lattice(v5e, monkeypatch, n):
             == [f"f32[2,{n},{n},{n}]"] * 2, operands
 
 
+def test_restriction_takes_no_strided_slice_and_no_padded_copy(v5e):
+    """``FullWeighting().apply_local`` at 512**3 float32, the program
+    ``multigrid-512-f32.vcycle`` runs six times a pair of levels: no
+    stride-2 ``slice`` along either minor axis (on the chip a relayout of
+    the ``(8, 128)`` tiles, not a copy: 117 of a V-cycle's 755 ms before
+    PR 41, ``jit_transfer_FullWeighting_local/slice``), no periodic pad
+    of the block written out (``f32[514,514,514]``), the two minor axes as
+    dot fusions, temporaries under 0.6 GB (1,025 MB with the padded
+    strided slices, on which this test fails)."""
+    import re
+    from pystella_tpu.multigrid import FullWeighting
+    decomp = ps.DomainDecomposition((1, 1, 1), devices=v5e[:1])
+    x = jax.ShapeDtypeStruct((512,) * 3, jnp.float32,
+                             sharding=decomp.sharding(0))
+    compiled = compile_tpu(FullWeighting().apply_local, x)
+    hlo = compiled.as_text()
+    assert "f32[514,514,514]" not in hlo
+    for ln in hlo.splitlines():
+        strides = re.search(r"\bslice\(.*slice=\{(.*?)\}", ln)
+        if strides:
+            y, z = re.findall(r"\[\d+:\d+(?::(\d+))?\]", strides.group(1))[-2:]
+            assert (y or "1", z or "1") == ("1", "1"), ln.strip()[:200]
+    dots = [ln for ln in hlo.splitlines()
+            if " fusion(" in ln and "kind=kOutput" in ln]
+    assert [re.search(r"= (f32\[[\d,]*\])", ln).group(1) for ln in dots] \
+        == ["f32[256,256,512]", "f32[256,256,256]"], dots
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.6e9
+
+
 # -- the ``--halo-shape 0`` programs (``preheat-spectral-f32``) --------------
 
 #: the cell's lattice (``slow``, 45 s) and in tier-1 a sixteenth of it
