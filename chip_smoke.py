@@ -21,7 +21,14 @@ in-place programs against undonated ones, bit for bit
 (:func:`in_place_stages`), the transform pair's round
 trip, and the histogram and one spectrum of a seeded field against
 numpy's float64 binning of the same field. With four chips it repeats all of
-it on a ``(2, 2, 1)`` mesh and checks the work is spread over them.
+it on a ``(2, 2, 1)`` mesh and checks the work is spread over them, and
+again on the slab decomposition ``(4, 1, 1)`` at ``(2048, 512, 512)``
+(upstream's ``-proc 4 1 1 -box 20 5 5``, frozen chunks: the
+``preheat-mesh4x-f32`` deployment), the one mesh on which the fused
+kernels take the interior/shell halo-overlap split: there it prints the
+``overlap_plan`` events and the split's two ``block_choice`` events and
+holds four steps of the split against the single launch bit for bit
+(:func:`split_against_single`). ``--legs slab,mesh,chip`` picks legs.
 
 It exits non-zero, before building anything, unless jax finds a TPU. Its
 last line of standard output is one JSON object,
@@ -46,6 +53,8 @@ import numpy as np
 HERE = os.path.dirname(os.path.abspath(__file__))
 
 GRID = (512, 512, 512)
+#: the slab leg: 512**3 a chip on (4, 1, 1), the box scaled with the grid
+SLAB_GRID, SLAB_BOX = (2048, 512, 512), (20.0, 5.0, 5.0)
 #: two steps of the fused stepper may differ from the generic reference
 #: by float32 round-off; the compiled kernels read 2.7e-7 at 128**3
 PARITY_BOUND = 1e-5
@@ -128,7 +137,7 @@ def check_run_events(events, label, kernels):
         d = e["data"]
         require(d["stencil"] == "StreamingStencil",
                 f"{label}: kernel {d['kernel']} took {d['stencil']}")
-        require(d["source"] in ("heuristic", "explicit"),
+        require(d["source"] in ("heuristic", "explicit", "split"),
                 f"{label}: kernel {d['kernel']} blocked from "
                 f"{d['source']} (an uncommitted table or override)")
         blocks[d["kernel"]] = (d["bx"], d["by"], d["source"])
@@ -136,6 +145,9 @@ def check_run_events(events, label, kernels):
     require(not missing, f"{label}: no block_choice for {missing}")
     for k, (bx, by, source) in blocks.items():
         say(f"{label}: kernel {k}: (bx, by) = ({bx}, {by}), {source}")
+    for e in events:
+        if e["kind"] == "overlap_plan":
+            say(f"{label}: overlap_plan {json.dumps(e['data'])}")
     done = [e for e in events if e["kind"] == "run_complete"][-1]
     done = {"step": done["step"], **done["data"]}
     require(np.isfinite(done["constraint"]),
@@ -287,7 +299,43 @@ def in_place_stages(grid_shape, decomp):
                for a, b in zip(*results))
 
 
-def run_leg(grid_shape, proc_shape, workdir):
+def split_against_single(grid_shape, decomp, nsteps=4):
+    """On an x-only mesh: ``multi_step`` of ``nsteps`` through the
+    interior/shell halo-overlap split (the default there) against the
+    slab-fed single launch (``overlap=False``), from one seeded float32
+    state. Every output element sees the same taps and arithmetic on
+    both paths, so none may differ; that Mosaic compiles the two
+    kernels' bodies alike is checked where Mosaic runs. Returns
+    ``(values that differ, the split's plan events)``. One stepper at a
+    time, results staged on the host."""
+    import pystella_tpu as ps
+    from pystella_tpu import obs
+
+    sector, lattice, dt, host = seeded_system(grid_shape, 23)
+    args = {"a": np.float32(1.0), "hubble": np.float32(0.5)}
+    results, plans = [], []
+    tap = obs.get_log().subscribe(
+        lambda rec: plans.append(rec["data"])
+        if rec["kind"] == "overlap_plan" else None)
+    try:
+        for overlap in (None, False):
+            stepper = ps.FusedScalarStepper(
+                sector, decomp, grid_shape, lattice.dx, 2,
+                dtype=np.float32, dt=dt, donate=True, overlap=overlap)
+            state = {k: decomp.shard(v) for k, v in host.items()}
+            state = stepper.multi_step(state, nsteps, 0.0, dt, args)
+            results.append({k: np.asarray(v) for k, v in state.items()})
+            del state, stepper
+    finally:
+        obs.get_log().unsubscribe(tap)
+    differing = sum(
+        int((results[0][k] != results[1][k]).sum())
+        + int(not np.all(np.isfinite(results[0][k]))) for k in results[0])
+    return differing, plans
+
+
+def run_leg(grid_shape, proc_shape, workdir, box=(5.0, 5.0, 5.0),
+            chunk_mode="coupled"):
     """The whole smoke on one mesh: both driver invocations, their
     checks, the checkpoint read-back and the parity comparison (at the
     run's own lattice: the generic path fits the chip at 512**3). Raises
@@ -304,9 +352,11 @@ def run_leg(grid_shape, proc_shape, workdir):
     devices = jax.devices()[:ndev]
     label = "x".join(str(p) for p in proc_shape)
     os.makedirs(workdir, exist_ok=True)
-    dt = 0.1 * 5.0 / max(grid_shape)
+    dt = 0.1 * min(b / n for b, n in zip(box, grid_shape))
+    slab = proc_shape[0] > 1 and proc_shape[1:] == (1, 1)
     common = ["--grid-shape", *map(str, grid_shape),
               "--proc-shape", *map(str, proc_shape),
+              "--box-dim", *map(str, box),
               "--dtype", "float32", "--halo-shape", "2", "--fused",
               "--forensics-dir", os.path.join(workdir, "forensics")]
 
@@ -315,7 +365,7 @@ def run_leg(grid_shape, proc_shape, workdir):
     ev_path = os.path.join(workdir, "chunk_events.jsonl")
     ckpt_dir = os.path.join(workdir, "ckpt")
     _, bad = run_example(
-        common + ["--chunk-steps", str(chunk),
+        common + ["--chunk-steps", str(chunk), "--chunk-mode", chunk_mode,
                   "--end-time", repr((nsteps - 0.5) * dt),
                   "--checkpoint-dir", ckpt_dir,
                   "--checkpoint-interval", "8",
@@ -323,8 +373,14 @@ def run_leg(grid_shape, proc_shape, workdir):
                   "--event-log", ev_path], f"{label} chunked")
     require(not bad, f"{label} chunked: fallback warning(s): {bad}")
     events = read_events(ev_path)
-    blocks, done = check_run_events(events, f"{label} chunked",
-                                    MAIN_KERNELS)
+    # frozen chunks are multi_step's pair kernels alone; on an x-only
+    # mesh each kernel without sums is the split's two beside itself
+    kernels = (MAIN_KERNELS if chunk_mode == "coupled"
+               else ("stage", "pair"))
+    if slab:
+        kernels += tuple(f"{k}_{part}" for k in ("stage", "pair")
+                         for part in ("interior", "shell"))
+    blocks, done = check_run_events(events, f"{label} chunked", kernels)
     require(done["step"] == nsteps,
             f"{label} chunked: ended at step {done['step']}, "
             f"expected {nsteps}")
@@ -391,6 +447,22 @@ def run_leg(grid_shape, proc_shape, workdir):
     require(differing == 0,
             f"{label}: the in-place stage programs differ from the "
             f"undonated ones in {differing} value(s)")
+
+    # 3c. an x-only mesh: the overlap split against the single launch
+    split_differing = None
+    if slab:
+        split_differing, plans = split_against_single(grid_shape, decomp)
+        for d in plans:
+            say(f"{label}: overlap_plan {json.dumps(d)}")
+        paths = [(d["kernel"], d["path"], d.get("reason")) for d in plans]
+        require(("pair", "split", None) in paths
+                and ("pair", "single", "off") in paths,
+                f"{label}: the two steppers' plans read {paths}")
+        say(f"{label}: 4 steps at {grid_shape}, the split against the "
+            f"single launch: {split_differing} value(s) differ")
+        require(split_differing == 0,
+                f"{label}: the overlap split differs from the single "
+                f"launch in {split_differing} value(s)")
 
     # 4. the transform pair the seeded state and the spectra go through:
     # back is what went in, twice the same (XLA's own inverse real
@@ -467,6 +539,7 @@ def run_leg(grid_shape, proc_shape, workdir):
         "stage_loop_constraint": done2["constraint"],
         "digest": digest, "parity_maxrel": maxrel,
         "in_place_differing": differing,
+        "split_differing": split_differing,
         "blocks": {k: list(v) for k, v in blocks.items()},
         "energy_rows": rows, "checkpoints": len(saves),
         "last_chunk_ms": chunk_ms[-1],
@@ -486,9 +559,15 @@ def check_peaks(leg):
             f"peak_bytes_in_use {peaks}: spread over 1.5x")
 
 
-def main():
+def main(argv=None):
+    import argparse
     import jax
     import jaxlib
+    ap = argparse.ArgumentParser()
+    ap.add_argument("--legs", default="slab,mesh,chip",
+                    help="comma-separated: slab (4,1,1), mesh (2,2,1), "
+                    "chip (1,1,1); the two meshes need four chips")
+    wanted = set(ap.parse_args(argv).legs.split(","))
     dev = jax.devices()[0]
     device = {"platform": dev.platform, "kind": dev.device_kind,
               "count": len(jax.devices())}
@@ -518,13 +597,21 @@ def main():
         if len(jax.devices()) >= 4:
             # first: an allocator's peak is for the life of the process,
             # and the one-chip leg leaves device 0's far above the rest
-            legs.append(run_leg(GRID, (2, 2, 1),
-                                os.path.join(tmp, "4chip")))
-            check_peaks(legs[-1])
+            if "mesh" in wanted:
+                legs.append(run_leg(GRID, (2, 2, 1),
+                                    os.path.join(tmp, "4chip")))
+                check_peaks(legs[-1])
+            if "slab" in wanted:
+                legs.append(run_leg(SLAB_GRID, (4, 1, 1),
+                                    os.path.join(tmp, "slab"),
+                                    box=SLAB_BOX, chunk_mode="frozen"))
+                check_peaks(legs[-1])
         else:
-            say(f"four-chip leg not run: {len(jax.devices())} device(s)")
-        legs.append(run_leg(GRID, (1, 1, 1), os.path.join(tmp, "1chip")))
-        check_peaks(legs[-1])
+            say(f"four-chip legs not run: {len(jax.devices())} device(s)")
+        if "chip" in wanted:
+            legs.append(run_leg(GRID, (1, 1, 1),
+                                os.path.join(tmp, "1chip")))
+            check_peaks(legs[-1])
 
     totals = obs.compile_totals()
     say(f"set-up: trace {totals['trace_s']:.1f}s + compile "

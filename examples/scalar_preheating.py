@@ -53,8 +53,12 @@ parser.add_argument("--gravitational-waves", "-gws", action="store_true")
 parser.add_argument("--outfile", type=str, default=None)
 parser.add_argument("--seed", type=int, default=49279)
 parser.add_argument("--fused", action="store_true",
-                    help="use the fused Pallas RK stages (requires y/z "
-                         "unsharded and halo-shape >= 1)")
+                    help="use the fused Pallas RK stages (halo-shape "
+                         ">= 1; the mesh may shard x and y, not z: "
+                         "-proc px py 1). On an x-only mesh (-proc N 1 "
+                         "1) the stage and pair kernels take the "
+                         "interior/shell halo-overlap split unless "
+                         "PYSTELLA_HALO_OVERLAP=0")
 parser.add_argument("--carry-dtype", type=jnp.dtype, default=None,
                     metavar="DTYPE",
                     help="with --fused: storage precision of the RK "

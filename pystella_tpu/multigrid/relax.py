@@ -422,18 +422,17 @@ class RelaxationBase:
         halo = sharded_halo(self.halo_shape, px, py)
         sharded = px > 1 or py > 1
         ov = None
-        if sharded and px > 1 and py == 1:
+        if sharded:
             from pystella_tpu.ops.pallas_stencil import (
                 OverlapStreamingStencil)
             from pystella_tpu.parallel import overlap as _overlap
-            if _overlap.enabled(decomp, self._overlap_override):
-                # x-sharded sweeps overlap the slab ppermutes with the
-                # interior kernel (bit-exact; infeasible shapes keep
-                # the padded single launch)
-                try:
-                    ov = OverlapStreamingStencil(st, self.halo_shape)
-                except ValueError:
-                    ov = None
+            # x-sharded sweeps overlap the slab ppermutes with the
+            # interior kernel (bit-exact; infeasible shapes keep the
+            # padded single launch, and the event says why)
+            ov = OverlapStreamingStencil.plan_for(
+                st, self.halo_shape,
+                enabled=_overlap.enabled(decomp, self._overlap_override),
+                label=type(self).__name__)
 
         def run(fstack, rhostack, aux_args, nu):
             scalars = dict(zip(aux_scal, aux_args[len(aux_lat):]))

@@ -200,18 +200,27 @@ for _name, _help in (
                       "scheme, how a real field comes back ('xla' or "
                       "'matmul'), grid, dtype, and how many fields "
                       "of a call go through one transform ('all')"),
+    ("overlap_plan", "a sharded stencil kernel was built: which launch "
+                     "it takes on the mesh, path 'split' (the "
+                     "interior/shell halo-overlap split: the two "
+                     "kernels' lattice, bx/by/grid, reread, and the "
+                     "stitch_bytes of the copies round them) or "
+                     "'single' with the reason ('off', 'sums', "
+                     "'y_sharded', 'thin', 'blocking')"),
     # -- fused kernel tiers --------------------------------------------------
     ("block_choice", "a fused kernel build chose its blocking "
                      "(bx/by/grid/win_halo, h: the stencil radius, "
                      "taps: shifted values a site and component's "
                      "derivatives take, 6h+1 a fused stage, "
                      "halo: each of (x, y) "
-                     "'wrap' or, on a sharded axis, 'slab', in_place: "
+                     "'wrap', on a sharded axis 'slab', or 'padded' "
+                     "in the overlap split's kernels, in_place: "
                      "the extras it writes over, reread: modelled "
                      "bytes moved over ideal bytes at that by + source: "
                      "'explicit' "
-                     "constructor pins or the choose_blocks "
-                     "'heuristic')"),
+                     "constructor pins, the choose_blocks "
+                     "'heuristic', or 'split': a <kind>_interior / "
+                     "<kind>_shell kernel of the overlap split)"),
     ("bincount_plan", "a binning program was built: what the one-hot "
                       "contraction took from the shapes (hi x lo "
                       "factorisation, tile, steps and partials, MXU "

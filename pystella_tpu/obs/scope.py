@@ -92,6 +92,9 @@ for _name in (
     # halo exchange: padded path and the overlapped interior/shell split
     "halo_exchange",
     "halo_overlap", "halo_overlap_interior", "halo_overlap_shells",
+    # the slab ppermutes of the Pallas split (OverlapStreamingStencil),
+    # issued ahead of its interior launch
+    "halo_overlap_exchange",
     # the raw XLA ppermute op rows — device traces carry them with no
     # named-scope path; the ledger's communication-time denominator
     "collective-permute",
@@ -179,6 +182,14 @@ for _name in (
     "service_request_span", "service_lease_span",
 ):
     register_scope(_name)
+    if _name.startswith("pallas_stencil"):
+        # a streaming kernel that OverlapStreamingStencil splits is two
+        # kernels of its own on an x-sharded mesh: the interior launch
+        # and the h-row shell launch, named after the kind they split
+        # (``%pallas_stencil_pair_interior.N`` in a TPU trace), so a
+        # device trace tells them from a single launch
+        register_scope(_name + "_interior")
+        register_scope(_name + "_shell")
 del _name
 
 

@@ -461,16 +461,12 @@ class FiniteDifferencer:
             from pystella_tpu.ops.pallas_stencil import (
                 OverlapStreamingStencil)
             decomp = self.decomp
-            ov = None
-            if self.overlap and py == 1:
-                # x-sharded windows admit the interior/shell launch
-                # split (y shells have no legal sublane blocking);
-                # infeasible shapes keep the single launch
-                try:
-                    ov = OverlapStreamingStencil(st, self.h)
-                except ValueError as err:
-                    logger.info("pallas halo overlap infeasible for %s "
-                                "(%s); single launch", global_shape, err)
+            # x-sharded windows admit the interior/shell launch split
+            # (y shells have no legal sublane blocking); the event says
+            # which path the operator takes, and why
+            ov = OverlapStreamingStencil.plan_for(
+                st, self.h, enabled=self.overlap,
+                label=type(self).__name__)
 
             def sharded_fn(x):
                 if ov is not None:

@@ -246,9 +246,13 @@ class FusedScalarStepper(_step.Stepper):
 
     def _emit_block_choice(self, kind, st, source):
         """The record of what a kernel build chose: ``source`` is
-        ``"explicit"`` (pinned by the caller) or ``"heuristic"``
-        (``choose_blocks``); ``halo`` is where its (x, y) edges come
-        from, ``"wrap"`` or (a sharded axis) ``"slab"``; ``in_place``
+        ``"explicit"`` (pinned by the caller), ``"heuristic"``
+        (``choose_blocks``) or ``"split"`` (the interior or shell
+        kernel of the overlap split, ``kernel`` ``<kind>_interior`` /
+        ``<kind>_shell``: the full-block kernel's ``by``, ``bx = h``
+        for a shell and ``choose_blocks``' for the interior); ``halo``
+        is where its (x, y) edges come from, ``"wrap"``, (a sharded
+        axis) ``"slab"`` or (the split's kernels) ``"padded"``; ``in_place``
         names the extras it writes over (the per-stage protocol's
         ``stage`` kernel under ``donate=True``, else none); ``reread``
         is the modelled real-over-ideal byte ratio of a call at that
@@ -261,7 +265,8 @@ class FusedScalarStepper(_step.Stepper):
         stage's): what the kernel's arithmetic scales with where its
         bytes do not."""
         stages = getattr(st, "stages", 1)
-        fused = 2 if kind in ("pair", "coupled_pair") else stages
+        whole = kind.removesuffix("_interior").removesuffix("_shell")
+        fused = 2 if whole in ("pair", "coupled_pair") else stages
         _events.emit(
             "block_choice", kernel=kind,
             stencil=type(st).__name__,
@@ -465,17 +470,15 @@ class FusedScalarStepper(_step.Stepper):
         scalar_names = st.scalar_names
         from jax.sharding import PartitionSpec as P
 
-        ov = None
-        if self._overlap and self._px > 1 and self._py == 1:
-            # x-sharded stages take the interior/shell launch split
-            # (kernels with sum outputs keep the single launch — the
-            # split would change the deterministic reduction order)
-            try:
-                ov = OverlapStreamingStencil(st, self.h)
-            except ValueError as e:
-                import logging
-                logging.getLogger(__name__).info(
-                    "fused halo overlap infeasible (%s); single launch", e)
+        # x-sharded stages take the interior/shell launch split where
+        # the policy asks for it and the kernel admits it (one with sum
+        # outputs keeps the single launch: the split would change the
+        # deterministic reduction order); the event says which, and why
+        ov = OverlapStreamingStencil.plan_for(
+            st, self.h, enabled=self._overlap, label=type(self).__name__)
+        if ov is not None:
+            for part in (ov.st_interior, ov.st_shell):
+                self._emit_block_choice(part.kind, part, "split")
 
         def body(*flat):
             nw = len(windows)

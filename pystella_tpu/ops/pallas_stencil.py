@@ -59,12 +59,13 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from pystella_tpu.obs import events as _events
 from pystella_tpu.obs import memory as _obs_memory
 from pystella_tpu.obs.scope import (
     in_jax_trace, kernel_scope, trace_scope)
 
 __all__ = ["StreamingStencil", "ResidentStencil", "OverlapStreamingStencil",
-           "Taps", "HY", "LANE",
+           "OverlapInfeasible", "Taps", "HY", "LANE",
            "choose_blocks", "feasible_blocks", "reread", "sharded_halo",
            "lap_from_taps", "grad_from_taps", "VMEM_LIMIT_BYTES",
            "BLOCK_BUDGET_BYTES", "TIER_BUDGET_BYTES"]
@@ -264,17 +265,18 @@ def feasible_blocks(n_comp, lattice_shape, h, itemsize, n_extra, n_out,
     return out
 
 
-def reread(n_comp, n_extra, n_out, by):
+def reread(n_comp, n_extra, n_out, by, x_reads=1.0):
     """Modelled bytes a streaming kernel call really moves over the
     ideal count (every lattice input once, every output once), in
     passes over one lattice array: each of the ``n_comp`` windowed
     components is DMA'd with its ``HY``-row y halos, ``(by + 2 * HY) /
-    by`` times (the x ring reads every row once), the extras and
-    outputs once. What a ``*_roofline`` of ideal bytes has to be
-    multiplied by to say which share of the HBM peak the kernel reaches
-    in the bytes it moves."""
+    by`` times (the x ring reads every row once; a pre-padded
+    ``x_halo`` kernel has no ring and reads every row ``x_reads`` =
+    ``(bx + 2h) / bx`` times), the extras and outputs once. What a
+    ``*_roofline`` of ideal bytes has to be multiplied by to say which
+    share of the HBM peak the kernel reaches in the bytes it moves."""
     ideal = n_comp + n_extra + n_out
-    return (n_comp * (by + 2 * HY) / by + n_extra + n_out) / ideal
+    return (n_comp * x_reads * (by + 2 * HY) / by + n_extra + n_out) / ideal
 
 
 class Taps:
@@ -861,8 +863,9 @@ class StreamingStencil:
     @property
     def reread(self):
         """The call's modelled real-over-ideal bytes (:func:`reread`)
-        at the kernel's own y block."""
-        return reread(*self.n_arrays, self.by)
+        at the kernel's own blocks."""
+        x_reads = (self.bx + 2 * self.wh) / self.bx if self.x_halo else 1.0
+        return reread(*self.n_arrays, self.by, x_reads)
 
     def _unpack_refs(self, refs):
         """``(f_refs, slab_refs, scalar_refs, extra_refs, out_refs, wins,
@@ -1115,12 +1118,14 @@ class StreamingStencil:
 
         return self._pallas_call(kernel, 2 * bxw)
 
-    def with_lattice(self, lattice_shape, bx=None, by=None, padded=False):
+    def with_lattice(self, lattice_shape, bx=None, by=None, padded=False,
+                     kind=None):
         """A new :class:`StreamingStencil` sharing this one's body,
         definitions, dtypes and halo mode, built for a different local
         lattice shape — how :class:`OverlapStreamingStencil` derives the
         interior and shell kernels from the full-block kernel; it asks
-        for them ``padded``: a slab-fed axis becomes a pre-padded one.
+        for them ``padded``: a slab-fed axis becomes a pre-padded one,
+        and under a ``kind`` of their own (this kernel's without one).
         Raises ``ValueError`` when the new shape admits no feasible
         blocking."""
         return StreamingStencil(
@@ -1134,7 +1139,7 @@ class StreamingStencil:
             y_slab=self.y_slab and not padded,
             interpret=self.interpret, sum_defs=self.sum_defs,
             dtypes=self.dtypes, win_halo=self.wh, stages=self.stages,
-            kind=self.kind, in_place=self.in_place)
+            kind=kind or self.kind, in_place=self.in_place)
 
     # -- invocation --------------------------------------------------------
 
@@ -1206,6 +1211,16 @@ class StreamingStencil:
         return out
 
 
+class OverlapInfeasible(ValueError):
+    """Why a kernel keeps its single launch: ``reason`` is the word an
+    ``overlap_plan`` event carries (``sums``, ``y_sharded``,
+    ``unsharded``, ``thin``, ``blocking``)."""
+
+    def __init__(self, reason, message):
+        super().__init__(message)
+        self.reason = reason
+
+
 class OverlapStreamingStencil:
     """Interior + x-shell split of a streaming stencil kernel for
     communication/computation overlap on x-sharded lattices.
@@ -1226,8 +1241,16 @@ class OverlapStreamingStencil:
     the padded launch: every output element sees identical tap offsets
     and per-element arithmetic (blocking never enters the math).
 
-    Feasibility (``ValueError`` otherwise — callers fall back to the
-    single launch): x-sharded windows only (``x_slab`` or ``x_halo``
+    The two kernels carry kinds of their own, ``<kind>_interior`` and
+    ``<kind>_shell`` (``interior`` / ``shell`` for a kernel of no
+    stated kind), so a TPU trace names their launches
+    ``%pallas_stencil_<kind>_interior.N`` / ``..._shell.N`` and tells
+    them from a single launch of the kind; :attr:`plan` says what was
+    built.
+
+    Feasibility (:class:`OverlapInfeasible`, a ``ValueError``,
+    otherwise — callers fall back to the single launch, :meth:`plan_for`
+    does it for them and says so in an event): x-sharded windows only (``x_slab`` or ``x_halo``
     set, y whole — an h-thin y shell has no legal sublane blocking; the
     three launches are pre-padded ``x_halo`` kernels either way),
     no ``sum_defs`` (the region split would change the deterministic
@@ -1237,24 +1260,98 @@ class OverlapStreamingStencil:
     def __init__(self, st, h):
         from pystella_tpu.parallel.overlap import MIN_INTERIOR_FACTOR
         if st.sum_defs:
-            raise ValueError(
+            raise OverlapInfeasible(
+                "sums",
                 "sum outputs: the interior/shell split would change the "
                 "deterministic reduction order")
-        if not (st.x_halo or st.x_slab) or st.y_halo or st.y_slab:
-            raise ValueError(
+        if st.y_halo or st.y_slab:
+            raise OverlapInfeasible(
+                "y_sharded",
+                "overlap split supports x-sharded windows only (an "
+                "h-thin y shell has no legal sublane blocking)")
+        if not (st.x_halo or st.x_slab):
+            raise OverlapInfeasible(
+                "unsharded",
                 "overlap split supports x-sharded windows only")
         X, Y, Z = st.lattice_shape
         self.h = int(h)
         if X < MIN_INTERIOR_FACTOR * self.h:
-            raise ValueError(
+            raise OverlapInfeasible(
+                "thin",
                 f"local x extent {X} thinner than "
                 f"{MIN_INTERIOR_FACTOR}*h: no interior to hide the "
                 "transfer behind")
         self.st = st
-        self.st_interior = st.with_lattice((X - 2 * self.h, Y, Z),
-                                           by=st.by, padded=True)
-        self.st_shell = st.with_lattice((self.h, Y, Z), bx=self.h,
-                                        by=st.by, padded=True)
+
+        def part(name):
+            return name if st.kind is None else f"{st.kind}_{name}"
+
+        try:
+            self.st_interior = st.with_lattice(
+                (X - 2 * self.h, Y, Z), by=st.by, padded=True,
+                kind=part("interior"))
+            self.st_shell = st.with_lattice(
+                (self.h, Y, Z), bx=self.h, by=st.by, padded=True,
+                kind=part("shell"))
+        except ValueError as e:
+            raise OverlapInfeasible("blocking", str(e)) from e
+
+    @classmethod
+    def plan_for(cls, st, h, enabled=True, label=None):
+        """The split of the full-block kernel ``st`` on a sharded mesh
+        where the policy asks for it (``enabled``:
+        :func:`pystella_tpu.parallel.overlap.enabled`) and the kernel
+        admits it, else ``None`` (the caller keeps the single launch);
+        either way one ``overlap_plan`` event says which, for ``label``
+        (the consumer's class): ``path`` ``"split"`` with :attr:`plan`'s
+        fields, or ``"single"`` with the ``reason`` (``off``: the
+        policy; ``sums``, ``y_sharded``, ``thin``, ``blocking``:
+        :class:`OverlapInfeasible`'s)."""
+        split, plan = None, {"path": "single", "reason": "off"}
+        if enabled:
+            try:
+                split = cls(st, h)
+                plan = split.plan
+            except OverlapInfeasible as e:
+                plan["reason"] = e.reason
+        _events.emit("overlap_plan", kernel=st.kind,
+                     local_shape=list(st.lattice_shape), label=label,
+                     **plan)
+        return split
+
+    @property
+    def stitch_bytes(self):
+        """Ideal bytes, read plus written, of the copies one call places
+        round its three launches: every lattice extra sliced to the
+        interior's and the two shells' rows, every window's two shell
+        inputs concatenated from a slab and ``2h`` local rows, every
+        output concatenated from its three pieces. XLA may fuse or
+        elide some of them; a kernel writes none of it."""
+        st, h = self.st, self.h
+        X, Y, Z = st.lattice_shape
+
+        def rows(name, lead, n):
+            comps = int(np.prod(lead)) if lead else 1
+            return comps * n * Y * Z * st.dtypes.get(name, st.dtype).itemsize
+
+        copied = sum(rows(n, lead, X) for n, lead in st.extra_defs.items())
+        copied += sum(rows(n, (c,), 2 * 3 * h)
+                      for n, c in st.win_defs.items())
+        copied += sum(rows(n, lead, X) for n, lead in st.out_defs.items())
+        return 2 * copied
+
+    @property
+    def plan(self):
+        """What the split built, as an ``overlap_plan`` event carries
+        it: per kernel the lattice, blocking, grid and modelled
+        ``reread``, and :attr:`stitch_bytes`."""
+        def built(st):
+            return {"kernel": st.kind, "lattice": list(st.lattice_shape),
+                    "bx": st.bx, "by": st.by, "grid": list(st.grid),
+                    "reread": st.reread}
+        return {"path": "split", "interior": built(self.st_interior),
+                "shell": built(self.st_shell),
+                "stitch_bytes": self.stitch_bytes}
 
     @staticmethod
     def _slice_x(tree, s, e):
@@ -1286,8 +1383,9 @@ class OverlapStreamingStencil:
         with trace_scope("halo_overlap"):
             # slab ppermutes first: program order hands the scheduler
             # the dependence-free interior launch to hide them behind
-            slabs = {n: decomp.exchange_slabs(a, 0, h)
-                     for n, a in wins.items()}
+            with trace_scope("halo_overlap_exchange"):
+                slabs = {n: decomp.exchange_slabs(a, 0, h)
+                         for n, a in wins.items()}
             with trace_scope("halo_overlap_interior"):
                 int_out = self.st_interior(
                     f, scalars=scalars,

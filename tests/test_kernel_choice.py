@@ -57,6 +57,15 @@ class _watch_events:
 
 @functools.lru_cache(maxsize=None)
 def _built(name):
+    """:func:`_built_cell` under the halo-overlap policy's own default,
+    which is what a cell runs under (the suite pins the policy off in
+    the environment): on for a sharded mesh."""
+    with mock.patch.dict(os.environ):
+        os.environ.pop("PYSTELLA_HALO_OVERLAP", None)
+        return _built_cell(name)
+
+
+def _built_cell(name):
     """What a cell of configuration ``name`` builds, as its family does
     (``benchmark/families/*.py``): the stepper with ``tableau, dtype, dt,
     donate`` and nothing else, the kernels the coupled chunk adds, and
@@ -206,6 +215,27 @@ _CELL_KERNELS = [
     ("preheat-h4-f32", "energy", 0, (4, 128), (4, 128), "explicit"),
     ("preheat-h4-f32", "lap", 0, (4, 256), (2, 128), "heuristic"),
     ("preheat-h4-f32", "grad", 0, (4, 256), (2, 128), "heuristic"),
+    # 512^3 per chip on the slab mesh (4, 1, 1) (PR 42): the one-chip
+    # kernels with x slabs, and beside each kernel without sums the two
+    # the overlap split launches in its place: the pre-padded interior
+    # over rows 2 ... 510 and the h-row shell, both at the whole
+    # kernel's y block; the coupled pairs emit sums and stay whole
+    ("preheat-mesh4x-f32", "stage", 0, (2, 256), (2, 256), "heuristic"),
+    ("preheat-mesh4x-f32", "stage_interior", 0, (2, 256), (2, 254),
+     "split"),
+    ("preheat-mesh4x-f32", "stage_shell", 0, (2, 256), (2, 1), "split"),
+    ("preheat-mesh4x-f32", "pair", 0, (2, 128), (4, 256), "heuristic"),
+    ("preheat-mesh4x-f32", "pair_interior", 0, (2, 128), (4, 254),
+     "split"),
+    ("preheat-mesh4x-f32", "pair_shell", 0, (2, 128), (4, 1), "split"),
+    ("preheat-mesh4x-f32", "coupled_pair", 0, (2, 128), (4, 256),
+     "heuristic"),
+    ("preheat-mesh4x-f32", "coupled_pair", 1, (2, 128), (4, 256),
+     "heuristic"),
+    ("preheat-mesh4x-f32", "lap", 0, (2, 256), (2, 256), "heuristic"),
+    ("preheat-mesh4x-f32", "lap_interior", 0, (2, 256), (2, 254),
+     "explicit"),
+    ("preheat-mesh4x-f32", "lap_shell", 0, (2, 256), (2, 1), "explicit"),
 ]
 
 
@@ -253,14 +283,19 @@ def test_cells_get_the_kernels_the_ledger_measured(config, kernel, nth,
     assert d["source"] == source
     # where the window's (x, y) edges come from follows from the mesh
     # the stepper or operator was built on, and from nothing else
-    sharded = config == "preheat-mesh4-f32"
-    assert d["halo"] == (["slab", "slab"] if sharded else ["wrap", "wrap"])
+    if kernel.endswith(("_interior", "_shell")):
+        assert d["halo"] == ["padded", "wrap"]
+    else:
+        assert d["halo"] == {"preheat-mesh4-f32": ["slab", "slab"],
+                             "preheat-mesh4x-f32": ["slab", "wrap"]}.get(
+                                 config, ["wrap", "wrap"])
     # the per-stage protocol's kernel writes its extras in place (the
     # families build with donate=True); no kernel of a chunk, and no
     # operator, does (PR 37)
     stage_extras = ["dfdt", "kf", "kdfdt"] + (
         ["dhijdt", "khij", "kdhijdt"] if config == "preheat-gw-f32" else [])
-    assert d["in_place"] == (stage_extras if kernel == "stage" else [])
+    assert d["in_place"] == (
+        stage_extras if kernel.startswith("stage") else [])
 
 
 # -- the budget, the tier figure and the re-read ---------------------------

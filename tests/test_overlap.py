@@ -363,3 +363,224 @@ def test_gate_warns_on_flag_mismatch():
 if __name__ == "__main__":
     import sys
     sys.exit(pytest.main([__file__, "-v"]))
+
+
+# -- the Pallas split says what it built (PR 42) ---------------------------
+
+def _plan_case(make_decomp, case):
+    """Build one sharded kernel consumer; returns the events seen."""
+    from test_kernel_choice import _watch_events
+    proc, grid, overlap = {
+        "split": ((2, 1, 1), (16, 16, 16), True),
+        "sums": ((2, 1, 1), (16, 16, 16), True),
+        "y_sharded": ((2, 2, 1), (16, 16, 16), True),
+        "thin": ((2, 1, 1), (8, 16, 16), True),
+        "off": ((2, 1, 1), (16, 16, 16), False),
+        "derivs": ((2, 1, 1), (16, 16, 16), True),
+        "multigrid": ((2, 1, 1), (16, 16, 16), True),
+    }[case]
+    decomp = make_decomp(proc)
+    with _watch_events() as seen:
+        if case == "derivs":
+            fd = ps.FiniteDifferencer(decomp, 2, 0.3, mode="pallas",
+                                      overlap=overlap)
+            fd._pallas_op("lap", 2, np.dtype("float32"), False, grid)
+        elif case == "multigrid":
+            from pystella_tpu.multigrid.relax import (
+                LevelSpec, NewtonIterator)
+            solver = NewtonIterator(
+                decomp, {ps.Field("f"): (ps.Field("f"), ps.Field("rho"))},
+                halo_shape=1, dtype=np.float32, smoother="pallas",
+                overlap=overlap, fixed_parameters=dict(omega=1 / 2))
+            assert solver._pallas_level(
+                "smooth", LevelSpec(grid, (0.3,) * 3, True), decomp,
+                np.dtype("float32"), ())
+        else:
+            stepper = _fused_pair(decomp, grid, overlap, np.float32(0.01))
+            if case == "sums":
+                stepper._ensure_energy_call()
+    return seen
+
+
+@pytest.mark.parametrize("case, kernel, reason", [
+    ("split", "pair", None), ("derivs", "lap", None),
+    ("multigrid", "mg_smooth", None), ("sums", "energy", "sums"),
+    ("y_sharded", "pair", "y_sharded"), ("thin", "pair", "thin"),
+    ("off", "pair", "off")])
+def test_overlap_plan_event(make_decomp, case, kernel, reason):
+    """Every sharded kernel build says which launch it takes: one
+    ``overlap_plan`` event a kernel, ``path: split`` with the interior's
+    and the shell's lattice, blocking, grid, modelled re-read and the
+    ideal bytes of the copies round them, or ``path: single`` with the
+    reason (what used to be a ``logging.info`` line, or nothing)."""
+    seen = _plan_case(make_decomp, case)
+    plans = {d["kernel"]: d for d in seen.of("overlap_plan")}
+    d = plans[kernel]
+    built = {b["kernel"]: b for b in seen.of("block_choice")}
+    if reason is not None:
+        assert (d["path"], d["reason"]) == ("single", reason)
+        assert "interior" not in d and "stitch_bytes" not in d
+        assert not any(k.endswith(("_interior", "_shell")) for k in built
+                       if k.startswith(kernel))
+        return
+    assert d["path"] == "split" and "reason" not in d
+    X, Y, Z = d["local_shape"]
+    h = 1 if case == "multigrid" else 2
+    inner, shell = d["interior"], d["shell"]
+    assert (inner["kernel"], shell["kernel"]) == (kernel + "_interior",
+                                                  kernel + "_shell")
+    assert inner["lattice"] == [X - 2 * h, Y, Z]
+    assert shell["lattice"] == [h, Y, Z] and shell["bx"] == h
+    assert inner["by"] == shell["by"]
+    assert inner["grid"] == [Y // inner["by"], (X - 2 * h) // inner["bx"]]
+    assert shell["grid"] == [Y // shell["by"], 1]
+    # a pre-padded kernel has no ring: every window row is read
+    # (bx + 2h) / bx times, and the event's re-read holds it
+    assert inner["reread"] > (inner["bx"] + 2 * h) / inner["bx"] * 0.3
+    assert d["stitch_bytes"] > 0
+    if case != "split":
+        return
+    # the fused steppers add a block_choice a kernel of the split
+    for part, plan in ((kernel + "_interior", inner),
+                       (kernel + "_shell", shell)):
+        b = built[part]
+        assert (b["bx"], b["by"], list(b["grid"])) == (
+            plan["bx"], plan["by"], plan["grid"])
+        assert b["halo"] == ["padded", "wrap"] and b["source"] == "split"
+        assert b["taps"] == 26 and b["reread"] == plan["reread"]
+    # the pair call's copies by hand: the extra kdfdt, four outputs,
+    # and per window two shell inputs of 3h rows; read and written
+    rows = (2 * X) + 4 * (2 * X) + 3 * (2 * 2 * 3 * h)
+    assert d["stitch_bytes"] == 2 * rows * Y * Z * 4
+
+
+@pytest.mark.parametrize("proc_shape", [(2, 1, 1)], indirect=True)
+def test_split_kernels_are_named_in_the_lowering(decomp, proc_shape):
+    """What a device trace will tell apart: under the split a step's
+    pair and stage kernels lower under ``pallas_stencil_<kind>_interior``
+    and ``..._shell`` and the slab ``ppermute``s under
+    ``halo_overlap_exchange``; the single launch keeps the bare kind."""
+    grid = (16, 16, 16)
+    dt = np.float32(0.01)
+    state = {k: decomp.shard(0.1 * _field(grid, seed=21, outer=(2,)))
+             for k in ("f", "dfdt")}
+    args = {"a": np.float32(1.0), "hubble": np.float32(0.1)}
+    split = [f"pallas_stencil_{kind}_{part}" for kind in ("pair", "stage")
+             for part in ("interior", "shell")] + ["halo_overlap_exchange"]
+    lowered = _fused_pair(decomp, grid, True, dt)._jit_step.lower(
+        dict(state), 0.0, dt, args)
+    for scope in split + ["halo_overlap_interior", "halo_overlap_shells"]:
+        assert obs.has_scope(lowered, scope), scope
+    lowered = _fused_pair(decomp, grid, False, dt)._jit_step.lower(
+        dict(state), 0.0, dt, args)
+    for scope in split:
+        assert not obs.has_scope(lowered, scope), scope
+    assert obs.has_scope(lowered, "pallas_stencil_pair")
+
+
+# -- the split against a plain RK54 on the gathered lattice ----------------
+
+#: Carpenter & Kennedy's 2N-storage RK54 and the centred second
+#: differences of radius 2 and 4, typed in: nothing of the program
+_RK54_A = (0.0, -567301805773 / 1357537059087,
+           -2404267990393 / 2016746695238,
+           -3550918686646 / 2091501179385,
+           -1275806237668 / 842570457699)
+_RK54_B = (1432997174477 / 9575080441755, 5161836677717 / 13612068292357,
+           1720146321549 / 2090206949498, 3134564353537 / 4481467310338,
+           2277821191437 / 14882151754819)
+_LAP_ROWS = {2: (-5 / 2, 4 / 3, -1 / 12),
+             4: (-205 / 72, 8 / 5, -1 / 5, 8 / 315, -1 / 560)}
+
+
+def _plain_rk54(f, dfdt, nsteps, dt, dx, h, a, hubble, carry_dtype=None):
+    """``jax.numpy`` on whole arrays: periodic shifts by ``jnp.roll``,
+    float32 throughout, the RK registers stored in ``carry_dtype``."""
+    import jax.numpy as jnp
+    f32 = jnp.float32
+    carry = carry_dtype or f32
+    rows = [f32(c / dx**2) for c in _LAP_ROWS[h]]
+    dt, a, hubble = f32(dt), f32(a), f32(hubble)
+    f, dfdt = jnp.asarray(f, f32), jnp.asarray(dfdt, f32)
+    kf = kdf = jnp.zeros_like(f).astype(carry)
+    for _ in range(nsteps):
+        for A, B in zip(_RK54_A, _RK54_B):
+            lap = 3 * rows[0] * f
+            for s in range(1, h + 1):
+                for axis in (1, 2, 3):
+                    lap = lap + rows[s] * (jnp.roll(f, s, axis)
+                                           + jnp.roll(f, -s, axis))
+            dV = jnp.stack([f[0] + f32(0.25) * f[0] * f[1]**2,
+                            f32(0.25) * f[0]**2 * f[1]])
+            kf = (f32(A) * kf.astype(f32) + dt * dfdt).astype(carry)
+            kdf = (f32(A) * kdf.astype(f32) + dt * (
+                lap - 2 * hubble * dfdt - a * a * dV)).astype(carry)
+            f = f + f32(B) * kf.astype(f32)
+            dfdt = dfdt + f32(B) * kdf.astype(f32)
+    return {"f": np.asarray(f), "dfdt": np.asarray(dfdt)}
+
+
+def _gap(got, ref):
+    """The benchmark's ``field_gap``: per array the largest difference
+    over the array's largest value; the worst array."""
+    return max(float(np.max(np.abs(np.asarray(got[k]) - ref[k]))
+                     / np.max(np.abs(ref[k]))) for k in ref)
+
+
+@pytest.mark.parametrize("h", [2, 4])
+def test_multi_step_split_matches_plain_rk54(make_decomp, h):
+    """``multi_step`` on the slab decomposition ``(4, 1, 1)`` with the
+    split ON (the suite's default is off; a run's is on), from seeded
+    random fields: against a plain ``jax.numpy`` RK54 on the gathered
+    lattice, and against the single launch.
+
+    The tolerance, 1e-5 of an array's largest value: both sides are
+    float32 and differ by the order of their sums (measured here 1.7e-7
+    at h = 2 and 1.5e-7 at h = 4 after two steps); the same reference
+    with its RK registers in bfloat16 reads 6.6e-4 and 6.3e-4, which
+    the last assertion holds well over the tolerance, so a run that
+    narrowed them fails.
+
+    The two launches' every output element sees the same taps and the
+    same arithmetic; the stage and one-step comparisons above hold them
+    bit for bit. Over ten pair calls on this lattice a dozen values of
+    a quarter of a million differ by one float32 ulp: in interpret mode
+    XLA:CPU compiles each kernel's body for its own block shapes and
+    contracts multiply-adds differently. Held here to one ulp of the
+    array's largest value at under a thousandth of the sites; on the
+    chip ``chip_smoke.py``'s x-only leg holds Mosaic's two paths bit
+    for bit at 512**3 a chip."""
+    import jax.numpy as jnp
+    decomp = make_decomp((4, 1, 1))
+    grid, dx, dt, nsteps = (64, 16, 128), 0.3, np.float32(0.01), 2
+    host = {k: 0.1 * _field(grid, seed=s, outer=(2,))
+            for k, s in (("f", 42), ("dfdt", 43))}
+    args = {"a": np.float32(1.0), "hubble": np.float32(0.1)}
+
+    def potential(f):
+        return 0.5 * f[0]**2 + 0.125 * f[0]**2 * f[1]**2
+
+    def run(overlap):
+        stepper = ps.FusedScalarStepper(
+            ps.ScalarSector(2, potential=potential), decomp, grid, dx, h,
+            dtype=np.float32, dt=dt, overlap=overlap)
+        state = {k: decomp.shard(v) for k, v in host.items()}
+        out = stepper.multi_step(state, nsteps, 0.0, dt, args)
+        return {k: np.asarray(v) for k, v in out.items()}
+
+    from test_kernel_choice import _watch_events
+    with _watch_events() as seen:
+        split = run(True)
+    plans = {d["kernel"]: d for d in seen.of("overlap_plan")}
+    assert plans["pair"]["path"] == "split"
+    assert plans["pair"]["interior"]["lattice"] == [16 - 2 * h, 16, 128]
+    single = run(False)
+    for k in split:
+        off = np.abs(split[k] - single[k])
+        assert off.max() <= 2.0**-23 * np.abs(single[k]).max(), k
+        assert np.count_nonzero(off) < 1e-3 * off.size, k
+    ref = _plain_rk54(host["f"], host["dfdt"], nsteps, dt, dx, h, 1.0, 0.1)
+    assert _gap(split, ref) < 1e-5, _gap(split, ref)
+    narrowed = _plain_rk54(host["f"], host["dfdt"], nsteps, dt, dx, h,
+                           1.0, 0.1, carry_dtype=jnp.bfloat16)
+    assert _gap(narrowed, ref) > 1e-4, _gap(narrowed, ref)

@@ -228,6 +228,126 @@ def test_coupled_chunk_kernels_are_named_by_kind(v5e):
                      "pallas_stencil_energy"}, sorted(kinds)
 
 
+#: the slab cell's own lattice (``preheat-mesh4x-f32``): 512**3 a chip
+#: on ``(4, 1, 1)``, the one mesh on which the overlap split runs
+SLAB = ((4, 1, 1), (2048, 512, 512))
+#: compiled ``multi_step(4)`` of that cell per ``overlap``: HLO text,
+#: the compiler's memory account, the ``overlap_plan`` events
+_SLAB_CHUNK = {}
+
+
+def _slab_chunk(v5e, monkeypatch, overlap):
+    """``jit_multi_step_4`` of ``FusedScalarStepper(donate=True)`` on the
+    slab mesh, as the ``fixed_bg`` loop body dispatches it; ``overlap``
+    ``None`` is the run's own default (the suite pins it off in the
+    environment, so the variable is taken away)."""
+    from test_kernel_choice import _watch_events
+    if overlap not in _SLAB_CHUNK:
+        monkeypatch.delenv("PYSTELLA_HALO_OVERLAP", raising=False)
+        proc_shape, grid = SLAB
+        decomp = ps.DomainDecomposition(proc_shape, devices=v5e[:4])
+        mphi, gsq = 1.20e-6, 2.5e-7
+
+        def potential(f):
+            return (mphi**2 / 2 * f[0]**2
+                    + gsq / 2 * f[0]**2 * f[1]**2) / mphi**2
+
+        dx = (5.0 / 512,) * 3
+        with _watch_events() as seen:
+            stepper = ps.FusedScalarStepper(
+                ps.ScalarSector(2, potential=potential), decomp, grid, dx,
+                2, dtype=jnp.float32, dt=np.float32(0.1 * dx[0]),
+                donate=True, interpret=False, overlap=overlap)
+        state = {k: jax.ShapeDtypeStruct((2,) + grid, jnp.float32,
+                                         sharding=decomp.sharding(1))
+                 for k in ("f", "dfdt")}
+        compiled = stepper._multi_jit(4).trace(
+            state, t=np.float32(0.0), dt=stepper.dt,
+            rhs_args={"a": np.float32(1.0), "hubble": np.float32(0.5)},
+            rhs_seq={}).lower(lowering_platforms=("tpu",)).compile()
+        mem = compiled.memory_analysis()
+        _SLAB_CHUNK[overlap] = (
+            compiled.as_text(),
+            (mem.argument_size_in_bytes, mem.temp_size_in_bytes),
+            {d["kernel"]: d for d in seen.of("overlap_plan")})
+    return _SLAB_CHUNK[overlap]
+
+
+@pytest.mark.parametrize("overlap", [
+    None, pytest.param(False, marks=pytest.mark.slow)],
+    ids=["split", "single"])
+def test_slab_chunk_compiles_and_what_it_holds(v5e, monkeypatch, overlap):
+    """``preheat-mesh4x-f32.fixed-bg``'s 4-step chunk at the cell's own
+    lattice, through Mosaic and XLA:TPU for the v5e, on both paths: the
+    default (``auto`` is ON for a sharded mesh, so the ten pair calls
+    are thirty launches: the pre-padded interior at lattice (508, 512,
+    512), ``bx`` 2, and two shells at (2, 512, 512), ``bx = h``, under
+    the 100-MB VMEM limit) and ``overlap=False`` (ten slab-fed single
+    launches). Neither had been compiled for the chip before PR 42.
+    Kept: the compiler's account of a chip's share. The state is 2.15
+    GB of arguments on both; the temporaries are 11.67 GB with the
+    split and 10.74 GB without it, so the default fits a 16-GB chip
+    with 1.9 GB to spare and the split's price in memory is 0.93 GB:
+    XLA materialises each output's three pieces and their
+    concatenation (``pad_maximum_fusion``, four lattice arrays a pair
+    call) and the extra sliced to the interior's rows."""
+    import re
+    hlo, (arguments, temporaries), plans = _slab_chunk(v5e, monkeypatch,
+                                                       overlap)
+    assert hlo.startswith("HloModule jit_multi_step_4"), hlo[:60]
+    kinds = [re.sub(r"\.\d+$", "", n) for n in _custom_call_names(hlo)]
+    one = 2 * 512**3 * 4            # one two-field array on a chip
+    assert arguments == pytest.approx(2 * one, rel=1e-3)
+    padded = re.findall(r"= f32\[2,512,512,512\]\S* fusion\([^\n]*"
+                        r"calls=%fused_computation[\w.]*", hlo)
+    if overlap is None:
+        assert plans["pair"]["path"] == "split"
+        assert plans["pair"]["interior"]["lattice"] == [508, 512, 512]
+        assert (plans["pair"]["interior"]["bx"],
+                plans["pair"]["shell"]["bx"]) == (2, 2)
+        assert plans["pair"]["interior"]["by"] == 128
+        # one array's worth over the ideal: windows read 3 x 1.125 times
+        assert plans["pair"]["interior"]["reread"] == pytest.approx(
+            (6 * 3 * 1.125 + 2 + 8) / 16)
+        assert plans["pair"]["stitch_bytes"] == pytest.approx(
+            10.9e9, rel=0.01)
+        assert kinds.count("pallas_stencil_pair_interior") == 10, kinds
+        assert kinds.count("pallas_stencil_pair_shell") == 20, kinds
+        assert 11.2e9 < temporaries < 12.2e9, temporaries
+        # the stitch is really placed: lattice-sized fusions over three
+        # pieces, three or four a pair call
+        assert len(padded) >= 28, len(padded)
+    else:
+        assert (plans["pair"]["path"], plans["pair"]["reason"]) == (
+            "single", "off")
+        assert kinds.count("pallas_stencil_pair") == 10, kinds
+        assert 10.3e9 < temporaries < 11.2e9, temporaries
+    assert len(set(kinds)) == (2 if overlap is None else 1), set(kinds)
+    assert arguments + temporaries < 15.75 * 2**30
+
+
+def test_slab_chunk_kernels_are_named_by_kind(v5e, monkeypatch):
+    """What a TPU trace will call the slab cell's kernels: every Mosaic
+    custom call of the compiled ``(4, 1, 1)`` chunk is
+    ``%pallas_stencil_pair_interior.N`` or ``%pallas_stencil_pair_shell.N``,
+    none a plain ``%pallas_stencil_pair.N`` (which is what a single
+    launch is, and what all three were before PR 42): so
+    ``benchmark/kernels/pallas_stencil_pair_interior.json`` and
+    ``..._pair_shell.json`` take them before ``pallas_stencil_pair.json``
+    does, and ``pair_roofline`` reads nothing in that cell."""
+    import re
+    hlo, _, _ = _slab_chunk(v5e, monkeypatch, None)
+    names = _custom_call_names(hlo)
+    assert len(names) == 30
+    kinds = {re.sub(r"\.\d+$", "", n) for n in names}
+    assert kinds == {"pallas_stencil_pair_interior",
+                     "pallas_stencil_pair_shell"}, sorted(kinds)
+    # and under the scopes a host-side profile folds them by
+    for scope in ("halo_overlap_exchange", "halo_overlap_interior",
+                  "halo_overlap_shells"):
+        assert scope in hlo, scope
+
+
 @pytest.mark.slow
 def test_derivs_kernels_are_named_by_kind(v5e, monkeypatch):
     """``FiniteDifferencer``'s lap and grad (``%tpu_custom_call.N`` in
