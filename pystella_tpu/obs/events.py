@@ -203,8 +203,12 @@ for _name, _help in (
     ("overlap_plan", "a sharded stencil kernel was built: which launch "
                      "it takes on the mesh, path 'split' (the "
                      "interior/shell halo-overlap split: the two "
-                     "kernels' lattice, bx/by/grid, reread, and the "
-                     "stitch_bytes of the copies round them) or "
+                     "kernels' lattice, bx/by/grid, halo (the "
+                     "interior's x edges: 'inset', the ring over the "
+                     "raw shard; the shells': 'padded'), reread, "
+                     "stitch ('in_place': the shells' rows go into "
+                     "the interior's outputs) and the stitch_bytes "
+                     "of the copies still round them) or "
                      "'single' with the reason ('off', 'sums', "
                      "'y_sharded', 'thin', 'blocking')"),
     # -- fused kernel tiers --------------------------------------------------
@@ -213,8 +217,9 @@ for _name, _help in (
                      "taps: shifted values a site and component's "
                      "derivatives take, 6h+1 a fused stage, "
                      "halo: each of (x, y) "
-                     "'wrap', on a sharded axis 'slab', or 'padded' "
-                     "in the overlap split's kernels, in_place: "
+                     "'wrap', on a sharded axis 'slab', or in the "
+                     "overlap split's kernels 'inset' (x, the "
+                     "interior) and 'padded' (the shells), in_place: "
                      "the extras it writes over, reread: modelled "
                      "bytes moved over ideal bytes at that by + source: "
                      "'explicit' "

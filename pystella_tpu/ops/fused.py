@@ -249,10 +249,12 @@ class FusedScalarStepper(_step.Stepper):
         ``"explicit"`` (pinned by the caller), ``"heuristic"``
         (``choose_blocks``) or ``"split"`` (the interior or shell
         kernel of the overlap split, ``kernel`` ``<kind>_interior`` /
-        ``<kind>_shell``: the full-block kernel's ``by``, ``bx = h``
-        for a shell and ``choose_blocks``' for the interior); ``halo``
-        is where its (x, y) edges come from, ``"wrap"``, (a sharded
-        axis) ``"slab"`` or (the split's kernels) ``"padded"``; ``in_place``
+        ``<kind>_shell``: the full-block kernel's ``by`` and ``bx =
+        h`` for both); ``halo`` is where its (x, y) edges come from,
+        ``"wrap"``, (a sharded axis) ``"slab"`` or, in x, the split's
+        ``"inset"`` (the interior: the ring over the raw shard, the
+        first and last x-block only read) and ``"padded"`` (the
+        shells); ``in_place``
         names the extras it writes over (the per-stage protocol's
         ``stage`` kernel under ``donate=True``, else none); ``reread``
         is the modelled real-over-ideal byte ratio of a call at that

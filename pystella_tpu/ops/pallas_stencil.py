@@ -39,10 +39,17 @@ Design (chosen by microbenchmark on TPU v5e):
   (:meth:`StreamingStencil.halo_slabs`); nothing the size of a window
   is copied. An axis that is not sharded wraps locally, per axis.
 - ``x_halo=True`` / ``y_halo=True`` is the older sharded variant, kept
-  for :class:`OverlapStreamingStencil` and ``multigrid/relax.py``: the
-  input is a *pre-padded copy* of the window (``h`` rows in x, ``HY`` in
-  y, ``pad_with_halos``), and with ``x_halo`` each program DMAs its own
-  ``bx + 2h`` rows (no ring: ``1 + 2h/bx`` reads of every row).
+  for the two ``h``-row shells of :class:`OverlapStreamingStencil` and
+  for ``multigrid/relax.py``: the input is a *pre-padded copy* of the
+  window (``h`` rows in x, ``HY`` in y, ``pad_with_halos``), and with
+  ``x_halo`` each program DMAs its own ``bx + 2h`` rows (no ring:
+  ``1 + 2h/bx`` reads of every row).
+- ``x_inset=True`` is the interior of that split: the same ring over
+  the raw shard, its grid short of the first and the last x-block, so
+  no edge is ever taken (ring blocks ``0`` and ``nbx - 1`` are in the
+  shard) and the launch waits for no collective. Its outputs are still
+  the full local lattice; the two edge blocks' rows are left unwritten
+  for the shells' rows to be put into.
 
 The kernel body is arbitrary traced JAX: finite-difference taps, fused
 Runge-Kutta stage updates (see :mod:`pystella_tpu.ops.fused`), multigrid
@@ -130,7 +137,8 @@ def _compiler_params(interpret):
 def sharded_halo(h, px, py):
     """Halo widths for ``pad_with_halos`` feeding the PRE-PADDED
     (``x_halo`` / ``y_halo``) window kernels, which the multigrid
-    smoothers and :class:`OverlapStreamingStencil` still build; the
+    smoothers and :class:`OverlapStreamingStencil`'s shells still
+    build; the
     fused steppers and ``FiniteDifferencer`` hand a sharded kernel its
     shard and thin slabs instead (``x_slab`` / ``y_slab``,
     :meth:`StreamingStencil.halo_slabs`), whose y slab keeps the same
@@ -577,6 +585,18 @@ class StreamingStencil:
     :arg x_halo: the older sharded variant: the input x-axis is a copy
         pre-padded with ``h`` halo rows, and every program DMAs its own
         ``bx + 2h`` rows (no ring). Not combined with the slab modes.
+        Built by the overlap split's shells and ``multigrid/relax.py``.
+    :arg x_inset: the overlap split's interior
+        (:class:`OverlapStreamingStencil` asks for it through
+        :meth:`with_lattice`; nothing else does): the ring kernel over
+        grid ``(Y // by, X // bx - 2)``, program ``i`` doing x-block
+        ``i + 1``. Blocks ``0`` and ``X // bx - 1`` are only ever read,
+        as the ring's first and last, so x neither wraps nor takes a
+        slab, every window row is DMA'd once, and the extras and
+        outputs stay arrays of the whole ``(X, Y, Z)`` whose two edge
+        blocks no program touches: an output's rows there are
+        unwritten (an ``in_place`` output keeps its extra's). Not
+        combined with ``x_slab`` / ``x_halo``.
     :arg y_halo: the input y-axis is pre-padded with ``HY`` (8) halo rows
         per side (sharded y): each y-block window is one contiguous
         8-aligned DMA piece from the padded input, no in-kernel wrap.
@@ -622,7 +642,7 @@ class StreamingStencil:
                  bx=None, by=None, x_halo=False, y_halo=False,
                  interpret=None, sum_defs=None, dtypes=None,
                  win_halo=None, stages=1, kind=None, x_slab=False,
-                 y_slab=False, in_place=()):
+                 y_slab=False, in_place=(), x_inset=False):
         if h > HY:
             raise ValueError(f"stencil radius {h} exceeds aligned halo {HY}")
         #: what the kernel is (``"pair"``, ``"lap"`` ...; ``None``: not
@@ -711,12 +731,21 @@ class StreamingStencil:
                 f"bx={bx} must be >= the window halo {self.wh} (ring "
                 "slots supply the halo rows)")
         self.bx, self.by = int(bx), int(by)
-        #: the kernel's grid: y-blocks, then x-blocks (x innermost)
-        self.grid = (Y // self.by, X // self.bx)
         self.x_halo = bool(x_halo)
         self.y_halo = bool(y_halo)
         self.x_slab = bool(x_slab)
         self.y_slab = bool(y_slab)
+        #: x-blocks at either end of the shard that no program does (the
+        #: overlap split's interior: 1; every other kernel: 0)
+        self.x_inset = int(bool(x_inset))
+        if self.x_inset and (self.x_slab or self.x_halo
+                             or X // self.bx < 3):
+            raise ValueError(
+                "an inset kernel streams the raw shard (no x slab, no "
+                f"padded copy) and needs three x-blocks of {self.bx} in "
+                f"{X} rows: its first and last are only read")
+        #: the kernel's grid: y-blocks, then x-blocks (x innermost)
+        self.grid = (Y // self.by, X // self.bx - 2 * self.x_inset)
         if (self.x_slab or self.y_slab) and (self.x_halo or self.y_halo):
             raise ValueError(
                 "slab edges and pre-padded windows do not combine: a "
@@ -788,15 +817,19 @@ class StreamingStencil:
     def _make_specs(self):
         """(in_specs, out_specs, out_shapes) shared by both kernel modes:
         program ``(j, i)`` reads and writes block ``(i, j)`` of the
-        full-lattice extras and outputs."""
+        full-lattice extras and outputs (``(i + 1, j)`` with
+        ``x_inset``)."""
         X, Y, Z = self.lattice_shape
         bx, by = self.bx, self.by
+        o = self.x_inset
 
         def block_spec(lead):
             nlead = len(lead)
+            # an inset kernel's program i does x-block i + o; every
+            # other kernel's index map holds no addition
             return pl.BlockSpec(
                 tuple(lead) + (bx, by, Z),
-                lambda j, i: (0,) * nlead + (i, j, 0))
+                lambda j, i: (0,) * nlead + (i + o if o else i, j, 0))
 
         in_specs = [pl.BlockSpec(memory_space=pl.ANY)
                     for _ in range(len(self.win_defs) + self._nslabs)]
@@ -853,12 +886,14 @@ class StreamingStencil:
     @property
     def halo(self):
         """Where the (x, y) edges of the window come from: ``"wrap"``
-        (the local periodic wrap), ``"slab"`` (thin halo-slab operands)
-        or ``"padded"`` (a pre-padded copy of the window)."""
-        return tuple(
-            "slab" if slab else "padded" if padded else "wrap"
-            for slab, padded in ((self.x_slab, self.x_halo),
-                                 (self.y_slab, self.y_halo)))
+        (the local periodic wrap), ``"slab"`` (thin halo-slab operands),
+        ``"padded"`` (a pre-padded copy of the window) or, in x,
+        ``"inset"`` (the shard's own first and last block, which the
+        kernel only reads)."""
+        x, y = ("slab" if slab else "padded" if padded else "wrap"
+                for slab, padded in ((self.x_slab, self.x_halo),
+                                     (self.y_slab, self.y_halo)))
+        return ("inset" if self.x_inset else x, y)
 
     @property
     def reread(self):
@@ -959,17 +994,23 @@ class StreamingStencil:
         if self.x_halo:
             return self._build_xhalo()
         h, bx = self.wh, self.bx
-        nbx = self.grid[1]
+        nbx = self.lattice_shape[0] // bx
+        # the x-blocks the programs do are o ... nbx - 1 - o, the ring
+        # holds o - 1 ... nbx - o: past the shard's ends where o is 0
+        # (the wrap, or the x slabs), inside it for an inset kernel
+        o = self.x_inset
         R = _RING
 
         def kernel(*refs):
             f_refs, slab_refs, scalar_refs, extra_refs, out_refs, wins, \
                 sem = self._unpack_refs(refs)
-            j, i = pl.program_id(0), pl.program_id(1)
+            j, p = pl.program_id(0), pl.program_id(1)
+            i = p + o if o else p   # the x-block this program does
 
             def stream(ypieces):
                 """Bring x-block i's window of this y-block into the
-                ring; the ring is primed anew at every y-block's i == 0."""
+                ring; the ring is primed anew at every y-block's first
+                program."""
                 def dmas(blk, slot):
                     # a block of the window operand fills its slot. With
                     # x slabs ring block -1 is the low slab and block
@@ -980,7 +1021,7 @@ class StreamingStencil:
                     xe = None
                     if self.x_slab and isinstance(blk, int):
                         xe = "lo" if blk < 0 else "hi" if blk >= nbx else None
-                    b = _rem(blk + nbx, nbx)
+                    b = blk if o else _rem(blk + nbx, nbx)
                     out = []
                     for f_ref, (slabs, comps), win in zip(
                             f_refs, slab_refs, wins):
@@ -1011,22 +1052,23 @@ class StreamingStencil:
                     for d in dmas(blk, slot):
                         d.wait()
 
-                if nbx <= 2:
-                    # all blocks (-1..nbx) fit in the ring: fetch at i==0
-                    @pl.when(i == 0)
+                if nbx - 2 * o <= 2:
+                    # all blocks (o-1..nbx-o) fit in the ring: fetch at
+                    # the first program
+                    @pl.when(p == 0)
                     def _():
-                        for blk in range(-1, nbx + 1):
+                        for blk in range(o - 1, nbx - o + 1):
                             start(blk, (blk + R) % R)
                             wait(blk, (blk + R) % R)
                 else:
-                    @pl.when(i == 0)
+                    @pl.when(p == 0)
                     def _():
-                        for db in (-1, 0, 1):
-                            start(db, (db + R) % R)
-                            wait(db, (db + R) % R)
-                        start(2, 2)
+                        for blk in (o - 1, o, o + 1):
+                            start(blk, (blk + R) % R)
+                            wait(blk, (blk + R) % R)
+                        start(o + 2, (o + 2) % R)
 
-                    @pl.when(i > 0)
+                    @pl.when(p > 0)
                     def _():
                         if self.x_slab:
                             # the block after the shard's last is the
@@ -1050,7 +1092,7 @@ class StreamingStencil:
                         else:
                             wait(i + 1, _rem(i + 1, R))
 
-                            @pl.when(i < nbx - 1)
+                            @pl.when(i < nbx - 1 - o)
                             def _():
                                 start(i + 2, _rem(i + 2, R))
 
@@ -1119,23 +1161,26 @@ class StreamingStencil:
         return self._pallas_call(kernel, 2 * bxw)
 
     def with_lattice(self, lattice_shape, bx=None, by=None, padded=False,
-                     kind=None):
+                     kind=None, inset=False):
         """A new :class:`StreamingStencil` sharing this one's body,
         definitions, dtypes and halo mode, built for a different local
         lattice shape — how :class:`OverlapStreamingStencil` derives the
-        interior and shell kernels from the full-block kernel; it asks
-        for them ``padded``: a slab-fed axis becomes a pre-padded one,
-        and under a ``kind`` of their own (this kernel's without one).
-        Raises ``ValueError`` when the new shape admits no feasible
+        interior and shell kernels from the full-block kernel, each
+        under a ``kind`` of its own (this kernel's without one). It
+        asks for the shell ``padded`` (a slab-fed axis becomes a
+        pre-padded one) and for the interior ``inset``: this kernel's
+        own lattice, the x axis neither slab-fed nor padded, the grid
+        short of the first and last x-block (``x_inset``). Raises
+        ``ValueError`` when the new shape admits no feasible
         blocking."""
         return StreamingStencil(
             lattice_shape, self.win_defs, self.h, self.body,
             self.out_defs, extra_defs=self.extra_defs,
             scalar_names=self.scalar_names, dtype=self.dtype,
-            bx=bx, by=by,
-            x_halo=self.x_halo or (padded and self.x_slab),
+            bx=bx, by=by, x_inset=inset,
+            x_halo=not inset and (self.x_halo or (padded and self.x_slab)),
             y_halo=self.y_halo or (padded and self.y_slab),
-            x_slab=self.x_slab and not padded,
+            x_slab=self.x_slab and not padded and not inset,
             y_slab=self.y_slab and not padded,
             interpret=self.interpret, sum_defs=self.sum_defs,
             dtypes=self.dtypes, win_halo=self.wh, stages=self.stages,
@@ -1225,21 +1270,33 @@ class OverlapStreamingStencil:
     """Interior + x-shell split of a streaming stencil kernel for
     communication/computation overlap on x-sharded lattices.
 
-    The padded single launch makes the whole kernel wait on the
-    ``ppermute``d x halos. Here the full-block kernel is rebuilt (same
-    body, same definitions — :meth:`StreamingStencil.with_lattice`) as
-    three launches over an x partition of the local block:
+    The single launch makes the whole kernel wait on the ``ppermute``d
+    x slabs (a custom call waits for all of its operands). Here the
+    full-block kernel is rebuilt (same body, same definitions —
+    :meth:`StreamingStencil.with_lattice`) as three launches over an x
+    partition of the local block:
 
-    - *interior*, lattice ``(X - 2h, Y, Z)``: its ``x_halo``-padded
-      input is exactly the RAW local block — no dependence on the
-      collectives, so it runs while they are in flight;
-    - two *x shells*, lattice ``(h, Y, Z)`` with ``bx = h``: their
-      inputs are ``concat(halo, first/last 2h local rows)``, computed
-      once the halos land.
+    - *interior*: the ring kernel over the RAW local block with its
+      grid inset by one x-block of ``bx = h`` rows at either end
+      (``x_inset``): rows ``h ... X - h``, every window row DMA'd once
+      as in the single launch, no dependence on the collectives, so it
+      runs while they are in flight. Its outputs are the full local
+      lattice, the ``h`` rows at either end left unwritten;
+    - two *x shells*, lattice ``(h, Y, Z)`` with ``bx = h``, the
+      pre-padded ``x_halo`` variant: their inputs are ``concat(slab,
+      first/last 2h local rows)``, computed once the slabs land.
 
-    Outputs stitch back with one concatenate per output. Bit-exact with
-    the padded launch: every output element sees identical tap offsets
-    and per-element arithmetic (blocking never enters the math).
+    The shells' ``h``-row outputs are put into the interior's outputs in
+    place (``dynamic_update_slice``: the interior's result is a buffer
+    XLA owns), so nothing the size of the lattice is copied round the
+    launches; the extras go to the interior whole (its index maps skip
+    the edge blocks) and to the shells as ``h``-row slices. An extra
+    the kernel writes in place (``in_place``) is aliased by the
+    interior alone: its edge rows still hold the old values when the
+    shells' slices are taken, which comes first in program order.
+    Bit-exact with the single launch: every output element sees
+    identical tap offsets and per-element arithmetic (blocking never
+    enters the math).
 
     The two kernels carry kinds of their own, ``<kind>_interior`` and
     ``<kind>_shell`` (``interior`` / ``shell`` for a kernel of no
@@ -1250,11 +1307,13 @@ class OverlapStreamingStencil:
 
     Feasibility (:class:`OverlapInfeasible`, a ``ValueError``,
     otherwise — callers fall back to the single launch, :meth:`plan_for`
-    does it for them and says so in an event): x-sharded windows only (``x_slab`` or ``x_halo``
-    set, y whole — an h-thin y shell has no legal sublane blocking; the
-    three launches are pre-padded ``x_halo`` kernels either way),
-    no ``sum_defs`` (the region split would change the deterministic
-    reduction order), and ``X >= 3h`` so an interior exists.
+    does it for them and says so in an event): x-sharded windows only
+    (``x_slab`` or ``x_halo`` set, y whole — an h-thin y shell has no
+    legal sublane blocking), no ``sum_defs`` (the region split would
+    change the deterministic reduction order), ``X >= 3h`` so an
+    interior exists, and x-blocks of ``h`` rows at the full-block
+    kernel's ``by`` (``blocking``: ``h`` has to divide ``X`` and be the
+    window's halo).
     """
 
     def __init__(self, st, h):
@@ -1286,9 +1345,11 @@ class OverlapStreamingStencil:
         def part(name):
             return name if st.kind is None else f"{st.kind}_{name}"
 
+        # both at x-blocks of h rows: the rows the interior's inset
+        # leaves are then exactly the shells'
         try:
             self.st_interior = st.with_lattice(
-                (X - 2 * self.h, Y, Z), by=st.by, padded=True,
+                st.lattice_shape, bx=self.h, by=st.by, inset=True,
                 kind=part("interior"))
             self.st_shell = st.with_lattice(
                 (self.h, Y, Z), bx=self.h, by=st.by, padded=True,
@@ -1321,36 +1382,43 @@ class OverlapStreamingStencil:
 
     @property
     def stitch_bytes(self):
-        """Ideal bytes, read plus written, of the copies one call places
-        round its three launches: every lattice extra sliced to the
-        interior's and the two shells' rows, every window's two shell
+        """Ideal bytes, read plus written, of the copies one call still
+        places round its three launches: every window's two shell
         inputs concatenated from a slab and ``2h`` local rows, every
-        output concatenated from its three pieces. XLA may fuse or
-        elide some of them; a kernel writes none of it."""
+        lattice extra's two ``h``-row slices for the shells, every
+        output's two ``h``-row updates. XLA may fuse or elide some of
+        them; a kernel writes none of it, and nothing the size of the
+        lattice is among them."""
         st, h = self.st, self.h
-        X, Y, Z = st.lattice_shape
+        Y, Z = st.lattice_shape[1:]
 
         def rows(name, lead, n):
             comps = int(np.prod(lead)) if lead else 1
             return comps * n * Y * Z * st.dtypes.get(name, st.dtype).itemsize
 
-        copied = sum(rows(n, lead, X) for n, lead in st.extra_defs.items())
-        copied += sum(rows(n, (c,), 2 * 3 * h)
-                      for n, c in st.win_defs.items())
-        copied += sum(rows(n, lead, X) for n, lead in st.out_defs.items())
+        copied = sum(rows(n, (c,), 2 * 3 * h)
+                     for n, c in st.win_defs.items())
+        copied += sum(rows(n, lead, 2 * h)
+                      for defs in (st.extra_defs, st.out_defs)
+                      for n, lead in defs.items())
         return 2 * copied
 
     @property
     def plan(self):
         """What the split built, as an ``overlap_plan`` event carries
-        it: per kernel the lattice, blocking, grid and modelled
-        ``reread``, and :attr:`stitch_bytes`."""
+        it: per kernel the lattice its launch updates, blocking, grid,
+        where its x edges come from (``halo``: the interior's
+        ``"inset"``, the shells' ``"padded"``) and modelled ``reread``
+        (the interior's is the single launch's: the ring reads every
+        row once); how the pieces meet (``stitch``: ``"in_place"``) and
+        :attr:`stitch_bytes`."""
         def built(st):
-            return {"kernel": st.kind, "lattice": list(st.lattice_shape),
+            return {"kernel": st.kind,
+                    "lattice": [st.grid[1] * st.bx, *st.lattice_shape[1:]],
                     "bx": st.bx, "by": st.by, "grid": list(st.grid),
-                    "reread": st.reread}
+                    "halo": st.halo[0], "reread": st.reread}
         return {"path": "split", "interior": built(self.st_interior),
-                "shell": built(self.st_shell),
+                "shell": built(self.st_shell), "stitch": "in_place",
                 "stitch_bytes": self.stitch_bytes}
 
     @staticmethod
@@ -1371,7 +1439,7 @@ class OverlapStreamingStencil:
         the RAW (unpadded) local window input — a single ``(C, X, Y,
         Z)`` array or a dict matching ``win_defs``; ``decomp`` issues
         the slab ``ppermute``s. Returns the same dict of full-block
-        outputs as the padded ``StreamingStencil.__call__``."""
+        outputs as the single ``StreamingStencil.__call__``."""
         h = self.h
         X = self.st.lattice_shape[0]
         single = not isinstance(f, dict)
@@ -1380,16 +1448,24 @@ class OverlapStreamingStencil:
         def xsl(a, s, e):
             return lax.slice_in_dim(a, s, e, axis=a.ndim - 3)
 
+        def put(a, rows, x0):
+            return lax.dynamic_update_slice_in_dim(a, rows, x0,
+                                                   axis=a.ndim - 3)
+
         with trace_scope("halo_overlap"):
             # slab ppermutes first: program order hands the scheduler
             # the dependence-free interior launch to hide them behind
             with trace_scope("halo_overlap_exchange"):
                 slabs = {n: decomp.exchange_slabs(a, 0, h)
                          for n, a in wins.items()}
+            with trace_scope("halo_overlap_shells"):
+                # the shells' h rows of every extra, taken before the
+                # interior's call: an extra it writes in place holds
+                # the old values in its edge rows only until then
+                low_extras = self._slice_x(extras, 0, h)
+                high_extras = self._slice_x(extras, X - h, X)
             with trace_scope("halo_overlap_interior"):
-                int_out = self.st_interior(
-                    f, scalars=scalars,
-                    extras=self._slice_x(extras, h, X - h))
+                out = self.st_interior(f, scalars=scalars, extras=extras)
             with trace_scope("halo_overlap_shells"):
                 low_in = {n: lax.concatenate(
                     [slabs[n][0], xsl(a, 0, 2 * h)],
@@ -1399,13 +1475,12 @@ class OverlapStreamingStencil:
                     dimension=a.ndim - 3) for n, a in wins.items()}
                 low_out = self.st_shell(
                     low_in["f"] if single else low_in, scalars=scalars,
-                    extras=self._slice_x(extras, 0, h))
+                    extras=low_extras)
                 high_out = self.st_shell(
                     high_in["f"] if single else high_in, scalars=scalars,
-                    extras=self._slice_x(extras, X - h, X))
-        out = {}
-        for n in self.st.out_defs:
-            ax = low_out[n].ndim - 3
-            out[n] = lax.concatenate(
-                [low_out[n], int_out[n], high_out[n]], dimension=ax)
+                    extras=high_extras)
+                # the interior's outputs are whole-lattice buffers of
+                # its own with these rows unwritten: filled in place
+                out = {n: put(put(a, low_out[n], 0), high_out[n], X - h)
+                       for n, a in out.items()}
         return out

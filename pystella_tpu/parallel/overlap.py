@@ -28,10 +28,15 @@ This module is the POLICY side:
 The MECHANISM lives in
 :meth:`~pystella_tpu.DomainDecomposition.overlap_stencil` (XLA-stencil
 tier) and :class:`~pystella_tpu.ops.pallas_stencil.OverlapStreamingStencil`
-(Pallas tier); when overlap cannot help (unsharded meshes, blocks
-thinner than ``3h``, y/z-sharded Pallas tiles, reduction-emitting
-kernels) every consumer falls back to the padded path — the two paths
-are bit-exact, so the choice is pure scheduling.
+(Pallas tier: the interior is the ring kernel over the raw shard with
+its grid inset by one ``h``-row x-block at either end, the two shells
+are ``h``-row launches on the slabs and ``2h`` local rows, and their
+rows are put into the interior's full-lattice outputs in place, so the
+split costs what the single launch costs); when overlap cannot help
+(unsharded meshes, blocks thinner than ``3h``, y/z-sharded Pallas
+tiles, reduction-emitting kernels) every consumer falls back to the
+single launch — the two paths are bit-exact, so the choice is pure
+scheduling.
 """
 
 from __future__ import annotations

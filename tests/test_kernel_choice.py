@@ -123,6 +123,7 @@ def _built_cell(name):
                 "kernel": self.kind, "stencil": "StreamingStencil",
                 "bx": self.bx, "by": self.by, "grid": list(self.grid),
                 "halo": list(self.halo), "in_place": list(self.in_place),
+                "reread": self.reread,
                 "source": "explicit" if pinned else "heuristic"})
 
     derivs = ps.FiniteDifferencer(decomp, h, lattice.dx)
@@ -217,9 +218,13 @@ _CELL_KERNELS = [
     ("preheat-h4-f32", "grad", 0, (4, 256), (2, 128), "heuristic"),
     # 512^3 per chip on the slab mesh (4, 1, 1) (PR 42): the one-chip
     # kernels with x slabs, and beside each kernel without sums the two
-    # the overlap split launches in its place: the pre-padded interior
-    # over rows 2 ... 510 and the h-row shell, both at the whole
-    # kernel's y block; the coupled pairs emit sums and stay whole
+    # the overlap split launches in its place: the interior over rows
+    # 2 ... 510 (PR 43: the ring kernel over the shard, its grid inset
+    # by an x-block of h rows at either end; before, a pre-padded
+    # kernel of lattice (508, 512, 512)) and the pre-padded h-row
+    # shell, both at bx = h and the whole kernel's y block
+    # (`_SPLIT_PARTS` holds their x edges and re-read); the coupled
+    # pairs emit sums and stay whole
     ("preheat-mesh4x-f32", "stage", 0, (2, 256), (2, 256), "heuristic"),
     ("preheat-mesh4x-f32", "stage_interior", 0, (2, 256), (2, 254),
      "split"),
@@ -237,6 +242,21 @@ _CELL_KERNELS = [
      "explicit"),
     ("preheat-mesh4x-f32", "lap_shell", 0, (2, 256), (2, 1), "explicit"),
 ]
+
+
+#: the split's kernels: where the x edges of the window come from and
+#: the modelled bytes moved over ideal bytes (``block_choice.reread``).
+#: The interior's is the whole kernel's (the ring reads every row once:
+#: `pair` 1.047 where the pre-padded interior said 1.89); a shell reads
+#: every window row (bx + 2h) / bx = 3 times
+_SPLIT_PARTS = {
+    "stage_interior": ("inset", (2 * 1.0625 + 14) / 16),
+    "stage_shell": ("padded", (2 * 3 * 1.0625 + 14) / 16),
+    "pair_interior": ("inset", (6 * 1.125 + 10) / 16),
+    "pair_shell": ("padded", (6 * 3 * 1.125 + 10) / 16),
+    "lap_interior": ("inset", (2 * 1.0625 + 2) / 4),
+    "lap_shell": ("padded", (2 * 3 * 1.0625 + 2) / 4),
+}
 
 
 @pytest.mark.parametrize(
@@ -284,7 +304,12 @@ def test_cells_get_the_kernels_the_ledger_measured(config, kernel, nth,
     # where the window's (x, y) edges come from follows from the mesh
     # the stepper or operator was built on, and from nothing else
     if kernel.endswith(("_interior", "_shell")):
-        assert d["halo"] == ["padded", "wrap"]
+        x_edges, moved = _SPLIT_PARTS[kernel]
+        assert d["halo"] == [x_edges, "wrap"]
+        assert d["reread"] == moved
+        if x_edges == "inset":
+            whole = built["choices"][kernel.removesuffix("_interior")]
+            assert d["reread"] == whole[0]["reread"]
     else:
         assert d["halo"] == {"preheat-mesh4-f32": ["slab", "slab"],
                              "preheat-mesh4x-f32": ["slab", "wrap"]}.get(

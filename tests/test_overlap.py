@@ -212,6 +212,52 @@ def test_fused_stage_overlap_bitexact(make_decomp, proc_shape):
         assert not obs.has_scope(lowered, "halo_overlap")
 
 
+@pytest.mark.parametrize("donate", [False, True],
+                         ids=["fresh", "in-place"])
+@pytest.mark.parametrize("h", [1, 2, 4])
+def test_split_stage_loop_equals_single_launch(make_decomp, h, donate):
+    """The split as PR 43 builds it (the interior the ring kernel over
+    an inset grid, the shells' rows put into its full-lattice outputs)
+    against the slab-fed single launch, bit for bit, through a whole
+    step of the stage-by-stage protocol (five ``stage`` kernels, three
+    extras each) at radius 1, 2 and 4, and a pair-kernel ``step`` at 2
+    and 4 (at radius 1 the CPU's compilation of the one-row shell
+    contracts one product of the pair body another way: one value in
+    8,192 is an ulp off, on the parent of PR 43 as here; Mosaic has no
+    such freedom). With ``donate=True`` the ``stage`` kernel writes its
+    extras in place: the interior aliases the donated buffers, and the
+    shells' rows of them are taken before it runs."""
+    decomp = make_decomp((2, 1, 1))
+    grid = (max(16, 8 * h), 16, 16)     # four x-blocks a shard at least
+    dt = np.float32(0.01)
+    args = {"a": np.float32(1.0), "hubble": np.float32(0.1)}
+
+    def potential(f):
+        return 0.5 * f[0]**2 + 0.125 * f[0]**2 * f[1]**2
+
+    def run(overlap):
+        stepper = ps.FusedScalarStepper(
+            ps.ScalarSector(2, potential=potential), decomp, grid, 0.3,
+            h, dtype=np.float32, dt=dt, overlap=overlap, donate=donate)
+        state = {k: decomp.shard(0.1 * _field(grid, seed=21, outer=(2,)))
+                 for k in ("f", "dfdt")}
+        carry = stepper.init_carry(state)
+        for s in range(stepper.num_stages):
+            carry = stepper.stage(s, carry, 0.0, dt, args)
+        staged = {k: np.asarray(v) for k, v in carry[0].items()}
+        state = stepper.step(dict(carry[0]), 0.0, dt, args)
+        return stepper, staged, {k: np.asarray(v) for k, v in state.items()}
+
+    split, staged, stepped = run(True)
+    _, staged1, stepped1 = run(False)
+    assert split._scalar_st.in_place == (
+        ("dfdt", "kf", "kdfdt") if donate else ())
+    pairs = [(staged, staged1)] + [(stepped, stepped1)] * (h > 1)
+    for got, ref in pairs:
+        for k in ref:
+            assert np.array_equal(got[k], ref[k]), k
+
+
 # -- multigrid smoother ----------------------------------------------------
 
 @pytest.mark.parametrize("proc_shape", [(2, 2, 1)], indirect=True)
@@ -410,8 +456,9 @@ def _plan_case(make_decomp, case):
 def test_overlap_plan_event(make_decomp, case, kernel, reason):
     """Every sharded kernel build says which launch it takes: one
     ``overlap_plan`` event a kernel, ``path: split`` with the interior's
-    and the shell's lattice, blocking, grid, modelled re-read and the
-    ideal bytes of the copies round them, or ``path: single`` with the
+    and the shell's lattice, blocking, grid, x-edge source (``halo``),
+    modelled re-read, how the pieces meet (``stitch``) and the ideal
+    bytes of the copies still round them, or ``path: single`` with the
     reason (what used to be a ``logging.info`` line, or nothing)."""
     seen = _plan_case(make_decomp, case)
     plans = {d["kernel"]: d for d in seen.of("overlap_plan")}
@@ -434,10 +481,16 @@ def test_overlap_plan_event(make_decomp, case, kernel, reason):
     assert inner["by"] == shell["by"]
     assert inner["grid"] == [Y // inner["by"], (X - 2 * h) // inner["bx"]]
     assert shell["grid"] == [Y // shell["by"], 1]
-    # a pre-padded kernel has no ring: every window row is read
-    # (bx + 2h) / bx times, and the event's re-read holds it
-    assert inner["reread"] > (inner["bx"] + 2 * h) / inner["bx"] * 0.3
-    assert d["stitch_bytes"] > 0
+    # the interior is the ring kernel over the shard, inset by one
+    # x-block of h rows at either end (PR 43): every window row is read
+    # once, so its re-read is the y windows' alone; the pre-padded
+    # shell has no ring and reads every row (bx + 2h) / bx = 3 times
+    assert (inner["halo"], shell["halo"]) == ("inset", "padded")
+    assert inner["bx"] == h
+    from pystella_tpu.ops.pallas_stencil import HY
+    assert 1 < inner["reread"] <= 1 + 2 * HY / inner["by"]
+    assert shell["reread"] > 1.3 * inner["reread"]
+    assert d["stitch"] == "in_place" and d["stitch_bytes"] > 0
     if case != "split":
         return
     # the fused steppers add a block_choice a kernel of the split
@@ -446,11 +499,15 @@ def test_overlap_plan_event(make_decomp, case, kernel, reason):
         b = built[part]
         assert (b["bx"], b["by"], list(b["grid"])) == (
             plan["bx"], plan["by"], plan["grid"])
-        assert b["halo"] == ["padded", "wrap"] and b["source"] == "split"
+        assert b["halo"] == [plan["halo"], "wrap"]
+        assert b["source"] == "split"
         assert b["taps"] == 26 and b["reread"] == plan["reread"]
-    # the pair call's copies by hand: the extra kdfdt, four outputs,
-    # and per window two shell inputs of 3h rows; read and written
-    rows = (2 * X) + 4 * (2 * X) + 3 * (2 * 2 * 3 * h)
+    # the interior moves what the single launch of its kind moves
+    assert inner["reread"] == built[kernel]["reread"]
+    # the pair call's copies by hand: per window two shell inputs of 3h
+    # rows, and 2h rows of the extra kdfdt and of each of four outputs
+    # (two fields each); read and written. Nothing of X rows is left
+    rows = 3 * (2 * 2 * 3 * h) + (1 + 4) * (2 * 2 * h)
     assert d["stitch_bytes"] == 2 * rows * Y * Z * 4
 
 

@@ -394,6 +394,148 @@ def test_streaming_in_place_equals_plain(variant, carry_dtype):
     assert aliases(plain) == {}
 
 
+def _inset_case(h, variant, shape):
+    """A single-launch (wrapped) kernel at ``bx = h`` and the inputs of
+    one call: ``one`` window and nothing else; ``several`` windows
+    (one of them in a storage dtype of its own) and a lattice extra;
+    ``in_place``: the stage shape, both extras written over."""
+    rng = np.random.default_rng(31 + h)
+
+    def lap_of(taps):
+        lap = -6 * taps()
+        for s in range(1, h + 1):   # taps out to the radius, on x too
+            lap = lap + (taps(s) + taps(-s) + taps(0, s) + taps(0, -s)
+                         + taps(0, 0, s) + taps(0, 0, -s)) / s
+        return lap
+
+    def arr(ncomp, dtype=jnp.float32):
+        return jnp.asarray(rng.standard_normal((ncomp,) + shape), dtype)
+
+    kw = dict(dtype=jnp.float32, scalar_names=("A",))
+    if variant == "one":
+        def body(taps, extras, scalars):
+            return {"f": scalars["A"] * lap_of(taps)}
+        wins, outs = {"f": 2}, {"f": (2,)}
+        f, extras = arr(2), {}
+    elif variant == "several":
+        def body(taps, extras, scalars):
+            g = taps["g"]().astype(jnp.float32)
+            return {"f": lap_of(taps["f"]) + scalars["A"] * g[:1],
+                    "g": g + taps["g"](h) - taps["g"](-h) + extras["e"]}
+        wins, outs = {"f": 2, "g": 1}, {"f": (2,), "g": (1,)}
+        kw.update(extra_defs={"e": (1,)}, dtypes={"g": jnp.bfloat16})
+        f = {"f": arr(2), "g": arr(1, jnp.bfloat16)}
+        extras = {"e": arr(1)}
+    else:
+        def body(taps, extras, scalars):
+            lap = lap_of(taps)
+            k2 = scalars["A"] * extras["k"] + lap[:1] - lap[1:]
+            d2 = extras["d"] + 1.25 * k2
+            return {"f": taps() + 0.25 * d2, "d": d2, "k": k2}
+        wins, outs = {"f": 2}, {"f": (2,), "d": (2,), "k": (1,)}
+        kw.update(extra_defs={"d": (2,), "k": (1,)}, in_place=("d", "k"))
+        f, extras = arr(2), {"d": arr(2), "k": arr(1)}
+    return wins, body, outs, kw, f, extras
+
+
+@interpret_only
+@pytest.mark.parametrize("variant", ["one", "several", "in_place"])
+@pytest.mark.parametrize("nprog", [1, 2, 3, 6])
+@pytest.mark.parametrize("h", [1, 2, 4])
+def test_inset_kernel_equals_single_launch_rows(h, nprog, variant):
+    """The overlap split's interior (PR 43) is the ring kernel with its
+    grid short of the first and the last x-block: on rows ``h ... X -
+    h`` it gives the single launch's bits, for radius 1, 2 and 4, one
+    window and several, a lattice extra, extras written in place, and
+    one, two (all blocks primed at once), three and six programs (the
+    streaming prime) a y-block. Its extras and outputs are arrays of
+    the whole lattice; it moves what the single launch moves
+    (``reread``), and says where its x edges come from."""
+    X, Y, Z = shape = ((nprog + 2) * h, 16, 8)
+    wins, body, outs, kw, f, extras = _inset_case(h, variant, shape)
+    st = StreamingStencil(shape, wins, h, body, outs, bx=h, by=8, **kw)
+    inset = st.with_lattice(shape, bx=h, by=8, inset=True, kind="interior")
+    assert st.grid == (2, nprog + 2) and inset.grid == (2, nprog)
+    assert (st.halo, inset.halo) == (("wrap", "wrap"), ("inset", "wrap"))
+    assert inset.x_inset == 1 and st.x_inset == 0
+    assert inset.reread == st.reread
+    assert inset.in_place == st.in_place and inset.kind == "interior"
+    ref = st(f, scalars={"A": 0.5}, extras=extras)
+    got = inset(f, scalars={"A": 0.5}, extras=extras)
+    for n in outs:
+        assert got[n].shape == ref[n].shape and got[n].dtype == ref[n].dtype
+        assert np.array_equal(
+            np.asarray(got[n][:, h:X - h], np.float32),
+            np.asarray(ref[n][:, h:X - h], np.float32)), n
+
+
+@pytest.mark.parametrize("kw, shape", [
+    ({"x_slab": True}, (8, 16, 8)), ({"x_halo": True}, (8, 16, 8)),
+    ({}, (4, 16, 8))], ids=["x-slab", "x-halo", "two-blocks"])
+def test_inset_refusals(kw, shape):
+    """An inset kernel streams the raw shard: it takes no x slab and no
+    padded copy, and needs a block between the two it only reads."""
+    with pytest.raises(ValueError, match="inset kernel"):
+        StreamingStencil(shape, 1, 1, lambda t, e, s: {}, {"f": (1,)},
+                         bx=2, by=8, interpret=True, x_inset=True, **kw)
+
+
+#: sha256 of the jaxpr text (``pallas_call`` equation, kernel and index
+#: maps included; no source locations, which the lowered module's
+#: bytecode carries) of four unsplit kernels, recorded on the parent of
+#: PR 43 (874860b) with x64 off
+_UNSPLIT_JAXPRS = {
+    ("wrap", 2): "d6831ae35c203f84",
+    ("wrap", 4): "cc58ac3bcf281a0f",
+    ("slab", 2): "31a0bb63d1e0c8ae",
+    ("slab", 4): "1d41539e4d5a405b",
+}
+
+
+@pytest.mark.parametrize("mode, nbx", list(_UNSPLIT_JAXPRS))
+def test_unsplit_kernel_traces_as_before_the_inset(mode, nbx):
+    """For every kernel that is not the split's interior the inset is a
+    static zero: a one-chip (wrapped) kernel and a slab-fed one (x and
+    y, as on ``(2, 2, 1)``), at two x-blocks and at four (both primes
+    of the ring), built for the chip, trace to the program they traced
+    to on the parent of PR 43, equation for equation. The lowered text
+    itself holds the kernel as bytecode with this file's line numbers,
+    so it is the jaxpr that is held."""
+    import hashlib
+    from pystella_tpu.ops.pallas_stencil import HY, LANE
+
+    def body(taps, extras, scalars):
+        fv = taps()
+        lap = -6.0 * fv
+        for d in range(3):
+            for s in (-1, 1):
+                off = [0, 0, 0]
+                off[d] = s
+                lap = lap + taps(*off)
+        return {"lap": scalars["c"] * lap + extras["e"]}
+
+    with jax.enable_x64(False):
+        X = 4 * nbx
+        st = StreamingStencil(
+            (X, 32, LANE), 2, 1, body, {"lap": (2,)},
+            extra_defs={"e": (2,)}, scalar_names=("c",),
+            dtype=jnp.float32, bx=4, by=8, interpret=False,
+            x_slab=mode == "slab", y_slab=mode == "slab")
+        x = jnp.zeros((2, X, 32, LANE), jnp.float32)
+        slabs = None
+        if mode == "slab":
+            slabs = [{"x": (jnp.zeros((2, 1, 32, LANE), jnp.float32),) * 2,
+                      "y": (jnp.zeros((2, X, HY, LANE), jnp.float32),) * 2}]
+
+        def call(x, slabs):
+            return st(x, scalars={"c": 3.0}, extras={"e": x}, slabs=slabs)
+
+        text = str(jax.make_jaxpr(call)(x, slabs))
+    assert "pallas_call" in text
+    assert hashlib.sha256(text.encode()).hexdigest()[:16] == \
+        _UNSPLIT_JAXPRS[mode, nbx]
+
+
 @pytest.mark.parametrize("names, defs, match", [
     (("f",), {}, "windowed input"),
     (("g",), {}, "an extra and an output of that name"),

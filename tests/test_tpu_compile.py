@@ -280,17 +280,24 @@ def test_slab_chunk_compiles_and_what_it_holds(v5e, monkeypatch, overlap):
     """``preheat-mesh4x-f32.fixed-bg``'s 4-step chunk at the cell's own
     lattice, through Mosaic and XLA:TPU for the v5e, on both paths: the
     default (``auto`` is ON for a sharded mesh, so the ten pair calls
-    are thirty launches: the pre-padded interior at lattice (508, 512,
-    512), ``bx`` 2, and two shells at (2, 512, 512), ``bx = h``, under
-    the 100-MB VMEM limit) and ``overlap=False`` (ten slab-fed single
-    launches). Neither had been compiled for the chip before PR 42.
-    Kept: the compiler's account of a chip's share. The state is 2.15
-    GB of arguments on both; the temporaries are 11.67 GB with the
-    split and 10.74 GB without it, so the default fits a 16-GB chip
-    with 1.9 GB to spare and the split's price in memory is 0.93 GB:
-    XLA materialises each output's three pieces and their
-    concatenation (``pad_maximum_fusion``, four lattice arrays a pair
-    call) and the extra sliced to the interior's rows."""
+    are thirty launches: the interior, the ring kernel over the shard
+    with its grid inset to rows 2 ... 510, ``bx`` 2, and two pre-padded
+    shells at (2, 512, 512), ``bx = h``, under the 100-MB VMEM limit)
+    and ``overlap=False`` (ten slab-fed single launches). Neither had
+    been compiled for the chip before PR 42. Kept: the compiler's
+    account of a chip's share. The state is 2.15 GB of arguments on
+    both; the temporaries are 7.61 GB with the split and 10.74 GB
+    without it. Re-recorded in PR 43: before it the interior was a
+    pre-padded kernel of lattice (508, 512, 512), XLA materialised
+    each output's three pieces and their concatenation
+    (``pad_maximum_fusion``, four lattice arrays a pair call, 29 a
+    chunk) and the extra sliced to the interior's rows, and the
+    temporaries were 11.67 GB. Now nothing the size of the lattice
+    stands between the launches: the shells' two rows go into the
+    interior's outputs by ``dynamic-update-slice`` fusions over those
+    rows alone (two an output that is read again: 76), and the only
+    ``pad`` / ``maximum`` fusions left make the shells' six-row
+    inputs."""
     import re
     hlo, (arguments, temporaries), plans = _slab_chunk(v5e, monkeypatch,
                                                        overlap)
@@ -298,32 +305,96 @@ def test_slab_chunk_compiles_and_what_it_holds(v5e, monkeypatch, overlap):
     kinds = [re.sub(r"\.\d+$", "", n) for n in _custom_call_names(hlo)]
     one = 2 * 512**3 * 4            # one two-field array on a chip
     assert arguments == pytest.approx(2 * one, rel=1e-3)
-    padded = re.findall(r"= f32\[2,512,512,512\]\S* fusion\([^\n]*"
-                        r"calls=%fused_computation[\w.]*", hlo)
+    # every instruction of the entry computation that makes an array
+    # the size of a chip's lattice, but the kernels: name and opcode
+    made = re.findall(r"^  (?:ROOT )?%(\S+) = f32\[2,512,512,512\]\S* "
+                      r"(?!custom-call|get-tuple-element|parameter)"
+                      r"([\w-]+)\(", hlo[hlo.index("\nENTRY "):], re.M)
     if overlap is None:
         assert plans["pair"]["path"] == "split"
         assert plans["pair"]["interior"]["lattice"] == [508, 512, 512]
         assert (plans["pair"]["interior"]["bx"],
                 plans["pair"]["shell"]["bx"]) == (2, 2)
         assert plans["pair"]["interior"]["by"] == 128
-        # one array's worth over the ideal: windows read 3 x 1.125 times
+        # the ring reads every window row once, with its y halos: what
+        # the single launch moves (the pre-padded interior: 3 x 1.125)
+        assert plans["pair"]["interior"]["halo"] == "inset"
         assert plans["pair"]["interior"]["reread"] == pytest.approx(
-            (6 * 3 * 1.125 + 2 + 8) / 16)
-        assert plans["pair"]["stitch_bytes"] == pytest.approx(
-            10.9e9, rel=0.01)
+            (6 * 1.125 + 2 + 8) / 16)
+        # 3 windows' 12 rows, an extra's and 4 outputs' 4 rows, of two
+        # fields, read and written: 0.23 GB a call (it was 10.9)
+        assert plans["pair"]["stitch"] == "in_place"
+        assert plans["pair"]["stitch_bytes"] == 2 * 2 * (
+            3 * 12 + 5 * 4) * 512 * 512 * 4
         assert kinds.count("pallas_stencil_pair_interior") == 10, kinds
         assert kinds.count("pallas_stencil_pair_shell") == 20, kinds
-        assert 11.2e9 < temporaries < 12.2e9, temporaries
-        # the stitch is really placed: lattice-sized fusions over three
-        # pieces, three or four a pair call
-        assert len(padded) >= 28, len(padded)
+        # the parent's 11.67 GB does not rise: it falls by four arrays
+        assert 7.2e9 < temporaries < 8.0e9, temporaries
+        # nothing lattice-sized is placed between the launches but the
+        # first step's zeroed carry and the shells' rows going in
+        dus = [n for n, op in made if op == "fusion"
+               and "dynamic-update-slice" in n]
+        assert len(dus) == 76, len(dus)
+        assert [op for n, op in made if n not in dus] == ["broadcast"], \
+            sorted(set(made))[:8]
+        # ... and each of those writes its two rows and no more
+        rows = re.findall(
+            r"dynamic-update-slice\(%\S+, %(\S+?),", hlo)
+        shapes = dict(re.findall(
+            r"^  %(\S+) = (f32\[[\d,]+\])\S* parameter\(1\)", hlo, re.M))
+        assert len(rows) == 76
+        assert {shapes[r] for r in rows} == {"f32[2,2,512,512]"}
     else:
         assert (plans["pair"]["path"], plans["pair"]["reason"]) == (
             "single", "off")
         assert kinds.count("pallas_stencil_pair") == 10, kinds
         assert 10.3e9 < temporaries < 11.2e9, temporaries
+        assert [op for _, op in made] == ["broadcast"], made
     assert len(set(kinds)) == (2 if overlap is None else 1), set(kinds)
     assert arguments + temporaries < 15.75 * 2**30
+
+
+def test_slab_stage_program_keeps_its_extras_in_place(v5e, monkeypatch):
+    """The stage-by-stage protocol on the slab mesh (upstream's loop on
+    ``-proc 4 1 1``): the donating ``stage`` program takes the split
+    too, and its interior writes the three donated extras in place.
+    Their edge rows still hold the old values when the shells' slices
+    are taken, and XLA orders it so without keeping a copy: the
+    compiler accounts 3.22 GB of the 4.29 GB of arguments as aliased
+    and 0.03 GB of temporaries (3.24 GB before PR 43, when every output
+    was concatenated from three pieces), and the program holds no
+    ``copy``, ``pad`` or ``concatenate`` the size of the lattice."""
+    import re
+    from unittest import mock
+    from pystella_tpu.ops import fused
+    monkeypatch.delenv("PYSTELLA_HALO_OVERLAP", raising=False)
+    proc_shape, grid = SLAB
+    programs = {}
+    instrument_jit = fused._obs_memory.instrument_jit
+
+    def recording(fn, label=None, **kw):
+        programs.setdefault(label, []).append(
+            instrument_jit(fn, label=label, **kw))
+        return programs[label][-1]
+
+    with mock.patch.object(fused._obs_memory, "instrument_jit", recording):
+        stepper, state, scalar = _preheat(v5e, proc_shape, grid)
+    assert stepper._scalar_st.in_place == ("dfdt", "kf", "kdfdt")
+    stage = programs["fused.FusedScalarStepper.stage_call_sharded"][0]
+    args = [state["f"]] + [scalar] * 5 + [state["f"]] * 3
+    compiled = stage._jitted.trace(*args).lower(
+        lowering_platforms=("tpu",)).compile()
+    hlo, mem = compiled.as_text(), compiled.memory_analysis()
+    kinds = sorted(re.sub(r"\.\d+$", "", n) for n in _custom_call_names(hlo))
+    assert kinds == ["pallas_stencil_stage_interior",
+                     "pallas_stencil_stage_shell",
+                     "pallas_stencil_stage_shell"], kinds
+    one = 2 * 512**3 * 4
+    assert mem.argument_size_in_bytes == pytest.approx(4 * one, rel=1e-3)
+    assert mem.alias_size_in_bytes == 3 * one
+    assert mem.temp_size_in_bytes < 0.1e9, mem.temp_size_in_bytes
+    assert not re.findall(r"= f32\[2,5\d\d,512,512\]\S* "
+                          r"(?:copy|pad|concatenate|maximum)\(", hlo)
 
 
 def test_slab_chunk_kernels_are_named_by_kind(v5e, monkeypatch):

@@ -77,12 +77,15 @@ def test_streaming_sums_lower():
     assert _has_aligned_dynamic_offset(st, f)
 
 
-@pytest.mark.parametrize("mode", ["x", "y", "slab-x", "slab-y", "slab-xy"])
+@pytest.mark.parametrize("mode", ["x", "y", "slab-x", "slab-y", "slab-xy",
+                                  "inset"])
 def test_streaming_halo_variants_lower(mode):
     """The halo variants on a 2-D grid with ``nby > 2``: with ``y_halo``
     every y-block's window is one piece at the dynamic ``j * by``; the
     slab-fed kernels stream the unpadded shard (the middle y-blocks'
-    piece at the dynamic ``j * by - HY``) and take slab operands."""
+    piece at the dynamic ``j * by - HY``) and take slab operands; the
+    inset kernel (the overlap split's interior) streams it too, over a
+    grid two x-blocks short."""
     from pystella_tpu.ops.pallas_stencil import HY
     h = 1
     slab = mode.startswith("slab-")
@@ -91,12 +94,15 @@ def test_streaming_halo_variants_lower(mode):
         (16, 32, LANE), 1, h, _lap_body, {"lap": (1,)},
         dtype=jnp.float32, bx=4, by=8, interpret=False,
         x_halo=(mode == "x"), y_halo=(mode == "y"),
-        x_slab=slab and xs, y_slab=slab and ys)
-    assert st.grid == (4, 4)
-    assert st.halo == tuple(
-        ("slab" if slab else "padded") if on else "wrap"
-        for on in (xs, ys))
-    shape = ((1, 16, 32, LANE) if slab
+        x_slab=slab and xs, y_slab=slab and ys, x_inset=mode == "inset")
+    if mode == "inset":
+        assert (st.grid, st.halo) == ((4, 2), ("inset", "wrap"))
+    else:
+        assert st.grid == (4, 4)
+        assert st.halo == tuple(
+            ("slab" if slab else "padded") if on else "wrap"
+            for on in (xs, ys))
+    shape = ((1, 16, 32, LANE) if slab or mode == "inset"
              else (1, 16 + 2 * h, 32, LANE) if mode == "x"
              else (1, 16, 32 + 16, LANE))
     x = jnp.zeros(shape, jnp.float32)
