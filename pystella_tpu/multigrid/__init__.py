@@ -8,6 +8,17 @@ correction-interpolation going up, multigrid/__init__.py:244-283) but are
 *functional*: a cycle maps input arrays to output arrays, and every
 per-level operation is a jitted XLA computation.
 
+The walk's layout: a level's unknowns are ONE ``(nf, X, Y, Z)`` array, the
+stack the stencil kernels take (``multigrid/relax.py``), in the order of
+the solver's ``f_to_rho_dict``; so are its sources, a residual, a
+correction and a tau right-hand side. ``__call__`` stacks the caller's
+unknowns and sources on entry and unstacks the finest unknowns on return
+(three layout copies a cycle, ``mg_cycle.layout_copies``); between them
+every program takes stacks and gives stacks, and a transfer, a norm or an
+add runs once a level and not once a name. One program donates an operand:
+the correction's add writes over the level's unknowns, a stack the walk owns.
+Auxiliary arrays stay by name.
+
 Level placement: fine levels run sharded over the device mesh (halo
 exchange by ``lax.ppermute`` inside ``shard_map``); once a level's local
 block would fall below the stencil/transfer halo, that level and all
@@ -26,14 +37,15 @@ import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from pystella_tpu.obs import events as _events
+from pystella_tpu.obs import memory as _obs_memory
 from pystella_tpu.obs import metrics as _metrics
 from pystella_tpu.obs.scope import host_span, trace_scope
 from pystella_tpu.multigrid.relax import (
-    LevelSpec, RelaxationBase, JacobiIterator, NewtonIterator)
+    LevelSpec, RelaxationBase, JacobiIterator, NewtonIterator, dispatch)
 from pystella_tpu.multigrid.transfer import (
     RestrictionBase, FullWeighting, Injection,
     InterpolationBase, LinearInterpolation, CubicInterpolation,
-    periodic_pad, _local_program, _run_local)
+    periodic_pad, _run_local)
 
 __all__ = [
     "mu_cycle", "v_cycle", "w_cycle", "f_cycle",
@@ -43,6 +55,13 @@ __all__ = [
     "InterpolationBase", "LinearInterpolation", "CubicInterpolation",
     "LevelSpec", "periodic_pad",
 ]
+
+
+#: a level's unknowns plus the interpolated correction, written over the
+#: unknowns' stack (the walk's own: it made it or a program returned it),
+#: so the sum takes no third lattice-sized buffer at the cycle's fullest
+_correct = _obs_memory.instrument_jit(jnp.add, label="mg.correct",
+                                      donate_argnums=0)
 
 
 def mu_cycle(mu, i, nu1, nu2, max_depth):
@@ -116,18 +135,12 @@ class FullApproximationScheme:
         self.restrictor = Restrictor(halo_shape=self.halo_shape)
         Interpolator = kwargs.pop("Interpolator", LinearInterpolation)
         self.interpolator = Interpolator(halo_shape=self.halo_shape)
-        #: error-norm materialization: deferred (device scalars converted
-        #: once at cycle end) keeps the device queue full — per-smooth
-        #: ``float()`` syncs (24 per V-cycle) each drain it. Eager stays
-        #: the default on CPU, where deferring device scalars across a
-        #: 3-axis virtual mesh was measured to abort XLA's CPU runtime.
-        defer = kwargs.pop("defer_errors", None)
-        self._defer_errors = defer
+        #: error-norm materialization (the class docstring): None = auto
+        self._defer_errors = kwargs.pop("defer_errors", None)
         if kwargs:
             raise TypeError(
                 f"{type(self).__name__}() got unexpected keyword "
                 f"argument(s): {', '.join(sorted(kwargs))}")
-        self._transfer_cache = {}
 
     # -- level geometry -----------------------------------------------------
 
@@ -162,34 +175,21 @@ class FullApproximationScheme:
         return jax.device_put(
             x, NamedSharding(decomp.mesh, P(*(None,) * x.ndim)))
 
-    def _transfer_fn(self, op, decomp, key):
-        key = key + (decomp,)
-        cached = self._transfer_cache.get(key)
-        if cached is None:
-            cached = _local_program(
-                op, f"mg.transfer.{type(op).__name__}", decomp)
-            self._transfer_cache[key] = cached
-        return cached
-
     def _restrict(self, decomp, lf, lc, x):
-        """Restrict ``x`` from (fine) level ``lf`` to (coarse) ``lc``.
-        Replicated levels go through ``_run_local``'s jitted path (one
-        executable instead of ~a dozen eager dispatches per transfer —
-        measured as the dominant V-cycle orchestration cost)."""
-        if lc.sharded:
-            return self._transfer_fn(
-                self.restrictor, decomp, ("r", lf.grid_shape))(x)
-        if lf.sharded:
+        """Restrict ``x`` (a stack, or an auxiliary array) from (fine)
+        level ``lf`` to (coarse) ``lc``: one cached program either way
+        (``_run_local``: under ``shard_map`` where the coarse level is
+        sharded, else on the whole replicated array)."""
+        if lf.sharded and not lc.sharded:
             x = self._replicate(decomp, x)
-        return _run_local(self.restrictor, x, None)
+        return dispatch(_run_local, self.restrictor, x,
+                        decomp if lc.sharded else None)
 
     def _interpolate(self, decomp, lc, lf, x):
         """Interpolate ``x`` from (coarse) level ``lc`` to (fine) ``lf``."""
-        if lc.sharded and lf.sharded:
-            return self._transfer_fn(
-                self.interpolator, decomp, ("i", lc.grid_shape))(x)
-        out = _run_local(self.interpolator, x, None)
-        if lf.sharded:
+        out = dispatch(_run_local, self.interpolator, x,
+                       decomp if lc.sharded else None)
+        if lf.sharded and not lc.sharded:
             out = jax.device_put(out, decomp.sharding(out.ndim - 3))
         return out
 
@@ -199,55 +199,56 @@ class FullApproximationScheme:
         """Restrict unknowns and build the tau-corrected coarse rho
         (reference multigrid/__init__.py:244-267)."""
         solver = self.solver
-        unknowns[i] = {n: self._restrict(decomp, levels[i - 1], levels[i], f)
-                       for n, f in unknowns[i - 1].items()}
-        r_fine = solver.residual(levels[i - 1], unknowns[i - 1],
-                                 rhos[i - 1], aux[i - 1], decomp)
-        rr = {n: self._restrict(decomp, levels[i - 1], levels[i], r)
-              for n, r in r_fine.items()}
-        rhos[i] = solver.tau_rhs(levels[i], unknowns[i], rr, aux[i], decomp)
+        fine, coarse = levels[i - 1], levels[i]
+        unknowns[i] = self._restrict(decomp, fine, coarse, unknowns[i - 1])
+        r_fine = solver.residual(fine, unknowns[i - 1], rhos[i - 1],
+                                 aux[i - 1], decomp)
+        rhos[i] = solver.tau_rhs(
+            coarse, unknowns[i], self._restrict(decomp, fine, coarse, r_fine),
+            aux[i], decomp)
 
     def transfer_up(self, decomp, levels, i, unknowns, rhos, aux):
         """Correct the finer level ``i`` by the coarse-grid change
         (reference multigrid/__init__.py:269-283): the correction is the
         smoothed coarse solution minus the restricted fine one, and is
         interpolated up and added."""
-        for n, f_fine in unknowns[i].items():
-            corr = (unknowns[i + 1][n]
-                    - self._restrict(decomp, levels[i], levels[i + 1],
-                                     f_fine))
-            unknowns[i][n] = f_fine + self._interpolate(
-                decomp, levels[i + 1], levels[i], corr)
+        corr = dispatch(
+            jnp.subtract, unknowns[i + 1],
+            self._restrict(decomp, levels[i], levels[i + 1], unknowns[i]))
+        unknowns[i] = dispatch(
+            _correct, unknowns[i],
+            self._interpolate(decomp, levels[i + 1], levels[i], corr))
 
     def smooth(self, levels, i, nu, unknowns, rhos, aux, decomp=None):
         """Relax level ``i`` for ``nu`` sweeps, recording errors before and
         after (reference multigrid/__init__.py:285-302). On accelerator
-        backends the norms stay device scalars until the cycle end
+        backends the norms stay device arrays until the cycle end
         (``__call__`` materializes them once) — eager per-smooth
-        ``float()`` syncs serialize the device queue. On CPU they
-        materialize
+        fetches serialize the device queue. On CPU they materialize
         eagerly (deferring across a 3-axis virtual mesh was measured to
         abort XLA's CPU runtime)."""
         solver = self.solver
         defer = (self._defer_errors if self._defer_errors is not None
                  else jax.default_backend() != "cpu")
-        err_fn = solver.error_arrays if defer else solver.get_error
-        errs1 = err_fn(levels[i], unknowns[i], rhos[i], aux[i], decomp)
+
+        def norms():
+            errs = solver.error_arrays(levels[i], unknowns[i], rhos[i],
+                                       aux[i], decomp)
+            return i, errs if defer else jax.device_get(errs)
+
+        before = norms()
         unknowns[i] = solver.smooth(levels[i], unknowns[i], rhos[i],
                                     aux[i], nu, decomp)
-        errs2 = err_fn(levels[i], unknowns[i], rhos[i], aux[i], decomp)
-        return [(i, errs1), (i, errs2)]
+        return [before, norms()]
 
-    @staticmethod
-    def _materialize_errors(errors):
-        """Convert any deferred device-scalar norms to floats via ONE
-        batched ``device_get`` of the whole record — per-scalar
-        ``float()`` fetches would still pay a device round trip each,
-        defeating the deferral."""
-        fetched = jax.device_get(errors)
-        return [(i, {n: [float(a), float(b)]
-                     for n, (a, b) in errs.items()})
-                for i, errs in fetched]
+    def _materialize_errors(self, errors):
+        """The reference's ``(level, {name: [Linf, L2]})`` record from
+        the walk's ``(level, (Linf, L2))`` pairs, deferred device arrays
+        among them fetched by ONE batched ``device_get`` of the whole
+        record — a fetch an entry would still pay a device round trip
+        each, defeating the deferral."""
+        return [(i, self.solver.named_errors(norms))
+                for i, norms in jax.device_get(errors)]
 
     # -- entry point --------------------------------------------------------
 
@@ -272,8 +273,11 @@ class FullApproximationScheme:
         for i in range(1, depth + 1):
             aux[i] = {k: self._restrict(decomp, levels[i - 1], levels[i], v)
                       for k, v in aux[i - 1].items()}
-        unknowns = {0: dict(unknowns0)}
-        rhos = {0: dict(rhos0)}
+        dispatched, copies = (_metrics.counter(c) for c in (
+            "mg_dispatches", "mg_layout_copies"))
+        dispatches0, copies0 = dispatched.value, copies.value
+        unknowns = {0: solver.stack(unknowns0)}
+        rhos = {0: solver.stack(rhos0, sources=True)}
 
         # host spans of the walk: each goes round dispatches only (a
         # smooth with its two error norms, a transfer), and the one
@@ -299,14 +303,17 @@ class FullApproximationScheme:
                     errors += self.smooth(levels, i, nu, unknowns, rhos,
                                           aux, decomp)
                 previous = i
+            solution = solver.unstack(unknowns[0])
             with host_span("mg_errors_fetch"):
                 materialized = self._materialize_errors(errors)
         _metrics.counter("mg_cycles").inc()
         _metrics.counter("mg_smooths").inc(len(cycle))
         final = materialized[-1][1] if materialized else {}
         _events.emit("mg_cycle", depth=depth, grid_shape=grid_shape,
-                     nsmooths=len(cycle), final_errors=final)
-        return materialized, unknowns[0]
+                     nsmooths=len(cycle), final_errors=final,
+                     dispatches=dispatched.value - dispatches0,
+                     layout_copies=copies.value - copies0)
+        return materialized, solution
 
 
 class MultiGridSolver(FullApproximationScheme):
@@ -318,17 +325,13 @@ class MultiGridSolver(FullApproximationScheme):
     added to the finer solution."""
 
     def transfer_down(self, decomp, levels, i, unknowns, rhos, aux):
-        solver = self.solver
-        r_fine = solver.residual(levels[i - 1], unknowns[i - 1],
-                                 rhos[i - 1], aux[i - 1], decomp)
-        rhos[i] = {}
-        unknowns[i] = {}
-        for n, r in r_fine.items():
-            rr = self._restrict(decomp, levels[i - 1], levels[i], r)
-            rhos[i][solver.f_to_rho_dict[n]] = rr
-            unknowns[i][n] = jnp.zeros_like(rr)
+        r_fine = self.solver.residual(levels[i - 1], unknowns[i - 1],
+                                      rhos[i - 1], aux[i - 1], decomp)
+        rhos[i] = self._restrict(decomp, levels[i - 1], levels[i], r_fine)
+        unknowns[i] = dispatch(jnp.zeros_like, rhos[i])
 
     def transfer_up(self, decomp, levels, i, unknowns, rhos, aux):
-        for n, f_fine in unknowns[i].items():
-            unknowns[i][n] = f_fine + self._interpolate(
-                decomp, levels[i + 1], levels[i], unknowns[i + 1][n])
+        unknowns[i] = dispatch(
+            _correct, unknowns[i],
+            self._interpolate(decomp, levels[i + 1], levels[i],
+                              unknowns[i + 1]))

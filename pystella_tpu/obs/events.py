@@ -187,10 +187,16 @@ for _name, _help in (
     ("perf_report", "a PerfLedger wrote perf_report.json"),
     ("gate_verdict", "the perf gate ran (ok, exit_code, reasons)"),
     # -- numerics / solver hot paths ----------------------------------------
-    ("mg_cycle", "one multigrid cycle (depth, smooths, errors)"),
+    ("mg_cycle", "one multigrid cycle (depth, smooths, errors, "
+                 "dispatches: the programs the walk dispatched, "
+                 "layout_copies: the stack and unstack programs among "
+                 "them, 3 a cycle: unknowns in, sources in, unknowns "
+                 "out)"),
     ("mg_level_plan", "a multigrid level's kernels were built: which "
                       "tier serves it ('streaming' with bx/by/grid, "
-                      "'resident', or 'xla' with the reason)"),
+                      "'resident', or 'xla' with the reason) and the "
+                      "layout its programs take and give ('stacked': "
+                      "one (nf, X, Y, Z) array)"),
     ("mg_transfer_plan", "a multigrid restriction program was traced: "
                          "the operator, the fine grid_shape, the form "
                          "each axis took ('split', 'contract' or, "

@@ -853,5 +853,5 @@ def test_multigrid_walk_has_its_host_spans_and_kernel_kinds(make_decomp):
     for kind in kinds:
         lowered = solver._pallas_level(
             kind, level, decomp, jnp.dtype("float32"), ())._jitted.lower(
-                (f,), (rho,), (), jnp.int32(2))
+                f[None], rho[None], (), jnp.int32(2))
         assert obs.has_scope(lowered, "pallas_stencil_mg_" + kind)

@@ -439,3 +439,126 @@ def test_pallas_smooth_is_nu_single_sweeps(make_decomp, grid_shape,
     smooths = [k for k in solver._compiled
                if k[0] == "pallas" and k[1] == "smooth"]
     assert len(smooths) == 1 and solver._compiled[smooths[0]] is not None
+
+
+# -- the walk's layout: stacks, against the operations by name ---------------
+
+def _cycle_by_name(mg, decomp, dx, cycle, unknowns, rhos):
+    """One cycle of ``mg``'s scheme by the dict interfaces alone, an
+    unknown at a time where the scheme allows it: every smooth, norm,
+    residual, tau right-hand side and transfer is a call of its own on
+    arrays by name (each stacks at its own boundary), as the walk made
+    them before it carried stacks. Returns ``(errors, unknowns)``."""
+    solver = mg.solver
+    f_to_rho = solver.f_to_rho_dict
+    depth = max(i for i, _ in cycle)
+    levels = mg._make_levels(decomp, next(iter(unknowns.values())).shape,
+                             dx, depth)
+    fs, rs, errors = {0: dict(unknowns)}, {0: dict(rhos)}, []
+
+    def restrict(i, x):
+        return mg._restrict(decomp, levels[i], levels[i + 1], x)
+
+    def smooth(i, nu):
+        errors.append((i, solver.get_error(levels[i], fs[i], rs[i], {},
+                                           decomp)))
+        fs[i] = solver.smooth(levels[i], fs[i], rs[i], {}, nu, decomp)
+        errors.append((i, solver.get_error(levels[i], fs[i], rs[i], {},
+                                           decomp)))
+
+    smooth(0, cycle[0][1])
+    previous = 0
+    for i, nu in cycle[1:]:
+        if i == previous + 1:
+            resid = solver.residual(levels[i - 1], fs[i - 1], rs[i - 1], {},
+                                    decomp)
+            rr = {n: restrict(i - 1, r) for n, r in resid.items()}
+            if isinstance(mg, MultiGridSolver):
+                rs[i] = {f_to_rho[n]: r for n, r in rr.items()}
+                fs[i] = {n: 0 * r for n, r in rr.items()}
+            else:
+                fs[i] = {n: restrict(i - 1, f) for n, f in fs[i - 1].items()}
+                rs[i] = solver.tau_rhs(levels[i], fs[i], rr, {}, decomp)
+        else:
+            for n, f in fs[i].items():
+                corr = fs[i + 1][n]
+                if not isinstance(mg, MultiGridSolver):
+                    corr = corr - restrict(i, f)
+                fs[i][n] = f + mg._interpolate(
+                    decomp, levels[i + 1], levels[i], corr)
+        smooth(i, nu)
+        previous = i
+    return errors, fs[0]
+
+
+@pytest.mark.parametrize("MG", [FullApproximationScheme, MultiGridSolver])
+@pytest.mark.parametrize("nf", [1, 2])
+@pytest.mark.parametrize("proc_shape", [(1, 1, 1), (2, 2, 1), (2, 1, 1)],
+                         indirect=True)
+@pytest.mark.parametrize("smoother", ["xla", "pallas"])
+def test_stacked_walk_is_the_operations_by_name(make_decomp, grid_shape,
+                                                proc_shape, smoother, nf,
+                                                MG):
+    """A cycle carries each level's unknowns, sources, residuals and tau
+    right-hand sides as one ``(nf, X, Y, Z)`` stack, stacked once on
+    entry and unstacked once on return (``mg_cycle.layout_copies`` 3,
+    whatever ``nf``), and a transfer, a norm or an add is one program a
+    level: its unknowns and errors are those of the same operations made
+    by name, to float32 rounding; the caller's arrays are neither changed
+    nor donated away, so the same call twice gives the same answer bit
+    for bit (the benchmark's ``repeat_gap``)."""
+    from pystella_tpu.obs import events
+    decomp = make_decomp(proc_shape)
+    dx = 10.0 / grid_shape[0]
+    problems = dict(list(make_problems().items())[-nf:])
+    solver = NewtonIterator(decomp, problems, halo_shape=1, dtype=np.float32,
+                            smoother=smoother,
+                            fixed_parameters=dict(omega=1 / 2))
+    mg = MG(solver=solver, halo_shape=1)
+    cycle = v_cycle(2, 3, 1)
+    names = list(solver.f_to_rho_dict)
+    rng = np.random.default_rng(404)
+    host = dict(zip(names + list(solver.f_to_rho_dict.values()),
+                    (np.asarray(a) for a in zero_mean_arrays(
+                        rng, decomp, grid_shape, 2 * nf, np.float32))))
+    arrays = {k: decomp.shard(v) for k, v in host.items()}
+
+    records = []
+    events.get_log().subscribe(records.append)
+    try:
+        runs = [mg(decomp, dx0=dx, cycle=cycle, **arrays) for _ in range(2)]
+    finally:
+        events.get_log().unsubscribe(records.append)
+    for k, v in arrays.items():  # still there, and still what they were
+        np.testing.assert_array_equal(np.asarray(v), host[k], err_msg=k)
+    (errs, sol), (errs_again, sol_again) = runs
+    assert errs == errs_again
+    for n in names:
+        assert sol[n].dtype == np.float32 and sol[n].shape == grid_shape
+        np.testing.assert_array_equal(np.asarray(sol[n]),
+                                      np.asarray(sol_again[n]), err_msg=n)
+
+    want_errs, want = _cycle_by_name(
+        mg, decomp, dx, cycle, {n: arrays[n] for n in names},
+        {r: arrays[r] for r in solver.f_to_rho_dict.values()})
+    for n in names:
+        got, ref = np.asarray(sol[n]), np.asarray(want[n])
+        assert np.max(np.abs(got - ref)) < 2e-6 * np.max(np.abs(ref)), n
+    assert [i for i, _ in errs] == [i for i, _ in want_errs] == [
+        0, 0, 1, 1, 0, 0]
+    for (_, got), (_, ref) in zip(errs, want_errs):
+        assert list(got) == names
+        for n in names:
+            np.testing.assert_allclose(got[n], ref[n], rtol=2e-4, err_msg=n)
+
+    done = [r["data"] for r in records if r["kind"] == "mg_cycle"]
+    assert [d["layout_copies"] for d in done] == [3, 3]
+    # three smooths of a kernel program and two norms of two programs
+    # each; down, two restrictions, a residual and a tau (the linear
+    # scheme: a restriction, a residual and the zeroed correction); up, a
+    # restriction and a subtraction (the full scheme's alone), an
+    # interpolation and an add; and the three layout copies
+    down_up = 3 + 2 if MG is MultiGridSolver else 4 + 4
+    assert [d["dispatches"] for d in done] == [3 * 5 + down_up + 3] * 2
+    plans = [r["data"] for r in records if r["kind"] == "mg_level_plan"]
+    assert plans and all(d["layout"] == "stacked" for d in plans)

@@ -700,8 +700,8 @@ def _mg_level_compiler(v5e, monkeypatch, n):
         halo_shape=1, dtype=np.float32, smoother="pallas",
         fixed_parameters=dict(omega=1 / 2))
     level = LevelSpec((n,) * 3, (10.0 / n,) * 3, False)
-    x = jax.ShapeDtypeStruct((n,) * 3, jnp.float32,
-                             sharding=decomp.sharding(0))
+    x = jax.ShapeDtypeStruct((2,) + (n,) * 3, jnp.float32,
+                             sharding=decomp.sharding(1))
     nu = jax.ShapeDtypeStruct(
         (), jnp.int32, sharding=NamedSharding(decomp.mesh, P()))
 
@@ -709,7 +709,7 @@ def _mg_level_compiler(v5e, monkeypatch, n):
         fn = solver._pallas_level(kind, level, decomp, jnp.dtype("float32"),
                                   ())
         assert fn is not None, f"{kind} at {n}^3 fell to the XLA path"
-        return fn._jitted.trace((x, x), (x, x), (), nu).lower(
+        return fn._jitted.trace(x, x, (), nu).lower(
             lowering_platforms=("tpu",)).compile()
     return compile
 
@@ -756,7 +756,10 @@ def test_multigrid_smooth_loop_copies_no_lattice(v5e, monkeypatch, n):
     sweeps an iteration the body is two kernel calls whose buffers
     alternate; each still takes two lattice operands (unknowns, sources)
     and gives one output, which is what the benchmark's byte count reads
-    from the instruction."""
+    from the instruction. Since PR 44 the program's parameter is that
+    stack itself and the odd sweep (or a first pair) is taken out of it
+    by a ``cond`` in front of the loop, both of whose branches compute:
+    the whole module holds no lattice-shaped ``copy``."""
     import re
     hlo = _mg_level_compiler(v5e, monkeypatch, n)("smooth").as_text()
     bodies = re.findall(r" while\(.*\bbody=%([\w.]+)", hlo)
@@ -780,23 +783,60 @@ def test_multigrid_smooth_loop_copies_no_lattice(v5e, monkeypatch, n):
             == [f"f32[2,{n},{n},{n}]"] * 2, operands
 
 
-def test_restriction_takes_no_strided_slice_and_no_padded_copy(v5e):
+@pytest.mark.parametrize("kind", ["residual", "smooth"])
+def test_multigrid_level_program_writes_no_lattice_but_its_kernels(
+        v5e, monkeypatch, kind):
+    """The level-0 residual and smooth programs of ``multigrid-512-f32``
+    take the kernels' own ``(2, 512, 512, 512)`` stacks and return one:
+    nothing in the compiled v5e module writes an array of the lattice's
+    size but the Mosaic calls, round them only the plumbing of the
+    ``cond`` and the ``while`` (parameters, tuples and their elements).
+    On PR 44's parent, whose programs took the unknowns and sources by
+    name, this fails on ``%pad_maximum_fusion`` (the two stacks, twice
+    2.15 GB of traffic) and ``%slice_bitcast_fusion`` (the unstack):
+    47 ms a cycle round a 24-ms residual kernel, five times a cycle."""
+    import re
+    hlo = _mg_level_compiler(v5e, monkeypatch, 512)(kind).as_text()
+    plumbing = {"parameter", "tuple", "get-tuple-element", "while",
+                "conditional", "bitcast"}
+    written = set()  # (the entry computation is listed twice)
+    for lines in _computations(hlo).values():
+        for ln in lines:
+            m = re.match(
+                r"\s*(?:ROOT )?%([\w.\-]+) = (\(?[^=]*?\)?) ([\w\-]+)\(", ln)
+            if m and re.search(r"f32\[(2,)?512,512,512\]", m.group(2)):
+                written.add((m.group(1), m.group(3)))
+    kernels = [name for name, op in written if op == "custom-call"]
+    assert len(kernels) == (1 if kind == "residual" else 5), kernels
+    assert all(name.startswith(f"pallas_stencil_mg_{kind}.")
+               for name in kernels), kernels
+    others = [(name, op) for name, op in written
+              if op != "custom-call" and op not in plumbing]
+    assert not others, others
+
+
+@pytest.mark.parametrize("lead", [(), (2,)])
+def test_restriction_takes_no_strided_slice_and_no_padded_copy(v5e, lead):
     """``FullWeighting().apply_local`` at 512**3 float32, the program
     ``multigrid-512-f32.vcycle`` runs six times a pair of levels: no
     stride-2 ``slice`` along either minor axis (on the chip a relayout of
     the ``(8, 128)`` tiles, not a copy: 117 of a V-cycle's 755 ms before
     PR 41, ``jit_transfer_FullWeighting_local/slice``), no periodic pad
     of the block written out (``f32[514,514,514]``), the two minor axes as
-    dot fusions, temporaries under 0.6 GB (1,025 MB with the padded
-    strided slices, on which this test fails)."""
+    dot fusions, temporaries under 0.6 GB an array (1,025 MB with the
+    padded strided slices, on which this test fails). The same of the
+    ``(2, 512, 512, 512)`` stack the walk restricts since PR 44 (``lead``):
+    the leading axis rides through the split and both contractions, and
+    brings no copy of its own."""
     import re
     from pystella_tpu.multigrid import FullWeighting
     decomp = ps.DomainDecomposition((1, 1, 1), devices=v5e[:1])
-    x = jax.ShapeDtypeStruct((512,) * 3, jnp.float32,
-                             sharding=decomp.sharding(0))
+    x = jax.ShapeDtypeStruct(lead + (512,) * 3, jnp.float32,
+                             sharding=decomp.sharding(len(lead)))
     compiled = compile_tpu(FullWeighting().apply_local, x)
     hlo = compiled.as_text()
-    assert "f32[514,514,514]" not in hlo
+    assert "514,514,514]" not in hlo
+    assert not re.search(r"= f32\[[\d,]*\]\S* copy\(", hlo)
     for ln in hlo.splitlines():
         strides = re.search(r"\bslice\(.*slice=\{(.*?)\}", ln)
         if strides:
@@ -804,9 +844,11 @@ def test_restriction_takes_no_strided_slice_and_no_padded_copy(v5e):
             assert (y or "1", z or "1") == ("1", "1"), ln.strip()[:200]
     dots = [ln for ln in hlo.splitlines()
             if " fusion(" in ln and "kind=kOutput" in ln]
+    pre = "".join(f"{n}," for n in lead)
     assert [re.search(r"= (f32\[[\d,]*\])", ln).group(1) for ln in dots] \
-        == ["f32[256,256,512]", "f32[256,256,256]"], dots
-    assert compiled.memory_analysis().temp_size_in_bytes < 0.6e9
+        == [f"f32[{pre}256,256,512]", f"f32[{pre}256,256,256]"], dots
+    assert compiled.memory_analysis().temp_size_in_bytes \
+        < 0.6e9 * int(np.prod(lead))
 
 
 # -- the ``--halo-shape 0`` programs (``preheat-spectral-f32``) --------------

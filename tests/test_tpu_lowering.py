@@ -238,11 +238,10 @@ def test_multigrid_smoother_lowers():
                               aux_struct)
     if fn is None:
         pytest.skip("level does not admit the pallas smoother tier")
-    f_list = (jnp.zeros(lvl_grid, jnp.float32),)
-    rho_list = (jnp.zeros(lvl_grid, jnp.float32),)
-    # _pallas_level caches a jitted entry taking per-field tuples
-    # (stacking happens inside the jit); trace it for TPU
-    lower_tpu(lambda a, b: fn(a, b, (), jnp.int32(2)), f_list, rho_list)
+    fstack = jnp.zeros((1,) + lvl_grid, jnp.float32)
+    # _pallas_level caches a jitted program from the unknowns' and the
+    # sources' (nf, X, Y, Z) stacks to a stack; trace it for TPU
+    lower_tpu(lambda a, b: fn(a, b, (), jnp.int32(2)), fstack, fstack)
 
 
 def test_chunk4_multi_step_lowers():
