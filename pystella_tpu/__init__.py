@@ -50,8 +50,6 @@ from pystella_tpu import resilience
 from pystella_tpu.resilience import (
     DeviceSubsetFault, FaultInjector, RecoveryFailed, RemeshPlanner,
     RetryPolicy, Supervisor)
-from pystella_tpu import service
-from pystella_tpu.service import ScenarioRequest, ScenarioService
 from pystella_tpu.utils import (Checkpointer, HealthMonitor,
     SimulationDiverged, OutputFile, ShardedSnapshot, StepTimer, timer,
     trace, advise_shapes)
@@ -102,7 +100,6 @@ __all__ = [
     "EnsembleMonitor",
     "resilience", "Supervisor", "FaultInjector", "RetryPolicy",
     "RecoveryFailed", "RemeshPlanner", "DeviceSubsetFault",
-    "service", "ScenarioService", "ScenarioRequest",
     "ElementWiseMap",
     "FirstCenteredDifference", "SecondCenteredDifference",
     "FiniteDifferencer",

@@ -121,7 +121,7 @@ register("PYSTELLA_EVENT_ROTATE_MB", default=None, kind="float",
          help="size-triggered event-log rollover in MiB: when the live "
               "JSONL file reaches this size, obs.events.EventLog "
               "renames it to <stem>.<n>.jsonl and opens a fresh file, "
-              "so a persistent server cannot grow one unbounded log; "
+              "so a long-lived process cannot grow one unbounded log; "
               "ledger ingestion reads the whole rotated family; unset "
               "disables rotation")
 register("PYSTELLA_HALO_OVERLAP", default="auto", kind="bool",
@@ -187,152 +187,6 @@ register("PYSTELLA_FAULT_DEVICE_SUBSET_PERSIST", default="1", kind="bool",
               "lost, and only a re-meshed program that no longer "
               "touches them replays through cleanly; 0 makes it a "
               "one-shot transient like the other fault kinds")
-register("PYSTELLA_SERVICE_SLOTS", default="4", kind="int",
-         help="batch slots per scenario-service lease "
-              "(service.ScenarioService): each scheduler dispatch "
-              "leases up to this many shape-compatible requests to one "
-              "batched EnsembleStepper program")
-register("PYSTELLA_SERVICE_CHUNK", default="2", kind="int",
-         help="steps per batched dispatch inside a scenario-service "
-              "lease; preemption and checkpointing happen at chunk "
-              "boundaries, so this is also the preemption-latency "
-              "granularity")
-register("PYSTELLA_SERVICE_COLD_POLICY", default="compile",
-         help="admission policy for a request whose (model, lattice, "
-              "mesh) signature has no warm-pool entry "
-              "(service.AdmissionController): 'compile' admits it "
-              "queued behind the build+compile of a fresh pool entry "
-              "(its time-to-first-step then pays the compile), "
-              "'reject' refuses it with a typed ColdSignature verdict")
-register("PYSTELLA_SERVICE_QUOTA", default="64", kind="int",
-         help="per-tenant admission quota of the scenario service's "
-              "fair-share scheduler: submissions beyond this many "
-              "queued requests for one tenant are rejected "
-              "(service_reject event, reason 'quota') instead of "
-              "letting one tenant starve the others")
-register("PYSTELLA_SERVICE_PREEMPT", default="1", kind="bool",
-         help="priority preemption in the scenario service: 1 "
-              "(default) lets a pending request of a strictly higher "
-              "priority class preempt a running lease at the next "
-              "chunk boundary (drain -> durable checkpoint -> "
-              "requeue, no work lost); 0 runs every lease to "
-              "completion")
-register("PYSTELLA_LIVE_PORT", default="0", kind="int",
-         help="TCP port of the opt-in in-process live telemetry "
-              "endpoint (obs.live: /metrics Prometheus exposition, "
-              "/healthz liveness+readiness, /slo burn-rate state), "
-              "bound to 127.0.0.1 on a daemon thread around "
-              "ScenarioService.serve(); 0 (default) or unset disables "
-              "the live plane entirely — emit paths and event logs "
-              "are then byte-identical to a build without it")
-register("PYSTELLA_SLO_FAST_WINDOW_S", default="60", kind="float",
-         help="fast window in seconds of the live SLO burn-rate "
-              "monitor (obs.slo.SLOMonitor): an alert fires only when "
-              "the windowed metric breaches its bar over BOTH the "
-              "fast window (it is still happening) and the slow "
-              "window (it is sustained), and resolves when the fast "
-              "window recovers or empties")
-register("PYSTELLA_SLO_SLOW_WINDOW_S", default="300", kind="float",
-         help="slow window in seconds of the live SLO burn-rate "
-              "monitor — the sustained-breach half of the fast/slow "
-              "multi-window alert rule")
-register("PYSTELLA_SLO_MIN_SAMPLES", default="1", kind="int",
-         help="minimum samples the fast window must hold before a "
-              "percentile/rate SLO leg may fire (count-kind legs are "
-              "exempt — their value IS the sample count); raise it on "
-              "a busy service so a single outlier dispatch cannot "
-              "page")
-register("PYSTELLA_PERF", default="1", kind="bool",
-         help="continuous-performance plane master switch (obs.perf): "
-              "1 (default) lets StepTimer and the scenario service's "
-              "dispatch loop feed the process-default step-time "
-              "digest + CUSUM change-point detector; 0 disables the "
-              "plane entirely — observe() is a no-op and the default "
-              "monitor is never constructed")
-register("PYSTELLA_PERF_WINDOW", default="64", kind="int",
-         help="healthy-baseline reference window (samples) of the "
-              "continuous-performance CUSUM detector "
-              "(obs.perf.CusumDetector): location/scale are the "
-              "median/MAD over the last this-many healthy samples "
-              "per program signature; the window freezes while an "
-              "anomaly is open so the baseline cannot absorb the "
-              "regression it is reporting")
-register("PYSTELLA_PERF_MIN_SAMPLES", default="16", kind="int",
-         help="samples the reference window must hold before the "
-              "continuous-performance detector may fire — warmup and "
-              "short runs stay quiet")
-register("PYSTELLA_PERF_CUSUM_K", default="0.5", kind="float",
-         help="CUSUM slack in sigmas (obs.perf): a sample only "
-              "accumulates drift when it exceeds baseline + k*sigma; "
-              "also the recovery band — perf_recovered needs the "
-              "recent samples back below that bar")
-register("PYSTELLA_PERF_CUSUM_H", default="8.0", kind="float",
-         help="CUSUM fire threshold in accumulated clipped sigmas "
-              "(obs.perf): per-sample increments are clipped at 4 "
-              "sigma, so with the default 8.0 a single spike cannot "
-              "fire — only >= 2 consecutive far-outliers (or a longer "
-              "run of modest ones) accumulate past it")
-register("PYSTELLA_PERF_RECOVER_N", default="5", kind="int",
-         help="consecutive in-band samples (below baseline + k*sigma) "
-              "after which an open perf anomaly emits perf_recovered "
-              "and the CUSUM accumulator resets")
-register("PYSTELLA_PERF_CAPTURE_DIR", default=None, kind="path",
-         help="artifact root of the anomaly-triggered flight recorder "
-              "(obs.perf.FlightRecorder): when set, a fired "
-              "perf_anomaly starts a rate-limited jax.profiler "
-              "capture of the next PYSTELLA_PERF_CAPTURE_STEPS steps "
-              "and writes the Perfetto trace under this directory "
-              "(perf_capture event carries the path); unset (default) "
-              "disables automatic capture — anomalies still fire, "
-              "nothing is profiled")
-register("PYSTELLA_PERF_CAPTURE_STEPS", default="8", kind="int",
-         help="steps the anomaly-triggered flight recorder keeps the "
-              "profiler running before closing the capture and "
-              "emitting perf_capture")
-register("PYSTELLA_PERF_CAPTURE_COOLDOWN_S", default="600", kind="float",
-         help="minimum seconds between anomaly-triggered profiler "
-              "capture starts — the rate limit: an anomaly storm "
-              "produces at most one trace per cooldown plus a "
-              "suppression count, not a disk full of traces")
-register("PYSTELLA_FLEET_DIR", default=None, kind="path",
-         help="shared replica-registry directory of the fleet "
-              "observability plane (service.registry / obs.fleet): "
-              "when set, ScenarioService.serve() announces a "
-              "heartbeated JSON record there (replica id, live URL, "
-              "stack fingerprint, warm-pool fingerprints, queue "
-              "depth) and withdraws it on exit; unset (default) "
-              "disables the fleet plane entirely")
-register("PYSTELLA_FLEET_HEARTBEAT_S", default="2.0", kind="float",
-         help="cadence in seconds at which a fleet replica rewrites "
-              "its registry record (service.registry.ReplicaRegistry); "
-              "each beat refreshes the dynamic fields (queue depth, "
-              "serving state, warm fingerprints); <= 0 announces once "
-              "and never beats (tests)")
-register("PYSTELLA_FLEET_EXPIRE_S", default="10", kind="float",
-         help="heartbeat age in seconds past which registry readers "
-              "(obs.fleet.FleetAggregator, service status --fleet) "
-              "treat a replica record as stale/dead — a crashed "
-              "replica cannot tombstone itself, so expiry is how the "
-              "fleet notices; keep it several heartbeats wide")
-register("PYSTELLA_FLEET_SCRAPE_TIMEOUT_S", default="2.0", kind="float",
-         help="per-endpoint HTTP timeout in seconds for one fleet "
-              "scrape of a replica's /metrics, /slo, /healthz "
-              "(obs.fleet.FleetAggregator); a replica slower than "
-              "this counts as a scrape failure, not a hang of the "
-              "whole aggregation pass")
-register("PYSTELLA_TRACE_SERVICE", default="1", kind="bool",
-         help="request-scoped distributed tracing in the scenario "
-              "service: 1 (default) allocates a trace id per "
-              "ScenarioRequest and threads trace/span/parent fields "
-              "(event schema v2) through submission, dispatch, the "
-              "supervised lease loop, and retire, so obs.spans can "
-              "assemble per-request critical-path latency; 0 emits "
-              "v1-shaped events with no trace context")
-register("PYSTELLA_TRACE_EXPORT", default=None, kind="path",
-         help="default Perfetto output path for the assembled service "
-              "span timeline: `python -m pystella_tpu.obs.spans` "
-              "writes the request-timeline trace file there when no "
-              "explicit --perfetto is given; unset writes none")
 register("PYSTELLA_FFT_SCHEME", default="auto",
          help="distributed-FFT scheme the planner (fourier.plan."
               "make_dft) and the spectra/projector/Poisson consumers "
@@ -348,31 +202,6 @@ register("PYSTELLA_FFT_REPLICATE_LIMIT", default="1073741824",
               "raises instead of silently replicating the k-space "
               "array on every device (override per-instance with "
               "replicate_limit=/allow_replicate=)")
-register("PYSTELLA_CAPACITY_HEADROOM", default="0.9", kind="float",
-         help="memory-aware admission budget as a fraction of device "
-              "HBM capacity (obs.capacity.CapacityMonitor): resident "
-              "warm-pool programs + the candidate lease's predicted "
-              "footprint must fit capacity x this, else the request is "
-              "rejected with a typed CapacityExceeded verdict")
-register("PYSTELLA_CAPACITY_POLICY", default="reject",
-         help="what memory-aware admission does on overcommit: "
-              "'reject' (default) refuses the request outright "
-              "(capacity_reject event), 'evict' first drops idle "
-              "warm-pool entries not backing queued work "
-              "(capacity_evict events) and re-checks — "
-              "queue-behind-eviction")
-register("PYSTELLA_CAPACITY_BYTES", default=None, kind="int",
-         help="device-capacity override in bytes for the admission "
-              "budget; unset uses the allocator's bytes_limit from "
-              "device.memory_stats(), and where neither exists (CPU) "
-              "the capacity check skips honestly (decision reason "
-              "'no-capacity-limit') instead of guessing")
-register("PYSTELLA_CAPACITY_DIR",
-         help="persistence directory for predicted HBM footprints "
-              "(obs.capacity.FootprintLedger, *.footprint.json beside "
-              "the warm-start artifacts); unset falls back to "
-              "PYSTELLA_WARMSTART_DIR, and with neither set the "
-              "ledger stays in-memory")
 
 # ---------------------------------------------------------------------------
 # driver knobs (examples, the gate's CLI)
@@ -395,7 +224,7 @@ register("PYSTELLA_GATE_COMM_EXCESS_PCT", default="25", kind="float",
 register("XLA_FLAGS", default=None, scope="external",
          help="XLA compiler/runtime flags; scheduler-relevant entries "
               "are fingerprinted into perf reports "
-              "(obs.ledger.xla_flag_fingerprint)")
+              "(obs.memory.flags_fingerprint)")
 register("LIBTPU_INIT_ARGS", default=None, scope="external",
          help="libtpu init flags; the package sets none, the "
               "scheduler-relevant ones present are fingerprinted into "

@@ -63,6 +63,7 @@ import jax
 from pystella_tpu import config as _config
 from pystella_tpu.obs import events as _events
 from pystella_tpu.obs import metrics as _metrics
+from pystella_tpu.obs.sentinel import Sentinel
 from pystella_tpu.ensemble.batch import EnsembleStepper
 from pystella_tpu.ensemble.health import EnsembleMonitor
 
@@ -117,21 +118,15 @@ class Scenario:
 
 
 class _Job:
-    __slots__ = ("scenario", "seed", "resume", "trace")
+    __slots__ = ("scenario", "seed", "resume")
 
-    def __init__(self, scenario, seed, resume=None, trace=None):
+    def __init__(self, scenario, seed, resume=None):
         self.scenario = scenario
         self.seed = int(seed)
         #: ``(state, step, t, params)`` for a job re-entering with a
         #: restored trajectory (a preempted member) instead of a fresh
         #: sampler draw — see :meth:`EnsembleDriver.requeue`
         self.resume = resume
-        #: optional request-scoped trace id (obs schema v2): the
-        #: member lifecycle events carry it, so a caller that owns
-        #: traces (the scenario service, a traced sweep harness) can
-        #: attribute driver work per request — and a requeued member
-        #: keeps its trace across the drain
-        self.trace = trace
 
 
 class _Slot:
@@ -194,8 +189,6 @@ class EnsembleDriver:
         unstarted jobs in ``pending``. :meth:`requeue` is the matching
         re-entry: a drained member resumes its OWN trajectory (bit-
         consistent with an uninterrupted run) instead of resampling.
-        This is the scenario service's preemption hook
-        (:mod:`pystella_tpu.service`).
     """
 
     def __init__(self, size=None, chunk=4, decomp=None, via="auto",
@@ -230,14 +223,12 @@ class EnsembleDriver:
 
     # -- queue --------------------------------------------------------------
 
-    def submit(self, scenario, seeds, trace=None):
+    def submit(self, scenario, seeds):
         """Enqueue one job per seed for ``scenario`` (FIFO; grouping
-        into shape-compatible batches happens at :meth:`run`).
-        ``trace`` optionally tags every job's member lifecycle events
-        with a request-scoped trace id (obs schema v2)."""
+        into shape-compatible batches happens at :meth:`run`)."""
         seeds = [int(s) for s in seeds]
         for s in seeds:
-            self._queue.append(_Job(scenario, s, trace=trace))
+            self._queue.append(_Job(scenario, s))
         nxt = self._next_seed.get(scenario.name, 0)
         self._next_seed[scenario.name] = max([nxt] + [s + 1 for s in seeds])
         return self
@@ -248,7 +239,7 @@ class EnsembleDriver:
         return s
 
     def requeue(self, scenario, state, step, seed=0, params=None,
-                t=None, trace=None):
+                t=None):
         """Re-enter a preempted member: the job re-joins the queue
         carrying its RESTORED state and completed step count, so its
         slot resumes the same trajectory instead of resampling from
@@ -261,13 +252,10 @@ class EnsembleDriver:
         requeued member's trajectory is bit-consistent with its
         uninterrupted run — the batched per-member bodies are
         lane-independent, so neither the preemption boundary nor the
-        co-members of the resumed batch change its arithmetic.
-        ``trace`` carries the member's request-scoped trace id across
-        the drain — the requeued member's events keep ONE trace."""
+        co-members of the resumed batch change its arithmetic."""
         job = _Job(scenario, seed,
                    resume={"state": state, "step": int(step),
-                           "t": t, "params": dict(params or {})},
-                   trace=trace)
+                           "t": t, "params": dict(params or {})})
         self._queue.append(job)
         nxt = self._next_seed.get(scenario.name, 0)
         self._next_seed[scenario.name] = max(nxt, int(seed) + 1)
@@ -400,14 +388,12 @@ class EnsembleDriver:
 
     def _run_group(self, group, results, evictions, totals, on_finish,
                    preempted=None, pending=None):
-        from pystella_tpu import obs
-
         jobs = list(group["jobs"])
         template_state, template_params = group["template"]
         stepper = jobs[0].scenario.stepper
         ens = EnsembleStepper(stepper, self.size, decomp=self.decomp,
                               via=self.via, donate=self.donate)
-        sentinel = obs.Sentinel.for_state(
+        sentinel = Sentinel.for_state(
             template_state, invariants=jobs[0].scenario.invariants)
         monitor = self._make_monitor(sentinel)
 
@@ -534,12 +520,9 @@ class EnsembleDriver:
                            for n in params},
             }
             preempted.append(rec)
-            rec["trace"] = s.job.trace
-            with _events.tracing(trace=s.job.trace):
-                _events.emit("member_preempted", label=self.label,
-                             member=s.index,
-                             scenario=s.job.scenario.name,
-                             seed=s.job.seed, step=s.steps_done)
+            _events.emit("member_preempted", label=self.label,
+                         member=s.index, scenario=s.job.scenario.name,
+                         seed=s.job.seed, step=s.steps_done)
             s.active = False
             monitor.mask_member(s.index)
         pending += [self._pending_record(j) for j in jobs]
@@ -552,11 +535,8 @@ class EnsembleDriver:
         resume payload — dropping it would silently restart the member
         from step 0, losing the work the earlier drain preserved;
         resubmit such a record with :meth:`requeue`, plain ones with
-        :meth:`submit`. The job's trace id rides along (pass it back
-        as ``trace=``) so an unstarted traced job keeps one trace
-        across the drain, like the started members do."""
-        rec = {"scenario": job.scenario, "seed": job.seed,
-               "trace": job.trace}
+        :meth:`submit`."""
+        rec = {"scenario": job.scenario, "seed": job.seed}
         if job.resume is not None:
             rec.update(state=job.resume["state"],
                        step=job.resume["step"], t=job.resume["t"],
@@ -583,13 +563,10 @@ class EnsembleDriver:
                            params={**draw, "seed": job.seed,
                                    "dt": slot.dt},
                            scenario=sc.name)
-        with _events.tracing(trace=job.trace):
-            _events.emit("member_started", label=self.label,
-                         member=slot.index, scenario=sc.name,
-                         seed=job.seed,
-                         resumed_from=(slot.steps_done
-                                       if job.resume is not None
-                                       else None))
+        _events.emit("member_started", label=self.label,
+                     member=slot.index, scenario=sc.name, seed=job.seed,
+                     resumed_from=(slot.steps_done
+                                   if job.resume is not None else None))
 
     def _handle_evictions(self, new_ev, slots, batch, ens, params,
                           t_vec, dt_vec, monitor, chunk_index,
@@ -652,9 +629,7 @@ class EnsembleDriver:
             }
             results.append(record)
             _metrics.counter("ensemble_members_completed").inc()
-            with _events.tracing(trace=job.trace):
-                _events.emit("member_finished", label=self.label,
-                             **record)
+            _events.emit("member_finished", label=self.label, **record)
             if on_finish is not None:
                 on_finish(record, ens.take_member(batch, slot.index))
             if jobs:

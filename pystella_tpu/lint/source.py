@@ -34,8 +34,8 @@ need not even be importable):
 - ``event-registry`` — every literal event kind passed to an
   ``emit(...)`` call must be registered in
   :func:`pystella_tpu.obs.events.registered_event_kinds` (same pattern
-  as the scope registry): the span assembler's and ledger's kind
-  vocabulary cannot silently drift from the emit sites.
+  as the scope registry): the ledger's kind vocabulary cannot silently
+  drift from the emit sites.
 
 Plus a doc-coverage check when linting the real package:
 
@@ -217,15 +217,13 @@ class _FileChecker(ast.NodeVisitor):
 
         # event-registry: literal kinds handed to any emit(...) call
         # (obs.events.emit, EventLog.emit, a `log`/`sink` variable —
-        # the method NAME is the contract; non-literal first args,
-        # e.g. ResultEmitter.emit(request, ...), are simply not kinds).
-        # A kind= keyword literal counts the same, and so do the
-        # private _emit(kind, ...) wrappers (resilience.retry,
-        # obs.perf) — both would otherwise drift
-        # past the registry silently. The keyword check is scoped to
-        # emit calls on purpose: kind= elsewhere means something else
-        # entirely (config.register's value type, the SLO monitor's
-        # window statistic).
+        # the method NAME is the contract; non-literal first args are
+        # simply not kinds). A kind= keyword literal counts the same,
+        # and so does the private _emit(kind, ...) wrapper
+        # (resilience.retry), which would otherwise drift past the
+        # registry silently. The keyword check is scoped to emit calls
+        # on purpose: kind= elsewhere means something else entirely
+        # (config.register's value type).
         if attr in ("emit", "_emit"):
             if node.args:
                 lit = _literal_str(node.args[0])

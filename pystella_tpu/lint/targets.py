@@ -278,7 +278,8 @@ def build_ensemble_step(size=4):
     # WHOLE population's HBM footprint, `size` times the single-run
     # cost)
     stepper = ps.LowStorageRK54(full_rhs, dt=dt, donate=True)
-    ens = stepper.batched(size, decomp=decomp, via="vmap", donate=True)
+    ens = ps.EnsembleStepper(stepper, size, decomp=decomp, via="vmap",
+                             donate=True)
 
     rng = np.random.default_rng(23)
     members = []

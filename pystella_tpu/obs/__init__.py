@@ -62,65 +62,12 @@ step critical path:
   slopes, sentinel overhead) and the gate fails CI on a
   constraint-drift regression exactly like a step-time regression.
 
-The REQUEST TRACING layer (PR 13) makes the scenario service's latency
-causal, not just measured: event schema v2 carries
-``trace``/``span``/``parent`` fields through an ambient
-:func:`~pystella_tpu.obs.events.tracing` context, and
-:mod:`pystella_tpu.obs.spans` (``python -m pystella_tpu.obs.spans``)
-reassembles them into per-request span trees — critical-path phase
-decomposition, the deadline-miss ledger, and a Perfetto-loadable
-service timeline sharing the hardware traces' scope vocabulary. The
-ledger's ``latency`` section and the gate's deadline-miss SLO consume
-it; :func:`~pystella_tpu.obs.events.registered_event_kinds` is the
-central emit vocabulary the source lint audits.
-
-The LIVE OPERATIONS PLANE (PR 14) is the other half of the
-production-telemetry split — everything above is post-hoc, while a
-persistent service needs scrape-time truth:
-
-- :mod:`pystella_tpu.obs.live` — an opt-in stdlib ``http.server``
-  endpoint on a daemon thread (``PYSTELLA_LIVE_PORT``, 0 = off):
-  ``/metrics`` Prometheus exposition of the metrics registry plus the
-  scenario service's live gauges (queue depth per class/tenant, active
-  leases, warm-pool fingerprint health, last-chunk member-steps/s),
-  ``/healthz`` liveness+readiness from the serve loop and supervisor
-  state, ``/slo`` the current burn-rate state.
-- :mod:`pystella_tpu.obs.slo` — a rolling-window SLO monitor fed by the
-  :meth:`EventLog.subscribe <pystella_tpu.obs.events.EventLog.
-  subscribe>` in-process push hook (not log tailing): queue-p95, warm
-  TTFS, deadline-miss rate, and incident rate as fast/slow multi-window
-  burn rates against the SAME factor+floor bars the gate uses, emitting
-  ``slo_alert``/``slo_resolved`` events so live alerts become
-  gate-visible evidence — the ledger's ``alerts`` section counts them
-  and the gate refuses an unresolved burn alert beside a green post-hoc
-  SLO section.
-
-The CONTINUOUS-PERFORMANCE PLANE (PR 17) watches for the regression
-nobody pages on — performance *drift*:
-
-- :mod:`pystella_tpu.obs.perf` — per-program-signature rolling
-  step-time quantile digests (p50/p95/p99, count-vector mergeable
-  across hosts) fed by every :class:`~pystella_tpu.utils.profiling.
-  StepTimer` tick and the scenario service's dispatch loop; a robust
-  CUSUM change-point detector emitting ``perf_anomaly`` /
-  ``perf_recovered`` (routed into the SLO monitor's
-  ``perf_regression`` burn leg); and an anomaly-triggered, rate-limited
-  ``jax.profiler`` flight recorder whose Perfetto artifacts land as
-  ``perf_capture`` events — the evidence is captured while the
-  regression is live, not after an operator notices.
-- :mod:`pystella_tpu.obs.stragglers` — cross-host step-time skew
-  attribution naming the slowest host in every anomaly payload.
-- the ledger gains a ``perf`` report section (anomaly rollup, digest
-  summaries, linked captures) and the gate refuses a report whose
-  unresolved ``perf_anomaly`` sits beside a green step-time verdict.
-
 See ``doc/observability.md`` for the event schema and driver recipes.
 """
 
 from pystella_tpu.obs.events import (
-    EventLog, configure, current_trace, emit, get_log, new_span_id,
-    new_trace_id, read_events, register_event_kind,
-    registered_event_kinds, tracing)
+    EventLog, configure, emit, get_log, read_events, register_event_kind,
+    registered_event_kinds)
 from pystella_tpu.obs.metrics import (
     Counter, Gauge, MetricsRegistry, Timer, counter, gauge, registry, timer)
 from pystella_tpu.obs.scope import (
@@ -129,28 +76,24 @@ from pystella_tpu.obs.scope import (
 from pystella_tpu.obs.memory import (
     CompileRecord, compile_totals,
     compile_watch, compile_with_report, device_memory_report,
-    device_memory_stats, ensure_compilation_cache, instrument_jit,
-    program_fingerprint, runtime_versions,
-    signature_fingerprint)
-# obs.gate, obs.warmstart, and obs.spans are deliberately NOT imported
-# here: their primary entry points are ``python -m pystella_tpu.obs.gate``
-# / ``... .obs.warmstart`` / ``... .obs.spans``, and runpy warns when
-# the module is already in sys.modules at -m execution time. Import
-# them explicitly (``from pystella_tpu.obs import gate, spans,
-# warmstart``) for programmatic use.
-from pystella_tpu.obs import forensics, ledger, perf, sentinel, stragglers, trace
-from pystella_tpu.obs.ledger import PerfLedger, environment_fingerprint
-from pystella_tpu.obs.perf import (
-    CusumDetector, Digest, FlightRecorder, PerfMonitor)
+    device_memory_stats, ensure_compilation_cache,
+    environment_fingerprint, instrument_jit, program_fingerprint,
+    runtime_versions, signature_fingerprint)
+# obs.gate and obs.warmstart are deliberately NOT imported here: their
+# primary entry points are ``python -m pystella_tpu.obs.gate`` /
+# ``... .obs.warmstart``, and runpy warns when the module is already in
+# sys.modules at -m execution time. Import them explicitly (``from
+# pystella_tpu.obs import gate, warmstart``) for programmatic use.
+from pystella_tpu.obs import forensics, ledger, sentinel, trace
+from pystella_tpu.obs.ledger import PerfLedger
 from pystella_tpu.obs.trace import scope_durations, summarize_trace
 from pystella_tpu.obs.sentinel import (
     Sentinel, SentinelMonitor, SimulationDiverged)
 from pystella_tpu.obs.forensics import ForensicSink, load_bundle, write_bundle
 
 __all__ = [
-    "EventLog", "configure", "current_trace", "emit", "get_log",
-    "new_span_id", "new_trace_id", "read_events",
-    "register_event_kind", "registered_event_kinds", "tracing",
+    "EventLog", "configure", "emit", "get_log", "read_events",
+    "register_event_kind", "registered_event_kinds",
     "Counter", "Gauge", "Timer", "MetricsRegistry",
     "counter", "gauge", "timer", "registry",
     "trace_scope", "host_span", "recording", "lowered_scopes",
@@ -160,9 +103,8 @@ __all__ = [
     "compile_totals", "instrument_jit", "ensure_compilation_cache",
     "program_fingerprint", "signature_fingerprint", "runtime_versions",
     "device_memory_report", "device_memory_stats",
-    "trace", "ledger", "sentinel", "forensics", "perf", "stragglers",
+    "trace", "ledger", "sentinel", "forensics",
     "PerfLedger", "environment_fingerprint",
-    "CusumDetector", "Digest", "FlightRecorder", "PerfMonitor",
     "scope_durations", "summarize_trace",
     "Sentinel", "SentinelMonitor", "SimulationDiverged",
     "ForensicSink", "load_bundle", "write_bundle",

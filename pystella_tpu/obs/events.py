@@ -22,25 +22,12 @@ key          meaning
              ``event-registry`` check enforces it, the way the scope
              registry gates trace-scope literals)
 ``step``     simulation step number, or ``null``
-``trace``    request-scoped trace id (v2, OPTIONAL — present only
-             when a :func:`tracing` context was active at emit time;
-             absent fields must be tolerated so v1 logs still ingest)
-``span``     the causal span this event belongs to (v2, optional)
-``parent``   the span's parent span id (v2, optional)
 ``data``     kind-specific payload (flat, JSON-safe)
 ===========  ======================================================
 
-The v2 ``trace``/``span``/``parent`` fields are the distributed-tracing
-layer: a trace id is allocated per
-:class:`~pystella_tpu.service.ScenarioRequest` and propagated through
-scheduler, admission, lease dispatch, the supervisor's chunk loop,
-checkpoint barriers, recovery, and retire — the
-:class:`~pystella_tpu.obs.spans.SpanAssembler` reconstructs per-request
-span trees and critical-path latency from exactly these fields. They
-ride an ambient thread-local context (:func:`tracing`), so existing
-``emit()`` call sites gain them without signature changes, and code
-emitting outside any context produces records indistinguishable from
-v1 apart from the version number.
+Readers take a record by these keys and ignore any other (a version-1
+log, or an older version-2 log with ``trace``/``span``/``parent``
+keys, still ingests).
 
 This module is importable without jax (a supervisor that must stay
 off the chip can log through it); the host id is resolved lazily from
@@ -51,8 +38,6 @@ Usage::
     from pystella_tpu import obs
     obs.configure("run_events.jsonl")       # or env PYSTELLA_EVENT_LOG
     obs.emit("checkpoint_save", step=1200, path="ckpts/1200")
-    with obs.events.tracing(trace=tid, span=sid):
-        obs.emit("service_dispatch", ...)   # carries trace/span/parent
     ...
     for ev in obs.read_events("run_events.jsonl"):
         ...
@@ -63,77 +48,17 @@ is a disabled sink and :func:`emit` costs one attribute check.
 
 from __future__ import annotations
 
-import contextlib
 import json
 import os
-import secrets
 import sys
 import threading
 import time
 
-__all__ = ["EventLog", "configure", "current_trace", "emit", "get_log",
-           "new_span_id", "new_trace_id", "read_events",
+__all__ = ["EventLog", "configure", "emit", "get_log", "read_events",
            "register_event_kind", "registered_event_kinds",
-           "rotated_family", "tracing", "SCHEMA_VERSION"]
+           "rotated_family", "SCHEMA_VERSION"]
 
 SCHEMA_VERSION = 2
-
-
-# ---------------------------------------------------------------------------
-# trace context: the request-scoped causal-span layer (schema v2)
-# ---------------------------------------------------------------------------
-
-def new_trace_id():
-    """A fresh 16-hex-char trace id (one per request lifecycle; a
-    preempted-and-requeued request KEEPS its trace id across leases)."""
-    return secrets.token_hex(8)
-
-
-def new_span_id():
-    """A fresh 8-hex-char span id (one per causal span: the request
-    root, each lease, each recovery incident)."""
-    return secrets.token_hex(4)
-
-
-_trace_tls = threading.local()
-
-
-def current_trace():
-    """The innermost active :func:`tracing` context as a dict
-    (``trace``/``span``/``parent``), or ``None``."""
-    stack = getattr(_trace_tls, "stack", None)
-    return stack[-1] if stack else None
-
-
-@contextlib.contextmanager
-def tracing(trace=None, span=None, parent=None):
-    """Attach trace/span/parent fields to every event emitted inside
-    (this thread only; telemetry from helper threads degrades to
-    context-less v1-shaped records rather than mis-attributing).
-
-    Fields not given inherit from the enclosing context, with one
-    causal rule: opening a NEW span (``span=`` given, ``parent=`` not)
-    records the enclosing span as its parent — so nesting
-    ``tracing(trace=T, span=ROOT)`` → ``tracing(span=LEASE)`` emits
-    lease-scoped events carrying ``parent=ROOT`` without the inner
-    site knowing the outer ids."""
-    outer = current_trace() or {}
-    ctx = {
-        "trace": trace if trace is not None else outer.get("trace"),
-        "span": span if span is not None else outer.get("span"),
-        "parent": parent if parent is not None else (
-            outer.get("span") if span is not None
-            and span != outer.get("span")
-            else outer.get("parent")),
-    }
-    stack = getattr(_trace_tls, "stack", None)
-    if stack is None:
-        stack = _trace_tls.stack = []
-    stack.append(ctx)
-    try:
-        yield ctx
-    finally:
-        stack.pop()
 
 
 # ---------------------------------------------------------------------------
@@ -143,8 +68,8 @@ def tracing(trace=None, span=None, parent=None):
 #: kind -> one-line description; seeded below with the in-tree
 #: vocabulary. The source lint's ``event-registry`` check audits every
 #: ``emit("<literal>", ...)`` in the package against this registry
-#: (same pattern as ``obs.scope.register_scope``), so the span
-#: assembler's kind vocabulary cannot silently drift from emit sites.
+#: (same pattern as ``obs.scope.register_scope``), so the ledger's
+#: kind vocabulary cannot silently drift from emit sites.
 _KIND_REGISTRY = {}
 
 
@@ -178,8 +103,6 @@ for _name, _help in (
     ("warmstart_gc", "stale AOT artifacts collected"),
     ("trace_summary", "per-scope duration table from a Perfetto capture"),
     ("trace_missing", "a profiler capture produced no trace file"),
-    ("service_trace", "assembled service span timeline exported "
-                      "(Perfetto-loadable, obs.spans)"),
     ("health", "one decoded sentinel health vector"),
     ("diverged", "sentinel trip (non-finite fields / bound violation)"),
     ("forensic_bundle", "a sentinel trip wrote a forensic bundle"),
@@ -269,91 +192,6 @@ for _name, _help in (
     ("member_evicted", "a member was evicted by the per-member sentinel"),
     ("member_preempted", "a driver drain captured a member as a requeue "
                          "record"),
-    # -- scenario service ---------------------------------------------------
-    ("service_start", "scenario-service serve loop began (policy config)"),
-    ("service_done", "scenario-service serve totals"),
-    ("service_request", "one request entered ingestion (traced root)"),
-    ("service_admit", "admission verdict (warm/cold, fingerprint)"),
-    ("service_reject", "typed rejection (quota / cold_signature)"),
-    ("service_arm", "a warm-pool entry was armed (compile paid here)"),
-    ("service_dispatch", "a request entered a lease (queue latency)"),
-    ("service_lease", "a lease finished or drained (TTFS, compile watch)"),
-    ("service_preempted", "a lease drained for a higher priority class"),
-    ("service_requeue", "an unfinished request re-entered the queue with "
-                        "its restored state"),
-    ("service_lease_failed", "a lease's supervision gave up; requests "
-                             "requeued"),
-    ("member_result", "one retired member's streamed analytics + "
-                      "deadline margin"),
-    ("deadline_missed", "a deadlined request retired after its deadline "
-                        "(margin_s < 0)"),
-    ("service_loadgen", "the synthetic-mix summary"),
-    # -- live operations plane (obs.live / obs.slo) -------------------------
-    ("live_serve", "the in-process telemetry endpoint came up "
-                   "(port, endpoints)"),
-    ("slo_alert", "a rolling-window SLO burn-rate alert FIRED "
-                  "(obs.slo.SLOMonitor; leg, windowed value, bar)"),
-    ("slo_resolved", "a burning SLO leg recovered below its bar "
-                     "(duration_s since the matching slo_alert)"),
-    ("obs_subscriber_error", "an EventLog emit subscriber raised; the "
-                             "emit path degraded it to this one-time "
-                             "event instead of breaking"),
-    # -- continuous-performance plane (obs.perf / obs.stragglers) -----------
-    ("perf_digest", "one signature's step-time digest window report "
-                    "(p50/p95/p99 ms + straggler attribution)"),
-    ("perf_anomaly", "the CUSUM change-point detector fired on a "
-                     "sustained step-time shift (signature, baseline, "
-                     "straggler attribution)"),
-    ("perf_recovered", "an anomalous signature's step times returned "
-                       "to the baseline band (duration_s since the "
-                       "matching perf_anomaly)"),
-    ("perf_capture", "an anomaly-triggered flight-recorder profiler "
-                     "capture closed (Perfetto artifact path, "
-                     "rate-limit suppression count)"),
-    ("perf_loadgen", "the seeded continuous-performance drill summary "
-                     "(service.loadgen.run_perf)"),
-    # -- fleet observability plane (service.registry / obs.fleet) -----------
-    ("fleet_announce", "a serving replica published its registry record "
-                       "(replica id, url, stack fingerprint)"),
-    ("fleet_withdraw", "a replica withdrew its registry record cleanly "
-                       "(tombstone written, heartbeats stopped)"),
-    ("fleet_scrape", "one fleet aggregation pass: per-replica scrape "
-                     "outcomes, merged fleet SLO legs, skew/divergence"),
-    ("fleet_replica_lost", "a previously-live replica went dark without "
-                           "withdrawing (heartbeat expired or endpoint "
-                           "unreachable)"),
-    ("fleet_alert", "a fleet-level SLO burn-rate alert FIRED "
-                    "(obs.fleet.FleetAggregator; leg, value, bar)"),
-    ("fleet_resolved", "a burning fleet SLO leg recovered below its "
-                       "bar (duration_s since the matching "
-                       "fleet_alert)"),
-    ("fleet_loadgen", "the two-replica fleet drill summary "
-                      "(service.loadgen.run_fleet)"),
-    # -- capacity & goodput plane (obs.capacity) ----------------------------
-    ("capacity_footprint", "a program's predicted HBM footprint "
-                           "recorded (fingerprint, bytes, source: "
-                           "memory_analysis or aval_estimate)"),
-    ("capacity_stale", "a persisted footprint was refused "
-                       "(version/flag drift — the warmstart staleness "
-                       "rule) or none existed"),
-    ("capacity_watermark", "one per-chunk live allocator sample "
-                           "(bytes_in_use / peak_bytes_in_use / "
-                           "headroom fraction)"),
-    ("capacity_reject", "memory-aware admission refused a request: "
-                        "resident + predicted footprint exceeded "
-                        "capacity x headroom (CapacityExceeded)"),
-    ("capacity_evict", "the evict admission policy dropped an idle "
-                       "warm-pool entry to make room for a candidate "
-                       "lease"),
-    ("capacity_oom", "a RESOURCE_EXHAUSTED lease failure wrote an OOM "
-                     "forensic bundle (footprint table, watermark "
-                     "series, the admitting decision)"),
-    ("capacity_account", "one request's retire-time chip-second "
-                         "account (phases x chip share, committed "
-                         "steps, waste, goodput)"),
-    ("capacity_usage", "the serve loop's capacity/goodput rollup "
-                       "(per-tenant chargeback table, reconciliation, "
-                       "watermark coverage)"),
     # -- driver-side kinds (examples, and what a driver may hand the
     # -- ledger; outside the package, so not lint-audited, but
     # -- registered so the vocabulary is one list)
@@ -436,13 +274,12 @@ class EventLog:
         for a disabled sink whose :meth:`emit` is a cheap no-op.
     :arg host: override the host id (default: lazy jax process index).
     :arg rotate_bytes: size-triggered rollover for long-lived processes
-        (the scenario service runs for days — one unbounded JSONL is an
-        operational hazard): when the live file reaches this size after
-        a write, it is renamed to the next ``<stem>.<n>.jsonl`` member
-        of the rotated family (:func:`rotated_family`) and a fresh file
-        is opened at ``path``. Default: the registered
-        ``PYSTELLA_EVENT_ROTATE_MB`` (unset disables). Rotation never
-        splits a line — whole events only.
+        (one unbounded JSONL is an operational hazard): when the live
+        file reaches this size after a write, it is renamed to the next
+        ``<stem>.<n>.jsonl`` member of the rotated family
+        (:func:`rotated_family`) and a fresh file is opened at ``path``.
+        Default: the registered ``PYSTELLA_EVENT_ROTATE_MB`` (unset
+        disables). Rotation never splits a line — whole events only.
 
     Thread-safe; every line is flushed on write so concurrently-appending
     processes (a supervisor and its workers) interleave whole lines.
@@ -489,8 +326,8 @@ class EventLog:
         otherwise two writers would leapfrog-rotate each other's fresh
         files. Lines the laggard wrote into the rotated member before
         noticing remain there (whole, just earlier in the family), so
-        the family read stays lossless; single-writer logs (the normal
-        service deployment) rotate exactly at the threshold."""
+        the family read stays lossless; single-writer logs rotate
+        exactly at the threshold."""
         try:
             st_fd = os.fstat(self._file.fileno())
             try:
@@ -528,19 +365,20 @@ class EventLog:
     def enabled(self):
         return self._file is not None
 
-    # -- subscribers: the in-process push channel (live SLO monitors) -------
+    # -- subscribers: the in-process push channel ---------------------------
 
     def subscribe(self, fn):
         """Register ``fn(record)`` to receive every emitted record
-        in-process, immediately after the write — the push channel the
-        live SLO monitor (:mod:`pystella_tpu.obs.slo`) rides instead of
-        tailing the log file. Subscribers survive size-triggered
-        rotation (they hang off the log object, not the file handle)
-        but NOT :func:`configure` (which builds a fresh log). A
-        subscriber that raises never breaks the emit path: the failure
-        degrades to a one-time ``obs_subscriber_error`` event and the
-        subscriber stays registered (the fault may be transient).
-        Returns ``fn`` so a lambda can be kept for :meth:`unsubscribe`.
+        in-process, immediately after the write: how a harness reads a
+        run's plan events (``block_choice``, ``kernel_tier``, ...)
+        without tailing the log file. Subscribers survive
+        size-triggered rotation (they hang off the log object, not the
+        file handle) but NOT :func:`configure` (which builds a fresh
+        log). A subscriber that raises never breaks the emit path: the
+        failure is reported once on stderr and the subscriber stays
+        registered; one that itself emits has that record written but
+        not pushed again. Returns ``fn`` so a lambda can be kept for
+        :meth:`unsubscribe`.
         """
         if fn not in self._subscribers:
             self._subscribers.append(fn)
@@ -554,14 +392,10 @@ class EventLog:
             pass
 
     def _notify(self, rec):
-        """Push ``rec`` to subscribers, outside the write lock (a
-        subscriber may itself emit — e.g. the SLO monitor's
-        ``slo_alert``) and re-entrancy-guarded per thread: an emit made
-        FROM a subscriber callback is written normally but not pushed
-        again, so a monitor that emits alerts cannot recurse through
-        its own hook."""
-        if not self._subscribers:
-            return
+        """Push ``rec`` to subscribers, outside the write lock and
+        re-entrancy-guarded per thread: an emit made FROM a subscriber
+        is written normally but not pushed again, so a subscriber that
+        emits cannot recurse through its own hook."""
         if getattr(self._notify_tls, "active", False):
             return
         self._notify_tls.active = True
@@ -576,9 +410,6 @@ class EventLog:
                               f"{fn!r} raised ({type(e).__name__}: {e});"
                               " telemetry continues without it",
                               file=sys.stderr)
-                        self.emit("obs_subscriber_error",
-                                  subscriber=repr(fn),
-                                  error=f"{type(e).__name__}: {e}")
         finally:
             self._notify_tls.active = False
 
@@ -586,11 +417,9 @@ class EventLog:
         """Append one event; returns the record dict (``None`` when
         nothing consumed it: a disabled, subscriber-less sink, or a
         failed write — telemetry is best-effort by design and must
-        never kill the instrumented run). The ambient :func:`tracing`
-        context, when active on this thread, lands as the v2
-        ``trace``/``span``/``parent`` fields. Registered subscribers
+        never kill the instrumented run). Registered subscribers
         (:meth:`subscribe`) receive the record after the write — also
-        on a file-less sink, so a live monitor works without a log."""
+        on a file-less sink, so a tap works without a log."""
         if self._file is None and not self._subscribers:
             # cheap pre-check; file re-read under the lock
             return None
@@ -600,11 +429,6 @@ class EventLog:
                "kind": str(kind),
                "step": None if step is None else int(step),
                "data": _jsonify(data)}
-        ctx = current_trace()
-        if ctx:
-            for key in ("trace", "span", "parent"):
-                if ctx.get(key) is not None:
-                    rec[key] = ctx[key]
         written = False
         if self._file is not None:
             line = json.dumps(rec)

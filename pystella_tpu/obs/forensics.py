@@ -37,7 +37,7 @@ import sys
 import time
 
 from pystella_tpu.obs import events as _events
-from pystella_tpu.obs import ledger as _ledger
+from pystella_tpu.obs.memory import environment_fingerprint
 
 __all__ = ["BUNDLE_SCHEMA_VERSION", "ForensicSink", "load_bundle",
            "write_bundle"]
@@ -128,7 +128,7 @@ def write_bundle(out_dir, step, reason, bad_fields=(),
         "health_history": _jsonify(list(history)),
         "field_history": _jsonify(_field_history(history)),
         "events_tail": events_tail,
-        "env": _ledger.environment_fingerprint(),
+        "env": environment_fingerprint(),
         "env_vars": env,
         "config": _jsonify(config) if config is not None else None,
         "last_good_checkpoint": _checkpoint_pointer(checkpoint),

@@ -19,18 +19,7 @@ Exit codes (CI and the armed-hardware-revalidation scripts key on them):
       (member-steps/s) drops more than ``ensemble_threshold_pct`` below
       the baseline's — or a SPECTRAL regression: the ``fft`` section's
       spectra p50 ms/call exceeds the baseline's by more than
-      ``fft_threshold_pct`` — or a SERVICE SLO regression: the
-      ``service`` section's queue-latency p95 (or warm-lease
-      time-to-first-step p50) exceeds the baseline's by both the
-      configured factor and floor — or a DEADLINE-MISS SLO regression:
-      the ``latency`` section's deadline-miss rate exceeds the
-      baseline's by both ``latency_miss_factor`` and
-      ``latency_miss_floor`` (``--no-latency`` opts out; traced
-      requests whose span tree fails to assemble degrade to a
-      coverage-loss warning) — or a FLEET SLO regression: the
-      ``fleet`` section's aggregated queue-p95 or warm-TTFS exceeds
-      the baseline's by both the configured factor and floor
-      (``--no-fleet`` opts out) — or a COMM EXCESS: a ``comm`` leg's
+      ``fft_threshold_pct`` — or a COMM EXCESS: a ``comm`` leg's
       measured collective traffic exceeds the dataflow lint tier's
       static model by more than ``comm_excess_pct`` (the model is an
       upper bound on what the program's collectives can move per
@@ -41,23 +30,10 @@ Exit codes (CI and the armed-hardware-revalidation scripts key on them):
       signature), the report has no step samples, the run DIVERGED (a
       sentinel trip in the ``numerics`` section — broken step times
       prove nothing), the report CLAIMS warm start over AOT artifacts
-      whose fingerprints mismatch the live compiler stack, the
-      ``service`` section claims warm ADMISSIONS over mismatched
-      fingerprints (the leases did not dispatch the programs the
-      admission contract names), the report claims fewer incidents
-      than its ``resilience`` event record carries (a clean headline
-      over a degraded fleet), the report's ``alerts`` section carries a
-      live burn alert UNRESOLVED at exit while the matching post-hoc
-      SLO section claims green (the live and post-hoc halves
-      contradict; ``--no-alerts`` opts out, alert-FLAP growth merely
-      warns), the report's ``perf`` section carries a ``perf_anomaly``
-      UNRESOLVED at exit while the post-hoc step-time verdict claims
-      green (same contradiction for the continuous-performance plane;
-      ``--no-perf`` opts out), the report's ``fleet`` section claims COMPLETE fleet
-      coverage while its own scrape record shows lost replicas or
-      failed scrapes (fleet aggregates over the survivors are partial
-      evidence; an HONESTLY-partial fleet record is annotated
-      degraded instead), the report's ``comm`` section claims
+      whose fingerprints mismatch the live compiler stack, the report
+      claims fewer incidents than its ``resilience`` event record
+      carries (a clean headline over a degraded fleet), the report's
+      ``comm`` section claims
       modeled-vs-measured coverage (``covered: true``) while no leg
       actually carries a static model (a coverage claim with nothing
       behind it — the dataflow lint tier never ran, or the section
@@ -89,13 +65,9 @@ outlier fraction, or a bimodal step-time distribution marks the run
 ``invalid_evidence`` — *neither pass nor fail*, because a contaminated
 measurement can prove nothing in either direction.
 
-The module body is stdlib-only on purpose (report comparison must not
-require a working accelerator stack), but the ``python -m`` entry point
-imports the ``pystella_tpu`` package — and therefore jax — like any
-in-repo CI environment has. A truly jax-free supervisor should call
-:func:`compare_reports` from a by-file module load
-(``importlib.util.spec_from_file_location``), loading ``ledger.py`` the
-same way first.
+The comparison itself touches no device: it reads two JSON reports
+(importing this module imports the ``pystella_tpu`` package, and
+therefore jax, like any in-repo CI environment has).
 """
 
 from __future__ import annotations
@@ -242,18 +214,7 @@ def compare_reports(baseline, current, threshold_pct=10.0, mad_k=3.0,
                     check_ensemble=True, ensemble_threshold_pct=20.0,
                     check_resilience=True,
                     check_fft=True, fft_threshold_pct=25.0,
-                    check_comm=True, comm_excess_pct=25.0,
-                    check_service=True, service_queue_factor=2.5,
-                    service_queue_floor_s=0.5,
-                    service_ttfs_factor=2.5,
-                    service_ttfs_floor_s=1.0,
-                    check_latency=True, latency_miss_factor=2.0,
-                    latency_miss_floor=0.05, check_alerts=True,
-                    check_fleet=True, fleet_queue_factor=2.5,
-                    fleet_queue_floor_s=0.5, fleet_ttfs_factor=2.5,
-                    fleet_ttfs_floor_s=1.0, check_perf=True,
-                    check_capacity=True, goodput_factor=2.0,
-                    goodput_floor=1.0, reconciliation_warn_pct=25.0):
+                    check_comm=True, comm_excess_pct=25.0):
     """Pure comparison core (the CLI is a thin wrapper; tests drive
     this). Returns a verdict dict with ``exit_code``.
 
@@ -330,54 +291,6 @@ def compare_reports(baseline, current, threshold_pct=10.0, mad_k=3.0,
     survivors; the ledger produces it automatically from the
     ``remesh_plan`` record) — and a run that finished degraded
     without any ``remesh_plan`` record warns (unauditable).
-
-    ``check_fleet`` (default on): the federation half of the same
-    honesty rule, for reports carrying a ``fleet`` section
-    (:mod:`pystella_tpu.obs.fleet`). A report whose fleet coverage
-    block claims ``complete`` while its own scrape record shows lost
-    replicas or failed scrapes is refused (exit 2) — fleet aggregates
-    over the survivors are partial evidence. The honest version of the
-    same record (coverage says partial) is annotated
-    (``verdict["degraded"]`` + warning), never silently accepted.
-    Against a baseline, fleet queue-p95 and fleet warm-TTFS regress
-    under the same factor+floor bars as the single-replica service
-    legs (exit 1); version/flag skew appearing, warm-fingerprint
-    divergence, and fleet-alert flap growth warn. ``--no-fleet`` opts
-    out.
-
-    ``check_perf`` (default on): the continuous-performance half of
-    the alert-evidence rule, for reports carrying a ``perf`` section
-    (:mod:`pystella_tpu.obs.perf`). A ``perf_anomaly`` still
-    unresolved when the run record ended — the change-point detector
-    watched a sustained step-time shift never recover — beside a GREEN
-    post-hoc step-time verdict is the same live/post-hoc contradiction
-    as an unresolved burn alert: invalid evidence, exit 2
-    (``--no-perf`` opts out). An unresolved anomaly whose post-hoc
-    step verdict also failed is corroboration (warning). Anomalies
-    that fired with NO flight-recorder capture recorded warn (the
-    profiling evidence the plane exists to capture is missing —
-    usually ``PYSTELLA_PERF_CAPTURE_DIR`` unset); anomaly-flap growth
-    and lost perf coverage warn like the other sections.
-
-    ``check_capacity`` (default on): the capacity-and-goodput half of
-    the evidence rule, for reports carrying a ``capacity`` section
-    (:mod:`pystella_tpu.obs.capacity`). A report whose capacity
-    coverage block claims ``complete`` watermark coverage while
-    recording ZERO live watermark samples is refused (exit 2) — a
-    full-coverage reconciliation claim with no device readings behind
-    it proves nothing. The honest version (coverage says
-    ``predicted_only``, the CPU degrade) is annotated
-    (``verdict["degraded"]`` + warning), never silently accepted, and
-    a predicted-vs-measured reconciliation error beyond
-    ``reconciliation_warn_pct`` warns (the footprint model is
-    drifting from the device). Against a baseline, **goodput**
-    (committed member-steps per chip-second) regresses DOWNWARD: the
-    gate fails (exit 1) when current goodput drops below baseline /
-    ``goodput_factor`` AND by more than ``goodput_floor``
-    steps/chip-s absolute — the factor+floor shape of every other SLO
-    leg, with the inequality flipped because higher is better. Waste
-    chip-second growth (replay + preempt-drain share) and lost
-    capacity coverage warn. ``--no-capacity`` opts out.
     """
     verdict = {"ok": True, "exit_code": 0, "reasons": [],
                "warnings": []}
@@ -525,142 +438,6 @@ def compare_reports(baseline, current, threshold_pct=10.0, mad_k=3.0,
                 "warmstart: stale artifact refused, cold fallback "
                 f"taken: {a.get('label')!r} "
                 f"({a.get('reason') or a.get('fingerprint')})")
-
-    if check_service:
-        csv = current.get("service") or {}
-        if csv.get("warm_claimed"):
-            bad = [a for a in csv.get("warm_admissions") or []
-                   if a.get("fingerprint_ok") is False]
-            if bad:
-                # the report says requests were admitted WARM — served
-                # from the ready pool, latency = dispatch — over
-                # program fingerprints that do not match the live
-                # compiler stack: whatever those leases dispatched, it
-                # was not the programs the admission contract names;
-                # neither pass nor fail
-                verdict.update(ok=False, exit_code=2)
-                for a in bad[:5]:
-                    verdict["reasons"].append(
-                        "invalid_evidence: report claims warm "
-                        "admission over a mismatched fingerprint: "
-                        f"request {a.get('id')} "
-                        f"({a.get('fingerprint')})")
-                return verdict
-        if csv.get("warm_lease_backend_compiles"):
-            # an honest-but-broken warm path: the fingerprints match
-            # but the compile ledger recorded backend compiles inside
-            # warm leases — the dispatch-never-compile contract
-            # regressed; warn loudly (the TTFS comparison below is
-            # what fails CI when it costs latency)
-            verdict["warnings"].append(
-                "service: "
-                f"{csv['warm_lease_backend_compiles']} backend "
-                "compile(s) recorded inside warm leases — the warm "
-                "path is supposed to be pure dispatch; check the "
-                "service section's lease records")
-
-    if check_fleet:
-        cfl = current.get("fleet") or {}
-        cov = cfl.get("coverage") or {}
-        lossy = bool((cfl.get("replicas_lost") or [])
-                     or (cov.get("endpoint_failed") or 0) > 0)
-        if cfl and cov.get("complete") and lossy:
-            # the report CLAIMS its fleet numbers cover the whole
-            # fleet while its own scrape record shows replicas lost or
-            # scrapes failed: whatever the aggregated legs measured,
-            # it was the survivors — a full-fleet throughput/SLO claim
-            # over partial evidence proves nothing either way
-            verdict.update(ok=False, exit_code=2)
-            verdict["reasons"].append(
-                "invalid_evidence: report claims complete fleet "
-                "coverage but its scrape record shows "
-                f"{len(cfl.get('replicas_lost') or [])} lost "
-                f"replica(s) and {cov.get('endpoint_failed') or 0} "
-                "failed scrape(s) — fleet aggregates over the "
-                "survivors are partial evidence, not a fleet claim")
-            return verdict
-        if cfl and lossy:
-            # the honest version of the same record: the report SAYS
-            # its coverage is partial — degraded evidence, annotated
-            # like a recovered incident, never silently accepted
-            verdict["degraded"] = True
-            lost_ids = sorted({str(r.get("replica"))
-                               for r in cfl.get("replicas_lost") or []})
-            verdict["warnings"].append(
-                "fleet: degraded fleet evidence — "
-                f"{len(lost_ids)} replica(s) lost mid-run "
-                f"({', '.join(lost_ids) or '?'}), scrape success "
-                f"{cfl.get('scrape_success_rate')} — fleet legs "
-                "aggregate the survivors; see the report's fleet "
-                "section before trusting fleet-wide claims")
-
-    if check_capacity:
-        ccap = current.get("capacity") or {}
-        ccov = ccap.get("coverage") or {}
-        n_samples = ccov.get("watermark_samples")
-        if ccap and ccov.get("complete") and not n_samples:
-            # the report CLAIMS its footprint reconciliation covered
-            # every lease with live watermarks while recording zero
-            # device samples: the "measured" side of the ledger never
-            # existed, so the reconciliation (and any OOM headroom
-            # claim built on it) proves nothing either way
-            verdict.update(ok=False, exit_code=2)
-            verdict["reasons"].append(
-                "invalid_evidence: report claims complete capacity "
-                "coverage but records 0 live watermark sample(s) — "
-                "a predicted-vs-measured reconciliation with no "
-                "device readings is not evidence of headroom")
-            return verdict
-        if ccap and ccov.get("predicted_only"):
-            # the honest CPU degrade: no device.memory_stats() on
-            # this host, so the ledger carries predictions only —
-            # annotated, never silently accepted as measured headroom
-            verdict["degraded"] = True
-            verdict["warnings"].append(
-                "capacity: predicted-only footprint evidence (no "
-                "live watermark samples on this host) — HBM "
-                "headroom claims rest on the aval/memory-analysis "
-                "model, not device readings")
-        rec = ccap.get("reconciliation") or {}
-        rel = rec.get("rel_err")
-        if isinstance(rel, (int, float)) \
-                and abs(rel) > reconciliation_warn_pct / 100.0:
-            verdict["warnings"].append(
-                "capacity: predicted footprints disagree with the "
-                f"measured HBM peak by {abs(rel):.0%} (warn bar "
-                f"{reconciliation_warn_pct:g}%) — the footprint "
-                "model is drifting from the device; re-arm with "
-                "fresh compile records before trusting admission "
-                "decisions")
-
-    if check_latency:
-        clat = current.get("latency") or {}
-        bad_asm = clat.get("unassembled") or []
-        n_bad = clat.get("unassembled_total")
-        if not isinstance(n_bad, int):
-            n_bad = len(bad_asm)  # pre-truncation-marker reports
-        if n_bad:
-            # traced requests whose span tree failed to close: the
-            # latency attribution silently lost coverage — warn (the
-            # requests may legitimately still be in flight, so this is
-            # evidence quality, not invalid evidence)
-            verdict["warnings"].append(
-                f"latency: {n_bad} traced request(s) failed to "
-                "assemble a span tree — critical-path coverage was "
-                "lost; see the report's latency.unassembled list")
-        chk = clat.get("phase_sum_check") or {}
-        if chk.get("ok") is False:
-            err = chk.get("max_rel_err")
-            tol = chk.get("tolerance")
-            detail = (
-                f" (worst rel err {err:.2%} over tolerance {tol:.0%})"
-                if isinstance(err, (int, float))
-                and isinstance(tol, (int, float)) else "")
-            verdict["warnings"].append(
-                "latency: the critical-path phases do not sum to the "
-                f"measured wall time{detail} — the span record is "
-                "internally inconsistent; treat phase attribution "
-                "with care")
 
     cur_num = current.get("numerics") or {}
     if check_numerics and cur_num.get("diverged"):
@@ -811,181 +588,13 @@ def compare_reports(baseline, current, threshold_pct=10.0, mad_k=3.0,
     if check_comm:
         _check_comm(verdict, baseline, current,
                     excess_pct=comm_excess_pct)
-    if check_service:
-        _compare_service(verdict, baseline, current,
-                         queue_factor=service_queue_factor,
-                         queue_floor_s=service_queue_floor_s,
-                         ttfs_factor=service_ttfs_factor,
-                         ttfs_floor_s=service_ttfs_floor_s)
-    if check_latency:
-        _compare_latency(verdict, baseline, current,
-                         miss_factor=latency_miss_factor,
-                         miss_floor=latency_miss_floor)
-    if check_fleet:
-        _compare_fleet(verdict, baseline, current,
-                       queue_factor=fleet_queue_factor,
-                       queue_floor_s=fleet_queue_floor_s,
-                       ttfs_factor=fleet_ttfs_factor,
-                       ttfs_floor_s=fleet_ttfs_floor_s)
-    if check_capacity:
-        _compare_capacity(verdict, baseline, current,
-                          goodput_factor=goodput_factor,
-                          goodput_floor=goodput_floor)
     if check_resilience and (baseline or {}).get("resilience") \
             and not current.get("resilience"):
         verdict["warnings"].append(
             "resilience: baseline carried a resilience section but the "
             "current run has none — incident/checkpoint coverage was "
             "lost")
-    if check_alerts:
-        _check_alerts(verdict, baseline, current)
-    if check_perf:
-        _check_perf(verdict, baseline, current)
     return verdict
-
-
-def _check_alerts(verdict, baseline, current):
-    """Live-alert consistency audit (mutates ``verdict`` in place; runs
-    AFTER the post-hoc SLO comparisons because it needs their
-    outcomes). The ``alerts`` report section
-    (:mod:`pystella_tpu.obs.slo` via the ledger) is the live half of
-    each SLO; the post-hoc sections are the other. The two must agree:
-
-    - an **unresolved-at-exit burn alert** for a leg whose post-hoc
-      verdict came out GREEN is a live/post-hoc contradiction — the
-      monitor watched the SLO burn until the record ended while the
-      report claims the SLO held, so one of them is wrong and the
-      evidence proves nothing either way: invalid evidence, exit 2
-      (``--no-alerts`` opts out). An unresolved alert whose post-hoc
-      leg ALSO failed is consistent (the gate already failed; the
-      alert is corroboration, noted as a warning).
-    - **alert-flap growth** (more fire→resolve→fire churn than the
-      baseline recorded) warns: a flapping SLO is a bar sitting on the
-      noise floor or a service oscillating around saturation — either
-      deserves an operator before it deserves a page.
-    - lost coverage (baseline carried an ``alerts`` section, current
-      does not) warns like every other section."""
-    cal = current.get("alerts") or {}
-    bal = (baseline or {}).get("alerts") or {}
-    if bal and not cal:
-        verdict["warnings"].append(
-            "alerts: baseline carried a live-alert (SLO burn) section "
-            "but the current run has none — live SLO coverage was "
-            "lost; attach the SLOMonitor (obs.slo)")
-        return
-    if not cal:
-        return
-    reasons = verdict.get("reasons") or []
-    # which post-hoc legs came out green (no failing reason / no
-    # recorded incidents)? keyed by the monitor's leg names
-    post_hoc_green = {
-        "queue_p95": not any("queue-latency p95" in r for r in reasons),
-        "warm_ttfs": not any("warm time-to-first-step" in r
-                             for r in reasons),
-        "deadline_miss": not any("deadline-miss SLO regression" in r
-                                 for r in reasons),
-        "incident_rate": not (current.get("resilience")
-                              or {}).get("n_incidents"),
-    }
-    for rec in cal.get("unresolved") or []:
-        leg = str(rec.get("leg"))
-        if post_hoc_green.get(leg, True):
-            verdict.update(ok=False, exit_code=2)
-            verdict["reasons"].append(
-                f"invalid_evidence: live burn alert {leg!r} was still "
-                f"firing when the run record ended (value "
-                f"{rec.get('value')} vs bar {rec.get('bar')}) but the "
-                "post-hoc SLO section claims green — the live and "
-                "post-hoc halves contradict; trust neither")
-        else:
-            verdict["warnings"].append(
-                f"alerts: unresolved live burn alert {leg!r} "
-                "corroborates the failed post-hoc verdict for the "
-                "same SLO")
-    b_flaps = bal.get("flaps")
-    c_flaps = cal.get("flaps")
-    if isinstance(b_flaps, int) and isinstance(c_flaps, int) \
-            and c_flaps > b_flaps:
-        verdict["warnings"].append(
-            f"alerts: {c_flaps} alert flap(s) vs {b_flaps} in the "
-            "baseline — an SLO oscillating around its bar; check the "
-            "report's alerts section before trusting either verdict")
-    verdict["alerts"] = {
-        "alerts": cal.get("alerts"), "resolved": cal.get("resolved"),
-        "flaps": c_flaps, "unresolved": len(cal.get("unresolved") or []),
-    }
-
-
-def _check_perf(verdict, baseline, current):
-    """Continuous-performance consistency audit (mutates ``verdict``
-    in place; runs AFTER the step-time comparison because it needs its
-    outcome). The ``perf`` report section
-    (:mod:`pystella_tpu.obs.perf` via the ledger) is the live
-    change-point record of the same step times the post-hoc median
-    comparison gates; the two must agree:
-
-    - an **unresolved-at-exit** ``perf_anomaly`` beside a GREEN
-      post-hoc step-time verdict is a live/post-hoc contradiction —
-      the detector watched a sustained shift never recover while the
-      report claims step times held: invalid evidence, exit 2
-      (``--no-perf`` opts out). Unresolved beside an already-failed
-      step verdict is corroboration (warning).
-    - anomalies that fired with **no flight-recorder capture**
-      recorded warn: the plane's whole point is profiling evidence
-      captured while the regression was live
-      (``PYSTELLA_PERF_CAPTURE_DIR`` probably unset).
-    - **anomaly-flap growth** vs the baseline and lost perf coverage
-      warn like the alert section's equivalents."""
-    cpf = current.get("perf") or {}
-    bpf = (baseline or {}).get("perf") or {}
-    if bpf and not cpf:
-        verdict["warnings"].append(
-            "perf: baseline carried a continuous-performance section "
-            "but the current run has none — change-point coverage was "
-            "lost (PYSTELLA_PERF=0?)")
-        return
-    if not cpf:
-        return
-    can = cpf.get("anomalies") or {}
-    reasons = verdict.get("reasons") or []
-    step_green = not any("median step time" in r for r in reasons)
-    for rec in can.get("unresolved") or []:
-        leg = str(rec.get("leg"))
-        if step_green:
-            verdict.update(ok=False, exit_code=2)
-            verdict["reasons"].append(
-                f"invalid_evidence: perf anomaly {leg!r} was still "
-                f"open when the run record ended ({rec.get('value')} "
-                f"ms vs baseline {rec.get('bar')} ms) but the "
-                "post-hoc step-time verdict claims green — the "
-                "change-point detector and the report contradict; "
-                "trust neither")
-        else:
-            verdict["warnings"].append(
-                f"perf: unresolved anomaly {leg!r} corroborates the "
-                "failed post-hoc step-time verdict")
-    if can.get("alerts") and not cpf.get("captures"):
-        verdict["warnings"].append(
-            f"perf: {can['alerts']} anomaly(ies) fired but no "
-            "flight-recorder capture was recorded — set "
-            "PYSTELLA_PERF_CAPTURE_DIR so the next regression "
-            "profiles itself")
-    b_flaps = (bpf.get("anomalies") or {}).get("flaps")
-    c_flaps = can.get("flaps")
-    if isinstance(b_flaps, int) and isinstance(c_flaps, int) \
-            and c_flaps > b_flaps:
-        verdict["warnings"].append(
-            f"perf: {c_flaps} anomaly flap(s) vs {b_flaps} in the "
-            "baseline — a detector oscillating around its threshold; "
-            "check the report's perf section before trusting either "
-            "verdict")
-    verdict["perf"] = {
-        "anomalies": can.get("alerts"),
-        "recovered": can.get("resolved"),
-        "flaps": c_flaps,
-        "unresolved": len(can.get("unresolved") or []),
-        "captures": len(cpf.get("captures") or []),
-    }
 
 
 def _compare_fft(verdict, baseline, current, threshold_pct=25.0):
@@ -1119,283 +728,6 @@ def _check_comm(verdict, baseline, current, excess_pct=25.0):
             "comm: baseline's comm section was covered (modeled and "
             "measured joined) but the current run's is not — "
             "communication coverage was lost")
-
-
-def _compare_service(verdict, baseline, current, queue_factor=2.5,
-                     queue_floor_s=0.5, ttfs_factor=2.5,
-                     ttfs_floor_s=1.0):
-    """Scenario-service SLO comparison (mutates ``verdict`` in place):
-    two production latency metrics from the ``service`` report section
-    (:mod:`pystella_tpu.service`), each gated by a relative factor AND
-    an absolute floor — service latencies on a small smoke mix are
-    single-sample-scale and jitter with host load, so a pure ratio
-    would flap:
-
-    - **queue-p95**: the overall p95 queue latency (submit ->
-      dispatch). A regression means the scheduler is falling behind
-      the offered load — the user-facing SLO.
-    - **warm TTFS**: the warm leases' median time-to-first-step. The
-      warm pool's whole contract is dispatch-never-compile; warm TTFS
-      drifting toward cold TTFS means requests are paying compiles
-      again.
-
-    Coverage loss (baseline had a ``service`` section, current does
-    not) degrades to a warning. The warm-over-mismatched-fingerprints
-    refusal runs earlier, before any baseline is consulted."""
-    bsv = (baseline or {}).get("service") or {}
-    csv = current.get("service") or {}
-    if bsv and not csv:
-        verdict["warnings"].append(
-            "service: baseline carried a service section but the "
-            "current run has none — queue/TTFS SLO coverage was lost")
-        return
-    if not bsv or not csv:
-        return
-    compared = {}
-
-    def _leg(name, b, c, factor, floor_s, what):
-        if not isinstance(b, (int, float)) or b < 0 \
-                or not isinstance(c, (int, float)):
-            if isinstance(b, (int, float)) and c is None:
-                verdict["warnings"].append(
-                    f"service: baseline tracked {what} but the "
-                    "current run's service section carries none — "
-                    "SLO coverage was lost")
-            return
-        compared[name] = {"baseline_s": b, "current_s": c,
-                          "factor": factor, "floor_s": floor_s}
-        if c > b * factor and c - b > floor_s:
-            verdict.update(ok=False,
-                           exit_code=max(verdict["exit_code"], 1))
-            verdict["reasons"].append(
-                f"service SLO regression: {what} {c:.3g} s vs "
-                f"baseline {b:.3g} s (allowed factor {factor:g}, "
-                f"floor {floor_s:g} s) — see the report's service "
-                "section")
-        elif b > c * factor and b - c > floor_s:
-            verdict["warnings"].append(
-                f"service improvement: {what} {c:.3g} s vs baseline "
-                f"{b:.3g} s — consider refreshing the baseline")
-
-    _leg("queue_p95",
-         ((bsv.get("queue_latency_s") or {}).get("overall")
-          or {}).get("p95_s"),
-         ((csv.get("queue_latency_s") or {}).get("overall")
-          or {}).get("p95_s"),
-         queue_factor, queue_floor_s, "queue-latency p95")
-    _leg("warm_ttfs",
-         ((bsv.get("ttfs_s") or {}).get("warm") or {}).get("p50_s"),
-         ((csv.get("ttfs_s") or {}).get("warm") or {}).get("p50_s"),
-         ttfs_factor, ttfs_floor_s, "warm time-to-first-step p50")
-    if compared:
-        verdict["service"] = compared
-
-
-def _compare_fleet(verdict, baseline, current, queue_factor=2.5,
-                   queue_floor_s=0.5, ttfs_factor=2.5,
-                   ttfs_floor_s=1.0):
-    """Fleet SLO comparison (mutates ``verdict`` in place): the fleet
-    ``legs`` of the ``fleet`` report section
-    (:mod:`pystella_tpu.obs.fleet` — each leg's windowed value at the
-    last aggregation pass, computed over EVERY replica's samples), held
-    to the same factor+floor bars as the single-replica service legs.
-    Also the fleet hygiene warnings: version/flag skew appearing when
-    the baseline fleet had none, warm-fingerprint divergence (the
-    hard precondition for cross-replica warm-artifact reuse), and
-    fleet-alert flap growth. Coverage loss (baseline had a fleet
-    section, current does not) degrades to a warning. The
-    partial-evidence refusal and the degraded annotation run earlier,
-    before any baseline is consulted."""
-    bfl = (baseline or {}).get("fleet") or {}
-    cfl = current.get("fleet") or {}
-    if bfl and not cfl:
-        verdict["warnings"].append(
-            "fleet: baseline carried a fleet section but the current "
-            "run has none — fleet SLO coverage was lost")
-        return
-    if not cfl:
-        return
-    # hygiene findings need no baseline: skew and divergence are
-    # absolute properties of THIS fleet
-    if (cfl.get("skew") or {}).get("skewed") \
-            and not (bfl.get("skew") or {}).get("skewed"):
-        verdict["warnings"].append(
-            "fleet: version/flag SKEW across live replicas "
-            f"({(cfl.get('skew') or {}).get('stacks')} distinct "
-            "compiler stacks) — fleet aggregates mix incomparable "
-            "programs; align the stacks before trusting fleet legs")
-    if cfl.get("divergence"):
-        verdict["warnings"].append(
-            "fleet: warm-fingerprint divergence across replicas for "
-            f"signature(s) {', '.join(cfl['divergence'])} — the same "
-            "signature is served by different programs; do not share "
-            "warm artifacts across this fleet")
-    if not bfl:
-        return
-    compared = {}
-
-    def _leg(name, factor, floor_s, what):
-        b = ((bfl.get("legs") or {}).get(name) or {}).get("value_fast")
-        c = ((cfl.get("legs") or {}).get(name) or {}).get("value_fast")
-        if not isinstance(b, (int, float)) or b < 0 \
-                or not isinstance(c, (int, float)):
-            if isinstance(b, (int, float)) and c is None:
-                verdict["warnings"].append(
-                    f"fleet: baseline tracked {what} but the current "
-                    "run's fleet section carries none — fleet SLO "
-                    "coverage was lost")
-            return
-        compared[name] = {"baseline_s": b, "current_s": c,
-                          "factor": factor, "floor_s": floor_s}
-        if c > b * factor and c - b > floor_s:
-            verdict.update(ok=False,
-                           exit_code=max(verdict["exit_code"], 1))
-            verdict["reasons"].append(
-                f"fleet SLO regression: {what} {c:.3g} s vs "
-                f"baseline {b:.3g} s (allowed factor {factor:g}, "
-                f"floor {floor_s:g} s) — see the report's fleet "
-                "section")
-        elif b > c * factor and b - c > floor_s:
-            verdict["warnings"].append(
-                f"fleet improvement: {what} {c:.3g} s vs baseline "
-                f"{b:.3g} s — consider refreshing the baseline")
-
-    _leg("queue_p95", queue_factor, queue_floor_s,
-         "fleet queue-latency p95")
-    _leg("warm_ttfs", ttfs_factor, ttfs_floor_s,
-         "fleet warm time-to-first-step p50")
-    b_flaps = (bfl.get("alerts") or {}).get("flaps")
-    c_flaps = (cfl.get("alerts") or {}).get("flaps")
-    if isinstance(b_flaps, int) and isinstance(c_flaps, int) \
-            and c_flaps > b_flaps:
-        verdict["warnings"].append(
-            f"fleet: {c_flaps} fleet alert flap(s) vs {b_flaps} in "
-            "the baseline — a fleet SLO oscillating around its bar")
-    if compared:
-        verdict["fleet"] = compared
-
-
-def _compare_capacity(verdict, baseline, current, goodput_factor=2.0,
-                      goodput_floor=1.0):
-    """Goodput comparison (mutates ``verdict`` in place): the current
-    ``capacity.goodput`` — committed member-steps per chip-second
-    leased (:mod:`pystella_tpu.obs.capacity` attribution over the
-    span phases × chips) — held to the same factor+floor shape as the
-    service SLO legs, with the inequality FLIPPED: goodput regresses
-    downward, so the gate fails (exit 1) when current drops below
-    baseline / ``goodput_factor`` AND by more than ``goodput_floor``
-    steps/chip-s absolute. Waste chip-second growth (replay +
-    preempt-drain share of the leased chip time) warns against the
-    baseline, and coverage loss (baseline had a capacity section,
-    current does not) degrades to a warning. The partial-evidence
-    refusal and the predicted-only annotation run earlier, before any
-    baseline is consulted."""
-    bcap = (baseline or {}).get("capacity") or {}
-    ccap = current.get("capacity") or {}
-    if bcap and not ccap:
-        verdict["warnings"].append(
-            "capacity: baseline carried a capacity section but the "
-            "current run has none — HBM-footprint/goodput coverage "
-            "was lost")
-        return
-    if not ccap or not bcap:
-        return
-    b = bcap.get("goodput")
-    c = ccap.get("goodput")
-    if isinstance(b, (int, float)) and b > 0 \
-            and isinstance(c, (int, float)):
-        verdict["capacity"] = {
-            "baseline_goodput": b, "current_goodput": c,
-            "factor": goodput_factor, "floor": goodput_floor}
-        if c < b / goodput_factor and b - c > goodput_floor:
-            verdict.update(ok=False,
-                           exit_code=max(verdict["exit_code"], 1))
-            verdict["reasons"].append(
-                f"goodput regression: {c:.3g} committed "
-                f"steps/chip-s vs baseline {b:.3g} (allowed factor "
-                f"{goodput_factor:g}, floor {goodput_floor:g}) — "
-                "chips are burning on waste (replay, drain, idle "
-                "leases); see the report's capacity section")
-        elif c > b * goodput_factor and c - b > goodput_floor:
-            verdict["warnings"].append(
-                f"goodput improvement: {c:.3g} steps/chip-s vs "
-                f"baseline {b:.3g} — consider refreshing the "
-                "baseline")
-    elif isinstance(b, (int, float)) and c is None:
-        verdict["warnings"].append(
-            "capacity: baseline tracked goodput but the current "
-            "run's capacity section carries none — chip-second "
-            "attribution coverage was lost")
-    b_waste = bcap.get("waste_chip_s")
-    c_waste = ccap.get("waste_chip_s")
-    if isinstance(b_waste, (int, float)) \
-            and isinstance(c_waste, (int, float)) \
-            and c_waste > 2.0 * b_waste and c_waste - b_waste > 1.0:
-        verdict["warnings"].append(
-            f"capacity: {c_waste:.3g} waste chip-second(s) (replay + "
-            f"preempt-drain) vs {b_waste:.3g} in the baseline — "
-            "recovery/eviction churn is eating leased chip time")
-
-
-def _compare_latency(verdict, baseline, current, miss_factor=2.0,
-                     miss_floor=0.05):
-    """Deadline-miss SLO comparison (mutates ``verdict`` in place):
-    the current ``latency.deadline.miss_rate`` — the fraction of
-    deadlined requests that retired after their deadline
-    (:mod:`pystella_tpu.obs.spans` /
-    :class:`~pystella_tpu.service.results.ResultEmitter`) — must stay
-    within ``miss_factor`` × the baseline's AND within ``miss_floor``
-    absolute above it before the gate fails (exit 1). Both bars, like
-    the other service SLOs: a smoke mix deadlines a handful of
-    requests, so one flipped verdict moves the rate by a whole
-    quantum — the floor keeps that honest while a real scheduler
-    regression (misses doubling AND growing by 5+ points) reliably
-    fails. Coverage loss (baseline had a ``latency`` section or a
-    deadline ledger, current does not) degrades to a warning; the
-    unassembled-span-tree warning runs earlier, before any baseline
-    is consulted."""
-    blat = (baseline or {}).get("latency") or {}
-    clat = current.get("latency") or {}
-    if blat and not clat:
-        verdict["warnings"].append(
-            "latency: baseline carried a latency (critical-path) "
-            "section but the current run has none — deadline-miss SLO "
-            "coverage was lost")
-        return
-    if not blat or not clat:
-        return
-    bdl = blat.get("deadline") or {}
-    cdl = clat.get("deadline") or {}
-    b = bdl.get("miss_rate")
-    c = cdl.get("miss_rate")
-    if isinstance(b, (int, float)) and c is None:
-        verdict["warnings"].append(
-            "latency: baseline tracked a deadline-miss rate but the "
-            "current run deadlined no requests — deadline-miss SLO "
-            "coverage was lost")
-        return
-    if not isinstance(b, (int, float)) or not isinstance(
-            c, (int, float)):
-        return
-    verdict["latency"] = {
-        "baseline_miss_rate": b, "current_miss_rate": c,
-        "baseline_missed": bdl.get("missed"),
-        "current_missed": cdl.get("missed"),
-        "miss_factor": miss_factor, "miss_floor": miss_floor,
-    }
-    if c > b * miss_factor and c - b > miss_floor:
-        verdict.update(ok=False, exit_code=max(verdict["exit_code"], 1))
-        verdict["reasons"].append(
-            f"deadline-miss SLO regression: miss rate {c:.1%} "
-            f"({cdl.get('missed')}/{cdl.get('deadlined')} deadlined "
-            f"request(s)) vs baseline {b:.1%} (allowed factor "
-            f"{miss_factor:g}, floor {miss_floor:g}) — see the "
-            "report's latency section for the dominant phase behind "
-            "the misses")
-    elif b > c * miss_factor and b - c > miss_floor:
-        verdict["warnings"].append(
-            f"deadline-miss improvement: miss rate {c:.1%} vs baseline "
-            f"{b:.1%} — consider refreshing the baseline")
 
 
 def _compare_ensemble(verdict, baseline, current, threshold_pct=20.0):
@@ -1625,80 +957,6 @@ def main(argv=None):
     p.add_argument("--no-comm", action="store_true",
                    help="skip the modeled-vs-measured communication "
                         "check (comm section)")
-    p.add_argument("--service-queue-factor", type=float, default=2.5,
-                   help="service: allowed multiple of the baseline's "
-                        "queue-latency p95 before the gate fails "
-                        "(default 2.5)")
-    p.add_argument("--service-queue-floor", type=float, default=0.5,
-                   help="service: absolute seconds a queue-p95 "
-                        "regression must also exceed (default 0.5)")
-    p.add_argument("--service-ttfs-factor", type=float, default=2.5,
-                   help="service: allowed multiple of the baseline's "
-                        "warm time-to-first-step p50 before the gate "
-                        "fails (default 2.5)")
-    p.add_argument("--service-ttfs-floor", type=float, default=1.0,
-                   help="service: absolute seconds a warm-TTFS "
-                        "regression must also exceed (default 1)")
-    p.add_argument("--no-service", action="store_true",
-                   help="skip the scenario-service checks (queue-p95 / "
-                        "warm-TTFS SLO regressions, warm-admission-"
-                        "over-mismatched-fingerprints refusal)")
-    p.add_argument("--latency-miss-factor", type=float, default=2.0,
-                   help="latency: allowed multiple of the baseline's "
-                        "deadline-miss rate before the gate fails "
-                        "(default 2)")
-    p.add_argument("--latency-miss-floor", type=float, default=0.05,
-                   help="latency: absolute miss-rate increase a "
-                        "regression must also exceed (default 0.05 — "
-                        "one flipped verdict on a small smoke mix "
-                        "moves the rate by a whole quantum)")
-    p.add_argument("--no-latency", action="store_true",
-                   help="skip the request-latency checks (deadline-"
-                        "miss SLO regression, span-assembly coverage "
-                        "warnings)")
-    p.add_argument("--fleet-queue-factor", type=float, default=2.5,
-                   help="fleet: allowed multiple of the baseline's "
-                        "fleet queue-latency p95 before the gate "
-                        "fails (default 2.5)")
-    p.add_argument("--fleet-queue-floor", type=float, default=0.5,
-                   help="fleet: absolute seconds a fleet queue-p95 "
-                        "regression must also exceed (default 0.5)")
-    p.add_argument("--fleet-ttfs-factor", type=float, default=2.5,
-                   help="fleet: allowed multiple of the baseline's "
-                        "fleet warm-TTFS p50 before the gate fails "
-                        "(default 2.5)")
-    p.add_argument("--fleet-ttfs-floor", type=float, default=1.0,
-                   help="fleet: absolute seconds a fleet warm-TTFS "
-                        "regression must also exceed (default 1)")
-    p.add_argument("--no-fleet", action="store_true",
-                   help="skip the fleet checks (full-coverage-claim-"
-                        "over-lossy-scrapes refusal, degraded-fleet "
-                        "annotation, fleet queue-p95/warm-TTFS "
-                        "regressions, skew/divergence/flap warnings)")
-    p.add_argument("--goodput-factor", type=float, default=2.0,
-                   help="capacity: allowed divisor of the baseline's "
-                        "goodput (committed steps/chip-s) before the "
-                        "gate fails (default 2)")
-    p.add_argument("--goodput-floor", type=float, default=1.0,
-                   help="capacity: absolute steps/chip-s a goodput "
-                        "regression must also exceed (default 1)")
-    p.add_argument("--no-capacity", action="store_true",
-                   help="skip the capacity checks (complete-coverage-"
-                        "with-no-watermarks refusal, predicted-only "
-                        "annotation, reconciliation-drift warning, "
-                        "goodput regression, waste-chip-second "
-                        "growth)")
-    p.add_argument("--no-alerts", action="store_true",
-                   help="skip the live-alert consistency audit (an "
-                        "unresolved burn alert beside a green post-hoc "
-                        "SLO section refuses the evidence; alert-flap "
-                        "growth warns)")
-    p.add_argument("--no-perf", action="store_true",
-                   help="skip the continuous-performance consistency "
-                        "audit (an unresolved perf_anomaly beside a "
-                        "green step-time verdict refuses the "
-                        "evidence; missing flight-recorder captures "
-                        "and anomaly-flap growth warn)")
     p.add_argument("--no-resilience", action="store_true",
                    help="skip the resilience triage (degraded-fleet "
                         "annotation of regressions/contamination across "
@@ -1759,25 +1017,7 @@ def main(argv=None):
         check_fft=not args.no_fft,
         fft_threshold_pct=args.fft_threshold_pct,
         check_comm=not args.no_comm,
-        comm_excess_pct=args.comm_excess_pct,
-        check_service=not args.no_service,
-        service_queue_factor=args.service_queue_factor,
-        service_queue_floor_s=args.service_queue_floor,
-        service_ttfs_factor=args.service_ttfs_factor,
-        service_ttfs_floor_s=args.service_ttfs_floor,
-        check_latency=not args.no_latency,
-        latency_miss_factor=args.latency_miss_factor,
-        latency_miss_floor=args.latency_miss_floor,
-        check_alerts=not args.no_alerts,
-        check_perf=not args.no_perf,
-        check_fleet=not args.no_fleet,
-        fleet_queue_factor=args.fleet_queue_factor,
-        fleet_queue_floor_s=args.fleet_queue_floor,
-        fleet_ttfs_factor=args.fleet_ttfs_factor,
-        fleet_ttfs_floor_s=args.fleet_ttfs_floor,
-        check_capacity=not args.no_capacity,
-        goodput_factor=args.goodput_factor,
-        goodput_floor=args.goodput_floor)
+        comm_excess_pct=args.comm_excess_pct)
 
     print(json.dumps(verdict, indent=1, sort_keys=True))
     for w in verdict.get("warnings", []):

@@ -27,26 +27,22 @@ roofline fractions, not a lone number:
 
 ``PerfLedger.write(dir)`` produces ``perf_report.json`` (schema below,
 consumed by :mod:`pystella_tpu.obs.gate`) and a human ``perf_report.md``.
-The module body never requires jax at runtime — versions come from
-package metadata and device fields degrade to ``None`` when no jax is
-loaded (importing it as ``pystella_tpu.obs.ledger`` still pulls jax via
-the package ``__init__``; a jax-free supervisor should load it by
-file).
+The fingerprint is :func:`pystella_tpu.obs.memory.
+environment_fingerprint`, whose versions and flags are the ones the
+program fingerprints hash.
 """
 
 from __future__ import annotations
 
 import json
 import os
-import platform as _platform
-import socket
-import sys
 import time
 
 from pystella_tpu.obs import events as _events
+from pystella_tpu.obs.memory import environment_fingerprint
 
-__all__ = ["REPORT_SCHEMA_VERSION", "PerfLedger", "environment_fingerprint",
-           "mad", "percentile", "step_stats"]
+__all__ = ["REPORT_SCHEMA_VERSION", "PerfLedger", "mad", "percentile",
+           "step_stats"]
 
 REPORT_SCHEMA_VERSION = 1
 
@@ -69,90 +65,6 @@ HBM_PEAK_GBPS = {
 #: contamination detector to see bursts, small enough to keep reports
 #: reviewable in a diff
 MAX_SAMPLES = 4096
-
-
-def _version_of(dist):
-    try:
-        from importlib.metadata import version
-        return version(dist)
-    except Exception:
-        return None
-
-
-def runtime_versions():
-    """The jax/jaxlib/libtpu version triple — the compiler stack that
-    keys both perf-report comparability (this module's environment
-    fingerprint) and cached/AOT program staleness
-    (``obs.memory`` bakes it into every program fingerprint, via this
-    one definition so the two can never diverge). Stdlib-only:
-    resolved from installed-distribution metadata, no jax import."""
-    return {
-        "jax": _version_of("jax"),
-        "jaxlib": _version_of("jaxlib"),
-        # a libtpu bump changes the generated code: cached/AOT programs
-        # keyed without it would silently serve stale executables
-        "libtpu": _version_of("libtpu") or _version_of("libtpu-nightly"),
-    }
-
-
-#: env-var name substrings that make an XLA/libtpu flag relevant to the
-#: fingerprint: async-collective and latency-hiding-scheduler toggles
-#: change what a step-time comparison means (the overlapped halo path
-#: depends on them to pay off). Kept in sync with
-#: ``pystella_tpu.parallel.overlap`` — duplicated here because this
-#: module must stay loadable BY FILE in a jax-free supervisor, where
-#: the package import (and thus jax) is unavailable.
-_FLAG_MARKERS = ("async_collective", "async_all_gather",
-                 "latency_hiding", "scheduler")
-
-
-def xla_flag_fingerprint():
-    """The scheduler-relevant flags in this process's environment
-    (``XLA_FLAGS`` + ``LIBTPU_INIT_ARGS``), as ``{name: value}``, plus
-    the ``PYSTELLA_HALO_OVERLAP`` policy setting when present —
-    stdlib-only, embedded in every report's environment fingerprint so
-    the gate can warn when two reports differ only in flags."""
-    flags = {}
-    for var in ("XLA_FLAGS", "LIBTPU_INIT_ARGS"):
-        # direct reads: this module stays loadable by file, jax- and
-        # package-free  # env-registry: XLA_FLAGS, LIBTPU_INIT_ARGS
-        for tok in os.environ.get(var, "").split():
-            name, _, value = tok.lstrip("-").partition("=")
-            if any(m in name for m in _FLAG_MARKERS):
-                flags[name] = value if value else "true"
-    setting = os.environ.get(
-        "PYSTELLA_HALO_OVERLAP")  # env-registry: PYSTELLA_HALO_OVERLAP
-    if setting is not None:
-        flags["PYSTELLA_HALO_OVERLAP"] = setting
-    return flags
-
-
-def environment_fingerprint():
-    """Everything needed to decide whether two perf reports are
-    comparable. Resolved from an already-imported jax only (the module
-    must stay importable in a jax-free supervisor); device fields
-    are ``None`` when jax is not loaded."""
-    env = {
-        "python": _platform.python_version(),
-        **runtime_versions(),
-        "hostname": socket.gethostname(),
-        "platform": None,
-        "device_kind": None,
-        "num_devices": None,
-        "num_processes": None,
-        "xla_flags": xla_flag_fingerprint(),
-    }
-    jax = sys.modules.get("jax")
-    if jax is not None:
-        try:
-            devs = jax.devices()
-            env["platform"] = devs[0].platform
-            env["device_kind"] = devs[0].device_kind
-            env["num_devices"] = len(devs)
-            env["num_processes"] = int(jax.process_count())
-        except Exception:
-            pass
-    return env
 
 
 def percentile(sorted_xs, q):
@@ -260,43 +172,6 @@ class PerfLedger:
         self.spectra_ms = []            # per-call spectra wall times
         #                                 (spectra_time events — drivers
         #                                 emit one per spectra output)
-        self.service_dispatches = []    # service_dispatch payloads
-        self.service_leases = []        # service_lease payloads
-        self.service_admits = []        # service_admit payloads
-        self.service_rejects = []       # service_reject payloads
-        self.service_preemptions = 0    # service_preempted events
-        self.service_results = []       # member_result payloads
-        self.service_done = {}          # last service_done payload
-        self.service_loadgen = {}       # last service_loadgen payload
-        self.service_lease_failures = 0  # service_lease_failed events
-        self.span_records = []          # raw trace/span-carrying events
-        #                                 (obs schema v2) — the latency
-        #                                 section's SpanAssembler input
-        self.deadline_miss_events = 0   # deadline_missed events
-        self.slo_events = []            # ("alert"|"resolved", ts, data)
-        #                                 from the live burn-rate
-        #                                 monitor (obs.slo) -> alerts()
-        self.fleet_scrapes = []         # fleet_scrape payloads, in order
-        self.fleet_lost = []            # fleet_replica_lost payloads
-        self.fleet_slo_events = []      # ("alert"|"resolved", ts, data)
-        #                                 from the fleet aggregator
-        self.fleet_announces = []       # fleet_announce payloads
-        self.fleet_withdraws = []       # fleet_withdraw payloads
-        self.perf_events = []           # ("anomaly"|"recovered", ts,
-        #                                 data) from the continuous-
-        #                                 performance detector
-        #                                 (obs.perf) -> perf()
-        self.perf_captures = []         # perf_capture payloads (the
-        #                                 flight-recorder artifacts)
-        self.perf_digests = []          # perf_digest window reports
-        self.capacity_footprints = []   # capacity_footprint payloads
-        self.capacity_watermarks = []   # capacity_watermark samples
-        self.capacity_rejects = []      # capacity_reject payloads
-        self.capacity_evictions = []    # capacity_evict payloads
-        self.capacity_oom = []          # capacity_oom payloads (the
-        #                                 OOM forensic-bundle pointers)
-        self.capacity_accounts = []     # capacity_account payloads
-        self.capacity_usage = {}        # last capacity_usage payload
 
     # -- ingestion ---------------------------------------------------------
 
@@ -327,9 +202,9 @@ class PerfLedger:
         """
         led = cls(label=label, sites=sites)
         window_ms = []
-        # include_rotated: a size-rotated long-lived log (the scenario
-        # service's rotate_bytes=) ingests as one continuous stream —
-        # the latest-run scoping below then applies across the family
+        # include_rotated: a size-rotated long-lived log (rotate_bytes=)
+        # ingests as one continuous stream — the latest-run scoping
+        # below then applies across the family
         all_events = _events.read_events(events_path,
                                          include_rotated=True)
         starts = [i for i, ev in enumerate(all_events)
@@ -339,13 +214,6 @@ class PerfLedger:
         for ev in all_events:
             kind = ev.get("kind")
             data = ev.get("data") or {}
-            # the span stream: every record carrying schema-v2 trace
-            # context feeds the latency section's SpanAssembler (raw,
-            # not just data — the assembler needs ts/trace/span/parent)
-            if ev.get("trace") is not None or ev.get("span") is not None:
-                led.span_records.append(ev)
-            if kind == "deadline_missed":
-                led.deadline_miss_events += 1
             if kind == "step_time" and isinstance(
                     data.get("ms"), (int, float)):
                 led.samples_ms.append(float(data["ms"]))
@@ -466,69 +334,6 @@ class PerfLedger:
                 # spectra cost is a ledger-visible series, not a one-off
                 # microbenchmark
                 led.spectra_ms.append(float(data["ms"]))
-            elif kind == "service_dispatch":
-                # the scenario service's per-request dispatch record
-                # (queue latency, priority class, warm/cold tag) — the
-                # `service` section's queue-latency percentiles come
-                # from these
-                led.service_dispatches.append(data)
-            elif kind == "service_lease":
-                led.service_leases.append(data)
-            elif kind == "service_admit":
-                led.service_admits.append(data)
-            elif kind == "service_reject":
-                led.service_rejects.append(data)
-            elif kind == "service_preempted":
-                led.service_preemptions += 1
-            elif kind == "service_lease_failed":
-                led.service_lease_failures += 1
-            elif kind == "member_result":
-                led.service_results.append(data)
-            elif kind == "service_done":
-                led.service_done = data
-            elif kind == "service_loadgen":
-                led.service_loadgen = data
-            elif kind == "slo_alert":
-                led.slo_events.append(("alert", ev.get("ts"), data))
-            elif kind == "slo_resolved":
-                led.slo_events.append(("resolved", ev.get("ts"), data))
-            elif kind == "fleet_scrape":
-                led.fleet_scrapes.append(data)
-            elif kind == "fleet_replica_lost":
-                led.fleet_lost.append(data)
-            elif kind == "fleet_alert":
-                led.fleet_slo_events.append(("alert", ev.get("ts"),
-                                             data))
-            elif kind == "fleet_resolved":
-                led.fleet_slo_events.append(("resolved", ev.get("ts"),
-                                             data))
-            elif kind == "fleet_announce":
-                led.fleet_announces.append(data)
-            elif kind == "fleet_withdraw":
-                led.fleet_withdraws.append(data)
-            elif kind == "perf_anomaly":
-                led.perf_events.append(("anomaly", ev.get("ts"), data))
-            elif kind == "perf_recovered":
-                led.perf_events.append(("recovered", ev.get("ts"),
-                                        data))
-            elif kind == "perf_capture":
-                led.perf_captures.append(data)
-            elif kind == "perf_digest":
-                led.perf_digests.append(data)
-            elif kind == "capacity_footprint":
-                led.capacity_footprints.append(data)
-            elif kind == "capacity_watermark":
-                led.capacity_watermarks.append(data)
-            elif kind == "capacity_reject":
-                led.capacity_rejects.append(data)
-            elif kind == "capacity_evict":
-                led.capacity_evictions.append(data)
-            elif kind == "capacity_oom":
-                led.capacity_oom.append(data)
-            elif kind == "capacity_account":
-                led.capacity_accounts.append(data)
-            elif kind == "capacity_usage":
-                led.capacity_usage = data
             elif kind == "run_start":
                 led.meta = data
         if not led.samples_ms and window_ms:
@@ -1214,338 +1019,6 @@ class PerfLedger:
             "num_devices": ndev,
         }
 
-    def service(self):
-        """The scenario-service summary (:mod:`pystella_tpu.service`):
-        queue-latency percentiles per priority class (from the
-        per-request ``service_dispatch`` records), time-to-first-step
-        split warm/cold (from the lease records — the cold side pays
-        the build+compile, the warm side must stay pure dispatch),
-        tenant occupancy shares, preemption counts plus
-        work-lost-to-replay, rejection/eviction accounting, and the
-        warm-admission evidence the gate audits: every warm admission's
-        fingerprint status and the warm leases' backend-compile count
-        from the compile ledger (a warm lease that compiled broke the
-        dispatch-never-compile contract). ``None`` when the run carried
-        no service telemetry at all."""
-        if not (self.service_dispatches or self.service_leases
-                or self.service_admits or self.service_rejects
-                or self.service_results or self.service_done):
-            return None
-        by_class = {}
-        qlats = []
-        for d in self.service_dispatches:
-            q = d.get("queue_latency_s")
-            if not isinstance(q, (int, float)):
-                continue
-            qlats.append(float(q))
-            by_class.setdefault(str(d.get("priority")), []).append(
-                float(q))
-        ttfs = {"warm": [], "cold": []}
-        for rec in self.service_leases:
-            t = rec.get("ttfs_s")
-            if isinstance(t, (int, float)):
-                ttfs["warm" if rec.get("warm") else "cold"].append(
-                    float(t))
-        warm_admissions = [
-            {"id": a.get("id"), "fingerprint": a.get("fingerprint"),
-             "fingerprint_ok": a.get("fingerprint_ok")}
-            for a in self.service_admits if a.get("warm")]
-        warm_leases = [r for r in self.service_leases if r.get("warm")]
-        warm_compiles = sum(int(r.get("backend_compiles") or 0)
-                            for r in warm_leases)
-        rejects = {}
-        for r in self.service_rejects:
-            reason = str(r.get("reason"))
-            rejects[reason] = rejects.get(reason, 0) + 1
-        statuses = {}
-        for r in self.service_results:
-            s = str(r.get("status"))
-            statuses[s] = statuses.get(s, 0) + 1
-        tenant_steps = dict(self.service_done.get("tenant_steps") or {})
-        if not tenant_steps:
-            for rec in self.service_leases:
-                for tenant, steps in (rec.get("tenant_steps")
-                                      or {}).items():
-                    tenant_steps[tenant] = (tenant_steps.get(tenant, 0)
-                                            + int(steps))
-        total_steps = sum(tenant_steps.values())
-        replayed = self.service_done.get("replayed_member_steps")
-        if replayed is None:
-            replayed = sum(int(r.get("replayed_member_steps") or 0)
-                           for r in self.service_leases)
-        out = {
-            "requests": len({d.get("id")
-                             for d in self.service_dispatches}),
-            "admitted": len(self.service_admits),
-            "results": statuses,
-            "completed": statuses.get("completed", 0),
-            "diverged": statuses.get("diverged", 0),
-            "rejected": rejects,
-            "queue_latency_s": {
-                "overall": _lat_stats(qlats),
-                "by_priority": {cls: _lat_stats(v)
-                                for cls, v in sorted(by_class.items())},
-            },
-            "ttfs_s": {"warm": _lat_stats(ttfs["warm"]),
-                       "cold": _lat_stats(ttfs["cold"])},
-            "warm_claimed": bool(warm_admissions),
-            "warm_admissions": warm_admissions[:64],
-            "warm_leases": len(warm_leases),
-            "warm_lease_backend_compiles": warm_compiles,
-            "leases": len(self.service_leases),
-            "lease_failures": self.service_lease_failures,
-            "preemptions": self.service_preemptions,
-            "work_lost_to_replay_member_steps": int(replayed or 0),
-            "tenant_member_steps": tenant_steps,
-            "tenant_share": ({t: s / total_steps
-                              for t, s in tenant_steps.items()}
-                             if total_steps else {}),
-        }
-        if self.service_loadgen:
-            out["loadgen"] = {
-                k: self.service_loadgen.get(k)
-                for k in ("seed", "requests", "warm_admissions",
-                          "cold_admissions", "preempted_requests",
-                          "preempt_bitexact")}
-        return out
-
-    def alerts(self):
-        """The live-alert summary (:mod:`pystella_tpu.obs.slo` burn-rate
-        monitor): per-leg alert/resolve counts, flaps (re-fires after a
-        resolve), total and max alert durations, and — the field the
-        gate audits — ``unresolved``: alerts still burning when the run
-        record ends. An unresolved burn alert beside a post-hoc SLO
-        section that claims green is the live/post-hoc contradiction
-        the gate refuses as invalid evidence (exit 2). ``None`` when
-        the run carried no live SLO telemetry at all (monitor not
-        attached — coverage the gate warns about when the baseline had
-        it)."""
-        if not self.slo_events:
-            return None
-        return _alert_rollup(self.slo_events)
-
-    def fleet(self):
-        """The fleet federation summary (:mod:`pystella_tpu.obs.fleet`
-        aggregator over the replica registry): the replica table as of
-        the last scrape (each row annotated with heartbeat age and
-        per-replica scrape outcomes), the aggregated fleet SLO legs,
-        lost replicas, the scrape-success rate, skew/divergence
-        findings, and the fleet alert rollup (same shape as
-        :meth:`alerts`, built from ``fleet_alert``/``fleet_resolved``).
-        The ``coverage`` block is the gate's honesty anchor: a fleet
-        claim over a run with lost replicas or failed scrapes is a
-        claim over PARTIAL evidence, and ``complete`` says which kind
-        this run's record is. ``None`` when the run carried no fleet
-        telemetry at all."""
-        if not (self.fleet_scrapes or self.fleet_lost
-                or self.fleet_slo_events):
-            return None
-        replicas = {}
-        for sc in self.fleet_scrapes:
-            for row in sc.get("replicas") or []:
-                rid = row.get("replica")
-                if rid:
-                    replicas[rid] = dict(row)
-        lost_rows = []
-        for data in self.fleet_lost:
-            rid = data.get("replica")
-            lost_rows.append({"replica": rid,
-                              "reason": data.get("reason"),
-                              "age_s": data.get("age_s")})
-            if rid:
-                replicas.setdefault(rid, {"replica": rid})
-                replicas[rid]["status"] = "lost"
-                replicas[rid]["lost_reason"] = data.get("reason")
-        last = self.fleet_scrapes[-1] if self.fleet_scrapes else {}
-        ok = sum(int(sc.get("ok") or 0) for sc in self.fleet_scrapes)
-        failed = sum(int(sc.get("failed") or 0)
-                     for sc in self.fleet_scrapes)
-        attempts = ok + failed
-        lost_ids = sorted({r["replica"] for r in lost_rows
-                           if r.get("replica")})
-        return {
-            "replicas": [replicas[rid] for rid in sorted(replicas)],
-            "scrapes": len(self.fleet_scrapes),
-            "endpoint_ok": ok,
-            "endpoint_failed": failed,
-            "scrape_success_rate": (ok / attempts if attempts
-                                    else None),
-            "replicas_lost": lost_rows,
-            "dead": last.get("dead"),
-            "legs": last.get("legs"),
-            "alerts": (_alert_rollup(self.fleet_slo_events)
-                       if self.fleet_slo_events else None),
-            "skew": {
-                "skewed": any(sc.get("skewed")
-                              for sc in self.fleet_scrapes),
-                "stacks": last.get("stacks"),
-            },
-            "divergence": sorted({sig for sc in self.fleet_scrapes
-                                  for sig in (sc.get("divergent")
-                                              or [])}),
-            "announces": len(self.fleet_announces),
-            "withdraws": len(self.fleet_withdraws),
-            "coverage": {
-                "replicas": len(replicas),
-                "lost": len(lost_ids),
-                "endpoint_failed": failed,
-                "complete": not lost_ids and failed == 0,
-            },
-        }
-
-    def perf(self):
-        """The continuous-performance summary (:mod:`pystella_tpu.obs.
-        perf` detector + flight recorder): the anomaly rollup per
-        program signature (same shape as :meth:`alerts` — the field
-        the gate audits is ``anomalies.unresolved``, anomalies still
-        open when the run record ends), the latest digest window per
-        signature (p50/p95/p99 ms), the flight-recorder captures with
-        their Perfetto artifact paths (the ledger link the gate checks
-        when anomalies fired), and the straggler attribution from the
-        last anomaly that carried one. ``None`` when the run carried
-        no continuous-performance telemetry at all (``PYSTELLA_PERF=0``
-        or a pre-PR-17 log — coverage the gate warns about when the
-        baseline had it)."""
-        if not (self.perf_events or self.perf_captures
-                or self.perf_digests):
-            return None
-        # reuse the alert rollup: an anomaly is a fired alert on the
-        # leg named by its signature, recovery resolves it
-        anomalies = _alert_rollup([
-            (("alert" if kind == "anomaly" else "resolved"), ts,
-             {**data, "leg": data.get("signature", "step"),
-              "value": data.get("ms"),
-              "bar": data.get("baseline_ms")})
-            for kind, ts, data in self.perf_events])
-        digests = {}
-        for data in self.perf_digests:
-            sig = data.get("signature", "step")
-            digests[sig] = {k: data.get(k) for k in
-                            ("count", "mean_ms", "p50_ms", "p95_ms",
-                             "p99_ms")}
-        straggler = None
-        for kind, _, data in reversed(self.perf_events):
-            if kind == "anomaly" and data.get("straggler"):
-                straggler = data["straggler"]
-                break
-        captures = [{k: data.get(k) for k in
-                     ("signature", "reason", "artifact", "logdir",
-                      "steps", "suppressed", "error") if k in data}
-                    for data in self.perf_captures]
-        return {
-            "anomalies": anomalies,
-            "digests": digests or None,
-            "captures": captures,
-            "captures_suppressed": max(
-                [int(c.get("suppressed") or 0) for c in captures],
-                default=0),
-            "straggler": straggler,
-        }
-
-    def capacity(self):
-        """The capacity & goodput summary (:mod:`pystella_tpu.obs.
-        capacity`): the per-program footprint table (predicted bytes +
-        prediction source) against the observed live watermarks, the
-        predicted-vs-peak reconciliation, the headroom series summary,
-        memory-aware admission rejections/evictions, OOM forensic
-        bundles, and the retire-time chargeback — per-tenant
-        chip-second/goodput table plus the overall
-        ``goodput = committed member-steps / total chip-seconds``. The
-        ``coverage`` block is the gate's honesty anchor: a capacity
-        claim over leases with NO watermark samples cannot read as
-        ``complete`` (CPU runs degrade to ``predicted_only``). ``None``
-        when the run carried no capacity telemetry at all (pre-PR-19
-        logs, or the plane disabled)."""
-        if not (self.capacity_footprints or self.capacity_watermarks
-                or self.capacity_accounts or self.capacity_usage
-                or self.capacity_rejects or self.capacity_oom):
-            return None
-        usage = self.capacity_usage or {}
-        footprints = {}
-        for data in self.capacity_footprints:
-            key = (data.get("label"), data.get("fingerprint"))
-            footprints[key] = {
-                k: data.get(k) for k in
-                ("label", "fingerprint", "predicted_bytes", "source")}
-        peaks = [w.get("peak_bytes_in_use")
-                 for w in self.capacity_watermarks
-                 if isinstance(w.get("peak_bytes_in_use"),
-                               (int, float))]
-        in_use = [w.get("bytes_in_use") for w in self.capacity_watermarks
-                  if isinstance(w.get("bytes_in_use"), (int, float))]
-        headroom = [w.get("headroom_frac")
-                    for w in self.capacity_watermarks
-                    if isinstance(w.get("headroom_frac"), (int, float))]
-        coverage = usage.get("coverage") or {
-            "leases": None,
-            "leases_sampled": None,
-            "watermark_samples": len(self.capacity_watermarks),
-            "predicted_only": not self.capacity_watermarks,
-            "complete": False,
-        }
-        rejects = {
-            "count": len(self.capacity_rejects),
-            "signatures": sorted({r.get("signature")
-                                  for r in self.capacity_rejects
-                                  if r.get("signature")}),
-            "last": (self.capacity_rejects[-1]
-                     if self.capacity_rejects else None),
-        }
-        return {
-            "footprints": [footprints[k] for k in sorted(
-                footprints, key=lambda k: (str(k[0]), str(k[1])))],
-            "watermarks": {
-                "samples": len(self.capacity_watermarks),
-                "peak_bytes_in_use": max(peaks) if peaks else None,
-                "max_bytes_in_use": max(in_use) if in_use else None,
-                "headroom_frac_max": (max(headroom) if headroom
-                                      else None),
-            },
-            "reconciliation": usage.get("reconciliation"),
-            "rejections": rejects,
-            "evictions": len(self.capacity_evictions),
-            "oom_bundles": [d.get("path") for d in self.capacity_oom],
-            "tenants": usage.get("tenants"),
-            "goodput": usage.get("goodput"),
-            "total_chip_s": usage.get("total_chip_s"),
-            "committed_steps": usage.get("committed_steps"),
-            "waste_chip_s": usage.get("waste_chip_s"),
-            "capacity_bytes": usage.get("capacity_bytes"),
-            "headroom": usage.get("headroom"),
-            "resident_predicted_bytes":
-                usage.get("resident_predicted_bytes"),
-            "accounts": self.capacity_accounts[-64:],
-            "coverage": coverage,
-        }
-
-    def latency(self):
-        """Request-scoped critical-path latency attribution
-        (:mod:`pystella_tpu.obs.spans` over the schema-v2 trace
-        stream): per-request phase decomposition percentiles (queue
-        wait / admission / compile / chunk compute / checkpoint
-        barrier / recovery replay / preempt drain), the dominant-phase
-        histogram, the partition audit (phases must sum to the
-        measured submit→retire wall), the deadline ledger (miss rate
-        per priority class + margin distribution — the gate's
-        deadline-miss SLO), and the coverage split (``unassembled``
-        names traced requests whose span tree failed to close — the
-        gate's coverage-loss warning). ``None`` when the run carried
-        no traced request at all (v1 logs, or
-        ``PYSTELLA_TRACE_SERVICE=0``)."""
-        if not self.span_records:
-            return None
-        # deferred import: obs.spans has a ``python -m`` entry point,
-        # and a module-level import here would put it in sys.modules
-        # before runpy executes it (same reason obs/__init__ leaves
-        # gate and warmstart out)
-        from pystella_tpu.obs import spans as _spans
-        summary = _spans.SpanAssembler.from_records(
-            self.span_records).summary()
-        if summary is not None:
-            summary["deadline"]["miss_events"] = \
-                self.deadline_miss_events
-        return summary
-
     def _degrading_plan(self):
         """The last remesh_plan that actually changed the mesh
         (``changed`` and ``feasible``), or ``None`` — transport-blip
@@ -1616,12 +1089,6 @@ class PerfLedger:
             "ensemble": self.ensemble(),
             "resilience": self.resilience(),
             "fft": self.fft(),
-            "service": self.service(),
-            "latency": self.latency(),
-            "alerts": self.alerts(),
-            "fleet": self.fleet(),
-            "perf": self.perf(),
-            "capacity": self.capacity(),
             "lint": self.lint,
             "scopes": self.scopes,
             "trace_file": self.trace_file,
@@ -1643,74 +1110,6 @@ class PerfLedger:
             f.write(render_markdown(rep))
         _events.emit("perf_report", path=json_path, label=self.label)
         return json_path
-
-
-def _alert_rollup(events):
-    """Per-leg fire/resolve bookkeeping over ``[("alert"|"resolved",
-    ts, data), ...]`` — one definition for both the live
-    (``slo_alert``) and fleet (``fleet_alert``) vocabularies, so their
-    report shapes cannot diverge."""
-    by_leg = {}
-
-    def row(leg):
-        return by_leg.setdefault(str(leg), {
-            "alerts": 0, "resolved": 0, "flaps": 0,
-            "total_alert_s": 0.0, "max_alert_s": None,
-            "open": None})
-
-    for kind, ts, data in events:
-        r = row(data.get("leg"))
-        if kind == "alert":
-            r["alerts"] += 1
-            r["flaps"] = max(0, r["alerts"] - 1)
-            r["open"] = {"since_ts": ts,
-                         "value": data.get("value"),
-                         "bar": data.get("bar"),
-                         "burn_fast": data.get("burn_fast"),
-                         "burn_slow": data.get("burn_slow")}
-        else:
-            r["resolved"] += 1
-            d = data.get("duration_s")
-            if d is None and r["open"] is not None \
-                    and isinstance(ts, (int, float)) \
-                    and isinstance(r["open"].get("since_ts"),
-                                   (int, float)):
-                d = ts - r["open"]["since_ts"]
-            if isinstance(d, (int, float)):
-                r["total_alert_s"] += float(d)
-                r["max_alert_s"] = (float(d)
-                                    if r["max_alert_s"] is None
-                                    else max(r["max_alert_s"],
-                                             float(d)))
-            r["open"] = None
-    unresolved = [{"leg": leg, **r["open"]}
-                  for leg, r in sorted(by_leg.items())
-                  if r["open"] is not None]
-    return {
-        "alerts": sum(r["alerts"] for r in by_leg.values()),
-        "resolved": sum(r["resolved"] for r in by_leg.values()),
-        "flaps": sum(r["flaps"] for r in by_leg.values()),
-        "unresolved": unresolved,
-        "by_leg": {leg: {k: v for k, v in r.items() if k != "open"}
-                   for leg, r in sorted(by_leg.items())},
-    }
-
-
-def _lat_stats(samples_s):
-    """Latency-distribution summary in SECONDS (the service section's
-    queue-latency / TTFS fields; ``step_stats`` stays the millisecond
-    step-time shape): count, mean, p50/p90/p95, max."""
-    if not samples_s:
-        return {"count": 0}
-    s = sorted(float(x) for x in samples_s)
-    return {
-        "count": len(s),
-        "mean_s": sum(s) / len(s),
-        "p50_s": percentile(s, 50),
-        "p90_s": percentile(s, 90),
-        "p95_s": percentile(s, 95),
-        "max_s": s[-1],
-    }
 
 
 def _slope(xs, ys):
@@ -2050,324 +1449,6 @@ def render_markdown(rep):
             for d in deg[:4]:
                 lines.append(f"- **degraded** at step {d.get('step')}: "
                              f"{d.get('note')}")
-        lines.append("")
-    sv = rep.get("service")
-    if sv:
-        lines += ["## Service", ""]
-        ql = (sv.get("queue_latency_s") or {})
-        overall = ql.get("overall") or {}
-        lines.append(
-            f"- {_fmt(sv.get('requests'), '.0f', '0')} request(s) "
-            f"dispatched over {_fmt(sv.get('leases'), '.0f', '0')} "
-            f"lease(s): {_fmt(sv.get('completed'), '.0f', '0')} "
-            f"completed, {_fmt(sv.get('diverged'), '.0f', '0')} "
-            f"diverged, "
-            f"{_fmt(sum((sv.get('rejected') or {}).values()), '.0f', '0')}"
-            f" rejected"
-            + (f" ({', '.join(f'{k}: {v}' for k, v in sorted((sv.get('rejected') or {}).items()))})"
-               if sv.get("rejected") else ""))
-        lines.append(
-            f"- queue latency: p50 {_fmt(overall.get('p50_s'))} s, "
-            f"p95 {_fmt(overall.get('p95_s'))} s over "
-            f"{_fmt(overall.get('count'), '.0f', '0')} dispatch(es)")
-        for cls, row in sorted((ql.get("by_priority") or {}).items()):
-            lines.append(
-                f"  - class {cls}: p50 {_fmt(row.get('p50_s'))} s, "
-                f"p95 {_fmt(row.get('p95_s'))} s "
-                f"({row.get('count')} dispatch(es))")
-        tf = sv.get("ttfs_s") or {}
-        warm_t, cold_t = tf.get("warm") or {}, tf.get("cold") or {}
-        lines.append(
-            f"- time-to-first-step: warm p50 "
-            f"{_fmt(warm_t.get('p50_s'))} s "
-            f"({_fmt(warm_t.get('count'), '.0f', '0')} lease(s)), "
-            f"cold p50 {_fmt(cold_t.get('p50_s'))} s "
-            f"({_fmt(cold_t.get('count'), '.0f', '0')} lease(s))")
-        lines.append(
-            f"- warm path: {_fmt(sv.get('warm_leases'), '.0f', '0')} "
-            f"warm lease(s), "
-            f"{_fmt(sv.get('warm_lease_backend_compiles'), '.0f', '0')} "
-            "backend compile(s) on them (the contract is ZERO)"
-            + ("" if not sv.get("warm_lease_backend_compiles") else
-               " — **dispatch-never-compile violated**"))
-        bad_warm = [a for a in sv.get("warm_admissions") or []
-                    if a.get("fingerprint_ok") is False]
-        if bad_warm:
-            lines.append(
-                f"- **{len(bad_warm)} warm admission(s) over "
-                "mismatched fingerprints** — the gate refuses this "
-                "report")
-        lines.append(
-            f"- {_fmt(sv.get('preemptions'), '.0f', '0')} "
-            f"preemption(s), "
-            f"{_fmt(sv.get('work_lost_to_replay_member_steps'), '.0f', '0')}"
-            f" member-step(s) lost to replay, "
-            f"{_fmt(sv.get('lease_failures'), '.0f', '0')} lease "
-            "failure(s)")
-        shares = sv.get("tenant_share") or {}
-        if shares:
-            lines.append("- tenant occupancy: " + ", ".join(
-                f"{t} {_fmt(f, '.1%')}"
-                for t, f in sorted(shares.items())))
-        lg = sv.get("loadgen")
-        if lg:
-            lines.append(
-                f"- loadgen (seed {lg.get('seed')}): "
-                f"{_fmt(lg.get('requests'), '.0f', '0')} request(s), "
-                f"{_fmt(lg.get('warm_admissions'), '.0f', '0')} warm / "
-                f"{_fmt(lg.get('cold_admissions'), '.0f', '0')} cold "
-                "admission(s), preempted-resume bit-exact: "
-                f"{lg.get('preempt_bitexact')}")
-        lines.append("")
-    lat = rep.get("latency")
-    if lat:
-        lines += ["## Latency (request critical path)", ""]
-        wall = lat.get("wall_s") or {}
-        lines.append(
-            f"- {_fmt(lat.get('assembled'), '.0f', '0')} of "
-            f"{_fmt(lat.get('traced'), '.0f', '0')} traced request(s) "
-            f"assembled; submit→retire wall p50 "
-            f"{_fmt(wall.get('p50_s'))} s, p95 {_fmt(wall.get('p95_s'))}"
-            " s")
-        if lat.get("unassembled"):
-            n_bad = lat.get("unassembled_total")
-            if not isinstance(n_bad, int):
-                n_bad = len(lat["unassembled"])
-            lines.append(
-                f"- **{n_bad} traced request(s) "
-                "failed to assemble** (coverage loss; see "
-                "`latency.unassembled`)")
-        chk = lat.get("phase_sum_check") or {}
-        if chk.get("max_rel_err") is not None:
-            lines.append(
-                f"- partition audit: phases sum to the wall within "
-                f"{_fmt(chk['max_rel_err'], '.2%')} worst-case "
-                f"(tolerance {_fmt(chk.get('tolerance'), '.0%')}: "
-                f"{'OK' if chk.get('ok') else '**VIOLATED**'})")
-        phases = lat.get("phases_s") or {}
-        if phases:
-            lines += ["", "| phase | requests | p50 s | p95 s | max s |",
-                      "|---|---|---|---|---|"]
-            for name, row in sorted(
-                    phases.items(),
-                    key=lambda kv: -(kv[1].get("p50_s") or 0.0)):
-                lines.append(
-                    f"| `{name}` | {row.get('count')} "
-                    f"| {_fmt(row.get('p50_s'))} "
-                    f"| {_fmt(row.get('p95_s'))} "
-                    f"| {_fmt(row.get('max_s'))} |")
-            lines.append("")
-        dom = lat.get("dominant_phase") or {}
-        if dom:
-            lines.append("- dominant phase: " + ", ".join(
-                f"`{p}` ×{n}" for p, n in sorted(
-                    dom.items(), key=lambda kv: -kv[1])))
-        dl = lat.get("deadline") or {}
-        if dl.get("deadlined"):
-            rate = dl.get("miss_rate")
-            lines.append(
-                f"- deadlines: {dl.get('missed')} of "
-                f"{dl.get('deadlined')} deadlined request(s) missed "
-                f"({_fmt(rate, '.0%')}); margin p50 "
-                f"{_fmt((dl.get('margin_s') or {}).get('p50_s'))} s")
-            for cls, row in sorted((dl.get("by_priority") or {}).items()):
-                lines.append(
-                    f"  - class {cls}: {row.get('missed')}/"
-                    f"{row.get('deadlined')} missed "
-                    f"({_fmt(row.get('miss_rate'), '.0%')})")
-        lines.append("")
-    al = rep.get("alerts")
-    if al:
-        lines += ["## SLO alerts (live burn-rate monitor)", ""]
-        lines.append(
-            f"- {_fmt(al.get('alerts'), '.0f', '0')} alert(s) fired, "
-            f"{_fmt(al.get('resolved'), '.0f', '0')} resolved, "
-            f"{_fmt(al.get('flaps'), '.0f', '0')} flap(s) "
-            "(re-fires after a resolve)")
-        for rec in al.get("unresolved") or []:
-            lines.append(
-                f"- **UNRESOLVED at exit**: `{rec.get('leg')}` burning "
-                f"at {_fmt(rec.get('value'))} vs bar "
-                f"{_fmt(rec.get('bar'))} — the gate refuses this "
-                "report if its post-hoc SLO section claims green")
-        for leg, r in sorted((al.get("by_leg") or {}).items()):
-            lines.append(
-                f"  - `{leg}`: {r.get('alerts')} fired / "
-                f"{r.get('resolved')} resolved, total "
-                f"{_fmt(r.get('total_alert_s'))} s alerting"
-                + (f" (max {_fmt(r.get('max_alert_s'))} s)"
-                   if r.get("max_alert_s") is not None else ""))
-        lines.append("")
-    pf = rep.get("perf")
-    if pf:
-        lines += ["## Continuous performance (obs.perf)", ""]
-        an = pf.get("anomalies") or {}
-        lines.append(
-            f"- {_fmt(an.get('alerts'), '.0f', '0')} anomaly(ies) "
-            f"fired, {_fmt(an.get('resolved'), '.0f', '0')} recovered, "
-            f"{_fmt(an.get('flaps'), '.0f', '0')} flap(s)")
-        for rec in an.get("unresolved") or []:
-            lines.append(
-                f"- **UNRESOLVED at exit**: `{rec.get('leg')}` at "
-                f"{_fmt(rec.get('value'))} ms vs baseline "
-                f"{_fmt(rec.get('bar'))} ms — the gate refuses this "
-                "report if its step-time verdict claims green")
-        for sig, d in sorted((pf.get("digests") or {}).items()):
-            lines.append(
-                f"  - `{sig}` digest: p50 {_fmt(d.get('p50_ms'))} / "
-                f"p95 {_fmt(d.get('p95_ms'))} / "
-                f"p99 {_fmt(d.get('p99_ms'))} ms over "
-                f"{_fmt(d.get('count'), '.0f')} step(s)")
-        st = pf.get("straggler")
-        if st:
-            slow = st.get("slowest") or {}
-            lines.append(
-                f"- straggler attribution: host {slow.get('host')} at "
-                f"{_fmt(slow.get('mean_ms'))} ms vs fleet median "
-                f"{_fmt(st.get('median_ms'))} ms "
-                f"(skew {_fmt(st.get('skew'))}"
-                + (", **skewed**)" if st.get("skewed") else ")"))
-        for cap in pf.get("captures") or []:
-            art = cap.get("artifact")
-            lines.append(
-                f"- flight-recorder capture (`{cap.get('signature')}`, "
-                f"{cap.get('steps')} step(s)): "
-                + (f"`{art}`" if art else "no artifact ("
-                   + str(cap.get("error")
-                         or "profiler produced no trace") + ")"))
-        sup = pf.get("captures_suppressed")
-        if sup:
-            lines.append(f"- {sup} capture request(s) rate-limit "
-                         "suppressed (one trace per cooldown)")
-        lines.append("")
-    fl = rep.get("fleet")
-    if fl:
-        lines += ["## Fleet (replica registry + federation)", ""]
-        cov = fl.get("coverage") or {}
-        lines.append(
-            f"- {_fmt(cov.get('replicas'), '.0f', '0')} replica(s) "
-            f"seen, {_fmt(cov.get('lost'), '.0f', '0')} lost, "
-            f"{_fmt(fl.get('scrapes'), '.0f', '0')} aggregation "
-            f"pass(es), scrape success "
-            f"{_fmt(fl.get('scrape_success_rate'), '.0%')} "
-            f"({'complete' if cov.get('complete') else 'PARTIAL'} "
-            "coverage)")
-        rows = fl.get("replicas") or []
-        if rows:
-            lines += ["", "| replica | status | heartbeat age s "
-                      "| queue | fingerprint |", "|---|---|---|---|---|"]
-            for row in rows:
-                lines.append(
-                    f"| `{row.get('replica')}` | {row.get('status')} "
-                    f"| {_fmt(row.get('age_s'))} "
-                    f"| {_fmt(row.get('queue_depth'), '.0f')} "
-                    f"| `{row.get('fingerprint') or '—'}` |")
-            lines.append("")
-        for rec in fl.get("replicas_lost") or []:
-            lines.append(
-                f"- **replica lost**: `{rec.get('replica')}` "
-                f"({rec.get('reason')}) — the fleet verdict is "
-                "degraded, not silently averaged over the survivors")
-        legs = fl.get("legs") or {}
-        if legs:
-            lines += ["", "| fleet leg | value | bar | alerting |",
-                      "|---|---|---|---|"]
-            for name, leg in sorted(legs.items()):
-                lines.append(
-                    f"| `{name}` | {_fmt(leg.get('value_fast'))} "
-                    f"| {_fmt(leg.get('bar'))} "
-                    f"| {'YES' if leg.get('alerting') else 'no'} |")
-            lines.append("")
-        fal = fl.get("alerts")
-        if fal:
-            lines.append(
-                f"- fleet alerts: {_fmt(fal.get('alerts'), '.0f', '0')} "
-                f"fired, {_fmt(fal.get('resolved'), '.0f', '0')} "
-                f"resolved, {_fmt(fal.get('flaps'), '.0f', '0')} "
-                "flap(s)")
-            for rec in fal.get("unresolved") or []:
-                lines.append(
-                    f"- **UNRESOLVED at exit**: fleet `{rec.get('leg')}` "
-                    f"burning at {_fmt(rec.get('value'))} vs bar "
-                    f"{_fmt(rec.get('bar'))}")
-        skew = fl.get("skew") or {}
-        if skew.get("skewed"):
-            lines.append(
-                f"- **version/flag SKEW**: {skew.get('stacks')} "
-                "distinct compiler stacks across live replicas")
-        if fl.get("divergence"):
-            lines.append(
-                "- **warm-fingerprint divergence**: "
-                + ", ".join(f"`{s}`" for s in fl["divergence"]))
-        lines.append("")
-    cap = rep.get("capacity")
-    if cap:
-        lines += ["## Capacity & goodput (obs.capacity)", ""]
-        cov = cap.get("coverage") or {}
-        wm = cap.get("watermarks") or {}
-        lines.append(
-            f"- {_fmt(wm.get('samples'), '.0f', '0')} watermark "
-            f"sample(s) over {_fmt(cov.get('leases'), '.0f')} "
-            f"lease(s) ("
-            + ("complete coverage" if cov.get("complete") else
-               ("predicted-only — stat-less backend"
-                if cov.get("predicted_only") else "PARTIAL coverage"))
-            + ")")
-        rec = cap.get("reconciliation")
-        if rec:
-            lines.append(
-                f"- reconciliation: predicted "
-                f"{_fmt(rec.get('predicted_bytes'), ',.0f')} B vs peak "
-                f"{_fmt(rec.get('peak_bytes_in_use'), ',.0f')} B in use "
-                f"(rel err {_fmt(rec.get('rel_err'), '.1%')})")
-        fps = cap.get("footprints") or []
-        if fps:
-            lines += ["", "| program | fingerprint | predicted bytes "
-                      "| source |", "|---|---|---|---|"]
-            for row in fps:
-                lines.append(
-                    f"| `{row.get('label')}` "
-                    f"| `{row.get('fingerprint') or '—'}` "
-                    f"| {_fmt(row.get('predicted_bytes'), ',.0f')} "
-                    f"| {row.get('source')} |")
-            lines.append("")
-        rej = cap.get("rejections") or {}
-        if rej.get("count"):
-            last = rej.get("last") or {}
-            lines.append(
-                f"- **{rej['count']} CapacityExceeded rejection(s)** "
-                f"({', '.join(f'`{s}`' for s in rej.get('signatures') or [])}) "
-                f"— last: predicted "
-                f"{_fmt(last.get('predicted_bytes'), ',.0f')} B over "
-                f"budget {_fmt(last.get('budget_bytes'), ',.0f')} B")
-        if cap.get("evictions"):
-            lines.append(
-                f"- {cap['evictions']} warm-pool eviction(s) under the "
-                "queue-behind-eviction policy")
-        for path in cap.get("oom_bundles") or []:
-            lines.append(f"- **OOM forensic bundle**: `{path}`")
-        tenants = cap.get("tenants") or {}
-        if tenants:
-            lines += ["", "| tenant | requests | chip-s | waste chip-s "
-                      "| committed steps | goodput steps/chip-s |",
-                      "|---|---|---|---|---|---|"]
-            for name in sorted(tenants):
-                row = tenants[name]
-                lines.append(
-                    f"| `{name}` | {_fmt(row.get('requests'), '.0f')} "
-                    f"| {_fmt(row.get('chip_s'))} "
-                    f"| {_fmt(row.get('waste_chip_s'))} "
-                    f"| {_fmt(row.get('committed_steps'), '.0f')} "
-                    f"| {_fmt(row.get('goodput'))} |")
-            lines.append("")
-        if cap.get("goodput") is not None:
-            lines.append(
-                f"- goodput: **{_fmt(cap.get('goodput'))} committed "
-                f"member-steps per chip-second** "
-                f"({_fmt(cap.get('committed_steps'), '.0f', '0')} steps "
-                f"/ {_fmt(cap.get('total_chip_s'))} chip-s, "
-                f"{_fmt(cap.get('waste_chip_s'))} chip-s replay+drain "
-                "waste)")
         lines.append("")
     ff = rep.get("fft")
     if ff:

@@ -39,7 +39,9 @@ import dataclasses
 import hashlib
 import json
 import os
+import platform
 import re
+import socket
 import threading
 import time
 
@@ -53,7 +55,8 @@ __all__ = ["CompileRecord", "compile_with_report", "compile_watch",
            "compile_totals",
            "ensure_compilation_cache",
            "program_fingerprint", "signature_fingerprint",
-           "runtime_versions", "device_memory_stats",
+           "runtime_versions", "flags_fingerprint",
+           "environment_fingerprint", "device_memory_stats",
            "device_memory_report"]
 
 
@@ -173,17 +176,6 @@ class compile_watch:
         return (self.trace_seconds > 0.0 or self.compile_seconds > 0.0
                 or self.cache_hits > 0 or self.cache_misses > 0)
 
-    @property
-    def backend_compiles(self):
-        """Backend (XLA) compiles the block actually paid — THE
-        zero-extra-compiles proof quantity (warm service leases): the
-        cache-miss count when cache
-        counters were observed, else inferred from any nonzero
-        backend-compile span (a backend without cache telemetry)."""
-        if self.cache_hits or self.cache_misses:
-            return int(self.cache_misses)
-        return 1 if self.compile_seconds > 0 else 0
-
     def __enter__(self):
         _install_jax_listeners()
         _watcher_stack().append(self)
@@ -203,18 +195,35 @@ class compile_watch:
 _versions_cache = None
 
 
+def _version_of(dist):
+    try:
+        from importlib.metadata import version
+        return version(dist)
+    except Exception:
+        return None
+
+
 def runtime_versions():
-    """The compiler-stack versions that invalidate cached/AOT programs:
-    a jax/jaxlib (or libtpu) bump must never silently load a stale
-    executable, so these are baked into every program fingerprint and
-    warm-start artifact. One definition, shared with the perf report's
-    environment fingerprint (``obs.ledger.runtime_versions``).
-    (Memoized — ``importlib.metadata`` scans dist-info, and
-    fingerprints are computed per observed compile.)"""
+    """The jax/jaxlib/libtpu version triple, the compiler stack that
+    invalidates cached/AOT programs: a jax/jaxlib (or libtpu) bump must
+    never silently load a stale executable, so these are baked into
+    every program fingerprint and warm-start artifact. One definition,
+    shared with the perf report's environment fingerprint
+    (:func:`environment_fingerprint`) so the two can never diverge.
+    Resolved from installed-distribution metadata and memoized
+    (``importlib.metadata`` scans dist-info, and fingerprints are
+    computed per observed compile)."""
     global _versions_cache
     if _versions_cache is None:
-        from pystella_tpu.obs import ledger as _ledger
-        _versions_cache = _ledger.runtime_versions()
+        _versions_cache = {
+            "jax": _version_of("jax"),
+            "jaxlib": _version_of("jaxlib"),
+            # a libtpu bump changes the generated code: cached/AOT
+            # programs keyed without it would silently serve stale
+            # executables
+            "libtpu": (_version_of("libtpu")
+                       or _version_of("libtpu-nightly")),
+        }
     return dict(_versions_cache)
 
 
@@ -238,14 +247,67 @@ def _leaf_signature(leaf):
     return sig
 
 
+#: env-var name substrings that make an XLA/libtpu flag relevant to a
+#: fingerprint: async-collective and latency-hiding-scheduler toggles
+#: change the compiled schedule, and so what a step-time comparison
+#: means (the overlapped halo path depends on them to pay off)
+_FLAG_MARKERS = ("async_collective", "async_all_gather",
+                 "latency_hiding", "scheduler")
+
+
+def flags_fingerprint(env=os.environ):
+    """The scheduler-relevant flags in ``env`` (``XLA_FLAGS`` +
+    ``LIBTPU_INIT_ARGS``) as ``{name: value}``, plus the
+    ``PYSTELLA_HALO_OVERLAP`` policy setting when present, so a report
+    says whether the overlapped code path was even eligible. Hashed
+    into every program fingerprint and embedded in every report's
+    environment fingerprint, so the gate can warn when two reports
+    differ only in flags."""
+    flags = {}
+    for var in ("XLA_FLAGS", "LIBTPU_INIT_ARGS"):
+        for tok in env.get(var, "").split():
+            name, _, value = tok.lstrip("-").partition("=")
+            if any(m in name for m in _FLAG_MARKERS):
+                flags[name] = value if value else "true"
+    setting = env.get("PYSTELLA_HALO_OVERLAP")
+    if setting is not None:
+        flags["PYSTELLA_HALO_OVERLAP"] = setting
+    return flags
+
+
+def environment_fingerprint():
+    """Everything needed to decide whether two perf reports (or two
+    forensic bundles) are comparable: python and compiler-stack
+    versions, hostname, device kind and count, process count, and the
+    scheduler-relevant flags."""
+    env = {
+        "python": platform.python_version(),
+        **runtime_versions(),
+        "hostname": socket.gethostname(),
+        "platform": None,
+        "device_kind": None,
+        "num_devices": None,
+        "num_processes": None,
+        "xla_flags": flags_fingerprint(),
+    }
+    try:
+        devs = jax.devices()
+        env["platform"] = devs[0].platform
+        env["device_kind"] = devs[0].device_kind
+        env["num_devices"] = len(devs)
+        env["num_processes"] = int(jax.process_count())
+    except Exception:
+        pass
+    return env
+
+
 def fingerprint_components(label="", args=None, kwargs=None):
     """The JSON-safe identity a program fingerprint hashes: label,
     per-leaf shape/dtype/sharding/mesh signature, compiler-stack
     versions (:func:`runtime_versions`), and the scheduler-relevant
-    flag fingerprint (``parallel.overlap.flags_fingerprint`` — the
-    same flags the perf-report environment records, because they change
-    the compiled schedule)."""
-    from pystella_tpu.parallel.overlap import flags_fingerprint
+    flag fingerprint (:func:`flags_fingerprint`, the same flags the
+    perf-report environment records, because they change the compiled
+    schedule)."""
     leaves = []
     if args is not None or kwargs is not None:
         leaves = [_leaf_signature(leaf) for leaf in
@@ -515,8 +577,9 @@ def instrument_jit(fn, label, name=None, **jit_kwargs):
     (:func:`program_name`), so the compiled module, and with it every
     row of a device trace, says which program it is instead of
     ``jit__unknown`` / ``jit_wrapped`` / ``jit__lambda``; its compiles
-    land in the compile ledger under ``label``. The package's internal jit sites (steppers, fused chunks,
-    operators, reductions, multigrid, spectra) all route through this,
+    land in the compile ledger under ``label``. The package's internal
+    jit sites (steppers, fused chunks, operators, reductions, multigrid,
+    spectra) all route through this,
     and a stencil kernel where it is dispatched eagerly (``name``
     given: the kernel's kind)."""
     def named(*args, **kwargs):

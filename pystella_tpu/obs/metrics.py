@@ -21,14 +21,14 @@ incremented inside jit-traced code count *traces*, not executions —
 increment from host-level entry points (``step()``, the cycle driver)
 for true counts; traced increments are a static proxy only.
 
-Thread-safety contract (the live telemetry endpoint scrapes
-:meth:`MetricsRegistry.snapshot` from its own daemon thread while the
-serve loop updates): every metric a registry creates shares the
-registry's re-entrant lock, each update (``inc``/``set``/``observe``)
-is one atomic section under it, and ``snapshot`` holds the same lock
-across ALL exports — a scrape can never observe a Timer between its
-``count`` bump and its ``total_s`` accumulation, or a half-updated
-EMA. A metric constructed standalone gets its own lock.
+Thread-safety contract (a reader may take
+:meth:`MetricsRegistry.snapshot` from another thread while the driver
+loop updates): every metric a registry creates shares the registry's
+re-entrant lock, each update (``inc``/``set``/``observe``) is one
+atomic section under it, and ``snapshot`` holds the same lock across
+ALL exports — a reader can never observe a Timer between its ``count``
+bump and its ``total_s`` accumulation, or a half-updated EMA. A metric
+constructed standalone gets its own lock.
 """
 
 from __future__ import annotations
@@ -61,10 +61,6 @@ class Counter:
         with self._lock:
             return {self.name: (float(self.value), "sum")}
 
-    def export_typed(self):
-        with self._lock:
-            return {self.name: (float(self.value), "counter")}
-
 
 class Gauge:
     """Last-set value; cross-host reduction per ``reduce``."""
@@ -86,10 +82,6 @@ class Gauge:
     def export(self):
         with self._lock:
             return {self.name: (self.value, self.reduce)}
-
-    def export_typed(self):
-        with self._lock:
-            return {self.name: (self.value, "gauge")}
 
 
 class Timer:
@@ -129,12 +121,6 @@ class Timer:
             return {f"{self.name}.count": (float(self.count), "sum"),
                     f"{self.name}.total_s": (self.total_s, "sum"),
                     f"{self.name}.ema_ms": (self.ema_ms, "mean")}
-
-    def export_typed(self):
-        with self._lock:
-            return {f"{self.name}.count": (float(self.count), "counter"),
-                    f"{self.name}.total_s": (self.total_s, "counter"),
-                    f"{self.name}.ema_ms": (self.ema_ms, "gauge")}
 
 
 class MetricsRegistry:
@@ -181,32 +167,23 @@ class MetricsRegistry:
 
     # -- snapshots and aggregation ----------------------------------------
 
-    def _exports(self, typed=False):
+    def _exports(self):
         """Sorted flat exports ``{key: (value, reduce_op)}`` — sorted so
         every host's snapshot vector lines up positionally for the
         cross-host gather (all hosts must register the same metrics,
         which lockstep SPMD drivers do by construction). Held under the
         registry lock end to end, so the whole vector is one consistent
-        cut even while another thread updates (the scrape-vs-serve-loop
-        race the live endpoint's thread-safety pin covers)."""
+        cut even while another thread updates."""
         with self._lock:
             flat = {}
             for m in self._metrics.values():
-                flat.update(m.export_typed() if typed else m.export())
+                flat.update(m.export())
         return dict(sorted(flat.items()))
 
     def snapshot(self):
         """Local values as ``{name: float}`` (sorted by name); one
         consistent cut under the registry lock (module docstring)."""
         return {k: v for k, (v, _) in self._exports().items()}
-
-    def snapshot_typed(self):
-        """Local values as ``{name: (float, prom_kind)}`` where
-        ``prom_kind`` is the Prometheus exposition type (``counter`` /
-        ``gauge``) — what :func:`pystella_tpu.obs.live.
-        render_prometheus` renders. Same consistency guarantee as
-        :meth:`snapshot`."""
-        return self._exports(typed=True)
 
     def reduce_snapshots(self, snapshots):
         """Reduce a sequence of per-host ``{name: value}`` snapshots

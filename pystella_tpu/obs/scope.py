@@ -170,16 +170,6 @@ for _name in (
     # scope-path rows are absent (longest-match folding keeps a
     # TPU row like `jit(..)/fft_stage/fft.3` in `fft_stage`, not here)
     "all-to-all", "fft",
-    # the scenario service's request-scoped span vocabulary
-    # (obs.spans): the SpanAssembler exports assembled request
-    # timelines as Perfetto complete-span rows under THESE names, so
-    # hardware profiler captures and service traces fold through one
-    # parser (obs.trace.scope_durations) — the critical-path phases...
-    "service_queue_wait", "service_admission", "service_compile",
-    "service_chunk_compute", "service_checkpoint_barrier",
-    "service_recovery_replay", "service_preempt_drain",
-    # ...plus the structural spans they hang off
-    "service_request_span", "service_lease_span",
 ):
     register_scope(_name)
     if _name.startswith("pallas_stencil"):

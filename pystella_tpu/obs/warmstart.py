@@ -39,7 +39,7 @@ CPU-safe 8-device builds the IR audit lowers) and serializes each;
 process's versions/flags (exit 1 when any is stale); ``list``
 enumerates artifacts with fingerprint/version/match-status (always
 exit 0); ``gc`` removes version- or flag-STALE exports — the tending a
-long-lived warm pool needs, since until now the store only ever grew
+long-lived store needs, since otherwise it only ever grows
 (a matching artifact is never touched; staleness is exactly the rule
 :meth:`WarmstartStore.load` refuses on). Exit codes: 0 ok, 1
 mismatch/failure, 2 bad usage.
@@ -302,10 +302,10 @@ def _gc_candidates(store):
 
 def gc_store(store, dry_run=False, log=None):
     """Garbage-collect STALE artifacts (version/flag mismatch against
-    the live process): the warm pool needs a tended store — exports
-    keyed on yesterday's compiler stack only cost disk and load-time
-    refusals. Returns ``(kept, removed)`` metadata lists; with
-    ``dry_run`` nothing is deleted. Emits one ``warmstart_gc`` event.
+    the live process): exports keyed on yesterday's compiler stack
+    only cost disk and load-time refusals. Returns ``(kept, removed)``
+    metadata lists; with ``dry_run`` nothing is deleted. Emits one
+    ``warmstart_gc`` event.
 
     Artifacts that merely belong to OTHER labels stay: staleness is
     strictly the fingerprint components the loader itself refuses on
@@ -377,9 +377,8 @@ def main(argv=None):
                          "$PYSTELLA_WARMSTART_DIR)")
     pg = sub.add_parser(
         "gc", help="garbage-collect STALE exports (version- or "
-                   "flag-mismatched against the live process) — the "
-                   "warm pool needs a tended store; matching artifacts "
-                   "are never touched")
+                   "flag-mismatched against the live process); "
+                   "matching artifacts are never touched")
     pg.add_argument("--dir", default=None,
                     help="artifact directory (default: "
                          "$PYSTELLA_WARMSTART_DIR)")

@@ -14,16 +14,13 @@ canonical optimization for distributed finite-difference solvers
 (Devito's MPI-X "computation/communication overlap", arxiv 2312.13094;
 the interior/boundary split of arxiv 2309.04671).
 
-This module is the POLICY side:
-
-- :func:`enabled` — resolves whether a given mesh takes the overlapped
-  path: per-call/constructor override > ``PYSTELLA_HALO_OVERLAP`` env
-  (``1``/``0``/``auto``) > auto (on for sharded meshes, i.e. >1 rank on
-  any lattice axis).
-- :func:`flags_fingerprint` — the scheduler-relevant flags currently in
-  the environment, recorded into ``perf_report.json``'s environment
-  fingerprint so two reports that differ only in scheduler flags are
-  flagged by the gate (warning, not refusal).
+This module is the POLICY side: :func:`enabled` resolves whether a
+given mesh takes the overlapped path: per-call/constructor override >
+``PYSTELLA_HALO_OVERLAP`` env (``1``/``0``/``auto``) > auto (on for
+sharded meshes, i.e. >1 rank on any lattice axis). The setting and the
+scheduler flags it depends on are recorded in every report's
+environment fingerprint (:func:`pystella_tpu.obs.memory.
+flags_fingerprint`).
 
 The MECHANISM lives in
 :meth:`~pystella_tpu.DomainDecomposition.overlap_stencil` (XLA-stencil
@@ -42,26 +39,18 @@ scheduling.
 from __future__ import annotations
 
 import logging
-import os
 
 from pystella_tpu import config as _config
 
 logger = logging.getLogger(__name__)
 
-__all__ = ["enabled", "env_setting", "flags_fingerprint",
-           "MIN_INTERIOR_FACTOR"]
+__all__ = ["enabled", "env_setting", "MIN_INTERIOR_FACTOR"]
 
 #: a block must span at least ``MIN_INTERIOR_FACTOR * h`` sites along a
 #: communicated axis for the interior/shell split to leave a non-empty
 #: interior worth hiding the transfer behind (two h-deep shells + at
 #: least h interior rows); thinner blocks take the padded path.
 MIN_INTERIOR_FACTOR = 3
-
-#: env-var name substrings that make a flag scheduler-relevant for the
-#: fingerprint (kept deliberately broad: any async-collective or
-#: latency-hiding toggle changes what a step-time comparison means)
-_FLAG_MARKERS = ("async_collective", "async_all_gather",
-                 "latency_hiding", "scheduler")
 
 
 def env_setting():
@@ -92,22 +81,3 @@ def enabled(decomp=None, override=None):
     if decomp is None:
         return False
     return any(p > 1 for p in decomp.proc_shape)
-
-
-def flags_fingerprint(env=os.environ):
-    """The scheduler-relevant flags active in this process's
-    environment, as ``{flag_name: value}`` — parsed from ``XLA_FLAGS``
-    and ``LIBTPU_INIT_ARGS`` (stdlib-only; the perf ledger embeds this
-    in every report's environment fingerprint). Also records the
-    overlap policy env itself, so a report says whether the overlapped
-    code path was even eligible."""
-    flags = {}
-    for var in ("XLA_FLAGS", "LIBTPU_INIT_ARGS"):
-        for tok in env.get(var, "").split():
-            name, _, value = tok.lstrip("-").partition("=")
-            if any(m in name for m in _FLAG_MARKERS):
-                flags[name] = value if value else "true"
-    setting = env.get("PYSTELLA_HALO_OVERLAP")
-    if setting is not None:
-        flags["PYSTELLA_HALO_OVERLAP"] = setting
-    return flags

@@ -452,24 +452,6 @@ class Supervisor:
         t0 = time.monotonic()
         err_str = f"{type(error).__name__}: {error}"
         trip_step = getattr(error, "step", at_step)
-        # one causal span per incident (schema v2): every recovery
-        # event below shares it, with the enclosing context (a service
-        # lease span, when the supervisor runs under one) recorded as
-        # its parent — the span assembler attributes the whole MTTR to
-        # the lease's recovery-replay phase through that link. Outside
-        # any tracing context (a standalone supervised run, or the
-        # service with PYSTELLA_TRACE_SERVICE=0) the events stay
-        # v1-shaped: an orphan span id would attach to nothing
-        if _events.current_trace() is not None:
-            with _events.tracing(span=_events.new_span_id()):
-                return self._recover_traced(kind, error, err_str,
-                                            at_step, trip_step, state,
-                                            t0)
-        return self._recover_traced(kind, error, err_str, at_step,
-                                    trip_step, state, t0)
-
-    def _recover_traced(self, kind, error, err_str, at_step, trip_step,
-                        state, t0):
         _events.emit("fault_detected", step=at_step, label=self.label,
                      fault_kind=kind, error=err_str, trip_step=trip_step)
 
@@ -566,9 +548,8 @@ class Supervisor:
         inside the run loop's fault triage: a trip here (corrupt state
         caught by the drain's own health check) recovers first, then
         the still-set preemption flag drains the restored state. The
-        drain's wall cost lands on ``run_preempted`` as ``drain_s`` —
-        the span assembler's preempt-drain phase is measured, not
-        inferred."""
+        drain's wall cost lands on ``run_preempted`` as ``drain_s``:
+        measured, not inferred."""
         t_drain0 = time.monotonic()
         if self.monitor is not None:
             # same contract as _checkpoint: a diverged state must

@@ -248,14 +248,6 @@ class Stepper:
             return state
         return fn
 
-    def batched(self, size, **kwargs):
-        """An :class:`~pystella_tpu.ensemble.EnsembleStepper` driving
-        ``size`` members of this stepper as one batched computation
-        (per-member t/dt/parameters as batched pytree leaves; see
-        :mod:`pystella_tpu.ensemble`)."""
-        from pystella_tpu.ensemble import EnsembleStepper
-        return EnsembleStepper(self, size, **kwargs)
-
     # -- per-stage interface (reference-style driver loops) ----------------
 
     def __call__(self, stage, state_or_carry, t=0.0, dt=None, **rhs_args):

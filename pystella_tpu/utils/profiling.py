@@ -101,37 +101,16 @@ class StepTimer:
     example runs enable it; leave it off for
     million-step production runs where one event per step is too chatty).
 
-    Every tick also feeds the continuous-performance plane
-    (:mod:`pystella_tpu.obs.perf`): the sample lands in the
-    process-default per-signature step-time digest + CUSUM change-point
-    detector, so every driver that owns a StepTimer is a
-    ``perf_anomaly`` source with no code changes. ``PYSTELLA_PERF=0``
-    (or ``perf=False``) opts out.
-
     :arg report_every: seconds between window reports.
     :arg emit_steps: emit a ``step_time`` event on every tick.
     :arg sample_capacity: per-step samples retained in
         :attr:`samples_ms`.
-    :arg signature: program signature the perf digest files samples
-        under (one detector baseline per signature).
-    :arg perf: ``None`` (default) feeds the process-default
-        :class:`~pystella_tpu.obs.perf.PerfMonitor` when
-        ``PYSTELLA_PERF`` is on; ``False`` disables the feed; a
-        :class:`~pystella_tpu.obs.perf.PerfMonitor` instance is used
-        directly (drills).
-    :arg clock: the monotonic seconds source ticks are timed on
-        (default ``time.perf_counter``; a drill injects one that only
-        moves when it says so).
     """
 
     def __init__(self, report_every=30.0, emit_steps=False,
-                 sample_capacity=4096, signature="step", perf=None,
-                 clock=time.perf_counter):
-        self.clock = clock
+                 sample_capacity=4096):
         self.report_every = float(report_every)
         self.emit_steps = bool(emit_steps)
-        self.signature = str(signature)
-        self._perf = perf
         self.samples_ms = collections.deque(maxlen=int(sample_capacity))
         # the clock starts at the FIRST tick, not at construction, so
         # timing covers steps 2..N and excludes the first step's jit
@@ -151,7 +130,7 @@ class StepTimer:
 
     def tick(self):
         self.steps += 1
-        now = self.clock()
+        now = time.perf_counter()
         if self.last_tick is None:
             self.last_tick = now
             self.last_report = now
@@ -162,14 +141,6 @@ class StepTimer:
         self.last_tick = now
         self._timer.observe(elapsed)  # the one accumulator
         self.samples_ms.append(elapsed * 1e3)
-        if self._perf is not False:
-            from pystella_tpu.obs import perf as _perf
-            if self._perf is None:
-                _perf.observe(self.signature, elapsed * 1e3,
-                              step=self.steps)
-            else:
-                self._perf.observe(self.signature, elapsed * 1e3,
-                                   step=self.steps)
         if self.emit_steps:
             _events.emit("step_time", step=self.steps, ms=elapsed * 1e3)
         if now - self.last_report < self.report_every:

@@ -35,15 +35,6 @@ if "xla_force_host_platform_device_count" not in _flags:
 # (the bit-exactness contract means results must be identical).
 os.environ.setdefault("PYSTELLA_HALO_OVERLAP", "0")
 
-# Pin the continuous-performance plane's ambient feed OFF suite-wide:
-# the process-default PerfMonitor is global state (per-signature
-# detectors + the metrics-registry gauges), so StepTimer-bearing tests
-# would otherwise couple through it, and every tick pays the observe
-# path against the 870 s budget. tests/test_perf.py opts in with
-# explicit monitors/recorders (which bypass the env gate entirely) and
-# monkeypatches PYSTELLA_PERF where the gate itself is under test.
-os.environ.setdefault("PYSTELLA_PERF", "0")
-
 import common  # noqa: F401, E402  (side effect: enables x64)
 import numpy as np  # noqa: E402
 import pytest  # noqa: E402

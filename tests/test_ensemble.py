@@ -104,7 +104,8 @@ def test_vmap_tier_agrees_with_sequential():
     vmap-vs-sequential contract on the harder sharded mesh.)"""
     size = 4
     stepper = ps.LowStorageRK54(_rhs, dt=1e-3)
-    ens = stepper.batched(size, decomp=_edecomp(size), via="vmap")
+    ens = EnsembleStepper(stepper, size, decomp=_edecomp(size),
+                          via="vmap")
     members = [_member(s) for s in range(size)]
     batch = ens.stack(members)
     m2 = np.linspace(0.1, 0.7, size)
@@ -155,7 +156,7 @@ def test_map_tier_bitexact_with_fused_sequential():
                                   lattice.dx, 2, dtype=jnp.float32,
                                   bx=4, by=8)
     size, nsteps = 2, 2
-    ens = fused.batched(size)
+    ens = EnsembleStepper(fused, size)
     assert ens.via == "map"  # auto-detected fused tier
     rng = np.random.default_rng(17)
     members = [
@@ -193,7 +194,7 @@ def test_spatial_plus_ensemble_mesh_packing():
 
     stepper = ps.LowStorageRK54(rhs, dt=1e-3)
     size = 4
-    ens = stepper.batched(size, decomp=decomp, via="vmap")
+    ens = EnsembleStepper(stepper, size, decomp=decomp, via="vmap")
     members = [_member(s, shape=grid_shape) for s in range(size)]
     batch = ens.stack(members)
     spec = batch["f"].sharding.spec
@@ -423,6 +424,74 @@ def test_driver_eviction_round_trip(tmp_path):
         assert done[0]["data"]["evictions"] == 1
     finally:
         events.configure(None)
+
+
+def test_driver_preempt_drain_and_requeue_bitexact(tmp_path):
+    """A preempted run drains its active members as requeue records,
+    and ``requeue`` re-enters a member with its restored state: the
+    resumed trajectory is bit-consistent with the uninterrupted run."""
+    ev_path = str(tmp_path / "ev.jsonl")
+    events.configure(ev_path)
+    try:
+        sc = _scenario(ps.LowStorageRK54(_rhs, dt=1e-3), nsteps=8)
+
+        finals = {}
+        d0 = ps.EnsembleDriver(size=2, chunk=2, via="vmap")
+        d0.submit(sc, seeds=[0, 1])
+        out0 = d0.run(on_finish=lambda rec, st:
+                      finals.setdefault(rec["seed"], st))
+        assert out0["stats"]["preempted"] == 0 and out0["pending"] == []
+
+        d1 = ps.EnsembleDriver(size=2, chunk=2, via="vmap",
+                               preempt=lambda ci: ci >= 2)
+        d1.submit(sc, seeds=[0, 1])
+        out1 = d1.run()
+        assert len(out1["preempted"]) == 2
+        assert all(r["step"] == 4 for r in out1["preempted"])
+        assert not out1["results"]
+
+        d2 = ps.EnsembleDriver(size=2, chunk=2, via="vmap")
+        for rec in out1["preempted"]:
+            d2.requeue(rec["scenario"], rec["state"], rec["step"],
+                       seed=rec["seed"], params=rec["params"],
+                       t=rec["t"])
+        finals2 = {}
+        out2 = d2.run(on_finish=lambda rec, st:
+                      finals2.setdefault(rec["seed"], st))
+        assert [r["steps"] for r in out2["results"]] == [8, 8]
+        for seed in (0, 1):
+            for k in finals[seed]:
+                assert np.array_equal(np.asarray(finals[seed][k]),
+                                      np.asarray(finals2[seed][k])), \
+                    (seed, k)
+        recs = events.read_events(ev_path)
+        kinds = [e["kind"] for e in recs]
+        assert kinds.count("member_preempted") == 2
+        resumed = [e["data"]["resumed_from"] for e in recs
+                   if e["kind"] == "member_started"]
+        assert resumed == [None, None, None, None, 4, 4]
+    finally:
+        events.configure(None)
+
+
+def test_driver_preempt_leaves_pending_jobs():
+    """A drain hands back what never started: plain jobs as
+    ``submit`` records, and a requeued job that was drained again
+    before it got a slot with its resume payload intact."""
+    sc = _scenario(ps.LowStorageRK54(_rhs, dt=1e-3), nsteps=8)
+    d = ps.EnsembleDriver(size=2, chunk=2, via="vmap",
+                          preempt=lambda ci: True)
+    d.submit(sc, seeds=[0, 1, 2, 3])
+    state, params = sc.sample(7)
+    d.requeue(sc, state, 4, seed=7, params=params, t=4e-3)
+    out = d.run()
+    assert len(out["preempted"]) == 2
+    assert [j["seed"] for j in out["pending"]] == [2, 3, 7]
+    assert set(out["pending"][0]) == {"scenario", "seed"}
+    held = out["pending"][2]
+    assert held["step"] == 4 and held["t"] == 4e-3
+    assert held["params"] == params
+    assert held["state"] is state
 
 
 @pytest.mark.slow

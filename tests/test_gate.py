@@ -129,6 +129,22 @@ def test_ledger_from_events(tmp_path):
     assert "driver_step" in md and "Roofline" in md
 
 
+@pytest.mark.parametrize("section", ["service", "alerts", "fleet", "perf",
+                                     "capacity", "latency"])
+def test_report_has_no_service_section(section):
+    """The report describes a run of the engine; the scenario service's
+    six sections went with it (PR 45), and a report that carries one
+    (an old baseline) is compared on what remains."""
+    led = ledger.PerfLedger(label="unit", sites=8)
+    for ms in (2.0, 2.1, 2.2, 2.1):
+        led.add_step_ms(ms)
+    rep = led.report()
+    assert section not in rep
+    old = dict(rep, **{section: {"alerts": 3, "unresolved": ["x"]}})
+    verdict = gate.compare_reports(old, rep, check_contamination="never")
+    assert verdict["ok"] and section not in verdict
+
+
 def test_ledger_scopes_to_latest_run(tmp_path):
     """EventLog appends; a reused log holds several runs. The ledger
     must describe only the LATEST run — mixing two runs' step times

@@ -3,6 +3,10 @@ aggregation on the virtual multi-device CPU mesh, trace-scope no-op
 safety under ``JAX_PLATFORMS=cpu``, named-scope presence in a fused-step
 lowering, and memory-analysis capture for one fused kernel."""
 
+import os
+import sys
+import threading
+
 import numpy as np
 import pytest
 
@@ -13,6 +17,8 @@ import jax.numpy as jnp
 import pystella_tpu as ps
 from pystella_tpu import obs
 from pystella_tpu.obs import events, metrics
+from pystella_tpu.obs.events import EventLog, rotated_family
+from pystella_tpu.obs.ledger import PerfLedger
 
 
 @pytest.fixture
@@ -78,6 +84,60 @@ def test_disabled_sink_is_noop(tmp_path):
     assert log.emit("anything", x=1) is None
 
 
+# -- event-log rotation -----------------------------------------------------
+
+def test_event_log_rotation_and_family_read(tmp_path):
+    path = str(tmp_path / "run_events.jsonl")
+    log = EventLog(path, rotate_bytes=600)
+    log.emit("run_start", mode="long")
+    for i in range(40):
+        log.emit("step_time", step=i, ms=1.0 + 0.01 * i)
+    log.close()
+    family = rotated_family(path)
+    assert len(family) > 2, "600-byte threshold must have rotated"
+    assert family[-1] == os.path.abspath(path)
+    # plain read sees only the live tail; the family read sees all
+    tail = events.read_events(path)
+    full = events.read_events(path, include_rotated=True)
+    assert len(full) == 41 and len(tail) < len(full)
+    steps = [e["step"] for e in full if e["kind"] == "step_time"]
+    assert steps == list(range(40))  # oldest-first, in order
+    # the ledger ingests the whole family (run_start sits in the
+    # OLDEST member; the latest-run scoping works across the rotation)
+    led = PerfLedger.from_events(path)
+    assert led.stats()["count"] == 40
+
+
+def test_event_rotate_env_knob(tmp_path, monkeypatch):
+    monkeypatch.setenv("PYSTELLA_EVENT_ROTATE_MB", "0.0005")  # ~524 B
+    path = str(tmp_path / "ev.jsonl")
+    log = EventLog(path)
+    assert log.rotate_bytes == int(0.0005 * 2**20)
+    for i in range(30):
+        log.emit("step_time", step=i, ms=1.0)
+    log.close()
+    assert len(rotated_family(path)) > 1
+
+
+def test_subscribers_survive_rotation(tmp_path):
+    """A subscriber registered before a size-triggered rollover keeps
+    receiving every record emitted after it (subscribers hang off the
+    log object, not the file handle)."""
+    path = str(tmp_path / "run_events.jsonl")
+    log = EventLog(path, rotate_bytes=600)
+    seen = []
+    log.subscribe(seen.append)
+    for i in range(40):
+        log.emit("step_time", step=i, ms=1.0 + 0.01 * i)
+    log.close()
+    family = rotated_family(path)
+    assert len(family) > 2, "600-byte threshold must have rotated"
+    assert [r["step"] for r in seen] == list(range(40))
+    # and the on-disk family still carries the same whole stream
+    full = events.read_events(path, include_rotated=True)
+    assert [e["step"] for e in full] == list(range(40))
+
+
 # -- metrics ---------------------------------------------------------------
 
 def test_counter_gauge_timer_exports():
@@ -97,6 +157,34 @@ def test_counter_gauge_timer_exports():
     assert list(snap) == sorted(snap)  # stable cross-host ordering
     with pytest.raises(TypeError):
         reg.gauge("steps")  # kind mismatch
+
+
+def test_snapshot_consistent_under_concurrent_updates():
+    """A snapshot racing another thread's timer updates is consistent:
+    never a Timer between its count bump and its total accumulation.
+    observe(1.0) keeps total_s == count exactly (1.0 sums without
+    rounding), so any torn read is detectable."""
+    reg = metrics.MetricsRegistry()
+    t = reg.timer("hammer")
+    stop = threading.Event()
+
+    def work():
+        while not stop.is_set():
+            t.observe(1.0)
+
+    switch0 = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)  # make torn reads likely without locks
+    worker = threading.Thread(target=work, daemon=True)
+    worker.start()
+    try:
+        for _ in range(300):
+            snap = reg.snapshot()
+            assert snap["hammer.total_s"] == snap["hammer.count"]
+    finally:
+        stop.set()
+        worker.join(timeout=10)
+        sys.setswitchinterval(switch0)
+    assert t.count > 0
 
 
 def test_reduce_snapshots_multihost_semantics():
@@ -263,6 +351,65 @@ def test_health_monitor_emits_diverged_event(event_log):
     assert len(evs) == 1
     assert evs[0]["step"] == 7
     assert evs[0]["data"]["fields"] == ["dfdt"]
+
+
+def test_subscriber_sees_records_and_cannot_break_emit(tmp_path, capsys):
+    """``EventLog.subscribe`` is how the benchmark and ``chip_smoke.py``
+    read a run's plan events: a subscriber gets every record after the
+    write, with or without a file; one that raises is reported once on
+    stderr and the emit path goes on."""
+    seen = []
+
+    def bad(rec):
+        raise RuntimeError("boom")
+
+    path = tmp_path / "ev.jsonl"
+    with events.EventLog(str(path)) as log:
+        log.subscribe(bad)
+        tap = log.subscribe(seen.append)
+        log.subscribe(seen.append)  # again: still delivered once
+        assert log.emit("unit_test", value=1)["kind"] == "unit_test"
+        log.emit("unit_test", value=2)
+        log.unsubscribe(tap)
+        log.emit("unit_test", value=3)
+    assert [r["data"]["value"] for r in seen] == [1, 2]
+    assert [r["data"]["value"]
+            for r in events.read_events(str(path))] == [1, 2, 3]
+    assert capsys.readouterr().err.count("event subscriber") == 1
+    # a file-less sink still feeds its taps
+    sink = events.EventLog(None)
+    sink.subscribe(seen.append)
+    assert sink.emit("unit_test", value=4)["data"] == {"value": 4}
+    assert seen[-1]["data"]["value"] == 4
+
+
+def test_subscriber_that_emits_does_not_recurse(tmp_path, capsys):
+    """An emit made from a subscriber is written and not pushed again:
+    the per-thread guard in ``EventLog._notify``."""
+    path = tmp_path / "ev.jsonl"
+    seen = []
+    with EventLog(str(path)) as log:
+        def echo(rec):
+            seen.append(rec["kind"])
+            log.emit("echo", of=rec["kind"])
+
+        log.subscribe(echo)
+        log.emit("unit_test", value=1)
+        log.emit("unit_test", value=2)
+    assert seen == ["unit_test", "unit_test"]
+    assert [r["kind"] for r in events.read_events(str(path))] == [
+        "unit_test", "echo", "unit_test", "echo"]
+    assert "event subscriber" not in capsys.readouterr().err
+
+
+def test_step_timer_takes_no_monitor():
+    """The step timer times steps: it feeds the registry's ``step``
+    timer and the event log, and no detector (PR 45: the ``perf=`` and
+    ``signature=`` arguments went with ``obs/perf.py``)."""
+    with pytest.raises(TypeError):
+        ps.StepTimer(perf=False)
+    with pytest.raises(TypeError):
+        ps.StepTimer(signature="step")
 
 
 def test_step_timer_feeds_metrics_and_events(event_log):

@@ -310,14 +310,14 @@ def test_scheduler_flags_fingerprint():
     env = {"LIBTPU_INIT_ARGS":
            "--xla_tpu_enable_latency_hiding_scheduler=true "
            "--xla_enable_async_all_gather=true"}
-    fp = overlap_mod.flags_fingerprint(env)
+    from pystella_tpu.obs import memory
+    fp = memory.flags_fingerprint(env)
     assert fp.get("xla_tpu_enable_latency_hiding_scheduler") == "true"
     assert fp.get("xla_enable_async_all_gather") == "true"
-    # the ledger's stdlib twin parses the same environment shape
-    from pystella_tpu.obs import ledger
+    # and the report's environment fingerprint reads the process's own
     os.environ["LIBTPU_INIT_ARGS"] = env["LIBTPU_INIT_ARGS"]
     try:
-        led_fp = ledger.xla_flag_fingerprint()
+        led_fp = memory.environment_fingerprint()["xla_flags"]
     finally:
         del os.environ["LIBTPU_INIT_ARGS"]
     assert led_fp.get("xla_tpu_enable_latency_hiding_scheduler") == "true"
