@@ -120,7 +120,8 @@ parser.add_argument("--fft-scheme", type=str, default=None,
                          "(fourier.pencil), default follows "
                          "PYSTELLA_FFT_SCHEME ('auto' keeps the "
                          "DFT tiering). The derivative/initialization "
-                         "transform is unaffected")
+                         "transform is unaffected (with --halo-shape 0 "
+                         "on a mesh it is make_dft's own choice)")
 parser.add_argument("--checkpoint-dir", type=str, default=None,
                     help="enable checkpoint/resume under this directory")
 parser.add_argument("--checkpoint-interval", type=int, default=100,
@@ -194,8 +195,17 @@ def main(argv=None):
                                     devices=jax.devices()[:ndev])
     # the seeded fluctuations come back from their modes by matrix
     # products: XLA's inverse real transform is wrong on the TPU
-    fft = ps.DFT(decomp, grid_shape=p.grid_shape, dtype=p.dtype,
-                 real_inverse="matmul")
+    fft_kw = dict(grid_shape=p.grid_shape, dtype=p.dtype,
+                  real_inverse="matmul")
+    if p.halo_shape == 0 and ndev > 1:
+        # derivatives by transforms on a mesh: forty distributed
+        # transforms a step, so the planner picks the tier (PencilFFT's
+        # explicit all_to_all where the lattice allows it: at 512^3 a
+        # chip on (2,2,1) a step is 2.74 s where the declarative
+        # reshards of ps.DFT take 3.79: PERF.md section 6, PR 46)
+        fft = ps.make_dft(decomp, **fft_kw)
+    else:
+        fft = ps.DFT(decomp, **fft_kw)
     if p.halo_shape == 0:
         derivs = ps.SpectralCollocator(fft, lattice.dk)
     else:

@@ -15,7 +15,13 @@ side calls :meth:`SpectralCollocator.lap`), the ops lie under the scopes
 ``spectral_forward``, ``spectral_symbol`` and ``spectral_inverse``; the
 host spans ``spectral_lap_dispatch`` and ``spectral_grad_dispatch`` lie
 round the two calls a driver loop makes; one ``spectral_plan`` event a
-built collocator says which transform and which inverse it got.
+built collocator says which transform and which inverse it got, on
+which mesh, and what a transform pays between chips (the transposes a
+forward and an inverse transform make, and the bytes of one field's
+k-space block a chip, which each of them rearranges). On a mesh the
+transform's own ops lie, inside the collocator's scopes, under
+``fft_transpose`` (what goes between chips) and ``fft_stage`` (what
+stays on one), on either tier (``fourier/dft.py``, ``fourier/pencil.py``).
 """
 
 from __future__ import annotations
@@ -82,10 +88,14 @@ class SpectralCollocator:
         self._grad_lap = program(self._grad_lap_impl, "grad_lap")
         self._pd = program(self._pd_impl, "pd", static_argnums=1)
         self._div = program(self._div_impl, "div")
+        forward, backward, nbytes = fft.transpose_plan()
         _events.emit(
             "spectral_plan", scheme=fft.scheme, inverse=inverse,
             grid_shape=list(fft.grid_shape), dtype=str(fft.dtype),
-            fields_a_call="all")
+            fields_a_call="all",
+            proc_shape=[int(n) for n in self.decomp.proc_shape],
+            transposes_forward=forward, transposes_inverse=backward,
+            transpose_bytes=nbytes)
 
     # -- the three parts of every derivative, each under its scope ---------
 

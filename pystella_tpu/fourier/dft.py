@@ -438,80 +438,110 @@ class DFT:
             self.decomp.mesh,
             P(*((None,) * outer), names[0], yz or None, None))
 
-    def _dft_impl(self, fx):
+    # On a mesh every reshard lies under the scope ``fft_transpose`` and
+    # every local transform under ``fft_stage``, the names the shard_map
+    # tier (``fourier/pencil.py``) gives its own: a trace tells what goes
+    # between chips from what stays on one, on either tier. A one-device
+    # transform has no transpose and carries neither scope.
+
+    @staticmethod
+    def _transpose(x, sharding):
         from jax.sharding import reshard
+        with jax.named_scope("fft_transpose"):
+            return reshard(x, sharding)
+
+    @staticmethod
+    def _stage(fn, x, *args, **kwargs):
+        with jax.named_scope("fft_stage"):
+            return fn(x, *args, **kwargs)
+
+    def transpose_plan(self):
+        """``(forward, inverse, nbytes)``: the reshards between chips one
+        forward and one inverse transform make (``reshard`` calls on
+        this class, ``all_to_all`` calls on the shard_map tier; 0 on one
+        device), and the bytes of what each rearranges on a chip: one
+        field's k-space block (``1 / P`` of the half spectrum among
+        ``P`` devices; on the ``partial`` tier the larger of its two
+        stages', on ``replicate`` the whole), of which ``(P - 1) / P``
+        leave the chip in a transpose over ``P`` of them. What a
+        ``spectral_plan`` event says of the mesh."""
+        if self._nproc == 1:
+            return 0, 0, 0
+        px, py, _ = self.decomp.proc_shape
+        count, share = {"replicate": (2, 1), "partial": (3, min(px, py)),
+                        "pencil": (5 if self._z_sharded else 3,
+                                   self._nproc)}[self._scheme]
+        nbytes = (int(np.prod(self.shape(True)))
+                  * np.dtype(self.cdtype).itemsize)
+        return count, count, nbytes // share
+
+    def _dft_impl(self, fx):
         outer = fx.ndim - 3
         if self._nproc == 1:
             return (jnp.fft.rfftn if self.is_real else jnp.fft.fftn)(
                 fx, axes=(-3, -2, -1))
+        transpose, stage = self._transpose, self._stage
         phome, khome, x_shard, y_shard = self._specs(outer)
         if not self._pencil_ok:
-            xk = reshard(fx, self._replicated())
-            xk = (jnp.fft.rfftn if self.is_real else jnp.fft.fftn)(
-                xk, axes=(-3, -2, -1))
-            return reshard(xk, khome)
+            xk = transpose(fx, self._replicated())
+            xk = stage(jnp.fft.rfftn if self.is_real else jnp.fft.fftn,
+                       xk, axes=(-3, -2, -1))
+            return transpose(xk, khome)
         if self._z_sharded:
             # make z local first (staged: home -> mid -> pencils, each a
             # partitioner-friendly transition — see _mid_spec)
-            xk = reshard(fx, self._mid_spec(outer))
-            xk = (jnp.fft.rfft if self.is_real else jnp.fft.fft)(xk, axis=-1)
-            xk = reshard(xk, x_shard)
-        else:
-            xk = (jnp.fft.rfft if self.is_real else jnp.fft.fft)(fx, axis=-1)
-            xk = reshard(xk, x_shard)
-        xk = jnp.fft.fft(xk, axis=-2)
-        xk = reshard(xk, y_shard)
-        xk = jnp.fft.fft(xk, axis=-3)
+            fx = transpose(fx, self._mid_spec(outer))
+        xk = stage(jnp.fft.rfft if self.is_real else jnp.fft.fft, fx,
+                   axis=-1)
+        xk = transpose(xk, x_shard)
+        xk = stage(jnp.fft.fft, xk, axis=-2)
+        xk = transpose(xk, y_shard)
+        xk = stage(jnp.fft.fft, xk, axis=-3)
         if self._z_sharded:
-            xk = reshard(xk, self._mid_spec(outer))
-        return reshard(xk, khome)
+            xk = transpose(xk, self._mid_spec(outer))
+        return transpose(xk, khome)
 
     def _idft_impl(self, fk):
-        from jax.sharding import reshard
         outer = fk.ndim - 3
+        nz = self.grid_shape[-1]
         if self._nproc == 1:
             if self._matmul_inverse:
                 return irfftn3(fk, self.grid_shape)
             if self.is_real:
                 return jnp.fft.irfftn(fk, s=self.grid_shape, axes=(-3, -2, -1))
             return jnp.fft.ifftn(fk, axes=(-3, -2, -1))
+        transpose, stage = self._transpose, self._stage
         phome, khome, x_shard, y_shard = self._specs(outer)
         if not self._pencil_ok:
-            xk = reshard(fk, self._replicated())
+            xk = transpose(fk, self._replicated())
             if self._matmul_inverse:
-                xk = irfftn3(xk, self.grid_shape)
+                xk = stage(irfftn3, xk, self.grid_shape)
             elif self.is_real:
-                xk = jnp.fft.irfftn(xk, s=self.grid_shape, axes=(-3, -2, -1))
+                xk = stage(jnp.fft.irfftn, xk, s=self.grid_shape,
+                           axes=(-3, -2, -1))
             else:
-                xk = jnp.fft.ifftn(xk, axes=(-3, -2, -1))
-            return reshard(xk, phome)
-        if self._z_sharded:
-            xk = reshard(fk, self._mid_spec(outer))
-            xk = reshard(xk, y_shard)
+                xk = stage(jnp.fft.ifftn, xk, axes=(-3, -2, -1))
+            return transpose(xk, phome)
+        if self._matmul_inverse:
+            ifft, ifft_z = ifft_matmul, (lambda b: irfft_matmul(b, nz))
         else:
-            xk = reshard(fk, y_shard)
-        ifft = ifft_matmul if self._matmul_inverse else (
-            lambda b, axis: jnp.fft.ifft(b, axis=axis))
-        xk = ifft(xk, -3)
-        xk = reshard(xk, x_shard)
-        xk = ifft(xk, -2)
+            ifft = (lambda b, axis: jnp.fft.ifft(b, axis=axis))
+            ifft_z = ((lambda b: jnp.fft.irfft(b, n=nz, axis=-1))
+                      if self.is_real else (lambda b: ifft(b, -1)))
+        if self._z_sharded:
+            fk = transpose(fk, self._mid_spec(outer))
+        xk = transpose(fk, y_shard)
+        xk = stage(ifft, xk, -3)
+        xk = transpose(xk, x_shard)
+        xk = stage(ifft, xk, -2)
         if self._z_sharded:
             # finish the z transform while z is still local, then go home
             # (staged again: pencil -> mid -> home)
-            if self._matmul_inverse:
-                xk = irfft_matmul(xk, self.grid_shape[-1])
-            elif self.is_real:
-                xk = jnp.fft.irfft(xk, n=self.grid_shape[-1], axis=-1)
-            else:
-                xk = jnp.fft.ifft(xk, axis=-1)
-            xk = reshard(xk, self._mid_spec(outer))
-            return reshard(xk, phome)
-        xk = reshard(xk, khome)
-        if self._matmul_inverse:
-            return irfft_matmul(xk, self.grid_shape[-1])
-        if self.is_real:
-            return jnp.fft.irfft(xk, n=self.grid_shape[-1], axis=-1)
-        return jnp.fft.ifft(xk, axis=-1)
+            xk = stage(ifft_z, xk)
+            xk = transpose(xk, self._mid_spec(outer))
+            return transpose(xk, phome)
+        xk = transpose(xk, khome)
+        return stage(ifft_z, xk)
 
     def k_sharding(self, outer_axes=0):
         """``NamedSharding`` of k-space arrays: x/y as the decomposition,

@@ -242,6 +242,17 @@ class PencilFFT(DFT):
             return blocks[0]
         return jnp.stack(blocks).reshape(oshape + blocks[0].shape)
 
+    def transpose_plan(self):
+        """:meth:`DFT.transpose_plan` of this tier: an ``all_to_all`` a
+        mesh axis the transform crosses and one over all of them, the
+        inverse its mirror; a field's k-space block is ``1 / P`` of the
+        half spectrum at every stage."""
+        forward = sum(t is not None for t, _ in self._forward_stages())
+        inverse = sum(t is not None for _, t in self._inverse_stages())
+        nbytes = (int(np.prod(self.shape(True)))
+                  * np.dtype(self.cdtype).itemsize // self._nproc)
+        return forward, inverse, nbytes if forward else 0
+
     def _forward_body(self, x):
         blocks, oshape = self._split_fields(x)
         for transpose, fft_fn in self._forward_stages():

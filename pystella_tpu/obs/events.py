@@ -127,8 +127,13 @@ for _name, _help in (
                          "the contractions' precision and flop count"),
     ("spectral_plan", "a SpectralCollocator was built: the transform's "
                       "scheme, how a real field comes back ('xla' or "
-                      "'matmul'), grid, dtype, and how many fields "
-                      "of a call go through one transform ('all')"),
+                      "'matmul'), grid, dtype, how many fields "
+                      "of a call go through one transform ('all'), "
+                      "and what the mesh costs it: proc_shape, the "
+                      "transposes between chips a forward and an "
+                      "inverse transform make, and the bytes of one "
+                      "field's k-space block a chip, which each "
+                      "rearranges (0 on one device)"),
     ("overlap_plan", "a sharded stencil kernel was built: which launch "
                      "it takes on the mesh, path 'split' (the "
                      "interior/shell halo-overlap split: the two "

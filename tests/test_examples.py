@@ -172,6 +172,44 @@ def test_scalar_preheating_spectral_derivs(tmp_path):
     assert float(line.split()[-1]) < 1e-4
 
 
+#: the transform a ``--halo-shape 0`` run builds on a mesh: ``PencilFFT``
+#: through ``make_dft`` (PR 46: the faster of the two tiers on the chip at
+#: 512^3 a chip, a step of 2.74 s against 3.79; ``PERF.md`` section 6)
+MESH_SCHEME = "pencil-a2a"
+
+
+def test_scalar_preheating_spectral_derivs_on_a_mesh(tmp_path):
+    """``--halo-shape 0 -proc 2 2 1 --dtype float32``: the example
+    through its normal path on four devices, every derivative a
+    distributed transform; its ``spectral_plan`` event names the mesh
+    and the transform tier the collocator got, and what a transform
+    moves between chips; a one-device run says the scheme and inverse
+    it always said."""
+    import json
+    for proc, scheme, transposes in (((2, 2, 1), MESH_SCHEME, True),
+                                     ((1, 1, 1), "pencil", False)):
+        log = tmp_path / f"events{proc[0]}.jsonl"
+        stdout = run_example(
+            "scalar_preheating.py", "-grid", "32", "32", "16", "-end-t",
+            "0.1", "--halo-shape", "0", "--dtype", "float32", "-proc",
+            *map(str, proc), "-box", "0.3125", "0.3125", "0.15625",
+            "--event-log", str(log), "--outfile", str(tmp_path / "spec"))
+        assert "Simulation complete" in stdout
+        line = [ln for ln in stdout.splitlines()
+                if "final constraint" in ln][-1]
+        assert float(line.split()[-1]) < 1e-4
+        with open(log) as fh:
+            plans = [rec["data"] for rec in map(json.loads, fh)
+                     if rec["kind"] == "spectral_plan"]
+        assert len(plans) == 1, plans
+        (plan,) = plans
+        assert plan["scheme"] == scheme and plan["inverse"] == "matmul"
+        assert plan["proc_shape"] == list(proc)
+        assert plan["grid_shape"] == [32, 32, 16]
+        assert (plan["transposes_forward"] > 0) is transposes
+        assert (plan["transpose_bytes"] > 0) is transposes
+
+
 def test_scalar_preheating_checkpoint_resume(tmp_path):
     """Two sequential runs sharing a checkpoint directory: the second must
     resume from the first's final checkpoint (orbax restore path) and

@@ -41,6 +41,60 @@ def test_plane_wave_derivatives(setup, grid_shape, proc_shape):
     assert np.allclose(np.asarray(l2), lap, atol=1e-12)
 
 
+@pytest.mark.parametrize("real_inverse", ["xla", "matmul"])
+@pytest.mark.parametrize("proc_shape", [(2, 2, 1)], indirect=True)
+def test_pencil_tier_against_the_declarative_one(setup, grid_shape,
+                                                 proc_shape, real_inverse,
+                                                 tmp_path):
+    """The collocator over ``PencilFFT`` (what ``make_dft`` hands a mesh:
+    explicit ``all_to_all``, k space in its own layout) against the one
+    over ``DFT`` (reshards the partitioner lowers) on ``(2, 2, 1)``:
+    plane-wave derivatives to round-off on both inverses, the same
+    answers from both, and a ``spectral_plan`` that tells them apart."""
+    from pystella_tpu import obs
+    from pystella_tpu.obs.events import read_events
+    decomp, lattice, _ = setup
+    kw = dict(grid_shape=grid_shape, dtype=np.float64,
+              real_inverse=real_inverse)
+    log = tmp_path / "events.jsonl"
+    obs.configure(str(log))
+    try:
+        made = ps.make_dft(decomp, **kw)
+        pencil = ps.SpectralCollocator(made, lattice.dk)
+        declared = ps.SpectralCollocator(ps.DFT(decomp, **kw), lattice.dk)
+    finally:
+        obs.configure(None)
+    assert made.is_pencil
+    plans = [e["data"] for e in read_events(str(log), kind="spectral_plan")]
+    assert [d["scheme"] for d in plans] == ["pencil-a2a", "pencil"]
+    assert [(d["transposes_forward"], d["transposes_inverse"])
+            for d in plans] == [(2, 2), (3, 3)]
+    half = int(np.prod(grid_shape[:2])) * (grid_shape[2] // 2 + 1)
+    for d in plans:
+        assert d["proc_shape"] == [2, 2, 1]
+        assert d["inverse"] == real_inverse
+        assert d["transpose_bytes"] == half * 16 // 4
+
+    xs = [np.arange(n) * d for n, d in zip(grid_shape, lattice.dx)]
+    X, Y, Z = np.meshgrid(*xs, indexing="ij")
+    kx, ky, kz = 3 * lattice.dk[0], 1 * lattice.dk[1], 2 * lattice.dk[2]
+    phase = kx * X + ky * Y + kz * Z
+    f = np.stack([np.sin(phase), 0.3 + np.cos(phase)])
+    arr = decomp.shard(f)
+    ksq = kx**2 + ky**2 + kz**2
+    for sc in (pencil, declared):
+        lap = np.asarray(sc.lap(arr))
+        assert np.abs(lap[0] + ksq * f[0]).max() < 1e-9
+        assert np.abs(lap[1] + ksq * (f[1] - 0.3)).max() < 1e-9
+        grd = np.asarray(sc.grad(arr))
+        for d, k in enumerate((kx, ky, kz)):
+            assert np.abs(grd[0, d] - k * np.cos(phase)).max() < 1e-10
+    assert np.allclose(np.asarray(pencil.lap(arr)),
+                       np.asarray(declared.lap(arr)), atol=1e-10)
+    assert np.allclose(np.asarray(pencil.grad(arr)),
+                       np.asarray(declared.grad(arr)), atol=1e-11)
+
+
 @pytest.mark.parametrize("proc_shape", [(2, 2, 1)], indirect=True)
 def test_divergence_and_pd(setup, grid_shape, proc_shape):
     decomp, lattice, fft = setup
@@ -107,6 +161,11 @@ def test_programs_scopes_spans_and_plan(setup, grid_shape, tmp_path):
     assert event["data"]["scheme"] == fft.scheme
     assert event["data"]["grid_shape"] == list(grid_shape)
     assert event["data"]["dtype"] == "float64"
+    # one device: no mesh to cross
+    assert event["data"]["proc_shape"] == [1, 1, 1]
+    assert (event["data"]["transposes_forward"],
+            event["data"]["transposes_inverse"],
+            event["data"]["transpose_bytes"]) == (0, 0, 0)
 
     x = jax.ShapeDtypeStruct(grid_shape, np.float64)
     vec = jax.ShapeDtypeStruct((3,) + grid_shape, np.float64)
@@ -120,6 +179,8 @@ def test_programs_scopes_spans_and_plan(setup, grid_shape, tmp_path):
             assert has_scope(lowered, scope), (name, scope)
     inlined = jax.jit(lambda f: 2 * sc.lap(f)).lower(x)
     assert has_scope(inlined, "spectral_inverse")
+    # a one-device transform has no transpose and says so by silence
+    assert not has_scope(inlined, "fft_transpose")
 
     arr = decomp.shard(np.ones(grid_shape))
     with obs.recording() as rows:

@@ -864,20 +864,30 @@ SPECTRAL_QUOTED = {"stage": (4294968832, 4294967808, 1074419200),
                    "lap": (1073741824, 1073741824, 3775612416)}
 
 
-def _spectral(devices, grid, monkeypatch):
-    """The example's ``--halo-shape 0`` branch on one described chip:
-    transform with the inverse by matrix products, collocator, generic
+def _spectral(devices, grid, monkeypatch, proc_shape=(1, 1, 1),
+              tier="dft"):
+    """The example's ``--halo-shape 0`` branch on described chips (one,
+    or a mesh of ``proc_shape``): transform with the inverse by matrix
+    products (``tier``: ``ps.DFT``, or ``PencilFFT`` as ``make_dft``
+    picks it for a mesh), collocator, generic
     ``LowStorageRK54(full_rhs)``, abstract state."""
+    from pystella_tpu.fourier.pencil import PencilFFT
+
     # a transform places its momenta with device_put, which a described
     # device refuses: here they become constants of the programs
-    monkeypatch.setattr(
-        ps.DomainDecomposition, "axis_array",
-        lambda self, mu, values, sharded=True: np.asarray(values).reshape(
-            [-1 if i == mu else 1 for i in range(3)]))
-    decomp = ps.DomainDecomposition((1, 1, 1), devices=devices[:1])
-    lattice = ps.Lattice(grid, (5.0,) * 3, dtype=np.float32)
-    fft = ps.DFT(decomp, grid_shape=grid, dtype=np.float32,
-                 real_inverse="matmul")
+    def constant(self, mu, values, sharded=True):
+        return np.asarray(values).reshape(
+            [-1 if i == mu else 1 for i in range(3)])
+
+    monkeypatch.setattr(ps.DomainDecomposition, "axis_array", constant)
+    monkeypatch.setattr(PencilFFT, "k_axis_array", constant)
+    ndev = int(np.prod(proc_shape))
+    decomp = ps.DomainDecomposition(proc_shape, devices=devices[:ndev])
+    lattice = ps.Lattice(grid, tuple(5.0 * n / 512 for n in grid)
+                         if ndev > 1 else (5.0,) * 3, dtype=np.float32)
+    kw = dict(grid_shape=grid, dtype=np.float32, real_inverse="matmul")
+    fft = (ps.make_dft(decomp, scheme="pencil", **kw) if tier == "pencil"
+           else ps.DFT(decomp, **kw))
     derivs = ps.SpectralCollocator(fft, lattice.dk)
     mphi, gsq = 1.20e-6, 2.5e-7
 
@@ -896,6 +906,24 @@ def _spectral(devices, grid, monkeypatch):
     return decomp, fft, derivs, stepper, x
 
 
+def _spectral_programs(derivs, stepper, x):
+    """``{kind: (jitted function, arguments, HLO module name)}`` of the
+    stage program (stages 1-4, undonated) and ``spectral_lap``."""
+    state = {"f": x, "dfdt": x}
+    args = {"a": np.float64(1.0), "hubble": np.float64(0.1)}
+    return {
+        "stage": (stepper._jit_stage, (1, (state, state), 0.0, stepper.dt,
+                                       args), "jit_LowStorageRK54_stage"),
+        "lap": (derivs._lap, (x,), "jit_spectral_lap")}
+
+
+def _largest_constant(hlo):
+    import re
+    return max(
+        int(np.prod([int(d) for d in dims.split(",")]))
+        for dims in re.findall(r"= \w+\[([\d,]+)\]\S* constant\(", hlo))
+
+
 @pytest.mark.parametrize("grid", SPECTRAL_GRIDS)
 def test_spectral_stage_and_lap_compile(v5e, monkeypatch, grid):
     """What ``benchmark/configs/preheat-spectral-f32.json`` needs of a
@@ -908,15 +936,9 @@ def test_spectral_stage_and_lap_compile(v5e, monkeypatch, grid):
     collocator's three scopes, and holds no constant of the lattice's
     size (``kx^2 + ky^2 + kz^2`` folded into one cost 269 MB a program
     and minutes of compile time at 512**3: PR 34)."""
-    import re
     _, _, derivs, stepper, x = _spectral(v5e, grid, monkeypatch)
-    state = {"f": x, "dfdt": x}
-    args = {"a": np.float64(1.0), "hubble": np.float64(0.1)}
-    programs = {
-        "stage": (stepper._jit_stage, (1, (state, state), 0.0, stepper.dt,
-                                       args), "jit_LowStorageRK54_stage"),
-        "lap": (derivs._lap, (x,), "jit_spectral_lap")}
-    for kind, (fn, fn_args, name) in programs.items():
+    for kind, (fn, fn_args, name) in _spectral_programs(
+            derivs, stepper, x).items():
         compiled = fn.trace(*fn_args).lower(
             lowering_platforms=("tpu",)).compile()
         hlo = compiled.as_text()
@@ -924,10 +946,8 @@ def test_spectral_stage_and_lap_compile(v5e, monkeypatch, grid):
         for scope in ("spectral_forward", "spectral_symbol",
                       "spectral_inverse"):
             assert scope in hlo, (kind, scope)
-        largest = max(
-            int(np.prod([int(d) for d in dims.split(",")]))
-            for dims in re.findall(r"= \w+\[([\d,]+)\]\S* constant\(", hlo))
-        assert largest <= 512 * 512, (kind, largest)
+        assert "fft_transpose" not in hlo, kind     # one chip: none
+        assert _largest_constant(hlo) <= 512 * 512, kind
         mem = compiled.memory_analysis()
         held = (mem.argument_size_in_bytes, mem.output_size_in_bytes,
                 mem.temp_size_in_bytes)
@@ -935,6 +955,74 @@ def test_spectral_stage_and_lap_compile(v5e, monkeypatch, grid):
         if grid == (512, 512, 512):
             for got, quoted in zip(held, SPECTRAL_QUOTED[kind]):
                 assert abs(got - quoted) <= 0.02 * quoted, (kind, held)
+
+
+#: what ``benchmark/configs/preheat-spectral-mesh4-f32.json`` quotes under
+#: ``assumed`` for (1024, 1024, 512) on ``(2, 2, 1)``, bytes a chip:
+#: (arguments, outputs, temporaries) of a stage program (stages 1-4,
+#: undonated) and of ``spectral_lap``, on either transform tier, and the
+#: ``all-to-all`` / ``collective-permute`` instructions of each
+SPECTRAL_MESH_QUOTED = {
+    "dft": {"stage": (4294968832, 4294967808, 1617936384),
+            "lap": (1073741824, 1073741824, 4298089472),
+            "collectives": (12, 4)},
+    "pencil": {"stage": (4294968832, 4294967808, 3233752576),
+               "lap": (1073741824, 1073741824, 3222322176),
+               "collectives": (16, 0)}}
+#: the cell's lattice (``slow``, 45 s a tier) and a sixty-fourth of it
+#: (30-50 s a tier: the same programs, scopes, mesh), in tier-1 on the
+#: tier the example's mesh run takes
+SPECTRAL_MESH_CASES = [
+    ((256, 256, 128), "pencil"),
+    pytest.param((256, 256, 128), "dft", marks=pytest.mark.slow),
+    pytest.param((1024, 1024, 512), "pencil", marks=pytest.mark.slow),
+    pytest.param((1024, 1024, 512), "dft", marks=pytest.mark.slow)]
+
+
+@pytest.mark.parametrize("grid,tier", SPECTRAL_MESH_CASES)
+def test_spectral_mesh_stage_and_lap_compile(v5e, monkeypatch, grid, tier):
+    """What ``benchmark/configs/preheat-spectral-mesh4-f32.json`` needs
+    of a four-chip host: on the ``(2, 2, 1)`` mesh the undonated stage
+    program and ``spectral_lap`` compile for the v5e and fit a chip, on
+    the declarative tier (``ps.DFT``) and on ``PencilFFT``; each is
+    named, carries the collocator's three scopes and, round what goes
+    between chips and what stays on one, ``fft_transpose`` and
+    ``fft_stage``; no transform gathers (no ``all-gather``: every stage
+    holds a quarter of a field) and none holds a constant of the
+    lattice's size; at the cell's lattice with the bytes and the
+    collectives the configuration quotes."""
+    import re
+    _, fft, derivs, stepper, x = _spectral(v5e, grid, monkeypatch,
+                                           proc_shape=(2, 2, 1), tier=tier)
+    assert fft.scheme == {"dft": "pencil", "pencil": "pencil-a2a"}[tier]
+    assert fft.transpose_plan() == (
+        (3, 3) if tier == "dft" else (2, 2)) + (
+        grid[0] * grid[1] * (grid[2] // 2 + 1) * 8 // 4,)
+    quoted = SPECTRAL_MESH_QUOTED[tier]
+    for kind, (fn, fn_args, name) in _spectral_programs(
+            derivs, stepper, x).items():
+        compiled = fn.trace(*fn_args).lower(
+            lowering_platforms=("tpu",)).compile()
+        hlo = compiled.as_text()
+        assert hlo.startswith("HloModule " + name), hlo[:60]
+        for scope in ("spectral_forward", "spectral_symbol",
+                      "spectral_inverse", "fft_transpose", "fft_stage"):
+            assert scope in hlo, (kind, scope)
+        assert "all-gather" not in hlo, kind
+        # the inverse's cos / sin matrices of an axis are the largest
+        assert _largest_constant(hlo) <= max(grid) ** 2, kind
+        collectives = tuple(
+            len(re.findall(rf" {op}(?:-start)?\(", hlo))
+            for op in ("all-to-all", "collective-permute"))
+        assert collectives[0] > 0, kind
+        mem = compiled.memory_analysis()
+        held = (mem.argument_size_in_bytes, mem.output_size_in_bytes,
+                mem.temp_size_in_bytes)
+        assert sum(held) < 15.75 * 2**30, (kind, held)
+        if grid == (1024, 1024, 512):
+            assert collectives == quoted["collectives"], (kind, collectives)
+            for got, want in zip(held, quoted[kind]):
+                assert abs(got - want) <= 0.02 * want, (kind, held)
 
 
 def test_spectral_collocator_refuses_xlas_inverse_for_a_tpu(v5e,
