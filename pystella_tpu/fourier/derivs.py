@@ -22,6 +22,33 @@ k-space block a chip, which each of them rearranges). On a mesh the
 transform's own ops lie, inside the collocator's scopes, under
 ``fft_transpose`` (what goes between chips) and ``fft_stage`` (what
 stays on one), on either tier (``fourier/dft.py``, ``fourier/pencil.py``).
+
+**The Laplacian it last returned** (PR 47). The reference-style loop
+takes ``derivs.lap(state["f"])`` for the energy and then runs a stage
+program whose right-hand side takes ``derivs.lap`` of that same array:
+ten two-field transform pairs a step where upstream, whose energy fills
+the ``lap_f`` the next stage reads, does five. So an eager
+:meth:`SpectralCollocator.lap` on a ``jax.Array`` remembers one pair, the
+array it was given (weakly) and the array it returned, in place of the
+pair before (:class:`pystella_tpu.handoff.LastLaplacian`). The pair is
+forgotten at the start of the collocator's next eager call of any kind,
+before that call allocates; when the array it was made from is
+collected; and the moment a stage dispatch is passed that array, which
+is what it is for: the generic stepper's per-stage dispatch
+(``step.py``), about to pass a state or carry of which a leaf **is**
+that array (the same object, not deleted), passes the Laplacian as one
+more argument if nobody else holds it any more, as nobody holds the
+loop's. It is donated: an output takes its buffer, and a ``weakref`` or
+a second array over that buffer finds it deleted. One that anybody
+holds by a name or in a container is not handed in, and survives. While
+the program that takes one is traced, :meth:`~SpectralCollocator.lap`,
+called by the right-hand side on the tracer of that very leaf, returns
+the argument's tracer and builds no transform. Any other call under
+trace (another array, a leaf the right-hand side touched first, a program
+with nothing handed in) builds the transforms as ever. Nothing switches
+this on or off: it depends on the identity of an array alone, and a hit
+is the value the program would have computed again. ``grad``,
+``grad_lap``, ``pd*`` and ``divergence`` remember nothing.
 """
 
 from __future__ import annotations
@@ -31,6 +58,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from pystella_tpu import handoff as _handoff
 from pystella_tpu.obs import events as _events
 from pystella_tpu.obs import memory as _obs_memory
 from pystella_tpu.obs.scope import host_span, trace_scope
@@ -88,6 +116,8 @@ class SpectralCollocator:
         self._grad_lap = program(self._grad_lap_impl, "grad_lap")
         self._pd = program(self._pd_impl, "pd", static_argnums=1)
         self._div = program(self._div_impl, "div")
+        #: the last Laplacian an eager call returned, and of which array
+        self._last_lap = _handoff.LastLaplacian("SpectralCollocator.lap")
         forward, backward, nbytes = fft.transpose_plan()
         _events.emit(
             "spectral_plan", scheme=fft.scheme, inverse=inverse,
@@ -159,27 +189,49 @@ class SpectralCollocator:
     # (reshard targets carry their mesh, so no ambient context is needed
     # whether called eagerly or inside a caller's jit)
 
+    def _eager(self, f):
+        """Whether this call runs a program of its own (``f`` is no
+        tracer of a caller's). One that does starts by forgetting the
+        remembered Laplacian, before it allocates."""
+        if isinstance(f, jax.core.Tracer):
+            return False
+        self._last_lap.forget()
+        return True
+
     def lap(self, f):
         with host_span("spectral_lap_dispatch"):
-            return self._lap(f)
+            if not self._eager(f):
+                handed = self._last_lap.offered(f)
+                return self._lap(f) if handed is None else handed
+            out = self._lap(f)
+            if isinstance(f, jax.Array):
+                self._last_lap.remember(f, out)
+            return out
 
     def grad(self, f):
+        self._eager(f)
         with host_span("spectral_grad_dispatch"):
             return self._grad(f)
 
     def grad_lap(self, f):
+        self._eager(f)
         return self._grad_lap(f)
 
+    def _pd_of(self, f, mu):
+        self._eager(f)
+        return self._pd(f, mu)
+
     def pdx(self, f):
-        return self._pd(f, 0)
+        return self._pd_of(f, 0)
 
     def pdy(self, f):
-        return self._pd(f, 1)
+        return self._pd_of(f, 1)
 
     def pdz(self, f):
-        return self._pd(f, 2)
+        return self._pd_of(f, 2)
 
     def divergence(self, vec):
+        self._eager(vec)
         return self._div(vec)
 
     def __call__(self, fx, *, lap=False, grd=False, div=False):

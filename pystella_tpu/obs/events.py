@@ -134,6 +134,16 @@ for _name, _help in (
                       "inverse transform make, and the bytes of one "
                       "field's k-space block a chip, which each "
                       "rearranges (0 on one device)"),
+    ("laplacian_handed_in", "a generic stepper's per-stage dispatch "
+                            "first passed its stage program a Laplacian "
+                            "the right-hand side's collocator had just "
+                            "returned for a leaf of the carry and nobody "
+                            "held any more, in place of the transform "
+                            "pair inside; the program consumes it "
+                            "(stepper, stage, producer, leaf: its key "
+                            "path, shape, dtype); one a stepper, the "
+                            "counters stage_laplacians_handed_in of "
+                            "stage_dispatches say how often"),
     ("overlap_plan", "a sharded stencil kernel was built: which launch "
                      "it takes on the mesh, path 'split' (the "
                      "interior/shell halo-overlap split: the two "

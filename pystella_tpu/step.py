@@ -17,13 +17,18 @@ The right-hand side is a plain function ``rhs(state, t, **args) -> dstate``
 
 from __future__ import annotations
 
+import functools
+
 import numpy as np
 
 import jax
 import jax.numpy as jnp
 
 from pystella_tpu import field as _field
+from pystella_tpu import handoff as _handoff
+from pystella_tpu.obs import events as _events
 from pystella_tpu.obs import memory as _obs_memory
+from pystella_tpu.obs import metrics as _metrics
 from pystella_tpu.obs.scope import host_span, trace_scope
 
 __all__ = [
@@ -158,22 +163,67 @@ class Stepper:
         if not hasattr(self, "_jit_stage"):
             donate = getattr(self, "_donate", False)
             cls = type(self).__name__
+
+            # ``handed``: the Laplacian the right-hand side's collocator
+            # has just returned for a leaf of the carry, which nobody
+            # else holds any more (:meth:`_dispatch_stage`): the program
+            # may write over it (it is as large as an output, and nobody
+            # is left to look), so it is donated whatever ``donate`` says
+            # of the carry. Without it the programs are the ones five
+            # arguments have always given.
+            def stage(s, carry, t, dt, rhs_args, handed=None):
+                with _handoff.offer(handed, carry):
+                    return self.stage(s, carry, t, dt, rhs_args)
+
+            def stage0(state, t, dt, rhs_args, handed=None):
+                with _handoff.offer(handed, state):
+                    return self.stage(0, self.init_carry(state), t, dt,
+                                      rhs_args)
+
             self._jit_stage = _obs_memory.instrument_jit(
-                self.stage, label=f"step.{cls}.stage", static_argnums=0,
-                donate_argnums=(1,) if donate else ())
+                stage, label=f"step.{cls}.stage", static_argnums=0,
+                donate_argnums=(1, 5) if donate else (5,))
             self._jit_stage0 = _obs_memory.instrument_jit(
-                lambda state, t, dt, rhs_args:
-                    self.stage(0, self.init_carry(state), t, dt, rhs_args),
-                label=f"step.{cls}.stage0",
-                donate_argnums=(0,) if donate else ())
+                stage0, label=f"step.{cls}.stage0",
+                donate_argnums=(0, 4) if donate else (4,))
 
     def _dispatch_stage(self, stage, state_or_carry, t, dt, rhs_args):
         """One dispatch of stage ``stage``'s cached program: the state
-        in at stage 0, the carry in after it, the carry out."""
+        in at stage 0, the carry in after it, the carry out.
+
+        Where a leaf of what goes in **is** the array whose Laplacian a
+        collocator has just returned to the loop's energy, and nobody
+        holds that Laplacian any more (:mod:`pystella_tpu.handoff`: the
+        loop's was a local of its energy function), it goes in as one
+        more argument and the right-hand side's ``lap`` of that leaf
+        is the argument, not a second transform pair: a second program
+        per stage index, keyed on which leaf and whose Laplacian. It is
+        donated, whatever ``donate`` says of the carry: nobody can see
+        it go, and the program then holds no array more than the one
+        that transforms for itself. A Laplacian somebody still holds is
+        not handed in: that dispatch is the program that transforms for
+        itself. ``stage_laplacians_handed_in`` of ``stage_dispatches``
+        count them; the first says which in a ``laplacian_handed_in``
+        event."""
         self._ensure_stage_jits()
-        if stage == 0:
-            return self._jit_stage0(state_or_carry, t, dt, rhs_args)
-        return self._jit_stage(stage, state_or_carry, t, dt, rhs_args)
+        _metrics.counter("stage_dispatches").inc()
+        program = (self._jit_stage0 if stage == 0
+                   else functools.partial(self._jit_stage, stage))
+        hit = _handoff.take(state_or_carry)
+        if hit is None:
+            return program(state_or_carry, t, dt, rhs_args)
+        handed, producer = hit
+        _metrics.counter("stage_laplacians_handed_in").inc()
+        if not getattr(self, "_handed_in_emitted", False):
+            self._handed_in_emitted = True
+            paths, _ = jax.tree_util.tree_flatten_with_path(state_or_carry)
+            _events.emit(
+                "laplacian_handed_in", stepper=type(self).__name__,
+                stage=stage, producer=producer,
+                leaf=jax.tree_util.keystr(paths[handed.leaf][0]),
+                shape=list(handed.value.shape),
+                dtype=str(handed.value.dtype))
+        return program(state_or_carry, t, dt, rhs_args, handed)
 
     # -- whole-step interface ---------------------------------------------
 
@@ -188,7 +238,6 @@ class Stepper:
             # steppers emit their own kernel_tier with the Pallas tier
             # actually dispatched; see ops/fused.py)
             self._tier_emitted_xla = True
-            from pystella_tpu.obs import events as _events
             _events.emit("kernel_tier", entrypoint="step", tier="xla",
                          label=type(self).__name__)
         with host_span("step_dispatch"):

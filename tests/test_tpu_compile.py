@@ -957,6 +957,55 @@ def test_spectral_stage_and_lap_compile(v5e, monkeypatch, grid):
                 assert abs(got - quoted) <= 0.02 * quoted, (kind, held)
 
 
+def _assert_handed_in_stage_has_no_transform(derivs, stepper, x, ndev=1):
+    """The stage program (stages 1-4) the generic stepper dispatches when
+    the loop's energy has just taken ``derivs.lap`` of the carry's ``f``
+    (``pystella_tpu/handoff.py``): the Laplacian is one more argument,
+    and what is left is the right-hand side and the RK update: the
+    module's name is the other variant's, no scope of the collocator, no
+    transform and nothing between chips; five lattice arrays in, and of
+    temporaries the right-hand side's components before their stack (one
+    array's bytes at most, which the other variant holds too: XLA makes
+    two fusions of a stage's arithmetic). The dispatch held the only
+    reference to the Laplacian, so the program may write over it, and
+    an output takes its place: the program then holds what the one that
+    takes its own Laplacian holds, and no array more."""
+    from pystella_tpu import handoff
+    state = {"f": x, "dfdt": x}
+    carry = (state, state)
+    leaf = next(i for i, (path, _) in enumerate(
+        jax.tree_util.tree_flatten_with_path(carry)[0])
+        if jax.tree_util.keystr(path) == "[0]['f']")
+    handed = handoff.HandedIn(x, leaf, derivs._last_lap.serial)
+    compiled = stepper._jit_stage.trace(
+        1, carry, 0.0, stepper.dt,
+        {"a": np.float64(1.0), "hubble": np.float64(0.1)}, handed).lower(
+            lowering_platforms=("tpu",)).compile()
+    hlo = compiled.as_text()
+    assert hlo.startswith("HloModule jit_LowStorageRK54_stage"), hlo[:60]
+    for absent in ("spectral_forward", "spectral_symbol",
+                   "spectral_inverse", "fft_transpose", "fft_stage",
+                   "convolution", "fft(", "all-to-all",
+                   "collective-permute", "all-gather"):
+        assert absent not in hlo, absent
+    mem = compiled.memory_analysis()
+    one = int(np.prod(x.shape)) * x.dtype.itemsize // ndev
+    assert mem.argument_size_in_bytes >= 5 * one
+    assert mem.argument_size_in_bytes <= 5 * one + 4096
+    assert mem.temp_size_in_bytes < 1.001 * one, mem.temp_size_in_bytes
+    assert mem.alias_size_in_bytes == one
+    assert derivs._last_lap.offered(x) is None       # nothing left on offer
+
+
+@pytest.mark.parametrize("grid", SPECTRAL_GRIDS)
+def test_spectral_stage_with_laplacian_handed_in_compile(v5e, monkeypatch,
+                                                         grid):
+    """Beside ``test_spectral_stage_and_lap_compile`` (nothing handed in:
+    unchanged): the one-chip cell's other stage program."""
+    _, _, derivs, stepper, x = _spectral(v5e, grid, monkeypatch)
+    _assert_handed_in_stage_has_no_transform(derivs, stepper, x)
+
+
 #: what ``benchmark/configs/preheat-spectral-mesh4-f32.json`` quotes under
 #: ``assumed`` for (1024, 1024, 512) on ``(2, 2, 1)``, bytes a chip:
 #: (arguments, outputs, temporaries) of a stage program (stages 1-4,
@@ -1023,6 +1072,19 @@ def test_spectral_mesh_stage_and_lap_compile(v5e, monkeypatch, grid, tier):
             assert collectives == quoted["collectives"], (kind, collectives)
             for got, want in zip(held, quoted[kind]):
                 assert abs(got - want) <= 0.02 * want, (kind, held)
+
+
+@pytest.mark.parametrize("grid", [
+    (256, 256, 128),
+    pytest.param((1024, 1024, 512), marks=pytest.mark.slow)])
+def test_spectral_mesh_stage_with_laplacian_handed_in_compile(
+        v5e, monkeypatch, grid):
+    """Beside ``test_spectral_mesh_stage_and_lap_compile``: the mesh
+    cell's other stage program, on ``PencilFFT`` (what the cell runs),
+    holds no ``all-to-all``: a chip's shard of the Laplacian comes in."""
+    _, _, derivs, stepper, x = _spectral(v5e, grid, monkeypatch,
+                                         proc_shape=(2, 2, 1), tier="pencil")
+    _assert_handed_in_stage_has_no_transform(derivs, stepper, x, ndev=4)
 
 
 def test_spectral_collocator_refuses_xlas_inverse_for_a_tpu(v5e,
