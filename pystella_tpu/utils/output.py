@@ -23,9 +23,32 @@ import sys
 
 import numpy as np
 
+from pystella_tpu.obs import metrics as _metrics
 from pystella_tpu.obs.scope import host_span
 
 __all__ = ["OutputFile", "ShardedSnapshot"]
+
+
+class _Rows:
+    """What :meth:`OutputFile.output` keeps of one dataset between two
+    rows: the open ``Dataset``, the shape of a row and, for the direct
+    append, the one-row memory dataspace and hyperslab."""
+
+    __slots__ = ("dset", "shape", "count", "zeros", "mspace")
+
+    def __init__(self, dset):
+        from h5py import h5s
+
+        self.dset = dset
+        # a dataset found in a reopened file that cannot grow by rows
+        # has no row shape: every value goes through h5py's statements
+        # and gets h5py's own exception
+        self.shape = (dset.shape[1:] if dset.shape and dset.chunks
+                      else None)
+        if self.shape is not None:
+            self.count = (1,) + self.shape
+            self.zeros = (0,) * len(self.shape)
+            self.mspace = h5s.create_simple(self.count)
 
 
 class OutputFile:
@@ -104,28 +127,63 @@ class OutputFile:
         for mod, ver in versions.items():
             self.file.attrs[f"{mod}_version"] = ver
 
+        # handles output() keeps: group name -> (Group, {key: _Rows})
+        self._groups = {}
+
     def output(self, group, **kwargs):
         """Append one record per keyword to (lazily-created) resizable
-        datasets under ``group`` (reference output.py:157-181)."""
-        with host_span("output_write"):
-            if group not in self.file:
-                grp = self.file.create_group(group)
-            else:
-                grp = self.file[group]
+        datasets under ``group`` (reference output.py:157-181).
 
+        The group and dataset handles are kept from the first row on, so
+        a later row costs no lookup: a value of the dataset's row shape
+        is appended by extent and one direct write of the row (HDF5
+        casts it to the dataset's dtype, as ``dset[-1] = arr`` has it
+        cast); a value of any other shape goes through h5py's own
+        ``resize`` and ``dset[-1] = arr`` and gets its broadcast or its
+        exception. The row is in the HDF5 library when this returns. The
+        number of rows is read from the dataset at every append, so a
+        resize through ``self.file`` in between is followed; a dataset
+        unlinked through ``self.file`` is not looked up again."""
+        with host_span("output_write"):
+            kept = self._groups.get(group)
+            if kept is None:
+                if group not in self.file:
+                    grp = self.file.create_group(group)
+                else:
+                    grp = self.file[group]
+                kept = self._groups[group] = (grp, {})
+            grp, rows_of = kept
+
+            _metrics.counter("output_appends").inc(len(kwargs))
+            generic = _metrics.counter("output_appends_generic")
             for key, val in kwargs.items():
                 # a device value waits here: the span holds that too
-                arr = np.asarray(val)
-                if key not in grp:
-                    grp.create_dataset(key, shape=(0,) + arr.shape,
-                                       maxshape=(None,) + arr.shape,
-                                       dtype=arr.dtype)
-                dset = grp[key]
-                dset.resize(dset.shape[0] + 1, axis=0)
-                dset[-1] = arr
+                arr = np.asarray(val, order="C")
+                rows = rows_of.get(key)
+                if rows is None:
+                    if key not in grp:
+                        dset = grp.create_dataset(
+                            key, shape=(0,) + arr.shape,
+                            maxshape=(None,) + arr.shape, dtype=arr.dtype)
+                    else:
+                        dset = grp[key]
+                    rows = rows_of[key] = _Rows(dset)
+                if arr.shape == rows.shape:
+                    dsid = rows.dset.id
+                    n = dsid.shape[0]
+                    dsid.set_extent((n + 1,) + rows.shape)
+                    fspace = dsid.get_space()
+                    fspace.select_hyperslab((n,) + rows.zeros, rows.count)
+                    dsid.write(rows.mspace, fspace, arr)
+                else:
+                    generic.inc()
+                    dset = rows.dset
+                    dset.resize(dset.shape[0] + 1, axis=0)
+                    dset[-1] = arr
 
     def close(self):
         if self.file:  # h5py File is falsy once closed; idempotent
+            self._groups.clear()
             self.file.close()
 
     def __enter__(self):
