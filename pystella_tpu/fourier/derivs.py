@@ -118,14 +118,9 @@ class SpectralCollocator:
         self._div = program(self._div_impl, "div")
         #: the last Laplacian an eager call returned, and of which array
         self._last_lap = _handoff.LastLaplacian("SpectralCollocator.lap")
-        forward, backward, nbytes = fft.transpose_plan()
-        _events.emit(
-            "spectral_plan", scheme=fft.scheme, inverse=inverse,
-            grid_shape=list(fft.grid_shape), dtype=str(fft.dtype),
-            fields_a_call="all",
-            proc_shape=[int(n) for n in self.decomp.proc_shape],
-            transposes_forward=forward, transposes_inverse=backward,
-            transpose_bytes=nbytes)
+        from pystella_tpu.fourier.plan import mesh_plan
+        _events.emit("spectral_plan", inverse=inverse, fields_a_call="all",
+                     **mesh_plan(fft))
 
     # -- the three parts of every derivative, each under its scope ---------
 

@@ -30,7 +30,8 @@ import numpy as np
 
 logger = logging.getLogger(__name__)
 
-__all__ = ["SCHEMES", "make_dft", "resolve_scheme", "ensure_spectral_fft"]
+__all__ = ["SCHEMES", "make_dft", "resolve_scheme", "ensure_spectral_fft",
+           "emit_spectra_plan"]
 
 #: accepted scheme names: "auto" plans; "pencil" forces the shard_map
 #: tier; everything else forces the DFT class (whose own divisibility
@@ -111,3 +112,33 @@ def ensure_spectral_fft(fft, scheme=None):
         return DFT(fft.decomp, grid_shape=fft.grid_shape,
                    dtype=fft.dtype)
     return fft
+
+
+def mesh_plan(fft):
+    """What a plan event says of a transform on its mesh: the scheme,
+    the lattice, ``proc_shape`` and what :meth:`DFT.transpose_plan`
+    counts (the reshards between chips a forward and an inverse
+    transform make, the bytes of one field's k-space block a chip)."""
+    forward, inverse, nbytes = fft.transpose_plan()
+    return dict(
+        scheme=fft.scheme, grid_shape=list(fft.grid_shape),
+        dtype=str(fft.dtype),
+        proc_shape=[int(n) for n in fft.decomp.proc_shape],
+        transposes_forward=forward, transposes_inverse=inverse,
+        transpose_bytes=nbytes)
+
+
+def emit_spectra_plan(consumer, fft):
+    """One ``spectra_plan`` event for a k-space consumer of the outputs
+    (:class:`~pystella_tpu.PowerSpectra`, :class:`~pystella_tpu.Projector`)
+    built on a mesh: which transform its outputs take (the class, how a
+    real field would come back) and :func:`mesh_plan`. A kind of its
+    own: ``spectral_plan`` is the collocator's, which stands in every
+    stage of a ``--halo-shape 0`` step. Nothing on one device."""
+    if fft._nproc == 1:
+        return
+    from pystella_tpu.obs import events as _events
+    _events.emit(
+        "spectra_plan", consumer=consumer, tier=type(fft).__name__,
+        real_inverse="matmul" if fft._matmul_inverse else "xla",
+        **mesh_plan(fft))

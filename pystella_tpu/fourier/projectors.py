@@ -44,8 +44,10 @@ class Projector:
     """
 
     def __init__(self, fft, effective_k, dk, dx, scheme=None):
-        from pystella_tpu.fourier.plan import ensure_spectral_fft
+        from pystella_tpu.fourier.plan import (
+            emit_spectra_plan, ensure_spectral_fft)
         fft = ensure_spectral_fft(fft, scheme)
+        emit_spectra_plan("Projector", fft)
         self.fft = fft
 
         if not callable(effective_k):

@@ -47,8 +47,10 @@ class PowerSpectra:
     """
 
     def __init__(self, decomp, fft, dk, volume, **kwargs):
-        from pystella_tpu.fourier.plan import ensure_spectral_fft
+        from pystella_tpu.fourier.plan import (
+            emit_spectra_plan, ensure_spectral_fft)
         fft = ensure_spectral_fft(fft, kwargs.pop("scheme", None))
+        emit_spectra_plan("PowerSpectra", fft)
         self.decomp = decomp
         self.fft = fft
         self.grid_shape = fft.grid_shape

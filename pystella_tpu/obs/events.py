@@ -134,6 +134,13 @@ for _name, _help in (
                       "inverse transform make, and the bytes of one "
                       "field's k-space block a chip, which each "
                       "rearranges (0 on one device)"),
+    ("spectra_plan", "a PowerSpectra or Projector (consumer) was built "
+                     "on a mesh: the transform its outputs take (tier: "
+                     "the class, scheme, real_inverse), grid, dtype, "
+                     "proc_shape, and the transposes between chips a "
+                     "forward and an inverse transform make with the "
+                     "bytes of one component's k-space block a chip, "
+                     "as spectral_plan counts them; none on one device"),
     ("laplacian_handed_in", "a generic stepper's per-stage dispatch "
                             "first passed its stage program a Laplacian "
                             "the right-hand side's collocator had just "
@@ -161,7 +168,9 @@ for _name, _help in (
                      "taps: shifted values a site and component's "
                      "derivatives take, 6h+1 a fused stage, "
                      "halo: each of (x, y) "
-                     "'wrap', on a sharded axis 'slab', or in the "
+                     "'wrap', on a sharded axis 'slab' (then also "
+                     "slab_bytes: what the ppermutes of its window "
+                     "components' faces move a call and chip), or in the "
                      "overlap split's kernels 'inset' (x, the "
                      "interior) and 'padded' (the shells), in_place: "
                      "the extras it writes over, reread: modelled "

@@ -254,7 +254,9 @@ class FusedScalarStepper(_step.Stepper):
         ``"wrap"``, (a sharded axis) ``"slab"`` or, in x, the split's
         ``"inset"`` (the interior: the ring over the raw shard, the
         first and last x-block only read) and ``"padded"`` (the
-        shells); ``in_place``
+        shells); a kernel with a ``"slab"`` edge also says
+        ``slab_bytes``, what its ``ppermute``s move a call and chip
+        (``StreamingStencil.slab_bytes``); ``in_place``
         names the extras it writes over (the per-stage protocol's
         ``stage`` kernel under ``donate=True``, else none); ``reread``
         is the modelled real-over-ideal byte ratio of a call at that
@@ -269,8 +271,11 @@ class FusedScalarStepper(_step.Stepper):
         stages = getattr(st, "stages", 1)
         whole = kind.removesuffix("_interior").removesuffix("_shell")
         fused = 2 if whole in ("pair", "coupled_pair") else stages
+        halo = list(getattr(st, "halo", ("wrap", "wrap")))
+        # what a slab-fed kernel's exchange moves between chips a call
+        slab = {"slab_bytes": st.slab_bytes} if "slab" in halo else {}
         _events.emit(
-            "block_choice", kernel=kind,
+            "block_choice", kernel=kind, **slab,
             stencil=type(st).__name__,
             bx=getattr(st, "bx", None), by=getattr(st, "by", None),
             grid=getattr(st, "grid", None),
@@ -278,7 +283,7 @@ class FusedScalarStepper(_step.Stepper):
             win_halo=getattr(st, "wh", None),
             h=self.h, taps=fused * (6 * self.h + 1),
             stages=stages,
-            halo=list(getattr(st, "halo", ("wrap", "wrap"))),
+            halo=halo,
             in_place=list(getattr(st, "in_place", ())),
             source=source, local_shape=list(self.local_shape),
             label=type(self).__name__)

@@ -884,6 +884,18 @@ class StreamingStencil:
         return len(self._slab_groups) * len(self._slab_keys)
 
     @property
+    def slab_bytes(self):
+        """The bytes the ``ppermute``s of :meth:`halo_slabs` move a call
+        and chip: every window component's ``wh`` rows of each sharded
+        axis' face, both directions (what leaves the chip: the zeros a
+        y slab is grown by stay local); 0 without slab edges."""
+        X, Y, Z = self.lattice_shape
+        rows = (Y * Z if self.x_slab else 0) + (X * Z if self.y_slab else 0)
+        return 2 * self.wh * rows * sum(
+            n * self.dtypes.get(k, self.dtype).itemsize
+            for k, n in self.win_defs.items())
+
+    @property
     def halo(self):
         """Where the (x, y) edges of the window come from: ``"wrap"``
         (the local periodic wrap), ``"slab"`` (thin halo-slab operands),

@@ -568,16 +568,17 @@ def test_undonated_stage_programs_copy_and_alias_nothing(v5e):
         assert "input_output_alias" not in hlo.split("\n", 1)[0], name
 
 
-def _gw_chunk(devices, carry_dtype, nsteps):
+def _gw_chunk(devices, carry_dtype, nsteps, proc_shape=(1, 1, 1),
+              grid=(384, 384, 384)):
     """The ``-gws`` coupled chunk of ``preheat-gw-f32`` (one chip, 384**3,
-    ``donate=True``) as ``coupled_multi_step`` jits it, and its abstract
-    arguments."""
-    grid = (384, 384, 384)
-    stepper_s, state, scalar = _preheat(devices, (1, 1, 1), grid)
+    ``donate=True``) or, on ``proc_shape`` (2, 2, 1) at (768, 768, 384),
+    of ``preheat-gw-mesh4-f32``, as ``coupled_multi_step`` jits it, and
+    its abstract arguments."""
+    stepper_s, state, scalar = _preheat(devices, proc_shape, grid)
     decomp = stepper_s.decomp
     stepper = ps.FusedPreheatStepper(
         stepper_s.sector, ps.TensorPerturbationSector([stepper_s.sector]),
-        decomp, grid, tuple(5.0 / n for n in grid), 2, dtype=jnp.float32,
+        decomp, grid, tuple(5.0 / 384 for _ in grid), 2, dtype=jnp.float32,
         dt=stepper_s.dt, donate=True, interpret=False,
         carry_dtype=carry_dtype)
     for k in ("hij", "dhijdt"):
@@ -617,6 +618,63 @@ def test_gw_chunk_at_384_and_what_the_carries_take(v5e):
     assert 14e9 < held[None] < 15.75 * 2**30, held
     with pytest.raises(Exception, match="RESOURCE_EXHAUSTED|hbm"):
         _gw_chunk(v5e, None, 1).compile()
+
+
+def test_gw_mesh_chunk_at_384_a_chip(v5e):
+    """What ``benchmark/configs/preheat-gw-mesh4-f32.json`` says of
+    memory, and what keeps the cell's kernel shares readable: on the
+    ``(2, 2, 1)`` mesh at (768, 768, 384), 384**3 a chip, the 4-step
+    coupled ``-gws`` chunk compiles for the v5e; it is 20 single-stage
+    energy kernels and no other custom call (the deferred pair finds no
+    blocking at 384**3 on the mesh either), fed by exchanged slabs on
+    both sharded axes (``block_choice``: ``halo`` slab, slab, with the
+    bytes a call moves), on the single launch (``overlap_plan``: a
+    kernel with sums keeps it); 20 ``all-reduce`` (the energy sums of a
+    stage in one), no ``all-gather``; arguments and temporaries
+    between 14.0 GB and the 15.75 GiB the compiler allows a chip. In
+    every kernel instruction ``custom_call_target`` stands inside the
+    first 1,600 characters: ``benchmark/trace_reduce.py`` keeps that
+    much of an instruction's text (``NAME_CHARS``) and tells a Mosaic
+    kernel by the mark, so past it ``energy_roofline``,
+    ``stencil_kernel_roofline`` and ``kernel_ms_per_step`` would read
+    nothing in the cell."""
+    import os
+    import re
+    from unittest import mock
+    from test_kernel_choice import _watch_events
+    # under the halo-overlap policy's own default, which is what a cell
+    # runs under (the suite pins the policy off in the environment)
+    with _watch_events() as seen, mock.patch.dict(os.environ):
+        os.environ.pop("PYSTELLA_HALO_OVERLAP", None)
+        compiled = _gw_chunk(v5e, None, 4, (2, 2, 1),
+                             (768, 768, 384)).compile()
+    hlo = compiled.as_text()
+    assert hlo.startswith("HloModule jit_coupled_multi_step_4"), hlo[:60]
+    names = _custom_call_names(hlo)
+    assert len(names) == 20
+    assert {re.sub(r"\.\d+$", "", n) for n in names} == {
+        "pallas_stencil_energy"}
+    assert len(re.findall(r"custom-call\(", hlo)) == 20
+    assert len(re.findall(r" all-reduce(?:-start)?\(", hlo)) == 20
+    assert "all-gather" not in hlo
+    assert re.search(r" collective-permute(?:-start)?\(", hlo)
+    mem = compiled.memory_analysis()
+    held = mem.argument_size_in_bytes + mem.temp_size_in_bytes
+    assert 14.0e9 < held < 15.75 * 2**30, held
+    (energy,) = [d for d in seen.of("block_choice")
+                 if d["kernel"] == "energy"]
+    assert energy["halo"] == ["slab", "slab"]
+    # eight window components (f, hij), two rows of an x and a y face,
+    # float32, there and back
+    assert energy["slab_bytes"] == 2 * 2 * (2 * 384 * 384) * 8 * 4
+    (plan,) = [d for d in seen.of("overlap_plan")
+               if d["kernel"] == "energy"]
+    assert (plan["path"], plan["reason"]) == ("single", "sums")
+    kernels = [ln for ln in hlo.splitlines()
+               if "custom_call_target=\"tpu_custom_call\"" in ln]
+    assert len(kernels) == 20
+    for ln in kernels:
+        assert ln.lstrip().index("custom_call_target") < 1600
 
 
 #: the binning programs of the two output cells on one chip:
