@@ -23,8 +23,10 @@ On the XLA path a smooth is a ``lax.fori_loop`` whose body fuses
 the stencil evaluation with the pointwise update, with ``lax.ppermute``
 halo exchanges inside (via ``shard_map``) on sharded levels and
 periodic-wrap pads on replicated (coarse) levels. On the Pallas path
-(``smoother="pallas"``) a sweep is one stencil kernel and the loop runs
-two sweeps an iteration, so that its carry's buffer and a temporary
+(``smoother="pallas"``) a sweep is one stencil kernel, or on a streaming
+unsharded level two sweeps are (the steppers' ``pair`` trade: both read
+old values only, so one pass over HBM serves two), and the loop runs two
+kernel calls an iteration, so that its carry's buffer and a temporary
 alternate and XLA copies nothing (``RelaxationBase._pallas_level``).
 
 Equations are specified as in the reference (``lhs_dict`` mapping unknown
@@ -291,12 +293,15 @@ class RelaxationBase:
         return fn
 
     def _plan_level(self, kind, level, decomp, dtype, tier, st=None,
-                    reason=None):
+                    reason=None, pair=None, pair_reason=None):
         """One ``mg_level_plan`` event a level: which tier serves it
         (``streaming`` with its blocking, ``resident``, or ``xla`` with
         the reason), said when the first of its kernels is built. A
         level's smooth, residual and tau kernels have the same windows,
-        extras and outputs, so what one of them gets the others get."""
+        extras and outputs, so what one of them gets the others get.
+        ``pair`` is the level's two-sweep smooth kernel
+        (``sweeps_per_pass`` 2, with its blocking) or, where a streaming
+        level keeps one sweep a pass, ``pair_reason`` says why."""
         key = (level, decomp)
         if key in self._planned:
             return
@@ -309,6 +314,9 @@ class RelaxationBase:
             tier=tier, stencil=type(st).__name__ if st is not None else None,
             bx=getattr(st, "bx", None), by=getattr(st, "by", None),
             grid=list(grid) if grid else None, reason=reason, kernel=kind,
+            sweeps_per_pass=2 if pair is not None else 1,
+            pair_bx=getattr(pair, "bx", None),
+            pair_by=getattr(pair, "by", None), pair_reason=pair_reason,
             dtype=str(jnp.dtype(dtype)), smoother=self.smoother,
             layout="stacked", label=type(self).__name__)
 
@@ -335,21 +343,33 @@ class RelaxationBase:
         and writes once — the identical streaming pattern as the fused
         RK stages.
 
-        A smooth is ``nu >= 1`` such kernel calls with ``nu`` a runtime
-        ``int32``, so one compile serves every sweep count, and no buffer
-        is ever both a kernel's operand and its result (the kernel cannot
-        write where it still reads, and XLA resolves that by a copy of
-        the whole stack beside the sweep: ``PERF.md`` section 6, PR 33).
-        First a ``cond`` takes the odd sweep, or for an even ``nu`` a
-        pair of sweeps, out of the parameter into a new buffer: both
-        branches compute, so neither passes its operand through (a
-        branch that did would have XLA copy the parameter before the
-        ``cond``, whichever branch runs), and the parameter is only read,
-        so it is not donated. Then a ``fori_loop`` runs TWO sweeps an
-        iteration on that buffer as its carry: the first writes a
-        temporary, the second the carry's buffer, whose last reader has
-        finished. The compiled v5e program holds no lattice-shaped
-        ``copy`` (``tests/test_tpu_compile.py``).
+        A smooth is ``nu >= 1`` sweeps with ``nu`` a runtime ``int32``,
+        so one compile serves every sweep count, and no buffer is ever
+        both a kernel's operand and its result (the kernel cannot write
+        where it still reads, and XLA resolves that by a copy of the
+        whole stack beside the sweep: ``PERF.md`` section 6, PR 33).
+        First a conditional takes the first sweeps out of the parameter
+        into a new buffer: every branch computes, so none passes its
+        operand through (a branch that did would have XLA copy the
+        parameter before the conditional, whichever branch runs), and
+        the parameter is only read, so it is not donated. Then a
+        ``fori_loop`` runs TWO kernel calls an iteration on that buffer
+        as its carry: the first writes a temporary, the second the
+        carry's buffer, whose last reader has finished. The compiled v5e
+        program holds no lattice-shaped ``copy``
+        (``tests/test_tpu_compile.py``).
+
+        On a streaming level that is not sharded a call of that loop is
+        the two-sweep kernel (PR 52): a sweep reads old values only, so
+        ``S(S(f))`` comes from one pass over HBM, out of windows two
+        radii wide of the unknowns and of what else a sweep reads at a
+        site, for the 3 ``nf`` lattice passes one sweep moves. An
+        iteration is then four sweeps and the conditional a four-way
+        switch on ``nu mod 4`` (one sweep, a pair, a pair after one, two
+        pairs). Elsewhere (a sharded level, whose padded copy carries
+        one radius; a resident level; a window the VMEM budget refuses:
+        ``mg_level_plan`` says which) a call is one sweep, an iteration
+        two, and the conditional takes the odd sweep or a first pair.
 
         Returns None when this level/mesh cannot take the kernel tier
         (z-sharded, sublane-infeasible sharded y, over-budget resident)
@@ -374,12 +394,16 @@ class RelaxationBase:
         aux_lat = [k for k, kk in aux_struct if kk == "lattice"]
         aux_scal = [k for k, kk in aux_struct if kk == "scalar"]
 
-        def body(taps, extras, scalars):
-            aux = {**{k: extras[k] for k in aux_lat},
-                   **{k: scalars[k] for k in aux_scal}}
-            return {"out": self._update(
-                kind, taps(), lap_from_taps(taps, coefs, inv_dx2),
-                extras["rhos"], aux, level.dx)}
+        def body_of(kind):
+            def body(taps, extras, scalars):
+                aux = {**{k: extras[k] for k in aux_lat},
+                       **{k: scalars[k] for k in aux_scal}}
+                return {"out": self._update(
+                    kind, taps(), lap_from_taps(taps, coefs, inv_dx2),
+                    extras["rhos"], aux, level.dx)}
+            return body
+
+        body = body_of(kind)
 
         st = None
         reason = ("z-sharded mesh, or a sharded y no 8-row window "
@@ -407,13 +431,49 @@ class RelaxationBase:
                              reason=reason)
             self._compiled[key] = None
             return None
+        sharded = px > 1 or py > 1
+        streaming = isinstance(st, StreamingStencil)
+        # two sweeps a pass (the steppers' `pair` trade): sweep 1 once
+        # over the block grown by h rows, from windows 2h wide of the
+        # unknowns and of everything the sweep reads at a site (the
+        # sources and lattice auxiliaries become windows too), then
+        # sweep 2 from that block: the single body twice, so the two
+        # calls' arithmetic and not an approximation of it, for one
+        # call's 3 nf lattice passes. Built beside the level's first
+        # kernel of any kind, whose plan says whether the smooth has it.
+        pair, pair_reason = None, None
+        if streaming and sharded:
+            pair_reason = ("sharded level: its padded copy carries one "
+                           "stencil radius in x")
+        elif streaming:
+            h = self.halo_shape
+            sweep = body_of("smooth")
+
+            def at_site(taps):
+                return {"rhos": taps["rhos"](),
+                        **{k: taps[k]()[0] for k in aux_lat}}
+
+            def pair_body(taps, extras, scalars):
+                wide = {k: t.grown(h) for k, t in taps.items()}
+                first = sweep(wide["f"], at_site(wide), scalars)["out"]
+                return sweep(taps["f"].over(first, h), at_site(taps),
+                             scalars)
+
+            try:
+                pair = StreamingStencil(
+                    local_shape,
+                    {"f": nf, "rhos": nf, **{k: 1 for k in aux_lat}},
+                    h, pair_body, {"out": (nf,)},
+                    scalar_names=tuple(aux_scal), dtype=dtype,
+                    win_halo=2 * h, stages=2, kind="mg_smooth")
+            except ValueError as e:
+                pair_reason = str(e)
         self._plan_level(
             kind, level, decomp, dtype,
-            "streaming" if isinstance(st, StreamingStencil) else "resident",
-            st=st)
+            "streaming" if streaming else "resident", st=st, pair=pair,
+            pair_reason=pair_reason)
 
         halo = sharded_halo(self.halo_shape, px, py)
-        sharded = px > 1 or py > 1
         ov = None
         if sharded:
             from pystella_tpu.ops.pallas_stencil import (
@@ -444,16 +504,28 @@ class RelaxationBase:
             if kind != "smooth":
                 return one(fstack)
 
-            def two(fst):
-                return one(one(fst))
+            if pair is None:
+                call = one
+            else:
+                wins = {"rhos": extras["rhos"],
+                        **{k: extras[k][None] for k in aux_lat}}
 
-            # the odd sweep or a first pair out of the parameter, then two
-            # sweeps an iteration: no buffer is both read and written,
+                def call(fst):
+                    return pair({"f": fst, **wins}, scalars=scalars)["out"]
+
+            def twice(fst):
+                return call(call(fst))
+
+            # the first sweeps out of the parameter by a switch on nu
+            # modulo an iteration's sweeps, then two calls an iteration:
+            # every branch computes, no buffer is both read and written,
             # and XLA copies nothing (the docstring says why)
-            first = 2 - nu % 2
+            first = [twice, one]
+            if pair is not None:
+                first += [call, lambda fst: call(one(fst))]
             return lax.fori_loop(
-                0, (nu - first) // 2, lambda _, fst: two(fst),
-                lax.cond(first == 1, one, two, fstack))
+                0, (nu - 1) // len(first), lambda _, fst: twice(fst),
+                lax.switch(nu % len(first), first, fstack))
 
         if sharded:
             from jax.sharding import PartitionSpec as P

@@ -117,7 +117,11 @@ for _name, _help in (
                  "out)"),
     ("mg_level_plan", "a multigrid level's kernels were built: which "
                       "tier serves it ('streaming' with bx/by/grid, "
-                      "'resident', or 'xla' with the reason) and the "
+                      "'resident', or 'xla' with the reason), how many "
+                      "sweeps a kernel pass of its smooth takes "
+                      "(sweeps_per_pass 2 with pair_bx/pair_by, the "
+                      "two-sweep kernel's blocking; 1 with, on a "
+                      "streaming level, the pair_reason) and the "
                       "layout its programs take and give ('stacked': "
                       "one (nf, X, Y, Z) array)"),
     ("mg_transfer_plan", "a multigrid restriction program was traced: "

@@ -52,6 +52,7 @@ from pystella_tpu.ops.derivs import (
 from pystella_tpu.ops.pallas_stencil import (
     ResidentStencil, StreamingStencil,
     grad_from_taps as _grad_from_taps, lap_from_taps as _lap_from_taps,
+    memo_taps as _memo_taps,
 )
 
 __all__ = ["FusedScalarStepper", "FusedPreheatStepper", "CARRY_SCOPE"]
@@ -623,27 +624,6 @@ class FusedScalarStepper(_step.Stepper):
     # transfers to 8/D (2 at depth 4).
 
     @staticmethod
-    def _memo_taps(compute_xy, roll):
-        """A taps-like view from an (sx, sy) -> block expression:
-        memoized per offset, z offsets as in-register rolls of the
-        offset-0 block (the ``_axpy_taps`` contract)."""
-        cache = {}
-
-        def taps(sx=0, sy=0, sz=0):
-            key = (sx, sy, sz)
-            if key in cache:
-                return cache[key]
-            if sz != 0:
-                if sx or sy:
-                    raise ValueError("taps must be axis-aligned")
-                out = roll(taps(), sz)
-            else:
-                out = compute_xy(sx, sy)
-            cache[key] = out
-            return out
-        return taps
-
-    @staticmethod
     def _lap_at(t, roll, coefs, inv_dx2, sx, sy):
         """The Laplacian of a taps-like view at a shifted base offset:
         a shifted-taps adapter handed to THE :func:`ops.pallas_stencil.
@@ -666,16 +646,16 @@ class FusedScalarStepper(_step.Stepper):
         :meth:`_scalar_pair_core`, evaluated lazily at any offset."""
         inv_dx2 = [1.0 / d**2 for d in self.dx]
         coefs = _lap_coefs[self.h]
-        kf1 = self._memo_taps(
+        kf1 = _memo_taps(
             lambda sx, sy: A * tkf(sx, sy) + dt * tdf(sx, sy), roll)
-        f1 = self._memo_taps(
+        f1 = _memo_taps(
             lambda sx, sy: tf(sx, sy) + B * kf1(sx, sy), roll)
-        kdf1 = self._memo_taps(
+        kdf1 = _memo_taps(
             lambda sx, sy: A * tkdf(sx, sy) + dt * (
                 self._lap_at(tf, roll, coefs, inv_dx2, sx, sy)
                 - 2 * hub * tdf(sx, sy)
                 - a * a * self._dV(tf(sx, sy), a, hub)), roll)
-        df1 = self._memo_taps(
+        df1 = _memo_taps(
             lambda sx, sy: tdf(sx, sy) + B * kdf1(sx, sy), roll)
         return f1, df1, kf1, kdf1
 
@@ -698,10 +678,10 @@ class FusedScalarStepper(_step.Stepper):
                 scalars[f"a{i}"], scalars[f"hubble{i}"],
                 scalars[f"A{i}"], scalars[f"B{i}"])
             if cd is not None and j % 2 == 1 and j < depth - 1:
-                tkf = self._memo_taps(
+                tkf = _memo_taps(
                     lambda sx, sy, t=tkf: _carry_cast(t(sx, sy), cd),
                     roll)
-                tkdf = self._memo_taps(
+                tkdf = _memo_taps(
                     lambda sx, sy, t=tkdf: _carry_cast(t(sx, sy), cd),
                     roll)
         return {"f": tf(), "dfdt": tdf(), "kf": tkf(), "kdfdt": tkdf()}

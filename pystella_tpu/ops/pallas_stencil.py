@@ -74,8 +74,8 @@ from pystella_tpu.obs.scope import (
 __all__ = ["StreamingStencil", "ResidentStencil", "OverlapStreamingStencil",
            "OverlapInfeasible", "Taps", "HY", "LANE",
            "choose_blocks", "feasible_blocks", "reread", "sharded_halo",
-           "lap_from_taps", "grad_from_taps", "VMEM_LIMIT_BYTES",
-           "BLOCK_BUDGET_BYTES", "TIER_BUDGET_BYTES"]
+           "lap_from_taps", "grad_from_taps", "memo_taps",
+           "VMEM_LIMIT_BYTES", "BLOCK_BUDGET_BYTES", "TIER_BUDGET_BYTES"]
 
 #: aligned y-halo width (one sublane tile); must be >= the stencil radius
 HY = 8
@@ -330,6 +330,63 @@ class Taps:
         # int32 shift: under x64 a bare python int traces as i64, which
         # tpu.dynamic_rotate rejects (caught by tests/test_tpu_lowering.py)
         return pltpu.roll(arr, jnp.int32((self._Z - sz) % self._Z), 3)
+
+    def grown(self, m):
+        """Taps of the block grown by ``m`` rows on either side in x and
+        to the whole window in y: ``(C, bx + 2m, by + 2 * HY, Z)``
+        blocks, for a body evaluated once where a second stencil will
+        read it (``multigrid/relax.py``'s two-sweep kernel). Axis-aligned
+        offsets up to ``wh - m`` in x (slices of the window's untiled
+        axis); a y offset is a sublane roll of the offset-0 block, whose
+        wrapped rows land in the window's outermost ``|sy|`` rows, so of
+        a radius-``h`` body's result the rows within ``HY - h`` of the
+        block are good: what :meth:`over` reads, ``m <= HY - h``."""
+        wh, bx = self._wh, self._bx
+        if not 0 < m <= wh:
+            raise ValueError(f"cannot grow a block by {m} rows inside a "
+                             f"window halo of {wh}")
+
+        def block(sx, sy):
+            if sy:
+                if sx:
+                    raise ValueError("taps must be axis-aligned")
+                if self._interpret:
+                    return jnp.roll(taps(), -sy, axis=2)
+                byw = self._by + 2 * HY  # an int32 shift, as in roll
+                return pltpu.roll(taps(), jnp.int32((byw - sy) % byw), 2)
+            if abs(sx) > wh - m:
+                raise ValueError(f"x offset {sx} leaves the window")
+            return self._w[:, wh - m + sx:wh + m + sx + bx]
+
+        taps = memo_taps(block, self.roll)
+        return taps
+
+    def over(self, block, m):
+        """The :class:`Taps` of a computed block shaped as :meth:`grown`
+        gives them: a window of halo ``m`` for the next stencil."""
+        return Taps(block, self._h, self._bx, self._by, self._Z,
+                    self._interpret, wh=m)
+
+
+def memo_taps(compute_xy, roll):
+    """A taps-like view from an (sx, sy) -> block expression: memoized
+    per offset, z offsets as in-register rolls of the offset-0 block
+    (what :class:`Taps` lowers its own z offsets to)."""
+    cache = {}
+
+    def taps(sx=0, sy=0, sz=0):
+        key = (sx, sy, sz)
+        if key in cache:
+            return cache[key]
+        if sz != 0:
+            if sx or sy:
+                raise ValueError("taps must be axis-aligned")
+            out = roll(taps(), sz)
+        else:
+            out = compute_xy(sx, sy)
+        cache[key] = out
+        return out
+    return taps
 
 
 def _sum_tile(terms, shape, dtype):

@@ -204,6 +204,11 @@ _CELL_KERNELS = [
     ("multigrid-512-f32", "mg_smooth", 2, (1, 128), (1, 128), "heuristic"),
     ("multigrid-512-f32", "mg_smooth", 3, None, None, "resident"),
     ("multigrid-512-f32", "mg_smooth", 6, None, None, "resident"),
+    # the two-sweep kernel a streaming level's smooth runs (PR 52): four
+    # window components (unknowns and sources) 2h wide, so bx = 2
+    ("multigrid-512-f32", "mg_smooth_pair", 0, (2, 256), None, "heuristic"),
+    ("multigrid-512-f32", "mg_smooth_pair", 1, (2, 256), None, "heuristic"),
+    ("multigrid-512-f32", "mg_smooth_pair", 2, (2, 128), None, "heuristic"),
     # --halo-shape 4 at 512^3 (PR 40): a radius of 4 has no smaller x
     # block than 4 to take, the ring is twice as deep and the y block
     # half the h = 2 cells'; as the chip run built them
@@ -272,9 +277,15 @@ def test_cells_get_the_kernels_the_ledger_measured(config, kernel, nth,
         d = built["plans"][nth]
         assert d["grid_shape"] == [512 >> nth] * 3 and d["kernel"] == "smooth"
         assert d["smoother"] == "pallas" and d["dtype"] == "float32"
-        if blocks is None:
+        if kernel == "mg_smooth_pair":
+            assert d["tier"] == "streaming" and d["sweeps_per_pass"] == 2
+            assert (d["pair_bx"], d["pair_by"]) == blocks
+            assert d["pair_reason"] is None
+        elif blocks is None:
             assert (d["tier"], d["stencil"]) == (source, "ResidentStencil")
             assert d["bx"] is d["by"] is d["grid"] is None
+            assert d["sweeps_per_pass"] == 1
+            assert d["pair_bx"] is d["pair_by"] is d["pair_reason"] is None
         else:
             assert (d["tier"], d["stencil"]) == ("streaming",
                                                  "StreamingStencil")
@@ -538,14 +549,18 @@ def test_level_plans_carry_what_the_benchmark_prints(smoother, tiers):
     for d in plans:
         assert {"grid_shape", "local_shape", "tier", "stencil", "bx", "by",
                 "grid", "reason", "kernel", "dtype", "smoother",
-                "label"} <= set(d)
+                "label", "sweeps_per_pass", "pair_bx", "pair_by",
+                "pair_reason"} <= set(d)
         assert d["smoother"] == smoother and d["dtype"] == "float32"
         if d["tier"] == "streaming":
             n = d["grid_shape"][0]
             assert d["grid"] == [n // d["by"], n // d["bx"]]
             assert d["reason"] is None
+            assert (d["sweeps_per_pass"], d["pair_bx"], d["pair_by"],
+                    d["pair_reason"]) == (2, 2, n, None)
         else:
             assert d["bx"] is None and d["reason"] == "smoother='xla'"
+            assert d["sweeps_per_pass"] == 1 and d["pair_reason"] is None
     assert len(seen.of("mg_cycle")) == 2
 
 

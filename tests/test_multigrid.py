@@ -2,6 +2,8 @@
 V-cycles on Poisson + Helmholtz must converge the residual to machine
 precision, plus transfer-operator identities and a nonlinear FAS solve)."""
 
+import os
+
 import numpy as np
 import pytest
 
@@ -441,6 +443,125 @@ def test_pallas_smooth_is_nu_single_sweeps(make_decomp, grid_shape,
     assert len(smooths) == 1 and solver._compiled[smooths[0]] is not None
 
 
+#: the two-sweep kernel's cases: name -> (problems, auxiliary arrays by
+#: name from (rng, decomp, grid_shape)); ``None`` marks a lattice-shaped
+#: auxiliary, a float a scalar one
+_PAIR_CASES = {
+    # the benchmark cell's Poisson + Helmholtz pair
+    "cell": (make_problems, {}),
+    # a nonlinear operator: the diagonal depends on the unknown
+    "nonlinear": (lambda: {ps.Field("f"): (
+        ps.Field("lap_f") - ps.Field("f") + ps.Field("f") ** 3,
+        ps.Field("rho"))}, {}),
+    # a lattice-shaped coefficient (a window of the pair kernel, an
+    # extra of the single one) beside a scalar one
+    "aux": (lambda: {ps.Field("f"): (
+        ps.Field("lap_f") - (ps.Field("m2") + ps.Var("c")) * ps.Field("f"),
+        ps.Field("rho"))}, {"m2": None, "c": 0.25}),
+}
+_PAIR_CHAINS = {}
+#: the XLA flag under which the CPU's compiler contracts nothing
+_UNCONTRACTED = "--xla_backend_optimization_level=0"
+
+
+def _pair_chain(make_decomp, grid_shape, case):
+    """The case's solvers, level and arrays, and the unknowns after 0..9
+    calls of ONE sweep each (the single-sweep kernel alone: a call of
+    one sweep takes the `nu mod 4 = 1` branch): built by the first case
+    that needs them."""
+    from pystella_tpu.multigrid.relax import LevelSpec
+    from pystella_tpu.obs import events
+    if case not in _PAIR_CHAINS:
+        problems, aux_spec = _PAIR_CASES[case]
+        decomp = make_decomp((1, 1, 1))
+        level = LevelSpec(tuple(grid_shape), (10.0 / grid_shape[0],) * 3,
+                          False)
+        kw = dict(halo_shape=1, dtype=np.float32,
+                  fixed_parameters=dict(omega=1 / 2))
+        solver = NewtonIterator(decomp, problems(), smoother="pallas", **kw)
+        xla = NewtonIterator(decomp, problems(), smoother="xla", **kw)
+        rng = np.random.default_rng(52)
+        nf = len(solver.f_to_rho_dict)
+        arrays = zero_mean_arrays(rng, decomp, grid_shape, 2 * nf,
+                                  dtype=np.float32)
+        fs = dict(zip(solver.f_to_rho_dict, arrays))
+        rhos = dict(zip(solver.f_to_rho_dict.values(), arrays[nf:]))
+        aux = {k: (decomp.shard((1 + rng.random(grid_shape)).astype(
+            np.float32)) if v is None else v) for k, v in aux_spec.items()}
+        records = []
+        events.get_log().subscribe(records.append)
+        try:
+            chain = [fs]
+            for _ in range(9):
+                chain.append(solver.smooth(level, chain[-1], rhos, aux, 1,
+                                           decomp))
+        finally:
+            events.get_log().unsubscribe(records.append)
+        plan, = [r["data"] for r in records if r["kind"] == "mg_level_plan"]
+        _PAIR_CHAINS[case] = (decomp, level, solver, xla, rhos, aux, chain,
+                              plan)
+    return _PAIR_CHAINS[case]
+
+
+@pytest.mark.parametrize("nu", range(1, 10))
+@pytest.mark.parametrize("case", list(_PAIR_CASES))
+def test_pallas_pair_kernel_is_two_sweeps(make_decomp, grid_shape, case, nu):
+    """On a streaming, unsharded level a smooth runs two sweeps a kernel
+    pass (PR 52: sweep 1 once over the block grown by ``h`` rows, sweep
+    2 from it, the single kernel's body twice), four sweeps a loop
+    iteration and ``nu mod 4`` taken out in front by a four-way switch:
+    ``smooth(..., nu)`` is ``nu`` calls of the single-sweep kernel bit
+    for bit, for every remainder with and without loop iterations
+    (``nu`` 1...9), for the benchmark cell's two linear problems and
+    with a lattice-shaped auxiliary (a window of the pair kernel) beside
+    a scalar one, and for an operator whose diagonal depends on the
+    unknown as far as the CPU's compiler lets two programs agree; and
+    the XLA path's ``nu`` sweeps to float32 rounding. Interpret mode on
+    the CPU."""
+    decomp, level, solver, xla, rhos, aux, chain, plan = _pair_chain(
+        make_decomp, grid_shape, case)
+    assert (plan["tier"], plan["sweeps_per_pass"]) == ("streaming", 2), plan
+    assert plan["pair_bx"] >= 2 and plan["pair_reason"] is None, plan
+    got = solver.smooth(level, chain[0], rhos, aux, nu, decomp)
+    ref = xla.smooth(level, chain[0], rhos, aux, nu, decomp)
+    for n, want in chain[nu].items():
+        if case == "cell" or _UNCONTRACTED in os.environ.get("XLA_FLAGS", ""):
+            np.testing.assert_array_equal(np.asarray(got[n]),
+                                          np.asarray(want), err_msg=n)
+        else:
+            # XLA:CPU contracts a product into the sum after it in one
+            # of the two programs and not in the other: one rounding of
+            # the largest value a sweep (bit for bit where it does not
+            # contract: the test below, and the chip)
+            want = np.asarray(want)
+            assert np.max(np.abs(np.asarray(got[n]) - want)) \
+                <= nu * np.finfo(np.float32).eps * np.max(np.abs(want)), n
+        r = np.asarray(ref[n])
+        assert np.max(np.abs(np.asarray(got[n]) - r)) \
+            < 2e-6 * np.max(np.abs(r)), n
+
+
+def test_pallas_pair_kernel_is_two_sweeps_bit_for_bit_uncontracted():
+    """The cases of ``test_pallas_pair_kernel_is_two_sweeps`` whose
+    operator holds a product before a sum, bit for bit: in a child whose
+    XLA:CPU runs no LLVM optimisation, so that no product is contracted
+    into a sum in one program and left alone in the other. The pair
+    kernel's arithmetic is the single kernel's twice, operation for
+    operation."""
+    import subprocess
+    import sys
+    env = dict(os.environ, XLA_FLAGS=_UNCONTRACTED, JAX_PLATFORMS="cpu")
+    out = subprocess.run(
+        [sys.executable, "-m", "pytest", __file__, "-q", "-p",
+         "no:cacheprovider", "-p", "no:xdist", "-k",
+         "test_pallas_pair_kernel_is_two_sweeps and not cell"
+         " and not uncontracted"],
+        env=env, capture_output=True, text=True, timeout=600,
+        cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+    assert out.returncode == 0 and "18 passed" in out.stdout, \
+        out.stdout[-3000:] + out.stderr[-1000:]
+
+
 # -- the walk's layout: stacks, against the operations by name ---------------
 
 def _cycle_by_name(mg, decomp, dx, cycle, unknowns, rhos):
@@ -562,3 +683,10 @@ def test_stacked_walk_is_the_operations_by_name(make_decomp, grid_shape,
     assert [d["dispatches"] for d in done] == [3 * 5 + down_up + 3] * 2
     plans = [r["data"] for r in records if r["kind"] == "mg_level_plan"]
     assert plans and all(d["layout"] == "stacked" for d in plans)
+    # a streaming level's smooth takes two sweeps a kernel pass unless the
+    # level is sharded, and then says why it does not
+    for d in plans:
+        if d["tier"] == "streaming":
+            sharded = d["local_shape"] != d["grid_shape"]
+            assert d["sweeps_per_pass"] == (1 if sharded else 2), d
+            assert (d["pair_reason"] or "").startswith("sharded") == sharded

@@ -807,17 +807,21 @@ def test_multigrid_smooth_loop_copies_no_lattice(v5e, monkeypatch, n):
     """A streaming level's smooth program holds no lattice-shaped ``copy``
     inside its ``while`` body, and at most one in the whole module. The
     sweep loop's carry is the ``(2, n, n, n)`` stack of unknowns, and the
-    kernel cannot write the buffer it reads: with one sweep an iteration
-    (the parent of PR 33, on which this test fails: it finds ``%copy.9``
-    in ``%wide.region_0.1.sunk``) XLA copies the carry before every kernel
-    call, 2.15 GB and 3.2 ms beside a 4.84-ms kernel at 512**3. With two
-    sweeps an iteration the body is two kernel calls whose buffers
-    alternate; each still takes two lattice operands (unknowns, sources)
-    and gives one output, which is what the benchmark's byte count reads
-    from the instruction. Since PR 44 the program's parameter is that
-    stack itself and the odd sweep (or a first pair) is taken out of it
-    by a ``cond`` in front of the loop, both of whose branches compute:
-    the whole module holds no lattice-shaped ``copy``."""
+    kernel cannot write the buffer it reads: with one kernel call an
+    iteration (the parent of PR 33, on which this test fails: it finds
+    ``%copy.9`` in ``%wide.region_0.1.sunk``) XLA copies the carry before
+    every kernel call, 2.15 GB and 3.2 ms beside a 4.84-ms kernel at
+    512**3. With two calls an iteration the body is two kernel calls
+    whose buffers alternate; each still takes two lattice operands
+    (unknowns, sources) and gives one output, which is what the
+    benchmark's byte count reads from the instruction. Since PR 44 the
+    program's parameter is that stack itself and the first sweeps are
+    taken out of it in front of the loop by a conditional all of whose
+    branches compute: the whole module holds no lattice-shaped ``copy``.
+    Since PR 52 a call of the loop is the two-sweep kernel (both stacks
+    as windows: still two lattice operands and one output, under the
+    same name), an iteration four sweeps, and the conditional has the
+    four branches of ``nu mod 4``."""
     import re
     hlo = _mg_level_compiler(v5e, monkeypatch, n)("smooth").as_text()
     bodies = re.findall(r" while\(.*\bbody=%([\w.]+)", hlo)
@@ -865,7 +869,9 @@ def test_multigrid_level_program_writes_no_lattice_but_its_kernels(
             if m and re.search(r"f32\[(2,)?512,512,512\]", m.group(2)):
                 written.add((m.group(1), m.group(3)))
     kernels = [name for name, op in written if op == "custom-call"]
-    assert len(kernels) == (1 if kind == "residual" else 5), kernels
+    # a smooth: the four branches of nu mod 4 (two calls, one, one, two)
+    # and the loop body's two (PR 52; 3 + 2 with one sweep a call)
+    assert len(kernels) == (1 if kind == "residual" else 8), kernels
     assert all(name.startswith(f"pallas_stencil_mg_{kind}.")
                for name in kernels), kernels
     others = [(name, op) for name, op in written
